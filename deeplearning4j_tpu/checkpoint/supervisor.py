@@ -84,6 +84,24 @@ class _Worker:
         self.respawn_at: Optional[float] = None  # backoff gate
 
 
+def _refuse_if_holding_accelerator():
+    """A chip belongs to one process: a supervisor that has initialised
+    an accelerator backend holds the chips its workers need, and they
+    would fail or hang at start-up. (A CPU backend, as in the tests, is
+    shared freely.)"""
+    import jax
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized():
+        platform = jax.default_backend()
+        if platform != "cpu":
+            raise RuntimeError(
+                f"train_until_process: this process has initialised the "
+                f"{platform} backend and holds its chips, so worker "
+                f"processes cannot start on them. Supervise from a "
+                f"process that has not touched JAX (the supervisor "
+                f"itself needs no device).")
+
+
 def train_until_process(worker_argv: Union[Sequence[str], Callable],
                         num_workers: int = 1,
                         restart_policy: Optional[RestartPolicy] = None,
@@ -103,7 +121,9 @@ def train_until_process(worker_argv: Union[Sequence[str], Callable],
     ``worker_argv`` is the argv list every worker runs, or a callable
     ``(worker_index, attempt) -> argv`` (attempt is 1-based per slot).
     Workers learn their identity from their argv — the supervisor passes
-    nothing implicitly.
+    nothing implicitly; several workers on one accelerator host must each
+    confine themselves to a chip of their own. The supervising process
+    must not have initialised an accelerator backend (RuntimeError).
 
     ``checkpoint_manager`` (optional, read-only here) annotates the crash
     history with the store's latest committed step at each crash/respawn
@@ -119,6 +139,7 @@ def train_until_process(worker_argv: Union[Sequence[str], Callable],
     complete, or when ``overall_timeout_s`` expires (everything is killed
     first — the caller never inherits a zombie fleet).
     """
+    _refuse_if_holding_accelerator()
     policy = restart_policy if restart_policy is not None else RestartPolicy()
     rng = random.Random(policy.seed)
     if log_dir is None:

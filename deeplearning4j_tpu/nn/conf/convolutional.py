@@ -564,7 +564,7 @@ def _bn_act_fwd_math(act_name, eps, z, gamma, beta, res):
     # otherwise — take() records kernel.pallas_/.xla_ either way
     from deeplearning4j_tpu.perf import pallas as _pk
     from deeplearning4j_tpu.perf.pallas import bn as _pk_bn
-    if _pk.take("bn_act", _pk_bn.supported(z)):
+    if _pk.take("bn_act", _pk_bn.supported(z, res is not None)):
         return _pk_bn.bn_act_fwd(act_name, eps, z, gamma, beta, res)
     mean, var = _bn_train_stats(z)
     sdt = var.dtype
@@ -603,7 +603,8 @@ def _fused_bn_act_bwd(act_name, eps, saved, cts):
     dout = cts[0]  # mean/var cotangents ignored (EMA-only outputs)
     from deeplearning4j_tpu.perf import pallas as _pk
     from deeplearning4j_tpu.perf.pallas import bn as _pk_bn
-    if _pk.take("bn_act_bwd", _pk_bn.supported(z)):
+    if _pk.take("bn_act_bwd",
+                _pk_bn.supported(z, res is not None, backward=True)):
         dz, dgamma, dbeta, dpre = _pk_bn.bn_act_bwd(
             act_name, eps, z, gamma, beta, res, mean, inv, dout)
         dres = None if res is None else dpre.astype(res.dtype)

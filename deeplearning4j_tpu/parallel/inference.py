@@ -351,6 +351,15 @@ class ParallelInference:
                                    target=target):
                 with self._model_lock:
                     out = self.model.output(arr)
+            if isinstance(out, list):
+                # ComputationGraph.output: one array per network output.
+                # Batches are padded, coalesced and split by ROW, so a
+                # graph is served through its one output
+                if len(out) != 1:
+                    raise ValueError(
+                        f"ParallelInference serves single-output models; "
+                        f"this graph has {len(out)} outputs")
+                out = out[0]
             return out[:n] if target != n else out
 
     def output(self, x) -> np.ndarray:

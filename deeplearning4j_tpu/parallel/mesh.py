@@ -58,9 +58,10 @@ def data_sharding(mesh: Mesh, ndim: int) -> NamedSharding:
 
 
 def shard_batch(mesh: Mesh, arr):
-    """Place one host array with its batch axis sharded over the mesh."""
-    import jax.numpy as jnp
-    a = jnp.asarray(arr)
+    """Place one array with its batch axis sharded over the mesh. A host
+    array goes straight to its shards — never whole onto the first
+    device and from there to the others."""
+    a = arr if isinstance(arr, jax.Array) else np.asarray(arr)
     return jax.device_put(a, data_sharding(mesh, a.ndim))
 
 

@@ -80,7 +80,8 @@ def pipeline_apply(block_fn: Callable, stacked_params, x_microbatches,
         T = M + S - 1
         # the carry becomes stage-varying after the first tick; mark the
         # initial zeros accordingly (shard_map varying-axes typing)
-        zero = jax.lax.pvary(jnp.zeros_like(xs[0]), (STAGE_AXIS,))
+        zero = jax.lax.pcast(jnp.zeros_like(xs[0]), (STAGE_AXIS,),
+                             to="varying")
         fwd = [(i, i + 1) for i in range(S - 1)]
 
         def tick(carry, t):
@@ -101,10 +102,9 @@ def pipeline_apply(block_fn: Callable, stacked_params, x_microbatches,
         outs = collected[S - 1:]
         return jax.lax.psum(outs, STAGE_AXIS)
 
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(per_stage, mesh=mesh,
-                   in_specs=(P(STAGE_AXIS), P()),
-                   out_specs=P())
+    fn = jax.shard_map(per_stage, mesh=mesh,
+                       in_specs=(P(STAGE_AXIS), P()),
+                       out_specs=P())
     return fn(stacked_params, x_microbatches)
 
 

@@ -24,7 +24,6 @@ import logging
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -32,7 +31,8 @@ log = logging.getLogger(__name__)
 
 from deeplearning4j_tpu.datasets.dataset import DataSet
 from deeplearning4j_tpu.parallel.mesh import (
-    DATA_AXIS, make_mesh, replicated, data_sharding, tp_shardings,
+    DATA_AXIS, data_sharding, make_mesh, replicated, shard_batch,
+    tp_shardings,
 )
 
 
@@ -166,10 +166,7 @@ class ParallelWrapper:
                 f"Global batch {n} not divisible by data-parallel size {dp}")
 
         def put(a):
-            if a is None:
-                return None
-            arr = jnp.asarray(a)
-            return jax.device_put(arr, data_sharding(self.mesh, arr.ndim))
+            return None if a is None else shard_batch(self.mesh, a)
 
         return DataSet(put(ds.features), put(ds.labels),
                        put(ds.features_mask), put(ds.labels_mask))
@@ -327,9 +324,7 @@ class ParallelWrapper:
     def output(self, x) -> np.ndarray:
         self._place_params()
         with self.mesh:
-            arr = jnp.asarray(x)
-            arr = jax.device_put(arr, data_sharding(self.mesh, arr.ndim))
-            return self.model.output(arr)
+            return self.model.output(shard_batch(self.mesh, x))
 
 
 class ClusterTrainer(ParallelWrapper):
@@ -408,11 +403,11 @@ class ClusterTrainer(ParallelWrapper):
         def gput(a):
             if a is None:
                 return None
-            arr = np.asarray(a)
-            sh = data_sharding(self.mesh, arr.ndim)
             if jax.process_count() == 1:
-                return jax.device_put(jnp.asarray(arr), sh)
-            return jax.make_array_from_process_local_data(sh, arr)
+                return shard_batch(self.mesh, a)
+            arr = np.asarray(a)
+            return jax.make_array_from_process_local_data(
+                data_sharding(self.mesh, arr.ndim), arr)
         return DataSet(gput(ds.features), gput(ds.labels),
                        features_mask=gput(ds.features_mask),
                        labels_mask=gput(ds.labels_mask))

@@ -203,19 +203,16 @@ def _install_listener():
     global _listener_installed
     if _listener_installed:
         return
-    try:
-        import jax.monitoring as monitoring
+    import jax
 
-        def _on_event(name, **kwargs):
-            if "compile" in name:
-                global _backend_compile_events
-                with _backend_lock:
-                    _backend_compile_events += 1
+    def _on_event(name, **kwargs):
+        if "compile" in name:
+            global _backend_compile_events
+            with _backend_lock:
+                _backend_compile_events += 1
 
-        monitoring.register_event_listener(_on_event)
-        _listener_installed = True
-    except Exception:  # pragma: no cover - older jax without monitoring
-        pass
+    jax.monitoring.register_event_listener(_on_event)
+    _listener_installed = True
 
 
 def backend_compile_events() -> int:

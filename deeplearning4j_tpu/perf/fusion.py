@@ -625,11 +625,11 @@ def _residual_bytes_of(run, *arg_structs) -> int:
     """Bytes of the tensors autodiff saves between forward and backward.
 
     ``run`` must call ``jax.vjp`` of a **jitted** scalar-valued forward:
-    partial evaluation then stages the forward as the first ``pjit``
+    partial evaluation then stages the forward as the first ``jit``
     equation of the jaxpr, whose outputs are exactly (primal, *residuals) —
     so the residual set is read off the jaxpr without allocating a byte."""
     jaxpr = jax.make_jaxpr(run)(*arg_structs)
-    fwd = next(e for e in jaxpr.eqns if e.primitive.name == "pjit")
+    fwd = next(e for e in jaxpr.eqns if e.primitive.name == "jit")
     total = 0
     for v in fwd.outvars[1:]:  # outvars[0] is the scalar loss
         aval = v.aval

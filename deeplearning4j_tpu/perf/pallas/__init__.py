@@ -1,7 +1,8 @@
 """Hand-written Pallas kernel layer: selection, fallback and counters.
 
-BENCH_r05 pins the ResNet50 bf16 step within ~5% of the measured HBM
-bandwidth floor: conv fwd+dW+dX alone would allow 51.4% MFU, but the
+The last chip profile on record (tools/PROFILE_r5.md) pins
+the ResNet50 bf16 step within ~5% of the measured HBM bandwidth floor:
+conv fwd+dW+dX alone would allow 51.4% MFU, but the
 BN-train stats/normalize/residual traffic XLA refuses to fuse across
 costs ~4.7 extra full activation-set HBM crossings (tools/PROFILE_r5.md).
 This package holds the kernels that cross that line by hand — SURVEY
@@ -23,13 +24,17 @@ Selection contract (every kernel, no exceptions):
 
 1. The jnp/XLA reference implementation stays where it is and remains
    the default. A kernel is USED only when :func:`enabled` resolves
-   true — explicitly via :func:`configure`/:func:`override`, via the
-   ``DLT_PALLAS`` env var, or automatically on a TPU backend. Anywhere
-   Pallas is unavailable or the platform is unsupported the reference
-   runs, silently and correctly.
+   true for its family — explicitly via :func:`configure`/
+   :func:`override` or the ``DLT_PALLAS`` env var (every family), or
+   automatically on a TPU backend (the families of
+   :data:`TPU_AUTO_FAMILIES` only) — AND the call site's shape predicate
+   (``bn.supported``, ``adc.pq_supported``, ``adc.int4_supported``) says
+   the kernel fits. Anywhere else the reference runs.
 2. Off-TPU, a force-enabled kernel runs in Pallas **interpret mode**
    (:func:`interpret` resolves true) — this is how CPU CI bitwise/
    tolerance-parity-tests the kernel bodies (tests/test_zz_pallas.py).
+   On a TPU backend nothing but an explicit ``configure(interpret=True)``
+   interprets.
 3. Every dispatch records which implementation served it:
    ``kernel.pallas_<family>`` / ``kernel.xla_<family>`` CompileWatch
    counters (``bump_active`` — landing on the owning model/index watch
@@ -43,10 +48,11 @@ Selection contract (every kernel, no exceptions):
    and the HBM planner snapshots it per plan
    (``MemoryPlan.kernels``).
 
-TPU-round caveat: this container is CPU-only, so the deliverable here is
-interpret-mode parity plus the candidate/fallback/observability
-plumbing; the measured activation-crossing / step-time thresholds are
-deferred to the TPU round (ROADMAP direction 2 backlog).
+What the v5e compiler (jax 0.9.0 / libtpu 0.0.34) says, family by
+family, is kept as tests: tests/test_chip_compile.py compiles every
+auto-selected kernel for a described v5e at a main-path shape, and
+chip_smoke.py's ``kernels`` phase runs each against its reference on
+the chip. No kernel has a measured speed yet (ROADMAP Speed 3/6).
 """
 
 from __future__ import annotations
@@ -57,9 +63,9 @@ import threading
 from typing import Callable, Dict, Optional
 
 __all__ = [
-    "FAMILIES", "available", "enabled", "interpret", "configure",
-    "override", "candidate_flags", "selection_snapshot", "take",
-    "kernel_select",
+    "FAMILIES", "TPU_AUTO_FAMILIES", "INTERPRET_ONLY_FAMILIES", "enabled",
+    "interpret", "configure", "override", "candidate_flags",
+    "selection_snapshot", "take", "kernel_select",
 ]
 
 # Kernel families this layer provides, family -> the boundary the kernel
@@ -76,39 +82,40 @@ FAMILIES: Dict[str, str] = {
                 "dense weights)",
 }
 
+# Families the automatic rule selects on a TPU backend: those the v5e
+# compiler accepts at the full-width shapes their call sites produce.
+# Not selected, with the compiler's words:
+# - bn_act / bn_act_bwd: whole-row channel tiles — ResNet50 batch 128
+#   fits only at the 7x7 stage ("RESOURCE_EXHAUSTED ... input window
+#   allocation ... bf16[401408,128]" at (128,56,56,256)); needs row
+#   blocking (ROADMAP Speed 3).
+# - adc_ivf_pq: data-dependent CSR row gather in-kernel, which Mosaic
+#   does not lower (its flat sibling's jnp.take: "Shape mismatch in
+#   input, indices and output"); needs a DMA rework (ROADMAP Speed 6).
+TPU_AUTO_FAMILIES = frozenset({"adc_pq", "int4_dot"})
+# No shape of these compiles, so not even an explicit enable (a
+# TuningRecord's ``pallas_kernels=True`` is applied process-wide) selects
+# them outside interpret mode.
+INTERPRET_ONLY_FAMILIES = frozenset({"adc_ivf_pq"})
+
 _UNSET = object()
 _lock = threading.Lock()
 _state = {"enabled": None, "interpret": None}  # None = resolve automatically
-_avail: Optional[bool] = None
-
-
-def available() -> bool:
-    """Is ``jax.experimental.pallas`` importable at all? (Cached; a JAX
-    build without Pallas simply never selects a kernel.)"""
-    global _avail
-    if _avail is None:
-        try:
-            from jax.experimental import pallas  # noqa: F401
-            from jax.experimental.pallas import tpu  # noqa: F401
-            _avail = True
-        except Exception:
-            _avail = False
-    return _avail
 
 
 def _backend() -> str:
     import jax
-    try:
-        return jax.default_backend()
-    except Exception:
-        return "cpu"
+    return jax.default_backend()
 
 
-def enabled() -> bool:
-    """Resolved selection state: explicit :func:`configure` wins, then the
-    ``DLT_PALLAS`` env var (``1``/``0``), then the automatic rule — on by
-    default on a TPU backend, off everywhere else."""
-    if not available():
+def enabled(family: Optional[str] = None) -> bool:
+    """Resolved selection state for ``family`` (``None``: for any family
+    at all): explicit :func:`configure` wins, then the ``DLT_PALLAS`` env
+    var (``1``/``0``) — both cover every family — then the automatic
+    rule: on a TPU backend the families of :data:`TPU_AUTO_FAMILIES`,
+    nothing anywhere else. :data:`INTERPRET_ONLY_FAMILIES` resolve false
+    whenever the kernel would be compiled."""
+    if family in INTERPRET_ONLY_FAMILIES and not interpret():
         return False
     with _lock:
         e = _state["enabled"]
@@ -117,21 +124,19 @@ def enabled() -> bool:
     env = os.environ.get("DLT_PALLAS")
     if env in ("0", "1"):
         return env == "1"
-    return _backend() == "tpu"
+    return ((family is None or family in TPU_AUTO_FAMILIES)
+            and _backend() == "tpu")
 
 
 def interpret() -> bool:
-    """Should ``pallas_call`` run in interpret mode? Explicit setting,
-    then ``DLT_PALLAS_INTERPRET``, then automatic: interpret everywhere
-    except a real TPU backend — force-enabling kernels on CPU (tests, CI)
-    gets the interpreter, never a Mosaic compile."""
+    """Should ``pallas_call`` run in interpret mode? An explicit setting,
+    else automatic: interpret everywhere except a TPU backend —
+    force-enabling kernels on CPU (tests, CI) gets the interpreter,
+    never a Mosaic compile, and a TPU never interprets unasked."""
     with _lock:
         i = _state["interpret"]
     if i is not None:
         return bool(i)
-    env = os.environ.get("DLT_PALLAS_INTERPRET")
-    if env in ("0", "1"):
-        return env == "1"
     return _backend() != "tpu"
 
 
@@ -164,10 +169,10 @@ def override(enabled: object = _UNSET, interpret: object = _UNSET):
 
 def candidate_flags() -> tuple:
     """The autotuner's searchable arms for the pallas knob: ``(False,
-    True)`` when kernels could actually serve (available AND either a TPU
-    backend or selection already forced on — the CPU-CI case), else ``()``
-    so the default search space stays exactly what it was."""
-    if available() and (_backend() == "tpu" or enabled()):
+    True)`` when kernels could actually serve (a TPU backend, or
+    selection already forced on — the CPU-CI case), else ``()`` so the
+    default search space stays exactly what it was."""
+    if _backend() == "tpu" or enabled():
         return (False, True)
     return ()
 
@@ -176,20 +181,20 @@ def selection_snapshot() -> Dict[str, str]:
     """family -> "pallas" | "xla" at this instant — what a training step
     traced right now would run. ``plan_memory`` stamps this into each
     ``MemoryPlan`` so a plan documents the kernel layer it assumed."""
-    impl = "pallas" if enabled() else "xla"
-    return {fam: impl for fam in FAMILIES}
+    return {fam: "pallas" if enabled(fam) else "xla" for fam in FAMILIES}
 
 
 # ------------------------------------------------------------- dispatch
 def take(family: str, supported: bool = True) -> bool:
     """One dispatch-site decision: returns True when the Pallas kernel
-    for ``family`` should serve this call (enabled AND the call shape is
-    ``supported``), recording ``kernel.pallas_<family>`` or
-    ``kernel.xla_<family>`` on the active CompileWatch either way. Called
-    at trace time for jitted bodies (the attention flash-kernel
-    precedent: one count per trace, not per step)."""
+    for ``family`` should serve this call (enabled for the family AND
+    the call shape is ``supported``), recording
+    ``kernel.pallas_<family>`` or ``kernel.xla_<family>`` on the active
+    CompileWatch either way. Called at trace time for jitted bodies (the
+    attention flash-kernel precedent: one count per trace, not per
+    step)."""
     from deeplearning4j_tpu.perf.compile_watch import bump_active
-    use = bool(supported) and enabled()
+    use = bool(supported) and enabled(family)
     bump_active(f"kernel.pallas_{family}" if use else f"kernel.xla_{family}")
     return use
 
@@ -197,17 +202,20 @@ def take(family: str, supported: bool = True) -> bool:
 class _KernelSelect:
     """Callable that picks the Pallas or XLA implementation PER CALL
     (selection config is re-read every dispatch, so a TuningRecord applied
-    after an index was built still takes effect) and exposes a combined
-    ``_cache_size`` so ``CompileWatch.wrap`` keeps exact compile counting
-    over both underlying jitted functions."""
+    after an index was built still takes effect; ``supported`` sees the
+    call's own arguments) and exposes a combined ``_cache_size`` so
+    ``CompileWatch.wrap`` keeps exact compile counting over both
+    underlying jitted functions."""
 
-    def __init__(self, family: str, pallas_fn: Callable, xla_fn: Callable):
+    def __init__(self, family: str, pallas_fn: Callable, xla_fn: Callable,
+                 supported: Callable[..., bool]):
         self.family = family
         self.pallas_fn = pallas_fn
         self.xla_fn = xla_fn
+        self.supported = supported
 
     def __call__(self, *args, **kwargs):
-        if take(self.family):
+        if take(self.family, self.supported(*args, **kwargs)):
             return self.pallas_fn(*args, **kwargs)
         return self.xla_fn(*args, **kwargs)
 
@@ -218,12 +226,14 @@ class _KernelSelect:
         return total
 
 
-def kernel_select(family: str, pallas_fn: Callable,
-                  xla_fn: Callable) -> _KernelSelect:
+def kernel_select(family: str, pallas_fn: Callable, xla_fn: Callable,
+                  supported: Callable[..., bool] = lambda *a, **k: True
+                  ) -> _KernelSelect:
     """The retrieval indexes' wiring point: ``compile_watch.wrap(
     kernel_select(...), key)`` dispatches to whichever implementation the
-    current selection resolves to, with per-dispatch kernel.* counters."""
+    current selection resolves to, with per-dispatch kernel.* counters.
+    ``supported(*call_args)`` is the kernel's shape predicate."""
     if family not in FAMILIES:
         raise KeyError(f"unknown pallas kernel family {family!r} "
                        f"(known: {sorted(FAMILIES)})")
-    return _KernelSelect(family, pallas_fn, xla_fn)
+    return _KernelSelect(family, pallas_fn, xla_fn, supported)

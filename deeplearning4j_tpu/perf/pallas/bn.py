@@ -22,9 +22,15 @@ through the full custom-VJP (forward AND backward).
 
 Grid: one program per channel tile (128 channels when the channel count
 is a multiple of 128, the whole axis otherwise); per-channel stats make
-tiles independent, so no cross-program reduction is needed. Row blocking
-(for activation sets whose rows overflow VMEM) is part of the TPU-round
-backlog — on this CPU container every kernel runs interpreted.
+tiles independent, so no cross-program reduction is needed. Every row of
+a tile is VMEM-resident at once, so the kernels only fit activation sets
+of a few thousand rows: of ResNet50's batch-128 stages the v5e compiler
+accepts the 7x7 one and refuses the rest (``(128,56,56,256)`` bf16:
+"RESOURCE_EXHAUSTED: ... input window allocation ... bf16[401408,128]",
+205 MB against 128 MiB of VMEM). :func:`supported` refuses what cannot
+fit, and the family is outside the automatic TPU rule
+(``perf.pallas.TPU_AUTO_FAMILIES``) until it is row-blocked (ROADMAP
+Speed 3).
 """
 
 from __future__ import annotations
@@ -41,11 +47,31 @@ from deeplearning4j_tpu.perf import pallas as _pk
 __all__ = ["supported", "bn_act_fwd", "bn_act_bwd"]
 
 
-def supported(z) -> bool:
+# Mosaic's default scoped-VMEM limit on a v5e. The estimate below charges
+# every streamed (rows, tile) window double-buffered plus the kernel's f32
+# temporaries, lanes padded to 128 — conservative against the compiler's
+# own accounting: the largest row count it accepts compiled for a
+# described v5e in every fwd/bwd x dtype x residual x tile combination
+# (tests/test_chip_compile.py keeps one shape each way).
+_VMEM_BUDGET = 16 * 1024 * 1024
+
+
+def _fits_vmem(z, streams: int, temps: int) -> bool:
+    c = z.shape[-1]
+    rows = z.size // c
+    per_elem = 2 * streams * z.dtype.itemsize + 4 * temps
+    return rows * max(_cblk(c), 128) * per_elem <= _VMEM_BUDGET
+
+
+def supported(z, has_res: bool = False, backward: bool = False) -> bool:
     """Shapes this kernel family handles: channels-last with at least one
-    leading axis and a non-empty channel axis (everything
-    ``fused_bn_act_train``'s callers produce)."""
-    return z.ndim >= 2 and z.shape[-1] > 0 and z.size > 0
+    leading axis and a non-empty channel axis, whose whole-row channel
+    tile (with the residual / cotangent streams riding along) fits VMEM."""
+    if not (z.ndim >= 2 and z.shape[-1] > 0 and z.size > 0):
+        return False
+    if backward:    # z, dout, dz (+ res, dpre)
+        return _fits_vmem(z, 5 if has_res else 3, temps=4)
+    return _fits_vmem(z, 3 if has_res else 2, temps=2)
 
 
 def _cblk(c: int) -> int:
@@ -150,9 +176,12 @@ def _bwd_kernel(act, eps, n_rows, has_res, *refs):
     if has_res:
         pre = pre + r_ref[...]
     # activation backward through the SAME implementation the forward
-    # used, on the recomputed pre-image (no activation-sized saves)
-    _, act_vjp = jax.vjp(act, pre)
-    dpre = act_vjp(dout_ref[...])[0]
+    # used, on the recomputed pre-image (no activation-sized saves). In
+    # f32 for bf16/f16: the v5e has no low-precision vector compare
+    # (relu's vjp: "Target does not support this comparison")
+    cdt = jnp.float32 if _low_precision(z.dtype) else z.dtype
+    _, act_vjp = jax.vjp(act, pre.astype(cdt))
+    dpre = act_vjp(dout_ref[...].astype(cdt))[0].astype(z.dtype)
     zf = z.astype(sdt)
     xhat = (zf - mean) * inv
     dpre32 = dpre.astype(sdt)

@@ -1,7 +1,7 @@
 """HBM planner: fit a training configuration under a stated memory budget.
 
-BENCH_r05 pins ResNet50 bf16 at ~5% above the measured BN-train HBM
-bandwidth floor — further raw-speed wins come from *planning* memory, not
+tools/PROFILE_r5.md (the last chip profile on record) pins ResNet50 bf16
+at ~5% above the measured BN-train HBM bandwidth floor — further raw-speed wins come from *planning* memory, not
 from more kernel tweaks. This module closes the measure→plan→verify loop
 over the knobs the repo already has:
 

@@ -24,7 +24,6 @@ from __future__ import annotations
 from collections import deque
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.datasets.dataset import DataSet, MultiDataSet
@@ -67,11 +66,10 @@ class DevicePrefetchIterator(DataSetIterator):
     def _place_array(self, a):
         if a is None:
             return None
-        arr = jnp.asarray(a)
         if self._mesh is not None:
-            from deeplearning4j_tpu.parallel.mesh import data_sharding
-            return jax.device_put(arr, data_sharding(self._mesh, arr.ndim))
-        return jax.device_put(arr)
+            from deeplearning4j_tpu.parallel.mesh import shard_batch
+            return shard_batch(self._mesh, a)
+        return jnp.asarray(a)
 
     def _place(self, ds):
         if self._place_fn is not None:

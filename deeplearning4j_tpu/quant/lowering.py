@@ -112,8 +112,9 @@ def _dense_int4_acc(xq, wq_packed, n_in: int):
     resolves to it (2-D activations), the jnp in-program unpack (which
     XLA fuses into the dot operand) otherwise."""
     from deeplearning4j_tpu.perf import pallas as _pk
-    if _pk.take("int4_dot", xq.ndim == 2):
-        from deeplearning4j_tpu.perf.pallas import adc as _pk_adc
+    from deeplearning4j_tpu.perf.pallas import adc as _pk_adc
+    if _pk.take("int4_dot", xq.ndim == 2 and _pk_adc.int4_supported(
+            xq.shape[0], *wq_packed.shape)):
         return _pk_adc.int4_matmul(xq, wq_packed, n_in)
     w8 = unpack_nibbles(wq_packed, n_in)                  # (n_out, n_in)
     return lax.dot_general(xq, w8, (((xq.ndim - 1,), (1,)), ((), ())),

@@ -698,7 +698,8 @@ class BruteForceIndex(_DeviceIndex):
             from deeplearning4j_tpu.perf.pallas import adc as _pk_adc
             self._score = self.compile_watch.wrap(
                 _pk.kernel_select("int4_dot", _pk_adc.score_brute_int4,
-                                  _score_brute_int4),
+                                  _score_brute_int4,
+                                  _pk_adc.brute_int4_supported),
                 "retrieval.brute_int4")
         elif self.int8:
             self._score = self.compile_watch.wrap(_score_brute_int8,

@@ -273,7 +273,8 @@ class PQIndex(_DeviceIndex):
         from deeplearning4j_tpu.perf import pallas as _pk
         from deeplearning4j_tpu.perf.pallas import adc as _pk_adc
         self._score = self.compile_watch.wrap(
-            _pk.kernel_select("adc_pq", _pk_adc.score_pq, _score_pq),
+            _pk.kernel_select("adc_pq", _pk_adc.score_pq, _score_pq,
+                              _pk_adc.pq_supported),
             "retrieval.pq")
 
     def _candidates(self) -> int:

@@ -829,7 +829,8 @@ class ModelServer:
         if compile_cache_dir is not None:
             from deeplearning4j_tpu.perf.compile_cache import \
                 enable_compilation_cache
-            enable_compilation_cache(compile_cache_dir)
+            # the directory in use: JAX_COMPILATION_CACHE_DIR wins
+            compile_cache_dir = enable_compilation_cache(compile_cache_dir)
         self.compile_cache_dir = compile_cache_dir
         self.port = port
         self.bind_address = bind_address

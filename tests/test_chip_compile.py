@@ -1,0 +1,163 @@
+"""Ask the v5e's compiler, without a chip, for every kernel the TPU runs.
+
+The TPU compiler is installed here and compiles for a chip that is
+described and not attached (``jax.experimental.topologies``). Interpret
+mode cannot show what it shows: every PR-16 kernel had passed its
+interpret-mode parity tests and four of five families were refused here
+(VMEM overflow, a bf16 vector compare, an in-kernel gather, a
+rank-changing reshape). So each kernel the automatic TPU rule selects
+(``perf.pallas.TPU_AUTO_FAMILIES``), plus flash attention and the
+Word2Vec scatter, is compiled ``interpret=False`` at one main-path shape;
+the BN family — outside the automatic rule — is compiled where its
+``supported()`` says it fits, and must refuse what does not.
+
+A compile that passes is not a chip run: nothing executes. The chip run is
+``chip_smoke.py``'s ``kernels`` phase. The name sorts early so the tier-1
+time cap cannot cut this file.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deeplearning4j_tpu.perf import pallas as pk
+from deeplearning4j_tpu.perf.pallas import adc, bn
+
+F32, BF16, I8, U8, I32 = (jnp.float32, jnp.bfloat16, jnp.int8, jnp.uint8,
+                          jnp.int32)
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One described v5e chip; the module is skipped where the topology
+    cannot be described. The persistent compile cache is off around these
+    compiles: an executable for a described chip can be written to it but
+    not read back without the chip."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def tpu_backend(monkeypatch):
+    """The code under test asks ``jax.default_backend()`` and would take
+    its CPU branch here; the test steers it, not an option of the
+    program. With this the AUTOMATIC rule resolves as it does on a chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert not pk.interpret()
+
+
+def _pq(S):
+    q, cb, codes = S((64, 64), F32), S((8, 256, 8), F32), S((1 << 20, 8), U8)
+    assert pk.take("adc_pq", adc.pq_supported(q, cb, codes))
+    return lambda q, cb, codes: adc.score_pq(q, cb, codes, k=16), (q, cb,
+                                                                   codes)
+
+
+def _int4_table(S):
+    n = 1 << 20
+    args = (S((64, 64), F32), S((n, 32), I8), S((n,), F32), S((n,), F32))
+    assert pk.take("int4_dot", adc.brute_int4_supported(*args))
+    return (lambda q, p, vn, sv: adc.score_brute_int4(
+        q, p, vn, sv, k=16, metric="euclidean")), args
+
+
+def _int4_weights(S):
+    # the ResNet50 head (2048 -> 1000) at the serving ladder's top rung,
+    # through the int4-weight call site itself
+    from deeplearning4j_tpu.quant.lowering import _dense_int4_acc
+    assert adc.int4_supported(32, 1000, 1024)
+    return (lambda xq, wq: _dense_int4_acc(xq, wq, 2048)), (
+        S((32, 2048), I8), S((1000, 1024), I8))
+
+
+def _flash(S):
+    from deeplearning4j_tpu.parallel.ring_attention import \
+        flash_self_attention
+    qkv = S((4, 8, 1024, 64), BF16)
+    return (lambda q, k, v: flash_self_attention(q, k, v, causal=True)), (
+        qkv, qkv, qkv)
+
+
+def _scatter(S):
+    from deeplearning4j_tpu.nlp.pallas_scatter import scatter_add_pallas
+    return scatter_add_pallas, (S((20000, 100), F32), S((8192,), I32),
+                                S((8192, 100), F32))
+
+
+def _bn_fwd(S):
+    # ResNet50 batch 128, the one stage whose rows fit: 7x7
+    z = S((128, 7, 7, 2048), BF16)
+    assert bn.supported(z, has_res=True)
+    return (lambda z, g, b, r: bn.bn_act_fwd("relu", 1e-5, z, g, b, r)), (
+        z, S((2048,), F32), S((2048,), F32), z)
+
+
+def _bn_bwd(S):
+    z, c = S((64, 7, 7, 512), BF16), S((512,), F32)
+    assert bn.supported(z, backward=True)
+    return (lambda z, g, b, m, i, d: bn.bn_act_bwd(
+        "relu", 1e-5, z, g, b, None, m, i, d)), (z, c, c, c, c, z)
+
+
+# (builder, family the automatic TPU rule must select for it | None)
+AUTO_CASES = [(_pq, "adc_pq"), (_int4_table, "int4_dot"),
+              (_int4_weights, "int4_dot"), (_flash, None), (_scatter, None)]
+EXPLICIT_CASES = [_bn_fwd, _bn_bwd]
+
+
+def _compiles_with_kernel(fn, args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+
+
+@pytest.mark.parametrize("build", [c[0] for c in AUTO_CASES]
+                         + EXPLICIT_CASES, ids=lambda f: f.__name__[1:])
+def test_v5e_compiler_accepts(build, v5e, tpu_backend):
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    if build in EXPLICIT_CASES:  # outside the automatic rule
+        assert not pk.enabled("bn_act") and not pk.enabled("bn_act_bwd")
+        with pk.override(enabled=True):
+            _compiles_with_kernel(*build(S))
+    else:
+        _compiles_with_kernel(*build(S))
+
+
+def test_every_auto_family_has_a_case(tpu_backend):
+    selected = {f for f, impl in pk.selection_snapshot().items()
+                if impl == "pallas"}
+    assert selected == set(pk.TPU_AUTO_FAMILIES)
+    assert selected == {fam for _, fam in AUTO_CASES if fam}
+    # a family with no compilable kernel stays off a TPU even when a
+    # TuningRecord force-enables the layer process-wide
+    with pk.override(enabled=True):
+        assert pk.enabled("bn_act") and not pk.enabled("adc_ivf_pq")
+        with pk.override(interpret=True):
+            assert pk.enabled("adc_ivf_pq")
+
+
+def test_bn_supported_refuses_what_cannot_fit():
+    # ResNet50 batch 128 stage 2: "input window allocation ...
+    # bf16[401408,128]", 205 MB against 128 MiB of VMEM
+    z = jax.ShapeDtypeStruct((128, 56, 56, 256), BF16)
+    assert not bn.supported(z)
+    assert not bn.supported(z, backward=True)
+    with pk.override(enabled=True):
+        assert not pk.take("bn_act", bn.supported(z))

@@ -460,6 +460,26 @@ def test_graph_fit_prefetch_bitwise_identical(devices):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_parallel_inference_serves_graph_by_row(devices):
+    """A ComputationGraph answers with a LIST of outputs; serving pads,
+    coalesces and splits by row, so a padded or coalesced request must
+    still get exactly its own rows (found by chip_smoke.py: ResNet50
+    behind ModelServer answered with the bucket's padding rows)."""
+    net = _graph()
+    x = np.random.default_rng(3).random((11, 4), np.float32)
+    want = net.output_single(x)
+    pi = ParallelInference(net, mesh=make_mesh(dp=1), batch_limit=4,
+                           queue_timeout_ms=20)
+    try:
+        np.testing.assert_allclose(pi.output(x[:3]), want[:3], rtol=1e-6)
+        pending = [pi.submit(x[lo:hi]) for lo, hi in ((0, 3), (3, 4),
+                                                      (4, 11))]
+        got = np.concatenate([p.get() for p in pending])
+        np.testing.assert_allclose(got, want, rtol=1e-6)
+    finally:
+        pi.shutdown()
+
+
 def test_pad_multi_dataset_masks(devices):
     """pad_multi_dataset fabricates a per-output labels mask with the same
     rules as pad_dataset, and the bucketed graph fit consumes MultiDataSets
@@ -523,6 +543,7 @@ def test_bench_quick_smoke():
     lines = [json.loads(l) for l in proc.stdout.splitlines() if l.strip()]
     by_metric = {l["metric"]: l for l in lines}
     assert not any("error" in l for l in lines), lines
+    assert all(l["platform"] == "cpu" and l["device_kind"] for l in lines)
     assert "lenet_mnist_train_imgs_per_sec_per_chip_plain_fit" in by_metric
     serving = by_metric["parallel_inference_serving_reqs_per_sec"]
     assert serving["value"] > 0
@@ -532,3 +553,85 @@ def test_bench_quick_smoke():
     # the shape-stability contract: traffic after warmup compiles nothing
     assert serving["compiles"] == serving["compiles_after_warmup"], serving
     assert serving["unwarmed_dispatches"] == 0
+
+
+# ------------------------------------------- where a run leaves its traces
+@pytest.mark.parametrize("env, arg, want", [
+    ("/from/env", None, "/from/env"),           # placed from outside
+    ("/from/env", "/from/arg", "/from/env"),    # ...and nothing overrides it
+    (None, "/from/arg", "/from/arg"),
+    (None, None, "<checkout>/.jax_cache"),      # fixed: never tmp/pid/time
+])
+def test_compile_cache_dir_resolution(monkeypatch, env, arg, want):
+    from deeplearning4j_tpu.perf import compile_cache
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.resolve_cache_dir(arg) == want.replace(
+        "<checkout>", repo)
+
+
+@pytest.fixture
+def bench_main(monkeypatch, capsys):
+    """bench.main() in-process over two stand-in benches (one emits, one
+    raises), with the process-global compile cache left alone. Returns
+    (exit code, parsed stdout lines)."""
+    import importlib.util
+
+    from deeplearning4j_tpu.perf import compile_cache
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "_bench_under_test", os.path.join(repo, "bench.py"))
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    placed = []
+    monkeypatch.setattr(compile_cache, "enable_compilation_cache",
+                        lambda *a, **k: placed.append(a))
+
+    def boom():
+        raise RuntimeError("bench exploded")
+    monkeypatch.setattr(bench, "bench_lenet",
+                        lambda: bench.emit("ok_metric", 1.0, "u", "lenet"))
+    monkeypatch.setattr(bench, "bench_word2vec", boom)
+
+    def run(only):
+        monkeypatch.setenv("BENCH_ONLY", only)
+        try:
+            bench.main()
+            code = 0
+        except SystemExit as e:
+            code = e.code
+        assert placed, "bench.main() never placed the compile cache"
+        return code, [json.loads(l) for l in
+                      capsys.readouterr().out.splitlines() if l.strip()]
+    return run
+
+
+@pytest.mark.parametrize("only, fails", [("lenet", False),
+                                         ("lenet,word2vec", True)])
+def test_bench_exit_code_and_device_fields(bench_main, only, fails):
+    code, lines = bench_main(only)
+    assert bool(code) == fails, (code, lines)
+    assert any("error" in l for l in lines) == fails
+    dev = jax.devices()[0]
+    for l in lines:  # result and error lines alike say where they ran
+        assert (l["platform"], l["device_kind"]) == (dev.platform,
+                                                     dev.device_kind), l
+        assert "mfu" not in l   # no peak is known for a CPU
+
+
+def test_chip_smoke_refuses_without_a_chip():
+    """chip_smoke.py's only tier-1 test: with no accelerator it fails in
+    its ``device`` phase, exits non-zero and prints no result line."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run([sys.executable, "chip_smoke.py"], cwd=repo,
+                          env=env, capture_output=True, text=True,
+                          timeout=120)
+    assert proc.returncode != 0, proc.stdout[-2000:]
+    lines = [json.loads(l) for l in proc.stdout.splitlines() if l.strip()]
+    assert [(l.get("phase"), l["ok"]) for l in lines] == [("device", False)]
+    assert '"ok": true' not in proc.stdout

@@ -235,7 +235,6 @@ def test_int4_weight_serving_accuracy_gate_and_kernel_parity():
 # ------------------------------------------------ selection + counters
 class TestSelectionAndCounters:
     def test_auto_off_on_cpu_and_env_configure_precedence(self):
-        assert pk.available()
         assert not pk.enabled()  # CPU backend, no env/configure: auto-off
         assert pk.interpret()    # ...and interpret mode off-TPU
         try:

@@ -133,12 +133,36 @@ def cmd_router(args) -> int:
 
 
 # ----------------------------------------------------------------------- up
+def _local_tpu_chips() -> int:
+    """TPU chips on this host, counted from their device files: this
+    process must never initialise a JAX backend (it would take the chips
+    its replicas need). 0 on a CPU-only host."""
+    import glob
+    return len(glob.glob("/dev/accel[0-9]*")
+               or glob.glob("/dev/vfio/[0-9]*"))
+
+
 def cmd_up(args) -> int:
     from deeplearning4j_tpu.fleet import FleetRouter, FleetView
 
+    # a chip belongs to one process: each replica gets a chip of its own
+    # (libtpu's per-process chip binding), and more replicas than chips
+    # is refused here rather than left to hang at the second start-up
+    chips = _local_tpu_chips()
+    if chips and args.replicas > chips:
+        print(f"error: --replicas {args.replicas} needs {args.replicas} "
+              f"TPU chips and this host has {chips}: a chip belongs to "
+              "one process (a second replica would hang waiting for it)",
+              file=sys.stderr)
+        return 2
     here = os.path.abspath(__file__)
     procs = []
     for i in range(args.replicas):
+        env = dict(os.environ)
+        if chips:
+            env.update(TPU_VISIBLE_CHIPS=str(i),
+                       TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+                       TPU_PROCESS_BOUNDS="1,1,1")
         cmd = [sys.executable, here, "replica", "--store", args.store,
                "--replica-id", f"rep{i}", "--ttl-s", str(args.ttl_s),
                "--drain-timeout-s", str(args.drain_timeout_s)]
@@ -146,7 +170,7 @@ def cmd_up(args) -> int:
             cmd += ["--model", f"{name}={ckpt}"]
         if args.poll_secs is not None:
             cmd += ["--poll-secs", str(args.poll_secs)]
-        procs.append(subprocess.Popen(cmd))
+        procs.append(subprocess.Popen(cmd, env=env))
     view = FleetView(args.store, ttl_s=args.ttl_s)
     router = FleetRouter(view, port=args.router_port,
                          bind_address=args.bind).start()
