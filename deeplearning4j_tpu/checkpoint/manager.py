@@ -273,17 +273,23 @@ class CheckpointManager:
         consumed so far in the CURRENT epoch — what exact-step resume skips."""
         if self._fenced_model is not None and model is not self._fenced_model:
             return  # stale thread: must not touch triggers or resume state
-        if batch_in_epoch is not None:
-            self._batch_in_epoch = int(batch_in_epoch)
-        n = self.save_every_n_steps
-        # threshold, not exact modulo: tbptt batches advance iteration by
-        # SEVERAL windows per step_end, so `iteration % n == 0` would fire
-        # only at lcm(windows, n) — or never — instead of every ~n steps
-        due = bool(n) and (model.iteration - self._last_save_step) >= n
-        if not due and self.save_every_secs is not None:
-            due = self._secs_trigger_due()
-        if due:
-            self.save(model)
+        from deeplearning4j_tpu.obs.trace import get_tracer
+        # the training thread's half of a checkpoint, in the fit loops'
+        # span tree (obs/trace.py); a save that triggers adds the
+        # checkpoint.snapshot child
+        with get_tracer().span("checkpoint.step_end"):
+            if batch_in_epoch is not None:
+                self._batch_in_epoch = int(batch_in_epoch)
+            n = self.save_every_n_steps
+            # threshold, not exact modulo: tbptt batches advance iteration
+            # by SEVERAL windows per step_end, so `iteration % n == 0`
+            # would fire only at lcm(windows, n) — or never — instead of
+            # every ~n steps
+            due = bool(n) and (model.iteration - self._last_save_step) >= n
+            if not due and self.save_every_secs is not None:
+                due = self._secs_trigger_due()
+            if due:
+                self.save(model)
 
     def epoch_end(self, model):
         """Epoch boundary: resume state resets to batch 0 of the (already
@@ -340,7 +346,7 @@ class CheckpointManager:
             self._barrier("checkpoint save")
             return None
         from deeplearning4j_tpu.utils.serialization import snapshot_training_state
-        snap = snapshot_training_state(model)
+        snap = self._snapshot(snapshot_training_state, model)
         if not self.save_updater:
             snap["opt_state"] = None
         self._seq += 1
@@ -363,6 +369,19 @@ class CheckpointManager:
         if wait:
             self.flush()
         return filename
+
+    @staticmethod
+    def _snapshot(take, model) -> dict:
+        """``take(model)`` (the device-to-host copy a save makes on the
+        calling thread) under a ``checkpoint.snapshot`` span that says how
+        many bytes it copied."""
+        import jax
+        from deeplearning4j_tpu.obs.trace import get_tracer
+        with get_tracer().span("checkpoint.snapshot") as sp:
+            snap = take(model)
+            sp.set(bytes=sum(int(getattr(leaf, "nbytes", 0))
+                             for leaf in jax.tree_util.tree_leaves(snap)))
+        return snap
 
     # ------------------------------------------------------ saver protocol
     # (duck-typed EarlyStoppingConfiguration.model_saver backend)
@@ -423,7 +442,7 @@ class CheckpointManager:
         pi, pc = jax.process_index(), jax.process_count()
         t0 = time.perf_counter()
         self._seq += 1  # every host: shard names must agree fleet-wide
-        snap = shd.shard_snapshot(model)
+        snap = self._snapshot(shd.shard_snapshot, model)
         if not self.save_updater:
             snap["updaterState"] = None
         extra = {

@@ -184,24 +184,31 @@ def apply_constraints(layer, params):
     return params
 
 
-def apply_layer(layer, params, state, x, *, train, rng, mask, extra=None):
+def apply_layer(layer, params, state, x, *, train, rng, mask, name,
+                extra=None):
     """The networks' single entry into ``layer.apply``: lowers the layer
     through ``jax.checkpoint`` when its ``remat=`` knob is set (policy names
     in perf/fusion.py), so the backward pass recomputes instead of saving
     what the policy excludes. ``extra`` carries optional additional traced
-    inputs (the fused residual-add input in ComputationGraph)."""
+    inputs (the fused residual-add input in ComputationGraph). ``name`` is
+    the layer's name in its network (a graph's vertex name, an MLN's
+    index): every operation of the layer, forward and backward, carries
+    ``<LayerClass>:<name>`` in its ``op_name``, so a device trace can be
+    read by layer kind whatever the compiler calls its fusions."""
     extra = extra or {}
-    if getattr(layer, "remat", None):
-        from deeplearning4j_tpu.perf.fusion import remat_policy
-        policy = remat_policy(layer.remat)
+    with jax.named_scope(f"{type(layer).__name__}:{name}"):
+        if getattr(layer, "remat", None):
+            from deeplearning4j_tpu.perf.fusion import remat_policy
+            policy = remat_policy(layer.remat)
 
-        def run(p, s, xx, kk, mm, ee):
-            return layer.apply(p, s, xx, train=train, rng=kk, mask=mm, **ee)
+            def run(p, s, xx, kk, mm, ee):
+                return layer.apply(p, s, xx, train=train, rng=kk, mask=mm,
+                                   **ee)
 
-        return jax.checkpoint(run, policy=policy)(params, state, x, rng,
-                                                  mask, extra)
-    return layer.apply(params, state, x, train=train, rng=rng, mask=mask,
-                       **extra)
+            return jax.checkpoint(run, policy=policy)(params, state, x, rng,
+                                                      mask, extra)
+        return layer.apply(params, state, x, train=train, rng=rng, mask=mask,
+                           **extra)
 
 
 def noisy_params(layer, params, rng, train: bool):

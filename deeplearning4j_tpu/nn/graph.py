@@ -196,7 +196,7 @@ class ComputationGraph:
                     # layer's remat= knob is set (perf/fusion.py policies)
                     out, st = apply_layer(obj, p_v, state[name], xs[0],
                                           train=train, rng=k, mask=in_mask,
-                                          extra=extra)
+                                          name=name, extra=extra)
                     new_state[name] = st
                 out_kind = obj.output_type(self.vertex_input_types[name][0]).kind
                 mask_of[name] = in_mask if out_kind in ("rnn", "cnn1d") else None
@@ -304,8 +304,9 @@ class ComputationGraph:
         value_and_grad = jax.value_and_grad(self._loss_fn_tbptt, has_aux=True)
         comp = self.grad_compression
         if comp is not None:
-            def step_c(params, state, opt_state, cstate, carries, rng,
-                       inputs, labels, fmasks, lmasks):
+            def tbptt_step_compressed(params, state, opt_state, cstate,
+                                      carries, rng, inputs, labels, fmasks,
+                                      lmasks):
                 (loss, (new_state, new_carries)), grads = value_and_grad(
                     params, state, carries, inputs, labels, rng, fmasks,
                     lmasks)
@@ -315,16 +316,17 @@ class ComputationGraph:
                 return (new_params, new_state, new_opt, cstate, new_carries,
                         loss)
 
-            return jax.jit(step_c, donate_argnums=(0, 1, 2, 3, 4))
+            return jax.jit(tbptt_step_compressed,
+                           donate_argnums=(0, 1, 2, 3, 4))
 
-        def step(params, state, opt_state, carries, rng, inputs, labels,
-                 fmasks, lmasks):
+        def tbptt_step(params, state, opt_state, carries, rng, inputs, labels,
+                       fmasks, lmasks):
             (loss, (new_state, new_carries)), grads = value_and_grad(
                 params, state, carries, inputs, labels, rng, fmasks, lmasks)
             new_params, new_opt = self._apply_updates(params, grads, opt_state)
             return new_params, new_state, new_opt, new_carries, loss
 
-        return jax.jit(step, donate_argnums=(0, 1, 2, 3))
+        return jax.jit(tbptt_step, donate_argnums=(0, 1, 2, 3))
 
     def _time_sliceable(self, i, x):
         """Whether graph input i carries a time axis to window over."""
@@ -339,6 +341,8 @@ class ComputationGraph:
         """Chunked fit over time windows (reference ComputationGraph.java:1158
         doTruncatedBPTT): one optimizer update per window, RNN state carried
         but gradients truncated at window boundaries."""
+        from deeplearning4j_tpu.obs.trace import get_tracer
+        tracer = get_tracer()
         step = self._get_jitted("tbptt")
         T = max(x.shape[1] for i, x in enumerate(inputs)
                 if self._time_sliceable(i, x))
@@ -354,26 +358,25 @@ class ComputationGraph:
                    [None if m is None else m[:, s:e] for m in fmasks])
             lms = (None if lmasks is None else
                    [None if m is None else m[:, s:e] for m in lmasks])
-            self._rng, k = jax.random.split(self._rng)
-            if self.grad_compression is not None:
-                if self.compress_state is None:
-                    from deeplearning4j_tpu.parallel.compress import (
-                        ensure_compress_state)
-                    ensure_compress_state(self)
-                (self.params, self.state, self.opt_state,
-                 self.compress_state, carries, loss) = step(
-                    self.params, self.state, self.opt_state,
-                    self.compress_state, carries, k, xs, ys, fms, lms)
-            else:
-                self.params, self.state, self.opt_state, carries, loss = step(
-                    self.params, self.state, self.opt_state, carries, k,
-                    xs, ys, fms, lms)
-            self._score = loss
-            self.last_batch_size = int(inputs[0].shape[0])
-            # one optimizer update per window == one iteration (MLN parity)
-            for listener in self.listeners:
-                listener.iteration_done(self, self.iteration, self.epoch)
-            self.iteration += 1
+            # one optimizer update per window == one iteration (MLN
+            # parity): each window's spans carry its own step
+            with tracer.span("train.dispatch", step=self.iteration,
+                             program="tbptt"):
+                self._rng, k = jax.random.split(self._rng)
+                if self.grad_compression is not None:
+                    if self.compress_state is None:
+                        from deeplearning4j_tpu.parallel.compress import (
+                            ensure_compress_state)
+                        ensure_compress_state(self)
+                    (self.params, self.state, self.opt_state,
+                     self.compress_state, carries, loss) = step(
+                        self.params, self.state, self.opt_state,
+                        self.compress_state, carries, k, xs, ys, fms, lms)
+                else:
+                    (self.params, self.state, self.opt_state, carries,
+                     loss) = step(self.params, self.state, self.opt_state,
+                                  carries, k, xs, ys, fms, lms)
+            self._finish_step(tracer, loss, int(inputs[0].shape[0]))
 
     def rnn_time_step(self, *inputs) -> List[np.ndarray]:
         """Stateful step-by-step inference for recurrent graphs (reference
@@ -454,8 +457,8 @@ class ComputationGraph:
         if comp is not None:
             # compressed collectives (parallel/compress.py): encode→decode
             # + error-feedback residual update inside the compiled step
-            def step_c(params, state, opt_state, cstate, rng, inputs,
-                       labels, fmasks, lmasks):
+            def train_step_compressed(params, state, opt_state, cstate, rng,
+                                      inputs, labels, fmasks, lmasks):
                 (loss, new_state), grads = value_and_grad(
                     params, state, inputs, labels, rng, fmasks, lmasks)
                 grads, cstate = comp.apply(grads, cstate)
@@ -463,15 +466,18 @@ class ComputationGraph:
                                                           opt_state)
                 return new_params, new_state, new_opt, cstate, loss
 
-            return jax.jit(step_c, donate_argnums=(0, 1, 2, 3))
+            return jax.jit(train_step_compressed, donate_argnums=(0, 1, 2, 3))
 
-        def step(params, state, opt_state, rng, inputs, labels, fmasks, lmasks):
+        # the function's name is the program's in a profiler trace
+        # (jit_train_step): keep it stable
+        def train_step(params, state, opt_state, rng, inputs, labels, fmasks,
+                       lmasks):
             (loss, new_state), grads = value_and_grad(
                 params, state, inputs, labels, rng, fmasks, lmasks)
             new_params, new_opt = self._apply_updates(params, grads, opt_state)
             return new_params, new_state, new_opt, loss
 
-        return jax.jit(step, donate_argnums=(0, 1, 2))
+        return jax.jit(train_step, donate_argnums=(0, 1, 2))
 
     def set_augmentation(self, augmentation) -> "ComputationGraph":
         """Enable on-device augmentation (datasets/augment.py) for the
@@ -492,22 +498,22 @@ class ComputationGraph:
             elif kind == "tbptt":
                 fn = self._make_tbptt_step()
             elif kind == "rnn_step":
-                def rnn_fn(params, state, carries, xs):
+                def rnn_step(params, state, carries, xs):
                     acts, _, _, _, nc = self._forward(
                         params, state, xs, False, None, None, carries)
                     return [acts[n] for n in self.conf.network_outputs], nc
-                fn = jax.jit(rnn_fn)
+                fn = jax.jit(rnn_step)
             elif kind == "output":
-                def out_fn(params, state, inputs, fmasks):
+                def output(params, state, inputs, fmasks):
                     acts, _, _, _ = self._forward(params, state, inputs, False,
                                                   None, fmasks)
                     return [acts[n] for n in self.conf.network_outputs]
-                fn = jax.jit(out_fn)
+                fn = jax.jit(output)
             elif kind == "score":
-                def score_fn(params, state, inputs, labels, fmasks, lmasks):
+                def score(params, state, inputs, labels, fmasks, lmasks):
                     return self._loss_fn(params, state, inputs, labels, None,
                                          fmasks, lmasks)[0]
-                fn = jax.jit(score_fn)
+                fn = jax.jit(score)
             else:
                 raise KeyError(kind)
             fn = self.compile_watch.wrap(fn, kind)
@@ -568,23 +574,19 @@ class ComputationGraph:
             stream = skip_consumed_batches(data, skip)
             if prefetch_cls is not None:
                 stream = prefetch_cls(stream)
-            # data-wait / host / device phase spans: same breakdown as
-            # multilayer.py fit (host-side only; see obs/trace.py)
-            stream = tracer.wrap_iter(stream, "train.data_wait")
+            # the fit loops' span tree, as in multilayer.py fit (host-side
+            # only, nothing waits for the device; see obs/trace.py)
+            stream = tracer.wrap_iter(stream, "train.data_wait",
+                                      turn="train.iteration",
+                                      step=lambda: self.iteration)
             bi = skip
             for ds in stream:
                 bi += 1
-                mds = MultiDataSet.from_dataset(ds) if isinstance(ds, DataSet) else ds
-                if tracer.enabled:
-                    with tracer.span("train.step_host", step=self.iteration):
-                        self._fit_batch(step, mds)
-                    with tracer.span("train.step_device",
-                                     step=self.iteration - 1):
-                        jax.block_until_ready(self._score)
-                else:
-                    self._fit_batch(step, mds)
-                if checkpoint_manager is not None:
-                    checkpoint_manager.step_end(self, batch_in_epoch=bi)
+                with tracer.span("train.step_host", step=self.iteration,
+                                 items=ds.num_examples()):
+                    self._fit_batch(step, ds)
+                    if checkpoint_manager is not None:
+                        checkpoint_manager.step_end(self, batch_in_epoch=bi)
             skip = 0
             for listener in self.listeners:
                 listener.on_epoch_end(self)
@@ -593,38 +595,68 @@ class ComputationGraph:
                 checkpoint_manager.epoch_end(self)
         return self
 
-    def _fit_batch(self, step, mds: MultiDataSet):
-        inputs = [jnp.asarray(f) for f in mds.features]
-        labels = [jnp.asarray(l) for l in mds.labels]
-        fmasks = (None if mds.features_masks is None else
-                  [None if m is None else jnp.asarray(m) for m in mds.features_masks])
-        lmasks = (None if mds.labels_masks is None else
-                  [None if m is None else jnp.asarray(m) for m in mds.labels_masks])
+    def _fit_batch(self, step, ds):
+        """One optimizer step on one DataSet or MultiDataSet, under the
+        inner spans of the fit loops' tree (obs/trace.py): opened here,
+        where the work is, so that every caller (``fit``,
+        ``ParallelWrapper.fit_batch``) gets them once, inside its own
+        ``train.step_host``."""
+        from deeplearning4j_tpu.obs.trace import get_tracer
+        tracer = get_tracer()
+        at = self.iteration
+        with tracer.span("train.stage", step=at):
+            mds = (MultiDataSet.from_dataset(ds) if isinstance(ds, DataSet)
+                   else ds)
+            inputs = [jnp.asarray(f) for f in mds.features]
+            labels = [jnp.asarray(l) for l in mds.labels]
+            fmasks = (None if mds.features_masks is None else
+                      [None if m is None else jnp.asarray(m)
+                       for m in mds.features_masks])
+            lmasks = (None if mds.labels_masks is None else
+                      [None if m is None else jnp.asarray(m)
+                       for m in mds.labels_masks])
         if self.conf.backprop_type == "tbptt":
             sliceable = [x.shape[1] for i, x in enumerate(inputs)
                          if self._time_sliceable(i, x)]
             if sliceable and max(sliceable) > self.conf.tbptt_fwd_length:
                 self._fit_tbptt(inputs, labels, fmasks, lmasks)
                 return
-        self._rng, k = jax.random.split(self._rng)
-        if self.grad_compression is not None:
-            if self.compress_state is None:
-                from deeplearning4j_tpu.parallel.compress import (
-                    ensure_compress_state)
-                ensure_compress_state(self)
-            (self.params, self.state, self.opt_state, self.compress_state,
-             loss) = step(self.params, self.state, self.opt_state,
-                          self.compress_state, k, inputs, labels, fmasks,
-                          lmasks)
-        else:
-            self.params, self.state, self.opt_state, loss = step(
-                self.params, self.state, self.opt_state, k, inputs, labels, fmasks, lmasks)
-        self._score = loss
-        self.last_batch_size = int(inputs[0].shape[0])
+        with tracer.span("train.dispatch", step=at, program="train"):
+            self._rng, k = jax.random.split(self._rng)
+            if self.grad_compression is not None:
+                if self.compress_state is None:
+                    from deeplearning4j_tpu.parallel.compress import (
+                        ensure_compress_state)
+                    ensure_compress_state(self)
+                (self.params, self.state, self.opt_state,
+                 self.compress_state, loss) = step(
+                    self.params, self.state, self.opt_state,
+                    self.compress_state, k, inputs, labels, fmasks, lmasks)
+            else:
+                self.params, self.state, self.opt_state, loss = step(
+                    self.params, self.state, self.opt_state, k, inputs,
+                    labels, fmasks, lmasks)
         # first sample per input only (see multilayer.py note)
-        self._last_features = [f[:1] for f in inputs]
-        for listener in self.listeners:
-            listener.iteration_done(self, self.iteration, self.epoch)
+        self._finish_step(tracer, loss, int(inputs[0].shape[0]),
+                          lambda: [f[:1] for f in inputs])
+
+    def _finish_step(self, tracer, loss, batch: int, sample=None):
+        """What follows a dispatch: ``train.post`` (the score handle,
+        counters, ``sample()``: the slices listeners read activations
+        from, each a device program of its own), then ``train.listeners``,
+        then the iteration counter."""
+        from deeplearning4j_tpu.obs.registry import count_train_steps
+        at = self.iteration
+        with tracer.span("train.post", step=at):
+            self._score = loss
+            self.last_batch_size = batch
+            if sample is not None:
+                self._last_features = sample()
+            count_train_steps(1, batch)
+        if self.listeners:
+            with tracer.span("train.listeners", step=at):
+                for listener in self.listeners:
+                    listener.iteration_done(self, at, self.epoch)
         self.iteration += 1
 
     # ---------------------------------------------------------------- output

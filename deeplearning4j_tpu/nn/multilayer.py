@@ -88,6 +88,7 @@ class MultiLayerNetwork:
         self.compile_watch = CompileWatch("MultiLayerNetwork")
         self._rnn_carries = None  # stateful rnnTimeStep carries
         self._last_features = None  # last fit minibatch (listener sampling)
+        self._window_scores = None  # fit_tbptt_fused: every window's loss
         # set by checkpoint.CheckpointManager.restore_latest; consumed by
         # the next fit() for exact-step resume (skip already-seen batches).
         # _restored_from is informational provenance (also set by
@@ -207,7 +208,7 @@ class MultiLayerNetwork:
                 # apply_layer lowers through jax.checkpoint when the layer's
                 # remat= knob is set (perf/fusion.py policies)
                 x, st = apply_layer(layer, p_i, state[i], x, train=train,
-                                    rng=k, mask=cur_mask)
+                                    rng=k, mask=cur_mask, name=i)
                 new_state.append(st)
                 new_carries.append({})
             if not self._mask_survives[i]:
@@ -290,8 +291,8 @@ class MultiLayerNetwork:
             # decode + error-feedback residual update runs INSIDE the
             # compiled step on the gradient pytree; cstate is donated
             # alongside opt_state
-            def step_c(params, state, opt_state, cstate, rng, x, y, fmask,
-                       lmask):
+            def train_step_compressed(params, state, opt_state, cstate, rng,
+                                      x, y, fmask, lmask):
                 (loss, new_state), grads = value_and_grad(
                     params, state, x, y, rng, fmask, lmask)
                 grads, cstate = comp.apply(grads, cstate)
@@ -299,14 +300,16 @@ class MultiLayerNetwork:
                                                           opt_state)
                 return new_params, new_state, new_opt, cstate, loss
 
-            return jax.jit(step_c, donate_argnums=(0, 1, 2, 3))
+            return jax.jit(train_step_compressed, donate_argnums=(0, 1, 2, 3))
 
-        def step(params, state, opt_state, rng, x, y, fmask, lmask):
+        # the function's name is the program's in a profiler trace
+        # (jit_train_step): keep it stable
+        def train_step(params, state, opt_state, rng, x, y, fmask, lmask):
             (loss, new_state), grads = value_and_grad(params, state, x, y, rng, fmask, lmask)
             new_params, new_opt = self._apply_updates(params, grads, opt_state)
             return new_params, new_state, new_opt, loss
 
-        return jax.jit(step, donate_argnums=(0, 1, 2))
+        return jax.jit(train_step, donate_argnums=(0, 1, 2))
 
     def _make_fused_train_step(self):
         """K sequential optimizer steps fused into ONE dispatch via lax.scan
@@ -322,8 +325,8 @@ class MultiLayerNetwork:
             # feedback residual + controller) threads through the scan
             # carry exactly like opt_state, so K fused steps evolve the
             # residual identically to K per-batch fit() calls
-            def fused_c(params, state, opt_state, cstate, rng, xs, ys,
-                        fmasks, lmasks):
+            def train_fused_step_compressed(params, state, opt_state, cstate,
+                                            rng, xs, ys, fmasks, lmasks):
                 def body(carry, inp):
                     params, state, opt_state, cstate, rng = carry
                     x, y, fm, lm = inp
@@ -342,8 +345,8 @@ class MultiLayerNetwork:
                                  (xs, ys, fmasks, lmasks))
                 return params, state, opt_state, cstate, rng, losses
 
-            def fused_c_nomask(params, state, opt_state, cstate, rng, xs,
-                               ys):
+            def train_fused_step_compressed_nomask(params, state, opt_state,
+                                                   cstate, rng, xs, ys):
                 def body(carry, inp):
                     params, state, opt_state, cstate, rng = carry
                     x, y = inp
@@ -362,10 +365,13 @@ class MultiLayerNetwork:
                                  (xs, ys))
                 return params, state, opt_state, cstate, rng, losses
 
-            return (jax.jit(fused_c, donate_argnums=(0, 1, 2, 3)),
-                    jax.jit(fused_c_nomask, donate_argnums=(0, 1, 2, 3)))
+            return (jax.jit(train_fused_step_compressed,
+                            donate_argnums=(0, 1, 2, 3)),
+                    jax.jit(train_fused_step_compressed_nomask,
+                            donate_argnums=(0, 1, 2, 3)))
 
-        def fused(params, state, opt_state, rng, xs, ys, fmasks, lmasks):
+        def train_fused_step(params, state, opt_state, rng, xs, ys, fmasks,
+                             lmasks):
             def body(carry, inp):
                 params, state, opt_state, rng = carry
                 x, y, fm, lm = inp
@@ -383,7 +389,7 @@ class MultiLayerNetwork:
 
         # two compiled variants: with and without masks (None is not
         # scannable, so maskless groups pass no mask operands)
-        def fused_nomask(params, state, opt_state, rng, xs, ys):
+        def train_fused_step_nomask(params, state, opt_state, rng, xs, ys):
             def body(carry, inp):
                 params, state, opt_state, rng = carry
                 x, y = inp
@@ -398,8 +404,8 @@ class MultiLayerNetwork:
                 body, (params, state, opt_state, rng), (xs, ys))
             return params, state, opt_state, rng, losses
 
-        return (jax.jit(fused, donate_argnums=(0, 1, 2)),
-                jax.jit(fused_nomask, donate_argnums=(0, 1, 2)))
+        return (jax.jit(train_fused_step, donate_argnums=(0, 1, 2)),
+                jax.jit(train_fused_step_nomask, donate_argnums=(0, 1, 2)))
 
     def fit_fused(self, datasets, bucket_policy=None) -> "MultiLayerNetwork":
         """Train on a list of equally-shaped DataSets — or a pre-stacked
@@ -427,6 +433,28 @@ class MultiLayerNetwork:
         if self.conf.backprop_type == "tbptt":
             raise ValueError("fit_fused does not window tBPTT sequences; "
                              "use fit() for tbptt-configured networks")
+        from deeplearning4j_tpu.obs.trace import get_tracer
+        tracer = get_tracer()
+        at = self.iteration
+        # one call is one turn of the span tree (obs/trace.py), with no
+        # stream to wait for; the dispatch runs the whole group
+        with tracer.span("train.iteration", step=at), \
+                tracer.span("train.step_host", step=at) as host:
+            with tracer.span("train.stage", step=at):
+                xs, ys, fmasks, lmasks, n_steps = self._stack_group(
+                    datasets, bucket_policy)
+            batch = int(xs.shape[1])
+            host.set(items=n_steps * batch)
+            with tracer.span("train.dispatch", step=at,
+                             program="train_fused", steps=n_steps):
+                losses = self._dispatch_fused(xs, ys, fmasks, lmasks)
+            self._finish_step(tracer, losses[-1], batch,
+                              lambda: xs[-1][:1], steps=n_steps)
+        return self
+
+    def _stack_group(self, datasets, bucket_policy):
+        """fit_fused's staging: ``(xs, ys, fmasks, lmasks, n_steps)`` with
+        (K, batch, ...) stacks on the device."""
         fmasks = lmasks = None
         if isinstance(datasets, tuple) and len(datasets) == 2:
             xa, ya = datasets
@@ -472,6 +500,10 @@ class MultiLayerNetwork:
                                 else np.asarray(m)) for m in masks])
             fmasks = _stack_masks([d.features_mask for d in datasets])
             lmasks = _stack_masks([d.labels_mask for d in datasets])
+        return xs, ys, fmasks, lmasks, n_steps
+
+    def _dispatch_fused(self, xs, ys, fmasks, lmasks):
+        """The one call of the fused program; returns the K losses."""
         step_masked, step_nomask = self._get_jitted("train_fused")
         if self.grad_compression is not None:
             # compressed fused steps thread cstate through the scan carry
@@ -498,14 +530,7 @@ class MultiLayerNetwork:
             self.params, self.state, self.opt_state, self._rng, losses = \
                 step_nomask(self.params, self.state, self.opt_state,
                             self._rng, xs, ys)
-        self._score = losses[-1]
-        self.last_batch_size = int(xs.shape[1])
-        self._last_features = xs[-1][:1]
-        for listener in self.listeners:
-            listener.iteration_done(self, self.iteration + n_steps - 1,
-                                    self.epoch)
-        self.iteration += n_steps
-        return self
+        return losses
 
     # ------------------------------------------------- truncated BPTT / state
     def _zero_carries(self, batch: int):
@@ -530,8 +555,8 @@ class MultiLayerNetwork:
         value_and_grad = jax.value_and_grad(self._loss_fn_tbptt, has_aux=True)
         comp = self.grad_compression
         if comp is not None:
-            def step_c(params, state, opt_state, cstate, carries, rng, x, y,
-                       fmask, lmask):
+            def tbptt_step_compressed(params, state, opt_state, cstate,
+                                      carries, rng, x, y, fmask, lmask):
                 (loss, (new_state, new_carries)), grads = value_and_grad(
                     params, state, carries, x, y, rng, fmask, lmask)
                 grads, cstate = comp.apply(grads, cstate)
@@ -540,16 +565,18 @@ class MultiLayerNetwork:
                 return (new_params, new_state, new_opt, cstate, new_carries,
                         loss)
 
-            return jax.jit(step_c, donate_argnums=(0, 1, 2, 3, 4))
+            return jax.jit(tbptt_step_compressed,
+                           donate_argnums=(0, 1, 2, 3, 4))
 
-        def step(params, state, opt_state, carries, rng, x, y, fmask, lmask):
+        def tbptt_step(params, state, opt_state, carries, rng, x, y, fmask,
+                       lmask):
             (loss, (new_state, new_carries)), grads = value_and_grad(
                 params, state, carries, x, y, rng, fmask, lmask)
             new_params, new_opt = self._apply_updates(params, grads,
                                                       opt_state)
             return new_params, new_state, new_opt, new_carries, loss
 
-        return jax.jit(step, donate_argnums=(0, 1, 2, 3))
+        return jax.jit(tbptt_step, donate_argnums=(0, 1, 2, 3))
 
     def _check_stateful(self):
         for layer in self.layers:
@@ -667,10 +694,12 @@ class MultiLayerNetwork:
             elif kind == "tbptt_fused":
                 fn = self._make_tbptt_scan_step()
             elif kind == "rnn_step":
-                fn = jax.jit(lambda params, state, carries, x:
-                             (lambda r: (r[0][-1], r[4]))(
-                                 self._forward(params, state, x, False, None,
-                                               None, carries)))
+                def rnn_step(params, state, carries, x):
+                    r = self._forward(params, state, x, False, None, None,
+                                      carries)
+                    return r[0][-1], r[4]
+
+                fn = jax.jit(rnn_step)
             elif kind == "rnn_single_step":
                 # one decode timestep: x has NO time axis ((b,) ids or
                 # (b, f) features) — it is added inside the trace and the
@@ -679,25 +708,28 @@ class MultiLayerNetwork:
                 index_seq = getattr(self.layers[0], "takes_index_sequence",
                                     False)
 
-                def single_step(params, state, carries, x):
+                def rnn_single_step(params, state, carries, x):
                     xt = x[:, None] if index_seq else x[:, None, :]
                     r = self._forward(params, state, xt, False, None, None,
                                       carries)
                     return r[0][-1][:, 0, :], r[4]
 
-                fn = jax.jit(single_step)
+                fn = jax.jit(rnn_single_step)
             elif kind == "output":
-                fn = jax.jit(lambda params, state, x, fmask:
-                             self._forward(params, state, x, False, None, fmask)[0][-1])
+                def output(params, state, x, fmask):
+                    return self._forward(params, state, x, False, None,
+                                         fmask)[0][-1]
+
+                fn = jax.jit(output)
             elif kind == "score":
-                def score_fn(params, state, x, y, fmask, lmask):
+                def score(params, state, x, y, fmask, lmask):
                     _, preout, _, cur_mask, _ = self._forward(params, state, x, False, None, fmask)
                     lm = lmask if lmask is not None else cur_mask
                     if y.dtype in (jnp.bfloat16, jnp.float16):
                         y = y.astype(jnp.float32)
                     return (self.layers[-1].compute_score(y, preout, lm)
                             + self._regularization(params))
-                fn = jax.jit(score_fn)
+                fn = jax.jit(score)
             else:
                 raise KeyError(kind)
             if isinstance(fn, tuple):  # train_fused: (masked, nomask) pair
@@ -757,7 +789,8 @@ class MultiLayerNetwork:
 
             grad_fn = jax.value_and_grad(loss_fn)
 
-            def step(p_i, opt_i, below_params, below_state, s_i, rng, x):
+            def pretrain_step(p_i, opt_i, below_params, below_state, s_i,
+                              rng, x):
                 loss, g = grad_fn(p_i, below_params, below_state, s_i, x, rng)
                 g = self._gnorms[i](g)
                 updates, opt_i = self._txs[i].update(g, opt_i, p_i)
@@ -765,7 +798,7 @@ class MultiLayerNetwork:
                                           optax.apply_updates(p_i, updates))
                 return new_p, opt_i, loss
 
-            step = jax.jit(step, donate_argnums=(0, 1))
+            step = jax.jit(pretrain_step, donate_argnums=(0, 1))
             self._jit_cache[key] = step
         for _ in range(num_epochs):
             for ds in data:
@@ -893,26 +926,21 @@ class MultiLayerNetwork:
             stream = skip_consumed_batches(data, skip)
             if prefetch_cls is not None:
                 stream = prefetch_cls(stream)
-            # data-wait spans sit ABOVE prefetch: they measure what the
-            # step loop actually waits for, which prefetch exists to hide
-            stream = tracer.wrap_iter(stream, "train.data_wait")
+            # the fit loops' span tree (obs/trace.py): one train.iteration
+            # a turn, its data-wait ABOVE prefetch (what the step loop
+            # actually waits for, which prefetch exists to hide), then the
+            # loop's own work on the batch. No span waits for the device.
+            stream = tracer.wrap_iter(stream, "train.data_wait",
+                                      turn="train.iteration",
+                                      step=lambda: self.iteration)
             bi = skip
             for ds in stream:
                 bi += 1
-                if tracer.enabled:
-                    # host phase = trace/dispatch + listeners (async
-                    # dispatch returns immediately); device phase = the
-                    # remaining on-device time, exposed by a host-side
-                    # block_until_ready — spans never enter traced code
-                    with tracer.span("train.step_host", step=self.iteration):
-                        self._fit_batch(train_step, ds)
-                    with tracer.span("train.step_device",
-                                     step=self.iteration - 1):
-                        jax.block_until_ready(self._score)
-                else:
+                with tracer.span("train.step_host", step=self.iteration,
+                                 items=ds.num_examples()):
                     self._fit_batch(train_step, ds)
-                if checkpoint_manager is not None:
-                    checkpoint_manager.step_end(self, batch_in_epoch=bi)
+                    if checkpoint_manager is not None:
+                        checkpoint_manager.step_end(self, batch_in_epoch=bi)
             skip = 0
             for listener in self.listeners:
                 listener.on_epoch_end(self)
@@ -922,10 +950,20 @@ class MultiLayerNetwork:
         return self
 
     def _fit_batch(self, train_step, ds: DataSet):
-        x = jnp.asarray(ds.features)
-        y = jnp.asarray(ds.labels)
-        fm = None if ds.features_mask is None else jnp.asarray(ds.features_mask)
-        lm = None if ds.labels_mask is None else jnp.asarray(ds.labels_mask)
+        """One optimizer step on one batch, under the inner spans of the
+        fit loops' tree (obs/trace.py): opened here, where the work is, so
+        that every caller (``fit``, ``ParallelWrapper.fit_batch``) gets
+        them once, inside its own ``train.step_host``."""
+        from deeplearning4j_tpu.obs.trace import get_tracer
+        tracer = get_tracer()
+        step = self.iteration
+        with tracer.span("train.stage", step=step):
+            x = jnp.asarray(ds.features)
+            y = jnp.asarray(ds.labels)
+            fm = (None if ds.features_mask is None
+                  else jnp.asarray(ds.features_mask))
+            lm = (None if ds.labels_mask is None
+                  else jnp.asarray(ds.labels_mask))
         # tbptt applies when the input has a time axis: 3-D dense sequences or
         # 2-D integer index sequences (EmbeddingSequenceLayer) under an RNN
         # input type
@@ -937,26 +975,43 @@ class MultiLayerNetwork:
                 and x.shape[1] > self.conf.tbptt_fwd_length):
             self._fit_tbptt(x, y, fm, lm)
             return
-        self._rng, k = jax.random.split(self._rng)
-        if self.grad_compression is not None:
-            if self.compress_state is None:
-                from deeplearning4j_tpu.parallel.compress import (
-                    ensure_compress_state)
-                ensure_compress_state(self)
-            (self.params, self.state, self.opt_state, self.compress_state,
-             loss) = train_step(self.params, self.state, self.opt_state,
-                                self.compress_state, k, x, y, fm, lm)
-        else:
-            self.params, self.state, self.opt_state, loss = train_step(
-                self.params, self.state, self.opt_state, k, x, y, fm, lm)
-        self._score = loss
-        self.last_batch_size = int(x.shape[0])
-        # first sample only: listeners sample activations, and pinning
-        # the whole batch keeps large device buffers alive after fit()
-        self._last_features = x[:1]
-        for listener in self.listeners:
-            listener.iteration_done(self, self.iteration, self.epoch)
-        self.iteration += 1
+        with tracer.span("train.dispatch", step=step, program="train"):
+            self._rng, k = jax.random.split(self._rng)
+            if self.grad_compression is not None:
+                if self.compress_state is None:
+                    from deeplearning4j_tpu.parallel.compress import (
+                        ensure_compress_state)
+                    ensure_compress_state(self)
+                (self.params, self.state, self.opt_state,
+                 self.compress_state, loss) = train_step(
+                    self.params, self.state, self.opt_state,
+                    self.compress_state, k, x, y, fm, lm)
+            else:
+                self.params, self.state, self.opt_state, loss = train_step(
+                    self.params, self.state, self.opt_state, k, x, y, fm, lm)
+        self._finish_step(tracer, loss, int(x.shape[0]), lambda: x[:1])
+
+    def _finish_step(self, tracer, loss, batch: int, sample, steps: int = 1):
+        """What follows a dispatch in every fit path: ``train.post`` (the
+        score handle, counters, ``sample()``: the slice listeners read
+        activations from, a device program of its own), then
+        ``train.listeners``, then the iteration counter. ``steps`` is the
+        optimizer steps the dispatch ran (fused paths: the group)."""
+        from deeplearning4j_tpu.obs.registry import count_train_steps
+        step = self.iteration
+        with tracer.span("train.post", step=step):
+            self._score = loss
+            self.last_batch_size = batch
+            # first sample only: listeners sample activations, and pinning
+            # the whole batch keeps large device buffers alive after fit()
+            self._last_features = sample()
+            count_train_steps(steps, steps * batch)
+        if self.listeners:
+            with tracer.span("train.listeners", step=step):
+                for listener in self.listeners:
+                    listener.iteration_done(self, step + steps - 1,
+                                            self.epoch)
+        self.iteration += steps
 
     def _make_tbptt_scan_step(self):
         """All tBPTT windows of one sequence batch fused into ONE dispatch:
@@ -970,8 +1025,8 @@ class MultiLayerNetwork:
         if comp is not None:
             # cstate through the scan carry — per-window error-feedback
             # evolution identical to the per-window _fit_tbptt loop
-            def fused_c(params, state, opt_state, cstate, carries, rng,
-                        xw, yw):
+            def tbptt_fused_step_compressed(params, state, opt_state, cstate,
+                                            carries, rng, xw, yw):
                 def body(c, inp):
                     params, state, opt_state, cstate, carries, rng = c
                     x, y = inp
@@ -991,9 +1046,10 @@ class MultiLayerNetwork:
                 return (params, state, opt_state, cstate, carries, rng,
                         losses)
 
-            return jax.jit(fused_c, donate_argnums=(0, 1, 2, 3, 4))
+            return jax.jit(tbptt_fused_step_compressed,
+                           donate_argnums=(0, 1, 2, 3, 4))
 
-        def fused(params, state, opt_state, carries, rng, xw, yw):
+        def tbptt_fused_step(params, state, opt_state, carries, rng, xw, yw):
             def body(c, inp):
                 params, state, opt_state, carries, rng = c
                 x, y = inp
@@ -1009,7 +1065,7 @@ class MultiLayerNetwork:
                 body, (params, state, opt_state, carries, rng), (xw, yw))
             return params, state, opt_state, carries, rng, losses
 
-        return jax.jit(fused, donate_argnums=(0, 1, 2, 3))
+        return jax.jit(tbptt_fused_step, donate_argnums=(0, 1, 2, 3))
 
     def fit_tbptt_fused(self, x, y) -> "MultiLayerNetwork":
         """Train one (batch, T, ...) sequence batch with ALL full tBPTT
@@ -1023,47 +1079,63 @@ class MultiLayerNetwork:
         if self.conf.backprop_type != "tbptt":
             raise ValueError("fit_tbptt_fused requires backprop_type='tbptt' "
                              "(this network is 'standard'; use fit/fit_fused)")
-        x = jnp.asarray(x)
-        y = jnp.asarray(y)
         L = self.conf.tbptt_fwd_length
-        T = int(x.shape[1])
+        T = int(np.shape(x)[1])
         if T % L != 0:
             raise ValueError(f"sequence length {T} must be a multiple of "
                              f"tbptt_fwd_length {L} for the fused path")
         w = T // L
-        b = int(x.shape[0])
-        # (b, T, ...) -> (W, b, L, ...)
-        xw = jnp.moveaxis(x.reshape((b, w, L) + x.shape[2:]), 1, 0)
-        yw = (jnp.moveaxis(y.reshape((b, w, L) + y.shape[2:]), 1, 0)
-              if y.ndim == 3 else jnp.broadcast_to(y, (w,) + y.shape))
-        carries = self._zero_carries(b)
-        step = self._get_jitted("tbptt_fused")
-        if self.grad_compression is not None:
-            if self.compress_state is None:
-                from deeplearning4j_tpu.parallel.compress import (
-                    ensure_compress_state)
-                ensure_compress_state(self)
-            (self.params, self.state, self.opt_state, self.compress_state,
-             _, self._rng, losses) = step(
-                self.params, self.state, self.opt_state,
-                self.compress_state, carries, self._rng, xw, yw)
-        else:
-            (self.params, self.state, self.opt_state, _, self._rng,
-             losses) = step(self.params, self.state, self.opt_state,
-                            carries, self._rng, xw, yw)
-        self._score = losses[-1]
-        self.last_batch_size = b
-        self._last_features = x[:1]
-        for listener in self.listeners:
-            listener.iteration_done(self, self.iteration + w - 1, self.epoch)
-        self.iteration += w
+        b = int(np.shape(x)[0])
+        from deeplearning4j_tpu.obs.trace import get_tracer
+        tracer = get_tracer()
+        at = self.iteration
+        # one call is one turn of the span tree (obs/trace.py), with no
+        # stream to wait for; the dispatch runs all w windows
+        with tracer.span("train.iteration", step=at), \
+                tracer.span("train.step_host", step=at, items=b * w):
+            with tracer.span("train.stage", step=at):
+                x = jnp.asarray(x)
+                y = jnp.asarray(y)
+                # (b, T, ...) -> (W, b, L, ...)
+                xw = jnp.moveaxis(x.reshape((b, w, L) + x.shape[2:]), 1, 0)
+                yw = (jnp.moveaxis(y.reshape((b, w, L) + y.shape[2:]), 1, 0)
+                      if y.ndim == 3
+                      else jnp.broadcast_to(y, (w,) + y.shape))
+                carries = self._zero_carries(b)
+            with tracer.span("train.dispatch", step=at,
+                             program="tbptt_fused", steps=w):
+                step = self._get_jitted("tbptt_fused")
+                if self.grad_compression is not None:
+                    if self.compress_state is None:
+                        from deeplearning4j_tpu.parallel.compress import (
+                            ensure_compress_state)
+                        ensure_compress_state(self)
+                    (self.params, self.state, self.opt_state,
+                     self.compress_state, _, self._rng, losses) = step(
+                        self.params, self.state, self.opt_state,
+                        self.compress_state, carries, self._rng, xw, yw)
+                else:
+                    (self.params, self.state, self.opt_state, _, self._rng,
+                     losses) = step(self.params, self.state, self.opt_state,
+                                    carries, self._rng, xw, yw)
+            self._window_scores = losses
+            self._finish_step(tracer, losses[-1], b, lambda: x[:1], steps=w)
         return self
+
+    def window_scores(self):
+        """Every window's loss of the last ``fit_tbptt_fused`` call, in
+        order: the (windows,) array the scan returned, still on the device
+        (reading it is the caller's sync, not the fit's). None before the
+        first call. ``score()`` is its last element."""
+        return self._window_scores
 
     def _fit_tbptt(self, x, y, fm, lm):
         """Chunked fit over time windows (reference doTruncatedBPTT
         MultiLayerNetwork.java:1393): one optimizer update per forward-length
         window, with RNN state carried (but not differentiated) across
         windows."""
+        from deeplearning4j_tpu.obs.trace import get_tracer
+        tracer = get_tracer()
         step = self._get_jitted("tbptt")
         T = x.shape[1]
         L = self.conf.tbptt_fwd_length
@@ -1076,25 +1148,26 @@ class MultiLayerNetwork:
             ys = y[:, s:e] if y.ndim == 3 else y
             fs = None if fm is None else fm[:, s:e]
             ls = None if lm is None else lm[:, s:e]
-            self._rng, k = jax.random.split(self._rng)
-            if self.grad_compression is not None:
-                if self.compress_state is None:
-                    from deeplearning4j_tpu.parallel.compress import (
-                        ensure_compress_state)
-                    ensure_compress_state(self)
-                (self.params, self.state, self.opt_state,
-                 self.compress_state, carries, loss) = step(
-                    self.params, self.state, self.opt_state,
-                    self.compress_state, carries, k, xs, ys, fs, ls)
-            else:
-                self.params, self.state, self.opt_state, carries, loss = step(
-                    self.params, self.state, self.opt_state, carries, k, xs, ys, fs, ls)
-            self._score = loss
-            self.last_batch_size = int(x.shape[0])
-            self._last_features = xs[:1]
-            for listener in self.listeners:
-                listener.iteration_done(self, self.iteration, self.epoch)
-            self.iteration += 1
+            # one optimizer update per window == one iteration: each
+            # window's spans carry its own step
+            with tracer.span("train.dispatch", step=self.iteration,
+                             program="tbptt"):
+                self._rng, k = jax.random.split(self._rng)
+                if self.grad_compression is not None:
+                    if self.compress_state is None:
+                        from deeplearning4j_tpu.parallel.compress import (
+                            ensure_compress_state)
+                        ensure_compress_state(self)
+                    (self.params, self.state, self.opt_state,
+                     self.compress_state, carries, loss) = step(
+                        self.params, self.state, self.opt_state,
+                        self.compress_state, carries, k, xs, ys, fs, ls)
+                else:
+                    (self.params, self.state, self.opt_state, carries,
+                     loss) = step(self.params, self.state, self.opt_state,
+                                  carries, k, xs, ys, fs, ls)
+            self._finish_step(tracer, loss, int(x.shape[0]),
+                              lambda xs=xs: xs[:1])
 
     # ---------------------------------------------------------------- output
     def output(self, x, train: bool = False, features_mask=None) -> np.ndarray:

@@ -9,9 +9,11 @@ One telemetry pipeline for everything the repo measures:
   ad-hoc stats (``CompileWatch``, ``TrainingStats``,
   ``ParallelInference.stats()``, ``CheckpointManager`` counters);
 - :mod:`~deeplearning4j_tpu.obs.trace` — explicit-clock host-side span
-  tracer (disabled ⇒ near-zero-cost no-op) instrumenting the per-step
-  phase breakdown in fit, serving dispatch, checkpoint commits and
-  elastic generation boundaries, plus the synced bench ``Stopwatch``;
+  tracer on the profiler's clock (every span is also a
+  ``jax.profiler.TraceAnnotation``; disabled ⇒ the annotation alone)
+  instrumenting one span tree in every fit loop, serving dispatch,
+  checkpoint commits and elastic generation boundaries, plus the synced
+  bench ``Stopwatch``;
 - :mod:`~deeplearning4j_tpu.obs.exporters` — Prometheus text format
   (served at ``/metrics`` by the existing ``UIServer``) and a JSONL event
   log through any ``StorageBackend``;

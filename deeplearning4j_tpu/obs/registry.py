@@ -320,6 +320,18 @@ def get_registry() -> MetricsRegistry:
         return _global
 
 
+def count_train_steps(steps: int, items: int) -> None:
+    """The fit loops' two counters, bumped in ``train.post`` (obs/trace.py)
+    by every fit path: optimizer steps dispatched and items (rows of a
+    batch, once per step they were trained in) handed to them."""
+    reg = get_registry()
+    reg.counter("train_steps_total", unit="steps",
+                help="optimizer steps the fit paths dispatched").inc(steps)
+    reg.counter("train_items_total", unit="items",
+                help="batch rows the fit paths handed to optimizer steps"
+                ).inc(items)
+
+
 def _sanitize(name: str) -> str:
     s = re.sub(r"[^a-z0-9_]", "_", str(name).lower()).strip("_")
     return s if s and s[0].isalpha() else f"m_{s}"

@@ -1,13 +1,45 @@
-"""Low-overhead host-side span tracer + the synced bench Stopwatch.
+"""The repo's one span tracer, on the profiler's clock, + the synced bench
+Stopwatch.
 
 Spans answer the question metrics can't: *why was step 812 slow* — was the
-host waiting on data, dispatching, or blocked on the device? The tracer is
-explicit-clock (injectable ``clock``; the overhead-guard test counts clock
-calls instead of trusting wall time on a noisy filesystem) and DISABLED by
-default with a near-zero-cost no-op path: ``span()`` on a disabled tracer
-returns a shared singleton — no allocation, no clock read, no sink
-dispatch. Spans are host-side only and must never enter jit-traced code
-(DLT002: a clock read inside a traced function freezes at trace time).
+host waiting on data, staging the batch, dispatching, or running
+listeners? Every span is ALSO a ``jax.profiler.TraceAnnotation``, entered
+and left with the span whether the tracer is enabled or not. With no
+profiler session that costs a flag test in C++; with one (a benchmark's
+``--trace 1`` run, ``optimize.listeners.ProfilerListener``) the span sits
+on the host plane of the ``xplane.pb``, on the clock the device planes use,
+so an idle gap on the device can be put down to a span of the program with
+nothing switched on.
+
+The tracer itself is explicit-clock (injectable ``clock``; the
+overhead-guard test counts clock calls instead of trusting wall time) and
+DISABLED by default: ``span()`` on a disabled tracer allocates the
+annotation and nothing else — no clock read, no record, no sink dispatch.
+Enabled, a finished span's record carries ``name``, ``id``, ``parent``
+(the id of the span open on that thread when it began), ``thread``,
+``start`` (on the duration clock), ``dur_ms``, ``wall`` and ``attrs``; a
+span that names no ``step`` of its own takes its parent's, so all spans of
+one training step share one. Self time is a span's duration minus its
+children's. Spans are host-side only and must never enter jit-traced code
+(DLT002: a clock read inside a traced function freezes at trace time), and
+NO span waits for the device: the fit loops' ``train.iteration`` (one turn
+of the loop) is the step time once the device is the bottleneck, because
+the runtime's back-pressure then holds the host inside ``train.dispatch``;
+device time per step proper comes from the device planes of a profiler
+trace. Operators without a profiler read ``train_iteration_ms`` (p50/p95).
+
+The fit loops' span tree (nn/multilayer.py, nn/graph.py,
+parallel/trainer.py, perf/prefetch.py, checkpoint/manager.py)::
+
+    train.iteration                 one turn of the loop
+      train.data_wait               next() of the stream, above prefetch
+        prefetch.place              issuing batch N+1's device_put
+      train.step_host               the loop's own work on its batch
+        train.stage                 host->device hand-over of THIS batch
+        train.dispatch              rng split + the jitted step's call
+        train.post                  score handle, counters, feature slice
+        train.listeners             iteration_done of the listeners
+        checkpoint.step_end         child checkpoint.snapshot on a save
 
 Finished spans and instant events are dispatched to *sinks* (the crash
 flight recorder's ring, a JSONL event log) and — when the tracer carries a
@@ -17,45 +49,61 @@ the per-step phase breakdown shows up in the Prometheus scrape for free.
 
 from __future__ import annotations
 
+import contextlib
+import itertools
 import logging
 import threading
 import time
 from typing import Callable, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 log = logging.getLogger(__name__)
 
 __all__ = ["Tracer", "get_tracer", "configure_tracer", "Stopwatch"]
 
 
-class _NoopSpan:
-    """Shared do-nothing span: the disabled tracer's entire cost is
-    returning this singleton."""
+class _Annotation(TraceAnnotation):
+    """A disabled tracer's span: the profiler annotation (begun when
+    made) under the span interface, and nothing else."""
 
     __slots__ = ()
 
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
     def end(self):
-        pass
+        self.__exit__(None, None, None)
+
+    cancel = end
+
+    def set(self, **attrs):
+        self.set_metadata(**attrs)
 
 
-_NOOP_SPAN = _NoopSpan()
+def _inherit_step(attrs: dict, above: Optional["_Span"]):
+    """A span or event that names no ``step`` takes the enclosing span's:
+    all records of one training step then share one."""
+    if (above is not None and "step" not in attrs
+            and "step" in above.attrs):
+        attrs["step"] = above.attrs["step"]
 
 
 class _Span:
-    __slots__ = ("tracer", "name", "attrs", "_t0", "_wall", "_done")
+    __slots__ = ("tracer", "name", "attrs", "id", "parent", "_ann", "_t0",
+                 "_wall", "_done")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
+        stack = tracer._stack()
+        above = stack[-1] if stack else None
+        _inherit_step(attrs, above)
         self.tracer = tracer
         self.name = name
         self.attrs = attrs
+        self.id = next(tracer._ids)
+        self.parent = None if above is None else above.id
+        self._done = False
+        stack.append(self)
+        self._ann = TraceAnnotation(name, **attrs)
         self._wall = time.time()
         self._t0 = tracer.clock()
-        self._done = False
 
     def __enter__(self):
         return self
@@ -64,15 +112,34 @@ class _Span:
         self.end()
         return False
 
-    def end(self):
+    def set(self, **attrs):
+        """Attributes learned while the span is open (``compiled=1``)."""
+        self.attrs.update(attrs)
+        self._ann.set_metadata(**attrs)
+
+    def _close(self) -> bool:
         if self._done:
-            return
+            return False
         self._done = True
+        self._ann.__exit__(None, None, None)
+        # itself only, wherever it sits: a span ended late (a generator
+        # closed by the collector) must not unseat live ones
+        with contextlib.suppress(ValueError):
+            self.tracer._stack().remove(self)
+        return True
+
+    def end(self):
         dur_ms = (self.tracer.clock() - self._t0) * 1000.0
-        self.tracer._dispatch({"kind": "span", "name": self.name,
-                               "wall": self._wall,
-                               "dur_ms": round(dur_ms, 4),
-                               "attrs": self.attrs})
+        if self._close():
+            self.tracer._dispatch({
+                "kind": "span", "name": self.name, "id": self.id,
+                "parent": self.parent, "thread": threading.get_ident(),
+                "start": self._t0, "dur_ms": round(dur_ms, 4),
+                "wall": self._wall, "attrs": self.attrs})
+
+    def cancel(self):
+        """Leave the span without a record (a probe that found nothing)."""
+        self._close()
 
 
 class Tracer:
@@ -92,6 +159,15 @@ class Tracer:
         self.registry = registry
         self._sinks: List[Callable[[dict], None]] = []
         self._sink_lock = threading.Lock()
+        self._ids = itertools.count(1)
+        self._local = threading.local()
+
+    def _stack(self) -> list:
+        try:
+            return self._local.stack
+        except AttributeError:
+            stack = self._local.stack = []
+            return stack
 
     # ---------------------------------------------------------------- sinks
     def add_sink(self, sink: Callable[[dict], None]):
@@ -129,40 +205,85 @@ class Tracer:
 
     # ----------------------------------------------------------------- API
     def span(self, name: str, **attrs):
-        """Context manager timing a host-side section. Disabled tracer:
-        returns the shared no-op singleton (no clock read, no alloc)."""
+        """Context manager timing a host-side section; always a profiler
+        annotation too. Disabled tracer: the annotation alone (no clock
+        read, no record, no sink call)."""
         if not self.enabled:
-            return _NOOP_SPAN
+            return _Annotation(name, **attrs)
         return _Span(self, name, attrs)
 
+    def current(self) -> Optional[_Span]:
+        """The innermost span open on this thread; None when there is
+        none or the tracer is disabled."""
+        if not self.enabled:
+            return None
+        stack = self._stack()
+        return stack[-1] if stack else None
+
+    @contextlib.contextmanager
+    def attach(self, span: Optional[_Span]):
+        """Make ``span`` (another thread's ``current()``) the parent of
+        the spans this thread opens inside: a step that a watchdog runs on
+        a worker thread stays in its turn's tree. ``None`` does nothing."""
+        if span is None:
+            yield
+            return
+        stack = self._stack()
+        stack.append(span)
+        try:
+            yield
+        finally:
+            with contextlib.suppress(ValueError):
+                stack.remove(span)
+
     def event(self, name: str, **attrs):
-        """Instant event (no duration) into the same sinks."""
+        """Instant event (no duration): an instant annotation always, and
+        a record into the sinks when enabled."""
+        above = self.current()
+        _inherit_step(attrs, above)
+        _Annotation(name, **attrs).end()
         if not self.enabled:
             return
-        self._dispatch({"kind": "event", "name": name, "wall": time.time(),
+        self._dispatch({"kind": "event", "name": name,
+                        "id": next(self._ids),
+                        "parent": None if above is None else above.id,
+                        "thread": threading.get_ident(),
+                        "start": self.clock(), "wall": time.time(),
                         "dur_ms": 0.0, "attrs": attrs})
 
-    def wrap_iter(self, iterable, name: str):
-        """Time each ``next()`` of ``iterable`` as a span — how the fit
-        loops measure data-wait without restructuring. Disabled tracer:
-        the iterable is returned UNCHANGED (zero per-batch cost)."""
-        if not self.enabled:
-            return iterable
-
-        def gen():
-            it = iter(iterable)
-            i = 0
-            while True:
-                sp = self.span(name, index=i)
-                try:
-                    item = next(it)
-                except StopIteration:
-                    return  # the exhausted probe is not a data wait:
-                    # its span is dropped, so N items → N spans
-                sp.end()
+    def wrap_iter(self, iterable, name: str, turn: Optional[str] = None,
+                  step: Optional[Callable[[], int]] = None):
+        """Time each ``next()`` of ``iterable`` as a ``name`` span — how
+        the fit loops measure data-wait without restructuring. With
+        ``turn``, every item's whole turn of the caller's loop (the
+        ``next()`` and the loop's body, up to the following ``next()``) is
+        a ``turn`` span whose first child is the wait. ``step`` is read at
+        each turn's start and lands on both as ``step=``. The exhausted
+        probe is not a data wait, and a turn whose loop died under it is
+        not a turn: both are dropped, so N items → N spans."""
+        it = iter(iterable)
+        while True:
+            attrs = {} if step is None else {"step": step()}
+            outer = None if turn is None else self.span(turn, **attrs)
+            wait = self.span(name, **attrs)
+            try:
+                item = next(it)
+            except BaseException as e:
+                wait.cancel()
+                if outer is not None:
+                    outer.cancel()
+                if isinstance(e, StopIteration):
+                    return
+                raise
+            wait.end()
+            try:
                 yield item
-                i += 1
-        return gen()
+            except GeneratorExit:
+                if outer is not None:
+                    outer.cancel()
+                raise
+            if outer is not None:
+                outer.end()
 
 
 # ---------------------------------------------------------- global default

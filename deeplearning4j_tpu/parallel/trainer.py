@@ -20,6 +20,7 @@ quantized encoding with error feedback into the step.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 from typing import Optional
 
@@ -188,13 +189,7 @@ class ParallelWrapper:
                     and is_sgd_family(getattr(conf, "optimization_algo",
                                               "stochastic_gradient_descent")))
         if standard and hasattr(m, "_fit_batch") and hasattr(m, "_get_jitted"):
-            from deeplearning4j_tpu.nn.graph import ComputationGraph
-            if isinstance(m, ComputationGraph):
-                from deeplearning4j_tpu.datasets.dataset import MultiDataSet
-                m._fit_batch(m._get_jitted("train"),
-                             MultiDataSet.from_dataset(sharded))
-            else:
-                m._fit_batch(m._get_jitted("train"), sharded)
+            m._fit_batch(m._get_jitted("train"), sharded)
         else:
             # tbptt/solver configs go through model.fit; suppress its
             # per-call epoch side effects (hooks + epoch counter) so the
@@ -214,12 +209,32 @@ class ParallelWrapper:
         the same drop/train decision without a coordination collective."""
         return bool(ds.num_examples() % self.mesh.shape[DATA_AXIS])
 
+    def _phase(self, name: str):
+        """TrainingStats' timer for one phase; nothing when stats are off."""
+        return (contextlib.nullcontext() if self.stats is None
+                else self.stats.time(name))
+
+    def _train_batch(self, ds: DataSet, examples: int):
+        """Place, shard and train one batch (inside ``with self.mesh``).
+        The wrapper's own hand-over is a ``train.stage`` span (obs/trace.py)
+        beside the model's; dispatch, post and listeners are the model's
+        ``_fit_batch``'s. ``examples`` is what TrainingStats counts."""
+        from deeplearning4j_tpu.obs.trace import get_tracer
+        with get_tracer().span("train.stage", step=self.model.iteration), \
+                self._phase("data_placement"):
+            self._place_params()
+            sharded = self._shard_dataset(ds)
+        with self._phase("train_dispatch"):
+            self._model_fit_batch(sharded)
+        if self.stats is not None:
+            self.stats.examples += examples
+            self.stats.minibatches += 1
+
     def fit_batch(self, ds: DataSet, drop_ragged: bool = False) -> bool:
         """Train on ONE global batch (sharded over the mesh); returns whether
         the batch was trained. ``drop_ragged`` drops batches that don't
         divide the data-parallel size instead of raising — static shapes are
         the TPU contract, so a ragged tail is dropped, not recompiled."""
-        self._place_params()
         dp = self.mesh.shape[DATA_AXIS]
         if self._is_ragged(ds) and drop_ragged:
             if not self._warned_ragged:
@@ -229,15 +244,7 @@ class ParallelWrapper:
                 self._warned_ragged = True
             return False
         with self.mesh:
-            if self.stats is None:
-                self._model_fit_batch(self._shard_dataset(ds))
-            else:
-                with self.stats.time("data_placement"):
-                    sharded = self._shard_dataset(ds)
-                with self.stats.time("train_dispatch"):
-                    self._model_fit_batch(sharded)
-                self.stats.examples += ds.num_examples()
-                self.stats.minibatches += 1
+            self._train_batch(ds, ds.num_examples())
         return True
 
     # ---- training (reference ParallelWrapper.fit dispatch loop :210) ----
@@ -263,6 +270,8 @@ class ParallelWrapper:
             prefetch_cls = DevicePrefetchIterator
         from deeplearning4j_tpu.checkpoint.manager import (
             resume_plan, skip_consumed_batches)
+        from deeplearning4j_tpu.obs.trace import get_tracer
+        tracer = get_tracer()
         epochs_to_run, skip = resume_plan(self.model, num_epochs)
         if hasattr(data, "bind_epoch"):
             # epoch-aware sharded readers follow the model's epoch
@@ -279,15 +288,24 @@ class ParallelWrapper:
             stream = skip_consumed_batches(data, skip)
             if prefetch_cls is not None:
                 stream = prefetch_cls(stream, mesh=self.mesh)
+            # the fit loops' span tree, as in multilayer.py fit (host-side
+            # only, nothing waits for the device; see obs/trace.py)
+            stream = tracer.wrap_iter(stream, "train.data_wait",
+                                      turn="train.iteration",
+                                      step=lambda: self.model.iteration)
             for ds in stream:
                 seen += 1
-                # a single explicit ragged DataSet raises (dropping it would
-                # train on nothing); iterator tail batches drop-remainder
-                if self.fit_batch(ds, drop_ragged=not explicit_single):
-                    trained += 1
-                    if checkpoint_manager is not None:
-                        checkpoint_manager.step_end(self.model,
-                                                    batch_in_epoch=seen)
+                with tracer.span("train.step_host",
+                                 step=self.model.iteration,
+                                 items=ds.num_examples()):
+                    # a single explicit ragged DataSet raises (dropping it
+                    # would train on nothing); iterator tail batches
+                    # drop-remainder
+                    if self.fit_batch(ds, drop_ragged=not explicit_single):
+                        trained += 1
+                        if checkpoint_manager is not None:
+                            checkpoint_manager.step_end(self.model,
+                                                        batch_in_epoch=seen)
             skip = 0
             if seen == 0:
                 raise ValueError(
@@ -540,63 +558,42 @@ class ClusterTrainer(ParallelWrapper):
                 if prefetch_cls is not None:
                     stream = prefetch_cls(stream,
                                           place_fn=self._stage_local_batch)
-                # same phase spans as MLN/graph fit (obs/trace.py): the
-                # elastic worker trains through THIS loop, so its crash
-                # ring / event log carry the per-step breakdown too
-                stream = tracer.wrap_iter(stream, "train.data_wait")
+                # the fit loops' span tree, as in multilayer.py fit
+                # (obs/trace.py): the elastic worker trains through THIS
+                # loop, so its crash ring / event log carry the per-step
+                # breakdown too
+                stream = tracer.wrap_iter(stream, "train.data_wait",
+                                          turn="train.iteration",
+                                          step=lambda: self.model.iteration)
                 for ds in stream:
+                    turn = tracer.current()
+
                     # _model_fit_batch, not model.fit: per-epoch hooks and
                     # the epoch counter must fire once per EPOCH, not once
                     # per minibatch (same contract as ParallelWrapper.fit)
-                    def one_step(d=ds):
-                        if self.stats is None:
-                            self._model_fit_batch(self._shard_dataset(d))
-                        else:
-                            # a prefetch-staged batch is already the GLOBAL
-                            # array: normalize the examples counter back to
-                            # process-local rows so the metric doesn't
-                            # change meaning with the prefetch flag
-                            n_local = d.num_examples()
-                            if getattr(d, "_staged_global", False):
-                                n_local //= max(1, jax.process_count())
-                            with self.stats.time("data_placement"):
-                                sharded = self._shard_dataset(d)
-                            with self.stats.time("train_dispatch"):
-                                self._model_fit_batch(sharded)
-                            self.stats.examples += n_local
-                            self.stats.minibatches += 1
-                    def guarded_step():
-                        if wd is None:
-                            one_step()
-                        else:
-                            # the dispatch itself can block synchronously
-                            # on a dead peer's collective rendezvous, so
-                            # the deadline must wrap the whole call, not
-                            # just a later sync
-                            wd.call(one_step,
-                                    what=f"cluster step {step_no + 1} "
-                                         "dispatch")
-                    if tracer.enabled:
-                        # both spans run inside ONE watchdog call so the
-                        # traced path pays the same single worker thread
-                        # per step as the untraced one, and the device
-                        # sync still sits under the deadline: a hung
-                        # collective raises CollectiveTimeoutError (the
-                        # elastic membership-bump escalation) instead of
-                        # hanging the tracing span forever
-                        def traced_step(n=step_no):
-                            with tracer.span("train.step_host", step=n):
-                                one_step()
-                            with tracer.span("train.step_device", step=n):
-                                jax.block_until_ready(self.model._score)
-                        if wd is None:
-                            traced_step()
-                        else:
-                            wd.call(traced_step,
-                                    what=f"cluster step {step_no + 1} "
-                                         "dispatch+sync")
+                    def one_step(d=ds, turn=turn):
+                        # a prefetch-staged batch is already the GLOBAL
+                        # array: normalize the examples counter back to
+                        # process-local rows so the metric doesn't change
+                        # meaning with the prefetch flag
+                        n_local = d.num_examples()
+                        if getattr(d, "_staged_global", False):
+                            n_local //= max(1, jax.process_count())
+                        # under a watchdog this runs on its worker thread:
+                        # attach keeps the step in its turn's tree
+                        with tracer.attach(turn), tracer.span(
+                                "train.step_host", step=self.model.iteration,
+                                items=n_local):
+                            self._train_batch(d, n_local)
+                    if wd is None:
+                        one_step()
                     else:
-                        guarded_step()
+                        # the dispatch itself can block synchronously on a
+                        # dead peer's collective rendezvous, so the
+                        # deadline must wrap the whole call, not just a
+                        # later sync
+                        wd.call(one_step,
+                                what=f"cluster step {step_no + 1} dispatch")
                     step_no += 1
                     seen += 1
                     if wd is not None and step_no % max(1, watchdog_every) == 0:

@@ -182,6 +182,16 @@ class _WatchedFunction:
                 self._seen_sigs.add(sig)
         for sink in self._sinks:
             sink._record(self._key, compiled, 1)
+        if compiled:
+            # an event at the step where it happened, and a mark on the
+            # enclosing train.dispatch: a trace or a crash ring then shows
+            # WHICH step recompiled, not only that one did
+            from deeplearning4j_tpu.obs.trace import get_tracer
+            tracer = get_tracer()
+            tracer.event("compile", program=self._key)
+            enclosing = tracer.current()
+            if enclosing is not None:
+                enclosing.set(compiled=1)
         for cb in list(_observers):
             try:
                 cb(self._key, self._fn, args, kwargs, compiled)

@@ -30,6 +30,14 @@ from deeplearning4j_tpu.datasets.dataset import DataSet, MultiDataSet
 from deeplearning4j_tpu.datasets.iterators import DataSetIterator
 
 
+def _arrays_of(ds):
+    """Every array slot of a DataSet or MultiDataSet (None where empty)."""
+    if isinstance(ds, MultiDataSet):
+        return [a for arrs in (ds.features, ds.labels, ds.features_masks,
+                               ds.labels_masks) for a in arrs or ()]
+    return [ds.features, ds.labels, ds.features_mask, ds.labels_mask]
+
+
 class DevicePrefetchIterator(DataSetIterator):
     """Yield DataSets (or MultiDataSets) whose arrays are already resident
     on device.
@@ -72,6 +80,23 @@ class DevicePrefetchIterator(DataSetIterator):
         return jnp.asarray(a)
 
     def _place(self, ds):
+        """``_stage`` under a ``prefetch.place`` span (obs/trace.py): the
+        host time to ISSUE one batch's staging, where it happens — inside
+        the fit loop's ``next()``, one batch ahead of the step."""
+        from deeplearning4j_tpu.obs.registry import get_registry
+        from deeplearning4j_tpu.obs.trace import get_tracer
+        arrays = [a for a in _arrays_of(ds) if a is not None]
+        nbytes = sum(int(getattr(a, "nbytes", 0)) for a in arrays)
+        with get_tracer().span("prefetch.place", bytes=nbytes,
+                               arrays=len(arrays)):
+            out = self._stage(ds)
+        get_registry().counter(
+            "prefetch_bytes_total", unit="bytes",
+            help="bytes of batch arrays handed to DevicePrefetchIterator's "
+                 "staging (device_put / shard_batch / place_fn)").inc(nbytes)
+        return out
+
+    def _stage(self, ds):
         if self._place_fn is not None:
             out = self._place_fn(ds)
             if out is ds:  # unchanged == declined (e.g. ragged)

@@ -153,7 +153,8 @@ class TestTracer:
     def test_disabled_is_noop_by_opcount(self):
         """Overhead guard asserted by OP COUNT, not wall clock (the 9p
         bench-sensitivity note): a disabled tracer never reads the clock,
-        never allocates a span, never touches a sink."""
+        never touches a sink, and allocates nothing but the profiler
+        annotation every span also is (no record, no stack entry)."""
         clock_calls = []
 
         def counting_clock():
@@ -167,11 +168,15 @@ class TestTracer:
         with s1:
             pass
         t.event("c", x=1)
-        assert s1 is s2  # the shared no-op singleton: zero allocation
+        import jax
+        for s in (s1, s2):  # nothing but the annotation
+            assert isinstance(s, jax.profiler.TraceAnnotation)
+            assert vars(s) == {}
+        assert t.current() is None and t._stack() == []
+        assert list(t.wrap_iter([1, 2, 3], "w", turn="t",
+                                step=lambda: 0)) == [1, 2, 3]
         assert clock_calls == []
         assert sink_calls == []
-        data = [1, 2, 3]
-        assert t.wrap_iter(data, "w") is data  # passthrough, not a wrapper
 
     def test_enabled_records_spans_and_histograms(self):
         r = obs.MetricsRegistry()
@@ -185,6 +190,55 @@ class TestTracer:
         assert kinds == [("span", "phase.one"), ("event", "boundary")]
         assert sink[0]["attrs"] == {"step": 3}
         assert r.metric("phase_one_ms").count == 1
+        for rec in sink:  # what self time and a timeline need
+            assert {"id", "parent", "thread", "start", "wall",
+                    "dur_ms"} <= set(rec)
+
+    def test_parents_self_time_and_step_inheritance(self):
+        """A span's parent is the span open on its thread when it began;
+        a child that names no step takes its parent's; another thread's
+        spans hang under what it attached."""
+        import threading
+        ticks = iter(range(100))
+        sink = []
+        t = obs.Tracer(enabled=True, clock=lambda: float(next(ticks)))
+        t.add_sink(sink.append)
+        with t.span("outer", step=7) as outer:
+            with t.span("a"):
+                pass
+            t.event("mark")
+            assert t.current() is outer
+
+            def other():
+                with t.attach(outer), t.span("b"):
+                    pass
+                assert t.current() is None
+            th = threading.Thread(target=other)
+            th.start()
+            th.join(timeout=30)
+            assert not th.is_alive()
+        by = {r["name"]: r for r in sink}
+        assert by["outer"]["parent"] is None
+        for child in ("a", "mark", "b"):
+            assert by[child]["parent"] == by["outer"]["id"]
+            assert by[child]["attrs"]["step"] == 7
+        assert by["b"]["thread"] != by["outer"]["thread"]
+        assert len({r["id"] for r in sink}) == len(sink)
+        # self time: the span's duration minus its children's
+        children = sum(r["dur_ms"] for r in sink
+                       if r["kind"] == "span"
+                       and r["parent"] == by["outer"]["id"])
+        assert by["outer"]["dur_ms"] - children > 0
+        assert by["a"]["start"] >= by["outer"]["start"]
+
+    def test_cancelled_span_leaves_no_record(self):
+        sink = []
+        t = obs.Tracer(enabled=True)
+        t.add_sink(sink.append)
+        with t.span("kept"):
+            t.span("dropped").cancel()
+            assert t.current().name == "kept"
+        assert [r["name"] for r in sink] == ["kept"]
 
     def test_wrap_iter_times_each_next(self):
         sink = []
@@ -193,6 +247,20 @@ class TestTracer:
         out = list(t.wrap_iter(iter([10, 20]), "data_wait"))
         assert out == [10, 20]
         assert [s["name"] for s in sink] == ["data_wait", "data_wait"]
+        # with a turn: each item's whole turn of the caller's loop is one
+        # span, its first child the wait; the exhausted probe leaves none
+        del sink[:]
+        n = [0]
+        for _ in t.wrap_iter(iter([10, 20]), "wait", turn="turn",
+                             step=lambda: n[0]):
+            with t.span("body"):
+                n[0] += 1
+        assert [s["name"] for s in sink] == ["wait", "body", "turn"] * 2
+        turns = [s for s in sink if s["name"] == "turn"]
+        assert [s["attrs"]["step"] for s in turns] == [0, 1]
+        for s in sink:
+            if s["name"] != "turn":
+                assert s["parent"] in {u["id"] for u in turns}
 
     def test_sink_errors_never_break_the_span(self):
         t = obs.Tracer(enabled=True)
@@ -214,38 +282,402 @@ class TestTracer:
 
 
 # ========================================================== fit phase spans
+def small_graph(seed=5):
+    from deeplearning4j_tpu.nn.conf.graph import GraphBuilder, MergeVertex
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+    parent = (NeuralNetConfiguration.builder().seed(seed)
+              .updater(Sgd(learning_rate=0.05)).weight_init("xavier"))
+    conf = (GraphBuilder(parent)
+            .add_inputs("in")
+            .add_layer("d1", DenseLayer(n_out=6, activation="relu"), "in")
+            .add_layer("d2", DenseLayer(n_out=6, activation="tanh"), "in")
+            .add_vertex("merge", MergeVertex(), "d1", "d2")
+            .add_layer("out", OutputLayer(n_out=3, loss="mcxent"), "merge")
+            .set_outputs("out")
+            .set_input_types(InputType.feed_forward(4))
+            .build())
+    return ComputationGraph(conf).init()
+
+
+def small_tbptt_net(seed=21):
+    from deeplearning4j_tpu.nn.conf.recurrent import LSTM, RnnOutputLayer
+    conf = (NeuralNetConfiguration.builder()
+            .seed(seed).updater(Sgd(learning_rate=0.05))
+            .weight_init("xavier").list()
+            .layer(LSTM(n_out=8, activation="tanh"))
+            .layer(RnnOutputLayer(n_out=3, loss="mcxent"))
+            .set_input_type(InputType.recurrent(4))
+            .backprop_type("tbptt", fwd_length=5, back_length=5)
+            .build())
+    return MultiLayerNetwork(conf).init()
+
+
+def sequence_batch(batch=3, steps=15, seed=5):
+    rng = np.random.default_rng(seed)
+    return (rng.random((batch, steps, 4)).astype(np.float32),
+            np.eye(3, dtype=np.float32)[rng.integers(0, 3, (batch, steps))])
+
+
+class _CountingListener:
+    """The least a listener is: makes ``train.listeners`` appear."""
+
+    def __init__(self):
+        self.calls = []
+
+    def iteration_done(self, model, iteration, epoch):
+        self.calls.append(iteration)
+
+    def on_epoch_start(self, model):
+        pass
+
+    def on_epoch_end(self, model):
+        pass
+
+
+def _traced(run):
+    """``run()`` with the global tracer on; the records it sank."""
+    sink = []
+    obs.configure_tracer(enabled=True)
+    obs.get_tracer().add_sink(sink.append)
+    try:
+        run()
+    finally:
+        obs.get_tracer().remove_sink(sink.append)
+        obs.configure_tracer(enabled=False)
+    return sink
+
+
+def _fit_mln():
+    net = small_net()
+    net.fit(toy_batches(3), num_epochs=2, prefetch=True)
+    return net
+
+
+def _fit_graph():
+    net = small_graph()
+    net.fit(toy_batches(3), num_epochs=2, prefetch=True)
+    return net
+
+
+def _fit_parallel_wrapper():
+    import jax
+    from deeplearning4j_tpu.parallel import ParallelWrapper
+    from deeplearning4j_tpu.parallel.mesh import make_mesh
+    net = small_net()
+    mesh = make_mesh(dp=1, tp=1, devices=jax.devices()[:1])
+    ParallelWrapper(net, mesh=mesh).fit(toy_batches(3), num_epochs=2,
+                                        prefetch=True)
+    return net
+
+
+def _fit_tbptt_fused():
+    net = small_tbptt_net()
+    for _ in range(6):
+        net.fit_tbptt_fused(*sequence_batch())
+    return net
+
+
+LOOPS = {"mln": (_fit_mln, 1, True), "graph": (_fit_graph, 1, True),
+         "parallel_wrapper": (_fit_parallel_wrapper, 1, True),
+         "tbptt_fused": (_fit_tbptt_fused, 3, False)}
+
+
 class TestFitPhaseBreakdown:
     def test_mln_fit_emits_phase_spans(self):
-        sink = []
-        obs.configure_tracer(enabled=True)
-        obs.get_tracer().add_sink(sink.append)
-        try:
-            net = small_net()
-            net.fit(toy_batches(3), num_epochs=2)
-        finally:
-            obs.get_tracer().remove_sink(sink.append)
+        sink = _traced(lambda: small_net().fit(toy_batches(3), num_epochs=2))
         names = [s["name"] for s in sink]
-        assert names.count("train.step_host") == 6
-        assert names.count("train.step_device") == 6
-        assert names.count("train.data_wait") == 6
+        for name in ("train.iteration", "train.data_wait", "train.step_host",
+                     "train.stage", "train.dispatch", "train.post"):
+            assert names.count(name) == 6, name
+        # no listener, no manager: their spans are absent, not empty
+        assert "train.listeners" not in names
+        assert "checkpoint.step_end" not in names
+        assert not [n for n in names if "device" in n]
         host = [s for s in sink if s["name"] == "train.step_host"]
-        assert all("step" in s["attrs"] for s in host)
+        assert [s["attrs"]["step"] for s in host] == list(range(6))
+        assert all(s["attrs"]["items"] == 16 for s in host)
+
+    @pytest.mark.parametrize("loop", sorted(LOOPS))
+    def test_every_loop_emits_the_same_span_tree(self, loop):
+        """Names, parents and one ``step`` a turn: the same tree out of
+        MultiLayerNetwork.fit, ComputationGraph.fit, ParallelWrapper.fit
+        (which had no span at all) and fit_tbptt_fused."""
+        run, steps_per_turn, streamed = LOOPS[loop]
+        sink = _traced(run)
+        spans = [s for s in sink if s["kind"] == "span"]
+        by_id = {s["id"]: s for s in spans}
+        turns = [s for s in spans if s["name"] == "train.iteration"]
+        assert len(turns) == 6 and all(t["parent"] is None for t in turns)
+        assert [t["attrs"]["step"] for t in turns] == [
+            i * steps_per_turn for i in range(6)]
+
+        def children(parent):
+            return [s["name"] for s in sorted(
+                (s for s in spans if s["parent"] == parent["id"]),
+                key=lambda s: s["start"])]
+
+        for turn in turns:
+            want = ["train.step_host"]
+            if streamed:
+                want.insert(0, "train.data_wait")
+            assert children(turn) == want
+            host = next(s for s in spans if s["parent"] == turn["id"]
+                        and s["name"] == "train.step_host")
+            inner = children(host)
+            # ParallelWrapper hands the batch over twice: its own sharding,
+            # then the model's (no-op) asarray
+            assert [n for i, n in enumerate(inner)
+                    if i == 0 or n != inner[i - 1]] == [
+                "train.stage", "train.dispatch", "train.post"], inner
+            dispatch = next(s for s in spans if s["parent"] == host["id"]
+                            and s["name"] == "train.dispatch")
+            assert dispatch["attrs"].get("steps", 1) == steps_per_turn
+        # every span of a turn carries that turn's step, and only that
+        for s in spans:
+            top = s
+            while top["parent"] is not None:
+                top = by_id[top["parent"]]
+            assert top["name"] == "train.iteration", s
+            assert s["attrs"]["step"] == top["attrs"]["step"], s
+        if streamed:
+            # batch N+1 is placed inside turn N's wait for batch N (the
+            # first wait places two, the last none)
+            places = [s for s in spans if s["name"] == "prefetch.place"]
+            assert len(places) == 6
+            assert all(by_id[p["parent"]]["name"] == "train.data_wait"
+                       and p["attrs"]["arrays"] == 2
+                       and p["attrs"]["bytes"] == 16 * (4 + 3) * 4
+                       for p in places)
+
+    def test_listeners_and_checkpoint_spans_sit_under_step_host(
+            self, tmp_path):
+        net = small_net()
+        listener = _CountingListener()
+        net.set_listeners(listener)
+        cm = CheckpointManager(str(tmp_path / "ck"), save_every_n_steps=2,
+                               async_write=False)
+        sink = _traced(lambda: net.fit(toy_batches(4),
+                                       checkpoint_manager=cm))
+        by_id = {s["id"]: s for s in sink}
+
+        def parent_name(s):
+            return by_id[s["parent"]]["name"]
+
+        assert listener.calls == [0, 1, 2, 3]
+        listeners = [s for s in sink if s["name"] == "train.listeners"]
+        ends = [s for s in sink if s["name"] == "checkpoint.step_end"]
+        snaps = [s for s in sink if s["name"] == "checkpoint.snapshot"]
+        assert len(listeners) == len(ends) == 4 and len(snaps) == 2
+        assert {parent_name(s) for s in listeners + ends} == {
+            "train.step_host"}
+        assert {parent_name(s) for s in snaps} == {"checkpoint.step_end"}
+        assert [s["attrs"]["step"] for s in snaps] == [1, 3]
+        assert all(s["attrs"]["bytes"] > 0 for s in snaps)
+
+    def test_per_window_tbptt_spans_carry_each_windows_step(self):
+        net = small_tbptt_net()
+        x, y = sequence_batch()
+        sink = _traced(lambda: net.fit(DataSet(x, y)))
+        dispatches = [s for s in sink if s["name"] == "train.dispatch"]
+        assert [(d["attrs"]["program"], d["attrs"]["step"])
+                for d in dispatches] == [("tbptt", 0), ("tbptt", 1),
+                                         ("tbptt", 2)]
+        assert [s["name"] for s in sink].count("train.step_host") == 1
+
+    def test_counters_at_the_span_boundaries(self):
+        reg = obs.get_registry()
+
+        def value(name):
+            m = reg.metric(name)
+            return m.value if m is not None else 0.0
+        before = {n: value(n) for n in ("train_steps_total",
+                                        "train_items_total",
+                                        "prefetch_bytes_total")}
+        small_net().fit(toy_batches(3), prefetch=True)   # tracer OFF
+        _fit_tbptt_fused()
+        assert value("train_steps_total") - before["train_steps_total"] \
+            == 3 + 6 * 3
+        assert value("train_items_total") - before["train_items_total"] \
+            == 3 * 16 + 6 * 3 * 3
+        assert value("prefetch_bytes_total") \
+            - before["prefetch_bytes_total"] == 3 * 16 * (4 + 3) * 4
+
+    def test_tracer_on_never_syncs_the_device(self, monkeypatch):
+        """The traced program is the program: a fit of 6 batches with the
+        tracer on never calls ``jax.block_until_ready``."""
+        import jax
+
+        def refuse(*a, **k):
+            raise AssertionError("fit synced the device for the tracer")
+        nets = [small_net(), small_graph()]
+        monkeypatch.setattr(jax, "block_until_ready", refuse)
+        sink = _traced(lambda: [net.fit(toy_batches(6)) for net in nets])
+        assert [s["name"] for s in sink].count("train.iteration") == 12
+
+    def test_recompile_is_an_event_at_its_step(self):
+        """A second batch shape mid-run: one more ``compile`` event, which
+        names the step, and ``compiled=1`` on that step's dispatch."""
+        net = small_net()
+        data = toy_batches(2) + toy_batches(1, batch=8) + toy_batches(1)
+        sink = _traced(lambda: net.fit(data))
+        compiles = [s for s in sink if s["name"] == "compile"]
+        assert [(c["attrs"]["program"], c["attrs"]["step"])
+                for c in compiles] == [("train", 0), ("train", 2)]
+        dispatches = [s for s in sink if s["name"] == "train.dispatch"]
+        assert [d["attrs"].get("compiled", 0) for d in dispatches] == [
+            1, 0, 1, 0]
+        assert all(c["parent"] == d["id"] for c, d in zip(
+            compiles, [dispatches[0], dispatches[2]]))
+        assert net.compile_watch.compiles("train") == 2
 
     def test_disabled_tracer_changes_nothing(self):
-        # identical parameter trajectory with tracing off and on: the
-        # spans are host-side only and never enter the traced program
+        # identical loss, parameters and iteration with tracing off and
+        # on: the spans are host-side only, never enter the traced program
+        # and the loop's body is the same code either way
         import jax
-        a, b = small_net(seed=5), small_net(seed=5)
-        data = toy_batches(2)
-        a.fit(data)
-        obs.configure_tracer(enabled=True)
+        for make in (small_net, small_graph):
+            a, b = make(seed=5), make(seed=5)
+            data = toy_batches(3)
+            a.fit(data, num_epochs=2)
+            _traced(lambda: b.fit(data, num_epochs=2))
+            assert a.iteration == b.iteration == 6
+            assert np.asarray(a.score()).tobytes() \
+                == np.asarray(b.score()).tobytes()
+            for la, lb in zip(jax.tree_util.tree_leaves(a.params),
+                              jax.tree_util.tree_leaves(b.params)):
+                np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
+
+    def test_spans_reach_a_profiler_trace_with_the_tracer_off(
+            self, tmp_path):
+        """Every span is a TraceAnnotation: a jax.profiler session sees
+        the fit loop's spans on the host plane, with their ``step`` stat,
+        with nothing switched on in the program."""
+        import glob
+        import jax
+        net = small_net()
+        net.fit(toy_batches(1))                      # compile outside
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
         try:
-            b.fit(data)
+            net.fit(toy_batches(3), prefetch=True)
         finally:
-            obs.configure_tracer(enabled=False)
-        for la, lb in zip(jax.tree_util.tree_leaves(a.params),
-                          jax.tree_util.tree_leaves(b.params)):
-            np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
+            jax.profiler.stop_trace()
+        path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                              / "*.xplane.pb"))
+        data = jax.profiler.ProfileData.from_file(path)
+        found = {}
+        for plane in data.planes:
+            if plane.name != "/host:CPU":
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith(("train.", "prefetch.")):
+                        found.setdefault(ev.name, []).append(
+                            dict(ev.stats))
+        for name in ("prefetch.place", "train.step_host", "train.stage",
+                     "train.dispatch", "train.post"):
+            assert len(found.get(name, [])) == 3, (name, sorted(found))
+        # an annotation cannot be taken back: the probe that found the
+        # stream exhausted is a fourth turn with a wait and no step_host
+        for name in ("train.iteration", "train.data_wait"):
+            assert len(found.get(name, [])) == 4, (name, sorted(found))
+        assert [st["step"] for st in found["train.dispatch"]] == [1, 2, 3]
+        assert found["train.dispatch"][0]["program"] == "train"
+
+
+class TestFusedTbptt:
+    def test_window_scores_keeps_every_windows_loss(self):
+        """The scan's per-window losses stay on the device; the last is
+        the score, and each equals the per-window path's."""
+        import jax
+        x, y = sequence_batch()
+        fused, seq = small_tbptt_net(), small_tbptt_net()
+        assert fused.window_scores() is None
+        fused.fit_tbptt_fused(x, y)
+        scores = fused.window_scores()
+        assert isinstance(scores, jax.Array) and scores.shape == (3,)
+        assert float(scores[-1]) == float(fused.score())
+        per_window = []
+
+        class Keep(_CountingListener):
+            def iteration_done(self, model, iteration, epoch):
+                per_window.append(float(model.score()))
+        seq.set_listeners(Keep())
+        seq.fit(DataSet(x, y))
+        np.testing.assert_array_equal(np.asarray(scores),
+                                      np.asarray(per_window, np.float32))
+
+
+# ======================================= names on the device side of a trace
+class TestDeviceSideNames:
+    def _conv_graph(self):
+        from deeplearning4j_tpu.nn.conf.graph import GraphBuilder
+        from deeplearning4j_tpu.nn.conf.convolutional import ConvolutionLayer
+        from deeplearning4j_tpu.nn.conf.normalization import (
+            BatchNormalization)
+        from deeplearning4j_tpu.nn.graph import ComputationGraph
+        parent = (NeuralNetConfiguration.builder().seed(3)
+                  .updater(Sgd(learning_rate=0.05)).weight_init("xavier"))
+        conf = (GraphBuilder(parent)
+                .add_inputs("in")
+                .add_layer("conv1", ConvolutionLayer(
+                    n_out=4, kernel_size=(3, 3), activation="identity"),
+                    "in")
+                .add_layer("bn1", BatchNormalization(activation="relu"),
+                           "conv1")
+                .add_layer("out", OutputLayer(n_out=3, loss="mcxent"), "bn1")
+                .set_outputs("out")
+                .set_input_types(InputType.convolutional(8, 8, 2))
+                .build())
+        return ComputationGraph(conf).init()
+
+    def test_step_hlo_names_its_layers_and_the_step_its_program(self):
+        """What a device trace shows of a ResNet50-shaped step: the
+        program under a stable name, every operation under its layer's
+        kind and name in ``op_name``."""
+        import jax.numpy as jnp
+        net = self._conv_graph()
+        rng = np.random.default_rng(0)
+        x = jnp.asarray(rng.standard_normal((4, 8, 8, 2)), jnp.float32)
+        y = jnp.asarray(np.eye(3, dtype=np.float32)[rng.integers(0, 3, 4)])
+        step = net._get_jitted("train")
+        lowered = step.lower(net.params, net.state, net.opt_state,
+                             net._rng, [x], [y], None, None)
+        assert "jit_train_step" in lowered.as_text().splitlines()[0]
+        hlo = lowered.compile().as_text()
+        ops = [l for l in hlo.splitlines() if "op_name=" in l]
+        for scope in ("ConvolutionLayer:conv1", "BatchNormalization:bn1"):
+            assert any(scope in l for l in ops), scope
+            # the backward pass of the layer carries the scope too
+            assert any(scope in l and "transpose" in l for l in ops), scope
+
+    @pytest.mark.parametrize("kind,name", [
+        ("train", "train_step"), ("output", "output"), ("score", "score")])
+    def test_jitted_programs_have_stable_names(self, kind, name):
+        for net in (small_net(), small_graph()):
+            fn = net._get_jitted(kind)
+            assert fn.__wrapped__.__name__ == name, (type(net), kind)
+
+    def test_scopes_do_not_change_the_numbers(self):
+        """Losses with the scopes are bitwise what they are without."""
+        import contextlib
+        import jax
+        from unittest import mock
+        data = [DataSet(np.random.default_rng(i).standard_normal(
+            (4, 8, 8, 2)).astype(np.float32),
+            np.eye(3, dtype=np.float32)[[0, 1, 2, 0]]) for i in range(3)]
+
+        def losses():
+            net, out = self._conv_graph(), []
+            for ds in data:
+                net.fit(ds)
+                out.append(np.asarray(net.score()).tobytes())
+            return out
+        scoped = losses()
+        with mock.patch.object(jax, "named_scope",
+                               lambda name: contextlib.nullcontext()):
+            assert losses() == scoped
 
 
 # ============================================================ serving + ckpt
@@ -427,10 +859,12 @@ class TestObsReport:
         t = obs.Tracer(enabled=True)
         t.add_sink(elog)
         for i in range(4):
-            with t.span("train.step_host", step=i):
-                pass
-            with t.span("train.step_device", step=i):
-                pass
+            with t.span("train.iteration", step=i):
+                with t.span("train.data_wait"):
+                    pass
+                with t.span("train.step_host", items=16):
+                    with t.span("train.dispatch", program="train"):
+                        pass
         t.event("elastic.generation_start", generation=1, world=2)
         elog.flush()
         return obs.read_event_log(store, "r.jsonl")
@@ -446,7 +880,9 @@ class TestObsReport:
                 "time": 1.0, "events": records[-3:]}
         text = obs_report.render_report(records, [dump], top=5)
         assert "Per-step phase breakdown" in text
-        assert "train.step_host" in text and "train.step_device" in text
+        for name in ("train.iteration", "train.data_wait",
+                     "train.step_host", "train.dispatch"):
+            assert name in text
         assert "Slowest spans" in text
         assert "Crash-ring tail — worker w9" in text
         assert "fault injection: kill" in text
@@ -522,8 +958,17 @@ class TestChaosPostMortem:
         for name in backend.list(prefix="events-"):
             records.extend(obs.read_event_log(backend, name))
         names = {r["name"] for r in records}
-        assert {"train.data_wait", "train.step_host",
-                "train.step_device"} <= names
+        assert {"train.iteration", "train.data_wait", "train.step_host",
+                "train.stage", "train.dispatch", "train.post",
+                "train.listeners", "checkpoint.step_end"} <= names
+        # the worker trains under a watchdog, on its worker thread: the
+        # step still hangs under its turn
+        by_id = {r["id"]: r for r in records if "id" in r}
+        hosts = [r for r in records if r["name"] == "train.step_host"]
+        assert hosts and all(
+            by_id[h["parent"]]["name"] == "train.iteration"
+            and by_id[h["parent"]]["thread"] != h["thread"] for h in hosts
+            if h["parent"] in by_id)
         pauses = [r for r in records
                   if r["name"] == "elastic.transition_pause"]
         assert pauses and pauses[0]["attrs"]["generation"] == 2
@@ -535,7 +980,8 @@ class TestChaosPostMortem:
         assert scrapes, "worker saved no /metrics scrape"
         txt = backend.get(scrapes[-1]).decode()
         assert "train_step_host_ms_bucket" in txt
-        assert "train_step_device_ms_count" in txt
+        assert "train_iteration_ms_count" in txt
+        assert "\ntrain_steps_total " in txt
         assert "elastic_transition_pause_ms_count 1" in txt
         assert "\nelastic_generation 2" in txt
 

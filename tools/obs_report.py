@@ -13,8 +13,12 @@ appearing in several inputs (the crash ring overlaps the event log when
 both came from the same process) are counted once.
 
 Sections:
-  - **Per-step phase breakdown** — the ``train.*`` spans (data-wait /
-    host / device) aggregated: count, total/mean/p50/p95/max ms;
+  - **Per-step phase breakdown** — the ``train.*`` spans of the fit
+    loops' tree (``train.iteration``: data-wait, then step_host: stage /
+    dispatch / post / listeners; obs/trace.py) aggregated: count,
+    total/mean/p50/p95/max ms. No span waits for the device:
+    ``train.iteration`` is the step time once the device is the
+    bottleneck;
   - **Span summary** — every span name aggregated the same way;
   - **Slowest spans** — the top-N individual spans with their attrs;
   - **Crash-ring tail** — the newest records of each flight dump, with
