@@ -642,17 +642,22 @@ class ComputationGraph:
 
     def _finish_step(self, tracer, loss, batch: int, sample=None):
         """What follows a dispatch: ``train.post`` (the score handle,
-        counters, ``sample()``: the slices listeners read activations
-        from, each a device program of its own), then ``train.listeners``,
-        then the iteration counter."""
+        counters and, only on an iteration some listener reads it
+        (``reads_features``), ``sample()``: the slices listeners read
+        activations from, each a device program of its own), then
+        ``train.listeners``, then the iteration counter."""
         from deeplearning4j_tpu.obs.registry import count_train_steps
+        from deeplearning4j_tpu.optimize.listeners import any_reads_features
         at = self.iteration
-        with tracer.span("train.post", step=at):
+        sampled = int(sample is not None
+                      and any_reads_features(self.listeners, at))
+        with tracer.span("train.post", step=at, sampled=sampled):
             self._score = loss
             self.last_batch_size = batch
-            if sample is not None:
-                self._last_features = sample()
-            count_train_steps(1, batch)
+            # None on every other turn: no stale sample of an earlier
+            # batch, no device program behind the step, nothing pinned
+            self._last_features = sample() if sampled else None
+            count_train_steps(1, batch, sampled)
         if self.listeners:
             with tracer.span("train.listeners", step=at):
                 for listener in self.listeners:

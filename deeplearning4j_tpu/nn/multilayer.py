@@ -993,24 +993,29 @@ class MultiLayerNetwork:
 
     def _finish_step(self, tracer, loss, batch: int, sample, steps: int = 1):
         """What follows a dispatch in every fit path: ``train.post`` (the
-        score handle, counters, ``sample()``: the slice listeners read
-        activations from, a device program of its own), then
+        score handle, counters and, only on an iteration some listener
+        reads it (``reads_features``), ``sample()``: the slice listeners
+        read activations from, a device program of its own), then
         ``train.listeners``, then the iteration counter. ``steps`` is the
         optimizer steps the dispatch ran (fused paths: the group)."""
         from deeplearning4j_tpu.obs.registry import count_train_steps
+        from deeplearning4j_tpu.optimize.listeners import any_reads_features
         step = self.iteration
-        with tracer.span("train.post", step=step):
+        last = step + steps - 1  # what iteration_done is told
+        sampled = int(any_reads_features(self.listeners, last))
+        with tracer.span("train.post", step=step, sampled=sampled):
             self._score = loss
             self.last_batch_size = batch
             # first sample only: listeners sample activations, and pinning
-            # the whole batch keeps large device buffers alive after fit()
-            self._last_features = sample()
-            count_train_steps(steps, steps * batch)
+            # the whole batch keeps large device buffers alive after fit().
+            # None on every other turn: no stale sample of an earlier
+            # batch, and no device program behind the step
+            self._last_features = sample() if sampled else None
+            count_train_steps(steps, steps * batch, sampled)
         if self.listeners:
             with tracer.span("train.listeners", step=step):
                 for listener in self.listeners:
-                    listener.iteration_done(self, step + steps - 1,
-                                            self.epoch)
+                    listener.iteration_done(self, last, self.epoch)
         self.iteration += steps
 
     def _make_tbptt_scan_step(self):

@@ -320,16 +320,22 @@ def get_registry() -> MetricsRegistry:
         return _global
 
 
-def count_train_steps(steps: int, items: int) -> None:
-    """The fit loops' two counters, bumped in ``train.post`` (obs/trace.py)
-    by every fit path: optimizer steps dispatched and items (rows of a
-    batch, once per step they were trained in) handed to them."""
+def count_train_steps(steps: int, items: int, samples: int = 0) -> None:
+    """The fit loops' counters, bumped in ``train.post`` (obs/trace.py) by
+    every fit path: optimizer steps dispatched, items (rows of a batch,
+    once per step they were trained in) handed to them, and feature
+    samples taken for a listener that reads them on this iteration
+    (``TrainingListener.reads_features``): 0 in a run with no such
+    listener."""
     reg = get_registry()
     reg.counter("train_steps_total", unit="steps",
                 help="optimizer steps the fit paths dispatched").inc(steps)
     reg.counter("train_items_total", unit="items",
                 help="batch rows the fit paths handed to optimizer steps"
                 ).inc(items)
+    reg.counter("train_feature_samples_total", unit="samples",
+                help="one-row feature samples the fit paths took for "
+                     "listeners that read activations").inc(samples)
 
 
 def _sanitize(name: str) -> str:

@@ -37,7 +37,8 @@ parallel/trainer.py, perf/prefetch.py, checkpoint/manager.py)::
       train.step_host               the loop's own work on its batch
         train.stage                 host->device hand-over of THIS batch
         train.dispatch              rng split + the jitted step's call
-        train.post                  score handle, counters, feature slice
+        train.post                  score handle, counters; sampled=1: the
+                                    feature slice a listener reads
         train.listeners             iteration_done of the listeners
         checkpoint.step_end         child checkpoint.snapshot on a save
 

@@ -34,6 +34,26 @@ class TrainingListener:
     def on_gradient_calculation(self, model):
         pass
 
+    def reads_features(self, iteration: int) -> bool:
+        """Whether ``iteration_done(model, iteration, ...)`` will read
+        ``model._last_features``, the one-row sample of the step's batch.
+        The fit loops take that sample (a device program behind the step)
+        only on the iterations some listener answers True for; on all
+        others ``_last_features`` is None."""
+        return False
+
+
+def any_reads_features(listeners, iteration: int) -> bool:
+    """The fit loops' question before ``train.post``: will some listener
+    read the feature sample on ``iteration``? A listener without
+    ``reads_features`` (duck-typed, not a ``TrainingListener``) reads
+    nothing."""
+    for listener in listeners:
+        reads = getattr(listener, "reads_features", None)
+        if reads is not None and reads(iteration):
+            return True
+    return False
+
 
 class ScoreIterationListener(TrainingListener):
     """Log score every N iterations (reference ScoreIterationListener.java)."""
@@ -381,8 +401,11 @@ class ConvolutionalIterationListener(TrainingListener):
                     out[name] = a[0]
         return dict(list(out.items())[: self.max_layers])
 
+    def reads_features(self, iteration):
+        return self._png_ok and iteration % self.frequency == 0
+
     def iteration_done(self, model, iteration, epoch):
-        if not self._png_ok or iteration % self.frequency != 0:
+        if not self.reads_features(iteration):
             return
         layers = {}
         for name, a in self._conv_activations(model).items():

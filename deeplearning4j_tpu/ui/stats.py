@@ -177,6 +177,9 @@ class StatsListener(TrainingListener):
         return group
 
     # ------------------------------------------------------------- listener
+    def reads_features(self, iteration: int) -> bool:
+        return self.collect_activations and iteration % self.frequency == 0
+
     def iteration_done(self, model, iteration: int, epoch: int):
         if not self._init_reported:
             self._report_init(model)
@@ -220,7 +223,7 @@ class StatsListener(TrainingListener):
                     / max(record["parameters"][k].get("mean_magnitude", 0.0), 1e-12))
                 for k in record.get("updates", {})
                 if "mean_magnitude" in record["updates"][k]}
-        if self.collect_activations:
+        if self.reads_features(iteration):
             acts = self._sample_activations(model)
             if acts:
                 record["activations"] = acts

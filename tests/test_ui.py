@@ -241,6 +241,29 @@ def test_conv_activations_module():
         server.stop()
 
 
+def test_listener_attached_after_fit_reads_nothing():
+    """A fit with no reading listener keeps no feature sample, so a listener
+    attached afterwards and called by hand finds none: the empty result,
+    not an exception."""
+    from deeplearning4j_tpu.optimize.listeners import (
+        ConvolutionalIterationListener,
+    )
+    net = small_net()
+    net.fit(toy_data())
+    assert net._last_features is None
+    storage = InMemoryStatsStorage()
+    conv = ConvolutionalIterationListener(storage, frequency=1,
+                                          session_id="late")
+    net.set_listeners(conv)
+    conv.iteration_done(net, 0, 0)
+    assert conv._conv_activations(net) == {}
+    assert storage.get_all_updates("late", "ActivationsListener") == []
+    stats = StatsListener(storage, session_id="late")
+    stats.iteration_done(net, 0, 0)
+    assert stats._sample_activations(net) is None
+    assert "activations" not in storage.get_all_updates("late", TYPE_ID)[-1]
+
+
 def test_inline_js_structural_contract():
     """No JS engine ships in this image, so validate the inline dashboard
     JS structurally: balanced brackets/template-literals outside string
