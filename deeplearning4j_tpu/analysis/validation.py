@@ -81,7 +81,9 @@ def _layer_name(i: Optional[int], layer) -> str:
 
 
 # layers where n_out == 0 is legal (width inferred from the input)
-_N_OUT_OPTIONAL = ("TransformerEncoderBlock",)
+_N_OUT_OPTIONAL = ("TransformerEncoderBlock", "KimiDeltaAttention",
+                   "MultiHeadLatentAttention", "GatedFeedForward",
+                   "RoutedExperts")
 
 
 def _check_layer(layer, cur, name: str) -> List[ValidationIssue]:
@@ -201,7 +203,12 @@ def _labels_shape_issue(out_layer, final_type, labels_shape,
     """Loss-vs-label shape compatibility for a concrete labels shape."""
     n_out = getattr(out_layer, "n_out", None) or final_type.flat_size()
     ls = tuple(int(d) for d in labels_shape)
-    if final_type.kind in ("rnn", "cnn1d"):
+    if getattr(out_layer, "sparse_labels", False):
+        # integer class ids: the output's shape without its class axis
+        rank = 2 if final_type.kind in ("rnn", "cnn1d") else 1
+        ok = len(ls) == rank
+        expected = "(batch, time) ids" if rank == 2 else "(batch,) ids"
+    elif final_type.kind in ("rnn", "cnn1d"):
         ok = len(ls) == 3 and ls[-1] == n_out
         expected = f"(batch, time, {n_out})"
     else:

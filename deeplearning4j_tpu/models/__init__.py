@@ -8,3 +8,4 @@ from deeplearning4j_tpu.models.darknet import Darknet19, TinyYOLO  # noqa: F401
 from deeplearning4j_tpu.models.textgenlstm import TextGenerationLSTM  # noqa: F401
 from deeplearning4j_tpu.models.googlenet import GoogLeNet  # noqa: F401
 from deeplearning4j_tpu.models.facenet import InceptionResNetV1, FaceNetNN4Small2  # noqa: F401
+from deeplearning4j_tpu.models.kimi_linear import KimiLinear  # noqa: F401

@@ -23,10 +23,12 @@ machinery — l1_bias/l2_bias regularization, bias constraints, weight noise
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from deeplearning4j_tpu.nn.activations import get_activation
 from deeplearning4j_tpu.nn.conf.inputs import InputType
@@ -232,4 +234,240 @@ class TransformerEncoderBlock(BaseLayer):
         return x, state
 
 
-__all__ = ["SelfAttentionLayer", "TransformerEncoderBlock"]
+# ------------------------------------------------- blocked causal attention
+def _pair_scores(q_i, k_j, scale):
+    return jnp.einsum("bhqd,bhkd->bhqk", q_i, k_j,
+                      preferred_element_type=jnp.float32) * scale
+
+
+def _diagonal_mask(block: int):
+    return jnp.tril(jnp.ones((block, block), bool))
+
+
+def _blocked_forward(q, k, v, block: int):
+    """(out, logsumexp) of causal softmax(q k^T / sqrt(d_q)) v, a block of
+    queries at a time against the key blocks at or before it; one
+    (block, block) score tile a head is alive at a time."""
+    bsz, h, t, dq = q.shape
+    scale = 1.0 / (dq ** 0.5)
+    nb = t // block
+    outs, lses = [], []
+
+    def tile(a, j):
+        return lax.dynamic_slice_in_dim(a, j * block, block, axis=2)
+
+    for i in range(nb):
+        q_i = q[:, :, i * block:(i + 1) * block]
+
+        def fold(carry, s, v_j):
+            m, l, acc = carry
+            m_new = jnp.maximum(m, jnp.max(s, -1))
+            p = jnp.exp(s - m_new[..., None])
+            fix = jnp.exp(m - m_new)
+            acc = acc * fix[..., None] + jnp.einsum(
+                "bhqk,bhkd->bhqd", p.astype(v_j.dtype), v_j,
+                preferred_element_type=jnp.float32)
+            return m_new, l * fix + jnp.sum(p, -1), acc
+
+        carry = (jnp.full((bsz, h, block), -jnp.inf, jnp.float32),
+                 jnp.zeros((bsz, h, block), jnp.float32),
+                 jnp.zeros((bsz, h, block, v.shape[-1]), jnp.float32))
+        # the diagonal tile first: every row has its own key, so the
+        # running maximum is finite from here on
+        s = jnp.where(_diagonal_mask(block), _pair_scores(q_i, tile(k, i),
+                                                          scale), -jnp.inf)
+        carry = fold(carry, s, tile(v, i))
+        if i:
+            carry = lax.fori_loop(
+                0, i, lambda j, c: fold(c, _pair_scores(q_i, tile(k, j),
+                                                        scale), tile(v, j)),
+                carry)
+        m, l, acc = carry
+        outs.append((acc / l[..., None]).astype(v.dtype))
+        lses.append(m + jnp.log(l))
+    return jnp.concatenate(outs, 2), jnp.concatenate(lses, 2)
+
+
+def _blocked_backward(q, k, v, out, lse, dout, block: int):
+    bsz, h, t, dq = q.shape
+    scale = 1.0 / (dq ** 0.5)
+    nb = t // block
+    delta = jnp.sum(out.astype(jnp.float32) * dout.astype(jnp.float32), -1)
+    dk = jnp.zeros(k.shape, jnp.float32)
+    dv = jnp.zeros(v.shape, jnp.float32)
+    dqs = []
+
+    def tile(a, j):
+        return lax.dynamic_slice_in_dim(a, j * block, block, axis=2)
+
+    def add_tile(a, j, upd):
+        cur = lax.dynamic_slice_in_dim(a, j * block, block, axis=2)
+        return lax.dynamic_update_slice_in_dim(a, cur + upd, j * block,
+                                               axis=2)
+
+    for i in range(nb):
+        sl = slice(i * block, (i + 1) * block)
+        q_i, do_i = q[:, :, sl], dout[:, :, sl]
+        lse_i, delta_i = lse[:, :, sl], delta[:, :, sl]
+
+        def pair(j, carry, masked):
+            dq_i, dk, dv = carry
+            k_j, v_j = tile(k, j), tile(v, j)
+            s = _pair_scores(q_i, k_j, scale)
+            if masked:
+                s = jnp.where(_diagonal_mask(block), s, -jnp.inf)
+            p = jnp.exp(s - lse_i[..., None])
+            dv = add_tile(dv, j, jnp.einsum(
+                "bhqk,bhqd->bhkd", p.astype(do_i.dtype), do_i,
+                preferred_element_type=jnp.float32))
+            dp = jnp.einsum("bhqd,bhkd->bhqk", do_i, v_j,
+                            preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta_i[..., None]) * scale).astype(q.dtype)
+            dq_i = dq_i + jnp.einsum("bhqk,bhkd->bhqd", ds, k_j,
+                                     preferred_element_type=jnp.float32)
+            dk = add_tile(dk, j, jnp.einsum(
+                "bhqk,bhqd->bhkd", ds, q_i,
+                preferred_element_type=jnp.float32))
+            return dq_i, dk, dv
+
+        carry = (jnp.zeros(q_i.shape, jnp.float32), dk, dv)
+        carry = pair(i, carry, True)
+        if i:
+            carry = lax.fori_loop(0, i, lambda j, c: pair(j, c, False),
+                                  carry)
+        dq_i, dk, dv = carry
+        dqs.append(dq_i)
+    return (jnp.concatenate(dqs, 2).astype(q.dtype), dk.astype(k.dtype),
+            dv.astype(v.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _blocked_attention(q, k, v, block):
+    return _blocked_forward(q, k, v, block)[0]
+
+
+def _blocked_attention_fwd(q, k, v, block):
+    out, lse = _blocked_forward(q, k, v, block)
+    return out, (q, k, v, out, lse)
+
+
+def _blocked_attention_bwd(block, res, dout):
+    return _blocked_backward(*res, dout, block)
+
+
+_blocked_attention.defvjp(_blocked_attention_fwd, _blocked_attention_bwd)
+
+
+def blocked_causal_attention(q, k, v, block: int = 512):
+    """Causal softmax(q k^T / sqrt(d_q)) v for ``q``, ``k`` (batch, heads,
+    time, d_q) and ``v`` (batch, heads, time, d_v), d_q and d_v free to
+    differ, without a (time, time) array: tiles of ``block`` x ``block``,
+    key tiles after the query tile skipped, an online softmax forward and a
+    backward pass that makes each tile's probabilities again from the saved
+    log-sum-exp (the flash-attention recipe in plain ``jax.numpy``).
+    ``time`` is padded up to a multiple of ``block``: padded keys lie after
+    every real query, and padded queries are cut off."""
+    t = q.shape[2]
+    block = min(block, t)
+    pad = (-t) % block
+    if pad:
+        q, k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0)))
+                   for a in (q, k, v))
+    out = _blocked_attention(q, k, v, block)
+    return out[:, :, :t] if pad else out
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class MultiHeadLatentAttention(BaseLayer):
+    """Multi-head latent attention without positional rotation (the
+    DeepSeek-V2 layout with ``mla_use_nope``): keys and values come from a
+    shared low-rank latent, ``[c; k_r] = W_kva x`` with ``c`` (``kv_rank``)
+    RMS-normalised; per head ``k = [W_kb^K c; k_r]`` (``k_r``, ``rope_dim``
+    wide, shared by the heads and NOT rotated), ``v = W_kb^V c``,
+    ``q = W_q x`` (``nope_dim + rope_dim``), causal attention, ``W_o`` over
+    heads x ``v_dim``. q/k heads and v heads differ in width, which the
+    Pallas flash kernel does not take: scores go through
+    ``blocked_causal_attention`` in tiles of ``block``. Which path a
+    compiled program took is counted at trace time (``bump_active``):
+    ``attention.mla_blocked`` with more than one tile,
+    ``attention.mla_single_tile`` otherwise. A features mask zeroes the
+    output at masked steps (right-padded batches are exact)."""
+
+    n_in: Optional[int] = None
+    n_out: int = 0              # model width; inferred from the input when 0
+    n_heads: int = 4
+    nope_dim: int = 32
+    rope_dim: int = 16
+    v_dim: int = 32
+    kv_rank: int = 64
+    block: int = 512
+    eps: float = 1e-5
+    weight_init: str = "xavier_fan_in"
+
+    supports_stateful = False
+
+    def input_kind(self):
+        return "rnn"
+
+    def is_recurrent(self):
+        return True
+
+    def regularizable(self):
+        return ("Wq", "Wkva", "Wkvb", "Wo")
+
+    def _width(self, it: InputType) -> int:
+        return self.n_out or self.n_in or it.size
+
+    def output_type(self, it: InputType) -> InputType:
+        return InputType.recurrent(self._width(it), it.timeseries_length)
+
+    def init(self, rng, it: InputType, dtype=jnp.float32):
+        d = self.n_in or it.size
+        h = self.n_heads
+        ks = jax.random.split(rng, 4)
+
+        def dense(key, n_in, n_out):
+            return init_weights(key, (n_in, n_out), n_in, n_out,
+                                self.weight_init, self.dist, dtype)
+
+        return {
+            "Wq": dense(ks[0], d, h * (self.nope_dim + self.rope_dim)),
+            "Wkva": dense(ks[1], d, self.kv_rank + self.rope_dim),
+            "kv_norm": jnp.ones((self.kv_rank,), dtype),
+            "Wkvb": dense(ks[2], self.kv_rank,
+                          h * (self.nope_dim + self.v_dim)),
+            "Wo": dense(ks[3], h * self.v_dim, self._width(it)),
+        }, {}
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        from deeplearning4j_tpu.nn.conf.normalization import rms_norm
+        from deeplearning4j_tpu.perf.compile_watch import bump_active
+
+        x = dropout_input(x, self.dropout, train, rng)
+        bsz, t, _ = x.shape
+        h, nope, rope = self.n_heads, self.nope_dim, self.rope_dim
+        q = (x @ params["Wq"]).reshape(bsz, t, h, nope + rope)
+        kva = x @ params["Wkva"]
+        c = rms_norm(kva[..., :self.kv_rank], params["kv_norm"], self.eps)
+        k_r = kva[..., self.kv_rank:]
+        kvb = (c @ params["Wkvb"]).reshape(bsz, t, h, nope + self.v_dim)
+        k = jnp.concatenate(
+            [kvb[..., :nope],
+             jnp.broadcast_to(k_r[:, :, None, :], (bsz, t, h, rope))], -1)
+        v = kvb[..., nope:]
+        bump_active("attention.mla_blocked" if t > self.block
+                    else "attention.mla_single_tile")
+        with jax.named_scope("mla.attend"):
+            o = blocked_causal_attention(
+                q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                v.transpose(0, 2, 1, 3), self.block)
+        out = o.transpose(0, 2, 1, 3).reshape(bsz, t, h * self.v_dim) \
+            @ params["Wo"]
+        if mask is not None:             # masked steps emit zeros
+            out = out * mask[..., None].astype(out.dtype)
+        return out, state
+
+
+__all__ = ["SelfAttentionLayer", "TransformerEncoderBlock",
+           "MultiHeadLatentAttention", "blocked_causal_attention"]

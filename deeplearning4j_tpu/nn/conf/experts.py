@@ -1,0 +1,296 @@
+"""Gated feed-forward layers: a dense SwiGLU and a routed mixture of experts
+that computes ITS SHARE of an expert-parallel deployment.
+
+Not in the 0.9.x reference line. ``RoutedExperts`` follows the
+DeepSeek-V3 / Kimi family's router: ``s = sigmoid(W_r x)`` over ALL
+``n_experts`` published experts, top-``top_k`` of ``s + bias``, weights
+``scaling * s_i / sum_topk s_j``, and
+
+    y = sum over chosen experts HELD HERE of w_i E_i(x) + E_shared(x),
+    E(x) = W_down(SiLU(W_gate x) * W_up x)
+
+The layer is told which experts it holds (``experts_held`` of them from
+``expert_offset``). What the absent experts would add is left out: on one
+chip the layer runs without the exchange, and the partial sum goes on. No
+(token, expert) pair that falls on a held expert is ever dropped, at any
+imbalance: the pairs are sorted by expert and the three products run as
+GROUPED matrix products over the rows each expert really got
+(``grouped_matmul``: the Pallas ``megablox`` kernel on TPU, whose grid
+follows the load, and ``lax.ragged_dot`` elsewhere). The sorted slots are
+computed a window at a time (an eighth of the worst case of tokens x top_k
+slots, four times the even share of a 1/32 deployment): one window when
+the held pairs fit it, all of them under a ``lax.cond`` when they do not,
+so that work, traffic and memory follow the load and a skewed batch is
+slower, never wrong. The selection bias and the load counters live in the layer's
+non-trained ``state``: ``bias`` (frozen; its load-driven update is not part
+of any published config), ``expert_tokens`` (pairs each held expert got,
+summed over steps), ``pairs_held`` and ``pairs_dropped`` (must stay 0).
+They are device arrays updated inside the step; ``obs.registry.watch_moe``
+reads them at scrape time only.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from deeplearning4j_tpu.nn.conf.inputs import InputType
+from deeplearning4j_tpu.nn.conf.layers import (
+    BaseLayer, dropout_input, register_layer,
+)
+from deeplearning4j_tpu.nn.initializers import init_weights
+
+GMM_ROW_TILE = 128
+
+
+def _swiglu(x, w_gate, w_up, w_down):
+    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class GatedFeedForward(BaseLayer):
+    """SwiGLU feed-forward over the feature axis:
+    ``W_down(SiLU(W_gate x) * W_up x)``, no biases. ``n_out`` (the model
+    width) is inferred from the input when 0; ``ff_size`` is the inner
+    width."""
+
+    n_in: Optional[int] = None
+    n_out: int = 0
+    ff_size: int = 0
+    weight_init: str = "xavier_fan_in"
+
+    def regularizable(self):
+        return ("Wgate", "Wup", "Wdown")
+
+    def _width(self, it: InputType) -> int:
+        return self.n_out or self.n_in or it.flat_size()
+
+    def output_type(self, it: InputType) -> InputType:
+        if it.kind == "rnn":
+            return InputType.recurrent(self._width(it), it.timeseries_length)
+        return InputType.feed_forward(self._width(it))
+
+    def init(self, rng, it: InputType, dtype=jnp.float32):
+        d = self.n_in or it.flat_size()
+        ff = self.ff_size or 4 * d
+        ks = jax.random.split(rng, 3)
+        return {
+            "Wgate": init_weights(ks[0], (d, ff), d, ff, self.weight_init,
+                                  self.dist, dtype),
+            "Wup": init_weights(ks[1], (d, ff), d, ff, self.weight_init,
+                                self.dist, dtype),
+            "Wdown": init_weights(ks[2], (ff, self._width(it)), ff,
+                                  self._width(it), self.weight_init,
+                                  self.dist, dtype),
+        }, {}
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        x = dropout_input(x, self.dropout, train, rng)
+        return _swiglu(x, params["Wgate"], params["Wup"],
+                       params["Wdown"]), state
+
+
+# ----------------------------------------------------------- grouped products
+def _gmm_tiling(m: int, k: int, n: int):
+    """Tiles for the megablox kernel: the whole contraction in one tile
+    where it is an expert's width or the model's (so that an expert's
+    matrix passes through VMEM once a row tile), 128 rows."""
+    def fit(x, most):
+        for t in (most, 1024, 768, 512, 384, 256, 128):
+            if t <= most and x % t == 0:
+                return t
+        return 128
+    return GMM_ROW_TILE, fit(k, 1152), fit(n, 512)
+
+
+def grouped_matmul(rows, weights, group_sizes):
+    """``rows[start_g : start_g + group_sizes[g]] @ weights[g]`` for every
+    group g, the groups lying one after another from row 0; rows past the
+    last group come back as zeros. ``rows`` (m, k), ``weights`` (groups, k,
+    n), ``group_sizes`` (groups,) int32. Differentiable in ``rows`` and
+    ``weights``.
+
+    Two paths, because ``lax.ragged_dot`` alone does not do on the TPU: it
+    leaves the rows past the last group unwritten there (NaN on the v5e;
+    zeros on the CPU), and at the benchmark's load its backward pass is
+    slower than the kernel's (PERF.md section 6, PR 26: the three products
+    of a window of 8192 rows x 2304 with 2,048 rows in 8 groups, forward
+    and backward, 2.07 ms against 1.68; 3.47 against 2.90 at 4,400 rows;
+    only a full window is faster, 3.56 against 3.97)."""
+    if jax.default_backend() == "tpu":
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+        # one more (empty-weight) group for the rows past the last: the
+        # kernel then zeroes what it did not write
+        sizes = jnp.concatenate(
+            [group_sizes, (rows.shape[0] - jnp.sum(group_sizes))[None]])
+        return gmm(rows, weights, sizes.astype(jnp.int32), rows.dtype,
+                   _gmm_tiling)
+    return lax.ragged_dot(rows, weights, group_sizes.astype(jnp.int32))
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class RoutedExperts(BaseLayer):
+    """This chip's experts of a routed mixture plus the shared expert (see
+    the module docstring). ``n_experts`` is the router's width (all
+    published experts), ``experts_held`` how many live here, from
+    ``expert_offset``. ``shared_size`` 0 leaves the shared expert out (the
+    other shares of a test that counts it once)."""
+
+    n_in: Optional[int] = None
+    n_out: int = 0
+    n_experts: int = 8
+    experts_held: int = 8
+    expert_offset: int = 0
+    top_k: int = 2
+    expert_size: int = 0
+    shared_size: int = 0
+    scaling: float = 1.0
+    weight_init: str = "xavier_fan_in"
+
+    def regularizable(self):
+        return ("Wr", "Wgate", "Wup", "Wdown", "Sgate", "Sup", "Sdown")
+
+    def _width(self, it: InputType) -> int:
+        return self.n_out or self.n_in or it.flat_size()
+
+    def output_type(self, it: InputType) -> InputType:
+        if self.expert_offset + self.experts_held > self.n_experts:
+            raise ValueError(
+                f"experts {self.expert_offset}..{self.expert_offset + self.experts_held}"
+                f" held, but the router has {self.n_experts}")
+        if it.kind == "rnn":
+            return InputType.recurrent(self._width(it), it.timeseries_length)
+        return InputType.feed_forward(self._width(it))
+
+    def init(self, rng, it: InputType, dtype=jnp.float32):
+        d = self.n_in or it.flat_size()
+        width = self._width(it)
+        ff = self.expert_size or d
+        e = self.experts_held
+        ks = jax.random.split(rng, 7)
+
+        def w(key, shape):
+            return init_weights(key, shape, shape[-2], shape[-1],
+                                self.weight_init, self.dist, dtype)
+
+        params = {"Wr": w(ks[0], (d, self.n_experts)),
+                  "Wgate": w(ks[1], (e, d, ff)), "Wup": w(ks[2], (e, d, ff)),
+                  "Wdown": w(ks[3], (e, ff, width))}
+        if self.shared_size:
+            params.update({"Sgate": w(ks[4], (d, self.shared_size)),
+                           "Sup": w(ks[5], (d, self.shared_size)),
+                           "Sdown": w(ks[6], (self.shared_size, width))})
+        state = {"bias": jnp.zeros((self.n_experts,), jnp.float32),
+                 "expert_tokens": jnp.zeros((e,), jnp.int32),
+                 "pairs_held": jnp.zeros((), jnp.int32),
+                 "pairs_dropped": jnp.zeros((), jnp.int32)}
+        return params, state
+
+    def route(self, x, w_r, bias):
+        """(weights, expert ids), both (tokens, top_k), over all experts."""
+        s = jax.nn.sigmoid((x @ w_r).astype(jnp.float32))
+        _, idx = lax.top_k(s + bias, self.top_k)
+        chosen = jnp.take_along_axis(s, idx, -1)
+        return (self.scaling * chosen
+                / jnp.sum(chosen, -1, keepdims=True)), idx
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        x = dropout_input(x, self.dropout, train, rng)
+        shape = x.shape
+        xf = x.reshape(-1, shape[-1])
+        n, k, e = xf.shape[0], self.top_k, self.experts_held
+        with jax.named_scope("moe.route"):
+            w, idx = self.route(xf, params["Wr"], state["bias"])
+        with jax.named_scope("moe.dispatch"):
+            local = idx - self.expert_offset
+            held = (local >= 0) & (local < e)
+            key = jnp.where(held, local, e).reshape(-1)       # (n * k,)
+            order = jnp.argsort(key, stable=True)     # sorted slot -> pair
+            sizes = jnp.sum(jax.nn.one_hot(key, e + 1, dtype=jnp.int32),
+                            0)[:e]
+            n_held = jnp.sum(sizes)
+        # the sorted slots are computed ``window`` at a time: an eighth of
+        # the worst case, four times the even share of a 1/32 deployment.
+        # The usual load fits the first window; a skewed batch takes as
+        # many as it needs, up to the worst case (tokens x top_k slots)
+        window = -(-n * k // (8 * GMM_ROW_TILE)) * GMM_ROW_TILE
+        windows = -(-n * k // window)
+        if windows * window > n * k:
+            order = jnp.pad(order, (0, windows * window - n * k))
+        ends = jnp.cumsum(sizes)
+
+        @jax.checkpoint
+        def run(start, xf, experts, order, w, sizes, ends):
+            """The sorted slots ``start .. start + window``: their tokens'
+            rows gathered, the three grouped products over the part of
+            each expert's rows that lies in the window, and the results
+            added back to their tokens with the router's weights. Gather
+            one way, scatter-add the other, over ``window`` rows."""
+            with jax.named_scope("moe.dispatch"):
+                slots = lax.dynamic_slice_in_dim(order, start, window)
+                token = slots // k
+                rows = xf[token]
+                stop = start + window
+                here = (jnp.clip(ends, start, stop)
+                        - jnp.clip(ends - sizes, start, stop))
+            with jax.named_scope("moe.experts"):
+                hidden = (jax.nn.silu(grouped_matmul(rows, experts["Wgate"],
+                                                     here))
+                          * grouped_matmul(rows, experts["Wup"], here))
+                y_rows = grouped_matmul(hidden, experts["Wdown"], here)
+            with jax.named_scope("moe.dispatch"):
+                by_slot = jnp.where(start + jnp.arange(window) < ends[-1],
+                                    w.reshape(-1)[slots], 0.0)
+                y = jnp.zeros((n, y_rows.shape[-1]), jnp.float32).at[
+                    token].add(y_rows.astype(jnp.float32)
+                               * by_slot[:, None])
+            # the rows the grouped products were given: what this window
+            # covered of the held pairs
+            return y, jnp.sum(here)
+
+        experts = {name: params[name] for name in ("Wgate", "Wup", "Wdown")}
+        operands = (xf, experts, order, w, sizes, ends)
+
+        def first_window(*ops):
+            return run(0, *ops)
+
+        def every_window(*ops):
+            """A skewed batch: every window in turn. A window past the
+            held pairs has empty groups (the kernel's grid is then empty)
+            and adds zeros; skipping it under a second ``lax.cond`` would
+            make the scan keep its operands once a window for the
+            backward pass (3.7 GB at the benchmark's sizes)."""
+            def step(carry, i):
+                y, rows = run(i * window, *ops)
+                return (carry[0] + y, carry[1] + rows), None
+            carry, _ = lax.scan(step, first_window(*ops),
+                                jnp.arange(1, windows))
+            return carry
+
+        if windows > 1:
+            y, covered = lax.cond(n_held <= window, first_window,
+                                  every_window, *operands)
+        else:
+            y, covered = first_window(*operands)
+        y = y.astype(x.dtype)
+        if self.shared_size:
+            with jax.named_scope("moe.shared"):
+                y = y + _swiglu(xf, params["Sgate"], params["Sup"],
+                                params["Sdown"])
+        # ``covered`` counts the rows that the windows which RAN handed to
+        # the grouped products; a held pair outside them was dropped
+        new_state = {"bias": state["bias"],
+                     "expert_tokens": state["expert_tokens"] + sizes,
+                     "pairs_held": state["pairs_held"] + n_held,
+                     "pairs_dropped": state["pairs_dropped"]
+                     + (n_held - covered)}
+        return y.reshape(shape[:-1] + (y.shape[-1],)), new_state
+
+
+__all__ = ["GatedFeedForward", "RoutedExperts", "grouped_matmul"]

@@ -1,0 +1,322 @@
+"""Linear attention as a registered layer: Kimi Delta Attention (KDA).
+
+Not in the 0.9.x reference line (it predates attention altogether); the
+layer follows Kimi Linear (arXiv:2510.26692): a gated delta rule whose
+decay is per CHANNEL. Per head, with d_k = d_v = ``head_dim``:
+
+    q = L2norm(SiLU(conv(W_q x))) / sqrt(d_k),   k = L2norm(SiLU(conv(W_k x)))
+    v = SiLU(conv(W_v x))                   (causal depthwise convolution)
+    g_t = -exp(A_log) * softplus(W_f2 W_f1 x + dt_bias),   a_t = exp(g_t)
+    b_t = sigmoid(W_b x)                                (a scalar per head)
+    S_t = (I - b_t k_t k_t^T) Diag(a_t) S_{t-1} + b_t k_t v_t^T,  o_t = S_t^T q_t
+    out = W_o (RMSNorm_head(o_t) * sigmoid(W_g2 W_g1 x))
+
+TPU-native: the recurrence is never run token by token. ``chunked_kda``
+cuts time into chunks of ``chunk`` steps. Inside a chunk everything is
+matrix products: with G the running sum of g inside the chunk, the delta
+rule's "pseudo values" U solve a unit-triangular system
+``(I + A) U = b (V - (K exp(G)) S_0)`` with
+``A[r, i] = b_r sum_c k_r[c] k_i[c] exp(G_r[c] - G_i[c])`` (i < r; solved
+by forward substitution over blocks of ``sub`` rows, ``chunk / sub``
+dependent steps of matrix products: only the ``sub`` x ``sub`` diagonal
+blocks are inverted outright, through their finite Neumann series; an
+explicit inverse of the whole chunk overflows float32 where the keys are
+alike), and the output is ``(Q exp(G)) S_0 + P U`` with P the same decayed
+product of q and k (i <= r). Only the chunk states S_0 are carried, by one
+``lax.scan`` over groups of chunks, in float32. The decayed products are
+exact at ANY decay: blocks of
+``sub`` x ``sub`` on the diagonal are written out channel by channel
+(``exp(G_r - G_i)`` itself, never a quotient of two exponentials), blocks
+below it go through the MXU around a reference row at which both factors
+are at most 1. Plain ``jax.numpy`` / ``lax``; XLA writes the backward pass.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from deeplearning4j_tpu.nn.conf.inputs import InputType
+from deeplearning4j_tpu.nn.conf.layers import (
+    BaseLayer, dropout_input, register_layer,
+)
+from deeplearning4j_tpu.nn.conf.normalization import rms_norm
+from deeplearning4j_tpu.nn.initializers import init_weights
+
+
+def causal_depthwise_conv(x, w):
+    """``x`` (batch, time, channels), ``w`` (taps, channels):
+    y_t = sum_j w[j] x_{t - (taps - 1) + j}, zeros before the start."""
+    taps, t = w.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    out = padded[:, 0:t] * w[0]
+    for j in range(1, taps):
+        out = out + padded[:, j:j + t] * w[j]
+    return out
+
+
+def _l2norm(x, eps: float = 1e-6):
+    x32 = x.astype(jnp.float32)
+    return x32 * lax.rsqrt(jnp.sum(jnp.square(x32), -1, keepdims=True) + eps)
+
+
+def _decayed_scores(xs, k, g_cum, sub: int):
+    """For every x in the stack ``xs`` (n, ..., C, K):
+    M[r, i] = sum_c x[r, c] k[i, c] exp(G[r, c] - G[i, c]) for i <= r and
+    0 above the diagonal. ``k``, ``g_cum`` (..., C, K); G is non-increasing
+    along C, so every exponent that is taken is <= 0. The decay factors
+    are made once for the whole stack."""
+    *lead, c, kd = k.shape
+    ns = c // sub
+    xs_b = xs.reshape(xs.shape[0], *lead, ns, sub, kd)
+    ks = k.reshape(*lead, ns, sub, kd)
+    gs = g_cum.reshape(*lead, ns, sub, kd)
+    # diagonal blocks, channel by channel
+    dg = gs[..., :, None, :] - gs[..., None, :, :]
+    low = jnp.tril(jnp.ones((sub, sub), bool))[..., None]
+    k_decayed = ks[..., None, :, :] * jnp.exp(jnp.where(low, dg, -jnp.inf))
+    diag = jnp.sum(xs_b[..., :, None, :] * k_decayed, -1)
+    # blocks below the diagonal: around the sub-block's first row R both
+    # exp(G_r - R) and exp(R - G_i) are <= 1 (i lies before the sub-block)
+    ref = gs[..., :, :1, :]
+    xd = xs_b * jnp.exp(gs - ref)
+    kd_all = k[..., None, :, :] * jnp.exp(
+        jnp.minimum(ref - g_cum[..., None, :, :], 0.0))
+    off = jnp.einsum("n...src,...sic->n...sri", xd, kd_all)
+    before = (jnp.arange(c)[None, :] < (jnp.arange(ns) * sub)[:, None])
+    off = jnp.where(before[:, None, :], off, 0.0)
+    eye = jnp.eye(ns, dtype=diag.dtype)
+    placed = jnp.einsum("...srj,st->...srtj", diag, eye)
+    full = off + placed.reshape(off.shape)
+    return full.reshape(xs.shape[0], *lead, c, c)
+
+
+def _unit_lower_inverse(a):
+    """(I + a)^-1 for a strictly lower-triangular ``a`` (..., n, n), n a
+    power of two: a is nilpotent (a^n = 0), so the Neumann series
+    sum_m (-a)^m ends and factors into log2(n) products,
+    (I - a)(I + a^2)(I + a^4)...: batched matrix products where a
+    triangular solve is n dependent steps. The inverse's entries can grow
+    like 2^n where the rows of ``a`` are alike (keys that point the same
+    way), so this is for SMALL n only: see ``_solve_unit_lower``."""
+    n = a.shape[-1]
+    hi = lax.Precision.HIGHEST
+    inv, power = jnp.eye(n, dtype=a.dtype) - a, a
+    for _ in range(max(n.bit_length() - 2, 0)):
+        power = jnp.matmul(power, power, precision=hi)
+        inv = inv + jnp.matmul(inv, power, precision=hi)
+    return inv
+
+
+def _solve_unit_lower(a, rhs, block: int):
+    """x with (I + a) x = rhs for a strictly lower-triangular ``a``
+    (..., C, C), by forward substitution over blocks of ``block`` rows:
+    x_s = (I + a_ss)^-1 (rhs_s - a[s, :s] x[:s]). Only the small diagonal
+    blocks are inverted outright (all at once); across blocks every x_s
+    comes from the x before it, as in the recurrence itself, which keeps
+    the solve as stable as the recurrence at any likeness of the keys
+    (an explicit 64 x 64 inverse overflows float32 there). C / block
+    dependent steps of matrix products."""
+    c = a.shape[-1]
+    nb = c // block
+    hi = lax.Precision.HIGHEST
+    inv = _unit_lower_inverse(jnp.stack(
+        [a[..., s * block:(s + 1) * block, s * block:(s + 1) * block]
+         for s in range(nb)], -3))                    # (..., nb, b, b)
+    out = []
+    for s in range(nb):
+        r = rhs[..., s * block:(s + 1) * block, :]
+        if s:
+            r = r - jnp.matmul(a[..., s * block:(s + 1) * block, :s * block],
+                               jnp.concatenate(out, -2), precision=hi)
+        out.append(jnp.matmul(inv[..., s, :, :], r, precision=hi))
+    return jnp.concatenate(out, -2)
+
+
+def _chunk_terms(qc, kc, vc, gc, bc, sub: int):
+    """Everything of a chunk that does not need the carried state. Inputs
+    (..., C, K|V) and ``bc`` (..., C); float32."""
+    c = qc.shape[-2]
+    g_cum = jnp.cumsum(gc, axis=-2)
+    p, a = _decayed_scores(jnp.stack([qc, kc]), kc, g_cum, sub)
+    a = jnp.where(jnp.tril(jnp.ones((c, c), bool), -1),
+                  a * bc[..., :, None], 0.0)
+    gamma = jnp.exp(g_cum)
+    rhs = jnp.concatenate([kc * gamma, vc], -1) * bc[..., :, None]
+    sol = _solve_unit_lower(a, rhs, sub)
+    kdim = kc.shape[-1]
+    w, uv = sol[..., :kdim], sol[..., kdim:]
+    g_end = g_cum[..., -1:, :]
+    return (p, w, uv, qc * gamma, kc * jnp.exp(g_end - g_cum),
+            jnp.exp(g_end[..., 0, :]))
+
+
+def chunked_kda(q, k, v, g, b, chunk: int = 64, sub: int = 8,
+                group: int = 2):
+    """The KDA recurrence from a zero state, chunk-wise. ``q``, ``k``,
+    ``g`` (batch, time, heads, K), ``v`` (batch, time, heads, V), ``b``
+    (batch, time, heads), in any float type (a group is cast to float32
+    as it is taken up); returns o (batch, time, heads, V) in float32.
+    ``time`` need not be a multiple of ``chunk`` (steps with k = 0, b = 0,
+    g = 0 are appended: they leave the state as it is). One ``lax.scan``
+    runs over groups of ``group`` chunks under ``jax.checkpoint``: a
+    group's state-free terms (the channel-by-channel diagonal blocks are
+    chunks x heads x sub^2 x K numbers) are made, used for its chunks'
+    state updates and dropped, forward and backward, and the backward
+    pass keeps one state a group. ``sub`` is the block of both the
+    written-out diagonal and the forward substitution: 8 keeps the solve
+    within float32 rounding of the recurrence at any likeness of the keys
+    (16 reads 1e-3 off, 32 overflows)."""
+    bsz, t, h, kdim = q.shape
+    vdim = v.shape[-1]
+    if chunk % sub or chunk & (chunk - 1):
+        raise ValueError(f"chunk {chunk} has to be a power of two and a "
+                         f"multiple of sub {sub}")
+    pad = (-t) % chunk
+    n = (t + pad) // chunk
+
+    def chunks(a):                       # (B, T, H, X) -> (N, B, H, C, X)
+        if pad:
+            a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        a = a.reshape((bsz, n, chunk) + a.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(a, 2, -2 if a.ndim == 5 else -1),
+                            1, 0)
+
+    qc, kc, vc, gc = chunks(q), chunks(k), chunks(v), chunks(g)
+    bc = chunks(b)                       # (N, B, H, C)
+    per = max(d for d in range(1, min(group, n) + 1) if n % d == 0)
+
+    def grouped(a):                      # (N, ...) -> (N / per, per, ...)
+        return a.reshape((n // per, per) + a.shape[1:])
+
+    def step(s, terms):
+        p, w, uv, qd, kd, decay = terms
+        u = uv - jnp.matmul(w, s)
+        o = jnp.matmul(qd, s) + jnp.matmul(p, u)
+        s = decay[..., :, None] * s + jnp.matmul(jnp.swapaxes(kd, -1, -2), u)
+        return s, o
+
+    def group_step(s, xs):
+        """``per`` chunks: their state-free terms, then the state through
+        them one chunk after another."""
+        terms = _chunk_terms(*(a.astype(jnp.float32) for a in xs), sub=sub)
+        outs = []
+        for i in range(per):
+            s, o = step(s, tuple(a[i] for a in terms))
+            outs.append(o)
+        return s, jnp.stack(outs)
+
+    s0 = jnp.zeros((bsz, h, kdim, vdim), jnp.float32)
+    _, o = lax.scan(jax.checkpoint(group_step), s0,
+                    tuple(grouped(a) for a in (qc, kc, vc, gc, bc)))
+    o = o.reshape((n,) + o.shape[2:])    # (N, B, H, C, V)
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)   # (B, N, C, H, V)
+    return o.reshape(bsz, n * chunk, h, vdim)[:, :t]
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class KimiDeltaAttention(BaseLayer):
+    """Kimi Delta Attention over (batch, time, features): width-preserving
+    token mixing with a (heads, head_dim, head_dim) state instead of a
+    cache. ``low_rank`` is the inner width of the decay and output-gate
+    projections (0: ``head_dim``). A features mask zeroes the output at
+    masked steps; the recurrence itself still runs over them (right-padded
+    batches are exact, masks inside a sequence are not supported)."""
+
+    n_in: Optional[int] = None
+    n_out: int = 0              # model width; inferred from the input when 0
+    n_heads: int = 4
+    head_dim: int = 64
+    conv_size: int = 4
+    low_rank: int = 0
+    chunk: int = 64
+    eps: float = 1e-5
+    weight_init: str = "xavier_fan_in"
+
+    supports_stateful = False   # no rnn_time_step carry (yet)
+
+    def input_kind(self):
+        return "rnn"
+
+    def is_recurrent(self):
+        return True
+
+    def regularizable(self):
+        return ("Wq", "Wk", "Wv", "Wf1", "Wf2", "Wb", "Wg1", "Wg2", "Wo")
+
+    def _width(self, it: InputType) -> int:
+        return self.n_out or self.n_in or it.size
+
+    def output_type(self, it: InputType) -> InputType:
+        return InputType.recurrent(self._width(it), it.timeseries_length)
+
+    def init(self, rng, it: InputType, dtype=jnp.float32):
+        d = self.n_in or it.size
+        width = self._width(it)
+        inner = self.n_heads * self.head_dim
+        r = self.low_rank or self.head_dim
+        keys = iter(jax.random.split(rng, 16))
+
+        def dense(n_in, n_out):
+            return init_weights(next(keys), (n_in, n_out), n_in, n_out,
+                                self.weight_init, self.dist, dtype)
+
+        p = {"Wq": dense(d, inner), "Wk": dense(d, inner),
+             "Wv": dense(d, inner)}
+        for name in ("conv_q", "conv_k", "conv_v"):
+            p[name] = (jax.random.normal(next(keys), (self.conv_size, inner),
+                                         dtype)
+                       / math.sqrt(self.conv_size))
+        p["Wf1"], p["Wf2"] = dense(d, r), dense(r, inner)
+        # the usual start of a gated delta layer: decay rates log-uniform
+        # in [1, 16], step sizes log-uniform in [1e-3, 1e-1]
+        p["A_log"] = jnp.log(jax.random.uniform(next(keys), (self.n_heads,),
+                                                dtype, 1.0, 16.0))
+        dt = jnp.exp(jax.random.uniform(next(keys), (inner,), dtype)
+                     * (math.log(0.1) - math.log(0.001)) + math.log(0.001))
+        p["dt_bias"] = dt + jnp.log(-jnp.expm1(-dt))
+        p["Wb"] = dense(d, self.n_heads)
+        p["Wg1"], p["Wg2"] = dense(d, r), dense(r, inner)
+        p["o_norm"] = jnp.ones((self.head_dim,), dtype)
+        p["Wo"] = dense(inner, width)
+        return p, {}
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        x = dropout_input(x, self.dropout, train, rng)
+        bsz, t, _ = x.shape
+        h, dk = self.n_heads, self.head_dim
+
+        def heads(a):
+            return a.reshape(bsz, t, h, dk)
+
+        with jax.named_scope("kda.conv"):
+            q, k, v = (jax.nn.silu(causal_depthwise_conv(x @ params[w],
+                                                         params[c]))
+                       for w, c in (("Wq", "conv_q"), ("Wk", "conv_k"),
+                                    ("Wv", "conv_v")))
+            # normalised in float32, handed on in the compute type
+            q = (_l2norm(heads(q)) * (1.0 / math.sqrt(dk))).astype(x.dtype)
+            k = _l2norm(heads(k)).astype(x.dtype)
+            f = ((x @ params["Wf1"]) @ params["Wf2"]).astype(jnp.float32)
+            g = -jnp.exp(params["A_log"].astype(jnp.float32))[:, None] * heads(
+                jax.nn.softplus(f + params["dt_bias"].astype(jnp.float32)))
+            b = jax.nn.sigmoid((x @ params["Wb"]).astype(jnp.float32))
+        with jax.named_scope("kda.scan"):
+            o = chunked_kda(q, k, heads(v), g, b, chunk=self.chunk)
+        with jax.named_scope("kda.out_gate"):
+            gate = jax.nn.sigmoid(
+                heads((x @ params["Wg1"]) @ params["Wg2"]).astype(jnp.float32))
+            o = rms_norm(o, params["o_norm"], self.eps) * gate
+            out = o.reshape(bsz, t, h * dk).astype(x.dtype) @ params["Wo"]
+        if mask is not None:             # masked steps emit zeros
+            out = out * mask[..., None].astype(out.dtype)
+        return out, state
+
+
+__all__ = ["KimiDeltaAttention", "chunked_kda", "causal_depthwise_conv"]

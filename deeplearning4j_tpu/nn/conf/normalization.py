@@ -1,4 +1,5 @@
-"""Normalization layers: BatchNormalization, LocalResponseNormalization.
+"""Normalization layers: BatchNormalization, LocalResponseNormalization,
+RMSNorm.
 
 Parity surface: reference ``nn/conf/layers/BatchNormalization.java`` +
 ``nn/layers/normalization/BatchNormalization.java:57`` (helper hook; cuDNN
@@ -124,3 +125,31 @@ class LocalResponseNormalization(Layer):
             padding=((0, 0), (0, 0), (0, 0), (half, half)),
         )
         return x / jnp.power(self.k + self.alpha * summed, self.beta), state
+
+
+def rms_norm(x, g, eps: float):
+    """x / sqrt(mean(x^2) + eps) * g over the last axis; the mean square is
+    taken in float32 whatever x's type, the result is x's type."""
+    x32 = x.astype(jnp.float32)
+    scale = lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True) + eps)
+    return (x32 * scale * g.astype(jnp.float32)).astype(x.dtype)
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class RMSNorm(BaseLayer):
+    """Root-mean-square normalisation over the feature axis with a learned
+    scale ``g`` and no bias or mean subtraction (Zhang & Sennrich 2019): the
+    pre-norm of today's decoder blocks. Shape-preserving."""
+
+    eps: float = 1e-5
+
+    def regularizable(self):
+        return ()
+
+    def init(self, rng, it: InputType, dtype=jnp.float32):
+        n = it.channels if it.kind == "cnn" else it.flat_size()
+        return {"g": jnp.ones((n,), dtype)}, {}
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        return rms_norm(x, params["g"], self.eps), state

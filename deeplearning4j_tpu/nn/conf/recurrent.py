@@ -306,6 +306,58 @@ class RnnOutputLayer(BaseOutputLayer):
 
 @register_layer
 @dataclasses.dataclass(frozen=True)
+class TokenOutputLayer(RnnOutputLayer):
+    """Per-timestep softmax over a vocabulary with INTEGER labels
+    (``sparse_mcxent``: labels are (batch, time) class ids, no one-hot
+    array ever exists) and a loss that never holds the sequence's logits:
+    ``compute_score`` runs the time axis in blocks of ``time_block`` steps
+    (``lossfunctions.blocked_sparse_mcxent``), so that at 8192 steps over
+    20,480 classes the float32 logits, their softmax and gradient are one
+    block's. ``apply`` / ``output`` still return the whole softmax."""
+
+    has_bias: bool = False
+    loss: str = "sparse_mcxent"
+    time_block: int = 1024
+
+    sparse_labels = True       # labels are ids: (batch, time)
+
+    def pre_output(self, params, x):
+        # the score needs the features and the weights, not the logits
+        out = {"x": x, "W": params["W"]}
+        if "b" in params:
+            out["b"] = params["b"]
+        return out
+
+    def _logits(self, preout):
+        z = preout["x"] @ preout["W"]
+        return z + preout["b"] if "b" in preout else z
+
+    def output_activations(self, preout):
+        return get_activation(self.activation)(self._logits(preout))
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        x = dropout_input(x, self.dropout, train, rng)
+        return self.output_activations(self.pre_output(params, x)), state
+
+    def _blocked(self):
+        return (str(self.loss).lower() == "sparse_mcxent"
+                and str(self.activation).lower() == "softmax"
+                and self.loss_weights is None)
+
+    def compute_score(self, labels, preout, mask=None):
+        if not self._blocked():
+            return super().compute_score(labels, self._logits(preout), mask)
+        from deeplearning4j_tpu.nn.lossfunctions import blocked_sparse_mcxent
+        return blocked_sparse_mcxent(preout["x"], preout["W"],
+                                     preout.get("b"), labels, mask,
+                                     self.time_block)
+
+    def compute_score_array(self, labels, preout, mask=None):
+        return super().compute_score_array(labels, self._logits(preout), mask)
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
 class EmbeddingLayer(BaseLayer):
     """Index -> vector lookup (reference nn/conf/layers/EmbeddingLayer.java +
     nn/layers/feedforward/embedding/EmbeddingLayer.java): input is a column of

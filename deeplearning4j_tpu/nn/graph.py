@@ -94,16 +94,27 @@ class ComputationGraph:
 
     # ------------------------------------------------------------------ init
     def init(self, seed: Optional[int] = None,
-             validate: Optional[bool] = None) -> "ComputationGraph":
+             validate: Optional[bool] = None,
+             params: Optional[Dict[str, dict]] = None) -> "ComputationGraph":
         """Initialize params/optimizer state. Runs ``conf.validate()`` first
         (vertex-named errors before any XLA trace); opt out per call with
-        ``validate=False`` or process-wide with ``DL4J_TPU_VALIDATE=0``."""
+        ``validate=False`` or process-wide with ``DL4J_TPU_VALIDATE=0``.
+
+        ``params`` ({vertex: {name: array}}, a leaf for every parameter the
+        layers would draw, in their shapes) starts the network from GIVEN
+        weights: nothing is drawn, the arrays are taken as they are (not
+        copied: the train step donates them), and the non-trained state and
+        the optimizer state are made in one jitted call. At 600M parameters
+        a draw that is at once replaced, leaf by leaf, costs more than the
+        first steps."""
         if validate is None:
             import os
             validate = os.environ.get("DL4J_TPU_VALIDATE", "1") != "0"
         if validate:
             self.conf.validate()
         rng = jax.random.key(self.conf.seed if seed is None else seed)
+        if params is not None:
+            return self._init_from(params, rng)
         params, state = {}, {}
         for name in self.order:
             obj, _ = self.vertices[name]
@@ -119,6 +130,42 @@ class ComputationGraph:
         self.opt_state = {n: self._txs[n].init(params[n])
                           for n in self._layer_names}
         self._rng = rng
+        return self
+
+    def _init_from(self, given: Dict[str, dict], rng) -> "ComputationGraph":
+        def own(rng):
+            """What ``init`` makes, with the same splits of ``rng``; under
+            ``jit`` the draws nobody reads are never computed."""
+            drawn, state = {}, {}
+            for name in self.order:
+                obj, _ = self.vertices[name]
+                if isinstance(obj, Layer):
+                    rng, k = jax.random.split(rng)
+                    drawn[name], state[name] = obj.init(
+                        k, self.vertex_input_types[name][0], jnp.float32)
+                else:
+                    drawn[name], state[name] = {}, {}
+            return drawn, state, rng
+
+        want = jax.tree_util.tree_map(lambda a: (a.shape, a.dtype),
+                                      jax.eval_shape(own, rng)[0])
+        params = {name: dict(given.get(name, {})) for name in self.order}
+        have = jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), params)
+        if have != want:
+            odd = sorted(
+                {(n, k, str(v)) for n, d in want.items()
+                 for k, v in d.items()}
+                ^ {(n, k, str(v)) for n, d in have.items()
+                   for k, v in d.items()})[:6]
+            raise ValueError(f"given weights do not fit the network: {odd}")
+
+        def rest(rng, params):
+            _, state, rng = own(rng)
+            return state, {n: self._txs[n].init(params[n])
+                           for n in self._layer_names}, rng
+
+        self.params = params
+        self.state, self.opt_state, self._rng = jax.jit(rest)(rng, params)
         return self
 
     def num_params(self) -> int:

@@ -7,7 +7,8 @@ the *pre-activation* output plus the activation name so that softmax+MCXENT and
 sigmoid+XENT use numerically-stable fused forms; the backward pass is autodiff.
 
 Conventions:
-- ``labels``/``preout`` are (batch, n_out) or (batch, time, n_out) for RNNs.
+- ``labels``/``preout`` are (batch, n_out) or (batch, time, n_out) for RNNs;
+  ``sparse_mcxent`` alone takes integer class ids, (batch,) or (batch, time).
 - ``mask`` is optional (batch,) or (batch, time); masked scores are excluded
   from the average (reference: per-example score arrays + mask handling in
   BaseOutputLayer/LossFunction scoreArray implementations).
@@ -72,6 +73,61 @@ def _score_xent(labels, preout, activation, weights):
     return _weighted(s, weights)
 
 
+def _score_sparse_mcxent(labels, preout, activation, weights):
+    """Multi-class cross-entropy over INTEGER class ids: ``labels`` has
+    ``preout``'s shape without its last axis. The score lands in the
+    label's own column, so that ``score_array``'s sum over the output axis
+    and ``score``'s mean over unmasked steps are ``mcxent``'s."""
+    ids = labels.astype(jnp.int32)
+    if ids.ndim == preout.ndim:            # (batch, time, 1)
+        ids = ids[..., 0]
+    onehot = jax.nn.one_hot(ids, preout.shape[-1], dtype=preout.dtype)
+    return _score_mcxent(onehot, preout, activation, weights)
+
+
+def blocked_sparse_mcxent(x, w, b, ids, mask=None, block: int = 1024):
+    """``score("sparse_mcxent", ids, x @ w + b, "softmax", mask)`` without
+    the logits of the whole sequence: the time axis goes through in blocks
+    of ``block`` steps under ``jax.checkpoint``, so that forward and
+    backward hold one block's float32 logits and their gradient, never the
+    (batch, time, classes) array. ``x`` (batch, time, n_in), ``w`` (n_in,
+    classes), ``b`` None or (classes,), ``ids`` (batch, time) integers,
+    ``mask`` None or (batch, time). Mean over unmasked steps."""
+    bsz, t, _ = x.shape
+    ids = ids.astype(jnp.int32)
+    if ids.ndim == 3:
+        ids = ids[..., 0]
+    m = (jnp.ones((bsz, t), jnp.float32) if mask is None
+         else mask.astype(jnp.float32))
+    block = min(block, t)
+    pad = (-t) % block
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+        ids = jnp.pad(ids, ((0, 0), (0, pad)))
+        m = jnp.pad(m, ((0, 0), (0, pad)))
+    n = (t + pad) // block
+
+    def split(a):
+        return jnp.moveaxis(a.reshape((bsz, n, block) + a.shape[2:]), 1, 0)
+
+    @jax.checkpoint
+    def one(xb, ib, mb):
+        z = (xb @ w).astype(jnp.float32)
+        if b is not None:
+            z = z + b
+        lse = jax.nn.logsumexp(z, axis=-1)
+        picked = jnp.take_along_axis(z, ib[..., None], -1)[..., 0]
+        return jnp.sum((lse - picked) * mb)
+
+    def step(total, blk):
+        return total + one(*blk), None
+
+    with jax.named_scope("loss.blocked"):
+        total, _ = jax.lax.scan(step, jnp.zeros((), jnp.float32),
+                                (split(x), split(ids), split(m)))
+    return total / jnp.maximum(jnp.sum(m), 1.0)
+
+
 def _score_nll(labels, preout, activation, weights):
     return _score_mcxent(labels, preout, activation, weights)
 
@@ -126,6 +182,7 @@ LOSSES = {
     "l1": _score_l1,
     "mae": _score_l1,
     "mcxent": _score_mcxent,
+    "sparse_mcxent": _score_sparse_mcxent,
     "xent": _score_xent,
     "negativeloglikelihood": _score_nll,
     "nll": _score_nll,

@@ -33,7 +33,8 @@ from deeplearning4j_tpu.obs.registry import (  # noqa: F401
     absorb_checkpoint_manager, absorb_compile_watch, absorb_index_endpoint,
     absorb_inference_stats, absorb_model_server, absorb_training_stats,
     get_registry,
-    publish_stats_update, watch_grad_compression, watch_training_stats)
+    publish_stats_update, watch_grad_compression, watch_moe,
+    watch_training_stats)
 from deeplearning4j_tpu.obs.trace import (  # noqa: F401
     Stopwatch, Tracer, configure_tracer, get_tracer)
 from deeplearning4j_tpu.obs.flight import (  # noqa: F401
@@ -45,7 +46,7 @@ from deeplearning4j_tpu.obs.exporters import (  # noqa: F401
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricError", "MetricsRegistry",
     "get_registry", "absorb_compile_watch", "absorb_training_stats",
-    "watch_training_stats", "watch_grad_compression",
+    "watch_training_stats", "watch_grad_compression", "watch_moe",
     "absorb_inference_stats", "absorb_checkpoint_manager",
     "absorb_index_endpoint",
     "publish_stats_update",

@@ -40,7 +40,7 @@ __all__ = [
     "get_registry", "absorb_compile_watch", "absorb_training_stats",
     "watch_training_stats",
     "absorb_inference_stats", "absorb_checkpoint_manager",
-    "absorb_model_server", "watch_grad_compression",
+    "absorb_model_server", "watch_grad_compression", "watch_moe",
     "publish_stats_update", "DEFAULT_BUCKETS_MS",
 ]
 
@@ -652,6 +652,86 @@ def watch_grad_compression(registry: MetricsRegistry, model):
         seen["wire"] = acc["wire_bytes"]
 
     _cb.reseed = _reseed
+    registry.register_callback(_cb)
+    return _cb
+
+
+def watch_moe(registry: MetricsRegistry, model):
+    """Register a collect-time callback pulling the routed-expert layers'
+    device-resident load counters (``nn/conf/experts.py``: the ``state`` of
+    every ``RoutedExperts`` layer of ``model``) into the registry:
+
+    * ``moe_tokens_held_total``: (token, expert) pairs that fell on an
+      expert held here, all routed layers together;
+    * ``moe_dropped_tokens_total``: such pairs that no grouped product
+      computed. Must read 0;
+    * ``moe_expert_tokens_<layer>_e<expert>`` gauges: pairs each held
+      expert of each layer has got so far (the registry has no labels:
+      layer and expert are in the name).
+
+    The device scalars are fetched at SCRAPE time only, never on the step
+    path: a turn of ``fit`` issues no device program and no sync for them.
+    Weakref'd and self-removing like ``watch_grad_compression``; counters
+    count what accumulated while THIS callback watched (int32 on the
+    device, so deltas are taken modulo 2**32)."""
+    ref = weakref.ref(model)
+    seen: Dict[str, int] = {}
+
+    def _read(state):
+        import numpy as _np
+        out = {}
+        # a graph's state is keyed by vertex, a MultiLayerNetwork's a list
+        named = (state.items() if isinstance(state, dict)
+                 else ((f"layer{i}", st) for i, st in enumerate(state)))
+        for layer, st in named:
+            if isinstance(st, dict) and "expert_tokens" in st:
+                out[layer] = {
+                    "expert_tokens": _np.asarray(st["expert_tokens"])
+                    .astype(_np.int64).tolist(),
+                    "pairs_held": int(_np.asarray(st["pairs_held"])),
+                    "pairs_dropped": int(_np.asarray(st["pairs_dropped"]))}
+        return out
+
+    def _cb(reg: MetricsRegistry):
+        live = ref()
+        if live is None:
+            reg.unregister_callback(_cb)
+            return
+        # the step donates the state it consumes: re-read the fresh
+        # attribute if a scrape catches the old tree mid-deletion
+        for _ in range(3):
+            state = getattr(live, "state", None)
+            if state is None:
+                return
+            try:
+                layers = _read(state)
+                break
+            except RuntimeError:
+                continue
+        else:
+            return
+        held = reg.counter(
+            "moe_tokens_held_total", unit="pairs",
+            help="(token, expert) pairs that fell on an expert this chip "
+                 "holds, all routed layers together")
+        dropped = reg.counter(
+            "moe_dropped_tokens_total", unit="pairs",
+            help="pairs on a held expert that no grouped product "
+                 "computed: must read 0")
+        for layer, c in layers.items():
+            for what, inst in (("pairs_held", held),
+                               ("pairs_dropped", dropped)):
+                key = f"{layer}/{what}"
+                now = c[what] % (1 << 32)
+                inst.inc(float((now - seen.get(key, 0)) % (1 << 32)))
+                seen[key] = now
+            for e, n in enumerate(c["expert_tokens"]):
+                reg.gauge(
+                    f"moe_expert_tokens_{_sanitize(layer)}_e{e}",
+                    unit="pairs",
+                    help=f"pairs expert {e} held by layer {layer} has got "
+                         "so far").set(float(n % (1 << 32)))
+
     registry.register_callback(_cb)
     return _cb
 

@@ -6,8 +6,8 @@ mode cannot show what it shows: every PR-16 kernel had passed its
 interpret-mode parity tests and four of five families were refused here
 (VMEM overflow, a bf16 vector compare, an in-kernel gather, a
 rank-changing reshape). So each kernel the automatic TPU rule selects
-(``perf.pallas.TPU_AUTO_FAMILIES``), plus flash attention and the
-Word2Vec scatter, is compiled ``interpret=False`` at one main-path shape;
+(``perf.pallas.TPU_AUTO_FAMILIES``), plus flash attention, the Word2Vec
+scatter and the routed experts' grouped products, is compiled ``interpret=False`` at one main-path shape;
 the BN family — outside the automatic rule — is compiled where its
 ``supported()`` says it fits, and must refuse what does not.
 
@@ -100,6 +100,23 @@ def _scatter(S):
                                 S((8192, 100), F32))
 
 
+def _grouped_experts(S):
+    # the routed layer's three grouped products and their backward pass at
+    # the Kimi Linear share's widths: 8192 tokens x top-8 rows, 8 experts
+    # of 2304 x 1024 (the megablox kernel, through the layer's own call)
+    from deeplearning4j_tpu.nn.conf.experts import grouped_matmul
+
+    def fwd_bwd(rows, w_gate, w_down, sizes):
+        def f(rows, w_gate, w_down):
+            hidden = jax.nn.silu(grouped_matmul(rows, w_gate, sizes))
+            return jnp.sum(grouped_matmul(hidden, w_down, sizes)
+                           .astype(F32))
+        return jax.grad(f, argnums=(0, 1, 2))(rows, w_gate, w_down)
+
+    return fwd_bwd, (S((65536, 2304), BF16), S((8, 2304, 1024), BF16),
+                     S((8, 1024, 2304), BF16), S((8,), I32))
+
+
 def _bn_fwd(S):
     # ResNet50 batch 128, the one stage whose rows fit: 7x7
     z = S((128, 7, 7, 2048), BF16)
@@ -117,7 +134,8 @@ def _bn_bwd(S):
 
 # (builder, family the automatic TPU rule must select for it | None)
 AUTO_CASES = [(_pq, "adc_pq"), (_int4_table, "int4_dot"),
-              (_int4_weights, "int4_dot"), (_flash, None), (_scatter, None)]
+              (_int4_weights, "int4_dot"), (_flash, None), (_scatter, None),
+              (_grouped_experts, None)]
 EXPLICIT_CASES = [_bn_fwd, _bn_bwd]
 
 

@@ -1,0 +1,523 @@
+"""The layers a hybrid linear-attention expert model needs (RMSNorm,
+GatedFeedForward, KimiDeltaAttention, MultiHeadLatentAttention,
+RoutedExperts, TokenOutputLayer and the ``sparse_mcxent`` loss), each
+against a plain form written out here, on the CPU at small sizes in
+float32. The whole model against the benchmark's reference is in
+``tests/benchmark/test_benchmark_kimi_linear.py``."""
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deeplearning4j_tpu import obs
+from deeplearning4j_tpu.datasets.dataset import DataSet
+from deeplearning4j_tpu.nn import lossfunctions
+from deeplearning4j_tpu.nn.conf import InputType, NeuralNetConfiguration
+from deeplearning4j_tpu.nn.conf.attention import (MultiHeadLatentAttention,
+                                                  blocked_causal_attention)
+from deeplearning4j_tpu.nn.conf.experts import (GatedFeedForward,
+                                                RoutedExperts, grouped_matmul)
+from deeplearning4j_tpu.nn.conf.layers import layer_from_dict, layer_to_dict
+from deeplearning4j_tpu.nn.conf.linear_attention import (
+    KimiDeltaAttention, causal_depthwise_conv, chunked_kda)
+from deeplearning4j_tpu.nn.conf.normalization import RMSNorm
+from deeplearning4j_tpu.nn.conf.recurrent import (EmbeddingSequenceLayer,
+                                                  TokenOutputLayer)
+from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+from deeplearning4j_tpu.optimize.updaters import Adam
+
+# float32 on the CPU: what differs between two orders of the same sums
+TOL = 2e-5
+
+
+@pytest.fixture(autouse=True)
+def _exact_products():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def _keys(n, seed=0):
+    return jax.random.split(jax.random.key(seed), n)
+
+
+# --------------------------------------------------------------------- KDA
+def _recurrence(q, k, v, g, b):
+    """S_t = (I - b k k^T) Diag(exp g) S_{t-1} + b k v^T, o_t = S_t^T q_t,
+    one token at a time in numpy float64."""
+    q, k, v, g, b = (np.asarray(a, np.float64) for a in (q, k, v, g, b))
+    bsz, t, h, kd = q.shape
+    out = np.zeros(v.shape)
+    for n in range(bsz):
+        for j in range(h):
+            s = np.zeros((kd, v.shape[-1]))
+            for i in range(t):
+                s = s * np.exp(g[n, i, j])[:, None]
+                kk = k[n, i, j]
+                s = s + b[n, i, j] * np.outer(kk, v[n, i, j] - s.T @ kk)
+                out[n, i, j] = s.T @ q[n, i, j]
+    return out
+
+
+def _kda_inputs(t, decay, seed=0):
+    ks = _keys(5, seed)
+    shape = (2, t, 2, 8)
+    q = jax.random.normal(ks[0], shape)
+    k = jax.random.normal(ks[1], shape)
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], shape)
+    g = -decay * jax.random.uniform(ks[3], shape)
+    b = jax.random.uniform(ks[4], shape[:3])
+    return q, k, v, g, b
+
+
+@pytest.mark.parametrize("t,decay", [(64, 1.0), (70, 0.05), (130, 1.0),
+                                     (33, 40.0), (128, 40.0)])
+def test_chunked_kda_is_the_token_recurrence(t, decay):
+    """Any length (not only multiples of the chunk) and any decay: at 40 a
+    step a quotient of two exponentials would overflow, the channel-by-
+    channel diagonal blocks do not."""
+    args = _kda_inputs(t, decay)
+    got = chunked_kda(*args, chunk=64)
+    want = _recurrence(*args)
+    assert np.all(np.isfinite(got))
+    assert np.max(np.abs(np.asarray(got) - want)) < TOL * max(
+        1.0, np.max(np.abs(want)))
+
+
+@pytest.mark.parametrize("alike", [0.5, 0.9, 1.0])
+def test_chunked_kda_stays_the_recurrence_when_keys_point_the_same_way(alike):
+    """Keys that are alike, little decay and b near 1 make (I + A)^-1 of a
+    whole chunk grow like 2^64: an explicit inverse overflows float32 (it
+    did, on the chip, in the second training step). Forward substitution
+    over blocks of 8 rows is as stable as the recurrence itself."""
+    ks = _keys(5, 4)
+    shape = (1, 256, 2, 16)
+    k = alike * jax.random.normal(ks[0], (1, 1, 2, 16)) + (
+        1 - alike) * jax.random.normal(ks[1], shape)
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    q = 0.3 * jax.random.normal(ks[2], shape)
+    v = jax.random.normal(ks[3], shape)
+    g = -0.001 * jax.random.uniform(ks[4], shape)
+    b = 0.95 + 0.05 * jax.random.uniform(ks[4], shape[:3])
+    got = chunked_kda(q, k, v, g, b)
+    want = _recurrence(q, k, v, g, b)
+    assert np.max(np.abs(np.asarray(got) - want)) < 5e-5 * max(
+        1.0, np.max(np.abs(want)))
+    grads = jax.grad(lambda *a: jnp.sum(jnp.sin(chunked_kda(*a))),
+                     argnums=range(5))(q, k, v, g, b)
+    assert all(np.all(np.isfinite(a)) for a in grads)
+
+
+def test_chunked_kda_gradients_match_a_scan_over_tokens():
+    args = _kda_inputs(70, 2.0, seed=3)
+
+    def scan_form(q, k, v, g, b):
+        def step(s, inp):
+            qt, kt, vt, gt, bt = inp
+            s = s * jnp.exp(gt)[..., None]
+            read = jnp.einsum("bhk,bhkv->bhv", kt, s)
+            s = s + jnp.einsum("bhk,bhv->bhkv", kt,
+                               bt[..., None] * (vt - read))
+            return s, jnp.einsum("bhk,bhkv->bhv", qt, s)
+        s0 = jnp.zeros((q.shape[0], q.shape[2], q.shape[3], v.shape[3]))
+        _, o = jax.lax.scan(step, s0, tuple(jnp.moveaxis(a, 1, 0)
+                                            for a in (q, k, v, g, b)))
+        return jnp.moveaxis(o, 0, 1)
+
+    def scalar(f):
+        return lambda *a: jnp.sum(jnp.sin(f(*a)))
+
+    got = jax.grad(scalar(lambda *a: chunked_kda(*a, chunk=32, sub=8)),
+                   argnums=range(5))(*args)
+    want = jax.grad(scalar(scan_form), argnums=range(5))(*args)
+    for a, b in zip(got, want):
+        assert np.all(np.isfinite(a))
+        assert float(jnp.max(jnp.abs(a - b))) < TOL * max(
+            1.0, float(jnp.max(jnp.abs(b))))
+
+
+def test_causal_depthwise_conv_sees_no_future():
+    x = jax.random.normal(_keys(1)[0], (1, 12, 3))
+    w = jnp.arange(12.0).reshape(4, 3) / 10
+    y = causal_depthwise_conv(x, w)
+    for t in (0, 2, 7):
+        want = sum(w[j] * (x[0, t - 3 + j] if t - 3 + j >= 0 else 0.0)
+                   for j in range(4))
+        assert np.allclose(y[0, t], want, atol=1e-6)
+    bumped = causal_depthwise_conv(x.at[0, 8].add(1.0), w)
+    assert np.allclose(bumped[0, :8], y[0, :8])
+
+
+def test_kda_layer_masks_its_output_and_keeps_its_width():
+    layer = KimiDeltaAttention(n_heads=2, head_dim=8, chunk=16)
+    it = InputType.recurrent(12, 20)
+    assert layer.output_type(it).size == 12
+    params, state = layer.init(jax.random.key(0), it)
+    x = jax.random.normal(jax.random.key(1), (2, 20, 12))
+    mask = jnp.concatenate([jnp.ones((2, 15)), jnp.zeros((2, 5))], 1)
+    out, _ = layer.apply(params, state, x, mask=mask)
+    free, _ = layer.apply(params, state, x)
+    assert out.shape == (2, 20, 12)
+    assert np.allclose(out[:, :15], free[:, :15], atol=1e-6)
+    assert not np.any(np.asarray(out[:, 15:]))
+
+
+# --------------------------------------------------------------------- MLA
+def _dense_causal(q, k, v):
+    t = q.shape[2]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(q.shape[-1])
+    s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+
+
+@pytest.mark.parametrize("t,block", [(128, 32), (100, 32), (20, 32),
+                                     (96, 96)])
+def test_blocked_attention_is_the_full_score_matrix(t, block):
+    """q/k heads of 24 and v heads of 16 (they differ, as in latent
+    attention), lengths that are and are not multiples of the tile, one
+    tile: forward and all three gradients."""
+    ks = _keys(3, 1)
+    q = jax.random.normal(ks[0], (2, 3, t, 24))
+    k = jax.random.normal(ks[1], (2, 3, t, 24))
+    v = jax.random.normal(ks[2], (2, 3, t, 16))
+    assert float(jnp.max(jnp.abs(blocked_causal_attention(q, k, v, block)
+                                 - _dense_causal(q, k, v)))) < TOL
+    got = jax.grad(lambda *a: jnp.sum(jnp.sin(
+        blocked_causal_attention(*a, block))), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(jnp.sin(_dense_causal(*a))),
+                    argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        assert float(jnp.max(jnp.abs(a - b))) < TOL
+
+
+def test_mla_layer_counts_the_path_it_took():
+    from deeplearning4j_tpu.perf.compile_watch import GLOBAL
+    layer = MultiHeadLatentAttention(n_heads=2, nope_dim=8, rope_dim=4,
+                                     v_dim=8, kv_rank=16, block=16)
+    it = InputType.recurrent(12, 40)
+    params, state = layer.init(jax.random.key(0), it)
+    x = jax.random.normal(jax.random.key(1), (1, 40, 12))
+    before = dict(GLOBAL.as_dict().get("counters", {}))
+    out, _ = layer.apply(params, state, x)
+    layer.apply(params, state, x[:, :16])
+    after = GLOBAL.as_dict()["counters"]
+    assert out.shape == (1, 40, 12)
+    assert after["attention.mla_blocked"] == before.get(
+        "attention.mla_blocked", 0) + 1
+    assert after["attention.mla_single_tile"] == before.get(
+        "attention.mla_single_tile", 0) + 1
+
+
+# -------------------------------------------------------------------- loss
+@pytest.mark.parametrize("t,block,masked", [(64, 16, False), (50, 16, False),
+                                            (50, 16, True), (10, 16, True)])
+def test_blocked_loss_is_the_plain_cross_entropy(t, block, masked):
+    ks = _keys(4, 2)
+    x = jax.random.normal(ks[0], (3, t, 12))
+    w = jax.random.normal(ks[1], (12, 30)) * 0.3
+    ids = jax.random.randint(ks[2], (3, t), 0, 30)
+    mask = ((jax.random.uniform(ks[3], (3, t)) > 0.3).astype(jnp.float32)
+            if masked else None)
+
+    def plain(x, w):
+        onehot = jax.nn.one_hot(ids, 30)
+        return lossfunctions.score("mcxent", onehot, x @ w, "softmax", mask)
+
+    def blocked(x, w):
+        return lossfunctions.blocked_sparse_mcxent(x, w, None, ids, mask,
+                                                   block)
+
+    assert abs(float(blocked(x, w) - plain(x, w))) < 1e-5
+    assert abs(float(lossfunctions.score("sparse_mcxent", ids, x @ w,
+                                         "softmax", mask)
+                     - plain(x, w))) < 1e-5
+    for a, b in zip(jax.grad(blocked, (0, 1))(x, w),
+                    jax.grad(plain, (0, 1))(x, w)):
+        assert float(jnp.max(jnp.abs(a - b))) < 1e-5
+
+
+def test_blocked_loss_never_builds_the_sequence_logits():
+    """No array of (time x classes) floats in the jaxpr of loss and
+    gradient: the largest is one block's."""
+    x = jnp.zeros((1, 256, 8))
+    w = jnp.zeros((8, 64))
+    ids = jnp.zeros((1, 256), jnp.int32)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda x, w: lossfunctions.blocked_sparse_mcxent(
+            x, w, None, ids, None, 32), (0, 1)))(x, w)
+
+    def shapes(jp):
+        for eqn in jp.eqns:
+            for var in eqn.outvars:
+                yield tuple(var.aval.shape)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from shapes(sub)
+
+    assert not any(s[-1:] == (64,) and math.prod(s) > 32 * 64
+                   for s in shapes(jaxpr.jaxpr))
+
+
+# ------------------------------------------------------------------ experts
+def _experts(held, offset=0, shared=8, total=8, top_k=2):
+    return RoutedExperts(n_experts=total, experts_held=held,
+                         expert_offset=offset, top_k=top_k, expert_size=8,
+                         shared_size=shared, scaling=2.446)
+
+
+def _plain_routed(layer, params, x, bias):
+    """The masked loop over the held experts."""
+    s = jax.nn.sigmoid(x @ params["Wr"])
+    _, idx = jax.lax.top_k(s + bias, layer.top_k)
+    chosen = jnp.take_along_axis(s, idx, -1)
+    w = layer.scaling * chosen / jnp.sum(chosen, -1, keepdims=True)
+    y = jnp.zeros_like(x)
+    for e in range(layer.experts_held):
+        weight = jnp.sum(jnp.where(idx == layer.expert_offset + e, w, 0.0),
+                         -1)
+        hidden = jax.nn.silu(x @ params["Wgate"][e]) * (x @ params["Wup"][e])
+        y = y + weight[..., None] * (hidden @ params["Wdown"][e])
+    if layer.shared_size:
+        y = y + (jax.nn.silu(x @ params["Sgate"]) * (x @ params["Sup"])) \
+            @ params["Sdown"]
+    return y
+
+
+@pytest.mark.parametrize("held,offset", [(8, 0), (4, 0), (2, 6)])
+def test_routed_experts_are_the_masked_loop(held, offset):
+    layer = _experts(held, offset)
+    it = InputType.recurrent(12, 24)
+    params, state = layer.init(jax.random.key(0), it)
+    x = jax.random.normal(jax.random.key(1), (2, 24, 12))
+    out, new = layer.apply(params, state, x)
+    want = _plain_routed(layer, params, x, state["bias"])
+    assert float(jnp.max(jnp.abs(out - want))) < TOL
+    got = jax.grad(lambda p: jnp.sum(jnp.sin(layer.apply(p, state, x)[0])))(
+        params)
+    ref = jax.grad(lambda p: jnp.sum(jnp.sin(_plain_routed(
+        layer, p, x, state["bias"]))))(params)
+    for key in ref:
+        assert float(jnp.max(jnp.abs(got[key] - ref[key]))) < TOL, key
+    assert int(new["pairs_dropped"]) == 0
+    assert int(new["pairs_held"]) == int(jnp.sum(new["expert_tokens"]))
+
+
+def test_grouped_matmul_leaves_rows_past_the_groups_zero():
+    rows = jnp.ones((16, 4))
+    weights = jnp.stack([jnp.eye(4) * (g + 1) for g in range(3)])
+    out = grouped_matmul(rows, weights, jnp.array([3, 0, 5], jnp.int32))
+    assert np.allclose(out[:3], 1.0) and np.allclose(out[3:8], 3.0)
+    assert not np.any(np.asarray(out[8:]))
+
+
+@pytest.mark.parametrize("where", ["all_on_one_held", "none_on_any_held",
+                                   "all_on_all_held"])
+def test_no_pair_is_dropped_at_any_imbalance(where):
+    """A router pushed by its bias: every token on one held expert (48
+    pairs on it, 24 times the even load), none on any, or all ``top_k``
+    slots of every token on held experts (the worst case the buffer is
+    sized for). The counters say what happened and the result is still the
+    masked loop's."""
+    layer = _experts(held=2, offset=0, shared=0)
+    it = InputType.recurrent(12, 24)
+    params, state = layer.init(jax.random.key(0), it)
+    bias = {"all_on_one_held": jnp.zeros(8).at[1].set(10.0),
+            "none_on_any_held": jnp.zeros(8).at[:2].set(-10.0),
+            "all_on_all_held": jnp.zeros(8).at[:2].set(10.0)}[where]
+    state = dict(state, bias=bias)
+    x = jax.random.normal(jax.random.key(1), (2, 24, 12))
+    out, new = layer.apply(params, state, x)
+    tokens = np.asarray(new["expert_tokens"])
+    if where == "all_on_one_held":
+        assert tokens[1] == 48 and int(new["pairs_held"]) >= 48
+    elif where == "none_on_any_held":
+        assert tokens.tolist() == [0, 0] and not np.any(np.asarray(out))
+    else:
+        assert tokens.tolist() == [48, 48] and int(new["pairs_held"]) == 96
+    assert int(new["pairs_dropped"]) == 0
+    assert float(jnp.max(jnp.abs(out - _plain_routed(layer, params, x,
+                                                     bias)))) < TOL
+    again, newer = layer.apply(params, new, x)
+    assert np.array_equal(np.asarray(newer["expert_tokens"]), 2 * tokens)
+
+
+@pytest.mark.parametrize("push", [0.0, 10.0], ids=["first_tier",
+                                                    "worst_case_tier"])
+def test_both_buffer_tiers_give_the_masked_loop(push):
+    """1,200 pairs: the first tier computes 256 sorted slots, enough for
+    the 75 pairs an even router sends to 2 of 32 experts; a router pushed
+    onto the held experts (1,200 pairs on them) takes the worst-case tier.
+    Same result, same gradients, nothing dropped."""
+    layer = _experts(held=2, offset=4, shared=0, total=32)
+    it = InputType.recurrent(12, 300)
+    params, state = layer.init(jax.random.key(0), it)
+    state = dict(state, bias=jnp.zeros(32).at[4:6].set(push))
+    x = jax.random.normal(jax.random.key(1), (2, 300, 12))
+    out, new = layer.apply(params, state, x)
+    held = int(new["pairs_held"])
+    assert (held <= 256) == (push == 0.0) and int(new["pairs_dropped"]) == 0
+    assert held == (1200 if push else int(jnp.sum(new["expert_tokens"])))
+    want = _plain_routed(layer, params, x, state["bias"])
+    assert float(jnp.max(jnp.abs(out - want))) < TOL * max(
+        1.0, float(jnp.max(jnp.abs(want))))
+    got = jax.grad(lambda p, x: jnp.sum(jnp.sin(
+        layer.apply(p, state, x)[0])), (0, 1))(params, x)
+    ref = jax.grad(lambda p, x: jnp.sum(jnp.sin(_plain_routed(
+        layer, p, x, state["bias"]))), (0, 1))(params, x)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(ref)):
+        assert float(jnp.max(jnp.abs(a - b))) < TOL * max(
+            1.0, float(jnp.max(jnp.abs(b))))
+
+
+def test_the_dropped_counter_sees_a_window_that_did_not_run(monkeypatch):
+    """``pairs_dropped`` is held pairs less the rows that the windows which
+    ran gave to the grouped products, not arithmetic that is 0 whatever
+    runs: a layer whose choice of tier is broken (always the first window,
+    here by a ``lax.cond`` that takes its first branch) reads the 944 of
+    1,200 pairs it left out, and its result is off."""
+    from deeplearning4j_tpu.nn.conf import experts as module
+    layer = _experts(held=2, offset=4, shared=0, total=32)
+    it = InputType.recurrent(12, 300)
+    params, state = layer.init(jax.random.key(0), it)
+    state = dict(state, bias=jnp.zeros(32).at[4:6].set(10.0))
+    x = jax.random.normal(jax.random.key(1), (2, 300, 12))
+    monkeypatch.setattr(module.lax, "cond",
+                        lambda pred, first, second, *ops: first(*ops))
+    out, new = layer.apply(params, state, x)
+    assert int(new["pairs_held"]) == 1200
+    assert int(new["pairs_dropped"]) == 1200 - 256
+    want = _plain_routed(layer, params, x, state["bias"])
+    assert float(jnp.max(jnp.abs(out - want))) > 100 * TOL
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """Four shares of two experts each (offsets 0, 2, 4, 6), the shared
+    expert counted once (on the first share), give what the layer that
+    holds all eight gives."""
+    whole = _experts(held=8)
+    it = InputType.recurrent(12, 24)
+    params, state = whole.init(jax.random.key(0), it)
+    x = jax.random.normal(jax.random.key(1), (2, 24, 12))
+    want, _ = whole.apply(params, state, x)
+    total = jnp.zeros_like(want)
+    for offset in (0, 2, 4, 6):
+        share = _experts(held=2, offset=offset, shared=8 if offset == 0 else 0)
+        own = {k: (v[offset:offset + 2] if k in ("Wgate", "Wup", "Wdown")
+                   else v) for k, v in params.items()
+               if share.shared_size or not k.startswith("S")}
+        part, _ = share.apply(own, share.init(jax.random.key(0), it)[1], x)
+        total = total + part
+    assert float(jnp.max(jnp.abs(total - want))) < TOL
+
+
+# ------------------------------------------------------- the framework's side
+LAYERS = [
+    RMSNorm(eps=1e-6),
+    GatedFeedForward(ff_size=24, remat="full"),
+    KimiDeltaAttention(n_heads=2, head_dim=8, low_rank=4, chunk=32),
+    MultiHeadLatentAttention(n_heads=2, nope_dim=8, rope_dim=4, v_dim=8,
+                             kv_rank=16, block=32),
+    RoutedExperts(n_experts=16, experts_held=4, expert_offset=8, top_k=3,
+                  expert_size=8, shared_size=8, scaling=2.446),
+    TokenOutputLayer(n_out=30, time_block=16),
+]
+
+
+@pytest.mark.parametrize("layer", LAYERS, ids=lambda l: type(l).__name__)
+def test_config_round_trip(layer):
+    import json
+    again = layer_from_dict(json.loads(json.dumps(layer_to_dict(layer))))
+    assert again == layer and type(again) is type(layer)
+
+
+def _mln(t=None):
+    conf = (NeuralNetConfiguration.builder().seed(5)
+            .updater(Adam(learning_rate=3e-3)).list()
+            .layer(EmbeddingSequenceLayer(n_in=30, n_out=12))
+            .layer(RMSNorm())
+            .layer(KimiDeltaAttention(n_heads=2, head_dim=8, chunk=16,
+                                      remat="full"))
+            .layer(RMSNorm())
+            .layer(MultiHeadLatentAttention(n_heads=2, nope_dim=8,
+                                            rope_dim=4, v_dim=8, kv_rank=16,
+                                            block=16))
+            .layer(RoutedExperts(n_experts=8, experts_held=4, top_k=2,
+                                 expert_size=8, shared_size=8,
+                                 remat="full"))
+            .layer(GatedFeedForward(ff_size=24))
+            .layer(TokenOutputLayer(n_out=30, time_block=16))
+            .set_input_type(InputType.recurrent(30, t)).build())
+    return conf
+
+
+def test_shape_inference_and_validation_take_the_new_layers():
+    conf = _mln(40)
+    issues = conf.validate(eval_shape_check=True, batch=2,
+                           labels_shape=(2, 40))
+    assert not [i for i in issues if i.severity == "error"], issues
+    bad = conf.validate(labels_shape=(2, 40, 30), raise_on_error=False)
+    assert any(i.rule == "labels-shape" for i in bad)
+
+
+def test_a_multilayer_network_learns_a_copy_task_through_them():
+    net = MultiLayerNetwork(_mln()).init()
+    rng = np.random.default_rng(0)
+    ids = rng.integers(0, 30, (4, 41)).astype(np.int32)
+    ids[:, 1::2] = ids[:, 0:-1:2]            # every odd id repeats the last
+    ds = DataSet(ids[:, :-1], ids[:, 1:])
+    net.fit(ds)
+    first = net.score()
+    for _ in range(60):
+        net.fit(ds)
+    assert net.score() < 0.8 * first
+    assert net.output(ids[:, :-1]).shape == (4, 40, 30)
+    experts = [s for s in net.state if "expert_tokens" in s]
+    assert len(experts) == 1
+    assert int(experts[0]["pairs_dropped"]) == 0
+    assert int(jnp.sum(experts[0]["expert_tokens"])) == int(
+        experts[0]["pairs_held"])
+
+
+def test_the_counters_cost_a_turn_of_fit_no_device_program_and_no_sync():
+    """The routed layers' counters ride in the step's own state: the
+    dispatches of a fit are the train steps and nothing else, the registry
+    does not move until it is scraped, and a scrape reads what the device
+    counted."""
+    from deeplearning4j_tpu.perf.compile_watch import GLOBAL
+    net = MultiLayerNetwork(_mln()).init()
+    obs.watch_moe(obs.get_registry(), net)
+    ids = np.random.default_rng(1).integers(0, 30, (2, 33)).astype(np.int32)
+    ds = DataSet(ids[:, :-1], ids[:, 1:])
+    net.fit(ds)                                   # compiles
+
+    def value(name):
+        m = obs.get_registry().metric(name)
+        return 0.0 if m is None else m.value
+
+    def on_device():
+        return int([s for s in net.state if "expert_tokens" in s][0][
+            "pairs_held"])
+
+    obs.get_registry().collect()
+    device0 = on_device()
+    held0, by_key0 = value("moe_tokens_held_total"), {
+        k: v["dispatches"] for k, v in GLOBAL.as_dict()["by_key"].items()}
+    with jax.transfer_guard_device_to_host("disallow"):
+        for _ in range(3):
+            net.fit(ds)
+    by_key = {k: v["dispatches"] for k, v in
+              GLOBAL.as_dict()["by_key"].items()}
+    moved = {k: v - by_key0.get(k, 0) for k, v in by_key.items()
+             if v != by_key0.get(k, 0)}
+    assert moved == {"train": 3}
+    assert value("moe_tokens_held_total") == held0      # nothing pushed
+    obs.get_registry().collect()
+    assert on_device() > device0
+    assert value("moe_tokens_held_total") - held0 == on_device() - device0
+    assert value("moe_dropped_tokens_total") == 0.0
+    gauges = [n for n in obs.get_registry().names()
+              if n.startswith("moe_expert_tokens_")]
+    assert len(gauges) >= 4
