@@ -551,6 +551,53 @@ def phase_kernels(ctx: Ctx) -> dict:
                            "index_counters": counts,
                            "weight_counters": wcounts, "seconds": lap(t)}
 
+        # ---- kda_scan: the chunked scan of Kimi Delta Attention, forward
+        # and backward kernels against the jax.numpy form, heads of 128
+        from deeplearning4j_tpu.nn.conf.linear_attention import chunked_kda
+        from deeplearning4j_tpu.perf.compile_watch import GLOBAL
+        from deeplearning4j_tpu.perf.pallas import kda
+        shape = (1, s.attn_seq + 40, 8 if chip else 2, 128)
+        keys = jax.random.split(jax.random.key(ctx.seed), 5)
+        cdt = jnp.bfloat16 if chip else jnp.float32
+        kq, kk_, kv = (jax.random.normal(key, shape) for key in keys[:3])
+        kk_ = kk_ / jnp.linalg.norm(kk_, axis=-1, keepdims=True)
+        kargs = (0.1 * kq.astype(cdt), kk_.astype(cdt), kv.astype(cdt),
+                 -jax.random.uniform(keys[3], shape),
+                 jax.random.uniform(keys[4], shape[:3]))
+        check(kda.supported(*kargs, 64, 8),
+              "kda_scan does not take heads of 128 in chunks of 64")
+
+        def kda_loss(*a):
+            o = chunked_kda(*a)
+            return jnp.sum(jnp.sin(o)), o
+        before = dict(GLOBAL.as_dict().get("counters", {}))
+        got = jax.value_and_grad(kda_loss, range(5), has_aux=True)(*kargs)
+        with pk.override(enabled=False):
+            want = jax.value_and_grad(kda_loss, range(5),
+                                      has_aux=True)(*kargs)
+        kcounts = {key: val - before.get(key, 0) for key, val in
+                   GLOBAL.as_dict()["counters"].items() if "kda_scan" in key}
+        check(kcounts == {"kernel.pallas_kda_scan": 1,
+                          "kernel.xla_kda_scan": 1},
+              f"kda_scan kernel counters {kcounts}")
+        gaps = []
+        for a, w in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            a, w = (np.asarray(x, np.float64) for x in (a, w))
+            check(np.all(np.isfinite(a)), "kda_scan: not finite")
+            gaps.append(float(np.linalg.norm(a - w)
+                              / max(np.linalg.norm(w), 1e-30)))
+        # both arms round their default-precision products to bfloat16 on
+        # the chip: they agree to that rounding, not to float32's
+        check(max(gaps) < (2e-2 if chip else 1e-4),
+              f"kda_scan differs from chunked_kda's jax.numpy form: {gaps}")
+        if chip:
+            check(_has_kernel(chunked_kda, *kargs),
+                  "kda_scan: no Mosaic kernel compiled")
+        out["kda_scan"] = {"shape": list(shape), "counters": kcounts,
+                           "worst_relative_gap": max(gaps),
+                           "seconds": lap(t)}
+
     # ---- bn_act / bn_act_bwd: NOT in the default selection; run under an
     # explicit override where supported() says the rows fit
     z = jnp.asarray(rng.standard_normal((64, 7, 7, 512)), jnp.bfloat16)

@@ -28,7 +28,15 @@ exact at ANY decay: blocks of
 ``sub`` x ``sub`` on the diagonal are written out channel by channel
 (``exp(G_r - G_i)`` itself, never a quotient of two exponentials), blocks
 below it go through the MXU around a reference row at which both factors
-are at most 1. Plain ``jax.numpy`` / ``lax``; XLA writes the backward pass.
+are at most 1.
+
+Two executions of that one algorithm, chosen at trace time by what the
+code can observe (``perf.pallas.take("kda_scan", supported(...))``, the
+``kernel.pallas_kda_scan`` / ``kernel.xla_kda_scan`` counters say which):
+plain ``jax.numpy`` / ``lax`` here, XLA writing the backward pass, for any
+shape and backend; and, on a TPU for heads of 128 in chunks of 64, the
+Pallas kernels of ``perf/pallas/kda.py``, forward and backward, which keep
+a chunk's terms in VMEM.
 """
 
 from __future__ import annotations
@@ -47,6 +55,8 @@ from deeplearning4j_tpu.nn.conf.layers import (
 )
 from deeplearning4j_tpu.nn.conf.normalization import rms_norm
 from deeplearning4j_tpu.nn.initializers import init_weights
+from deeplearning4j_tpu.perf import pallas as pk
+from deeplearning4j_tpu.perf.pallas import kda as kda_kernels
 
 
 def causal_depthwise_conv(x, w):
@@ -156,6 +166,16 @@ def _chunk_terms(qc, kc, vc, gc, bc, sub: int):
             jnp.exp(g_end[..., 0, :]))
 
 
+def _state_step(s, terms):
+    """One chunk's ``_chunk_terms`` met with the state (..., K, V) at its
+    entry: the state at its exit and the chunk's output."""
+    p, w, uv, qd, kd, decay = terms
+    u = uv - jnp.matmul(w, s)
+    o = jnp.matmul(qd, s) + jnp.matmul(p, u)
+    s = decay[..., :, None] * s + jnp.matmul(jnp.swapaxes(kd, -1, -2), u)
+    return s, o
+
+
 def chunked_kda(q, k, v, g, b, chunk: int = 64, sub: int = 8,
                 group: int = 2):
     """The KDA recurrence from a zero state, chunk-wise. ``q``, ``k``,
@@ -171,7 +191,10 @@ def chunked_kda(q, k, v, g, b, chunk: int = 64, sub: int = 8,
     pass keeps one state a group. ``sub`` is the block of both the
     written-out diagonal and the forward substitution: 8 keeps the solve
     within float32 rounding of the recurrence at any likeness of the keys
-    (16 reads 1e-3 off, 32 overflows)."""
+    (16 reads 1e-3 off, 32 overflows). Where ``perf.pallas.kda.supported``
+    takes the shape and the ``kda_scan`` family is on (a TPU, or forced),
+    the same chunks run as that module's kernels instead of the scan
+    below; ``group`` is then not used."""
     bsz, t, h, kdim = q.shape
     vdim = v.shape[-1]
     if chunk % sub or chunk & (chunk - 1):
@@ -179,6 +202,13 @@ def chunked_kda(q, k, v, g, b, chunk: int = 64, sub: int = 8,
                          f"multiple of sub {sub}")
     pad = (-t) % chunk
     n = (t + pad) // chunk
+    if pk.take("kda_scan", kda_kernels.supported(q, k, v, g, b, chunk, sub)):
+        if pad:
+            q, k, v, g, b = (jnp.pad(a, ((0, 0), (0, pad))
+                                     + ((0, 0),) * (a.ndim - 2))
+                             for a in (q, k, v, g, b))
+        return kda_kernels.kda_scan(q, k, v, g.astype(jnp.float32),
+                                    b.astype(jnp.float32))[:, :t]
 
     def chunks(a):                       # (B, T, H, X) -> (N, B, H, C, X)
         if pad:
@@ -194,20 +224,13 @@ def chunked_kda(q, k, v, g, b, chunk: int = 64, sub: int = 8,
     def grouped(a):                      # (N, ...) -> (N / per, per, ...)
         return a.reshape((n // per, per) + a.shape[1:])
 
-    def step(s, terms):
-        p, w, uv, qd, kd, decay = terms
-        u = uv - jnp.matmul(w, s)
-        o = jnp.matmul(qd, s) + jnp.matmul(p, u)
-        s = decay[..., :, None] * s + jnp.matmul(jnp.swapaxes(kd, -1, -2), u)
-        return s, o
-
     def group_step(s, xs):
         """``per`` chunks: their state-free terms, then the state through
         them one chunk after another."""
         terms = _chunk_terms(*(a.astype(jnp.float32) for a in xs), sub=sub)
         outs = []
         for i in range(per):
-            s, o = step(s, tuple(a[i] for a in terms))
+            s, o = _state_step(s, tuple(a[i] for a in terms))
             outs.append(o)
         return s, jnp.stack(outs)
 

@@ -7,7 +7,7 @@ BN-train stats/normalize/residual traffic XLA refuses to fuse across
 costs ~4.7 extra full activation-set HBM crossings (tools/PROFILE_r5.md).
 This package holds the kernels that cross that line by hand — SURVEY
 L0/§7's replacement for libnd4j's C++ kernels exactly where XLA's fusion
-control runs out. Two families, each slotted behind a boundary the repo
+control runs out. Three families, each slotted behind a boundary the repo
 already parity-tests:
 
 - **bn** (:mod:`perf.pallas.bn`): fused BN-train forward/backward behind
@@ -19,6 +19,10 @@ already parity-tests:
   gather-accumulate for ``PQIndex``/``IVFPQIndex`` and the int4
   nibble-unpack fused against the int8×int8→int32 dot for the int4
   tables and int4 quantized weights.
+- **kda** (:mod:`perf.pallas.kda`): the chunked scan of Kimi Delta
+  Attention behind ``chunked_kda`` (nn/conf/linear_attention.py), forward
+  and backward behind one custom-VJP — a chunk's decayed scores and its
+  triangular solve made and used in VMEM, the state carried in scratch.
 
 Selection contract (every kernel, no exceptions):
 
@@ -28,7 +32,8 @@ Selection contract (every kernel, no exceptions):
    :func:`override` or the ``DLT_PALLAS`` env var (every family), or
    automatically on a TPU backend (the families of
    :data:`TPU_AUTO_FAMILIES` only) — AND the call site's shape predicate
-   (``bn.supported``, ``adc.pq_supported``, ``adc.int4_supported``) says
+   (``bn.supported``, ``adc.pq_supported``, ``adc.int4_supported``,
+   ``kda.supported``) says
    the kernel fits. Anywhere else the reference runs.
 2. Off-TPU, a force-enabled kernel runs in Pallas **interpret mode**
    (:func:`interpret` resolves true) — this is how CPU CI bitwise/
@@ -52,7 +57,9 @@ What the v5e compiler (jax 0.9.0 / libtpu 0.0.34) says, family by
 family, is kept as tests: tests/test_chip_compile.py compiles every
 auto-selected kernel for a described v5e at a main-path shape, and
 chip_smoke.py's ``kernels`` phase runs each against its reference on
-the chip. No kernel has a measured speed yet (ROADMAP Speed 3/6).
+the chip. Measured speed: ``kda_scan`` alone (PERF.md §5-6, PR 27: the
+cell's scan 221 -> 103 ms a step); the retrieval kernels against XLA at 1M
+rows are still unmeasured (ROADMAP Speed 3/6).
 """
 
 from __future__ import annotations
@@ -80,6 +87,8 @@ FAMILIES: Dict[str, str] = {
     "int4_dot": "int4 nibble-unpack fused against the int32 dot "
                 "(retrieval/index.py brute table, quant/lowering.py "
                 "dense weights)",
+    "kda_scan": "chunked_kda's scan over chunks, forward and backward "
+                "(nn/conf/linear_attention.py)",
 }
 
 # Families the automatic rule selects on a TPU backend: those the v5e
@@ -92,7 +101,7 @@ FAMILIES: Dict[str, str] = {
 # - adc_ivf_pq: data-dependent CSR row gather in-kernel, which Mosaic
 #   does not lower (its flat sibling's jnp.take: "Shape mismatch in
 #   input, indices and output"); needs a DMA rework (ROADMAP Speed 6).
-TPU_AUTO_FAMILIES = frozenset({"adc_pq", "int4_dot"})
+TPU_AUTO_FAMILIES = frozenset({"adc_pq", "int4_dot", "kda_scan"})
 # No shape of these compiles, so not even an explicit enable (a
 # TuningRecord's ``pallas_kernels=True`` is applied process-wide) selects
 # them outside interpret mode.

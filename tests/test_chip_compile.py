@@ -7,7 +7,8 @@ interpret-mode parity tests and four of five families were refused here
 (VMEM overflow, a bf16 vector compare, an in-kernel gather, a
 rank-changing reshape). So each kernel the automatic TPU rule selects
 (``perf.pallas.TPU_AUTO_FAMILIES``), plus flash attention, the Word2Vec
-scatter and the routed experts' grouped products, is compiled ``interpret=False`` at one main-path shape;
+scatter and the routed experts' grouped products, is compiled
+``interpret=False`` at one main-path shape;
 the BN family — outside the automatic rule — is compiled where its
 ``supported()`` says it fits, and must refuse what does not.
 
@@ -117,6 +118,40 @@ def _grouped_experts(S):
                      S((8, 1024, 2304), BF16), S((8,), I32))
 
 
+def _kda_scan(S):
+    # Kimi Delta Attention's chunked scan at the Kimi Linear share's shape,
+    # one sequence of 8192 steps, 32 heads of 128: the forward kernel that
+    # saves the chunks' entry states and the backward kernel, through
+    # chunked_kda's own selection
+    from deeplearning4j_tpu.nn.conf.linear_attention import chunked_kda
+    from deeplearning4j_tpu.perf.pallas import kda
+    shape = (1, 8192, 32, 128)
+    args = (S(shape, BF16),) * 3 + (S(shape, F32), S(shape[:3], F32))
+    assert pk.take("kda_scan", kda.supported(*args, 64, 8))
+
+    def fwd_bwd(*a):
+        return jax.grad(lambda *a: jnp.sum(chunked_kda(*a)),
+                        argnums=range(5))(*a)
+
+    return fwd_bwd, args, ("kda_scan_fwd", "kda_scan_bwd")
+
+
+def _kda_scan_float32(S):
+    # the same kernels with every product in float32 (what they compile to
+    # under jax.default_matmul_precision("highest")), float32 inputs, a
+    # head count that one grid step takes whole
+    from deeplearning4j_tpu.nn.conf.linear_attention import chunked_kda
+    shape = (2, 1024, 6, 128)
+    args = (S(shape, F32),) * 4 + (S(shape[:3], F32),)
+
+    def fwd_bwd(*a):
+        with jax.default_matmul_precision("highest"):
+            return jax.grad(lambda *a: jnp.sum(chunked_kda(*a)),
+                            argnums=range(5))(*a)
+
+    return fwd_bwd, args, ("kda_scan_fwd", "kda_scan_bwd")
+
+
 def _bn_fwd(S):
     # ResNet50 batch 128, the one stage whose rows fit: 7x7
     z = S((128, 7, 7, 2048), BF16)
@@ -135,13 +170,16 @@ def _bn_bwd(S):
 # (builder, family the automatic TPU rule must select for it | None)
 AUTO_CASES = [(_pq, "adc_pq"), (_int4_table, "int4_dot"),
               (_int4_weights, "int4_dot"), (_flash, None), (_scatter, None),
-              (_grouped_experts, None)]
+              (_grouped_experts, None), (_kda_scan, "kda_scan"),
+              (_kda_scan_float32, None)]
 EXPLICIT_CASES = [_bn_fwd, _bn_bwd]
 
 
-def _compiles_with_kernel(fn, args):
+def _compiles_with_kernel(fn, args, kernels=()):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    for name in kernels:
+        assert name in text, f"no kernel named {name} in the program"
 
 
 @pytest.mark.parametrize("build", [c[0] for c in AUTO_CASES]

@@ -28,6 +28,7 @@ from deeplearning4j_tpu.nn.conf.recurrent import (EmbeddingSequenceLayer,
                                                   TokenOutputLayer)
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
 from deeplearning4j_tpu.optimize.updaters import Adam
+from deeplearning4j_tpu.perf import pallas as pk
 
 # float32 on the CPU: what differs between two orders of the same sums
 TOL = 2e-5
@@ -61,9 +62,34 @@ def _recurrence(q, k, v, g, b):
     return out
 
 
-def _kda_inputs(t, decay, seed=0):
+@pytest.fixture(params=["xla", "pallas"])
+def kda_impl(request):
+    """The two executions of ``chunked_kda``: plain ``jax.numpy`` at the
+    small heads the other tests use, and the Pallas kernels (interpreted
+    on the CPU) at the smallest shape they take: heads of 128, one
+    sequence. Yields the head size to draw inputs at, and holds the test
+    to the execution it asked for by the ``kernel.*_kda_scan`` counters."""
+    from deeplearning4j_tpu.perf.compile_watch import GLOBAL
+
+    def taken():
+        counters = GLOBAL.as_dict().get("counters", {})
+        return [counters.get(f"kernel.{impl}_kda_scan", 0)
+                for impl in ("xla", "pallas")]
+
+    before = taken()
+    if request.param == "xla":
+        with pk.override(enabled=False):
+            yield 8
+    else:
+        with pk.override(enabled=True, interpret=True):
+            yield 128
+    rose = [b > a for a, b in zip(before, taken())]
+    assert rose == [request.param == "xla", request.param == "pallas"]
+
+
+def _kda_inputs(t, decay, seed=0, kd=8):
     ks = _keys(5, seed)
-    shape = (2, t, 2, 8)
+    shape = (2 if kd == 8 else 1, t, 2, kd)
     q = jax.random.normal(ks[0], shape)
     k = jax.random.normal(ks[1], shape)
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
@@ -75,11 +101,11 @@ def _kda_inputs(t, decay, seed=0):
 
 @pytest.mark.parametrize("t,decay", [(64, 1.0), (70, 0.05), (130, 1.0),
                                      (33, 40.0), (128, 40.0)])
-def test_chunked_kda_is_the_token_recurrence(t, decay):
+def test_chunked_kda_is_the_token_recurrence(t, decay, kda_impl):
     """Any length (not only multiples of the chunk) and any decay: at 40 a
     step a quotient of two exponentials would overflow, the channel-by-
     channel diagonal blocks do not."""
-    args = _kda_inputs(t, decay)
+    args = _kda_inputs(t, decay, kd=kda_impl)
     got = chunked_kda(*args, chunk=64)
     want = _recurrence(*args)
     assert np.all(np.isfinite(got))
@@ -88,14 +114,16 @@ def test_chunked_kda_is_the_token_recurrence(t, decay):
 
 
 @pytest.mark.parametrize("alike", [0.5, 0.9, 1.0])
-def test_chunked_kda_stays_the_recurrence_when_keys_point_the_same_way(alike):
+def test_chunked_kda_stays_the_recurrence_when_keys_point_the_same_way(
+        alike, kda_impl):
     """Keys that are alike, little decay and b near 1 make (I + A)^-1 of a
     whole chunk grow like 2^64: an explicit inverse overflows float32 (it
     did, on the chip, in the second training step). Forward substitution
     over blocks of 8 rows is as stable as the recurrence itself."""
     ks = _keys(5, 4)
-    shape = (1, 256, 2, 16)
-    k = alike * jax.random.normal(ks[0], (1, 1, 2, 16)) + (
+    kd = 16 if kda_impl == 8 else kda_impl
+    shape = (1, 256 if kd == 16 else 192, 2, kd)
+    k = alike * jax.random.normal(ks[0], (1, 1, 2, kd)) + (
         1 - alike) * jax.random.normal(ks[1], shape)
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
     q = 0.3 * jax.random.normal(ks[2], shape)
@@ -111,8 +139,9 @@ def test_chunked_kda_stays_the_recurrence_when_keys_point_the_same_way(alike):
     assert all(np.all(np.isfinite(a)) for a in grads)
 
 
-def test_chunked_kda_gradients_match_a_scan_over_tokens():
-    args = _kda_inputs(70, 2.0, seed=3)
+def test_chunked_kda_gradients_match_a_scan_over_tokens(kda_impl):
+    args = _kda_inputs(70, 2.0, seed=3, kd=kda_impl)
+    chunk = 32 if kda_impl == 8 else 64
 
     def scan_form(q, k, v, g, b):
         def step(s, inp):
@@ -130,7 +159,7 @@ def test_chunked_kda_gradients_match_a_scan_over_tokens():
     def scalar(f):
         return lambda *a: jnp.sum(jnp.sin(f(*a)))
 
-    got = jax.grad(scalar(lambda *a: chunked_kda(*a, chunk=32, sub=8)),
+    got = jax.grad(scalar(lambda *a: chunked_kda(*a, chunk=chunk, sub=8)),
                    argnums=range(5))(*args)
     want = jax.grad(scalar(scan_form), argnums=range(5))(*args)
     for a, b in zip(got, want):

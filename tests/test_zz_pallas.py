@@ -372,3 +372,223 @@ def test_bench_pallas_quick_smoke():
             assert f"{stem}_{tag}" in metrics, sorted(metrics)
     assert metrics["pallas_bn_block_step_ms_on"]["speedup_vs_off"] > 0
     assert metrics["pallas_bn_block_step_ms_on"]["kernel_mode"] == "interpret"
+
+
+# ----------------------------------------------------- KDA's chunked scan
+# perf/pallas/kda.py behind nn/conf/linear_attention.py::chunked_kda. The
+# recurrence, the keys that point the same way and the gradients against a
+# scan over tokens are tests/test_sequence_layers.py's, run over both
+# executions; here the kernels' hand-written pieces are held to jax.vjp of
+# the jax.numpy form, and the selection to what it says.
+from deeplearning4j_tpu.nn.conf import linear_attention as la  # noqa: E402
+from deeplearning4j_tpu.perf.pallas import kda  # noqa: E402
+
+
+@pytest.fixture
+def exact_products():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def _kda_chunk(seed, decay, c=64, kd=128):
+    ks = jax.random.split(jax.random.key(seed), 8)
+    q = 0.3 * jax.random.normal(ks[0], (c, kd))
+    k = jax.random.normal(ks[1], (c, kd))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (c, kd))
+    g = -decay * jax.random.uniform(ks[3], (c, kd))
+    b = jax.random.uniform(ks[4], (c, 1))
+    state = jax.random.normal(ks[5], (kd, kd))      # (V, K), as the kernels
+    do = jax.random.normal(ks[6], (c, kd))
+    dstate = jax.random.normal(ks[7], (kd, kd))
+    return q, k, v, g, b, state, do, dstate
+
+
+def _close(got, want, tol=2e-5):
+    scale = max(1.0, float(jnp.max(jnp.abs(want))))
+    assert float(jnp.max(jnp.abs(got - want))) < tol * scale
+
+
+def _jnp_chunk(q, k, v, g, b, state):
+    """A chunk as ``chunked_kda`` computes it: ``_chunk_terms`` then
+    ``_state_step``, the state carried transposed as the kernels do."""
+    terms = la._chunk_terms(q, k, v, g, b[:, 0], sub=8)
+    s, o = la._state_step(state.T, terms)
+    return o, s.T
+
+
+@pytest.mark.parametrize("decay", [0.05, 1.0, 40.0])
+def test_kda_chunk_forward_and_backward_are_chunk_terms_and_step(
+        decay, exact_products):
+    q, k, v, g, b, state, do, dstate = _kda_chunk(1, decay)
+    want, vjp = jax.vjp(_jnp_chunk, q, k, v, g, b, state)
+    for a, w in zip(kda.chunk_forward(q, k, v, g, b, state, True), want):
+        _close(a, w)
+    got = kda.chunk_backward(q, k, v, g, b, state, do, dstate, True)
+    for a, w in zip(got, vjp((do, dstate))):
+        assert np.all(np.isfinite(a))
+        _close(a, w)
+
+
+@pytest.mark.parametrize("decay", [0.05, 40.0])
+def test_kda_scores_backward_is_the_vjp_of_decayed_scores(decay,
+                                                          exact_products):
+    q, k, _, g, _, _, _, _ = _kda_chunk(2, decay)
+    c = q.shape[0]
+    ks = jax.random.split(jax.random.key(5), 2)
+    lower = jnp.tril(jnp.ones((c, c), bool))
+    dp = jnp.where(lower, jax.random.normal(ks[0], (c, c)), 0.0)
+    dkk = jnp.where(jnp.tril(lower, -1), jax.random.normal(ks[1], (c, c)),
+                    0.0)
+    g_cum = jnp.cumsum(g, 0)
+
+    def scores(q, kx, k, g_cum):      # kx: k as the rows' factor
+        return la._decayed_scores(jnp.stack([q, kx]), k, g_cum, 8)
+
+    both, vjp = jax.vjp(scores, q, k, k, g_cum)
+    want_q, want_kx, want_k, want_g = vjp(jnp.stack([dp, dkk]))
+    p, kk_off, kk_cols = kda._chunk_terms(q, k, g, True)[1:]
+    _close(p, both[0])
+    _close(kk_off + kda._placed(kk_cols), jnp.tril(both[1], -1))
+    dq, dkx, dk = kda._scores_backward(q, k, g_cum, dp, dkk, True)
+    _close(dq, want_q)
+    _close(dkx, want_kx)
+    _close(dk, want_k)
+    _close(q * dq + k * dkx - k * dk, want_g)
+
+
+def test_kda_block_solves_are_solve_unit_lower_and_its_transpose(
+        exact_products):
+    """Keys nearly alike and b near 1: the system whose explicit inverse
+    overflows. Forward substitution over blocks of 8, the diagonal blocks'
+    inverse applied by substitution inside the block, against
+    ``_solve_unit_lower`` (Neumann inverses of the blocks) and its vjp."""
+    c, kd = 64, 128
+    ks = jax.random.split(jax.random.key(7), 4)
+    k = 0.95 * jax.random.normal(ks[0], (1, kd)) + 0.05 * jax.random.normal(
+        ks[1], (c, kd))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    a = jnp.tril(0.97 * k @ k.T, -1)
+    rhs, dx = (jax.random.normal(key, (c, kd)) for key in ks[2:])
+    col_in_block, _ = kda._block_masks(c)
+    inside = (col_in_block >= 0) & (col_in_block < 8)
+    a_off = jnp.where(inside, 0.0, a)
+    a_cols = [jnp.sum(jnp.where(col_in_block == i, a, 0.0), 1, keepdims=True)
+              for i in range(8)]
+    want, vjp = jax.vjp(lambda rhs: la._solve_unit_lower(a, rhs, 8), rhs)
+    _close(kda._solve_lower(a_off, a_cols, rhs), want, 5e-5)
+    _close(kda._solve_upper(a_off, a_cols, dx), vjp(dx)[0], 5e-5)
+
+
+def _kda_layer_grads(layer, t, seed=0):
+    it = InputType.recurrent(12, t)
+    params, state = layer.init(jax.random.key(seed), it)
+    x = jax.random.normal(jax.random.key(seed + 1), (1, t, 12))
+
+    def loss(params, x):
+        return jnp.sum(jnp.sin(layer.apply(params, state, x)[0]))
+
+    return jax.value_and_grad(loss, argnums=(0, 1))(params, x)
+
+
+def _kda_counters():
+    from deeplearning4j_tpu.perf.compile_watch import GLOBAL
+    counters = GLOBAL.as_dict().get("counters", {})
+    return (counters.get("kernel.xla_kda_scan", 0),
+            counters.get("kernel.pallas_kda_scan", 0))
+
+
+def test_kda_layer_is_untouched_where_the_kernels_do_not_apply():
+    """Family off, and family on at a shape ``supported`` refuses (heads
+    of 8, chunks of 16): the same program, bit for bit, and the
+    ``kernel.xla_kda_scan`` counter says so both times."""
+    layer = la.KimiDeltaAttention(n_heads=2, head_dim=8, chunk=16)
+    before = _kda_counters()
+    with pk.override(enabled=False):
+        off = _kda_layer_grads(layer, 40)
+    with pk.override(enabled=True, interpret=True):
+        on = _kda_layer_grads(layer, 40)
+    assert _kda_counters() == (before[0] + 2, before[1])
+    for a, b in zip(jax.tree.leaves(off), jax.tree.leaves(on)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_kda_layer_counts_the_execution_it_took_and_agrees():
+    """Heads of 128, 70 steps (no multiple of 64): the kernels serve when
+    the family is on (``kernel.pallas_kda_scan``), ``jax.numpy`` when it is
+    off, and the layer's output and gradients agree."""
+    layer = la.KimiDeltaAttention(n_heads=2, head_dim=128, low_rank=8)
+    before = _kda_counters()
+    with jax.default_matmul_precision("highest"):
+        with pk.override(enabled=False):
+            off = _kda_layer_grads(layer, 70)
+        assert _kda_counters() == (before[0] + 1, before[1])
+        with pk.override(enabled=True, interpret=True):
+            on = _kda_layer_grads(layer, 70)
+        assert _kda_counters() == (before[0] + 1, before[1] + 1)
+    for a, b in zip(jax.tree.leaves(off), jax.tree.leaves(on)):
+        _close(a, b, 1e-4)
+    assert "kda_scan" in pk.FAMILIES and "kda_scan" in pk.TPU_AUTO_FAMILIES
+    assert not kda.supported(*[jnp.zeros((1, 64, 2, 128))] * 4,
+                             jnp.zeros((1, 64, 2)), 32, 8)
+
+
+def test_kda_kernels_take_two_sequences_of_heads_of_256(exact_products):
+    """The widest head ``supported`` lets in, a batch of two, three heads
+    (one grid step takes them all) and a length that is no multiple of
+    the chunk: output and all five gradients against ``jax.numpy``."""
+    ks = jax.random.split(jax.random.key(11), 5)
+    shape = (2, 70, 3, 256)
+    k = jax.random.normal(ks[1], shape)
+    args = (0.3 * jax.random.normal(ks[0], shape),
+            k / jnp.linalg.norm(k, axis=-1, keepdims=True),
+            jax.random.normal(ks[2], shape),
+            -jax.random.uniform(ks[3], shape),
+            jax.random.uniform(ks[4], shape[:3]))
+
+    def run(*a):
+        o = la.chunked_kda(*a)
+        return jnp.sum(jnp.sin(o)), o
+
+    with pk.override(enabled=False):
+        want = jax.value_and_grad(run, range(5), has_aux=True)(*args)
+    with pk.override(enabled=True, interpret=True):
+        assert kda.supported(*args, 64, 8)
+        got = jax.value_and_grad(run, range(5), has_aux=True)(*args)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        _close(a, b)
+
+
+def test_kda_kernels_lower_under_the_layers_scan_scope():
+    """``kda.device_ms_per_step`` and ``kda.scan_roofline_pct`` find their
+    operations by ``op_name`` in the step's HLO text: every operation the
+    forward and backward kernels (here their interpreted bodies) lower to
+    has to carry the layer's scope and ``kda.scan``, in the backward pass
+    too."""
+    from deeplearning4j_tpu.nn.conf.graph import GraphBuilder
+    from deeplearning4j_tpu.nn.conf.recurrent import RnnOutputLayer
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+    conf = (GraphBuilder(NeuralNetConfiguration.builder().seed(3)
+                         .updater(Sgd(learning_rate=0.05)))
+            .add_inputs("in")
+            .add_layer("kda1", la.KimiDeltaAttention(
+                n_heads=2, head_dim=128, low_rank=8), "in")
+            .add_layer("out", RnnOutputLayer(n_out=3, loss="mcxent"), "kda1")
+            .set_outputs("out")
+            .set_input_types(InputType.recurrent(12, 64)).build())
+    with pk.override(enabled=True, interpret=True):
+        net = ComputationGraph(conf).init()
+        x = jnp.zeros((1, 64, 12), jnp.float32)
+        y = jnp.zeros((1, 64, 3), jnp.float32)
+        step = net._get_jitted("train")
+        hlo = step.lower(net.params, net.state, net.opt_state, net._rng,
+                         [x], [y], None, None).compile().as_text()
+    ops = [l for l in hlo.splitlines() if "op_name=" in l]
+    for kernel, way in (("kda_scan_fwd", "jvp("), ("kda_scan_bwd",
+                                                   "transpose(")):
+        mine = [l for l in ops if kernel in l]
+        assert len(mine) > 50, (kernel, len(mine))
+        for l in mine:
+            name = l.split('op_name="')[1].split('"')[0]
+            assert "KimiDeltaAttention:kda1" in name and "kda.scan" in name
+            assert way in name, name
