@@ -819,9 +819,8 @@ def consume_resume_state(model):
 def resume_plan(model, num_epochs: int):
     """Consume the model's resume marker and return ``(epochs_to_run,
     skip_batches)`` for a fit targeting ``num_epochs`` TOTAL epochs. The
-    single definition of the resume arithmetic — every fit wire-in
-    (MultiLayerNetwork, ComputationGraph, ParallelWrapper, ClusterTrainer)
-    calls this instead of re-deriving it."""
+    single definition of the resume arithmetic, called by the one epoch
+    loop under every fit (nn/engine.py ``run_epochs``)."""
     rs = consume_resume_state(model)
     if rs is None:
         return num_epochs, 0

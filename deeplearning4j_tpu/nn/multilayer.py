@@ -15,50 +15,35 @@ traced program; listeners and iterators remain host-side, as in the reference.
 
 from __future__ import annotations
 
-import functools
-from typing import List, Optional, Sequence
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.datasets.dataset import DataSet
-from deeplearning4j_tpu.nn.activations import get_activation
 from deeplearning4j_tpu.nn.conf.layers import (apply_constraints, apply_layer,
                                                dropout_input, noisy_params)
 from deeplearning4j_tpu.nn.conf.network import MultiLayerConfiguration
-from deeplearning4j_tpu.optimize.fused_update import bucketed_apply
-from deeplearning4j_tpu.optimize.updaters import (gradient_normalization,
-                                                  is_sgd_family)
-from deeplearning4j_tpu.perf.compile_watch import CompileWatch
+from deeplearning4j_tpu.nn.engine import (Network, _f32, bind_epoch,
+                                          run_epochs)
+from deeplearning4j_tpu.obs.trace import get_tracer
+from deeplearning4j_tpu.optimize.updaters import is_sgd_family
 import optax
 
 
-def _compute_dtype(name: str):
-    return {"float32": jnp.float32, "bfloat16": jnp.bfloat16,
-            "float16": jnp.float16, "float64": jnp.float64}[name]
-
-
-class MultiLayerNetwork:
-    """Sequential network with fit/output/score (see module docstring)."""
+class MultiLayerNetwork(Network):
+    """Sequential network with fit/output/score (see module docstring):
+    the stack's forward pass, staging and programs over ``nn/engine.py``'s
+    ``Network``, which holds the steps and the fit path."""
 
     def __init__(self, conf: MultiLayerConfiguration):
-        self.conf = conf
         self.layers = conf.wired_layers()
         self._pre = conf.resolved_preprocessors()
         if not self.layers:
             raise ValueError("Empty layer list")
-        self._dtype = _compute_dtype(conf.dtype)
-        # per-layer optax transforms (reference BaseMultiLayerUpdater blocks).
-        # Every layer gets its updater — a layer whose init() returns an empty
-        # param dict makes the transform a no-op, and layers with
-        # non-regularizable trainables (e.g. batchnorm gamma/beta) still train.
-        self._updaters = [
-            l.updater if getattr(l, "updater", None) is not None
-            else conf.updater
-            for l in self.layers
-        ]
-        self._txs = [u.to_optax() for u in self._updaters]
+        self._param_layers = list(enumerate(self.layers))
+        super().__init__(conf)
         # whether each layer's OUTPUT still has a time axis the feature mask
         # applies to; a per-step mask must not survive layers that collapse
         # time (cnn/ff) or it breaks the loss shape (graph.py does the same)
@@ -68,53 +53,11 @@ class MultiLayerNetwork:
                 for l, it in zip(self.layers, conf.layer_input_types())]
         except Exception:
             self._mask_survives = [True] * len(self.layers)
-        self._gnorms = [
-            gradient_normalization(getattr(l, "gradient_normalization", None),
-                                   getattr(l, "gradient_normalization_threshold", 1.0))
-            for l in self.layers
-        ]
-        self.params: Optional[List[dict]] = None
-        self.state: Optional[List[dict]] = None
-        self.opt_state: Optional[list] = None
-        self.listeners: list = []
-        self.iteration = 0
-        self.epoch = 0
-        self.last_batch_size: Optional[int] = None
-        self._score: Optional[float] = None
-        self._rng = None
-        self._jit_cache = {}
-        # per-network compile/dispatch counters (perf/compile_watch.py);
-        # every jitted program minted by _get_jitted records here
-        self.compile_watch = CompileWatch("MultiLayerNetwork")
-        self._rnn_carries = None  # stateful rnnTimeStep carries
-        self._last_features = None  # last fit minibatch (listener sampling)
         self._window_scores = None  # fit_tbptt_fused: every window's loss
-        # set by checkpoint.CheckpointManager.restore_latest; consumed by
-        # the next fit() for exact-step resume (skip already-seen batches).
-        # _restored_from is informational provenance (also set by
-        # restore_best) and never consumed.
-        self._resume_state = None
-        self._restored_from = None
-        # compressed gradient collectives (parallel/compress.py): the
-        # scheme config plus device-resident error-feedback state threaded
-        # through the jitted step next to opt_state. Set via
-        # enable_grad_compression / ParallelWrapper(grad_compression=);
-        # restored from checkpoint metadata by utils/serialization.
-        self.grad_compression = None
-        self.compress_state = None
-        # on-device augmentation (datasets/augment.py): applied to the
-        # features INSIDE the jitted train step, seeded from the step rng.
-        # Part of the jit-cache key — see set_augmentation.
-        self.augmentation = None
 
-    def set_augmentation(self, augmentation) -> "MultiLayerNetwork":
-        """Enable on-device augmentation (a frozen
-        ``datasets.augment.ImageAugmentation``, or None to disable): the
-        train step augments its feature batch in-graph, seeded from the
-        step rng key, so epochs stay deterministic and resume replays
-        bitwise. Inference/score paths are unaffected (no rng there)."""
-        self.augmentation = augmentation
-        return self
+    def _collect(self, entries, like=None):
+        # every layer of a stack owns an entry: nothing of ``like`` is kept
+        return [entries[i] for i in range(len(self.layers))]
 
     # ------------------------------------------------------------------ init
     def init(self, seed: Optional[int] = None,
@@ -125,40 +68,16 @@ class MultiLayerNetwork:
         layer-named message instead of seconds later inside an XLA trace.
         Opt out per call with ``validate=False`` or process-wide with
         ``DL4J_TPU_VALIDATE=0``."""
-        if validate is None:
-            import os
-            validate = os.environ.get("DL4J_TPU_VALIDATE", "1") != "0"
-        if validate:
-            self.conf.validate()
-        rng = jax.random.key(self.conf.seed if seed is None else seed)
-        types = self.conf.layer_input_types()
+        return self._init_drawn(self._seeded_key(seed, validate))
+
+    def _draw(self, rng):
         params, state = [], []
-        for layer, it in zip(self.layers, types):
+        for layer, it in zip(self.layers, self.conf.layer_input_types()):
             rng, k = jax.random.split(rng)
             p, s = layer.init(k, it, jnp.float32)  # master params in f32
             params.append(p)
             state.append(s)
-        self.params = params
-        self.state = state
-        self.opt_state = [tx.init(p) for tx, p in zip(self._txs, params)]
-        self._rng = rng
-        return self
-
-    def num_params(self) -> int:
-        if self.params is None:
-            return 0
-        return sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(self.params))
-
-    def set_listeners(self, *listeners):
-        self.listeners = list(listeners)
-        return self
-
-    def add_listener(self, listener):
-        self.listeners.append(listener)
-
-    def score(self) -> Optional[float]:
-        """Most recent minibatch score (reference Model.score())."""
-        return None if self._score is None else float(self._score)
+        return params, state, rng
 
     # --------------------------------------------------------------- forward
     def _forward(self, params, state, x, train: bool, rng, fmask, carries=None):
@@ -191,9 +110,7 @@ class MultiLayerNetwork:
                 x_in = dropout_input(x, layer.dropout, train, k)
                 preout = layer.pre_output(p_i, x_in)
                 # loss math in f32 (preout may be a pytree: CenterLoss/YOLO)
-                preout = jax.tree_util.tree_map(
-                    lambda a: a.astype(jnp.float32)
-                    if a.dtype in (jnp.bfloat16, jnp.float16) else a, preout)
+                preout = jax.tree_util.tree_map(_f32, preout)
                 x = layer.output_activations(preout)
                 new_state.append(state[i])
                 new_carries.append({})
@@ -216,154 +133,123 @@ class MultiLayerNetwork:
             acts.append(x)
         return acts, preout, new_state, cur_mask, new_carries
 
-    def _regularization(self, params):
-        """L1/L2 penalty (reference BaseLayer.calcL2/calcL1; score term added in
-        BaseOutputLayer.computeScore fullNetworkL1/L2)."""
-        from deeplearning4j_tpu.nn.conf.layers import (
-            _bias_keys, regularization_coefficients, resolve_param_path,
-        )
-        total = 0.0
-        for layer, p in zip(self.layers, params):
-            l1, l2, l1b, l2b = regularization_coefficients(layer)
-            for key in layer.regularizable():
-                w = resolve_param_path(p, key)
-                if w is not None:
-                    if w.dtype in (jnp.bfloat16, jnp.float16):
-                        w = w.astype(jnp.float32)
-                    if l2:
-                        total = total + 0.5 * l2 * jnp.sum(w * w)
-                    if l1:
-                        total = total + l1 * jnp.sum(jnp.abs(w))
-            if l1b or l2b:
-                # _bias_keys, not just "b": nested attention biases (q/b,
-                # k/b, ...) are penalized as attention.py's docstring claims
-                for bk in _bias_keys(layer, p):
-                    b = resolve_param_path(p, bk)
-                    if b.dtype in (jnp.bfloat16, jnp.float16):
-                        b = b.astype(jnp.float32)
-                    if l2b:
-                        total = total + 0.5 * l2b * jnp.sum(b * b)
-                    if l1b:
-                        total = total + l1b * jnp.sum(jnp.abs(b))
-        return total
+    # ------------------------------------------------- the engine's hooks
+    def _augment(self, x, rng):
+        return self.augmentation.apply(x, rng)
 
-    # ------------------------------------------------------------ train step
-    def _loss_fn(self, params, state, x, y, rng, fmask, lmask):
+    def _forward_loss(self, params, state, x, y, rng, fmask, lmask, carries):
         out_layer = self.layers[-1]
         if not out_layer.is_output_layer():
             raise ValueError("Last layer must be an output/loss layer to fit()")
-        if self.augmentation is not None and rng is not None:
-            # in-graph augmentation off a split of the STEP key: train-mode
-            # only (score/eval call with rng=None) and deterministic per
-            # (seed, step) — the dropout reproducibility contract
-            rng, ak = jax.random.split(rng)
-            x = self.augmentation.apply(x, ak)
-        acts, preout, new_state, cur_mask, _ = self._forward(params, state, x, True, rng, fmask)
-        lm = lmask if lmask is not None else (cur_mask if cur_mask is not None else None)
-        if y.dtype in (jnp.bfloat16, jnp.float16):
-            y = y.astype(jnp.float32)
-        loss = out_layer.compute_score(y, preout, lm)
-        loss = loss + self._regularization(params)
-        return loss, new_state
+        _, preout, new_state, cur_mask, new_carries = self._forward(
+            params, state, x, True, rng, fmask, carries)
+        loss = self._output_score(out_layer, y, preout, lmask, cur_mask)
+        return loss, (new_state if carries is None
+                      else (new_state, new_carries))
 
-    def _apply_updates(self, params, grads, opt_state):
-        """Per-layer optimizer application shared by the standard, fused and
-        tBPTT steps. Small leaves are horizontally fused across layers via
-        ``bucketed_apply`` (optimize/fused_update.py) — identical math, one
-        XLA fusion per updater config instead of one per leaf."""
-        results = bucketed_apply(range(len(self._txs)), self._updaters,
-                                 self._txs, self._gnorms, params, grads,
-                                 opt_state)
-        new_params = []
-        new_opt = []
-        for i in range(len(self._txs)):
-            updates, os = results[i]
-            new_params.append(apply_constraints(
-                self.layers[i], optax.apply_updates(params[i], updates)))
-            new_opt.append(os)
-        return new_params, new_opt
+    def _loss_fn_tbptt(self, params, state, carries, x, y, rng, fmask, lmask):
+        # a stack's window step does not augment (a graph's does: it goes
+        # through _loss_fn)
+        loss, aux = self._forward_loss(params, state, x, y, rng, fmask,
+                                       lmask, carries)
+        return loss + self._regularization(params), aux
 
-    def _make_train_step(self):
-        value_and_grad = jax.value_and_grad(self._loss_fn, has_aux=True)
+    def _stage(self, ds: DataSet):
+        return (jnp.asarray(ds.features), jnp.asarray(ds.labels),
+                None if ds.features_mask is None
+                else jnp.asarray(ds.features_mask),
+                None if ds.labels_mask is None
+                else jnp.asarray(ds.labels_mask))
+
+    def _rows(self, x) -> int:
+        return int(x.shape[0])
+
+    def _sample(self, x):
+        return lambda: x[:1]
+
+    def _wants_tbptt(self, x) -> bool:
+        if self.conf.backprop_type != "tbptt":
+            return False
+        # tbptt applies when the input has a time axis: 3-D dense sequences or
+        # 2-D integer index sequences (EmbeddingSequenceLayer) under an RNN
+        # input type
+        has_time_axis = x.ndim == 3 or (
+            x.ndim == 2 and self.conf.input_type is not None
+            and self.conf.input_type.kind == "rnn"
+            and not self.layers[0].input_kind() == "ff")
+        return has_time_axis and x.shape[1] > self.conf.tbptt_fwd_length
+
+    def _windows(self, x, y, fm, lm):
+        T = x.shape[1]
+        L = self.conf.tbptt_fwd_length
+        for s in range(0, T, L):
+            e = min(s + L, T)
+            # keep window length static where possible: last ragged window
+            # gets its own jit specialization
+            xs = x[:, s:e]
+            yield (xs, y[:, s:e] if y.ndim == 3 else y,
+                   None if fm is None else fm[:, s:e],
+                   None if lm is None else lm[:, s:e], self._sample(xs))
+
+    def _scan_steps(self, loss_fn, loss_args, stacked, params, state,
+                    opt_state, rng, cstate=None, carries=None):
+        """The fused programs' body: one optimizer step a slice of
+        ``stacked`` under ``lax.scan``, with the same per-step rng split
+        chain as ``fit``. Under a compression scheme ``cstate`` (the
+        error-feedback residual and controller) threads through the scan
+        carry exactly like opt_state, so K fused steps evolve it
+        identically to K per-batch fit() calls; tBPTT threads its
+        ``carries`` the same way. ``loss_args(carries, slice, key)`` is
+        what follows (params, state) in a call of ``loss_fn``. Returns
+        what it was given, in the programs' order (params, state,
+        opt_state[, cstate][, carries], rng), and the losses."""
+        value_and_grad = jax.value_and_grad(loss_fn, has_aux=True)
         comp = self.grad_compression
-        if comp is not None:
-            # compressed collectives (parallel/compress.py): the encode→
-            # decode + error-feedback residual update runs INSIDE the
-            # compiled step on the gradient pytree; cstate is donated
-            # alongside opt_state
-            def train_step_compressed(params, state, opt_state, cstate, rng,
-                                      x, y, fmask, lmask):
-                (loss, new_state), grads = value_and_grad(
-                    params, state, x, y, rng, fmask, lmask)
+
+        def body(carry, inp):
+            params, state, opt_state, cstate, carries, rng = carry
+            rng, k = jax.random.split(rng)   # same chain as fit()
+            (loss, aux), grads = value_and_grad(
+                params, state, *loss_args(carries, inp, k))
+            if comp is not None:
                 grads, cstate = comp.apply(grads, cstate)
-                new_params, new_opt = self._apply_updates(params, grads,
-                                                          opt_state)
-                return new_params, new_state, new_opt, cstate, loss
+            new_params, new_opt = self._apply_updates(params, grads,
+                                                      opt_state)
+            new_state, carries = (aux, None) if carries is None else aux
+            return (new_params, new_state, new_opt, cstate, carries,
+                    rng), loss
 
-            return jax.jit(train_step_compressed, donate_argnums=(0, 1, 2, 3))
-
-        # the function's name is the program's in a profiler trace
-        # (jit_train_step): keep it stable
-        def train_step(params, state, opt_state, rng, x, y, fmask, lmask):
-            (loss, new_state), grads = value_and_grad(params, state, x, y, rng, fmask, lmask)
-            new_params, new_opt = self._apply_updates(params, grads, opt_state)
-            return new_params, new_state, new_opt, loss
-
-        return jax.jit(train_step, donate_argnums=(0, 1, 2))
+        carry, losses = jax.lax.scan(
+            body, (params, state, opt_state, cstate, carries, rng), stacked)
+        return tuple(t for t in (*carry, losses) if t is not None)
 
     def _make_fused_train_step(self):
         """K sequential optimizer steps fused into ONE dispatch via lax.scan
         over stacked (K, batch, ...) minibatches — identical math to K
-        ``fit`` calls (same per-step rng split chain), but the host pays one
-        dispatch instead of K. On dispatch-latency-bound paths (small
-        models, high-latency links) this is the throughput path; see
-        ``fit_fused``."""
-        value_and_grad = jax.value_and_grad(self._loss_fn, has_aux=True)
-        comp = self.grad_compression
-        if comp is not None:
-            # compressed collectives on the fused path: cstate (error-
-            # feedback residual + controller) threads through the scan
-            # carry exactly like opt_state, so K fused steps evolve the
-            # residual identically to K per-batch fit() calls
+        ``fit`` calls, but the host pays one dispatch instead of K. On
+        dispatch-latency-bound paths (small models, high-latency links)
+        this is the throughput path; see ``fit_fused``. Two compiled
+        variants: with and without masks (None is not scannable, so
+        maskless groups pass no mask operands)."""
+        def masked(_, inp, k):
+            x, y, fm, lm = inp
+            return x, y, k, fm, lm
+
+        def nomask(_, inp, k):
+            x, y = inp
+            return x, y, k, None, None
+
+        if self.grad_compression is not None:
             def train_fused_step_compressed(params, state, opt_state, cstate,
                                             rng, xs, ys, fmasks, lmasks):
-                def body(carry, inp):
-                    params, state, opt_state, cstate, rng = carry
-                    x, y, fm, lm = inp
-                    rng, k = jax.random.split(rng)   # same chain as fit()
-                    (loss, new_state), grads = value_and_grad(
-                        params, state, x, y, k, fm, lm)
-                    grads, cstate = comp.apply(grads, cstate)
-                    new_params, new_opt = self._apply_updates(
-                        params, grads, opt_state)
-                    return (new_params, new_state, new_opt, cstate,
-                            rng), loss
-
-                (params, state, opt_state, cstate, rng), losses = \
-                    jax.lax.scan(body,
-                                 (params, state, opt_state, cstate, rng),
-                                 (xs, ys, fmasks, lmasks))
-                return params, state, opt_state, cstate, rng, losses
+                return self._scan_steps(
+                    self._loss_fn, masked, (xs, ys, fmasks, lmasks), params,
+                    state, opt_state, rng, cstate)
 
             def train_fused_step_compressed_nomask(params, state, opt_state,
                                                    cstate, rng, xs, ys):
-                def body(carry, inp):
-                    params, state, opt_state, cstate, rng = carry
-                    x, y = inp
-                    rng, k = jax.random.split(rng)
-                    (loss, new_state), grads = value_and_grad(
-                        params, state, x, y, k, None, None)
-                    grads, cstate = comp.apply(grads, cstate)
-                    new_params, new_opt = self._apply_updates(
-                        params, grads, opt_state)
-                    return (new_params, new_state, new_opt, cstate,
-                            rng), loss
-
-                (params, state, opt_state, cstate, rng), losses = \
-                    jax.lax.scan(body,
-                                 (params, state, opt_state, cstate, rng),
-                                 (xs, ys))
-                return params, state, opt_state, cstate, rng, losses
+                return self._scan_steps(self._loss_fn, nomask, (xs, ys),
+                                        params, state, opt_state, rng, cstate)
 
             return (jax.jit(train_fused_step_compressed,
                             donate_argnums=(0, 1, 2, 3)),
@@ -372,37 +258,13 @@ class MultiLayerNetwork:
 
         def train_fused_step(params, state, opt_state, rng, xs, ys, fmasks,
                              lmasks):
-            def body(carry, inp):
-                params, state, opt_state, rng = carry
-                x, y, fm, lm = inp
-                rng, k = jax.random.split(rng)   # same chain as fit()
-                (loss, new_state), grads = value_and_grad(
-                    params, state, x, y, k, fm, lm)
-                new_params, new_opt = self._apply_updates(
-                    params, grads, opt_state)
-                return (new_params, new_state, new_opt, rng), loss
+            return self._scan_steps(
+                self._loss_fn, masked, (xs, ys, fmasks, lmasks), params,
+                state, opt_state, rng)
 
-            (params, state, opt_state, rng), losses = jax.lax.scan(
-                body, (params, state, opt_state, rng),
-                (xs, ys, fmasks, lmasks))
-            return params, state, opt_state, rng, losses
-
-        # two compiled variants: with and without masks (None is not
-        # scannable, so maskless groups pass no mask operands)
         def train_fused_step_nomask(params, state, opt_state, rng, xs, ys):
-            def body(carry, inp):
-                params, state, opt_state, rng = carry
-                x, y = inp
-                rng, k = jax.random.split(rng)
-                (loss, new_state), grads = value_and_grad(
-                    params, state, x, y, k, None, None)
-                new_params, new_opt = self._apply_updates(
-                    params, grads, opt_state)
-                return (new_params, new_state, new_opt, rng), loss
-
-            (params, state, opt_state, rng), losses = jax.lax.scan(
-                body, (params, state, opt_state, rng), (xs, ys))
-            return params, state, opt_state, rng, losses
+            return self._scan_steps(self._loss_fn, nomask, (xs, ys), params,
+                                    state, opt_state, rng)
 
         return (jax.jit(train_fused_step, donate_argnums=(0, 1, 2)),
                 jax.jit(train_fused_step_nomask, donate_argnums=(0, 1, 2)))
@@ -433,7 +295,6 @@ class MultiLayerNetwork:
         if self.conf.backprop_type == "tbptt":
             raise ValueError("fit_fused does not window tBPTT sequences; "
                              "use fit() for tbptt-configured networks")
-        from deeplearning4j_tpu.obs.trace import get_tracer
         tracer = get_tracer()
         at = self.iteration
         # one call is one turn of the span tree (obs/trace.py), with no
@@ -503,81 +364,19 @@ class MultiLayerNetwork:
         return xs, ys, fmasks, lmasks, n_steps
 
     def _dispatch_fused(self, xs, ys, fmasks, lmasks):
-        """The one call of the fused program; returns the K losses."""
+        """The one call of the fused program; returns the K losses. A
+        compressed fused step threads cstate through the scan carry (same
+        error-feedback evolution as K per-batch fit() calls)."""
         step_masked, step_nomask = self._get_jitted("train_fused")
-        if self.grad_compression is not None:
-            # compressed fused steps thread cstate through the scan carry
-            # (same error-feedback evolution as K per-batch fit() calls)
-            if self.compress_state is None:
-                from deeplearning4j_tpu.parallel.compress import (
-                    ensure_compress_state)
-                ensure_compress_state(self)
-            if fmasks is not None or lmasks is not None:
-                (self.params, self.state, self.opt_state,
-                 self.compress_state, self._rng, losses) = step_masked(
-                    self.params, self.state, self.opt_state,
-                    self.compress_state, self._rng, xs, ys, fmasks, lmasks)
-            else:
-                (self.params, self.state, self.opt_state,
-                 self.compress_state, self._rng, losses) = step_nomask(
-                    self.params, self.state, self.opt_state,
-                    self.compress_state, self._rng, xs, ys)
-        elif fmasks is not None or lmasks is not None:
-            self.params, self.state, self.opt_state, self._rng, losses = \
-                step_masked(self.params, self.state, self.opt_state,
-                            self._rng, xs, ys, fmasks, lmasks)
+        if fmasks is not None or lmasks is not None:
+            self._rng, losses = self._run_step(step_masked, self._rng, xs,
+                                               ys, fmasks, lmasks)
         else:
-            self.params, self.state, self.opt_state, self._rng, losses = \
-                step_nomask(self.params, self.state, self.opt_state,
-                            self._rng, xs, ys)
+            self._rng, losses = self._run_step(step_nomask, self._rng, xs,
+                                               ys)
         return losses
 
     # ------------------------------------------------- truncated BPTT / state
-    def _zero_carries(self, batch: int):
-        return [l.init_carry(batch) if hasattr(l, "init_carry") else {}
-                for l in self.layers]
-
-    def _loss_fn_tbptt(self, params, state, carries, x, y, rng, fmask, lmask):
-        out_layer = self.layers[-1]
-        acts, preout, new_state, cur_mask, new_carries = self._forward(
-            params, state, x, True, rng, fmask, carries)
-        lm = lmask if lmask is not None else cur_mask
-        if y.dtype in (jnp.bfloat16, jnp.float16):
-            y = y.astype(jnp.float32)
-        loss = out_layer.compute_score(y, preout, lm) + self._regularization(params)
-        return loss, (new_state, new_carries)
-
-    def _make_tbptt_step(self):
-        """One tBPTT window update (reference doTruncatedBPTT —
-        MultiLayerNetwork.java:1393). Incoming carries are constants of the
-        traced program, so gradients truncate at the window boundary exactly
-        like the reference's stored-state scheme."""
-        value_and_grad = jax.value_and_grad(self._loss_fn_tbptt, has_aux=True)
-        comp = self.grad_compression
-        if comp is not None:
-            def tbptt_step_compressed(params, state, opt_state, cstate,
-                                      carries, rng, x, y, fmask, lmask):
-                (loss, (new_state, new_carries)), grads = value_and_grad(
-                    params, state, carries, x, y, rng, fmask, lmask)
-                grads, cstate = comp.apply(grads, cstate)
-                new_params, new_opt = self._apply_updates(params, grads,
-                                                          opt_state)
-                return (new_params, new_state, new_opt, cstate, new_carries,
-                        loss)
-
-            return jax.jit(tbptt_step_compressed,
-                           donate_argnums=(0, 1, 2, 3, 4))
-
-        def tbptt_step(params, state, opt_state, carries, rng, x, y, fmask,
-                       lmask):
-            (loss, (new_state, new_carries)), grads = value_and_grad(
-                params, state, carries, x, y, rng, fmask, lmask)
-            new_params, new_opt = self._apply_updates(params, grads,
-                                                      opt_state)
-            return new_params, new_state, new_opt, new_carries, loss
-
-        return jax.jit(tbptt_step, donate_argnums=(0, 1, 2, 3))
-
     def _check_stateful(self):
         for layer in self.layers:
             if not getattr(layer, "supports_stateful", True):
@@ -671,74 +470,49 @@ class MultiLayerNetwork:
         for closed-loop generation; serving/decode.py enforces that."""
         return int(self.conf.layer_input_types()[0].size)
 
-    def rnn_clear_previous_state(self):
-        """reference MultiLayerNetwork.rnnClearPreviousState."""
-        self._rnn_carries = None
+    def _make_program(self, kind):
+        if kind == "train_fused":
+            return self._make_fused_train_step()
+        if kind == "tbptt_fused":
+            return self._make_tbptt_scan_step()
+        if kind == "rnn_step":
+            def rnn_step(params, state, carries, x):
+                r = self._forward(params, state, x, False, None, None,
+                                  carries)
+                return r[0][-1], r[4]
 
-    def rnn_get_previous_state(self):
-        return self._rnn_carries
+            return jax.jit(rnn_step)
+        if kind == "rnn_single_step":
+            # one decode timestep: x has NO time axis ((b,) ids or
+            # (b, f) features) — it is added inside the trace and the
+            # output squeezed back, so rnn_time_step and the serving
+            # decode tier share one warmed program shape per batch
+            index_seq = getattr(self.layers[0], "takes_index_sequence",
+                                False)
 
-    def _get_jitted(self, kind, key=()):
-        # the compression scheme AND the augmentation config are part of
-        # the cache key: enabling (or changing) either mints a fresh step
-        # instead of reusing the old compiled program under the same name
-        k = (kind, self.grad_compression, self.augmentation) + tuple(key)
-        fn = self._jit_cache.get(k)
-        if fn is None:
-            if kind == "train":
-                fn = self._make_train_step()
-            elif kind == "train_fused":
-                fn = self._make_fused_train_step()
-            elif kind == "tbptt":
-                fn = self._make_tbptt_step()
-            elif kind == "tbptt_fused":
-                fn = self._make_tbptt_scan_step()
-            elif kind == "rnn_step":
-                def rnn_step(params, state, carries, x):
-                    r = self._forward(params, state, x, False, None, None,
-                                      carries)
-                    return r[0][-1], r[4]
+            def rnn_single_step(params, state, carries, x):
+                xt = x[:, None] if index_seq else x[:, None, :]
+                r = self._forward(params, state, xt, False, None, None,
+                                  carries)
+                return r[0][-1][:, 0, :], r[4]
 
-                fn = jax.jit(rnn_step)
-            elif kind == "rnn_single_step":
-                # one decode timestep: x has NO time axis ((b,) ids or
-                # (b, f) features) — it is added inside the trace and the
-                # output squeezed back, so rnn_time_step and the serving
-                # decode tier share one warmed program shape per batch
-                index_seq = getattr(self.layers[0], "takes_index_sequence",
-                                    False)
+            return jax.jit(rnn_single_step)
+        if kind == "output":
+            def output(params, state, x, fmask):
+                return self._forward(params, state, x, False, None,
+                                     fmask)[0][-1]
 
-                def rnn_single_step(params, state, carries, x):
-                    xt = x[:, None] if index_seq else x[:, None, :]
-                    r = self._forward(params, state, xt, False, None, None,
-                                      carries)
-                    return r[0][-1][:, 0, :], r[4]
+            return jax.jit(output)
+        if kind == "score":
+            def score(params, state, x, y, fmask, lmask):
+                _, preout, _, cur_mask, _ = self._forward(
+                    params, state, x, False, None, fmask)
+                return (self._output_score(self.layers[-1], y, preout, lmask,
+                                           cur_mask)
+                        + self._regularization(params))
 
-                fn = jax.jit(rnn_single_step)
-            elif kind == "output":
-                def output(params, state, x, fmask):
-                    return self._forward(params, state, x, False, None,
-                                         fmask)[0][-1]
-
-                fn = jax.jit(output)
-            elif kind == "score":
-                def score(params, state, x, y, fmask, lmask):
-                    _, preout, _, cur_mask, _ = self._forward(params, state, x, False, None, fmask)
-                    lm = lmask if lmask is not None else cur_mask
-                    if y.dtype in (jnp.bfloat16, jnp.float16):
-                        y = y.astype(jnp.float32)
-                    return (self.layers[-1].compute_score(y, preout, lm)
-                            + self._regularization(params))
-                fn = jax.jit(score)
-            else:
-                raise KeyError(kind)
-            if isinstance(fn, tuple):  # train_fused: (masked, nomask) pair
-                fn = tuple(self.compile_watch.wrap(f, f"{kind}.{tag}")
-                           for f, tag in zip(fn, ("masked", "nomask")))
-            else:
-                fn = self.compile_watch.wrap(fn, kind)
-            self._jit_cache[k] = fn
-        return fn
+            return jax.jit(score)
+        raise KeyError(kind)
 
     # -------------------------------------------------------------- pretrain
     def _featurize(self, params, state, x, upto: int):
@@ -847,14 +621,6 @@ class MultiLayerNetwork:
             data = [DataSet(np.asarray(data), np.asarray(labels))]
         elif isinstance(data, DataSet):
             data = [data]
-        from deeplearning4j_tpu.checkpoint.manager import (
-            resume_plan, skip_consumed_batches)
-        epochs_to_run, skip = resume_plan(self, num_epochs)
-        if hasattr(data, "bind_epoch"):
-            # epoch-aware sharded readers (datasets/sharded.py) follow
-            # the MODEL's epoch counter, so a restored model replays
-            # exactly the interrupted epoch's shuffle order
-            data.bind_epoch(lambda: self.epoch)
         if not is_sgd_family(self.conf):
             # full-batch solver path (reference Solver.java dispatch on
             # OptimizationAlgorithm — LBFGS / CG / line gradient descent)
@@ -866,27 +632,19 @@ class MultiLayerNetwork:
                     "SGD step loop only", self.conf.optimization_algo)
             from deeplearning4j_tpu.optimize.solvers import Solver
             solver = Solver(self.conf.optimization_algo)
-            for _ in range(epochs_to_run):
+
+            def solve_batch(ds):
+                solver.optimize(self, ds)
+                self.last_batch_size = ds.num_examples()
                 for listener in self.listeners:
-                    listener.on_epoch_start(self)
-                bi = skip
-                for ds in skip_consumed_batches(data, skip):
-                    bi += 1
-                    solver.optimize(self, ds)
-                    self.last_batch_size = ds.num_examples()
-                    for listener in self.listeners:
-                        listener.iteration_done(self, self.iteration, self.epoch)
-                    self.iteration += 1
-                    if checkpoint_manager is not None:
-                        checkpoint_manager.step_end(self, batch_in_epoch=bi)
-                skip = 0
-                for listener in self.listeners:
-                    listener.on_epoch_end(self)
-                self.epoch += 1
-                if checkpoint_manager is not None:
-                    checkpoint_manager.epoch_end(self)
+                    listener.iteration_done(self, self.iteration, self.epoch)
+                self.iteration += 1
+
+            run_epochs(self, data, num_epochs, solve_batch,
+                       checkpoint_manager=checkpoint_manager)
             return self
         train_step = self._get_jitted("train")
+        source = data
         record = getattr(self, "_tuning_record", None)
         if (caller_iterator and record is not None
                 and getattr(record, "batch_size", 0)):
@@ -910,113 +668,15 @@ class MultiLayerNetwork:
             # exactly as in the uninterrupted run (they feed the jit shapes
             # and, for batch-coupled layers like BN, the math)
             data = BucketPadDataSetIterator(data, policy)
-        prefetch_cls = None
-        if prefetch:
-            from deeplearning4j_tpu.perf.prefetch import DevicePrefetchIterator
-            prefetch_cls = DevicePrefetchIterator
-        from deeplearning4j_tpu.obs.trace import get_tracer
-        tracer = get_tracer()
-        for _ in range(epochs_to_run):
-            for listener in self.listeners:
-                listener.on_epoch_start(self)
-            # skip UNDER the prefetch wrapper: batches consumed before the
-            # checkpoint are never transferred just to be discarded (and no
-            # rng split / update runs for them — the restored chain stays
-            # exact)
-            stream = skip_consumed_batches(data, skip)
-            if prefetch_cls is not None:
-                stream = prefetch_cls(stream)
-            # the fit loops' span tree (obs/trace.py): one train.iteration
-            # a turn, its data-wait ABOVE prefetch (what the step loop
-            # actually waits for, which prefetch exists to hide), then the
-            # loop's own work on the batch. No span waits for the device.
-            stream = tracer.wrap_iter(stream, "train.data_wait",
-                                      turn="train.iteration",
-                                      step=lambda: self.iteration)
-            bi = skip
-            for ds in stream:
-                bi += 1
-                with tracer.span("train.step_host", step=self.iteration,
-                                 items=ds.num_examples()):
-                    self._fit_batch(train_step, ds)
-                    if checkpoint_manager is not None:
-                        checkpoint_manager.step_end(self, batch_in_epoch=bi)
-            skip = 0
-            for listener in self.listeners:
-                listener.on_epoch_end(self)
-            self.epoch += 1
-            if checkpoint_manager is not None:
-                checkpoint_manager.epoch_end(self)
+        if data is not source:
+            # neither wrapper hands bind_epoch on: the reader under them
+            # is bound here, the loop binds what it is given
+            bind_epoch(self, source)
+        run_epochs(self, data, num_epochs,
+                   lambda ds: self._fit_batch(train_step, ds),
+                   prefetch={} if prefetch else None,
+                   checkpoint_manager=checkpoint_manager)
         return self
-
-    def _fit_batch(self, train_step, ds: DataSet):
-        """One optimizer step on one batch, under the inner spans of the
-        fit loops' tree (obs/trace.py): opened here, where the work is, so
-        that every caller (``fit``, ``ParallelWrapper.fit_batch``) gets
-        them once, inside its own ``train.step_host``."""
-        from deeplearning4j_tpu.obs.trace import get_tracer
-        tracer = get_tracer()
-        step = self.iteration
-        with tracer.span("train.stage", step=step):
-            x = jnp.asarray(ds.features)
-            y = jnp.asarray(ds.labels)
-            fm = (None if ds.features_mask is None
-                  else jnp.asarray(ds.features_mask))
-            lm = (None if ds.labels_mask is None
-                  else jnp.asarray(ds.labels_mask))
-        # tbptt applies when the input has a time axis: 3-D dense sequences or
-        # 2-D integer index sequences (EmbeddingSequenceLayer) under an RNN
-        # input type
-        has_time_axis = x.ndim == 3 or (
-            x.ndim == 2 and self.conf.input_type is not None
-            and self.conf.input_type.kind == "rnn"
-            and not self.layers[0].input_kind() == "ff")
-        if (self.conf.backprop_type == "tbptt" and has_time_axis
-                and x.shape[1] > self.conf.tbptt_fwd_length):
-            self._fit_tbptt(x, y, fm, lm)
-            return
-        with tracer.span("train.dispatch", step=step, program="train"):
-            self._rng, k = jax.random.split(self._rng)
-            if self.grad_compression is not None:
-                if self.compress_state is None:
-                    from deeplearning4j_tpu.parallel.compress import (
-                        ensure_compress_state)
-                    ensure_compress_state(self)
-                (self.params, self.state, self.opt_state,
-                 self.compress_state, loss) = train_step(
-                    self.params, self.state, self.opt_state,
-                    self.compress_state, k, x, y, fm, lm)
-            else:
-                self.params, self.state, self.opt_state, loss = train_step(
-                    self.params, self.state, self.opt_state, k, x, y, fm, lm)
-        self._finish_step(tracer, loss, int(x.shape[0]), lambda: x[:1])
-
-    def _finish_step(self, tracer, loss, batch: int, sample, steps: int = 1):
-        """What follows a dispatch in every fit path: ``train.post`` (the
-        score handle, counters and, only on an iteration some listener
-        reads it (``reads_features``), ``sample()``: the slice listeners
-        read activations from, a device program of its own), then
-        ``train.listeners``, then the iteration counter. ``steps`` is the
-        optimizer steps the dispatch ran (fused paths: the group)."""
-        from deeplearning4j_tpu.obs.registry import count_train_steps
-        from deeplearning4j_tpu.optimize.listeners import any_reads_features
-        step = self.iteration
-        last = step + steps - 1  # what iteration_done is told
-        sampled = int(any_reads_features(self.listeners, last))
-        with tracer.span("train.post", step=step, sampled=sampled):
-            self._score = loss
-            self.last_batch_size = batch
-            # first sample only: listeners sample activations, and pinning
-            # the whole batch keeps large device buffers alive after fit().
-            # None on every other turn: no stale sample of an earlier
-            # batch, and no device program behind the step
-            self._last_features = sample() if sampled else None
-            count_train_steps(steps, steps * batch, sampled)
-        if self.listeners:
-            with tracer.span("train.listeners", step=step):
-                for listener in self.listeners:
-                    listener.iteration_done(self, last, self.epoch)
-        self.iteration += steps
 
     def _make_tbptt_scan_step(self):
         """All tBPTT windows of one sequence batch fused into ONE dispatch:
@@ -1025,50 +685,24 @@ class MultiLayerNetwork:
         IDENTICAL to the per-window loop — each scan iteration runs its own
         value_and_grad, and the carries passed forward are values, not
         differentiated across windows. Same rng split chain as _fit_tbptt."""
-        value_and_grad = jax.value_and_grad(self._loss_fn_tbptt, has_aux=True)
-        comp = self.grad_compression
-        if comp is not None:
-            # cstate through the scan carry — per-window error-feedback
-            # evolution identical to the per-window _fit_tbptt loop
+        def window(carries, inp, k):
+            x, y = inp
+            return carries, x, y, k, None, None
+
+        if self.grad_compression is not None:
             def tbptt_fused_step_compressed(params, state, opt_state, cstate,
                                             carries, rng, xw, yw):
-                def body(c, inp):
-                    params, state, opt_state, cstate, carries, rng = c
-                    x, y = inp
-                    rng, k = jax.random.split(rng)
-                    (loss, (new_state, new_carries)), grads = \
-                        value_and_grad(params, state, carries, x, y, k,
-                                       None, None)
-                    grads, cstate = comp.apply(grads, cstate)
-                    new_params, new_opt = self._apply_updates(
-                        params, grads, opt_state)
-                    return (new_params, new_state, new_opt, cstate,
-                            new_carries, rng), loss
-
-                (params, state, opt_state, cstate, carries, rng), losses = \
-                    jax.lax.scan(body, (params, state, opt_state, cstate,
-                                        carries, rng), (xw, yw))
-                return (params, state, opt_state, cstate, carries, rng,
-                        losses)
+                return self._scan_steps(
+                    self._loss_fn_tbptt, window, (xw, yw), params, state,
+                    opt_state, rng, cstate, carries)
 
             return jax.jit(tbptt_fused_step_compressed,
                            donate_argnums=(0, 1, 2, 3, 4))
 
         def tbptt_fused_step(params, state, opt_state, carries, rng, xw, yw):
-            def body(c, inp):
-                params, state, opt_state, carries, rng = c
-                x, y = inp
-                rng, k = jax.random.split(rng)
-                (loss, (new_state, new_carries)), grads = value_and_grad(
-                    params, state, carries, x, y, k, None, None)
-                new_params, new_opt = self._apply_updates(
-                    params, grads, opt_state)
-                return (new_params, new_state, new_opt, new_carries,
-                        rng), loss
-
-            (params, state, opt_state, carries, rng), losses = jax.lax.scan(
-                body, (params, state, opt_state, carries, rng), (xw, yw))
-            return params, state, opt_state, carries, rng, losses
+            return self._scan_steps(
+                self._loss_fn_tbptt, window, (xw, yw), params, state,
+                opt_state, rng, carries=carries)
 
         return jax.jit(tbptt_fused_step, donate_argnums=(0, 1, 2, 3))
 
@@ -1091,7 +725,6 @@ class MultiLayerNetwork:
                              f"tbptt_fwd_length {L} for the fused path")
         w = T // L
         b = int(np.shape(x)[0])
-        from deeplearning4j_tpu.obs.trace import get_tracer
         tracer = get_tracer()
         at = self.iteration
         # one call is one turn of the span tree (obs/trace.py), with no
@@ -1109,20 +742,9 @@ class MultiLayerNetwork:
                 carries = self._zero_carries(b)
             with tracer.span("train.dispatch", step=at,
                              program="tbptt_fused", steps=w):
-                step = self._get_jitted("tbptt_fused")
-                if self.grad_compression is not None:
-                    if self.compress_state is None:
-                        from deeplearning4j_tpu.parallel.compress import (
-                            ensure_compress_state)
-                        ensure_compress_state(self)
-                    (self.params, self.state, self.opt_state,
-                     self.compress_state, _, self._rng, losses) = step(
-                        self.params, self.state, self.opt_state,
-                        self.compress_state, carries, self._rng, xw, yw)
-                else:
-                    (self.params, self.state, self.opt_state, _, self._rng,
-                     losses) = step(self.params, self.state, self.opt_state,
-                                    carries, self._rng, xw, yw)
+                _, self._rng, losses = self._run_step(
+                    self._get_jitted("tbptt_fused"), carries, self._rng, xw,
+                    yw)
             self._window_scores = losses
             self._finish_step(tracer, losses[-1], b, lambda: x[:1], steps=w)
         return self
@@ -1133,46 +755,6 @@ class MultiLayerNetwork:
         (reading it is the caller's sync, not the fit's). None before the
         first call. ``score()`` is its last element."""
         return self._window_scores
-
-    def _fit_tbptt(self, x, y, fm, lm):
-        """Chunked fit over time windows (reference doTruncatedBPTT
-        MultiLayerNetwork.java:1393): one optimizer update per forward-length
-        window, with RNN state carried (but not differentiated) across
-        windows."""
-        from deeplearning4j_tpu.obs.trace import get_tracer
-        tracer = get_tracer()
-        step = self._get_jitted("tbptt")
-        T = x.shape[1]
-        L = self.conf.tbptt_fwd_length
-        carries = self._zero_carries(int(x.shape[0]))
-        for s in range(0, T, L):
-            e = min(s + L, T)
-            # keep window length static where possible: last ragged window
-            # gets its own jit specialization
-            xs = x[:, s:e]
-            ys = y[:, s:e] if y.ndim == 3 else y
-            fs = None if fm is None else fm[:, s:e]
-            ls = None if lm is None else lm[:, s:e]
-            # one optimizer update per window == one iteration: each
-            # window's spans carry its own step
-            with tracer.span("train.dispatch", step=self.iteration,
-                             program="tbptt"):
-                self._rng, k = jax.random.split(self._rng)
-                if self.grad_compression is not None:
-                    if self.compress_state is None:
-                        from deeplearning4j_tpu.parallel.compress import (
-                            ensure_compress_state)
-                        ensure_compress_state(self)
-                    (self.params, self.state, self.opt_state,
-                     self.compress_state, carries, loss) = step(
-                        self.params, self.state, self.opt_state,
-                        self.compress_state, carries, k, xs, ys, fs, ls)
-                else:
-                    (self.params, self.state, self.opt_state, carries,
-                     loss) = step(self.params, self.state, self.opt_state,
-                                  carries, k, xs, ys, fs, ls)
-            self._finish_step(tracer, loss, int(x.shape[0]),
-                              lambda xs=xs: xs[:1])
 
     # ---------------------------------------------------------------- output
     def output(self, x, train: bool = False, features_mask=None) -> np.ndarray:
@@ -1194,14 +776,6 @@ class MultiLayerNetwork:
         acts = self._forward(self.params, self.state, jnp.asarray(x),
                              train, None, None)[0]
         return [np.asarray(a) for a in acts]
-
-    def score_dataset(self, ds: DataSet) -> float:
-        """Loss on a dataset (reference MultiLayerNetwork.score(DataSet))."""
-        fn = self._get_jitted("score")
-        fm = None if ds.features_mask is None else jnp.asarray(ds.features_mask)
-        lm = None if ds.labels_mask is None else jnp.asarray(ds.labels_mask)
-        return float(fn(self.params, self.state, jnp.asarray(ds.features),
-                        jnp.asarray(ds.labels), fm, lm))
 
     def evaluate(self, iterator):
         """Classification evaluation over an iterator (reference

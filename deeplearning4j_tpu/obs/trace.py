@@ -28,8 +28,10 @@ the runtime's back-pressure then holds the host inside ``train.dispatch``;
 device time per step proper comes from the device planes of a profiler
 trace. Operators without a profiler read ``train_iteration_ms`` (p50/p95).
 
-The fit loops' span tree (nn/multilayer.py, nn/graph.py,
-parallel/trainer.py, perf/prefetch.py, checkpoint/manager.py)::
+The fit loops' span tree (the turn and its wait: nn/engine.py's
+``run_epochs``, the one loop under every fit; the batch's own spans: its
+``Network._fit_batch``; parallel/trainer.py's hand-over, perf/prefetch.py,
+checkpoint/manager.py)::
 
     train.iteration                 one turn of the loop
       train.data_wait               next() of the stream, above prefetch

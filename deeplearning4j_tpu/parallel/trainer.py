@@ -31,6 +31,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 log = logging.getLogger(__name__)
 
 from deeplearning4j_tpu.datasets.dataset import DataSet
+from deeplearning4j_tpu.nn.engine import run_epochs, step_host
+from deeplearning4j_tpu.obs.trace import get_tracer
+from deeplearning4j_tpu.optimize.updaters import is_sgd_family
 from deeplearning4j_tpu.parallel.mesh import (
     DATA_AXIS, data_sharding, make_mesh, replicated, shard_batch,
     tp_shardings,
@@ -119,10 +122,7 @@ class ParallelWrapper:
         if self.tensor_parallel:
             # re-init optimizer state on the sharded params so moment tensors
             # inherit the param shardings
-            if hasattr(m, "_txs") and isinstance(m.opt_state, list):
-                m.opt_state = [tx.init(p) for tx, p in zip(m._txs, m.params)]
-            elif hasattr(m, "_txs") and isinstance(m.opt_state, dict):
-                m.opt_state = {n: m._txs[n].init(m.params[n]) for n in m.opt_state}
+            m.opt_state = m.init_opt_state(m.params)
         else:
             m.opt_state = jax.device_put(
                 m.opt_state, jax.tree_util.tree_map(opt_sh, m.opt_state))
@@ -179,16 +179,13 @@ class ParallelWrapper:
         model's internal batch path for the standard SGD case; tbptt/solver
         configs fall back to model.fit."""
         m = self.model
-        conf = getattr(m, "conf", None)
         # is_sgd_family is the ONE normalized-name dispatch shared with
         # fit()'s solver dispatch and the compression guards — not another
-        # ad-hoc lowercase string tuple
-        from deeplearning4j_tpu.optimize.updaters import is_sgd_family
-        standard = (conf is not None
-                    and getattr(conf, "backprop_type", "standard") == "standard"
-                    and is_sgd_family(getattr(conf, "optimization_algo",
-                                              "stochastic_gradient_descent")))
-        if standard and hasattr(m, "_fit_batch") and hasattr(m, "_get_jitted"):
+        # ad-hoc lowercase string tuple (a graph's configuration names no
+        # algorithm: it is always the jitted step)
+        if (m.conf.backprop_type == "standard"
+                and is_sgd_family(getattr(m.conf, "optimization_algo",
+                                          "stochastic_gradient_descent"))):
             m._fit_batch(m._get_jitted("train"), sharded)
         else:
             # tbptt/solver configs go through model.fit; suppress its
@@ -219,7 +216,6 @@ class ParallelWrapper:
         The wrapper's own hand-over is a ``train.stage`` span (obs/trace.py)
         beside the model's; dispatch, post and listeners are the model's
         ``_fit_batch``'s. ``examples`` is what TrainingStats counts."""
-        from deeplearning4j_tpu.obs.trace import get_tracer
         with get_tracer().span("train.stage", step=self.model.iteration), \
                 self._phase("data_placement"):
             self._place_params()
@@ -264,70 +260,32 @@ class ParallelWrapper:
         explicit_single = isinstance(data, DataSet)
         if explicit_single:
             data = [data]
-        prefetch_cls = None
-        if prefetch and not explicit_single:
-            from deeplearning4j_tpu.perf.prefetch import DevicePrefetchIterator
-            prefetch_cls = DevicePrefetchIterator
-        from deeplearning4j_tpu.checkpoint.manager import (
-            resume_plan, skip_consumed_batches)
-        from deeplearning4j_tpu.obs.trace import get_tracer
-        tracer = get_tracer()
-        epochs_to_run, skip = resume_plan(self.model, num_epochs)
-        if hasattr(data, "bind_epoch"):
-            # epoch-aware sharded readers follow the model's epoch
-            # counter (see multilayer.py fit)
-            data.bind_epoch(lambda: self.model.epoch)
-        for _ in range(epochs_to_run):
-            for listener in self.model.listeners:
-                listener.on_epoch_start(self.model)
-            trained = 0
-            seen = skip
-            resumed_mid_epoch = skip > 0
-            # skip UNDER the prefetch wrapper: consumed batches are never
-            # sharded/transferred just to be discarded
-            stream = skip_consumed_batches(data, skip)
-            if prefetch_cls is not None:
-                stream = prefetch_cls(stream, mesh=self.mesh)
-            # the fit loops' span tree, as in multilayer.py fit (host-side
-            # only, nothing waits for the device; see obs/trace.py)
-            stream = tracer.wrap_iter(stream, "train.data_wait",
-                                      turn="train.iteration",
-                                      step=lambda: self.model.iteration)
-            for ds in stream:
-                seen += 1
-                with tracer.span("train.step_host",
-                                 step=self.model.iteration,
-                                 items=ds.num_examples()):
-                    # a single explicit ragged DataSet raises (dropping it
-                    # would train on nothing); iterator tail batches
-                    # drop-remainder
-                    if self.fit_batch(ds, drop_ragged=not explicit_single):
-                        trained += 1
-                        if checkpoint_manager is not None:
-                            checkpoint_manager.step_end(self.model,
-                                                        batch_in_epoch=seen)
-            skip = 0
-            if seen == 0:
-                raise ValueError(
-                    "No batches this epoch — the data iterable is empty or a "
-                    "one-shot generator exhausted by a previous epoch; pass a "
-                    "re-iterable DataSetIterator")
+
+        def epoch_drained(seen, trained, resumed_mid_epoch):
+            _raise_if_no_batches(seen)
             if trained == 0 and not resumed_mid_epoch:
                 raise ValueError(
                     "Every batch this epoch was dropped as ragged — the "
                     f"batch size never divides the data-parallel size "
                     f"{self.mesh.shape[DATA_AXIS]}; pick a divisible batch")
-            for listener in self.model.listeners:
-                listener.on_epoch_end(self.model)
-            self.model.epoch += 1
-            if checkpoint_manager is not None:
-                checkpoint_manager.epoch_end(self.model)
+
+        def epoch_done():
             if self.stats is not None:
                 # steps dispatch asynchronously: one sync per epoch shows
                 # the true device time under "epoch_sync"
                 with self.stats.time("epoch_sync"):
                     jax.block_until_ready(self.model.params)
                 self._record_compile_counters()
+
+        # a single explicit ragged DataSet raises (dropping it would train
+        # on nothing); iterator tail batches drop-remainder
+        run_epochs(self.model, data, num_epochs,
+                   lambda ds: self.fit_batch(ds,
+                                             drop_ragged=not explicit_single),
+                   prefetch=({"mesh": self.mesh}
+                             if prefetch and not explicit_single else None),
+                   checkpoint_manager=checkpoint_manager,
+                   epoch_drained=epoch_drained, epoch_done=epoch_done)
         return self
 
     def _record_compile_counters(self):
@@ -529,97 +487,73 @@ class ClusterTrainer(ParallelWrapper):
         self._place_params()
         if isinstance(data, DataSet):
             data = [data]
-        prefetch_cls = None
-        if prefetch:
-            from deeplearning4j_tpu.perf.prefetch import DevicePrefetchIterator
-            prefetch_cls = DevicePrefetchIterator
-        from deeplearning4j_tpu.checkpoint.manager import (
-            resume_plan, skip_consumed_batches)
-        from deeplearning4j_tpu.obs.trace import get_tracer
         tracer = get_tracer()
-        epochs_to_run, skip = resume_plan(self.model, num_epochs)
-        if hasattr(data, "bind_epoch"):
-            # epoch-aware sharded readers follow the model's epoch
-            # counter — fleet-true resume replays the interrupted
-            # epoch's shuffle order at ANY world size
-            data.bind_epoch(lambda: self.model.epoch)
         step_no = 0
-        with self.mesh:
-            for _ in range(epochs_to_run):
-                # every host re-verifies at its first batch — an ALIGNED
-                # once-per-epoch collective (see _verify_equal_local_shards)
-                self._epoch_shards_verified = False
-                for listener in self.model.listeners:
-                    listener.on_epoch_start(self.model)
-                seen = skip
-                # skip UNDER the prefetch wrapper: consumed batches are
-                # never assembled/transferred just to be discarded
-                stream = skip_consumed_batches(data, skip)
-                if prefetch_cls is not None:
-                    stream = prefetch_cls(stream,
-                                          place_fn=self._stage_local_batch)
-                # the fit loops' span tree, as in multilayer.py fit
-                # (obs/trace.py): the elastic worker trains through THIS
-                # loop, so its crash ring / event log carry the per-step
-                # breakdown too
-                stream = tracer.wrap_iter(stream, "train.data_wait",
-                                          turn="train.iteration",
-                                          step=lambda: self.model.iteration)
-                for ds in stream:
-                    turn = tracer.current()
 
-                    # _model_fit_batch, not model.fit: per-epoch hooks and
-                    # the epoch counter must fire once per EPOCH, not once
-                    # per minibatch (same contract as ParallelWrapper.fit)
-                    def one_step(d=ds, turn=turn):
-                        # a prefetch-staged batch is already the GLOBAL
-                        # array: normalize the examples counter back to
-                        # process-local rows so the metric doesn't change
-                        # meaning with the prefetch flag
-                        n_local = d.num_examples()
-                        if getattr(d, "_staged_global", False):
-                            n_local //= max(1, jax.process_count())
-                        # under a watchdog this runs on its worker thread:
-                        # attach keeps the step in its turn's tree
-                        with tracer.attach(turn), tracer.span(
-                                "train.step_host", step=self.model.iteration,
-                                items=n_local):
-                            self._train_batch(d, n_local)
-                    if wd is None:
-                        one_step()
-                    else:
-                        # the dispatch itself can block synchronously on a
-                        # dead peer's collective rendezvous, so the
-                        # deadline must wrap the whole call, not just a
-                        # later sync
-                        wd.call(one_step,
-                                what=f"cluster step {step_no + 1} dispatch")
-                    step_no += 1
-                    seen += 1
-                    if wd is not None and step_no % max(1, watchdog_every) == 0:
-                        wd.sync(self.model.params,
-                                what=f"cluster step {step_no}")
-                    if checkpoint_manager is not None:
-                        checkpoint_manager.step_end(self.model,
-                                                    batch_in_epoch=seen)
-                skip = 0
-                if seen == 0:
-                    raise ValueError(
-                        "No batches this epoch — the data iterable is empty "
-                        "or a one-shot generator exhausted by a previous "
-                        "epoch; pass a re-iterable DataSetIterator")
-                for listener in self.model.listeners:
-                    listener.on_epoch_end(self.model)
-                self.model.epoch += 1
-                if checkpoint_manager is not None:
-                    checkpoint_manager.epoch_end(self.model)
-                self._record_compile_counters()
+        def turn(ds, seen):
+            nonlocal step_no
+            above = tracer.current()
+
+            # _model_fit_batch, not model.fit: per-epoch hooks and the
+            # epoch counter must fire once per EPOCH, not once per
+            # minibatch (same contract as ParallelWrapper.fit)
+            def one_step():
+                # a prefetch-staged batch is already the GLOBAL array:
+                # normalize the examples counter back to process-local
+                # rows so the metric doesn't change meaning with the
+                # prefetch flag
+                n_local = ds.num_examples()
+                if getattr(ds, "_staged_global", False):
+                    n_local //= max(1, jax.process_count())
+                # under a watchdog this runs on its worker thread: attach
+                # keeps the step in its turn's tree
+                with tracer.attach(above), step_host(tracer, self.model,
+                                                     n_local):
+                    self._train_batch(ds, n_local)
+            if wd is None:
+                one_step()
+            else:
+                # the dispatch itself can block synchronously on a dead
+                # peer's collective rendezvous, so the deadline must wrap
+                # the whole call, not just a later sync
+                wd.call(one_step, what=f"cluster step {step_no + 1} dispatch")
+            step_no += 1
+            if wd is not None and step_no % max(1, watchdog_every) == 0:
+                wd.sync(self.model.params, what=f"cluster step {step_no}")
+            if checkpoint_manager is not None:
+                checkpoint_manager.step_end(self.model, batch_in_epoch=seen)
+
+        def epoch_start():
+            # every host re-verifies at its first batch — an ALIGNED
+            # once-per-epoch collective (see _verify_equal_local_shards)
+            self._epoch_shards_verified = False
+
+        with self.mesh:
+            # the elastic worker trains through THIS loop, so its crash
+            # ring / event log carry the per-step breakdown too; a batch
+            # is assembled (place_fn) one ahead, never for the skipped
+            run_epochs(self.model, data, num_epochs, turn=turn,
+                       prefetch=({"place_fn": self._stage_local_batch}
+                                 if prefetch else None),
+                       checkpoint_manager=checkpoint_manager,
+                       epoch_start=epoch_start,
+                       epoch_drained=lambda seen, *_: _raise_if_no_batches(
+                           seen),
+                       epoch_done=self._record_compile_counters)
             if wd is not None:
                 # tail steps after the last every-N sync must not escape the
                 # deadline — a hang there would otherwise surface only at
                 # the caller's next (unguarded) host sync
                 wd.sync(self.model.params, what=f"epoch end (step {step_no})")
         return self
+
+
+def _raise_if_no_batches(seen: int) -> None:
+    if seen == 0:
+        raise ValueError(
+            "No batches this epoch — the data iterable is empty or a "
+            "one-shot generator exhausted by a previous epoch; pass a "
+            "re-iterable DataSetIterator")
 
 
 from deeplearning4j_tpu.earlystopping.trainer import EarlyStoppingTrainer

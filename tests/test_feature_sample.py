@@ -138,7 +138,19 @@ def _parallel_wrapper(listeners):
     return net, [0, 1, 2, 3], [ds.features[:1] for ds in data]
 
 
+def _cluster_local_shard(listeners):
+    """ClusterTrainer.fit_local_shard in one process: the same one-batch
+    method under the epoch loop's third caller."""
+    from deeplearning4j_tpu.parallel import ClusterTrainer
+    from deeplearning4j_tpu.parallel.mesh import make_mesh
+    net, data = conv_graph(), image_batches(4)
+    net.set_listeners(*listeners)
+    ClusterTrainer(net, mesh=make_mesh()).fit_local_shard(data)
+    return net, [0, 1, 2, 3], [ds.features[:1] for ds in data]
+
+
 PATHS = {"mln_fit": _mln_fit, "fit_fused": _fit_fused,
+         "cluster_local_shard": _cluster_local_shard,
          "fit_tbptt_fused": _fit_tbptt_fused,
          "tbptt_windows": _tbptt_windows, "graph_fit": _graph_fit,
          "parallel_wrapper": _parallel_wrapper}

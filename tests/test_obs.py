@@ -377,9 +377,44 @@ def _fit_tbptt_fused():
     return net
 
 
-LOOPS = {"mln": (_fit_mln, 1, True), "graph": (_fit_graph, 1, True),
-         "parallel_wrapper": (_fit_parallel_wrapper, 1, True),
-         "tbptt_fused": (_fit_tbptt_fused, 3, False)}
+def _fit_cluster_local_shard():
+    """ClusterTrainer.fit_local_shard as far as one process runs it: the
+    batch is this process's whole shard, staged one ahead by place_fn."""
+    import jax
+    from deeplearning4j_tpu.parallel import ClusterTrainer
+    from deeplearning4j_tpu.parallel.mesh import make_mesh
+    net = small_net()
+    mesh = make_mesh(dp=1, tp=1, devices=jax.devices()[:1])
+    ClusterTrainer(net, mesh=mesh).fit_local_shard(
+        toy_batches(3), num_epochs=2, prefetch=True)
+    return net
+
+
+def _fit_solver():
+    """The stack's full-batch solver loop: the three outer spans of the
+    tree and nothing under ``train.step_host`` (prefetch is ignored)."""
+    conf = (NeuralNetConfiguration.builder()
+            .seed(11).updater(Sgd(learning_rate=0.05))
+            .weight_init("xavier").list()
+            .optimization_algo("lbfgs")
+            .layer(DenseLayer(n_out=8, activation="tanh"))
+            .layer(OutputLayer(n_out=3, loss="mcxent"))
+            .set_input_type(InputType.feed_forward(4))
+            .build())
+    net = MultiLayerNetwork(conf).init()
+    net.fit(toy_batches(3), num_epochs=2)
+    return net
+
+
+# run, optimizer steps a turn, streamed (a data wait a turn), placed (the
+# prefetcher's spans under the wait), inner (the batch's own spans)
+LOOPS = {"mln": (_fit_mln, 1, True, True, True),
+         "graph": (_fit_graph, 1, True, True, True),
+         "parallel_wrapper": (_fit_parallel_wrapper, 1, True, True, True),
+         "cluster_local_shard": (_fit_cluster_local_shard, 1, True, True,
+                                 True),
+         "solver": (_fit_solver, 1, True, False, False),
+         "tbptt_fused": (_fit_tbptt_fused, 3, False, False, True)}
 
 
 class TestFitPhaseBreakdown:
@@ -400,9 +435,10 @@ class TestFitPhaseBreakdown:
     @pytest.mark.parametrize("loop", sorted(LOOPS))
     def test_every_loop_emits_the_same_span_tree(self, loop):
         """Names, parents and one ``step`` a turn: the same tree out of
-        MultiLayerNetwork.fit, ComputationGraph.fit, ParallelWrapper.fit
-        (which had no span at all) and fit_tbptt_fused."""
-        run, steps_per_turn, streamed = LOOPS[loop]
+        MultiLayerNetwork.fit (the solver loop: its outer three spans),
+        ComputationGraph.fit, ParallelWrapper.fit,
+        ClusterTrainer.fit_local_shard and fit_tbptt_fused."""
+        run, steps_per_turn, streamed, placed, inner_spans = LOOPS[loop]
         sink = _traced(run)
         spans = [s for s in sink if s["kind"] == "span"]
         by_id = {s["id"]: s for s in spans}
@@ -424,6 +460,9 @@ class TestFitPhaseBreakdown:
             host = next(s for s in spans if s["parent"] == turn["id"]
                         and s["name"] == "train.step_host")
             inner = children(host)
+            if not inner_spans:
+                assert inner == []
+                continue
             # ParallelWrapper hands the batch over twice: its own sharding,
             # then the model's (no-op) asarray
             assert [n for i, n in enumerate(inner)
@@ -439,7 +478,7 @@ class TestFitPhaseBreakdown:
                 top = by_id[top["parent"]]
             assert top["name"] == "train.iteration", s
             assert s["attrs"]["step"] == top["attrs"]["step"], s
-        if streamed:
+        if placed:
             # batch N+1 is placed inside turn N's wait for batch N (the
             # first wait places two, the last none)
             places = [s for s in spans if s["name"] == "prefetch.place"]
