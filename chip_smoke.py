@@ -446,6 +446,19 @@ def _ids_agree(a, b) -> float:
     return float(np.mean(np.asarray(a) == np.asarray(b)))
 
 
+def _relative_gaps(got, want, what: str) -> list:
+    """Leaf by leaf, |got - want| / |want| by norms; ``got`` has to be
+    finite."""
+    gaps = []
+    for a, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        a, w = (np.asarray(x, np.float64) for x in (a, w))
+        check(np.all(np.isfinite(a)), f"{what}: not finite")
+        gaps.append(float(np.linalg.norm(a - w)
+                          / max(np.linalg.norm(w), 1e-30)))
+    return gaps
+
+
 def phase_kernels(ctx: Ctx) -> dict:
     from deeplearning4j_tpu.nlp.pallas_scatter import scatter_add_pallas
     from deeplearning4j_tpu.nn.conf import InputType, NeuralNetConfiguration
@@ -580,13 +593,7 @@ def phase_kernels(ctx: Ctx) -> dict:
         check(kcounts == {"kernel.pallas_kda_scan": 1,
                           "kernel.xla_kda_scan": 1},
               f"kda_scan kernel counters {kcounts}")
-        gaps = []
-        for a, w in zip(jax.tree_util.tree_leaves(got),
-                        jax.tree_util.tree_leaves(want)):
-            a, w = (np.asarray(x, np.float64) for x in (a, w))
-            check(np.all(np.isfinite(a)), "kda_scan: not finite")
-            gaps.append(float(np.linalg.norm(a - w)
-                              / max(np.linalg.norm(w), 1e-30)))
+        gaps = _relative_gaps(got, want, "kda_scan")
         # both arms round their default-precision products to bfloat16 on
         # the chip: they agree to that rounding, not to float32's
         check(max(gaps) < (2e-2 if chip else 1e-4),
@@ -597,6 +604,50 @@ def phase_kernels(ctx: Ctx) -> dict:
         out["kda_scan"] = {"shape": list(shape), "counters": kcounts,
                            "worst_relative_gap": max(gaps),
                            "seconds": lap(t)}
+
+        # ---- blocked_attention: latent attention's tile pairs, forward
+        # and backward kernels against the jax.numpy form, q/k heads of
+        # 192 and v heads of 128, several tiles, a length that is padded
+        from deeplearning4j_tpu.nn.conf.attention import (
+            blocked_causal_attention)
+        from deeplearning4j_tpu.perf.pallas import attention as attn_kernels
+        heads, steps = (8, 3 * s.attn_seq - 24) if chip else (2, 360)
+        block = s.attn_seq // 2 if chip else 128
+        keys = jax.random.split(jax.random.key(ctx.seed + 1), 3)
+        aargs = tuple(jax.random.normal(key, (2, heads, steps, width), cdt)
+                      for key, width in zip(keys, (192, 192, 128)))
+        padded = tuple(jnp.pad(a, ((0, 0), (0, 0), (0, -steps % block),
+                                   (0, 0))) for a in aargs)
+        check(attn_kernels.supported(*padded, block),
+              "blocked_attention does not take heads of 192 / 128")
+
+        def attn_loss(*a):
+            o = blocked_causal_attention(*a, block).astype(jnp.float32)
+            return jnp.sum(jnp.sin(o)), o
+        before = dict(GLOBAL.as_dict().get("counters", {}))
+        got = jax.value_and_grad(attn_loss, range(3), has_aux=True)(*aargs)
+        with pk.override(enabled=False):
+            want = jax.value_and_grad(attn_loss, range(3),
+                                      has_aux=True)(*aargs)
+        acounts = {key: val - before.get(key, 0) for key, val in
+                   GLOBAL.as_dict()["counters"].items()
+                   if "blocked_attention" in key}
+        check(acounts == {"kernel.pallas_blocked_attention": 1,
+                          "kernel.xla_blocked_attention": 1},
+              f"blocked_attention kernel counters {acounts}")
+        gaps = _relative_gaps(got, want, "blocked_attention")
+        # both executions round p and ds to bfloat16 on the chip
+        check(max(gaps) < (2e-2 if chip else 1e-4),
+              "blocked_attention differs from the jax.numpy form: "
+              f"{gaps}")
+        if chip:
+            check(_has_kernel(lambda *a: blocked_causal_attention(*a, block),
+                              *aargs),
+                  "blocked_attention: no Mosaic kernel compiled")
+        out["blocked_attention"] = {
+            "shape": [2, heads, steps, 192, 128], "block": block,
+            "counters": acounts, "worst_relative_gap": max(gaps),
+            "seconds": lap(t)}
 
     # ---- bn_act / bn_act_bwd: NOT in the default selection; run under an
     # explicit override where supported() says the rows fit

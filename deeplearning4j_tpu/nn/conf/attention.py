@@ -364,16 +364,32 @@ def blocked_causal_attention(q, k, v, block: int = 512):
     differ, without a (time, time) array: tiles of ``block`` x ``block``,
     key tiles after the query tile skipped, an online softmax forward and a
     backward pass that makes each tile's probabilities again from the saved
-    log-sum-exp (the flash-attention recipe in plain ``jax.numpy``).
-    ``time`` is padded up to a multiple of ``block``: padded keys lie after
-    every real query, and padded queries are cut off."""
+    log-sum-exp (the flash-attention recipe). ``time`` is padded up to a
+    multiple of ``block``: padded keys lie after every real query, and
+    padded queries are cut off.
+
+    One algorithm, two executions, chosen at trace time by what the code
+    can observe (``perf.pallas.take("blocked_attention", supported(...))``;
+    the ``kernel.pallas_blocked_attention`` / ``kernel.xla_blocked_attention``
+    counters say which): the Pallas kernels of ``perf/pallas/attention.py``,
+    which keep a tile pair's scores, probabilities and their cotangents in
+    VMEM, on a TPU for more than one tile of a length that is a multiple
+    of 128, q, k, v alike in bfloat16 or float32, head widths multiples of
+    64 up to 256; plain ``jax.numpy`` in tiles of ``block`` everywhere
+    else, and as the reference the kernels are held to."""
+    from deeplearning4j_tpu.perf import pallas as pk
+    from deeplearning4j_tpu.perf.pallas import attention as kernels
+
     t = q.shape[2]
     block = min(block, t)
     pad = (-t) % block
     if pad:
         q, k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0)))
                    for a in (q, k, v))
-    out = _blocked_attention(q, k, v, block)
+    if pk.take("blocked_attention", kernels.supported(q, k, v, block)):
+        out = kernels.blocked_attention(q, k, v)
+    else:
+        out = _blocked_attention(q, k, v, block)
     return out[:, :, :t] if pad else out
 
 
@@ -386,13 +402,20 @@ class MultiHeadLatentAttention(BaseLayer):
     RMS-normalised; per head ``k = [W_kb^K c; k_r]`` (``k_r``, ``rope_dim``
     wide, shared by the heads and NOT rotated), ``v = W_kb^V c``,
     ``q = W_q x`` (``nope_dim + rope_dim``), causal attention, ``W_o`` over
-    heads x ``v_dim``. q/k heads and v heads differ in width, which the
-    Pallas flash kernel does not take: scores go through
-    ``blocked_causal_attention`` in tiles of ``block``. Which path a
-    compiled program took is counted at trace time (``bump_active``):
+    heads x ``v_dim``. q/k heads and v heads differ in width, which
+    ``pallas.ops.tpu.flash_attention`` (``SelfAttentionLayer``'s kernel)
+    does not take: scores go through ``blocked_causal_attention`` in tiles
+    of ``block``, which on a TPU runs as the Pallas kernels of
+    ``perf/pallas/attention.py`` for the shapes they take (more than one
+    tile, a padded length that is a multiple of 128, head widths multiples
+    of 64 up to 256, bfloat16 or float32) and as plain ``jax.numpy``
+    otherwise: off a TPU, one tile, an odd width. Which path a compiled
+    program took is counted at trace time (``bump_active``):
     ``attention.mla_blocked`` with more than one tile,
-    ``attention.mla_single_tile`` otherwise. A features mask zeroes the
-    output at masked steps (right-padded batches are exact)."""
+    ``attention.mla_single_tile`` otherwise, and beside them
+    ``kernel.pallas_blocked_attention`` / ``kernel.xla_blocked_attention``.
+    A features mask zeroes the output at masked steps (right-padded batches
+    are exact)."""
 
     n_in: Optional[int] = None
     n_out: int = 0              # model width; inferred from the input when 0

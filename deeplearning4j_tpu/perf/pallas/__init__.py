@@ -7,7 +7,7 @@ BN-train stats/normalize/residual traffic XLA refuses to fuse across
 costs ~4.7 extra full activation-set HBM crossings (tools/PROFILE_r5.md).
 This package holds the kernels that cross that line by hand — SURVEY
 L0/§7's replacement for libnd4j's C++ kernels exactly where XLA's fusion
-control runs out. Three families, each slotted behind a boundary the repo
+control runs out. Four families, each slotted behind a boundary the repo
 already parity-tests:
 
 - **bn** (:mod:`perf.pallas.bn`): fused BN-train forward/backward behind
@@ -23,6 +23,11 @@ already parity-tests:
   Attention behind ``chunked_kda`` (nn/conf/linear_attention.py), forward
   and backward behind one custom-VJP — a chunk's decayed scores and its
   triangular solve made and used in VMEM, the state carried in scratch.
+- **attention** (:mod:`perf.pallas.attention`): blocked causal attention
+  with q/k heads and v heads of different widths behind
+  ``blocked_causal_attention`` (nn/conf/attention.py), a forward and a
+  single-pass backward kernel behind one custom-VJP — a tile pair's
+  scores, probabilities and their cotangents made and used in VMEM.
 
 Selection contract (every kernel, no exceptions):
 
@@ -33,7 +38,7 @@ Selection contract (every kernel, no exceptions):
    automatically on a TPU backend (the families of
    :data:`TPU_AUTO_FAMILIES` only) — AND the call site's shape predicate
    (``bn.supported``, ``adc.pq_supported``, ``adc.int4_supported``,
-   ``kda.supported``) says
+   ``kda.supported``, ``attention.supported``) says
    the kernel fits. Anywhere else the reference runs.
 2. Off-TPU, a force-enabled kernel runs in Pallas **interpret mode**
    (:func:`interpret` resolves true) — this is how CPU CI bitwise/
@@ -57,8 +62,9 @@ What the v5e compiler (jax 0.9.0 / libtpu 0.0.34) says, family by
 family, is kept as tests: tests/test_chip_compile.py compiles every
 auto-selected kernel for a described v5e at a main-path shape, and
 chip_smoke.py's ``kernels`` phase runs each against its reference on
-the chip. Measured speed: ``kda_scan`` alone (PERF.md §5-6, PR 27: the
-cell's scan 221 -> 103 ms a step); the retrieval kernels against XLA at 1M
+the chip. Measured speed: ``kda_scan`` (PERF.md §5-6, PR 27: the cell's
+scan 221 -> 103 ms a step) and ``blocked_attention`` (PR 29: the cell's
+latent attention, see PERF.md §6); the retrieval kernels against XLA at 1M
 rows are still unmeasured (ROADMAP Speed 3/6).
 """
 
@@ -89,6 +95,8 @@ FAMILIES: Dict[str, str] = {
                 "dense weights)",
     "kda_scan": "chunked_kda's scan over chunks, forward and backward "
                 "(nn/conf/linear_attention.py)",
+    "blocked_attention": "blocked_causal_attention's tile pairs, forward "
+                         "and backward (nn/conf/attention.py)",
 }
 
 # Families the automatic rule selects on a TPU backend: those the v5e
@@ -101,7 +109,8 @@ FAMILIES: Dict[str, str] = {
 # - adc_ivf_pq: data-dependent CSR row gather in-kernel, which Mosaic
 #   does not lower (its flat sibling's jnp.take: "Shape mismatch in
 #   input, indices and output"); needs a DMA rework (ROADMAP Speed 6).
-TPU_AUTO_FAMILIES = frozenset({"adc_pq", "int4_dot", "kda_scan"})
+TPU_AUTO_FAMILIES = frozenset({"adc_pq", "int4_dot", "kda_scan",
+                               "blocked_attention"})
 # No shape of these compiles, so not even an explicit enable (a
 # TuningRecord's ``pallas_kernels=True`` is applied process-wide) selects
 # them outside interpret mode.

@@ -152,6 +152,36 @@ def _kda_scan_float32(S):
     return fwd_bwd, args, ("kda_scan_fwd", "kda_scan_bwd")
 
 
+def _blocked_attention(S):
+    # latent attention at the Kimi Linear share's shape, one sequence of
+    # 8192 steps, 32 q/k heads of 192 and v heads of 128 in bfloat16: the
+    # forward kernel that saves the log-sum-exp and the backward
+    # kernel, through blocked_causal_attention's own selection
+    from deeplearning4j_tpu.nn.conf.attention import blocked_causal_attention
+    from deeplearning4j_tpu.perf.pallas import attention
+    args = (S((1, 32, 8192, 192), BF16),) * 2 + (S((1, 32, 8192, 128), BF16),)
+    assert pk.take("blocked_attention", attention.supported(*args, 512))
+
+    def fwd_bwd(*a):
+        return jax.grad(lambda *a: jnp.sum(blocked_causal_attention(
+            *a, 512).astype(F32)), argnums=(0, 1, 2))(*a)
+
+    return fwd_bwd, args, ("mla_attend_fwd", "mla_attend_bwd")
+
+
+def _blocked_attention_float32(S):
+    # the same kernels on float32 inputs, a batch of two, a head count one
+    # grid step does not take whole, a length that is padded (1000 -> 1024)
+    from deeplearning4j_tpu.nn.conf.attention import blocked_causal_attention
+    args = (S((2, 6, 1000, 192), F32),) * 2 + (S((2, 6, 1000, 128), F32),)
+
+    def fwd_bwd(*a):
+        return jax.grad(lambda *a: jnp.sum(blocked_causal_attention(
+            *a, 256)), argnums=(0, 1, 2))(*a)
+
+    return fwd_bwd, args, ("mla_attend_fwd", "mla_attend_bwd")
+
+
 def _bn_fwd(S):
     # ResNet50 batch 128, the one stage whose rows fit: 7x7
     z = S((128, 7, 7, 2048), BF16)
@@ -171,7 +201,9 @@ def _bn_bwd(S):
 AUTO_CASES = [(_pq, "adc_pq"), (_int4_table, "int4_dot"),
               (_int4_weights, "int4_dot"), (_flash, None), (_scatter, None),
               (_grouped_experts, None), (_kda_scan, "kda_scan"),
-              (_kda_scan_float32, None)]
+              (_kda_scan_float32, None),
+              (_blocked_attention, "blocked_attention"),
+              (_blocked_attention_float32, None)]
 EXPLICIT_CASES = [_bn_fwd, _bn_bwd]
 
 

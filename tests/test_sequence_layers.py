@@ -202,42 +202,174 @@ def _dense_causal(q, k, v):
     return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
 
 
-@pytest.mark.parametrize("t,block", [(128, 32), (100, 32), (20, 32),
-                                     (96, 96)])
-def test_blocked_attention_is_the_full_score_matrix(t, block):
-    """q/k heads of 24 and v heads of 16 (they differ, as in latent
-    attention), lengths that are and are not multiples of the tile, one
-    tile: forward and all three gradients."""
+def _attention_counters():
+    from deeplearning4j_tpu.perf.compile_watch import GLOBAL
+    counters = GLOBAL.as_dict().get("counters", {})
+    return [counters.get(f"kernel.{impl}_blocked_attention", 0)
+            for impl in ("xla", "pallas")]
+
+
+class _AttentionImpl:
+    """What a test of blocked attention draws at, and which execution it
+    has to have taken by its end."""
+
+    def __init__(self, name):
+        self.name = name
+        # q/k heads and v heads differ, as in latent attention; the
+        # kernels take widths that are multiples of 64
+        self.widths = (24, 16) if name == "xla" else (192, 128)
+        self.rises = [name == "xla", name == "pallas"]
+
+    def one_tile(self, only=True):
+        """This test has a call of a single tile (``only``: has no
+        other): ``jax.numpy`` serves it whatever the family says."""
+        self.rises = [True, self.rises[1] and not only]
+
+
+@pytest.fixture(params=["xla", "pallas"])
+def attn_impl(request):
+    """The two executions of ``blocked_causal_attention``: plain
+    ``jax.numpy`` at small heads, and the Pallas kernels (interpreted on
+    the CPU) at heads of 192 / 128. Holds the test to the execution it
+    asked for by the ``kernel.*_blocked_attention`` counters."""
+    impl = _AttentionImpl(request.param)
+    before = _attention_counters()
+    with pk.override(enabled=request.param == "pallas", interpret=True):
+        yield impl
+    rose = [b > a for a, b in zip(before, _attention_counters())]
+    assert rose == impl.rises
+
+
+def _attention_inputs(t, widths, batch=2, heads=3, dtype=jnp.float32):
     ks = _keys(3, 1)
-    q = jax.random.normal(ks[0], (2, 3, t, 24))
-    k = jax.random.normal(ks[1], (2, 3, t, 24))
-    v = jax.random.normal(ks[2], (2, 3, t, 16))
-    assert float(jnp.max(jnp.abs(blocked_causal_attention(q, k, v, block)
-                                 - _dense_causal(q, k, v)))) < TOL
-    got = jax.grad(lambda *a: jnp.sum(jnp.sin(
-        blocked_causal_attention(*a, block))), argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(jnp.sin(_dense_causal(*a))),
-                    argnums=(0, 1, 2))(q, k, v)
+    return (jax.random.normal(ks[0], (batch, heads, t, widths[0]), dtype),
+            jax.random.normal(ks[1], (batch, heads, t, widths[0]), dtype),
+            jax.random.normal(ks[2], (batch, heads, t, widths[1]), dtype))
+
+
+def _out_and_grads(fn, q, k, v):
+    def run(*a):
+        o = fn(*a).astype(jnp.float32)
+        return jnp.sum(jnp.sin(o)), o
+
+    (_, o), grads = jax.value_and_grad(run, (0, 1, 2), has_aux=True)(q, k, v)
+    return (o,) + tuple(g.astype(jnp.float32) for g in grads)
+
+
+@pytest.mark.parametrize("t,block", [(128, 32), (100, 32), (20, 32),
+                                     (96, 96), (384, 128), (200, 128)])
+def test_blocked_attention_is_the_full_score_matrix(t, block, attn_impl):
+    """q/k heads and v heads that differ, a batch of two, lengths that are
+    and are not multiples of the tile, one tile (always ``jax.numpy``) and
+    up to three by three: forward and all three gradients."""
+    if t <= block:
+        attn_impl.one_tile()
+    q, k, v = _attention_inputs(t, attn_impl.widths)
+    got = _out_and_grads(lambda *a: blocked_causal_attention(*a, block),
+                         q, k, v)
+    want = _out_and_grads(_dense_causal, q, k, v)
     for a, b in zip(got, want):
         assert float(jnp.max(jnp.abs(a - b))) < TOL
 
 
-def test_mla_layer_counts_the_path_it_took():
+@pytest.mark.parametrize("t,block", [(256, 128), (200, 64)])
+def test_blocked_attention_in_bfloat16_stays_inside_the_plain_forms_gap(
+        t, block):
+    """bfloat16 at heads of 192 / 128: what the kernels round is what the
+    ``jax.numpy`` form rounds, so their distance to the dense float32 form
+    is held to the ``jax.numpy`` form's own (and a half), output and each
+    gradient by its norm."""
+    q, k, v = _attention_inputs(t, (192, 128), dtype=jnp.bfloat16)
+    want = _out_and_grads(_dense_causal, *(a.astype(jnp.float32)
+                                           for a in (q, k, v)))
+    before = _attention_counters()
+
+    def gaps():
+        got = _out_and_grads(lambda *a: blocked_causal_attention(*a, block),
+                             q, k, v)
+        return [float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+                for a, b in zip(got, want)]
+
+    with pk.override(enabled=False):
+        plain = gaps()
+    with pk.override(enabled=True, interpret=True):
+        kernels = gaps()
+    assert _attention_counters() == [before[0] + 1, before[1] + 1]
+    assert all(0 < g < 0.02 for g in plain), plain
+    for g, p in zip(kernels, plain):
+        assert g < 1.5 * p, (kernels, plain)
+
+
+def _mla_layer(widths, block):
+    nope = 2 * widths[0] // 3
+    return MultiHeadLatentAttention(
+        n_heads=2, nope_dim=nope, rope_dim=widths[0] - nope,
+        v_dim=widths[1], kv_rank=16, block=block)
+
+
+def test_mla_layer_counts_the_path_it_took(attn_impl):
     from deeplearning4j_tpu.perf.compile_watch import GLOBAL
-    layer = MultiHeadLatentAttention(n_heads=2, nope_dim=8, rope_dim=4,
-                                     v_dim=8, kv_rank=16, block=16)
-    it = InputType.recurrent(12, 40)
+    attn_impl.one_tile(only=False)
+    block = 16 if attn_impl.name == "xla" else 128
+    layer = _mla_layer(attn_impl.widths, block)
+    t = 2 * block + block // 2
+    it = InputType.recurrent(12, t)
     params, state = layer.init(jax.random.key(0), it)
-    x = jax.random.normal(jax.random.key(1), (1, 40, 12))
+    x = jax.random.normal(jax.random.key(1), (1, t, 12))
     before = dict(GLOBAL.as_dict().get("counters", {}))
+    kernels = _attention_counters()
     out, _ = layer.apply(params, state, x)
-    layer.apply(params, state, x[:, :16])
+    layer.apply(params, state, x[:, :block])
     after = GLOBAL.as_dict()["counters"]
-    assert out.shape == (1, 40, 12)
+    assert out.shape == (1, t, 12)
     assert after["attention.mla_blocked"] == before.get(
         "attention.mla_blocked", 0) + 1
     assert after["attention.mla_single_tile"] == before.get(
         "attention.mla_single_tile", 0) + 1
+    # the single tile is plain jax.numpy under either family setting
+    served = attn_impl.name == "pallas"
+    assert _attention_counters() == [kernels[0] + 2 - served,
+                                     kernels[1] + served]
+
+
+def test_mla_layer_is_the_dense_form_and_masks_its_output(attn_impl):
+    """The layer against its own equations written out with a dense score
+    matrix, output and the gradients of every parameter and of the input,
+    at a length that is no multiple of the tile; a features mask zeroes
+    the masked steps."""
+    from deeplearning4j_tpu.nn.conf.normalization import rms_norm
+    block = 16 if attn_impl.name == "xla" else 128
+    layer = _mla_layer(attn_impl.widths, block)
+    t, h = 2 * block + 3, layer.n_heads
+    nope, rope, vd = layer.nope_dim, layer.rope_dim, layer.v_dim
+    params, state = layer.init(jax.random.key(2), InputType.recurrent(12, t))
+    x = jax.random.normal(jax.random.key(3), (2, t, 12))
+
+    def dense(params, x):
+        q = (x @ params["Wq"]).reshape(2, t, h, nope + rope)
+        kva = x @ params["Wkva"]
+        c = rms_norm(kva[..., :layer.kv_rank], params["kv_norm"], layer.eps)
+        kvb = (c @ params["Wkvb"]).reshape(2, t, h, nope + vd)
+        k = jnp.concatenate([kvb[..., :nope], jnp.broadcast_to(
+            kva[:, :, None, layer.kv_rank:], (2, t, h, rope))], -1)
+        o = _dense_causal(*(a.transpose(0, 2, 1, 3)
+                            for a in (q, k, kvb[..., nope:])))
+        return o.transpose(0, 2, 1, 3).reshape(2, t, h * vd) @ params["Wo"]
+
+    def run(fn):
+        def loss(params, x):
+            o = fn(params, x)
+            return jnp.sum(jnp.sin(o)), o
+        return jax.value_and_grad(loss, (0, 1), has_aux=True)(params, x)
+
+    got = run(lambda p, x: layer.apply(p, state, x)[0])
+    want = run(dense)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert float(jnp.max(jnp.abs(a - b))) < 20 * TOL
+    mask = jnp.ones((2, t)).at[1, t - 5:].set(0.0)
+    masked, _ = layer.apply(params, state, x, mask=mask)
+    assert float(jnp.max(jnp.abs(masked[1, t - 5:]))) == 0.0
+    assert float(jnp.max(jnp.abs(masked[0] - got[0][1][0]))) < TOL
 
 
 # -------------------------------------------------------------------- loss

@@ -27,6 +27,7 @@ Covers the PR-16 acceptance bars, all on CPU via Pallas interpret mode
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -591,4 +592,183 @@ def test_kda_kernels_lower_under_the_layers_scan_scope():
         for l in mine:
             name = l.split('op_name="')[1].split('"')[0]
             assert "KimiDeltaAttention:kda1" in name and "kda.scan" in name
+            assert way in name, name
+
+
+# ------------------------------------------- blocked causal attention (PR 29)
+# The kernels against the dense score matrix are
+# tests/test_sequence_layers.py's, run over both executions; here the
+# selection is held to what it says, and the kernels to the layer's scope.
+from deeplearning4j_tpu.nn.conf import attention as att  # noqa: E402
+from deeplearning4j_tpu.perf.pallas import attention as att_kernels  # noqa: E402
+
+
+def _attention_counters():
+    from deeplearning4j_tpu.perf.compile_watch import GLOBAL
+    counters = GLOBAL.as_dict().get("counters", {})
+    return (counters.get("kernel.xla_blocked_attention", 0),
+            counters.get("kernel.pallas_blocked_attention", 0))
+
+
+def _attention_out_and_grads(q, k, v, block, fn=None):
+    fn = fn or att.blocked_causal_attention
+
+    def run(*a):
+        o = fn(*a, block)
+        return jnp.sum(jnp.sin(o.astype(jnp.float32))), o
+
+    (_, o), grads = jax.value_and_grad(run, (0, 1, 2), has_aux=True)(q, k, v)
+    return (o,) + grads
+
+
+@pytest.mark.parametrize("shape,block,why", [
+    ((2, 2, 256, 192, 128), 128, "family_off"),
+    ((2, 2, 256, 192, 128), 128, "cpu_unforced"),
+    ((2, 3, 100, 24, 16), 32, "odd_widths"),
+    ((1, 2, 192, 192, 128), 96, "length_no_multiple_of_128"),
+    ((1, 2, 128, 192, 128), 128, "one_tile"),
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_blocked_attention_is_untouched_where_the_kernels_do_not_apply(
+        shape, block, why, dtype):
+    """Family off, the CPU with nothing forced, and family on at a shape
+    ``supported`` refuses: the ``jax.numpy`` form as it was before the
+    kernels, output and the three gradients bit for bit, and the
+    ``kernel.xla_blocked_attention`` counter says so."""
+    b, h, t, dq, dv = shape
+    ks = jax.random.split(jax.random.key(29), 3)
+    q = jax.random.normal(ks[0], (b, h, t, dq), dtype)
+    k = jax.random.normal(ks[1], (b, h, t, dq), dtype)
+    v = jax.random.normal(ks[2], (b, h, t, dv), dtype)
+
+    def before_the_kernels(q, k, v, block):
+        # blocked_causal_attention as PR 26 wrote it
+        t = q.shape[2]
+        block = min(block, t)
+        pad = (-t) % block
+        if pad:
+            q, k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0)))
+                       for a in (q, k, v))
+        return att._blocked_attention(q, k, v, block)[:, :, :t]
+
+    want = _attention_out_and_grads(q, k, v, block, before_the_kernels)
+    counted = _attention_counters()
+    if why == "family_off":
+        with pk.override(enabled=False):
+            got = _attention_out_and_grads(q, k, v, block)
+    elif why == "cpu_unforced":
+        assert jax.default_backend() == "cpu" and not pk.enabled(
+            "blocked_attention")
+        got = _attention_out_and_grads(q, k, v, block)
+    else:
+        with pk.override(enabled=True, interpret=True):
+            assert not att_kernels.supported(
+                *(jnp.pad(a, ((0, 0), (0, 0), (0, (-t) % min(block, t)),
+                              (0, 0))) for a in (q, k, v)), min(block, t))
+            got = _attention_out_and_grads(q, k, v, block)
+    assert _attention_counters() == (counted[0] + 1, counted[1])
+    for a, b_ in zip(got, want):
+        assert a.dtype == b_.dtype
+        assert np.array_equal(np.asarray(a), np.asarray(b_))
+
+
+def test_blocked_attention_family_is_in_the_automatic_rule():
+    assert "blocked_attention" in pk.FAMILIES
+    assert "blocked_attention" in pk.TPU_AUTO_FAMILIES
+    z = jnp.zeros((1, 2, 256, 192))
+    with pk.override(enabled=True, interpret=True):
+        assert att_kernels.supported(z, z, z[..., :128], 128)
+        assert not att_kernels.supported(z, z, z[..., :128], 256)
+        assert not att_kernels.supported(z, z[:, :1], z[..., :128], 128)
+        assert not att_kernels.supported(
+            z, z.astype(jnp.bfloat16), z[..., :128], 128)
+        assert not att_kernels.supported(
+            *[z.astype(jnp.float16)] * 2, z[..., :128].astype(jnp.float16),
+            128)
+        assert not att_kernels.supported(z[..., :100], z[..., :100],
+                                         z[..., :128], 128)
+    # selected by backend: nothing forced, a CPU interprets and is refused
+    # by enabled(), not by the shape
+    assert att_kernels.supported(z, z, z[..., :128], 128) == pk.interpret()
+
+
+def test_blocked_attention_counters_reach_the_metrics_registry():
+    """``kernel.pallas_blocked_attention`` / ``kernel.xla_blocked_attention``
+    are counted once a call at trace time and surface on ``/metrics``
+    through ``absorb_compile_watch``, like ``kernel.pallas_kda_scan``."""
+    from deeplearning4j_tpu import obs
+    z = jnp.ones((1, 2, 256, 64))
+    before = _attention_counters()
+    with pk.override(enabled=True, interpret=True):
+        att.blocked_causal_attention(z, z, z, 128)
+    with pk.override(enabled=False):
+        att.blocked_causal_attention(z, z, z, 128)
+    assert _attention_counters() == (before[0] + 1, before[1] + 1)
+    registry = obs.MetricsRegistry()
+    obs.absorb_compile_watch(registry)
+    for impl, count in zip(("xla", "pallas"), _attention_counters()):
+        gauge = registry.metric(f"jit_kernel_{impl}_blocked_attention")
+        assert gauge is not None and gauge.value == count
+
+
+@pytest.mark.parametrize("heads,t", [(5, 640), (8, 256)])
+def test_attention_kernels_take_head_groups_and_many_tiles(
+        heads, t, exact_products):
+    """Head counts that a grid step takes one at a time (5 heads) and in
+    groups (8 heads: all forward, 4 backward), five by five tiles of 128
+    (every kind of pair: first, inner, diagonal) and one tile of 256:
+    output and the three gradients against ``jax.numpy``."""
+    ks = jax.random.split(jax.random.key(31), 3)
+    q = jax.random.normal(ks[0], (1, heads, t, 64))
+    k = jax.random.normal(ks[1], (1, heads, t, 64))
+    v = jax.random.normal(ks[2], (1, heads, t, 256))
+    with pk.override(enabled=False):
+        want = _attention_out_and_grads(q, k, v, 128)
+    with pk.override(enabled=True, interpret=True):
+        assert att_kernels.supported(q, k, v, 128)
+        got = _attention_out_and_grads(q, k, v, 128)
+    for a, b in zip(got, want):
+        _close(a, b)
+
+
+def test_attention_kernels_lower_under_the_layers_attend_scope():
+    """``mla.device_ms_per_step`` finds its operations by ``op_name`` in
+    the step's HLO text: every operation the forward and backward kernels
+    (here their interpreted bodies) lower to has to carry the layer's
+    scope and ``mla.attend``, in the backward pass too; otherwise the
+    metric reads a gain that is only operations gone missing."""
+    from deeplearning4j_tpu.nn.conf.graph import GraphBuilder
+    from deeplearning4j_tpu.nn.conf.recurrent import RnnOutputLayer
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+    conf = (GraphBuilder(NeuralNetConfiguration.builder().seed(3)
+                         .updater(Sgd(learning_rate=0.05)))
+            .add_inputs("in")
+            .add_layer("mla1", att.MultiHeadLatentAttention(
+                n_heads=2, nope_dim=128, rope_dim=64, v_dim=128, kv_rank=16,
+                block=128), "in")
+            .add_layer("out", RnnOutputLayer(n_out=3, loss="mcxent"), "mla1")
+            .set_outputs("out")
+            .set_input_types(InputType.recurrent(12, 256)).build())
+    before = _attention_counters()
+    with pk.override(enabled=True, interpret=True):
+        net = ComputationGraph(conf).init()
+        x = jnp.zeros((1, 256, 12), jnp.float32)
+        y = jnp.zeros((1, 256, 3), jnp.float32)
+        step = net._get_jitted("train")
+        hlo = step.lower(net.params, net.state, net.opt_state, net._rng,
+                         [x], [y], None, None).compile().as_text()
+    assert _attention_counters() == (before[0], before[1] + 1)
+    # a reduction's scalar reducer (``f32[] add(a, b)``) is no operation
+    # of its own on a device and is named from the kernel's root
+    ops = [l for l in hlo.splitlines() if "op_name=" in l
+           and not re.search(r"= f32\[\] (add|maximum)\(", l)]
+    for kernel, way in (("mla_attend_fwd", "jvp("),
+                        ("mla_attend_bwd", "transpose(")):
+        mine = [l for l in ops if kernel in l]
+        assert len(mine) > 20, (kernel, len(mine))
+        for l in mine:
+            name = l.split('op_name="')[1].split('"')[0]
+            assert ("MultiHeadLatentAttention:mla1" in name
+                    and "mla.attend" in name), name
             assert way in name, name
