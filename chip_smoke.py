@@ -564,53 +564,76 @@ def phase_kernels(ctx: Ctx) -> dict:
                            "index_counters": counts,
                            "weight_counters": wcounts, "seconds": lap(t)}
 
-        # ---- kda_scan: the chunked scan of Kimi Delta Attention, forward
-        # and backward kernels against the jax.numpy form, heads of 128
-        from deeplearning4j_tpu.nn.conf.linear_attention import chunked_kda
-        from deeplearning4j_tpu.perf.compile_watch import GLOBAL
-        from deeplearning4j_tpu.perf.pallas import kda
-        shape = (1, s.attn_seq + 40, 8 if chip else 2, 128)
-        keys = jax.random.split(jax.random.key(ctx.seed), 5)
-        cdt = jnp.bfloat16 if chip else jnp.float32
-        kq, kk_, kv = (jax.random.normal(key, shape) for key in keys[:3])
-        kk_ = kk_ / jnp.linalg.norm(kk_, axis=-1, keepdims=True)
-        kargs = (0.1 * kq.astype(cdt), kk_.astype(cdt), kv.astype(cdt),
-                 -jax.random.uniform(keys[3], shape),
-                 jax.random.uniform(keys[4], shape[:3]))
-        check(kda.supported(*kargs, 64, 8),
-              "kda_scan does not take heads of 128 in chunks of 64")
-
-        def kda_loss(*a):
-            o = chunked_kda(*a)
-            return jnp.sum(jnp.sin(o)), o
-        before = dict(GLOBAL.as_dict().get("counters", {}))
-        got = jax.value_and_grad(kda_loss, range(5), has_aux=True)(*kargs)
-        with pk.override(enabled=False):
-            want = jax.value_and_grad(kda_loss, range(5),
-                                      has_aux=True)(*kargs)
-        kcounts = {key: val - before.get(key, 0) for key, val in
-                   GLOBAL.as_dict()["counters"].items() if "kda_scan" in key}
-        check(kcounts == {"kernel.pallas_kda_scan": 1,
-                          "kernel.xla_kda_scan": 1},
-              f"kda_scan kernel counters {kcounts}")
-        gaps = _relative_gaps(got, want, "kda_scan")
-        # both arms round their default-precision products to bfloat16 on
-        # the chip: they agree to that rounding, not to float32's
-        check(max(gaps) < (2e-2 if chip else 1e-4),
-              f"kda_scan differs from chunked_kda's jax.numpy form: {gaps}")
-        if chip:
-            check(_has_kernel(chunked_kda, *kargs),
-                  "kda_scan: no Mosaic kernel compiled")
-        out["kda_scan"] = {"shape": list(shape), "counters": kcounts,
-                           "worst_relative_gap": max(gaps),
-                           "seconds": lap(t)}
-
-        # ---- blocked_attention: latent attention's tile pairs, forward
-        # and backward kernels against the jax.numpy form, q/k heads of
-        # 192 and v heads of 128, several tiles, a length that is padded
+        # ---- kda_scan and blocked_attention: each family's kernels, forward
+        # and backward, against the jax.numpy form of the same call
         from deeplearning4j_tpu.nn.conf.attention import (
             blocked_causal_attention)
+        from deeplearning4j_tpu.nn.conf.linear_attention import chunked_kda
+        from deeplearning4j_tpu.perf.compile_watch import GLOBAL
         from deeplearning4j_tpu.perf.pallas import attention as attn_kernels
+        from deeplearning4j_tpu.perf.pallas import kda
+        cdt = jnp.bfloat16 if chip else jnp.float32
+
+        def both_arms(name, family, fn, args, **report):
+            """``fn(*args)``'s output and every gradient with the family's
+            kernels and as plain jax.numpy: one call counted on each arm,
+            and the arms agree to their own rounding (both round their
+            default-precision products, p and ds to bfloat16 on the chip:
+            they agree to that, not to float32's)."""
+            def loss(*a):
+                o = fn(*a).astype(jnp.float32)
+                return jnp.sum(jnp.sin(o)), o
+            grad = jax.value_and_grad(loss, range(len(args)), has_aux=True)
+            before = dict(GLOBAL.as_dict().get("counters", {}))
+            got = grad(*args)
+            with pk.override(enabled=False):
+                want = grad(*args)
+            counts = {key: val - before.get(key, 0) for key, val in
+                      GLOBAL.as_dict()["counters"].items() if family in key}
+            check(counts == {f"kernel.pallas_{family}": 1,
+                             f"kernel.xla_{family}": 1},
+                  f"{name}: {family} kernel counters {counts}")
+            gaps = _relative_gaps(got, want, name)
+            check(max(gaps) < (2e-2 if chip else 1e-4),
+                  f"{name} differs from the jax.numpy form: {gaps}")
+            if chip:
+                check(_has_kernel(fn, *args),
+                      f"{name}: no Mosaic kernel compiled")
+            out[name] = {**report, "counters": counts,
+                         "worst_relative_gap": max(gaps), "seconds": lap(t)}
+
+        def scan_inputs(shape, seed, decay_shape):
+            keys = jax.random.split(jax.random.key(seed), 5)
+            q, k, v = (jax.random.normal(key, shape) for key in keys[:3])
+            k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+            return (0.1 * q.astype(cdt), k.astype(cdt), v.astype(cdt),
+                    -jax.random.uniform(keys[3], decay_shape),
+                    jax.random.uniform(keys[4], shape[:3]))
+
+        # Kimi Delta Attention's call: a decay a channel, heads of 128
+        shape = (1, s.attn_seq + 40, 8 if chip else 2, 128)
+        kargs = scan_inputs(shape, ctx.seed, shape)
+        check(kda.supported(*kargs, 64, 8),
+              "kda_scan does not take heads of 128 in chunks of 64")
+        both_arms("kda_scan", "kda_scan", chunked_kda, kargs,
+                  shape=list(shape))
+        # Gated DeltaNet's call at the Qwen3-Next cell's shape: ONE decay a
+        # head spread over its channels (its cotangent summed back), 16 q/k
+        # heads repeated to 32 value heads
+        steps, hk, hv = (8192, 16, 32) if chip else (s.attn_seq + 40, 1, 2)
+        q, k, v, g, b = scan_inputs((1, steps, hv, 128), ctx.seed + 2,
+                                    (1, steps, hv))
+
+        def scalar_decay_scan(q, k, v, g, b):
+            q, k = (jnp.repeat(a, hv // hk, axis=2) for a in (q, k))
+            return chunked_kda(q, k, v, jnp.broadcast_to(g[..., None],
+                                                         q.shape), b)
+        both_arms("kda_scan_scalar_decay", "kda_scan", scalar_decay_scan,
+                  (q[:, :, :hk], k[:, :, :hk], v, g, b),
+                  shape=[1, steps, hk, hv, 128])
+
+        # latent attention's call: q/k heads of 192 and v heads of 128,
+        # several tiles, a length that is padded
         heads, steps = (8, 3 * s.attn_seq - 24) if chip else (2, 360)
         block = s.attn_seq // 2 if chip else 128
         keys = jax.random.split(jax.random.key(ctx.seed + 1), 3)
@@ -620,34 +643,24 @@ def phase_kernels(ctx: Ctx) -> dict:
                                    (0, 0))) for a in aargs)
         check(attn_kernels.supported(*padded, block),
               "blocked_attention does not take heads of 192 / 128")
+        both_arms("blocked_attention", "blocked_attention",
+                  lambda *a: blocked_causal_attention(*a, block), aargs,
+                  shape=[2, heads, steps, 192, 128], block=block)
+        # gated attention's call at the Qwen3-Next cell's shape: 16 query
+        # heads of 256 over 2 k/v heads repeated in front of the kernels
+        # (dk, dv summed over the group on the way back)
+        h, hkv, steps, width, block = ((16, 2, 8192, 256, 512) if chip
+                                       else (4, 2, 360, 64, 128))
+        keys = jax.random.split(jax.random.key(ctx.seed + 3), 3)
+        gargs = tuple(jax.random.normal(key, (1, n, steps, width), cdt)
+                      for key, n in zip(keys, (h, hkv, hkv)))
 
-        def attn_loss(*a):
-            o = blocked_causal_attention(*a, block).astype(jnp.float32)
-            return jnp.sum(jnp.sin(o)), o
-        before = dict(GLOBAL.as_dict().get("counters", {}))
-        got = jax.value_and_grad(attn_loss, range(3), has_aux=True)(*aargs)
-        with pk.override(enabled=False):
-            want = jax.value_and_grad(attn_loss, range(3),
-                                      has_aux=True)(*aargs)
-        acounts = {key: val - before.get(key, 0) for key, val in
-                   GLOBAL.as_dict()["counters"].items()
-                   if "blocked_attention" in key}
-        check(acounts == {"kernel.pallas_blocked_attention": 1,
-                          "kernel.xla_blocked_attention": 1},
-              f"blocked_attention kernel counters {acounts}")
-        gaps = _relative_gaps(got, want, "blocked_attention")
-        # both executions round p and ds to bfloat16 on the chip
-        check(max(gaps) < (2e-2 if chip else 1e-4),
-              "blocked_attention differs from the jax.numpy form: "
-              f"{gaps}")
-        if chip:
-            check(_has_kernel(lambda *a: blocked_causal_attention(*a, block),
-                              *aargs),
-                  "blocked_attention: no Mosaic kernel compiled")
-        out["blocked_attention"] = {
-            "shape": [2, heads, steps, 192, 128], "block": block,
-            "counters": acounts, "worst_relative_gap": max(gaps),
-            "seconds": lap(t)}
+        def grouped_attention(q, k, v):
+            k, v = (jnp.repeat(a, h // hkv, axis=1) for a in (k, v))
+            return blocked_causal_attention(q, k, v, block)
+        both_arms("blocked_attention_grouped", "blocked_attention",
+                  grouped_attention, gargs,
+                  shape=[1, h, hkv, steps, width], block=block)
 
     # ---- bn_act / bn_act_bwd: NOT in the default selection; run under an
     # explicit override where supported() says the rows fit

@@ -82,8 +82,8 @@ def _layer_name(i: Optional[int], layer) -> str:
 
 # layers where n_out == 0 is legal (width inferred from the input)
 _N_OUT_OPTIONAL = ("TransformerEncoderBlock", "KimiDeltaAttention",
-                   "MultiHeadLatentAttention", "GatedFeedForward",
-                   "RoutedExperts")
+                   "GatedDeltaNet", "MultiHeadLatentAttention",
+                   "GatedAttention", "GatedFeedForward", "RoutedExperts")
 
 
 def _check_layer(layer, cur, name: str) -> List[ValidationIssue]:

@@ -13,6 +13,13 @@ parity tests so there is exactly ONE dense implementation). For sequences
 beyond one chip, the same math shards over the mesh via
 ``ring_self_attention`` / ``ulysses_self_attention`` (parallel/).
 
+Beside them the two attention layers of today's hybrid decoders, both over
+``blocked_causal_attention`` (tiles, own backward pass, Pallas kernels on a
+TPU): ``MultiHeadLatentAttention`` (as many k/v heads as query heads, q/k
+and v widths that differ, no rotation) and ``GatedAttention`` (fewer k/v
+heads than query heads, per-head q/k norms, a partial rotary embedding,
+an output gate).
+
 Param layout: nested ``{"q": {"W", "b"}, "k": ..., "v": ..., "o": ...}``
 (plus ``ff1``/``ff2`` in the encoder block) so the framework's bias-aware
 machinery — l1_bias/l2_bias regularization, bias constraints, weight noise
@@ -361,7 +368,9 @@ _blocked_attention.defvjp(_blocked_attention_fwd, _blocked_attention_bwd)
 def blocked_causal_attention(q, k, v, block: int = 512):
     """Causal softmax(q k^T / sqrt(d_q)) v for ``q``, ``k`` (batch, heads,
     time, d_q) and ``v`` (batch, heads, time, d_v), d_q and d_v free to
-    differ, without a (time, time) array: tiles of ``block`` x ``block``,
+    differ, q, k and v with the same number of heads (a caller with
+    grouped-query heads repeats k and v over their group first, as
+    ``GatedAttention`` does), without a (time, time) array: tiles of ``block`` x ``block``,
     key tiles after the query tile skipped, an online softmax forward and a
     backward pass that makes each tile's probabilities again from the saved
     log-sum-exp (the flash-attention recipe). ``time`` is padded up to a
@@ -492,5 +501,138 @@ class MultiHeadLatentAttention(BaseLayer):
         return out, state
 
 
+def rotate_half_split(x, positions, rotary_dim: int, theta: float):
+    """A rotary embedding over the first ``rotary_dim`` widths of ``x``
+    (batch, time, heads, width), the rest left as they are: width j of the
+    first half is paired with width j + rotary_dim / 2 (the "half-split"
+    layout of the Llama family's public code) and the pair turned by
+    ``positions * theta^(-2j / rotary_dim)``. Angles, sines and the turn
+    itself in float32; the result in ``x``'s type."""
+    half = rotary_dim // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rotary_dim)
+    angle = positions.astype(jnp.float32)[:, None] * freq      # (time, half)
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:rotary_dim].astype(jnp.float32)
+    turned = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return jnp.concatenate([turned.astype(x.dtype), x[..., rotary_dim:]], -1)
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class GatedAttention(BaseLayer):
+    """Causal grouped-query attention with per-head q/k norms, a partial
+    rotary embedding and a sigmoid output gate, as the Qwen3-Next family's
+    full-attention layers run it. With h = ``n_heads``, h_kv =
+    ``n_kv_heads`` (h a multiple of it) and d = ``head_dim``:
+
+        [q, gate] = W_q x       (h x 2d columns: each head's q, then its gate)
+        k = W_k x,  v = W_v x                          (h_kv x d columns each)
+        q, k = RMSNorm_head(q), RMSNorm_head(k)     (over d, weights q_norm /
+                      k_norm started at zero, the scale ``1 + w``)
+        the first ``rotary_dim`` widths of q and k rotated
+        (``rotate_half_split``), the others left alone
+        each k / v head serves h / h_kv consecutive query heads
+        out = W_o (softmax(q k^T / sqrt(d)) v * sigmoid(gate)),  no bias
+
+    The scores go through ``blocked_causal_attention`` with k and v
+    REPEATED over their group in front of it (``jnp.repeat``, whose
+    transpose sums dk and dv over the group on the way back): the tile
+    kernels keep their one index map and the latent attention's call
+    compiles as before; k and v are then read once a query head, 8 KB a
+    key of the 0.5 MB of scores a head makes from them. Which path a
+    compiled program took is counted at trace time (``bump_active``):
+    ``attention.gqa_blocked`` with more than one tile,
+    ``attention.gqa_single_tile`` otherwise, and beside them
+    ``kernel.pallas_blocked_attention`` / ``kernel.xla_blocked_attention``.
+    A features mask zeroes the output at masked steps (right-padded batches
+    are exact)."""
+
+    n_in: Optional[int] = None
+    n_out: int = 0              # model width; inferred from the input when 0
+    n_heads: int = 4
+    n_kv_heads: int = 2
+    head_dim: int = 32
+    rotary_dim: int = 8
+    rope_theta: float = 10000.0
+    block: int = 512
+    eps: float = 1e-6
+    weight_init: str = "xavier_fan_in"
+
+    supports_stateful = False
+
+    def input_kind(self):
+        return "rnn"
+
+    def is_recurrent(self):
+        return True
+
+    def regularizable(self):
+        return ("Wq", "Wk", "Wv", "Wo")
+
+    def _width(self, it: InputType) -> int:
+        return self.n_out or self.n_in or it.size
+
+    def output_type(self, it: InputType) -> InputType:
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError(f"{self.n_heads} query heads are no multiple "
+                             f"of {self.n_kv_heads} key/value heads")
+        if self.rotary_dim % 2 or self.rotary_dim > self.head_dim:
+            raise ValueError(f"rotary_dim {self.rotary_dim} has to be even "
+                             f"and at most head_dim {self.head_dim}")
+        return InputType.recurrent(self._width(it), it.timeseries_length)
+
+    def init(self, rng, it: InputType, dtype=jnp.float32):
+        d = self.n_in or it.size
+        h, hkv, dh = self.n_heads, self.n_kv_heads, self.head_dim
+        ks = jax.random.split(rng, 4)
+
+        def dense(key, n_in, n_out):
+            return init_weights(key, (n_in, n_out), n_in, n_out,
+                                self.weight_init, self.dist, dtype)
+
+        return {
+            "Wq": dense(ks[0], d, h * 2 * dh),
+            "Wk": dense(ks[1], d, hkv * dh),
+            "Wv": dense(ks[2], d, hkv * dh),
+            "q_norm": jnp.zeros((dh,), dtype),
+            "k_norm": jnp.zeros((dh,), dtype),
+            "Wo": dense(ks[3], h * dh, self._width(it)),
+        }, {}
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        from deeplearning4j_tpu.nn.conf.normalization import rms_norm
+        from deeplearning4j_tpu.perf.compile_watch import bump_active
+
+        x = dropout_input(x, self.dropout, train, rng)
+        bsz, t, _ = x.shape
+        h, hkv, dh = self.n_heads, self.n_kv_heads, self.head_dim
+        qg = (x @ params["Wq"]).reshape(bsz, t, h, 2 * dh)
+        q, gate = qg[..., :dh], qg[..., dh:]
+        k = (x @ params["Wk"]).reshape(bsz, t, hkv, dh)
+        v = (x @ params["Wv"]).reshape(bsz, t, hkv, dh)
+        with jax.named_scope("gattn.qk_norm_rope"):
+            positions = jnp.arange(t)
+            q, k = (rotate_half_split(
+                rms_norm(a, 1.0 + params[w], self.eps), positions,
+                self.rotary_dim, self.rope_theta)
+                for a, w in ((q, "q_norm"), (k, "k_norm")))
+        bump_active("attention.gqa_blocked" if t > self.block
+                    else "attention.gqa_single_tile")
+        with jax.named_scope("gattn.attend"):
+            k, v = (jnp.repeat(a.transpose(0, 2, 1, 3), h // hkv, axis=1)
+                    for a in (k, v))
+            o = blocked_causal_attention(q.transpose(0, 2, 1, 3), k, v,
+                                         self.block)
+        with jax.named_scope("gattn.out_gate"):
+            o = o.transpose(0, 2, 1, 3) * jax.nn.sigmoid(
+                gate.astype(jnp.float32)).astype(x.dtype)
+            out = o.reshape(bsz, t, h * dh) @ params["Wo"]
+        if mask is not None:             # masked steps emit zeros
+            out = out * mask[..., None].astype(out.dtype)
+        return out, state
+
+
 __all__ = ["SelfAttentionLayer", "TransformerEncoderBlock",
-           "MultiHeadLatentAttention", "blocked_causal_attention"]
+           "MultiHeadLatentAttention", "GatedAttention",
+           "blocked_causal_attention", "rotate_half_split"]

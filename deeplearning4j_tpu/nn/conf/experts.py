@@ -1,13 +1,18 @@
 """Gated feed-forward layers: a dense SwiGLU and a routed mixture of experts
 that computes ITS SHARE of an expert-parallel deployment.
 
-Not in the 0.9.x reference line. ``RoutedExperts`` follows the
-DeepSeek-V3 / Kimi family's router: ``s = sigmoid(W_r x)`` over ALL
-``n_experts`` published experts, top-``top_k`` of ``s + bias``, weights
+Not in the 0.9.x reference line. ``RoutedExperts`` routes over ALL
+``n_experts`` published experts: scores ``s = act(W_r x)`` in float32 with
+``act`` the layer's ``router_activation`` (``"sigmoid"``, the DeepSeek-V3 /
+Kimi family's router, or ``"softmax"`` over the experts, the Qwen3-Next
+family's), top-``top_k`` of ``s + bias``, weights
 ``scaling * s_i / sum_topk s_j``, and
 
-    y = sum over chosen experts HELD HERE of w_i E_i(x) + E_shared(x),
+    y = sum over chosen experts HELD HERE of w_i E_i(x) + c(x) E_shared(x),
     E(x) = W_down(SiLU(W_gate x) * W_up x)
+
+with ``c(x) = sigmoid(w_sg . x)`` where the layer has a ``shared_gate`` and
+1 where it has none.
 
 The layer is told which experts it holds (``experts_held`` of them from
 ``expert_offset``). What the absent experts would add is left out: on one
@@ -17,8 +22,8 @@ imbalance: the pairs are sorted by expert and the three products run as
 GROUPED matrix products over the rows each expert really got
 (``grouped_matmul``: the Pallas ``megablox`` kernel on TPU, whose grid
 follows the load, and ``lax.ragged_dot`` elsewhere). The sorted slots are
-computed a window at a time (an eighth of the worst case of tokens x top_k
-slots, four times the even share of a 1/32 deployment): one window when
+computed a window at a time (four times the even share of the held
+experts, at most the worst case of tokens x top_k slots): one window when
 the held pairs fit it, all of them under a ``lax.cond`` when they do not,
 so that work, traffic and memory follow the load and a skewed batch is
 slower, never wrong. The selection bias and the load counters live in the layer's
@@ -140,7 +145,9 @@ class RoutedExperts(BaseLayer):
     the module docstring). ``n_experts`` is the router's width (all
     published experts), ``experts_held`` how many live here, from
     ``expert_offset``. ``shared_size`` 0 leaves the shared expert out (the
-    other shares of a test that counts it once)."""
+    other shares of a test that counts it once). ``router_activation`` is
+    ``"sigmoid"`` or ``"softmax"``; ``shared_gate`` multiplies the shared
+    expert by ``sigmoid(w_sg . x)`` (the leaf ``Wsg``)."""
 
     n_in: Optional[int] = None
     n_out: int = 0
@@ -151,10 +158,13 @@ class RoutedExperts(BaseLayer):
     expert_size: int = 0
     shared_size: int = 0
     scaling: float = 1.0
+    router_activation: str = "sigmoid"
+    shared_gate: bool = False
     weight_init: str = "xavier_fan_in"
 
     def regularizable(self):
-        return ("Wr", "Wgate", "Wup", "Wdown", "Sgate", "Sup", "Sdown")
+        return ("Wr", "Wgate", "Wup", "Wdown", "Sgate", "Sup", "Sdown",
+                "Wsg")
 
     def _width(self, it: InputType) -> int:
         return self.n_out or self.n_in or it.flat_size()
@@ -164,6 +174,11 @@ class RoutedExperts(BaseLayer):
             raise ValueError(
                 f"experts {self.expert_offset}..{self.expert_offset + self.experts_held}"
                 f" held, but the router has {self.n_experts}")
+        if self.router_activation not in ("sigmoid", "softmax"):
+            raise ValueError("router_activation is 'sigmoid' or 'softmax', "
+                             f"not {self.router_activation!r}")
+        if self.shared_gate and not self.shared_size:
+            raise ValueError("a shared_gate needs a shared expert")
         if it.kind == "rnn":
             return InputType.recurrent(self._width(it), it.timeseries_length)
         return InputType.feed_forward(self._width(it))
@@ -173,7 +188,7 @@ class RoutedExperts(BaseLayer):
         width = self._width(it)
         ff = self.expert_size or d
         e = self.experts_held
-        ks = jax.random.split(rng, 7)
+        ks = jax.random.split(rng, 8)
 
         def w(key, shape):
             return init_weights(key, shape, shape[-2], shape[-1],
@@ -186,6 +201,8 @@ class RoutedExperts(BaseLayer):
             params.update({"Sgate": w(ks[4], (d, self.shared_size)),
                            "Sup": w(ks[5], (d, self.shared_size)),
                            "Sdown": w(ks[6], (self.shared_size, width))})
+        if self.shared_gate:
+            params["Wsg"] = w(ks[7], (d, 1))
         state = {"bias": jnp.zeros((self.n_experts,), jnp.float32),
                  "expert_tokens": jnp.zeros((e,), jnp.int32),
                  "pairs_held": jnp.zeros((), jnp.int32),
@@ -194,7 +211,9 @@ class RoutedExperts(BaseLayer):
 
     def route(self, x, w_r, bias):
         """(weights, expert ids), both (tokens, top_k), over all experts."""
-        s = jax.nn.sigmoid((x @ w_r).astype(jnp.float32))
+        logits = (x @ w_r).astype(jnp.float32)
+        s = (jax.nn.softmax(logits, -1) if self.router_activation == "softmax"
+             else jax.nn.sigmoid(logits))
         _, idx = lax.top_k(s + bias, self.top_k)
         chosen = jnp.take_along_axis(s, idx, -1)
         return (self.scaling * chosen
@@ -215,11 +234,14 @@ class RoutedExperts(BaseLayer):
             sizes = jnp.sum(jax.nn.one_hot(key, e + 1, dtype=jnp.int32),
                             0)[:e]
             n_held = jnp.sum(sizes)
-        # the sorted slots are computed ``window`` at a time: an eighth of
-        # the worst case, four times the even share of a 1/32 deployment.
-        # The usual load fits the first window; a skewed batch takes as
-        # many as it needs, up to the worst case (tokens x top_k slots)
-        window = -(-n * k // (8 * GMM_ROW_TILE)) * GMM_ROW_TILE
+        # the sorted slots are computed ``window`` at a time: four times
+        # the even share of the held experts (held / all of the tokens x
+        # top_k slots). The usual load fits the first window, with room
+        # for a router that drifts towards the experts it is trained
+        # through; a skewed batch takes as many as it needs, up to the
+        # worst case (tokens x top_k slots)
+        slots = min(-(-n * k * 4 * e // self.n_experts), n * k)
+        window = -(-slots // GMM_ROW_TILE) * GMM_ROW_TILE
         windows = -(-n * k // window)
         if windows * window > n * k:
             order = jnp.pad(order, (0, windows * window - n * k))
@@ -281,8 +303,14 @@ class RoutedExperts(BaseLayer):
         y = y.astype(x.dtype)
         if self.shared_size:
             with jax.named_scope("moe.shared"):
-                y = y + _swiglu(xf, params["Sgate"], params["Sup"],
-                                params["Sdown"])
+                shared = _swiglu(xf, params["Sgate"], params["Sup"],
+                                 params["Sdown"])
+                if self.shared_gate:
+                    with jax.named_scope("moe.shared_gate"):
+                        shared = shared * jax.nn.sigmoid(
+                            (xf @ params["Wsg"]).astype(jnp.float32)).astype(
+                                shared.dtype)
+                y = y + shared
         # ``covered`` counts the rows that the windows which RAN handed to
         # the grouped products; a held pair outside them was dropped
         new_state = {"bias": state["bias"],

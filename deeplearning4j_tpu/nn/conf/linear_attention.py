@@ -1,4 +1,6 @@
-"""Linear attention as a registered layer: Kimi Delta Attention (KDA).
+"""Linear attention as registered layers: Kimi Delta Attention (KDA) and,
+over the same chunked scan, Gated DeltaNet (one decay a head; see the
+class).
 
 Not in the 0.9.x reference line (it predates attention altogether); the
 layer follows Kimi Linear (arXiv:2510.26692): a gated delta rule whose
@@ -73,6 +75,16 @@ def causal_depthwise_conv(x, w):
 def _l2norm(x, eps: float = 1e-6):
     x32 = x.astype(jnp.float32)
     return x32 * lax.rsqrt(jnp.sum(jnp.square(x32), -1, keepdims=True) + eps)
+
+
+def _decay_start(key_rates, key_steps, rates: int, steps: int, dtype):
+    """(``A_log``, ``dt_bias``), the usual start of a gated delta layer:
+    ``rates`` decay rates log-uniform in [1, 16], ``steps`` step sizes
+    log-uniform in [1e-3, 1e-1] (the bias is their inverse softplus)."""
+    a_log = jnp.log(jax.random.uniform(key_rates, (rates,), dtype, 1.0, 16.0))
+    dt = jnp.exp(jax.random.uniform(key_steps, (steps,), dtype)
+                 * (math.log(0.1) - math.log(0.001)) + math.log(0.001))
+    return a_log, dt + jnp.log(-jnp.expm1(-dt))
 
 
 def _decayed_scores(xs, k, g_cum, sub: int):
@@ -297,13 +309,8 @@ class KimiDeltaAttention(BaseLayer):
                                          dtype)
                        / math.sqrt(self.conv_size))
         p["Wf1"], p["Wf2"] = dense(d, r), dense(r, inner)
-        # the usual start of a gated delta layer: decay rates log-uniform
-        # in [1, 16], step sizes log-uniform in [1e-3, 1e-1]
-        p["A_log"] = jnp.log(jax.random.uniform(next(keys), (self.n_heads,),
-                                                dtype, 1.0, 16.0))
-        dt = jnp.exp(jax.random.uniform(next(keys), (inner,), dtype)
-                     * (math.log(0.1) - math.log(0.001)) + math.log(0.001))
-        p["dt_bias"] = dt + jnp.log(-jnp.expm1(-dt))
+        p["A_log"], p["dt_bias"] = _decay_start(next(keys), next(keys),
+                                                self.n_heads, inner, dtype)
         p["Wb"] = dense(d, self.n_heads)
         p["Wg1"], p["Wg2"] = dense(d, r), dense(r, inner)
         p["o_norm"] = jnp.ones((self.head_dim,), dtype)
@@ -342,4 +349,121 @@ class KimiDeltaAttention(BaseLayer):
         return out, state
 
 
-__all__ = ["KimiDeltaAttention", "chunked_kda", "causal_depthwise_conv"]
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class GatedDeltaNet(BaseLayer):
+    """Gated DeltaNet over (batch, time, features), as the Qwen3-Next
+    family runs it (``model_type`` ``qwen3_next``): the delta rule with ONE
+    decay a value head and step, fewer key heads than value heads, one
+    convolution over q|k|v and a SiLU-gated per-head RMSNorm at the output.
+    With h_k = ``n_key_heads``, h_v = ``n_value_heads`` (a multiple of h_k)
+    and d = ``head_dim`` for keys and values alike:
+
+        [q, k, v, z] = W_qkvz x     (h_k d + h_k d + h_v d + h_v d columns)
+        [b, a] = W_ba x                                  (h_v + h_v columns)
+        [q, k, v] = SiLU(conv([q, k, v]))  (causal, depthwise, no bias)
+        q = L2norm(q) / sqrt(d),  k = L2norm(k)   (each head serves h_v / h_k
+                                                   consecutive value heads)
+        beta_t = sigmoid(b_t),  g_t = -exp(A_log) * softplus(a_t + dt_bias)
+        S_t = exp(g_t) S_{t-1} + beta_t k_t (v_t - exp(g_t) S_{t-1}^T k_t)^T
+        o_t = S_t^T q_t
+        out = W_o (o_norm * o_t / sqrt(mean(o_t^2) + eps) * SiLU(z_t))
+
+    That recurrence is KDA's with the decay alike in every channel, so it
+    runs through ``chunked_kda`` (and on a TPU through the ``kda_scan``
+    kernels, which are exact at any decay) with ``g`` spread over the
+    head's channels; there is no scalar form of the kernels. The fused
+    projections keep their columns in the order written above, plain
+    blocks. A features mask zeroes the output at masked steps; the
+    recurrence itself still runs over them (right-padded batches are
+    exact)."""
+
+    n_in: Optional[int] = None
+    n_out: int = 0              # model width; inferred from the input when 0
+    n_key_heads: int = 2
+    n_value_heads: int = 4
+    head_dim: int = 64
+    conv_size: int = 4
+    chunk: int = 64
+    eps: float = 1e-6
+    weight_init: str = "xavier_fan_in"
+
+    supports_stateful = False   # no rnn_time_step carry (yet)
+
+    def input_kind(self):
+        return "rnn"
+
+    def is_recurrent(self):
+        return True
+
+    def regularizable(self):
+        return ("Wqkvz", "Wba", "Wo")
+
+    def _width(self, it: InputType) -> int:
+        return self.n_out or self.n_in or it.size
+
+    def output_type(self, it: InputType) -> InputType:
+        if self.n_value_heads % self.n_key_heads:
+            raise ValueError(
+                f"{self.n_value_heads} value heads are no multiple of "
+                f"{self.n_key_heads} key heads")
+        return InputType.recurrent(self._width(it), it.timeseries_length)
+
+    def init(self, rng, it: InputType, dtype=jnp.float32):
+        d = self.n_in or it.size
+        hk, hv, dh = self.n_key_heads, self.n_value_heads, self.head_dim
+        ks = jax.random.split(rng, 6)
+
+        def dense(key, n_in, n_out):
+            return init_weights(key, (n_in, n_out), n_in, n_out,
+                                self.weight_init, self.dist, dtype)
+
+        a_log, dt_bias = _decay_start(ks[3], ks[4], hv, hv, dtype)
+        return {
+            "Wqkvz": dense(ks[0], d, 2 * (hk + hv) * dh),
+            "Wba": dense(ks[1], d, 2 * hv),
+            "conv": (jax.random.normal(ks[2], (self.conv_size,
+                                               (2 * hk + hv) * dh), dtype)
+                     / math.sqrt(self.conv_size)),
+            "A_log": a_log, "dt_bias": dt_bias,      # one each a value head
+            "o_norm": jnp.ones((dh,), dtype),
+            "Wo": dense(ks[5], hv * dh, self._width(it)),
+        }, {}
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        x = dropout_input(x, self.dropout, train, rng)
+        bsz, t, _ = x.shape
+        hk, hv, dh = self.n_key_heads, self.n_value_heads, self.head_dim
+        f32 = jnp.float32
+        with jax.named_scope("gdn.conv"):
+            qkvz = x @ params["Wqkvz"]
+            mixed = jax.nn.silu(causal_depthwise_conv(
+                qkvz[..., :(2 * hk + hv) * dh], params["conv"]))
+            z = qkvz[..., (2 * hk + hv) * dh:].reshape(bsz, t, hv, dh)
+            q = mixed[..., :hk * dh].reshape(bsz, t, hk, dh)
+            k = mixed[..., hk * dh:2 * hk * dh].reshape(bsz, t, hk, dh)
+            v = mixed[..., 2 * hk * dh:].reshape(bsz, t, hv, dh)
+            # normalised in float32, handed on in the compute type; a key
+            # head's q and k serve h_v / h_k value heads that lie together
+            q = (_l2norm(q) * (1.0 / math.sqrt(dh))).astype(x.dtype)
+            k = _l2norm(k).astype(x.dtype)
+            if hv != hk:
+                q, k = (jnp.repeat(a, hv // hk, axis=2) for a in (q, k))
+            ba = (x @ params["Wba"]).astype(f32)
+            b = jax.nn.sigmoid(ba[..., :hv])
+            g = -jnp.exp(params["A_log"].astype(f32)) * jax.nn.softplus(
+                ba[..., hv:] + params["dt_bias"].astype(f32))
+        with jax.named_scope("gdn.scan"):
+            o = chunked_kda(q, k, v, jnp.broadcast_to(g[..., None], q.shape),
+                            b, chunk=self.chunk)
+        with jax.named_scope("gdn.out_gate"):
+            o = rms_norm(o, params["o_norm"], self.eps) * jax.nn.silu(
+                z.astype(f32))
+            out = o.reshape(bsz, t, hv * dh).astype(x.dtype) @ params["Wo"]
+        if mask is not None:             # masked steps emit zeros
+            out = out * mask[..., None].astype(out.dtype)
+        return out, state
+
+
+__all__ = ["KimiDeltaAttention", "GatedDeltaNet", "chunked_kda",
+           "causal_depthwise_conv"]

@@ -139,17 +139,23 @@ def rms_norm(x, g, eps: float):
 @dataclasses.dataclass(frozen=True)
 class RMSNorm(BaseLayer):
     """Root-mean-square normalisation over the feature axis with a learned
-    scale ``g`` and no bias or mean subtraction (Zhang & Sennrich 2019): the
-    pre-norm of today's decoder blocks. Shape-preserving."""
+    scale and no bias or mean subtraction (Zhang & Sennrich 2019): the
+    pre-norm of today's decoder blocks. Shape-preserving. The scale is the
+    leaf ``g``, started at one; with ``zero_centered`` (the Qwen3-Next
+    family's norm) it is ``1 + w`` with the leaf ``w`` started at zero."""
 
     eps: float = 1e-5
+    zero_centered: bool = False
 
     def regularizable(self):
         return ()
 
     def init(self, rng, it: InputType, dtype=jnp.float32):
         n = it.channels if it.kind == "cnn" else it.flat_size()
+        if self.zero_centered:
+            return {"w": jnp.zeros((n,), dtype)}, {}
         return {"g": jnp.ones((n,), dtype)}, {}
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        return rms_norm(x, params["g"], self.eps), state
+        g = 1.0 + params["w"] if self.zero_centered else params["g"]
+        return rms_norm(x, g, self.eps), state

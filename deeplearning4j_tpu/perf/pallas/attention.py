@@ -1,5 +1,6 @@
 """Pallas TPU kernels for blocked causal attention with q/k heads and v
-heads of different widths (latent attention).
+heads of different widths (latent attention) or alike (gated
+grouped-query attention, its k and v repeated to the query heads).
 
 The boundary is ``blocked_causal_attention`` (nn/conf/attention.py): causal
 softmax(q k^T / sqrt(d_q)) v in tiles, online softmax forward, the
@@ -87,7 +88,10 @@ def _dq_bytes_a_head(t: int, dq: int, dtype) -> int:
 
 def supported(q, k, v, block: int) -> bool:
     """Shapes the kernels take: q, k (batch, heads, time, d_q) and v
-    (batch, heads, time, d_v) alike in bfloat16 or float32, ``time``
+    (batch, heads, time, d_v) with the SAME head count, any number of
+    heads (grouped-query heads reach the kernels with k and v repeated
+    over their group by the caller: ``GatedAttention``), alike in
+    bfloat16 or float32, ``time``
     (padded by the caller) more than one ``block`` and a multiple of 128,
     head widths multiples of 64 up to 256, one head's dq within the
     backward kernel's VMEM (32768 steps of 192 in bfloat16); on a TPU

@@ -182,6 +182,43 @@ def _blocked_attention_float32(S):
     return fwd_bwd, args, ("mla_attend_fwd", "mla_attend_bwd")
 
 
+def _kda_scan_scalar_decay(S):
+    # Gated DeltaNet at the Qwen3-Next share's shape: ONE decay a value
+    # head spread over its 128 channels in front of the kernels, 16 q/k
+    # heads repeated to the 32 value heads, one sequence of 8192 steps
+    from deeplearning4j_tpu.nn.conf.linear_attention import chunked_kda
+    args = ((S((1, 8192, 16, 128), BF16),) * 2 + (S((1, 8192, 32, 128), BF16),)
+            + (S((1, 8192, 32), F32),) * 2)
+
+    def fwd_bwd(*a):
+        def scan(q, k, v, g, b):
+            q, k = (jnp.repeat(x, 2, axis=2) for x in (q, k))
+            return jnp.sum(chunked_kda(
+                q, k, v, jnp.broadcast_to(g[..., None], q.shape), b))
+        return jax.grad(scan, argnums=range(5))(*a)
+
+    return fwd_bwd, args, ("kda_scan_fwd", "kda_scan_bwd")
+
+
+def _blocked_attention_grouped(S):
+    # gated attention at the Qwen3-Next share's shape: 16 query heads of
+    # 256 over 2 k/v heads repeated in front of the kernels, one sequence
+    # of 8192 steps in bfloat16; the backward kernel keeps four heads' dq
+    # (8192 x 256 float32 each) in VMEM
+    from deeplearning4j_tpu.nn.conf.attention import blocked_causal_attention
+    from deeplearning4j_tpu.perf.pallas import attention
+    q, kv = S((1, 16, 8192, 256), BF16), S((1, 2, 8192, 256), BF16)
+    assert pk.take("blocked_attention", attention.supported(q, q, q, 512))
+
+    def fwd_bwd(q, k, v):
+        def attend(q, k, v):
+            k, v = (jnp.repeat(x, 8, axis=1) for x in (k, v))
+            return jnp.sum(blocked_causal_attention(q, k, v, 512).astype(F32))
+        return jax.grad(attend, argnums=(0, 1, 2))(q, k, v)
+
+    return fwd_bwd, (q, kv, kv), ("mla_attend_fwd", "mla_attend_bwd")
+
+
 def _bn_fwd(S):
     # ResNet50 batch 128, the one stage whose rows fit: 7x7
     z = S((128, 7, 7, 2048), BF16)
@@ -203,7 +240,9 @@ AUTO_CASES = [(_pq, "adc_pq"), (_int4_table, "int4_dot"),
               (_grouped_experts, None), (_kda_scan, "kda_scan"),
               (_kda_scan_float32, None),
               (_blocked_attention, "blocked_attention"),
-              (_blocked_attention_float32, None)]
+              (_blocked_attention_float32, None),
+              (_kda_scan_scalar_decay, None),
+              (_blocked_attention_grouped, None)]
 EXPLICIT_CASES = [_bn_fwd, _bn_bwd]
 
 

@@ -1,9 +1,10 @@
 """The layers a hybrid linear-attention expert model needs (RMSNorm,
-GatedFeedForward, KimiDeltaAttention, MultiHeadLatentAttention,
-RoutedExperts, TokenOutputLayer and the ``sparse_mcxent`` loss), each
-against a plain form written out here, on the CPU at small sizes in
-float32. The whole model against the benchmark's reference is in
-``tests/benchmark/test_benchmark_kimi_linear.py``."""
+GatedFeedForward, KimiDeltaAttention, GatedDeltaNet,
+MultiHeadLatentAttention, GatedAttention, RoutedExperts, TokenOutputLayer
+and the ``sparse_mcxent`` loss), each against a plain form written out
+here, on the CPU at small sizes in float32. The whole models against the
+benchmark's references are in ``tests/benchmark/test_benchmark_kimi_linear.py``
+and ``test_benchmark_qwen3_next.py``."""
 
 import math
 
@@ -16,13 +17,15 @@ from deeplearning4j_tpu import obs
 from deeplearning4j_tpu.datasets.dataset import DataSet
 from deeplearning4j_tpu.nn import lossfunctions
 from deeplearning4j_tpu.nn.conf import InputType, NeuralNetConfiguration
-from deeplearning4j_tpu.nn.conf.attention import (MultiHeadLatentAttention,
-                                                  blocked_causal_attention)
+from deeplearning4j_tpu.nn.conf.attention import (GatedAttention,
+                                                  MultiHeadLatentAttention,
+                                                  blocked_causal_attention,
+                                                  rotate_half_split)
 from deeplearning4j_tpu.nn.conf.experts import (GatedFeedForward,
                                                 RoutedExperts, grouped_matmul)
 from deeplearning4j_tpu.nn.conf.layers import layer_from_dict, layer_to_dict
 from deeplearning4j_tpu.nn.conf.linear_attention import (
-    KimiDeltaAttention, causal_depthwise_conv, chunked_kda)
+    GatedDeltaNet, KimiDeltaAttention, causal_depthwise_conv, chunked_kda)
 from deeplearning4j_tpu.nn.conf.normalization import RMSNorm
 from deeplearning4j_tpu.nn.conf.recurrent import (EmbeddingSequenceLayer,
                                                   TokenOutputLayer)
@@ -192,6 +195,90 @@ def test_kda_layer_masks_its_output_and_keeps_its_width():
     assert out.shape == (2, 20, 12)
     assert np.allclose(out[:, :15], free[:, :15], atol=1e-6)
     assert not np.any(np.asarray(out[:, 15:]))
+
+
+# ----------------------------------------------------------- Gated DeltaNet
+def _gdn_plain(layer, params, x, scan):
+    """The layer's equations written out; ``scan(q, k, v, g, b)`` runs the
+    recurrence with ``g`` ONE number a value head (batch, time, heads)."""
+    bsz, t, _ = x.shape
+    hk, hv, dh = layer.n_key_heads, layer.n_value_heads, layer.head_dim
+    qkvz = x @ params["Wqkvz"]
+    mixed = jax.nn.silu(causal_depthwise_conv(qkvz[..., :(2 * hk + hv) * dh],
+                                              params["conv"]))
+    z = qkvz[..., (2 * hk + hv) * dh:].reshape(bsz, t, hv, dh)
+    q = mixed[..., :hk * dh].reshape(bsz, t, hk, dh)
+    k = mixed[..., hk * dh:2 * hk * dh].reshape(bsz, t, hk, dh)
+    v = mixed[..., 2 * hk * dh:].reshape(bsz, t, hv, dh)
+
+    def unit(a):
+        return a / jnp.sqrt(jnp.sum(a * a, -1, keepdims=True) + 1e-6)
+
+    # value head j reads q/k head j // (hv / hk)
+    q = jnp.repeat(unit(q) / math.sqrt(dh), hv // hk, axis=2)
+    k = jnp.repeat(unit(k), hv // hk, axis=2)
+    ba = x @ params["Wba"]
+    b = jax.nn.sigmoid(ba[..., :hv])
+    g = -jnp.exp(params["A_log"]) * jax.nn.softplus(
+        ba[..., hv:] + params["dt_bias"])
+    o = scan(q, k, v, g, b)
+    o = o / jnp.sqrt(jnp.mean(o * o, -1, keepdims=True) + layer.eps)
+    o = o * params["o_norm"] * jax.nn.silu(z)
+    return o.reshape(bsz, t, hv * dh) @ params["Wo"]
+
+
+def _scalar_decay_scan(q, k, v, g, b):
+    """S_t = exp(g_t) S_{t-1} + b_t k_t (v_t - exp(g_t) S_{t-1}^T k_t)^T,
+    o_t = S_t^T q_t with ``lax.scan`` over time: differentiable."""
+    def step(s, inp):
+        qt, kt, vt, gt, bt = inp
+        s = s * jnp.exp(gt)[..., None, None]
+        s = s + jnp.einsum("bhk,bhv->bhkv", kt, bt[..., None] * (
+            vt - jnp.einsum("bhk,bhkv->bhv", kt, s)))
+        return s, jnp.einsum("bhk,bhkv->bhv", qt, s)
+
+    s0 = jnp.zeros(q.shape[:1] + q.shape[2:] + v.shape[-1:])
+    _, o = jax.lax.scan(step, s0, tuple(jnp.moveaxis(a, 1, 0)
+                                        for a in (q, k, v, g, b)))
+    return jnp.moveaxis(o, 0, 1)
+
+
+def test_gated_delta_net_is_its_equations_token_by_token(kda_impl):
+    """Output, every parameter's gradient and the input's against the
+    equations with a scan over tokens and ONE decay a head; the same
+    output from KDA's own recurrence (``_recurrence``, a decay a channel)
+    given that decay in every channel; fewer key heads than value heads;
+    a length that is no multiple of the chunk; a mask zeroes its steps."""
+    dh = kda_impl
+    layer = GatedDeltaNet(n_key_heads=1 if dh == 128 else 2,
+                          n_value_heads=2 if dh == 128 else 4, head_dim=dh)
+    bsz, t = (1, 70) if dh == 128 else (2, 70)
+    params, state = layer.init(jax.random.key(0), InputType.recurrent(12, t))
+    x = jax.random.normal(jax.random.key(1), (bsz, t, 12))
+
+    def run(fn):
+        def loss(params, x):
+            o = fn(params, x)
+            return jnp.sum(jnp.sin(o)), o
+        return jax.value_and_grad(loss, (0, 1), has_aux=True)(params, x)
+
+    got = run(lambda p, x: layer.apply(p, state, x)[0])
+    want = run(lambda p, x: _gdn_plain(layer, p, x, _scalar_decay_scan))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert float(jnp.max(jnp.abs(a - b))) < 20 * TOL * max(
+            1.0, float(jnp.max(jnp.abs(b))))
+    per_channel = _gdn_plain(
+        layer, params, x, lambda q, k, v, g, b: jnp.asarray(_recurrence(
+            q, k, v, jnp.broadcast_to(g[..., None], q.shape), b),
+            jnp.float32))
+    assert float(jnp.max(jnp.abs(got[0][1] - per_channel))) < 20 * TOL
+    assert layer.output_type(InputType.recurrent(12, t)).size == 12
+    mask = jnp.ones((bsz, t)).at[0, t - 5:].set(0.0)
+    masked, _ = layer.apply(params, state, x, mask=mask)
+    assert float(jnp.max(jnp.abs(masked[0, t - 5:]))) == 0.0
+    with pytest.raises(ValueError, match="no multiple"):
+        GatedDeltaNet(n_key_heads=3, n_value_heads=4).output_type(
+            InputType.recurrent(12, t))
 
 
 # --------------------------------------------------------------------- MLA
@@ -372,6 +459,112 @@ def test_mla_layer_is_the_dense_form_and_masks_its_output(attn_impl):
     assert float(jnp.max(jnp.abs(masked[0] - got[0][1][0]))) < TOL
 
 
+# --------------------------------------------------------- gated attention
+def test_rotation_leaves_the_other_widths_and_position_zero_alone():
+    x = jax.random.normal(jax.random.key(0), (2, 9, 3, 256))
+    got = rotate_half_split(x, jnp.arange(9), 64, 1e7)
+    assert np.array_equal(np.asarray(got[..., 64:]), np.asarray(x[..., 64:]))
+    assert np.array_equal(np.asarray(got[:, 0]), np.asarray(x[:, 0]))
+    assert float(jnp.max(jnp.abs(got[:, 1:, :, :64] - x[:, 1:, :, :64]))) > 0.1
+    # width j turns with width j + 32 by t * theta^(-2j / 64)
+    j, t = 5, 7
+    angle = t * 1e7 ** (-2 * j / 64)
+    a, b = x[1, t, 2, j], x[1, t, 2, j + 32]
+    assert float(got[1, t, 2, j]) == pytest.approx(
+        float(a * math.cos(angle) - b * math.sin(angle)), abs=1e-5)
+    assert float(got[1, t, 2, j + 32]) == pytest.approx(
+        float(b * math.cos(angle) + a * math.sin(angle)), abs=1e-5)
+    # a turn: norms stay, and q . k depends on the distance alone
+    assert float(jnp.max(jnp.abs(jnp.linalg.norm(got, axis=-1)
+                                 - jnp.linalg.norm(x, axis=-1)))) < 1e-4
+    q = jnp.broadcast_to(x[:1, :1], (1, 9, 3, 256))
+    k = jnp.broadcast_to(x[1:, :1], (1, 9, 3, 256))
+    rq, rk = (rotate_half_split(a, jnp.arange(9), 64, 1e7) for a in (q, k))
+    near = jnp.sum(rq[0, 3] * rk[0, 1], -1)
+    far = jnp.sum(rq[0, 8] * rk[0, 6], -1)
+    assert float(jnp.max(jnp.abs(near - far))) < 1e-3
+    # bfloat16 in, bfloat16 out, turned in float32
+    assert rotate_half_split(x.astype(jnp.bfloat16), jnp.arange(9), 64,
+                             1e7).dtype == jnp.bfloat16
+
+
+def _gattn_layer(impl, block):
+    wide = impl.name == "pallas"          # the kernels take heads of 64 up
+    return GatedAttention(n_heads=4, n_kv_heads=2, head_dim=64 if wide else 16,
+                          rotary_dim=16 if wide else 4, rope_theta=1e4,
+                          block=block)
+
+
+def test_gated_attention_is_full_heads_with_k_and_v_repeated(attn_impl):
+    """The layer against its equations written out with a dense score
+    matrix over FOUR full heads whose k and v are the two k/v heads
+    repeated: output and the gradients of every parameter (dk and dv
+    summed over the group) and of the input, at a length that is no
+    multiple of the tile; a features mask zeroes the masked steps."""
+    block = 16 if attn_impl.name == "xla" else 128
+    layer = _gattn_layer(attn_impl, block)
+    t, h, hkv, dh = 2 * block + 3, layer.n_heads, layer.n_kv_heads, \
+        layer.head_dim
+    params, state = layer.init(jax.random.key(2), InputType.recurrent(12, t))
+    params = {k: (0.3 * jax.random.normal(jax.random.key(7), v.shape)
+                  if k.endswith("_norm") else v) for k, v in params.items()}
+    x = jax.random.normal(jax.random.key(3), (2, t, 12))
+
+    def dense(params, x):
+        qg = (x @ params["Wq"]).reshape(2, t, h, 2 * dh)
+        q, gate = qg[..., :dh], qg[..., dh:]
+        k = (x @ params["Wk"]).reshape(2, t, hkv, dh)
+        v = (x @ params["Wv"]).reshape(2, t, hkv, dh)
+
+        def normed(a, w):
+            a = a / jnp.sqrt(jnp.mean(a * a, -1, keepdims=True) + layer.eps)
+            return rotate_half_split(a * (1.0 + w), jnp.arange(t),
+                                     layer.rotary_dim, layer.rope_theta)
+
+        q, k = normed(q, params["q_norm"]), normed(k, params["k_norm"])
+        k, v = (jnp.repeat(a, h // hkv, axis=2) for a in (k, v))
+        o = _dense_causal(*(a.transpose(0, 2, 1, 3) for a in (q, k, v)))
+        o = o.transpose(0, 2, 1, 3) * jax.nn.sigmoid(gate)
+        return o.reshape(2, t, h * dh) @ params["Wo"]
+
+    def run(fn):
+        def loss(params, x):
+            o = fn(params, x)
+            return jnp.sum(jnp.sin(o)), o
+        return jax.value_and_grad(loss, (0, 1), has_aux=True)(params, x)
+
+    got = run(lambda p, x: layer.apply(p, state, x)[0])
+    want = run(dense)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert float(jnp.max(jnp.abs(a - b))) < 20 * TOL
+    assert float(jnp.max(jnp.abs(got[1][0]["Wk"]))) > 0
+    mask = jnp.ones((2, t)).at[1, t - 5:].set(0.0)
+    masked, _ = layer.apply(params, state, x, mask=mask)
+    assert float(jnp.max(jnp.abs(masked[1, t - 5:]))) == 0.0
+    assert float(jnp.max(jnp.abs(masked[0] - got[0][1][0]))) < TOL
+
+
+def test_gated_attention_counts_the_path_it_took(attn_impl):
+    from deeplearning4j_tpu.perf.compile_watch import GLOBAL
+    attn_impl.one_tile(only=False)
+    block = 16 if attn_impl.name == "xla" else 128
+    layer = _gattn_layer(attn_impl, block)
+    t = 2 * block + block // 2
+    params, state = layer.init(jax.random.key(0), InputType.recurrent(12, t))
+    x = jax.random.normal(jax.random.key(1), (1, t, 12))
+    before = dict(GLOBAL.as_dict().get("counters", {}))
+    out, _ = layer.apply(params, state, x)
+    layer.apply(params, state, x[:, :block])
+    after = GLOBAL.as_dict()["counters"]
+    assert out.shape == (1, t, 12)
+    for name in ("attention.gqa_blocked", "attention.gqa_single_tile"):
+        assert after[name] == before.get(name, 0) + 1
+    for bad in (dict(n_heads=3, n_kv_heads=2), dict(rotary_dim=5),
+                dict(head_dim=8, rotary_dim=16)):
+        with pytest.raises(ValueError):
+            GatedAttention(**bad).output_type(InputType.recurrent(12, t))
+
+
 # -------------------------------------------------------------------- loss
 @pytest.mark.parametrize("t,block,masked", [(64, 16, False), (50, 16, False),
                                             (50, 16, True), (10, 16, True)])
@@ -422,7 +615,15 @@ def test_blocked_loss_never_builds_the_sequence_logits():
 
 
 # ------------------------------------------------------------------ experts
-def _experts(held, offset=0, shared=8, total=8, top_k=2):
+def _experts(held, offset=0, shared=8, total=8, top_k=2, softmax=False):
+    """Kimi's router (sigmoid scores x 2.446), or with ``softmax`` the
+    Qwen3-Next family's: softmax scores, no scale, the shared expert
+    behind a sigmoid gate."""
+    if softmax:
+        return RoutedExperts(n_experts=total, experts_held=held,
+                             expert_offset=offset, top_k=top_k, expert_size=8,
+                             shared_size=shared, router_activation="softmax",
+                             shared_gate=bool(shared))
     return RoutedExperts(n_experts=total, experts_held=held,
                          expert_offset=offset, top_k=top_k, expert_size=8,
                          shared_size=shared, scaling=2.446)
@@ -430,7 +631,9 @@ def _experts(held, offset=0, shared=8, total=8, top_k=2):
 
 def _plain_routed(layer, params, x, bias):
     """The masked loop over the held experts."""
-    s = jax.nn.sigmoid(x @ params["Wr"])
+    s = (jax.nn.softmax(x @ params["Wr"], -1)
+         if layer.router_activation == "softmax"
+         else jax.nn.sigmoid(x @ params["Wr"]))
     _, idx = jax.lax.top_k(s + bias, layer.top_k)
     chosen = jnp.take_along_axis(s, idx, -1)
     w = layer.scaling * chosen / jnp.sum(chosen, -1, keepdims=True)
@@ -441,14 +644,18 @@ def _plain_routed(layer, params, x, bias):
         hidden = jax.nn.silu(x @ params["Wgate"][e]) * (x @ params["Wup"][e])
         y = y + weight[..., None] * (hidden @ params["Wdown"][e])
     if layer.shared_size:
-        y = y + (jax.nn.silu(x @ params["Sgate"]) * (x @ params["Sup"])) \
+        shared = (jax.nn.silu(x @ params["Sgate"]) * (x @ params["Sup"])) \
             @ params["Sdown"]
+        if layer.shared_gate:
+            shared = shared * jax.nn.sigmoid(x @ params["Wsg"])
+        y = y + shared
     return y
 
 
+@pytest.mark.parametrize("softmax", [False, True], ids=["sigmoid", "softmax"])
 @pytest.mark.parametrize("held,offset", [(8, 0), (4, 0), (2, 6)])
-def test_routed_experts_are_the_masked_loop(held, offset):
-    layer = _experts(held, offset)
+def test_routed_experts_are_the_masked_loop(held, offset, softmax):
+    layer = _experts(held, offset, softmax=softmax)
     it = InputType.recurrent(12, 24)
     params, state = layer.init(jax.random.key(0), it)
     x = jax.random.normal(jax.random.key(1), (2, 24, 12))
@@ -507,8 +714,9 @@ def test_no_pair_is_dropped_at_any_imbalance(where):
 @pytest.mark.parametrize("push", [0.0, 10.0], ids=["first_tier",
                                                     "worst_case_tier"])
 def test_both_buffer_tiers_give_the_masked_loop(push):
-    """1,200 pairs: the first tier computes 256 sorted slots, enough for
-    the 75 pairs an even router sends to 2 of 32 experts; a router pushed
+    """1,200 pairs: the first tier computes 384 sorted slots (four times
+    the 75 pairs an even router sends to 2 of 32 experts, in row tiles of
+    128), enough for what this router sends them; a router pushed
     onto the held experts (1,200 pairs on them) takes the worst-case tier.
     Same result, same gradients, nothing dropped."""
     layer = _experts(held=2, offset=4, shared=0, total=32)
@@ -518,7 +726,7 @@ def test_both_buffer_tiers_give_the_masked_loop(push):
     x = jax.random.normal(jax.random.key(1), (2, 300, 12))
     out, new = layer.apply(params, state, x)
     held = int(new["pairs_held"])
-    assert (held <= 256) == (push == 0.0) and int(new["pairs_dropped"]) == 0
+    assert (held <= 384) == (push == 0.0) and int(new["pairs_dropped"]) == 0
     assert held == (1200 if push else int(jnp.sum(new["expert_tokens"])))
     want = _plain_routed(layer, params, x, state["bias"])
     assert float(jnp.max(jnp.abs(out - want))) < TOL * max(
@@ -537,7 +745,7 @@ def test_the_dropped_counter_sees_a_window_that_did_not_run(monkeypatch):
     """``pairs_dropped`` is held pairs less the rows that the windows which
     ran gave to the grouped products, not arithmetic that is 0 whatever
     runs: a layer whose choice of tier is broken (always the first window,
-    here by a ``lax.cond`` that takes its first branch) reads the 944 of
+    here by a ``lax.cond`` that takes its first branch) reads the 816 of
     1,200 pairs it left out, and its result is off."""
     from deeplearning4j_tpu.nn.conf import experts as module
     layer = _experts(held=2, offset=4, shared=0, total=32)
@@ -549,26 +757,45 @@ def test_the_dropped_counter_sees_a_window_that_did_not_run(monkeypatch):
                         lambda pred, first, second, *ops: first(*ops))
     out, new = layer.apply(params, state, x)
     assert int(new["pairs_held"]) == 1200
-    assert int(new["pairs_dropped"]) == 1200 - 256
+    assert int(new["pairs_dropped"]) == 1200 - 384
     want = _plain_routed(layer, params, x, state["bias"])
     assert float(jnp.max(jnp.abs(out - want))) > 100 * TOL
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
+def test_a_router_or_gate_the_layer_does_not_know_is_refused():
+    it = InputType.recurrent(12, 24)
+    with pytest.raises(ValueError, match="router_activation"):
+        RoutedExperts(router_activation="tanh").output_type(it)
+    with pytest.raises(ValueError, match="shared_gate"):
+        RoutedExperts(shared_gate=True, shared_size=0).output_type(it)
+    # the softmax scores of one token add up to one over ALL experts, and
+    # the weights of its chosen experts to one (no scale)
+    layer = _experts(held=2, offset=2, softmax=True)
+    params, state = layer.init(jax.random.key(0), it)
+    w, idx = layer.route(jax.random.normal(jax.random.key(1), (24, 12)),
+                         params["Wr"], state["bias"])
+    assert w.shape == idx.shape == (24, 2)
+    assert float(jnp.max(jnp.abs(jnp.sum(w, -1) - 1.0))) < 1e-6
+    assert int(jnp.max(idx)) > 3          # experts this share does not hold
+
+
+@pytest.mark.parametrize("softmax", [False, True], ids=["sigmoid", "softmax"])
+def test_the_shares_add_up_to_the_uncut_layer(softmax):
     """Four shares of two experts each (offsets 0, 2, 4, 6), the shared
-    expert counted once (on the first share), give what the layer that
-    holds all eight gives."""
-    whole = _experts(held=8)
+    expert (with its gate, where it has one) counted once (on the first
+    share), give what the layer that holds all eight gives."""
+    whole = _experts(held=8, softmax=softmax)
     it = InputType.recurrent(12, 24)
     params, state = whole.init(jax.random.key(0), it)
     x = jax.random.normal(jax.random.key(1), (2, 24, 12))
     want, _ = whole.apply(params, state, x)
     total = jnp.zeros_like(want)
     for offset in (0, 2, 4, 6):
-        share = _experts(held=2, offset=offset, shared=8 if offset == 0 else 0)
+        share = _experts(held=2, offset=offset, shared=8 if offset == 0 else 0,
+                         softmax=softmax)
         own = {k: (v[offset:offset + 2] if k in ("Wgate", "Wup", "Wdown")
                    else v) for k, v in params.items()
-               if share.shared_size or not k.startswith("S")}
+               if share.shared_size or not (k.startswith("S") or k == "Wsg")}
         part, _ = share.apply(own, share.init(jax.random.key(0), it)[1], x)
         total = total + part
     assert float(jnp.max(jnp.abs(total - want))) < TOL
@@ -577,6 +804,13 @@ def test_the_shares_add_up_to_the_uncut_layer():
 # ------------------------------------------------------- the framework's side
 LAYERS = [
     RMSNorm(eps=1e-6),
+    RMSNorm(eps=1e-6, zero_centered=True),
+    GatedDeltaNet(n_key_heads=2, n_value_heads=4, head_dim=8, chunk=32),
+    GatedAttention(n_heads=4, n_kv_heads=2, head_dim=16, rotary_dim=4,
+                   rope_theta=1e7, block=32),
+    RoutedExperts(n_experts=16, experts_held=4, top_k=3, expert_size=8,
+                  shared_size=8, router_activation="softmax",
+                  shared_gate=True),
     GatedFeedForward(ff_size=24, remat="full"),
     KimiDeltaAttention(n_heads=2, head_dim=8, low_rank=4, chunk=32),
     MultiHeadLatentAttention(n_heads=2, nope_dim=8, rope_dim=4, v_dim=8,
@@ -587,7 +821,8 @@ LAYERS = [
 ]
 
 
-@pytest.mark.parametrize("layer", LAYERS, ids=lambda l: type(l).__name__)
+@pytest.mark.parametrize("layer", LAYERS, ids=[
+    f"{i}-{type(l).__name__}" for i, l in enumerate(LAYERS)])
 def test_config_round_trip(layer):
     import json
     again = layer_from_dict(json.loads(json.dumps(layer_to_dict(layer))))
@@ -682,3 +917,59 @@ def test_the_counters_cost_a_turn_of_fit_no_device_program_and_no_sync():
     gauges = [n for n in obs.get_registry().names()
               if n.startswith("moe_expert_tokens_")]
     assert len(gauges) >= 4
+
+
+# ------------------------------------------------ the Qwen3-Next family's side
+def test_a_zero_centred_norm_scales_by_one_plus_its_weight():
+    it = InputType.recurrent(12, 5)
+    plain, centred = RMSNorm(eps=1e-6), RMSNorm(eps=1e-6, zero_centered=True)
+    (pp, _), (pc, _) = plain.init(None, it), centred.init(None, it)
+    assert set(pc) == {"w"} and float(jnp.max(jnp.abs(pc["w"]))) == 0.0
+    x = jax.random.normal(jax.random.key(0), (2, 5, 12))
+    w = 0.3 * jax.random.normal(jax.random.key(1), (12,))
+    got, _ = centred.apply({"w": w}, {}, x)
+    want, _ = plain.apply({"g": 1.0 + w}, {}, x)
+    assert float(jnp.max(jnp.abs(got - want))) < 1e-6
+    by_hand = x / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) * (1 + w)
+    assert float(jnp.max(jnp.abs(got - by_hand))) < 1e-6
+    # the same gradient for w as for g: Adam without decay takes one step
+    dw = jax.grad(lambda w: jnp.sum(jnp.sin(centred.apply({"w": w}, {}, x)[0])))(w)
+    dg = jax.grad(lambda g: jnp.sum(jnp.sin(plain.apply({"g": g}, {}, x)[0])))(
+        1.0 + w)
+    assert float(jnp.max(jnp.abs(dw - dg))) < 1e-6
+
+
+def _qwen_mln(t=None):
+    return (NeuralNetConfiguration.builder().seed(5)
+            .updater(Adam(learning_rate=3e-3)).list()
+            .layer(EmbeddingSequenceLayer(n_in=30, n_out=12))
+            .layer(RMSNorm(zero_centered=True))
+            .layer(GatedDeltaNet(n_key_heads=1, n_value_heads=2, head_dim=8,
+                                 chunk=16, remat="full"))
+            .layer(RMSNorm(zero_centered=True))
+            .layer(GatedAttention(n_heads=4, n_kv_heads=2, head_dim=8,
+                                  rotary_dim=4, block=16, remat="full"))
+            .layer(RoutedExperts(n_experts=8, experts_held=4, top_k=2,
+                                 expert_size=8, shared_size=8,
+                                 router_activation="softmax",
+                                 shared_gate=True))
+            .layer(TokenOutputLayer(n_out=30, time_block=16))
+            .set_input_type(InputType.recurrent(30, t)).build())
+
+
+def test_a_network_of_the_qwen_layers_validates_and_learns_a_copy_task():
+    issues = _qwen_mln(40).validate(eval_shape_check=True, batch=2,
+                                    labels_shape=(2, 40))
+    assert not [i for i in issues if i.severity == "error"], issues
+    net = MultiLayerNetwork(_qwen_mln()).init()
+    rng = np.random.default_rng(0)
+    ids = rng.integers(0, 30, (4, 41)).astype(np.int32)
+    ids[:, 1::2] = ids[:, 0:-1:2]            # every odd id repeats the last
+    ds = DataSet(ids[:, :-1], ids[:, 1:])
+    net.fit(ds)
+    first = net.score()
+    for _ in range(60):
+        net.fit(ds)
+    assert net.score() < 0.8 * first
+    experts = [s for s in net.state if "expert_tokens" in s]
+    assert len(experts) == 1 and int(experts[0]["pairs_dropped"]) == 0
