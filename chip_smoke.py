@@ -662,6 +662,29 @@ def phase_kernels(ctx: Ctx) -> dict:
                   grouped_attention, gargs,
                   shape=[1, h, hkv, steps, width], block=block)
 
+        # ---- kda_inputs: the delta-rule layers with their input path as
+        # kernels (and the scan on their heads-major operands) against the
+        # layers' jax.numpy lines, at the token cells' head counts and a
+        # length that is padded; gradients of every parameter and the input
+        from deeplearning4j_tpu.nn.conf.linear_attention import (
+            GatedDeltaNet, KimiDeltaAttention)
+        steps, width = (2048 + 40, 256) if chip else (s.attn_seq + 40, 12)
+        hk, hv = (16, 32) if chip else (1, 2)
+        for name, layer in (
+                ("kda_inputs", KimiDeltaAttention(
+                    n_heads=hv, head_dim=128, low_rank=16)),
+                ("kda_inputs_gated_delta_net", GatedDeltaNet(
+                    n_key_heads=hk, n_value_heads=hv, head_dim=128))):
+            params, state = layer.init(jax.random.key(ctx.seed + 5),
+                                       InputType.recurrent(width, steps))
+            params = jax.tree_util.tree_map(lambda a: a.astype(cdt), params)
+            x = jax.random.normal(jax.random.key(ctx.seed + 6),
+                                  (1, steps, width)).astype(cdt)
+            both_arms(name, "kda_inputs",
+                      lambda p, x, layer=layer, state=state: layer.apply(
+                          p, state, x)[0], (params, x),
+                      shape=[1, steps, width, hk, hv, 128])
+
     # ---- bn_act / bn_act_bwd: NOT in the default selection; run under an
     # explicit override where supported() says the rows fit
     z = jnp.asarray(rng.standard_normal((64, 7, 7, 512)), jnp.bfloat16)

@@ -39,6 +39,20 @@ plain ``jax.numpy`` / ``lax`` here, XLA writing the backward pass, for any
 shape and backend; and, on a TPU for heads of 128 in chunks of 64, the
 Pallas kernels of ``perf/pallas/kda.py``, forward and backward, which keep
 a chunk's terms in VMEM.
+
+The layers' input path (the short convolution, SiLU, the q/k head norms
+and KDA's decay, between the projections' products and the scan) has two
+executions in the same way (``take("kda_inputs", ...)``,
+``kernel.pallas_kda_inputs`` / ``kernel.xla_kda_inputs``, once a call of a
+layer): the ``jax.numpy`` lines of ``KimiDeltaAttention.apply`` and
+``GatedDeltaNet.apply`` (``causal_depthwise_conv``, ``_l2norm``) anywhere,
+every CPU run included, and the tests' reference; on a TPU, for heads of
+128 or 256, at most 4 taps and bfloat16 or float32, one kernel forward and
+one backward of ``perf/pallas/kda_inputs.py`` that read the products'
+(time, heads x K) outputs and hand the scan's kernels their (batch, heads,
+time, K) windows (``kda.kda_scan_heads_major``: no transpose of q, k, v, g
+in or of dq, dk, dv, dg out). Measured in the benchmark's two token cells:
+PERF.md §5-6, PR 31.
 """
 
 from __future__ import annotations
@@ -59,6 +73,7 @@ from deeplearning4j_tpu.nn.conf.normalization import rms_norm
 from deeplearning4j_tpu.nn.initializers import init_weights
 from deeplearning4j_tpu.perf import pallas as pk
 from deeplearning4j_tpu.perf.pallas import kda as kda_kernels
+from deeplearning4j_tpu.perf.pallas import kda_inputs
 
 
 def causal_depthwise_conv(x, w):
@@ -75,6 +90,34 @@ def causal_depthwise_conv(x, w):
 def _l2norm(x, eps: float = 1e-6):
     x32 = x.astype(jnp.float32)
     return x32 * lax.rsqrt(jnp.sum(jnp.square(x32), -1, keepdims=True) + eps)
+
+
+def _take_fused_inputs(x, spec, taps: int, chunk: int):
+    """Does the layer's input path run as the ``kda_inputs`` kernels (and
+    the scan behind them as ``kda_scan``'s, on heads-major operands)?
+    Counted once a call (``kernel.pallas_kda_inputs`` /
+    ``kernel.xla_kda_inputs``). Returns the length the kernels run at, a
+    multiple of the scan's chunk, or 0: the ``jax.numpy`` lines."""
+    bsz, t, _ = x.shape
+    padded = t + (-t) % chunk
+    if pk.take("kda_inputs", chunk == kda_kernels.CHUNK
+               and pk.enabled("kda_scan")
+               and kda_inputs.supported(x.dtype, bsz, padded, spec, taps)):
+        return padded
+    return 0
+
+
+def _pad_time(x, padded: int):
+    """Zero steps appended: they reach no step before them."""
+    t = x.shape[1]
+    return x if padded == t else jnp.pad(x, ((0, 0), (0, padded - t), (0, 0)))
+
+
+def _scan_heads_major(q, k, v, g, b, t: int):
+    """``chunked_kda`` for the operands ``kda_inputs`` wrote: (batch,
+    heads, padded time, K), b (batch, padded time, heads) float32."""
+    pk.take("kda_scan")
+    return kda_kernels.kda_scan_heads_major(q, k, v, g, b)[:, :t]
 
 
 def _decay_start(key_rates, key_steps, rates: int, steps: int, dtype):
@@ -325,20 +368,39 @@ class KimiDeltaAttention(BaseLayer):
         def heads(a):
             return a.reshape(bsz, t, h, dk)
 
+        f32 = jnp.float32
+        spec = kda_inputs.Spec(srcs=((0, 0, 0), (1, 1, 0), (2, 2, 0)),
+                               decay=(3, 0), key_heads=h, rep=1, head_dim=dk)
+        padded = _take_fused_inputs(x, spec, self.conv_size, self.chunk)
+        xp = _pad_time(x, padded or t)
         with jax.named_scope("kda.conv"):
-            q, k, v = (jax.nn.silu(causal_depthwise_conv(x @ params[w],
-                                                         params[c]))
-                       for w, c in (("Wq", "conv_q"), ("Wk", "conv_k"),
-                                    ("Wv", "conv_v")))
-            # normalised in float32, handed on in the compute type
-            q = (_l2norm(heads(q)) * (1.0 / math.sqrt(dk))).astype(x.dtype)
-            k = _l2norm(heads(k)).astype(x.dtype)
-            f = ((x @ params["Wf1"]) @ params["Wf2"]).astype(jnp.float32)
-            g = -jnp.exp(params["A_log"].astype(jnp.float32))[:, None] * heads(
-                jax.nn.softplus(f + params["dt_bias"].astype(jnp.float32)))
-            b = jax.nn.sigmoid((x @ params["Wb"]).astype(jnp.float32))
+            if padded:
+                # one kernel from the products to the scan's heads-major
+                # windows (perf/pallas/kda_inputs.py)
+                q, k, v, g = kda_inputs.kda_inputs(
+                    tuple(xp @ params[w] for w in ("Wq", "Wk", "Wv"))
+                    + ((xp @ params["Wf1"]) @ params["Wf2"],),
+                    tuple(params[c].astype(f32)
+                          for c in ("conv_q", "conv_k", "conv_v")),
+                    (jnp.repeat(-jnp.exp(params["A_log"].astype(f32)),
+                                dk)[None],
+                     params["dt_bias"].astype(f32)[None]), spec)
+            else:
+                q, k, v = (jax.nn.silu(causal_depthwise_conv(x @ params[w],
+                                                             params[c]))
+                           for w, c in (("Wq", "conv_q"), ("Wk", "conv_k"),
+                                        ("Wv", "conv_v")))
+                # normalised in float32, handed on in the compute type
+                q = (_l2norm(heads(q))
+                     * (1.0 / math.sqrt(dk))).astype(x.dtype)
+                k = _l2norm(heads(k)).astype(x.dtype)
+                f = ((x @ params["Wf1"]) @ params["Wf2"]).astype(f32)
+                g = -jnp.exp(params["A_log"].astype(f32))[:, None] * heads(
+                    jax.nn.softplus(f + params["dt_bias"].astype(f32)))
+            b = jax.nn.sigmoid((xp @ params["Wb"]).astype(f32))
         with jax.named_scope("kda.scan"):
-            o = chunked_kda(q, k, heads(v), g, b, chunk=self.chunk)
+            o = (_scan_heads_major(q, k, v, g, b, t) if padded
+                 else chunked_kda(q, k, heads(v), g, b, chunk=self.chunk))
         with jax.named_scope("kda.out_gate"):
             gate = jax.nn.sigmoid(
                 heads((x @ params["Wg1"]) @ params["Wg2"]).astype(jnp.float32))
@@ -435,27 +497,46 @@ class GatedDeltaNet(BaseLayer):
         bsz, t, _ = x.shape
         hk, hv, dh = self.n_key_heads, self.n_value_heads, self.head_dim
         f32 = jnp.float32
+        spec = kda_inputs.Spec(srcs=((0, 0, 0), (0, 0, hk), (0, 0, 2 * hk)),
+                               decay=None, key_heads=hk, rep=hv // hk,
+                               head_dim=dh)
+        padded = _take_fused_inputs(x, spec, self.conv_size, self.chunk)
+        xp = _pad_time(x, padded or t)
         with jax.named_scope("gdn.conv"):
-            qkvz = x @ params["Wqkvz"]
-            mixed = jax.nn.silu(causal_depthwise_conv(
-                qkvz[..., :(2 * hk + hv) * dh], params["conv"]))
-            z = qkvz[..., (2 * hk + hv) * dh:].reshape(bsz, t, hv, dh)
-            q = mixed[..., :hk * dh].reshape(bsz, t, hk, dh)
-            k = mixed[..., hk * dh:2 * hk * dh].reshape(bsz, t, hk, dh)
-            v = mixed[..., 2 * hk * dh:].reshape(bsz, t, hv, dh)
-            # normalised in float32, handed on in the compute type; a key
-            # head's q and k serve h_v / h_k value heads that lie together
-            q = (_l2norm(q) * (1.0 / math.sqrt(dh))).astype(x.dtype)
-            k = _l2norm(k).astype(x.dtype)
-            if hv != hk:
-                q, k = (jnp.repeat(a, hv // hk, axis=2) for a in (q, k))
-            ba = (x @ params["Wba"]).astype(f32)
+            qkvz = xp @ params["Wqkvz"]
+            if padded:
+                # the kernels KDA takes: q, k, v are three column ranges of
+                # one product, a q/k head is written to the value heads it
+                # serves (perf/pallas/kda_inputs.py)
+                q, k, v = kda_inputs.kda_inputs(
+                    (qkvz,), (params["conv"].astype(f32),), (), spec)
+                z = qkvz[:, :t, (2 * hk + hv) * dh:].reshape(bsz, t, hv, dh)
+            else:
+                mixed = jax.nn.silu(causal_depthwise_conv(
+                    qkvz[..., :(2 * hk + hv) * dh], params["conv"]))
+                z = qkvz[..., (2 * hk + hv) * dh:].reshape(bsz, t, hv, dh)
+                q = mixed[..., :hk * dh].reshape(bsz, t, hk, dh)
+                k = mixed[..., hk * dh:2 * hk * dh].reshape(bsz, t, hk, dh)
+                v = mixed[..., 2 * hk * dh:].reshape(bsz, t, hv, dh)
+                # normalised in float32, handed on in the compute type; a key
+                # head's q and k serve h_v / h_k value heads that lie together
+                q = (_l2norm(q) * (1.0 / math.sqrt(dh))).astype(x.dtype)
+                k = _l2norm(k).astype(x.dtype)
+                if hv != hk:
+                    q, k = (jnp.repeat(a, hv // hk, axis=2) for a in (q, k))
+            ba = (xp @ params["Wba"]).astype(f32)
             b = jax.nn.sigmoid(ba[..., :hv])
             g = -jnp.exp(params["A_log"].astype(f32)) * jax.nn.softplus(
                 ba[..., hv:] + params["dt_bias"].astype(f32))
         with jax.named_scope("gdn.scan"):
-            o = chunked_kda(q, k, v, jnp.broadcast_to(g[..., None], q.shape),
-                            b, chunk=self.chunk)
+            # one decay a head, spread over the head's channels
+            if padded:
+                o = _scan_heads_major(q, k, v, jnp.broadcast_to(
+                    jnp.swapaxes(g, 1, 2)[..., None], q.shape), b, t)
+            else:
+                o = chunked_kda(q, k, v,
+                                jnp.broadcast_to(g[..., None], q.shape), b,
+                                chunk=self.chunk)
         with jax.named_scope("gdn.out_gate"):
             o = rms_norm(o, params["o_norm"], self.eps) * jax.nn.silu(
                 z.astype(f32))

@@ -7,7 +7,7 @@ BN-train stats/normalize/residual traffic XLA refuses to fuse across
 costs ~4.7 extra full activation-set HBM crossings (tools/PROFILE_r5.md).
 This package holds the kernels that cross that line by hand — SURVEY
 L0/§7's replacement for libnd4j's C++ kernels exactly where XLA's fusion
-control runs out. Four families, each slotted behind a boundary the repo
+control runs out. Five families, each slotted behind a boundary the repo
 already parity-tests:
 
 - **bn** (:mod:`perf.pallas.bn`): fused BN-train forward/backward behind
@@ -23,6 +23,13 @@ already parity-tests:
   Attention behind ``chunked_kda`` (nn/conf/linear_attention.py), forward
   and backward behind one custom-VJP — a chunk's decayed scores and its
   triangular solve made and used in VMEM, the state carried in scratch.
+- **kda_inputs** (:mod:`perf.pallas.kda_inputs`): what the delta-rule
+  layers (``KimiDeltaAttention``, ``GatedDeltaNet``) do between their
+  projections' products and that scan — the causal depthwise convolution,
+  SiLU, the q/k head norms, KDA's decay — as one kernel forward and one
+  backward behind one custom-VJP, reading the products' (time, heads x K)
+  outputs and writing the scan's (batch, heads, time, K) windows (a q/k
+  head to every value head it serves).
 - **attention** (:mod:`perf.pallas.attention`): blocked causal attention
   with q/k heads and v heads of different widths behind
   ``blocked_causal_attention`` (nn/conf/attention.py), a forward and a
@@ -38,7 +45,8 @@ Selection contract (every kernel, no exceptions):
    automatically on a TPU backend (the families of
    :data:`TPU_AUTO_FAMILIES` only) — AND the call site's shape predicate
    (``bn.supported``, ``adc.pq_supported``, ``adc.int4_supported``,
-   ``kda.supported``, ``attention.supported``) says
+   ``kda.supported``, ``kda_inputs.supported``, ``attention.supported``)
+   says
    the kernel fits. Anywhere else the reference runs.
 2. Off-TPU, a force-enabled kernel runs in Pallas **interpret mode**
    (:func:`interpret` resolves true) — this is how CPU CI bitwise/
@@ -63,9 +71,10 @@ family, is kept as tests: tests/test_chip_compile.py compiles every
 auto-selected kernel for a described v5e at a main-path shape, and
 chip_smoke.py's ``kernels`` phase runs each against its reference on
 the chip. Measured speed: ``kda_scan`` (PERF.md §5-6, PR 27: the cell's
-scan 221 -> 103 ms a step) and ``blocked_attention`` (PR 29: the cell's
-latent attention, see PERF.md §6); the retrieval kernels against XLA at 1M
-rows are still unmeasured (ROADMAP Speed 3/6).
+scan 221 -> 103 ms a step), ``blocked_attention`` (PR 29: the cell's
+latent attention, see PERF.md §6) and ``kda_inputs`` (PR 31: both token
+cells' delta-rule layers, PERF.md §5-6); the retrieval kernels against XLA
+at 1M rows are still unmeasured (ROADMAP Speed 3/6).
 """
 
 from __future__ import annotations
@@ -97,6 +106,9 @@ FAMILIES: Dict[str, str] = {
                 "(nn/conf/linear_attention.py)",
     "blocked_attention": "blocked_causal_attention's tile pairs, forward "
                          "and backward (nn/conf/attention.py)",
+    "kda_inputs": "the delta-rule layers' input path, from the products to "
+                  "the scan's operands, forward and backward "
+                  "(nn/conf/linear_attention.py)",
 }
 
 # Families the automatic rule selects on a TPU backend: those the v5e
@@ -110,7 +122,7 @@ FAMILIES: Dict[str, str] = {
 #   does not lower (its flat sibling's jnp.take: "Shape mismatch in
 #   input, indices and output"); needs a DMA rework (ROADMAP Speed 6).
 TPU_AUTO_FAMILIES = frozenset({"adc_pq", "int4_dot", "kda_scan",
-                               "blocked_attention"})
+                               "blocked_attention", "kda_inputs"})
 # No shape of these compiles, so not even an explicit enable (a
 # TuningRecord's ``pallas_kernels=True`` is applied process-wide) selects
 # them outside interpret mode.

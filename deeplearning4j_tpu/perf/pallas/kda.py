@@ -59,7 +59,7 @@ from jax import lax
 
 from deeplearning4j_tpu.perf import pallas as _pk
 
-__all__ = ["supported", "kda_scan"]
+__all__ = ["supported", "kda_scan", "kda_scan_heads_major"]
 
 CHUNK, SUB = 64, 8
 _HI = lax.Precision.HIGHEST
@@ -516,13 +516,26 @@ def _specs(shape, hb: int, reverse: bool):
     return (bsz, h // hb, n), wide, col, state
 
 
-@functools.partial(jax.jit, static_argnames=("save", "exact", "interpret"))
-def _forward(q, k, v, g, b, save: bool, exact: bool, interpret: bool):
+def _windows(q, k, v, g, heads_major: bool):
+    """q, k, v, g as the kernels read them, (B, H, T, K), and that shape's
+    (B, T, H, K): arrays that arrive time-major are transposed here, by
+    XLA; arrays that arrive heads-major (``kda_inputs`` writes them so) are
+    taken as they are."""
+    if heads_major:
+        bsz, h, t, kd = q.shape
+        return [q, k, v, g], (bsz, t, h, kd)
+    return [jnp.swapaxes(a, 1, 2) for a in (q, k, v, g)], q.shape
+
+
+@functools.partial(jax.jit, static_argnames=("save", "exact", "interpret",
+                                             "heads_major"))
+def _forward(q, k, v, g, b, save: bool, exact: bool, interpret: bool,
+             heads_major: bool = False):
     from jax.experimental.pallas import tpu as pltpu
-    bsz, t, h, kd = q.shape
+    flat, shape = _windows(q, k, v, g, heads_major)
+    bsz, t, h, kd = shape
     hb = _heads_a_step(h)
-    grid, wide, col, state = _specs(q.shape, hb, reverse=False)
-    flat = [jnp.swapaxes(a, 1, 2) for a in (q, k, v, g)]
+    grid, wide, col, state = _specs(shape, hb, reverse=False)
     out_shape = [jax.ShapeDtypeStruct((bsz, h, t, kd), _F32)]
     out_specs = [wide]
     if save:
@@ -537,13 +550,15 @@ def _forward(q, k, v, g, b, save: bool, exact: bool, interpret: bool):
     return (o, outs[1]) if save else o
 
 
-@functools.partial(jax.jit, static_argnames=("exact", "interpret"))
-def _backward(q, k, v, g, b, states, do, exact: bool, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("exact", "interpret",
+                                             "heads_major"))
+def _backward(q, k, v, g, b, states, do, exact: bool, interpret: bool,
+              heads_major: bool = False):
     from jax.experimental.pallas import tpu as pltpu
-    bsz, t, h, kd = q.shape
+    flat, shape = _windows(q, k, v, g, heads_major)
+    bsz, t, h, kd = shape
     hb = _heads_a_step(h)
-    grid, wide, col, state = _specs(q.shape, hb, reverse=True)
-    flat = [jnp.swapaxes(a, 1, 2) for a in (q, k, v, g)]
+    grid, wide, col, state = _specs(shape, hb, reverse=True)
     like = jax.ShapeDtypeStruct((bsz, h, t, kd), q.dtype)
     out_shape = [like, like, like,
                  jax.ShapeDtypeStruct((bsz, h, t, kd), _F32),
@@ -554,25 +569,35 @@ def _backward(q, k, v, g, b, states, do, exact: bool, interpret: bool):
         out_shape, [pltpu.VMEM((hb, kd, kd), _F32)])(
             *flat, _by_head_group(b, hb), states,
             jnp.swapaxes(do.astype(_F32), 1, 2))
-    return (jnp.swapaxes(dq, 1, 2), jnp.swapaxes(dk, 1, 2),
-            jnp.swapaxes(dv, 1, 2), jnp.swapaxes(dg, 1, 2),
-            jnp.swapaxes(db, 1, 2).reshape(bsz, t, h))
+    if not heads_major:
+        dq, dk, dv, dg = (jnp.swapaxes(a, 1, 2) for a in (dq, dk, dv, dg))
+    return dq, dk, dv, dg, jnp.swapaxes(db, 1, 2).reshape(bsz, t, h)
 
 
-@jax.custom_vjp
-def kda_scan(q, k, v, g, b):
-    """``chunked_kda`` at chunk 64, block 8 for inputs ``supported`` takes,
-    ``time`` a multiple of 64: o (batch, time, heads, V) in float32."""
-    return _forward(q, k, v, g, b, False, *_trace_time_choices())
+def _scan(heads_major: bool, doc: str):
+    @jax.custom_vjp
+    def scan(q, k, v, g, b):
+        return _forward(q, k, v, g, b, False, *_trace_time_choices(),
+                        heads_major=heads_major)
+
+    def fwd(q, k, v, g, b):
+        o, states = _forward(q, k, v, g, b, True, *_trace_time_choices(),
+                             heads_major=heads_major)
+        return o, (q, k, v, g, b, states)
+
+    def bwd(res, do):
+        return _backward(*res, do, *_trace_time_choices(),
+                         heads_major=heads_major)
+
+    scan.defvjp(fwd, bwd)
+    scan.__doc__ = doc
+    return scan
 
 
-def _kda_scan_fwd(q, k, v, g, b):
-    o, states = _forward(q, k, v, g, b, True, *_trace_time_choices())
-    return o, (q, k, v, g, b, states)
-
-
-def _kda_scan_bwd(res, do):
-    return _backward(*res, do, *_trace_time_choices())
-
-
-kda_scan.defvjp(_kda_scan_fwd, _kda_scan_bwd)
+kda_scan = _scan(False, """``chunked_kda`` at chunk 64, block 8 for inputs
+``supported`` takes, ``time`` a multiple of 64: o (batch, time, heads, V) in
+float32.""")
+kda_scan_heads_major = _scan(True, """``kda_scan`` for q, k, v, g that arrive
+as the kernels read them, (batch, heads, time, K) (``kda_inputs`` writes them
+so), with b (batch, time, heads): the same o (batch, time, heads, V); dq, dk,
+dv, dg leave heads-major too. No transpose of the four runs, in or out.""")

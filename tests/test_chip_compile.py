@@ -219,6 +219,53 @@ def _blocked_attention_grouped(S):
     return fwd_bwd, (q, kv, kv), ("mla_attend_fwd", "mla_attend_bwd")
 
 
+def _inputs_case(S, dtype, gated_delta_net):
+    # the delta-rule layers' input path at the two token cells' shapes, one
+    # sequence of 8192 steps: KDA's four streams of 32 heads of 128 (q, k,
+    # v under their taps, the decay), or Gated DeltaNet's 16 + 16 + 32
+    # heads as three column ranges of one product under one taps array,
+    # each q/k head written to two value heads; forward and backward
+    from deeplearning4j_tpu.perf.pallas import kda_inputs
+    if gated_delta_net:
+        spec = kda_inputs.Spec(srcs=((0, 0, 0), (0, 0, 16), (0, 0, 32)),
+                               decay=None, key_heads=16, rep=2, head_dim=128)
+        args = ((S((1, 8192, 96 * 128), dtype),), (S((4, 64 * 128), F32),),
+                ())
+    else:
+        spec = kda_inputs.Spec(srcs=((0, 0, 0), (1, 1, 0), (2, 2, 0)),
+                               decay=(3, 0), key_heads=32, rep=1,
+                               head_dim=128)
+        args = ((S((1, 8192, 4096), dtype),) * 4, (S((4, 4096), F32),) * 3,
+                (S((1, 4096), F32),) * 2)
+    assert pk.take("kda_inputs", kda_inputs.supported(dtype, 1, 8192, spec,
+                                                      4))
+
+    def fwd_bwd(xs, ws, rows):
+        def total(xs, ws, rows):
+            return sum(jnp.sum(o.astype(F32))
+                       for o in kda_inputs.kda_inputs(xs, ws, rows, spec))
+        return (kda_inputs.kda_inputs(xs, ws, rows, spec),
+                jax.grad(total, argnums=(0, 1, 2))(xs, ws, rows))
+
+    return fwd_bwd, args, ("kda_inputs_fwd", "kda_inputs_bwd")
+
+
+def _kda_inputs(S):
+    return _inputs_case(S, BF16, False)
+
+
+def _kda_inputs_float32(S):
+    return _inputs_case(S, F32, False)
+
+
+def _gdn_inputs(S):
+    return _inputs_case(S, BF16, True)
+
+
+def _gdn_inputs_float32(S):
+    return _inputs_case(S, F32, True)
+
+
 def _bn_fwd(S):
     # ResNet50 batch 128, the one stage whose rows fit: 7x7
     z = S((128, 7, 7, 2048), BF16)
@@ -242,7 +289,9 @@ AUTO_CASES = [(_pq, "adc_pq"), (_int4_table, "int4_dot"),
               (_blocked_attention, "blocked_attention"),
               (_blocked_attention_float32, None),
               (_kda_scan_scalar_decay, None),
-              (_blocked_attention_grouped, None)]
+              (_blocked_attention_grouped, None),
+              (_kda_inputs, "kda_inputs"), (_kda_inputs_float32, None),
+              (_gdn_inputs, None), (_gdn_inputs_float32, None)]
 EXPLICIT_CASES = [_bn_fwd, _bn_bwd]
 
 

@@ -26,6 +26,7 @@ Covers the PR-16 acceptance bars, all on CPU via Pallas interpret mode
 """
 
 import json
+import math
 import os
 import re
 import subprocess
@@ -481,22 +482,30 @@ def test_kda_block_solves_are_solve_unit_lower_and_its_transpose(
     _close(kda._solve_upper(a_off, a_cols, dx), vjp(dx)[0], 5e-5)
 
 
-def _kda_layer_grads(layer, t, seed=0):
+def _kda_layer_grads(layer, t, seed=0, dtype=jnp.float32, batch=1):
     it = InputType.recurrent(12, t)
     params, state = layer.init(jax.random.key(seed), it)
-    x = jax.random.normal(jax.random.key(seed + 1), (1, t, 12))
+    params = jax.tree.map(lambda a: a.astype(dtype), params)
+    x = jax.random.normal(jax.random.key(seed + 1),
+                          (batch, t, 12)).astype(dtype)
 
     def loss(params, x):
-        return jnp.sum(jnp.sin(layer.apply(params, state, x)[0]))
+        out = layer.apply(params, state, x)[0]
+        return jnp.sum(jnp.sin(out.astype(jnp.float32)))
 
     return jax.value_and_grad(loss, argnums=(0, 1))(params, x)
 
 
-def _kda_counters():
+def _family_counters(family):
+    """(``kernel.xla_<family>``, ``kernel.pallas_<family>``) so far."""
     from deeplearning4j_tpu.perf.compile_watch import GLOBAL
     counters = GLOBAL.as_dict().get("counters", {})
-    return (counters.get("kernel.xla_kda_scan", 0),
-            counters.get("kernel.pallas_kda_scan", 0))
+    return (counters.get(f"kernel.xla_{family}", 0),
+            counters.get(f"kernel.pallas_{family}", 0))
+
+
+def _kda_counters():
+    return _family_counters("kda_scan")
 
 
 def test_kda_layer_is_untouched_where_the_kernels_do_not_apply():
@@ -771,4 +780,225 @@ def test_attention_kernels_lower_under_the_layers_attend_scope():
             name = l.split('op_name="')[1].split('"')[0]
             assert ("MultiHeadLatentAttention:mla1" in name
                     and "mla.attend" in name), name
+            assert way in name, name
+
+
+# ------------------------------------- the delta-rule layers' input path
+# perf/pallas/kda_inputs.py behind KimiDeltaAttention.apply and
+# GatedDeltaNet.apply: convolution, SiLU, head norms and decay as one kernel
+# forward and one backward that hand the scan heads-major operands. The
+# jax.numpy lines of the layers are the reference. Last in the file: these
+# are its heaviest cases, and the file's tail runs when the other files'
+# host-timing tests are over.
+from deeplearning4j_tpu.perf.pallas import kda_inputs  # noqa: E402
+
+_INPUT_LAYERS = {
+    "kda": la.KimiDeltaAttention(n_heads=2, head_dim=128, low_rank=8),
+    # Gated DeltaNet's one product and one taps array, each q/k head
+    # written to the two value heads it serves
+    "gdn": la.GatedDeltaNet(n_key_heads=2, n_value_heads=4, head_dim=128),
+}
+
+
+def _inputs_counters():
+    return _family_counters("kda_inputs")
+
+
+@pytest.mark.parametrize("t", [192, 150],
+                         ids=["three_tiles", "padded_150_to_192"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("kind", ["kda", "gdn"])
+def test_fused_input_path_is_the_layers_jnp_lines(kind, dtype, t):
+    """The layer with the ``kda_inputs`` kernels (and the scan on their
+    heads-major operands) against its ``jax.numpy`` lines: the loss and the
+    gradient of every parameter (the taps, ``A_log`` and ``dt_bias`` among
+    them) and of the input, over a length of three time tiles and one that
+    is padded; the counters say which path each call took. bfloat16 is
+    held to its own rounding: the kernels skip the ``jax.numpy`` form's
+    roundings after the convolution and the SiLU."""
+    layer = _INPUT_LAYERS[kind]
+    before = _inputs_counters()
+    with jax.default_matmul_precision("highest"):
+        with pk.override(enabled=False):
+            want = _kda_layer_grads(layer, t, 5, dtype)
+        assert _inputs_counters() == (before[0] + 1, before[1])
+        with pk.override(enabled=True, interpret=True):
+            got = _kda_layer_grads(layer, t, 5, dtype)
+        assert _inputs_counters() == (before[0] + 1, before[1] + 1)
+    names = [jax.tree_util.keystr(k)
+             for k, _ in jax.tree_util.tree_flatten_with_path(want)[0]]
+    assert any("conv" in n for n in names) and any("A_log" in n
+                                                   for n in names)
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    for name, a, b in zip(names, jax.tree.leaves(got), jax.tree.leaves(want)):
+        a, b = (np.asarray(x.astype(jnp.float32)) for x in (a, b))
+        # the loss is a sum of t x 12 sines that cancel: held to their count
+        scale = np.sqrt(12.0 * t) if a.ndim == 0 else np.linalg.norm(b)
+        assert np.linalg.norm(a - b) / max(scale, 1e-30) < tol, name
+
+
+def _kda_streams(t, seed=0, h=2, d=128, taps=4):
+    ks = jax.random.split(jax.random.key(seed), 10)
+    xs = tuple(jax.random.normal(ks[i], (1, t, h * d)) for i in range(4))
+    ws = tuple(0.5 * jax.random.normal(ks[4 + i], (taps, h * d))
+               for i in range(3))
+    rows = (-jnp.exp(jax.random.normal(ks[7], (1, h * d))),
+            jax.random.normal(ks[8], (1, h * d)))
+    spec = kda_inputs.Spec(srcs=((0, 0, 0), (1, 1, 0), (2, 2, 0)),
+                           decay=(3, 0), key_heads=h, rep=1, head_dim=d)
+    return xs, ws, rows, spec
+
+
+def _jnp_streams(xs, ws, rows, spec):
+    """The layers' own lines on the streams, heads-major."""
+    bsz, t, _ = xs[0].shape
+    h, d = spec.key_heads, spec.head_dim
+
+    def heads(a):
+        return jnp.swapaxes(a.reshape(bsz, t, h, d), 1, 2)
+
+    q, k, v = (jax.nn.silu(la.causal_depthwise_conv(x, w))
+               for x, w in zip(xs, ws))
+    g = rows[0][0] * jax.nn.softplus(xs[3] + rows[1][0])
+    return (heads(la._l2norm(q.reshape(bsz, t, h, d)).reshape(q.shape))
+            / math.sqrt(d),
+            heads(la._l2norm(k.reshape(bsz, t, h, d)).reshape(k.shape)),
+            heads(v), heads(g))
+
+
+@pytest.mark.parametrize("t,row", [(192, 126), (1024, 510)],
+                         ids=["three_tiles_of_64", "two_tiles_of_512"])
+def test_fused_inputs_halo_is_causal_and_tiles_meet_row_for_row(t, row):
+    """Three time tiles of one pass, and two of eight passes (four to a
+    loop body): every row agrees with the unfused form (the rows after a
+    tile's or a pass's edge take their earlier steps from the halo window
+    or the rows before), and a change at ``row`` moves no output before it
+    by a single bit, but moves it and the three rows after it, which lie
+    across a tile's edge."""
+    xs, ws, rows, spec = _kda_streams(t)
+    with pk.override(enabled=True, interpret=True):
+        assert kda_inputs.supported(jnp.float32, 1, t, spec, 4)
+        got = kda_inputs.kda_inputs(xs, ws, rows, spec)
+        bumped = tuple(x.at[0, row].add(1.0) for x in xs)
+        moved = kda_inputs.kda_inputs(bumped, ws, rows, spec)
+    for a, b in zip(got, _jnp_streams(xs, ws, rows, spec)):
+        gap = jnp.max(jnp.abs(a - b), axis=(0, 1, 3))        # a row
+        assert float(jnp.max(gap)) < 2e-5, np.argmax(np.asarray(gap))
+    for a, b in zip(got[:3], moved[:3]):
+        assert np.array_equal(np.asarray(a[:, :, :row]),
+                              np.asarray(b[:, :, :row]))
+        for r in range(row, row + 4):
+            assert not np.array_equal(np.asarray(a[:, :, r]),
+                                      np.asarray(b[:, :, r]))
+    # the decay has no convolution: only its own row moves
+    assert np.array_equal(np.asarray(got[3][:, :, row + 1:]),
+                          np.asarray(moved[3][:, :, row + 1:]))
+
+
+@pytest.mark.parametrize("kind", ["kda", "gdn"])
+def test_input_path_is_untouched_where_the_kernels_do_not_apply(kind):
+    """Heads of 64 (``supported`` refuses) with the families on, and any
+    shape on a CPU with nothing forced: the layer lowers to the text it
+    lowers to with the families off, and ``kernel.xla_kda_inputs`` counts
+    each call."""
+    small = {"kda": la.KimiDeltaAttention(n_heads=2, head_dim=64, low_rank=8),
+             "gdn": la.GatedDeltaNet(n_key_heads=2, n_value_heads=4,
+                                     head_dim=64)}[kind]
+
+    def text(layer):
+        params, state = layer.init(jax.random.key(0),
+                                   InputType.recurrent(12, 128))
+        x = jnp.zeros((1, 128, 12))
+        return jax.jit(lambda p, x: layer.apply(p, state, x)[0]).lower(
+            params, x).as_text()
+
+    before = _inputs_counters()
+    with pk.override(enabled=False):
+        off_small, off_wide = text(small), text(_INPUT_LAYERS[kind])
+    with pk.override(enabled=True, interpret=True):
+        assert text(small) == off_small
+    assert text(_INPUT_LAYERS[kind]) == off_wide        # nothing forced
+    assert _inputs_counters() == (before[0] + 4, before[1])
+    assert ("kda_inputs" in pk.FAMILIES
+            and "kda_inputs" in pk.TPU_AUTO_FAMILIES)
+
+
+def _zoo_step(name):
+    """The train step of a zoo model at the benchmark configuration's
+    rehearsal size with delta-rule heads of 128, lowered (nothing runs)."""
+    from deeplearning4j_tpu import models
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", name + ".json"),
+              encoding="utf-8") as f:
+        cfg = json.load(f)
+    cfg = {**cfg, **cfg["rehearse"]}
+    cfg = {**cfg, **cfg["published"]}
+    if name.startswith("kimi"):
+        cfg["linear_attn_config"] = {**cfg["linear_attn_config"],
+                                     "head_dim": 128}
+        zoo = models.KimiLinear(cfg, layers=5, experts_held=4,
+                                kda_low_rank=16, sequence_length=128,
+                                attention_block=64, loss_block=64)
+    else:
+        cfg.update(linear_key_head_dim=128, linear_value_head_dim=128)
+        zoo = models.Qwen3Next(cfg, layers=4, experts_held=4,
+                               sequence_length=128, attention_block=64,
+                               loss_block=64)
+    net = ComputationGraph(zoo.conf()).init()
+    ids = jnp.zeros((1, 128), jnp.int32)
+    return net._get_jitted("train").lower(
+        net.params, net.state, net.opt_state, net._rng, [ids], [ids], None,
+        None)
+
+
+@pytest.mark.parametrize("name,layers", [
+    ("kimi_linear_48b_a3b_ep32", 4), ("qwen3_next_80b_a3b_ep16", 3)])
+def test_zoo_steps_count_their_delta_rule_layers(name, layers):
+    """The two zoo models' steps at small depth (the cells' cuts: four KDA
+    layers of five, three Gated DeltaNet layers of four): every delta-rule
+    layer takes the input kernels and the scan's, none the ``jax.numpy``
+    form: 4 / 0 and 3 / 0, beside ``kernel.pallas_kda_scan`` 4 and 3."""
+    before, scan_before = _inputs_counters(), _kda_counters()
+    with pk.override(enabled=True, interpret=True):
+        _zoo_step(name)
+    assert _inputs_counters() == (before[0], before[1] + layers)
+    assert _kda_counters() == (scan_before[0], scan_before[1] + layers)
+
+
+@pytest.mark.parametrize("kind,cls,scope", [
+    ("kda", "KimiDeltaAttention", "kda.conv"),
+    ("gdn", "GatedDeltaNet", "gdn.conv")])
+def test_input_kernels_lower_under_the_layers_conv_scope(kind, cls, scope):
+    """``kda.device_ms_per_step`` / ``gdn.device_ms_per_step`` and the
+    scope table find operations by ``op_name``: what the input kernels
+    (here their interpreted bodies) lower to carries the layer's scope and
+    ``kda.conv`` / ``gdn.conv``, forward and backward."""
+    from deeplearning4j_tpu.nn.conf.graph import GraphBuilder
+    from deeplearning4j_tpu.nn.conf.recurrent import RnnOutputLayer
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+    conf = (GraphBuilder(NeuralNetConfiguration.builder().seed(3)
+                         .updater(Sgd(learning_rate=0.05)))
+            .add_inputs("in")
+            .add_layer("mix1", _INPUT_LAYERS[kind], "in")
+            .add_layer("out", RnnOutputLayer(n_out=3, loss="mcxent"), "mix1")
+            .set_outputs("out")
+            .set_input_types(InputType.recurrent(12, 64)).build())
+    with pk.override(enabled=True, interpret=True):
+        net = ComputationGraph(conf).init()
+        x = jnp.zeros((1, 64, 12), jnp.float32)
+        y = jnp.zeros((1, 64, 3), jnp.float32)
+        hlo = net._get_jitted("train").lower(
+            net.params, net.state, net.opt_state, net._rng, [x], [y], None,
+            None).compile().as_text()
+    ops = [l for l in hlo.splitlines() if "op_name=" in l
+           and not re.search(r"= f32\[\] (add|maximum)\(", l)]
+    for kernel, way in (("kda_inputs_fwd", "jvp("),
+                        ("kda_inputs_bwd", "transpose(")):
+        mine = [l for l in ops if kernel in l]
+        assert len(mine) > 20, (kernel, len(mine))
+        for l in mine:
+            name = l.split('op_name="')[1].split('"')[0]
+            assert f"{cls}:mix1" in name and scope in name, name
             assert way in name, name
