@@ -59,6 +59,9 @@ def describe_type(it) -> str:
     """Human-readable InputType, used in every both-shapes message."""
     if it is None:
         return "<unknown>"
+    if it.passes:
+        one = describe_type(dataclasses.replace(it, passes=None))
+        return f"{it.passes} passes of {one}"
     if it.kind == "cnn":
         return f"cnn(h={it.height}, w={it.width}, c={it.channels})"
     if it.kind == "cnn_flat":
@@ -83,7 +86,8 @@ def _layer_name(i: Optional[int], layer) -> str:
 # layers where n_out == 0 is legal (width inferred from the input)
 _N_OUT_OPTIONAL = ("TransformerEncoderBlock", "KimiDeltaAttention",
                    "GatedDeltaNet", "MultiHeadLatentAttention",
-                   "GatedAttention", "GatedFeedForward", "RoutedExperts")
+                   "GatedAttention", "RotaryAttention", "GatedFeedForward",
+                   "RoutedExperts")
 
 
 def _check_layer(layer, cur, name: str) -> List[ValidationIssue]:
@@ -580,6 +584,10 @@ def _shape_agrees(predicted, actual: Tuple[int, ...]) -> bool:
     """Does a traced activation shape match the InputType prediction?
     Batch dims are never compared (preprocessors legally fold time into
     batch); unknown sequence lengths (None) match anything."""
+    if predicted.passes:     # a LoopVertex's stack: passes, then one pass
+        return (len(actual) > 1 and actual[0] == predicted.passes
+                and _shape_agrees(dataclasses.replace(predicted, passes=None),
+                                  actual[1:]))
     if predicted.kind in ("ff", "cnn_flat"):
         return len(actual) == 2 and actual[-1] == predicted.flat_size()
     if predicted.kind in ("rnn", "cnn1d"):

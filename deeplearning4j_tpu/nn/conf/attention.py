@@ -13,12 +13,14 @@ parity tests so there is exactly ONE dense implementation). For sequences
 beyond one chip, the same math shards over the mesh via
 ``ring_self_attention`` / ``ulysses_self_attention`` (parallel/).
 
-Beside them the two attention layers of today's hybrid decoders, both over
+Beside them the three attention layers of today's decoders, all over
 ``blocked_causal_attention`` (tiles, own backward pass, Pallas kernels on a
 TPU): ``MultiHeadLatentAttention`` (as many k/v heads as query heads, q/k
-and v widths that differ, no rotation) and ``GatedAttention`` (fewer k/v
+and v widths that differ, no rotation), ``GatedAttention`` (fewer k/v
 heads than query heads, per-head q/k norms, a partial rotary embedding,
-an output gate).
+an output gate) and ``RotaryAttention`` (the plain decoder attention: q, k,
+v, o projections, a rotary embedding over the whole head width, equal or
+grouped heads, no norm and no gate).
 
 Param layout: nested ``{"q": {"W", "b"}, "k": ..., "v": ..., "o": ...}``
 (plus ``ff1``/``ff2`` in the encoder block) so the framework's bias-aware
@@ -633,6 +635,107 @@ class GatedAttention(BaseLayer):
         return out, state
 
 
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class RotaryAttention(BaseLayer):
+    """Causal multi-head attention with a rotary embedding and nothing
+    else, as the Llama line of decoders (and the looped ``ouro`` models)
+    run it. With h = ``n_heads``, h_kv = ``n_kv_heads`` (h when left at
+    0; h a multiple of it) and d = ``head_dim``:
+
+        q = W_q x  (h x d columns),  k = W_k x,  v = W_v x  (h_kv x d each)
+        all d widths of q and k rotated (``rotate_half_split``; a part of
+        the head: ``GatedAttention``)
+        each k / v head serves h / h_kv consecutive query heads
+        out = W_o softmax(q k^T / sqrt(d)) v,  no bias, no norm, no gate
+
+    The scores go through ``blocked_causal_attention``; k and v are
+    repeated over their group in front of it only where the group is more
+    than one head (``GatedAttention`` has the reasons). Which path a
+    compiled program took is counted at trace time (``bump_active``):
+    ``attention.rotary_blocked`` with more than one tile,
+    ``attention.rotary_single_tile`` otherwise, and beside them
+    ``kernel.pallas_blocked_attention`` / ``kernel.xla_blocked_attention``.
+    A features mask zeroes the output at masked steps (right-padded batches
+    are exact)."""
+
+    n_in: Optional[int] = None
+    n_out: int = 0              # model width; inferred from the input when 0
+    n_heads: int = 4
+    n_kv_heads: int = 0         # 0: as many as query heads
+    head_dim: int = 32
+    rope_theta: float = 10000.0
+    block: int = 512
+    weight_init: str = "xavier_fan_in"
+
+    supports_stateful = False
+
+    def input_kind(self):
+        return "rnn"
+
+    def is_recurrent(self):
+        return True
+
+    def regularizable(self):
+        return ("Wq", "Wk", "Wv", "Wo")
+
+    def _width(self, it: InputType) -> int:
+        return self.n_out or self.n_in or it.size
+
+    def output_type(self, it: InputType) -> InputType:
+        hkv = self.n_kv_heads or self.n_heads
+        if self.n_heads % hkv:
+            raise ValueError(f"{self.n_heads} query heads are no multiple "
+                             f"of {hkv} key/value heads")
+        if self.head_dim % 2:
+            raise ValueError(f"head_dim {self.head_dim} has to be even: the "
+                             "rotation pairs its halves")
+        return InputType.recurrent(self._width(it), it.timeseries_length)
+
+    def init(self, rng, it: InputType, dtype=jnp.float32):
+        d = self.n_in or it.size
+        h, dh = self.n_heads, self.head_dim
+        hkv = self.n_kv_heads or h
+        ks = jax.random.split(rng, 4)
+
+        def dense(key, n_in, n_out):
+            return init_weights(key, (n_in, n_out), n_in, n_out,
+                                self.weight_init, self.dist, dtype)
+
+        return {
+            "Wq": dense(ks[0], d, h * dh),
+            "Wk": dense(ks[1], d, hkv * dh),
+            "Wv": dense(ks[2], d, hkv * dh),
+            "Wo": dense(ks[3], h * dh, self._width(it)),
+        }, {}
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        from deeplearning4j_tpu.perf.compile_watch import bump_active
+
+        x = dropout_input(x, self.dropout, train, rng)
+        bsz, t, _ = x.shape
+        h, dh = self.n_heads, self.head_dim
+        hkv = self.n_kv_heads or h
+        q = (x @ params["Wq"]).reshape(bsz, t, h, dh)
+        k = (x @ params["Wk"]).reshape(bsz, t, hkv, dh)
+        v = (x @ params["Wv"]).reshape(bsz, t, hkv, dh)
+        with jax.named_scope("rattn.rope"):
+            positions = jnp.arange(t)
+            q, k = (rotate_half_split(a, positions, dh, self.rope_theta)
+                    for a in (q, k))
+        bump_active("attention.rotary_blocked" if t > self.block
+                    else "attention.rotary_single_tile")
+        with jax.named_scope("rattn.attend"):
+            q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
+            if h != hkv:
+                k, v = (jnp.repeat(a, h // hkv, axis=1) for a in (k, v))
+            o = blocked_causal_attention(q, k, v, self.block)
+        out = o.transpose(0, 2, 1, 3).reshape(bsz, t, h * dh) @ params["Wo"]
+        if mask is not None:             # masked steps emit zeros
+            out = out * mask[..., None].astype(out.dtype)
+        return out, state
+
+
 __all__ = ["SelfAttentionLayer", "TransformerEncoderBlock",
-           "MultiHeadLatentAttention", "GatedAttention",
+           "MultiHeadLatentAttention", "GatedAttention", "RotaryAttention",
            "blocked_causal_attention", "rotate_half_split"]

@@ -5,7 +5,9 @@ Parity surface: reference ``nn/conf/ComputationGraphConfiguration.java``
 ``nn/graph/vertex/impl/`` (14 classes + rnn/): MergeVertex,
 ElementWiseVertex, StackVertex, UnstackVertex, SubsetVertex, ReshapeVertex,
 ScaleVertex, ShiftVertex, L2NormalizeVertex, L2Vertex, PreprocessorVertex,
-LastTimeStepVertex, DuplicateToTimeSeriesVertex.
+LastTimeStepVertex, DuplicateToTimeSeriesVertex. Not in the reference:
+``LoopVertex``, a sub-graph run several times over one set of weights (a
+layer to the networks: it owns parameters).
 
 TPU-native: a vertex is a pure function of its input activations; the whole
 DAG is traced in topological order into ONE XLA program (the reference's
@@ -19,10 +21,12 @@ import dataclasses
 import json
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.conf.inputs import InputType
-from deeplearning4j_tpu.nn.conf.layers import Layer, layer_from_dict, layer_to_dict
+from deeplearning4j_tpu.nn.conf.layers import (Layer, layer_from_dict,
+                                               layer_to_dict, register_layer)
 from deeplearning4j_tpu.optimize.updaters import Updater, Sgd
 
 _VERTEX_REGISTRY = {}
@@ -426,8 +430,8 @@ class ComputationGraphConfiguration:
         return fuse(self)
 
     # ---- serde ----
-    def to_json(self) -> str:
-        d = {
+    def to_dict(self) -> dict:
+        return {
             "network_inputs": list(self.network_inputs),
             "network_outputs": list(self.network_outputs),
             "input_types": [t.to_dict() for t in self.input_types],
@@ -445,12 +449,17 @@ class ComputationGraphConfiguration:
                 for name, (obj, inputs) in self.vertices.items()
             },
         }
-        return json.dumps(d, indent=2)
+
+    def to_json(self) -> str:
+        return json.dumps(self.to_dict(), indent=2)
 
     @staticmethod
     def from_json(s: str) -> "ComputationGraphConfiguration":
+        return ComputationGraphConfiguration.from_dict(json.loads(s))
+
+    @staticmethod
+    def from_dict(d: dict) -> "ComputationGraphConfiguration":
         from deeplearning4j_tpu.nn.conf.preprocessors import preprocessor_from_dict
-        d = json.loads(s)
         vertices = {}
         for name, vd in d["vertices"].items():
             node = vd["node"]
@@ -473,6 +482,151 @@ class ComputationGraphConfiguration:
             tbptt_fwd_length=d.get("tbptt_fwd_length", 20),
             tbptt_back_length=d.get("tbptt_back_length", 20),
         )
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class LoopVertex(Layer):
+    """A sub-graph run ``steps`` times over ONE set of weights: pass r
+    reads what pass r - 1 wrote (``h_r = body(h_{r-1})``, ``h_0`` the
+    vertex's input) and the vertex hands on every pass's output, stacked
+    on a new leading axis (``steps``, batch, ...; ``InputType.passes``), or
+    with ``stacked=False`` the last pass's alone. The depth-recurrent
+    ("looped", "universal") transformers are this around their block of
+    layers; ``ExitWeightedTokenOutputLayer`` scores the stack.
+
+    ``body`` is a whole ``ComputationGraphConfiguration`` with one input
+    and one output (build it with ``GraphBuilder`` and
+    ``set_input_types``; its seed, dtype and updater are not read: the
+    network's are). The body's parameters exist once, nested under the
+    vertex (``params[vertex][body vertex][leaf]``), and so do their
+    optimizer state, checkpoint entry and count; the gradient autodiff
+    hands the optimizer is the sum over the passes. A body layer is a
+    layer like any other (``apply_layer``: its ``<LayerClass>:<name>``
+    scope, its ``remat`` knob) and may be a ``LoopVertex`` itself: with
+    ``steps=1, stacked=False, remat="full"`` that is a sub-graph
+    rematerialised as one unit. The engine updates by top-level vertex:
+    the body layers that name an updater have to name the same one, which
+    is then the vertex's, and l1 / l2, constraints and gradient
+    normalisation inside a body are refused.
+
+    More than one pass runs as one ``lax.scan`` over the body, so a step
+    program holds the body once however many passes there are. Dropout and
+    weight noise draw anew every pass. Counted at trace time
+    (``bump_active``) for more than one pass: ``loop.scanned``; scope
+    ``loop.body``."""
+
+    body: Optional[ComputationGraphConfiguration] = None
+    steps: int = 1
+    stacked: bool = True
+
+    def __post_init__(self):
+        if isinstance(self.body, dict):      # from JSON
+            object.__setattr__(
+                self, "body", ComputationGraphConfiguration.from_dict(self.body))
+
+    def input_kind(self):
+        return "any"
+
+    def _wiring(self):
+        """(order, wired vertices, preprocessors, input types) of the
+        body, as ``ComputationGraph`` keeps them for a whole graph."""
+        types, pres, _ = self.body._infer()
+        return (self.body.topological_order(), self.body.wired_vertices(),
+                pres, types)
+
+    def _body_layers(self):
+        return [(n, obj) for n, (obj, _) in self.body.vertices.items()
+                if isinstance(obj, Layer)]
+
+    @property
+    def updater(self):
+        """The one updater the body's layers name (a builder's default
+        lands on every layer), or None: the network's."""
+        named = {repr(u): u for _, obj in self._body_layers()
+                 if (u := getattr(obj, "updater", None)) is not None}
+        if len(named) > 1:
+            raise ValueError("the layers of a loop's body name different "
+                             f"updaters: {sorted(named)}")
+        return next(iter(named.values()), None)
+
+    def output_type(self, it: InputType) -> InputType:
+        body = self.body
+        if body is None or self.steps < 1:
+            raise ValueError("a LoopVertex needs a body and steps >= 1")
+        if len(body.network_inputs) != 1 or len(body.network_outputs) != 1 \
+                or len(body.input_types) != 1:
+            raise ValueError("a loop's body has one typed input and one "
+                             "output")
+        inner = body.input_types[0]
+        if (inner.kind, inner.flat_size()) != (it.kind, it.flat_size()):
+            raise ValueError(f"the body is typed for {inner}, the vertex is "
+                             f"given {it}")
+        for name, obj in self._body_layers():
+            if obj.is_output_layer():
+                raise ValueError(f"body vertex '{name}' is an output layer")
+            unbuilt = [k for k in ("l1", "l2", "l1_bias", "l2_bias",
+                                   "constraints", "gradient_normalization")
+                       if getattr(obj, k, None)]
+            if unbuilt:
+                raise ValueError(f"body vertex '{name}' sets {unbuilt}: not "
+                                 "built inside a loop's body")
+        self.updater                      # raises where they disagree
+        out = body.vertex_output_types()[body.network_outputs[0]]
+        if self.steps > 1 and (out.kind, out.flat_size()) != (
+                inner.kind, inner.flat_size()):
+            raise ValueError(f"a pass writes {out} where the next reads "
+                             f"{inner}")
+        return (dataclasses.replace(out, passes=self.steps) if self.stacked
+                else out)
+
+    def init(self, rng, it: InputType, dtype=jnp.float32):
+        order, wired, _, types = self._wiring()
+        params, state = {}, {}
+        for name in order:
+            obj = wired[name][0]
+            if isinstance(obj, Layer):
+                rng, k = jax.random.split(rng)
+                p, st = obj.init(k, types[name][0], dtype)
+                # a body vertex without leaves has no entry
+                if p:
+                    params[name] = p
+                if st:
+                    state[name] = st
+        return params, state
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        from deeplearning4j_tpu.nn.graph import run_vertices
+        from deeplearning4j_tpu.perf.compile_watch import bump_active
+
+        order, wired, vpre, types = self._wiring()
+        source, sink = self.body.network_inputs[0], self.body.network_outputs[0]
+        # the walk reads an entry a vertex; ``init`` keeps none for a body
+        # vertex without leaves
+        params = {name: params.get(name, {}) for name in order}
+
+        def one_pass(h, st, r):
+            acts, mask_of = {source: h}, {source: mask}
+            key = None if rng is None else jax.random.fold_in(rng, r)
+            _, new, _ = run_vertices(
+                order, wired, vpre, types, params,
+                {name: st.get(name, {}) for name in order}, acts, mask_of,
+                train, key)
+            return acts[sink], {name: new[name] for name in st}
+
+        if self.steps == 1:
+            h, st = one_pass(x, state, 0)
+            return (h[None] if self.stacked else h), st
+        with jax.named_scope("loop.body"):
+            bump_active("loop.scanned")
+
+            def step(carry, r):
+                h, st = one_pass(*carry, r)
+                return (h, st), (h if self.stacked else None)
+
+            (h, st), outs = jax.lax.scan(step, (x, state),
+                                         jnp.arange(self.steps))
+            return (outs if self.stacked else h), st
 
 
 class GraphBuilder:

@@ -25,6 +25,9 @@ class InputType:
     width: int = 0
     channels: int = 0
     timeseries_length: Optional[int] = None
+    # a ``LoopVertex``'s stacked output: this many passes on a new leading
+    # axis, in front of the batch; the other fields describe one pass
+    passes: Optional[int] = None
 
     # ---- factories (InputType.feedForward etc. in the reference) ----
     @staticmethod
@@ -61,6 +64,9 @@ class InputType:
 
     def example_shape(self, batch: int = 1) -> Tuple[int, ...]:
         """Concrete array shape for one batch of this input type."""
+        if self.passes:
+            one = dataclasses.replace(self, passes=None)
+            return (self.passes,) + one.example_shape(batch)
         if self.kind in ("ff", "cnn_flat"):
             return (batch, self.flat_size())
         if self.kind == "rnn":

@@ -358,6 +358,55 @@ class TokenOutputLayer(RnnOutputLayer):
 
 @register_layer
 @dataclasses.dataclass(frozen=True)
+class ExitWeightedTokenOutputLayer(TokenOutputLayer):
+    """The output layer of a looped model that may leave after any pass:
+    its input is a ``LoopVertex``'s stacked output (passes, batch, time,
+    n_in), every pass is scored by the one head ``W`` and gated by
+    ``sigmoid(x_r Wg + bg)``, and the training loss is the expectation of
+    the passes' cross-entropies under the gates' exit distribution less
+    ``entropy_weight`` times that distribution's entropy
+    (``lossfunctions.blocked_exit_weighted_mcxent``: one pass's block of
+    ``time_block`` steps of logits at a time). INTEGER labels (batch,
+    time), as ``TokenOutputLayer``. ``apply`` / ``output`` give the LAST
+    pass's softmax: leaving early at inference is not built."""
+
+    entropy_weight: float = 0.0
+
+    def output_type(self, it: InputType) -> InputType:
+        if not it.passes:
+            raise ValueError("expects a LoopVertex's stacked passes, the "
+                             "input brings one state")
+        return InputType.recurrent(self.n_out, it.timeseries_length)
+
+    def init(self, rng, it: InputType, dtype=jnp.float32):
+        k_head, k_gate = jax.random.split(rng)
+        params, state = super().init(k_head, it, dtype)
+        n_in = self.n_in or it.size
+        params["Wg"] = init_weights(k_gate, (n_in, 1), n_in, 1,
+                                    self.weight_init, self.dist, dtype)
+        params["bg"] = jnp.zeros((1,), dtype)
+        return params, state
+
+    def pre_output(self, params, x):
+        return {**super().pre_output(params, x), "Wg": params["Wg"],
+                "bg": params["bg"]}
+
+    def _logits(self, preout):
+        return super()._logits({**preout, "x": preout["x"][-1]})
+
+    def compute_score(self, labels, preout, mask=None):
+        if not self._blocked():
+            raise ValueError("the exit-weighted loss is sparse_mcxent over a "
+                             "softmax without class weights")
+        from deeplearning4j_tpu.nn.lossfunctions import (
+            blocked_exit_weighted_mcxent)
+        return blocked_exit_weighted_mcxent(
+            preout["x"], preout["W"], preout.get("b"), preout["Wg"],
+            preout["bg"], labels, mask, self.time_block, self.entropy_weight)
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
 class EmbeddingLayer(BaseLayer):
     """Index -> vector lookup (reference nn/conf/layers/EmbeddingLayer.java +
     nn/layers/feedforward/embedding/EmbeddingLayer.java): input is a column of

@@ -34,6 +34,79 @@ def _arrays(items):
             [None if a is None else jnp.asarray(a) for a in items])
 
 
+def run_vertices(order, vertices, vpre, input_types, params, state, acts,
+                 mask_of, train: bool, rng, carries=None):
+    """Walk wired vertices in ``order``: ``acts`` and ``mask_of`` hold the
+    inputs' activations and masks on entry and every vertex's on return.
+    ``params`` / ``state`` hold an entry for every vertex. Returns (output
+    layers' preouts, new state, new carries). The whole graph's forward pass
+    and a ``LoopVertex``'s body both run here, so a layer is applied in one
+    place (``apply_layer``: its ``<LayerClass>:<name>`` scope, its ``remat``
+    knob)."""
+    new_state = {}
+    new_carries = {}
+    preouts = {}
+    for name in order:
+        obj, in_names = vertices[name]
+        xs = [acts[i] for i in in_names]
+        in_mask = next((mask_of[i] for i in in_names if mask_of[i] is not None), None)
+        k = None
+        if rng is not None:
+            rng, k = jax.random.split(rng)
+        if isinstance(obj, Layer):
+            if name in vpre:
+                xs = list(xs)
+                xs[0], in_mask = vpre[name].apply(xs[0], in_mask)
+            p_v = noisy_params(obj, params[name], k, train)
+            if obj.is_output_layer():
+                x_in = dropout_input(xs[0], obj.dropout, train, k)
+                z = obj.pre_output(p_v, x_in)
+                # loss math in f32 (z may be a pytree: CenterLoss/YOLO)
+                z = jax.tree_util.tree_map(_f32, z)
+                preouts[name] = z
+                out = obj.output_activations(z)
+                new_state[name] = state[name]
+            elif (carries is not None and hasattr(obj, "apply_seq")
+                  and getattr(obj, "supports_stateful", True)):
+                x_in = dropout_input(xs[0], obj.dropout, train, k)
+                out, nc = obj.apply_seq(p_v, carries[name], x_in,
+                                        train=train, rng=None,
+                                        mask=in_mask)
+                new_carries[name] = nc
+                new_state[name] = state[name]
+            else:
+                # fused conv→BN→act blocks with residual=True take the
+                # residual-add operand as a second vertex input
+                extra = ({"res": xs[1]}
+                         if getattr(obj, "residual", False) and len(xs) > 1
+                         else None)
+                # apply_layer lowers through jax.checkpoint when the
+                # layer's remat= knob is set (perf/fusion.py policies)
+                out, st = apply_layer(obj, p_v, state[name], xs[0],
+                                      train=train, rng=k, mask=in_mask,
+                                      name=name, extra=extra)
+                new_state[name] = st
+            out_kind = obj.output_type(input_types[name][0]).kind
+            mask_of[name] = in_mask if out_kind in ("rnn", "cnn1d") else None
+        else:
+            if isinstance(obj, LastTimeStepVertex):
+                m = in_mask
+                if obj.mask_input is not None:
+                    m = mask_of.get(obj.mask_input)
+                out = obj.apply(*xs, mask=m)
+                mask_of[name] = None
+            elif isinstance(obj, DuplicateToTimeSeriesVertex):
+                t = acts[obj.reference_input].shape[1]
+                out = obj.apply(*xs, time_steps=t)
+                mask_of[name] = mask_of.get(obj.reference_input)
+            else:
+                out = obj.apply(*xs)
+                mask_of[name] = in_mask
+            new_state[name] = state[name]
+        acts[name] = out
+    return preouts, new_state, new_carries
+
+
 class ComputationGraph(Network):
     """The DAG's forward pass, staging and programs over ``nn/engine.py``'s
     ``Network``, which holds the steps and the fit path."""
@@ -134,67 +207,9 @@ class ComputationGraph(Network):
             acts[name] = x.astype(cdt) if (cdt != jnp.float32 and
                                            jnp.issubdtype(x.dtype, jnp.floating)) else x
             mask_of[name] = None if masks is None else masks[i]
-        new_state = {}
-        new_carries = {}
-        preouts = {}
-        for name in self.order:
-            obj, in_names = self.vertices[name]
-            xs = [acts[i] for i in in_names]
-            in_mask = next((mask_of[i] for i in in_names if mask_of[i] is not None), None)
-            k = None
-            if rng is not None:
-                rng, k = jax.random.split(rng)
-            if isinstance(obj, Layer):
-                if name in self._vpre:
-                    xs = list(xs)
-                    xs[0], in_mask = self._vpre[name].apply(xs[0], in_mask)
-                p_v = noisy_params(obj, params[name], k, train)
-                if obj.is_output_layer():
-                    x_in = dropout_input(xs[0], obj.dropout, train, k)
-                    z = obj.pre_output(p_v, x_in)
-                    # loss math in f32 (z may be a pytree: CenterLoss/YOLO)
-                    z = jax.tree_util.tree_map(_f32, z)
-                    preouts[name] = z
-                    out = obj.output_activations(z)
-                    new_state[name] = state[name]
-                elif (carries is not None and hasattr(obj, "apply_seq")
-                      and getattr(obj, "supports_stateful", True)):
-                    x_in = dropout_input(xs[0], obj.dropout, train, k)
-                    out, nc = obj.apply_seq(p_v, carries[name], x_in,
-                                            train=train, rng=None,
-                                            mask=in_mask)
-                    new_carries[name] = nc
-                    new_state[name] = state[name]
-                else:
-                    # fused conv→BN→act blocks with residual=True take the
-                    # residual-add operand as a second vertex input
-                    extra = ({"res": xs[1]}
-                             if getattr(obj, "residual", False) and len(xs) > 1
-                             else None)
-                    # apply_layer lowers through jax.checkpoint when the
-                    # layer's remat= knob is set (perf/fusion.py policies)
-                    out, st = apply_layer(obj, p_v, state[name], xs[0],
-                                          train=train, rng=k, mask=in_mask,
-                                          name=name, extra=extra)
-                    new_state[name] = st
-                out_kind = obj.output_type(self.vertex_input_types[name][0]).kind
-                mask_of[name] = in_mask if out_kind in ("rnn", "cnn1d") else None
-            else:
-                if isinstance(obj, LastTimeStepVertex):
-                    m = in_mask
-                    if obj.mask_input is not None:
-                        m = mask_of.get(obj.mask_input)
-                    out = obj.apply(*xs, mask=m)
-                    mask_of[name] = None
-                elif isinstance(obj, DuplicateToTimeSeriesVertex):
-                    t = acts[obj.reference_input].shape[1]
-                    out = obj.apply(*xs, time_steps=t)
-                    mask_of[name] = mask_of.get(obj.reference_input)
-                else:
-                    out = obj.apply(*xs)
-                    mask_of[name] = in_mask
-                new_state[name] = state[name]
-            acts[name] = out
+        preouts, new_state, new_carries = run_vertices(
+            self.order, self.vertices, self._vpre, self.vertex_input_types,
+            params, state, acts, mask_of, train, rng, carries)
         if carries is not None:
             for n in carries:
                 new_carries.setdefault(n, carries[n])

@@ -128,6 +128,85 @@ def blocked_sparse_mcxent(x, w, b, ids, mask=None, block: int = 1024):
     return total / jnp.maximum(jnp.sum(m), 1.0)
 
 
+def exit_distribution(gate_logits):
+    """The exit distribution of a looped model over its R passes from the
+    gates' logits ``gate_logits`` (R, ...), in log space: with
+    ``lambda_r = sigmoid(gate_logits[r])``,
+    ``p_r = lambda_r prod_{j<r} (1 - lambda_j)`` for r < R and
+    ``p_R = prod_{j<R} (1 - lambda_j)`` (the last pass takes what is left,
+    so its own gate is not read and the p_r sum to one). Returns
+    ``log p`` (R, ...), float32."""
+    g = gate_logits.astype(jnp.float32)
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-g), axis=0)        # sum_{j<=r}
+    before = jnp.concatenate([jnp.zeros_like(stay[:1]), stay[:-1]], 0)
+    return jnp.concatenate([jax.nn.log_sigmoid(g[:-1]) + before[:-1],
+                            before[-1:]], 0)
+
+
+def blocked_exit_weighted_mcxent(x, w, b, wg, bg, ids, mask=None,
+                                 block: int = 1024,
+                                 entropy_weight: float = 0.0):
+    """The training loss of a looped model with an exit gate after every
+    pass, over ``blocked_sparse_mcxent``'s blocks: ``x`` (R, batch, time,
+    n_in) holds the R passes' states, every pass is scored by the one head
+    ``w`` (n_in, classes) (``b`` None or (classes,)) and gated by
+    ``sigmoid(x_r @ wg + bg)`` (``wg`` (n_in, 1), ``bg`` (1,)), and a
+    token's loss is
+
+        sum_r p_r CE(x_r w + b, id) - entropy_weight * H(p),
+        H(p) = -sum_r p_r log p_r,   p = ``exit_distribution``
+
+    One (pass, time block) pair's float32 logits are alive at a time,
+    forward and backward (each pair under ``jax.checkpoint``); the gates,
+    the distribution and the mixing are whole-sequence arrays of R numbers
+    a token. ``ids`` (batch, time) integers, ``mask`` None or (batch,
+    time). Returns the mean over unmasked steps."""
+    passes, bsz, t, _ = x.shape
+    ids = ids.astype(jnp.int32)
+    if ids.ndim == 3:
+        ids = ids[..., 0]
+    m = (jnp.ones((bsz, t), jnp.float32) if mask is None
+         else mask.astype(jnp.float32))
+    with jax.named_scope("loop.exit_gate"):
+        # an elementwise product and a sum: float32 whatever the backend's
+        # default matmul precision is
+        logp = exit_distribution(
+            jnp.sum(x.astype(jnp.float32) * wg[:, 0].astype(jnp.float32),
+                    -1) + bg)
+        p = jnp.exp(logp)
+    block = min(block, t)
+    pad = (-t) % block
+    xp, idp = x, ids
+    if pad:
+        xp = jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        idp = jnp.pad(ids, ((0, 0), (0, pad)))
+    n = (t + pad) // block
+
+    @jax.checkpoint
+    def one(xb, ib):
+        z = (xb @ w).astype(jnp.float32)
+        if b is not None:
+            z = z + b
+        lse = jax.nn.logsumexp(z, axis=-1)
+        return lse - jnp.take_along_axis(z, ib[..., None], -1)[..., 0]
+
+    with jax.named_scope("loop.exit_head"):
+        # (pass, block) pairs, one after another
+        xs = jnp.moveaxis(xp.reshape(passes, bsz, n, block, -1), 2, 1)
+        ib = jnp.moveaxis(idp.reshape(bsz, n, block), 1, 0)
+        _, ce = jax.lax.scan(
+            lambda _, pair: (None, one(*pair)), None,
+            (xs.reshape((passes * n, bsz, block) + xs.shape[4:]),
+             jnp.tile(ib, (passes, 1, 1))))
+        ce = jnp.moveaxis(ce.reshape(passes, n, bsz, block), 1, 2) \
+            .reshape(passes, bsz, n * block)[..., :t]
+    with jax.named_scope("loss.exit_weighted"):
+        token = jnp.sum(p * ce, 0)
+        if entropy_weight:
+            token = token + entropy_weight * jnp.sum(p * logp, 0)
+        return jnp.sum(token * m) / jnp.maximum(jnp.sum(m), 1.0)
+
+
 def _score_nll(labels, preout, activation, weights):
     return _score_mcxent(labels, preout, activation, weights)
 

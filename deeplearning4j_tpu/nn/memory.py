@@ -123,6 +123,8 @@ def _tree_bytes(tree) -> int:
 def _type_shape(it) -> tuple:
     """Per-example activation shape for an InputType (time axis of an
     unknown-length sequence counted as 1 step)."""
+    if it.passes:
+        return (it.passes,) + _type_shape(dataclasses.replace(it, passes=None))
     if it.kind == "cnn":
         return (it.height, it.width, it.channels)
     if it.kind in ("rnn", "cnn1d"):

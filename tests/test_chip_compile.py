@@ -219,6 +219,26 @@ def _blocked_attention_grouped(S):
     return fwd_bwd, (q, kv, kv), ("mla_attend_fwd", "mla_attend_bwd")
 
 
+def _blocked_attention_rotary(S):
+    # the looped block's attention at the Ouro stage's shape, through the
+    # layer: 16 query and 16 key/value heads of 128 (equal q, k and v
+    # widths, no repeat), every width rotated, one sequence of 8192 steps
+    # in bfloat16
+    from deeplearning4j_tpu.nn.conf.attention import RotaryAttention
+    from deeplearning4j_tpu.nn.conf.inputs import InputType
+    layer = RotaryAttention(n_heads=16, head_dim=128, rope_theta=1e6)
+    shapes = jax.eval_shape(lambda k: layer.init(
+        k, InputType.recurrent(2048, 8192), BF16)[0], jax.random.key(0))
+    params = {k: S(a.shape, BF16) for k, a in shapes.items()}
+
+    def fwd_bwd(params, x):
+        return jax.grad(lambda p, x: jnp.sum(layer.apply(
+            p, {}, x)[0].astype(F32)), argnums=(0, 1))(params, x)
+
+    return (fwd_bwd, (params, S((1, 8192, 2048), BF16)),
+            ("mla_attend_fwd", "mla_attend_bwd"))
+
+
 def _inputs_case(S, dtype, gated_delta_net):
     # the delta-rule layers' input path at the two token cells' shapes, one
     # sequence of 8192 steps: KDA's four streams of 32 heads of 128 (q, k,
@@ -290,6 +310,7 @@ AUTO_CASES = [(_pq, "adc_pq"), (_int4_table, "int4_dot"),
               (_blocked_attention_float32, None),
               (_kda_scan_scalar_decay, None),
               (_blocked_attention_grouped, None),
+              (_blocked_attention_rotary, None),
               (_kda_inputs, "kda_inputs"), (_kda_inputs_float32, None),
               (_gdn_inputs, None), (_gdn_inputs_float32, None)]
 EXPLICIT_CASES = [_bn_fwd, _bn_bwd]
