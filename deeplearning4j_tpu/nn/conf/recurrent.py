@@ -313,7 +313,14 @@ class TokenOutputLayer(RnnOutputLayer):
     ``compute_score`` runs the time axis in blocks of ``time_block`` steps
     (``lossfunctions.blocked_sparse_mcxent``), so that at 8192 steps over
     20,480 classes the float32 logits, their softmax and gradient are one
-    block's. ``apply`` / ``output`` still return the whole softmax."""
+    block's. In a training step the loss and its gradients come out of ONE
+    loop over the blocks (a rule of its own, ``jax.custom_vjp``: three
+    products a block); what it keeps for the backward pass are the
+    gradients of the features, ``W`` and ``b`` and a cross-entropy a token,
+    which the backward pass scales. ``score`` runs one product a block.
+    The loss has no forward-mode rule (``jax.jvp`` / ``jacfwd`` /
+    ``hessian`` of it raise). ``apply`` / ``output`` still return the whole
+    softmax."""
 
     has_bias: bool = False
     loss: str = "sparse_mcxent"
@@ -366,9 +373,14 @@ class ExitWeightedTokenOutputLayer(TokenOutputLayer):
     the passes' cross-entropies under the gates' exit distribution less
     ``entropy_weight`` times that distribution's entropy
     (``lossfunctions.blocked_exit_weighted_mcxent``: one pass's block of
-    ``time_block`` steps of logits at a time). INTEGER labels (batch,
-    time), as ``TokenOutputLayer``. ``apply`` / ``output`` give the LAST
-    pass's softmax: leaving early at inference is not built."""
+    ``time_block`` steps of logits and their gradient at a time, over
+    ``TokenOutputLayer``'s block loop: in a training step one loop over the
+    (pass, block) pairs yields the loss and the gradients of the passes'
+    states and the head, and keeps them and the passes' cross-entropy a
+    token, through which the gates learn, for the backward pass; no
+    forward-mode rule). INTEGER labels (batch, time), as
+    ``TokenOutputLayer``. ``apply`` / ``output`` give the LAST pass's
+    softmax: leaving early at inference is not built."""
 
     entropy_weight: float = 0.0
 

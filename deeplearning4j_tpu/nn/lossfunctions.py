@@ -85,47 +85,145 @@ def _score_sparse_mcxent(labels, preout, activation, weights):
     return _score_mcxent(onehot, preout, activation, weights)
 
 
-def blocked_sparse_mcxent(x, w, b, ids, mask=None, block: int = 1024):
-    """``score("sparse_mcxent", ids, x @ w + b, "softmax", mask)`` without
-    the logits of the whole sequence: the time axis goes through in blocks
-    of ``block`` steps under ``jax.checkpoint``, so that forward and
-    backward hold one block's float32 logits and their gradient, never the
-    (batch, time, classes) array. ``x`` (batch, time, n_in), ``w`` (n_in,
-    classes), ``b`` None or (classes,), ``ids`` (batch, time) integers,
-    ``mask`` None or (batch, time). Mean over unmasked steps."""
-    bsz, t, _ = x.shape
+def _block_logits(xb, w, b):
+    """One block's float32 logits: the product at the default matmul
+    precision, accumulated in float32."""
+    z = jnp.matmul(xb, w, preferred_element_type=jnp.float32)
+    return z if b is None else z + b.astype(jnp.float32)
+
+
+def _block_ce(z, ib):
+    """(logsumexp, cross-entropy) a token of one block's float32 logits."""
+    lse = jax.nn.logsumexp(z, axis=-1)
+    return lse, lse - jnp.take_along_axis(z, ib[..., None], -1)[..., 0]
+
+
+@jax.custom_vjp
+def _blocked_ce(x, w, b, ids, coef):
+    """``sum(coef * CE(x w + b, ids))`` over blocks of tokens, one after
+    another: ``x`` (blocks, batch, block, n_in), ``w`` (n_in, classes),
+    ``b`` None or (classes,), ``ids`` (blocks, batch, block) int32,
+    ``coef`` (blocks, batch, block) float32, one number a token (the
+    mask over the count; for a looped model times a pass's exit
+    probability, with the passes' blocks laid end to end). The one block
+    loop under ``blocked_sparse_mcxent`` and ``blocked_exit_weighted_mcxent``.
+
+    Called without differentiation (``score``, evaluation) this body runs:
+    one product a block, no gradient. Under ``jax.grad`` the rule below
+    runs instead (``_blocked_ce_fwd``): ONE loop that yields the loss and
+    its gradients, three products a block. There is no forward-mode rule
+    (``jax.jvp`` / ``jacfwd`` / ``hessian`` of it raise)."""
+    from deeplearning4j_tpu.perf.compile_watch import bump_active
+
+    bump_active("loss.blocked_forward_only")
+
+    def step(total, blk):
+        xb, ib, cb = blk
+        _, ce = _block_ce(_block_logits(xb, w, b), ib)
+        return total + jnp.sum(ce * cb), None
+
+    total, _ = jax.lax.scan(step, jnp.zeros((), jnp.float32), (x, ids, coef))
+    return total
+
+
+def _blocked_ce_fwd(x, w, b, ids, coef):
+    """Loss and gradients in one pass over the blocks. A block's logits are
+    formed once (first product); from them, in float32, the logsumexp, the
+    token's cross-entropy (kept whole: it is ``coef``'s cotangent, through
+    which a looped model's gates learn) and ``coef`` times the softmax less
+    the one-hot, which gives the block's rows of ``x``'s gradient (second
+    product, written once) and its part of ``w``'s and ``b``'s (third
+    product, summed in float32 in the loop's carry). Alive at a time: one
+    block's logits and their gradient. Kept for the backward pass, which
+    only scales them by the scalar that arrives: the three gradients (the
+    size of ``x``, ``w``, ``b``) and a cross-entropy a token."""
+    from deeplearning4j_tpu.perf.compile_watch import bump_active
+
+    bump_active("loss.blocked_one_pass")
+    classes = w.shape[-1]
+
+    def step(carry, blk):
+        dw, db = carry
+        xb, ib, cb = blk
+        z = _block_logits(xb, w, b)
+        lse, ce = _block_ce(z, ib)
+        hit = jax.lax.broadcasted_iota(jnp.int32, z.shape, z.ndim - 1) \
+            == ib[..., None]
+        p = jnp.exp(z - lse[..., None])
+        g = jnp.where(hit, p - 1.0, p) * cb[..., None]
+        dxb = jnp.matmul(g, w.T, preferred_element_type=jnp.float32)
+        dw = dw + jnp.einsum("...d,...v->dv", xb, g,
+                             preferred_element_type=jnp.float32)
+        if b is not None:
+            db = db + jnp.sum(g.reshape(-1, classes), 0)
+        return (dw, db), (ce, dxb.astype(x.dtype))
+
+    # b may be None: an empty subtree to tree_map, here and below
+    zero = jax.tree_util.tree_map(lambda a: jnp.zeros(a.shape, jnp.float32),
+                                  (w, b))
+    (dw, db), (ce, dx) = jax.lax.scan(step, zero, (x, ids, coef))
+    dw, db = jax.tree_util.tree_map(lambda g, a: g.astype(a.dtype), (dw, db),
+                                    (w, b))
+    return jnp.sum(ce * coef), (dx, dw, db, ce)
+
+
+def _blocked_ce_bwd(kept, ct):
+    *grads, ce = kept
+    dx, dw, db = jax.tree_util.tree_map(lambda g: (ct * g).astype(g.dtype),
+                                        grads)
+    return dx, dw, db, None, ct * ce
+
+
+_blocked_ce.defvjp(_blocked_ce_fwd, _blocked_ce_bwd)
+
+
+def _time_blocks(t, block):
+    """(block length, padding, number of blocks) for ``t`` steps."""
+    block = min(block, t)
+    pad = (-t) % block
+    return block, pad, (t + pad) // block
+
+
+def _split_time(a, axis, n, pad):
+    """``a``'s time axis ``axis`` zero-padded by ``pad`` and cut into ``n``
+    blocks, the block index in front: (n, ..., block, ...)."""
+    if pad:
+        a = jnp.pad(a, [(0, pad if i == axis else 0) for i in range(a.ndim)])
+    a = a.reshape(a.shape[:axis] + (n, -1) + a.shape[axis + 1:])
+    return jnp.moveaxis(a, axis, 0)
+
+
+def _ids_and_mask(ids, mask, bsz, t):
     ids = ids.astype(jnp.int32)
     if ids.ndim == 3:
         ids = ids[..., 0]
     m = (jnp.ones((bsz, t), jnp.float32) if mask is None
          else mask.astype(jnp.float32))
-    block = min(block, t)
-    pad = (-t) % block
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
-        ids = jnp.pad(ids, ((0, 0), (0, pad)))
-        m = jnp.pad(m, ((0, 0), (0, pad)))
-    n = (t + pad) // block
+    return ids, m
 
-    def split(a):
-        return jnp.moveaxis(a.reshape((bsz, n, block) + a.shape[2:]), 1, 0)
 
-    @jax.checkpoint
-    def one(xb, ib, mb):
-        z = (xb @ w).astype(jnp.float32)
-        if b is not None:
-            z = z + b
-        lse = jax.nn.logsumexp(z, axis=-1)
-        picked = jnp.take_along_axis(z, ib[..., None], -1)[..., 0]
-        return jnp.sum((lse - picked) * mb)
-
-    def step(total, blk):
-        return total + one(*blk), None
-
+def blocked_sparse_mcxent(x, w, b, ids, mask=None, block: int = 1024):
+    """``score("sparse_mcxent", ids, x @ w + b, "softmax", mask)`` without
+    the logits of the whole sequence: the time axis goes through in blocks
+    of ``block`` steps (``_blocked_ce``), so that one block's float32
+    logits and their gradient are alive at a time, never the (batch, time,
+    classes) array. Under ``jax.grad`` the loss and its gradients come out
+    of ONE loop over the blocks (three products a block: logits, ``x``'s
+    gradient, ``w``'s); kept between the forward and the backward pass are
+    those gradients (the size of ``x``, ``w``, ``b``) and a cross-entropy
+    a token, which the backward pass scales. Not differentiated (``score``)
+    it is one product a block. No forward-mode rule (``jax.jvp``,
+    ``jacfwd``, ``hessian`` raise). ``x`` (batch, time, n_in), ``w`` (n_in,
+    classes), ``b`` None or (classes,), ``ids`` (batch, time) integers,
+    ``mask`` None or (batch, time). Mean over unmasked steps."""
+    bsz, t, _ = x.shape
+    ids, m = _ids_and_mask(ids, mask, bsz, t)
+    block, pad, n = _time_blocks(t, block)
     with jax.named_scope("loss.blocked"):
-        total, _ = jax.lax.scan(step, jnp.zeros((), jnp.float32),
-                                (split(x), split(ids), split(m)))
-    return total / jnp.maximum(jnp.sum(m), 1.0)
+        coef = m / jnp.maximum(jnp.sum(m), 1.0)
+        return _blocked_ce(_split_time(x, 1, n, pad), w, b,
+                           _split_time(ids, 1, n, pad),
+                           _split_time(coef, 1, n, pad))
 
 
 def exit_distribution(gate_logits):
@@ -147,26 +245,26 @@ def blocked_exit_weighted_mcxent(x, w, b, wg, bg, ids, mask=None,
                                  block: int = 1024,
                                  entropy_weight: float = 0.0):
     """The training loss of a looped model with an exit gate after every
-    pass, over ``blocked_sparse_mcxent``'s blocks: ``x`` (R, batch, time,
-    n_in) holds the R passes' states, every pass is scored by the one head
-    ``w`` (n_in, classes) (``b`` None or (classes,)) and gated by
-    ``sigmoid(x_r @ wg + bg)`` (``wg`` (n_in, 1), ``bg`` (1,)), and a
-    token's loss is
+    pass, over ``blocked_sparse_mcxent``'s block loop (``_blocked_ce``):
+    ``x`` (R, batch, time, n_in) holds the R passes' states, every pass is
+    scored by the one head ``w`` (n_in, classes) (``b`` None or
+    (classes,)) and gated by ``sigmoid(x_r @ wg + bg)`` (``wg`` (n_in, 1),
+    ``bg`` (1,)), and a token's loss is
 
         sum_r p_r CE(x_r w + b, id) - entropy_weight * H(p),
         H(p) = -sum_r p_r log p_r,   p = ``exit_distribution``
 
-    One (pass, time block) pair's float32 logits are alive at a time,
-    forward and backward (each pair under ``jax.checkpoint``); the gates,
-    the distribution and the mixing are whole-sequence arrays of R numbers
-    a token. ``ids`` (batch, time) integers, ``mask`` None or (batch,
-    time). Returns the mean over unmasked steps."""
+    One (pass, time block) pair's float32 logits and their gradient are
+    alive at a time; under ``jax.grad`` loss and gradients come out of ONE
+    loop over the pairs (three products a pair), which keeps for the
+    backward pass the gradients of ``x``, ``w`` and ``b`` and the passes'
+    cross-entropy a token (the gates learn through it); the gates, the
+    distribution and the mixing are whole-sequence arrays of R numbers a
+    token under plain autodiff. No forward-mode rule (``jax.jvp``,
+    ``jacfwd``, ``hessian`` raise). ``ids`` (batch, time) integers,
+    ``mask`` None or (batch, time). Returns the mean over unmasked steps."""
     passes, bsz, t, _ = x.shape
-    ids = ids.astype(jnp.int32)
-    if ids.ndim == 3:
-        ids = ids[..., 0]
-    m = (jnp.ones((bsz, t), jnp.float32) if mask is None
-         else mask.astype(jnp.float32))
+    ids, m = _ids_and_mask(ids, mask, bsz, t)
     with jax.named_scope("loop.exit_gate"):
         # an elementwise product and a sum: float32 whatever the backend's
         # default matmul precision is
@@ -174,37 +272,23 @@ def blocked_exit_weighted_mcxent(x, w, b, wg, bg, ids, mask=None,
             jnp.sum(x.astype(jnp.float32) * wg[:, 0].astype(jnp.float32),
                     -1) + bg)
         p = jnp.exp(logp)
-    block = min(block, t)
-    pad = (-t) % block
-    xp, idp = x, ids
-    if pad:
-        xp = jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        idp = jnp.pad(ids, ((0, 0), (0, pad)))
-    n = (t + pad) // block
-
-    @jax.checkpoint
-    def one(xb, ib):
-        z = (xb @ w).astype(jnp.float32)
-        if b is not None:
-            z = z + b
-        lse = jax.nn.logsumexp(z, axis=-1)
-        return lse - jnp.take_along_axis(z, ib[..., None], -1)[..., 0]
-
+    block, pad, n = _time_blocks(t, block)
+    with jax.named_scope("loss.exit_weighted"):
+        scale = m / jnp.maximum(jnp.sum(m), 1.0)
+        coef = p * scale
     with jax.named_scope("loop.exit_head"):
         # (pass, block) pairs, one after another
-        xs = jnp.moveaxis(xp.reshape(passes, bsz, n, block, -1), 2, 1)
-        ib = jnp.moveaxis(idp.reshape(bsz, n, block), 1, 0)
-        _, ce = jax.lax.scan(
-            lambda _, pair: (None, one(*pair)), None,
-            (xs.reshape((passes * n, bsz, block) + xs.shape[4:]),
-             jnp.tile(ib, (passes, 1, 1))))
-        ce = jnp.moveaxis(ce.reshape(passes, n, bsz, block), 1, 2) \
-            .reshape(passes, bsz, n * block)[..., :t]
+        def pairs(a):
+            a = _split_time(a, 2, n, pad)              # (n, passes, ...)
+            return jnp.moveaxis(a, 0, 1).reshape((passes * n,) + a.shape[2:])
+
+        total = _blocked_ce(pairs(x), w, b,
+                            jnp.tile(_split_time(ids, 1, n, pad),
+                                     (passes, 1, 1)), pairs(coef))
+    if not entropy_weight:
+        return total
     with jax.named_scope("loss.exit_weighted"):
-        token = jnp.sum(p * ce, 0)
-        if entropy_weight:
-            token = token + entropy_weight * jnp.sum(p * logp, 0)
-        return jnp.sum(token * m) / jnp.maximum(jnp.sum(m), 1.0)
+        return total + entropy_weight * jnp.sum(jnp.sum(p * logp, 0) * scale)
 
 
 def _score_nll(labels, preout, activation, weights):

@@ -6,6 +6,7 @@ holds the zoo builder to its plain reference."""
 
 import dataclasses
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -414,38 +415,83 @@ def test_the_exit_distribution_sums_to_one_and_is_the_products():
     assert float(jnp.max(jnp.abs(one))) == 0.0
 
 
-@pytest.mark.parametrize("t, block, masked", [(24, 8, False), (21, 8, True),
-                                              (5, 1024, False)])
-def test_the_loss_is_the_four_line_form(t, block, masked):
+@pytest.mark.parametrize("t, block, masked, r, beta, biased, scaled", [
+    (24, 8, False, 4, 0.05, True, False), (21, 8, True, 4, 0.05, True, False),
+    (5, 1024, False, 4, 0.05, True, False),
+    # no entropy term; one pass; no bias; a loss that is scaled and summed
+    # with a penalty (an upstream cotangent of 0.37)
+    (24, 8, False, 4, 0.0, True, False), (21, 8, True, 1, 0.05, True, False),
+    (24, 8, False, 3, 0.05, False, False), (21, 8, True, 4, 0.05, True, True),
+    (5, 1024, True, 2, 0.0, False, True)])
+def test_the_loss_is_the_four_line_form(t, block, masked, r, beta, biased,
+                                        scaled):
     ks = jax.random.split(jax.random.key(3), 6)
-    r, bsz, d, beta = 4, 2, 10, 0.05
+    bsz, d = 2, 10
     x = jax.random.normal(ks[0], (r, bsz, t, d))
     w = jax.random.normal(ks[1], (d, V))
-    b = 0.1 * jax.random.normal(ks[2], (V,))
+    b = 0.1 * jax.random.normal(ks[2], (V,)) if biased else None
     wg = jax.random.normal(ks[3], (d, 1))
     bg = jnp.array([0.3])
     ids = jax.random.randint(ks[4], (bsz, t), 0, V)
     mask = (jax.random.uniform(ks[5], (bsz, t)) < 0.7) if masked else None
 
+    def around(loss, x, w, wg):
+        return (0.37 * loss + 0.1 * jnp.sum(x * x) + 0.2 * jnp.sum(w * w)
+                + 0.3 * jnp.sum(wg * wg) if scaled else loss)
+
     def by_hand(x, w, b, wg, bg):
         lam = jax.nn.sigmoid((x @ wg)[..., 0] + bg)
-        p = jnp.stack([lam[0], lam[1] * (1 - lam[0]),
-                       lam[2] * (1 - lam[0]) * (1 - lam[1]),
-                       (1 - lam[0]) * (1 - lam[1]) * (1 - lam[2])])
-        ce = -jnp.take_along_axis(jax.nn.log_softmax(x @ w + b, -1),
+        stay = jnp.cumprod(1 - lam, 0)             # prod_{j<=r} (1 - lam_j)
+        before = jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]], 0)
+        p = jnp.concatenate([lam[:-1] * before[:-1], before[-1:]], 0)
+        z = x @ w if b is None else x @ w + b
+        ce = -jnp.take_along_axis(jax.nn.log_softmax(z, -1),
                                   ids[None, ..., None], -1)[..., 0]
         token = jnp.sum(p * ce, 0) + beta * jnp.sum(p * jnp.log(p), 0)
         m = jnp.ones_like(token) if mask is None else mask.astype(token.dtype)
-        return jnp.sum(token * m) / jnp.sum(m)
+        return around(jnp.sum(token * m) / jnp.sum(m), x, w, wg)
 
     def blocked(x, w, b, wg, bg):
-        return lossfunctions.blocked_exit_weighted_mcxent(
-            x, w, b, wg, bg, ids, mask, block, beta)
+        return around(lossfunctions.blocked_exit_weighted_mcxent(
+            x, w, b, wg, bg, ids, mask, block, beta), x, w, wg)
 
-    want, g_want = jax.value_and_grad(by_hand, range(5))(x, w, b, wg, bg)
-    got, g_got = jax.value_and_grad(blocked, range(5))(x, w, b, wg, bg)
+    wrt = (0, 1, 2, 3, 4) if biased else (0, 1, 3, 4)
+    want, g_want = jax.value_and_grad(by_hand, wrt)(x, w, b, wg, bg)
+    got, g_got = jax.value_and_grad(blocked, wrt)(x, w, b, wg, bg)
     assert float(abs(got - want)) < 1e-5
+    # not differentiated: the forward-only loop gives the same number
+    assert float(abs(blocked(x, w, b, wg, bg) - want)) < 1e-5
     _close(g_got, g_want, 2e-5)
+
+
+def test_the_looped_graph_s_step_keeps_the_exits_scopes_and_one_loss_loop():
+    """The compiled train step of the small looped graph carries the three
+    scopes that ``loop.exits_device_ms_per_step`` sums, forward and
+    backward, and the exits' head is ONE ``while`` (loss and gradients in
+    one pass over the (pass, block) pairs), traced once."""
+    net = ComputationGraph(_looped()).init()
+    x, y = _ids(7)
+    net.fit(DataSet(x, y))
+    assert net.compile_watch.counters("loss.") == {"loss.blocked_one_pass": 1}
+    net.score_dataset(DataSet(x, y))
+    assert net.compile_watch.counters("loss.") == {
+        "loss.blocked_one_pass": 1, "loss.blocked_forward_only": 1}
+
+    def struct(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+    ids = jax.ShapeDtypeStruct(x.shape, jnp.int32)
+    text = net._get_jitted("train").lower(
+        struct(net.params), struct(net.state), struct(net.opt_state),
+        struct(net._rng), [ids], [ids], None, None).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    for scope in ("loop.exit_gate", "loop.exit_head", "loss.exit_weighted"):
+        under = [o for o in names if scope in o]
+        assert any("transpose(" in o for o in under), scope
+        assert any("transpose(" not in o for o in under), scope
+    loops = [o for o in names if o.endswith("/while") and "exit_head" in o]
+    assert loops and all("transpose(" not in o for o in loops)
 
 
 def test_with_one_pass_the_loss_is_the_token_output_layer_s():

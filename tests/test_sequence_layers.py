@@ -6,7 +6,9 @@ here, on the CPU at small sizes in float32. The whole models against the
 benchmark's references are in ``tests/benchmark/test_benchmark_kimi_linear.py``
 and ``test_benchmark_qwen3_next.py``."""
 
+import dataclasses
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -566,52 +568,177 @@ def test_gated_attention_counts_the_path_it_took(attn_impl):
 
 
 # -------------------------------------------------------------------- loss
-@pytest.mark.parametrize("t,block,masked", [(64, 16, False), (50, 16, False),
-                                            (50, 16, True), (10, 16, True)])
-def test_blocked_loss_is_the_plain_cross_entropy(t, block, masked):
-    ks = _keys(4, 2)
+@pytest.mark.parametrize("t,block,masked,biased,scaled", [
+    (64, 16, False, False, False), (50, 16, False, False, False),
+    (50, 16, True, False, False), (10, 16, True, False, False),
+    # a bias; a loss that is scaled and summed with a penalty (an upstream
+    # cotangent of 0.37); a block longer than the sequence, unmasked
+    (64, 16, False, True, False), (50, 16, True, True, True),
+    (64, 16, False, False, True), (10, 1024, False, True, True)])
+def test_blocked_loss_is_the_plain_cross_entropy(t, block, masked, biased,
+                                                 scaled):
+    ks = _keys(5, 2)
     x = jax.random.normal(ks[0], (3, t, 12))
     w = jax.random.normal(ks[1], (12, 30)) * 0.3
+    b = 0.2 * jax.random.normal(ks[4], (30,)) if biased else None
     ids = jax.random.randint(ks[2], (3, t), 0, 30)
     mask = ((jax.random.uniform(ks[3], (3, t)) > 0.3).astype(jnp.float32)
             if masked else None)
 
-    def plain(x, w):
+    def around(loss, x, w):
+        return (0.37 * loss + 0.1 * jnp.sum(x * x) + 0.2 * jnp.sum(w * w)
+                if scaled else loss)
+
+    def plain(x, w, b):
         onehot = jax.nn.one_hot(ids, 30)
-        return lossfunctions.score("mcxent", onehot, x @ w, "softmax", mask)
+        z = x @ w if b is None else x @ w + b
+        return around(lossfunctions.score("mcxent", onehot, z, "softmax",
+                                          mask), x, w)
 
-    def blocked(x, w):
-        return lossfunctions.blocked_sparse_mcxent(x, w, None, ids, mask,
-                                                   block)
+    def blocked(x, w, b):
+        return around(lossfunctions.blocked_sparse_mcxent(x, w, b, ids, mask,
+                                                          block), x, w)
 
-    assert abs(float(blocked(x, w) - plain(x, w))) < 1e-5
-    assert abs(float(lossfunctions.score("sparse_mcxent", ids, x @ w,
-                                         "softmax", mask)
-                     - plain(x, w))) < 1e-5
-    for a, b in zip(jax.grad(blocked, (0, 1))(x, w),
-                    jax.grad(plain, (0, 1))(x, w)):
-        assert float(jnp.max(jnp.abs(a - b))) < 1e-5
+    wrt = (0, 1, 2) if biased else (0, 1)
+    want, g_want = jax.value_and_grad(plain, wrt)(x, w, b)
+    got, g_got = jax.value_and_grad(blocked, wrt)(x, w, b)
+    assert abs(float(got - want)) < 1e-5
+    # not differentiated (``score``): the forward-only loop, the same number
+    assert abs(float(blocked(x, w, b) - want)) < 1e-5
+    z = x @ w if b is None else x @ w + b
+    assert abs(float(around(lossfunctions.score(
+        "sparse_mcxent", ids, z, "softmax", mask), x, w) - want)) < 1e-5
+    for a, e in zip(g_got, g_want):
+        assert float(jnp.max(jnp.abs(a - e))) < 1e-5
 
 
-def test_blocked_loss_never_builds_the_sequence_logits():
-    """No array of (time x classes) floats in the jaxpr of loss and
-    gradient: the largest is one block's."""
-    x = jnp.zeros((1, 256, 8))
-    w = jnp.zeros((8, 64))
+def _walk(jp):
+    """Every equation of a jaxpr and of the jaxprs in its parameters."""
+    for eqn in jp.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk(sub)
+
+
+def _block_loops(jp, blocks, block, classes):
+    """[products a body] of the loops over ``blocks`` blocks whose body
+    forms or reads a (block, classes) array in a product."""
+    found = []
+    for eqn in _walk(jp):
+        if eqn.primitive.name != "scan" or eqn.params["length"] != blocks:
+            continue
+        found.append(sum(
+            1 for e in _walk(eqn.params["jaxpr"].jaxpr)
+            if e.primitive.name == "dot_general"
+            and any(tuple(v.aval.shape)[-2:] == (block, classes)
+                    for v in list(e.invars) + list(e.outvars))))
+    return [n for n in found if n]
+
+
+def _blocked_loss_case(which):
+    """(loss of (x, w), x, w, blocks, block, classes): 8 blocks of 32
+    steps over 64 classes; the looped loss 2 passes of them."""
+    w = 0.1 * jnp.ones((8, 64))
     ids = jnp.zeros((1, 256), jnp.int32)
-    jaxpr = jax.make_jaxpr(jax.grad(
-        lambda x, w: lossfunctions.blocked_sparse_mcxent(
-            x, w, None, ids, None, 32), (0, 1)))(x, w)
+    if which == "plain":
+        return (lambda x, w: lossfunctions.blocked_sparse_mcxent(
+            x, w, None, ids, None, 32)), jnp.ones((1, 256, 8)), w, 8
+    wg, bg = 0.1 * jnp.ones((8, 1)), jnp.zeros((1,))
+    return (lambda x, w: lossfunctions.blocked_exit_weighted_mcxent(
+        x, w, None, wg, bg, ids, None, 32, 0.05)), jnp.ones((2, 1, 256, 8)), \
+        w, 16
 
-    def shapes(jp):
-        for eqn in jp.eqns:
-            for var in eqn.outvars:
-                yield tuple(var.aval.shape)
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from shapes(sub)
 
-    assert not any(s[-1:] == (64,) and math.prod(s) > 32 * 64
-                   for s in shapes(jaxpr.jaxpr))
+@pytest.mark.parametrize("which", ["plain", "exit_weighted"])
+def test_blocked_loss_never_builds_the_sequence_logits(which):
+    """No array of (time x classes) floats in the jaxpr of loss and
+    gradient, nor one of all the passes' blocks: the largest is one
+    block's."""
+    loss, x, w, _ = _blocked_loss_case(which)
+    jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1)))(x, w)
+    assert not any(v.aval.shape[-1:] == (64,)
+                   and math.prod(v.aval.shape) > 32 * 64
+                   for eqn in _walk(jaxpr.jaxpr) for v in eqn.outvars)
+
+
+@pytest.mark.parametrize("which", ["plain", "exit_weighted"])
+def test_blocked_loss_is_one_loop_of_three_products(which):
+    """Loss and gradients come out of ONE loop over the blocks that forms
+    a block's logits once: three products a body (logits, the states'
+    gradient, the head's), where autodiff through a rematerialised body
+    made two loops and four products. Not differentiated it is one loop of
+    one product, and the counters say which was traced."""
+    from deeplearning4j_tpu.perf.compile_watch import GLOBAL
+    loss, x, w, blocks = _blocked_loss_case(which)
+
+    def counters():
+        return {k: GLOBAL.counters("loss.").get(k, 0) for k in
+                ("loss.blocked_one_pass", "loss.blocked_forward_only")}
+
+    before = counters()
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, (0, 1)))(x, w)
+    assert _block_loops(jaxpr.jaxpr, blocks, 32, 64) == [3]
+    after = counters()
+    assert after == {"loss.blocked_one_pass":
+                     before["loss.blocked_one_pass"] + 1,
+                     "loss.blocked_forward_only":
+                     before["loss.blocked_forward_only"]}
+    plain = jax.make_jaxpr(loss)(x, w)
+    assert _block_loops(plain.jaxpr, blocks, 32, 64) == [1]
+    # no gradient: nothing of the head's or the states' size is made
+    assert not any(tuple(v.aval.shape)[-2:] in ((8, 64), (32, 8))
+                   for e in _walk(plain.jaxpr) if e.primitive.name == "scan"
+                   for v in e.outvars)
+    assert counters() == {"loss.blocked_one_pass":
+                          after["loss.blocked_one_pass"],
+                          "loss.blocked_forward_only":
+                          after["loss.blocked_forward_only"] + 1}
+    with pytest.raises(TypeError, match="custom_vjp"):
+        jax.jvp(loss, (x, w), (x, w))
+
+
+def _token_graph():
+    from deeplearning4j_tpu.nn.conf.graph import GraphBuilder
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+    g = GraphBuilder()
+    g.add_inputs("ids")
+    g.add_layer("embed", EmbeddingSequenceLayer(n_in=30, n_out=12), "ids")
+    g.add_layer("norm", RMSNorm(), "embed")
+    g.add_layer("head", TokenOutputLayer(n_out=30, time_block=16), "norm")
+    g.set_outputs("head")
+    g.set_input_types(InputType.recurrent(30, 40))
+    return ComputationGraph(dataclasses.replace(
+        g.build(), updater=Adam(1e-2))).init()
+
+
+def test_a_token_graph_s_step_keeps_the_loss_scope_and_counts_its_loop():
+    """The compiled train step carries ``loss.blocked`` forward and
+    backward (what ``benchmark/scope_table.py`` files the loss under), the
+    step program traced the one-pass loop and ``score`` the forward-only
+    one."""
+    net = _token_graph()
+    ids = np.random.default_rng(0).integers(0, 30, (2, 41)).astype(np.int32)
+    ds = DataSet(ids[:, :-1], ids[:, 1:])
+    net.fit(ds)
+    assert net.compile_watch.counters("loss.") == {"loss.blocked_one_pass": 1}
+    first = net.score()
+    assert abs(net.score_dataset(ds) - first) < 0.1 * first
+    assert net.compile_watch.counters("loss.") == {
+        "loss.blocked_one_pass": 1, "loss.blocked_forward_only": 1}
+
+    def struct(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+    x = jax.ShapeDtypeStruct((2, 40), jnp.int32)
+    text = net._get_jitted("train").lower(
+        struct(net.params), struct(net.state), struct(net.opt_state),
+        struct(net._rng), [x], [x], None, None).compile().as_text()
+    under = [o for o in re.findall(r'op_name="([^"]*)"', text)
+             if "loss.blocked" in o]
+    assert any("transpose(" in o for o in under)
+    assert any("transpose(" not in o for o in under)
+    assert len(re.findall(r"\bwhile\(", text)) == 1
 
 
 # ------------------------------------------------------------------ experts
