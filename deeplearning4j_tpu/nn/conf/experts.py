@@ -29,9 +29,11 @@ so that work, traffic and memory follow the load and a skewed batch is
 slower, never wrong. The selection bias and the load counters live in the layer's
 non-trained ``state``: ``bias`` (frozen; its load-driven update is not part
 of any published config), ``expert_tokens`` (pairs each held expert got,
-summed over steps), ``pairs_held`` and ``pairs_dropped`` (must stay 0).
-They are device arrays updated inside the step; ``obs.registry.watch_moe``
-reads them at scrape time only.
+summed over steps), ``pairs_held``, ``pairs_dropped`` (must stay 0) and
+``steps_every_window`` (steps whose held pairs passed the first window, so
+that every window ran: the one branch whose choice changes the step's
+length). They are device arrays updated inside the step;
+``obs.registry.watch_moe`` reads them at scrape time only.
 """
 
 from __future__ import annotations
@@ -206,7 +208,8 @@ class RoutedExperts(BaseLayer):
         state = {"bias": jnp.zeros((self.n_experts,), jnp.float32),
                  "expert_tokens": jnp.zeros((e,), jnp.int32),
                  "pairs_held": jnp.zeros((), jnp.int32),
-                 "pairs_dropped": jnp.zeros((), jnp.int32)}
+                 "pairs_dropped": jnp.zeros((), jnp.int32),
+                 "steps_every_window": jnp.zeros((), jnp.int32)}
         return params, state
 
     def route(self, x, w_r, bias):
@@ -298,8 +301,10 @@ class RoutedExperts(BaseLayer):
         if windows > 1:
             y, covered = lax.cond(n_held <= window, first_window,
                                   every_window, *operands)
+            took_every = (n_held > window).astype(jnp.int32)
         else:
             y, covered = first_window(*operands)
+            took_every = 0
         y = y.astype(x.dtype)
         if self.shared_size:
             with jax.named_scope("moe.shared"):
@@ -317,7 +322,9 @@ class RoutedExperts(BaseLayer):
                      "expert_tokens": state["expert_tokens"] + sizes,
                      "pairs_held": state["pairs_held"] + n_held,
                      "pairs_dropped": state["pairs_dropped"]
-                     + (n_held - covered)}
+                     + (n_held - covered),
+                     "steps_every_window": state["steps_every_window"]
+                     + took_every}
         return y.reshape(shape[:-1] + (y.shape[-1],)), new_state
 
 

@@ -32,6 +32,7 @@ from deeplearning4j_tpu.nn.activations import get_activation
 from deeplearning4j_tpu.nn.initializers import Distribution, init_weights
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn import lossfunctions
+from deeplearning4j_tpu.obs.owners import layer_marker
 from deeplearning4j_tpu.optimize.updaters import Updater
 
 LAYER_REGISTRY = {}
@@ -196,7 +197,7 @@ def apply_layer(layer, params, state, x, *, train, rng, mask, name,
     ``<LayerClass>:<name>`` in its ``op_name``, so a device trace can be
     read by layer kind whatever the compiler calls its fusions."""
     extra = extra or {}
-    with jax.named_scope(f"{type(layer).__name__}:{name}"):
+    with jax.named_scope(layer_marker(layer, name)):
         if getattr(layer, "remat", None):
             from deeplearning4j_tpu.perf.fusion import remat_policy
             policy = remat_policy(layer.remat)

@@ -38,6 +38,8 @@ import optax
 from deeplearning4j_tpu.nn.conf.layers import (_bias_keys, apply_constraints,
                                                regularization_coefficients,
                                                resolve_param_path)
+from deeplearning4j_tpu.obs.owners import (LOSS_PENALTY, LOSS_SCORE, OPTIM,
+                                           PARAMS_CAST)
 from deeplearning4j_tpu.obs.registry import count_train_steps
 from deeplearning4j_tpu.obs.trace import get_tracer
 from deeplearning4j_tpu.optimize.fused_update import bucketed_apply
@@ -186,6 +188,20 @@ class Network:
                                                y, fm, lm))
 
     # ---------------------------------------------------------------- loss
+    def _to_compute_dtype(self, params, inputs):
+        """``params`` and the floating ones of ``inputs`` (an array or a
+        list of them) in the compute dtype: the master weights' cast (and,
+        under autodiff, the gradient's cast back) and the features', which
+        no layer owns, under the ``params.cast`` scope (obs/owners.py)."""
+        cdt = self._dtype
+        if cdt == jnp.float32:
+            return params, inputs
+        with jax.named_scope(PARAMS_CAST):
+            return jax.tree_util.tree_map(
+                lambda a: a.astype(cdt)
+                if jnp.issubdtype(a.dtype, jnp.floating) else a,
+                (params, inputs))
+
     def _regularization(self, params):
         """L1/L2 penalty (reference BaseLayer.calcL2/calcL1; score term added in
         BaseOutputLayer.computeScore fullNetworkL1/L2)."""
@@ -216,8 +232,10 @@ class Network:
     def _output_score(layer, y, preout, lmask, forward_mask):
         """One output layer's loss: labels in f32, and the mask the forward
         pass carried to the layer where the batch brings no label mask."""
-        return layer.compute_score(
-            _f32(y), preout, lmask if lmask is not None else forward_mask)
+        with jax.named_scope(LOSS_SCORE):
+            return layer.compute_score(
+                _f32(y), preout,
+                lmask if lmask is not None else forward_mask)
 
     def _loss_fn(self, params, state, x, y, rng, fmask, lmask, carries=None):
         """Loss over the output layers plus the penalty; with ``carries``
@@ -231,7 +249,8 @@ class Network:
             x = self._augment(x, ak)
         loss, aux = self._forward_loss(params, state, x, y, rng, fmask,
                                        lmask, carries)
-        return loss + self._regularization(params), aux
+        with jax.named_scope(LOSS_PENALTY):
+            return loss + self._regularization(params), aux
 
     # ---------------------------------------------------------- the steps
     def _apply_updates(self, params, grads, opt_state):
@@ -245,15 +264,20 @@ class Network:
         run through ``bucketed_apply`` (optimize/fused_update.py), which
         computes the identical math over one concatenated vector per
         updater config so XLA emits a handful of fusions instead of one
-        per leaf (ResNet50: 244 small fusions ~8 ms/step)."""
-        results = bucketed_apply([k for k, _ in self._param_layers],
-                                 self._updaters, self._txs, self._gnorms,
-                                 params, grads, opt_state)
-        new_params, new_opt = {}, {}
-        for k, layer in self._param_layers:
-            updates, new_opt[k] = results[k]
-            new_params[k] = apply_constraints(
-                layer, optax.apply_updates(params[k], updates))
+        per leaf (ResNet50: 244 small fusions ~8 ms/step).
+
+        Everything here lies under the ``optim.update`` scope
+        (obs/owners.py): the optimizer is the owner of these operations in
+        every step program, as a layer is of its own."""
+        with jax.named_scope(OPTIM):
+            results = bucketed_apply([k for k, _ in self._param_layers],
+                                     self._updaters, self._txs, self._gnorms,
+                                     params, grads, opt_state)
+            new_params, new_opt = {}, {}
+            for k, layer in self._param_layers:
+                updates, new_opt[k] = results[k]
+                new_params[k] = apply_constraints(
+                    layer, optax.apply_updates(params[k], updates))
         return (self._collect(new_params, params),
                 self._collect(new_opt, opt_state))
 

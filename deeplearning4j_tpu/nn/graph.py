@@ -25,6 +25,7 @@ from deeplearning4j_tpu.nn.conf.graph import (
 from deeplearning4j_tpu.nn.conf.layers import (Layer, apply_layer,
                                                dropout_input, noisy_params)
 from deeplearning4j_tpu.nn.engine import Network, _f32, run_epochs
+from deeplearning4j_tpu.obs.owners import LOSS_SCORE, layer_marker
 
 
 def _arrays(items):
@@ -53,25 +54,31 @@ def run_vertices(order, vertices, vpre, input_types, params, state, acts,
         k = None
         if rng is not None:
             rng, k = jax.random.split(rng)
+        # what runs for a vertex outside ``apply_layer`` lies under the
+        # vertex's marker too (obs/owners.py)
+        marker = layer_marker(obj, name)
         if isinstance(obj, Layer):
-            if name in vpre:
-                xs = list(xs)
-                xs[0], in_mask = vpre[name].apply(xs[0], in_mask)
-            p_v = noisy_params(obj, params[name], k, train)
+            with jax.named_scope(marker):
+                if name in vpre:
+                    xs = list(xs)
+                    xs[0], in_mask = vpre[name].apply(xs[0], in_mask)
+                p_v = noisy_params(obj, params[name], k, train)
             if obj.is_output_layer():
-                x_in = dropout_input(xs[0], obj.dropout, train, k)
-                z = obj.pre_output(p_v, x_in)
-                # loss math in f32 (z may be a pytree: CenterLoss/YOLO)
-                z = jax.tree_util.tree_map(_f32, z)
-                preouts[name] = z
-                out = obj.output_activations(z)
+                with jax.named_scope(marker):
+                    x_in = dropout_input(xs[0], obj.dropout, train, k)
+                    z = obj.pre_output(p_v, x_in)
+                    # loss math in f32 (z may be a pytree: CenterLoss/YOLO)
+                    z = jax.tree_util.tree_map(_f32, z)
+                    preouts[name] = z
+                    out = obj.output_activations(z)
                 new_state[name] = state[name]
             elif (carries is not None and hasattr(obj, "apply_seq")
                   and getattr(obj, "supports_stateful", True)):
-                x_in = dropout_input(xs[0], obj.dropout, train, k)
-                out, nc = obj.apply_seq(p_v, carries[name], x_in,
-                                        train=train, rng=None,
-                                        mask=in_mask)
+                with jax.named_scope(marker):
+                    x_in = dropout_input(xs[0], obj.dropout, train, k)
+                    out, nc = obj.apply_seq(p_v, carries[name], x_in,
+                                            train=train, rng=None,
+                                            mask=in_mask)
                 new_carries[name] = nc
                 new_state[name] = state[name]
             else:
@@ -93,14 +100,17 @@ def run_vertices(order, vertices, vpre, input_types, params, state, acts,
                 m = in_mask
                 if obj.mask_input is not None:
                     m = mask_of.get(obj.mask_input)
-                out = obj.apply(*xs, mask=m)
+                with jax.named_scope(marker):
+                    out = obj.apply(*xs, mask=m)
                 mask_of[name] = None
             elif isinstance(obj, DuplicateToTimeSeriesVertex):
                 t = acts[obj.reference_input].shape[1]
-                out = obj.apply(*xs, time_steps=t)
+                with jax.named_scope(marker):
+                    out = obj.apply(*xs, time_steps=t)
                 mask_of[name] = mask_of.get(obj.reference_input)
             else:
-                out = obj.apply(*xs)
+                with jax.named_scope(marker):
+                    out = obj.apply(*xs)
                 mask_of[name] = in_mask
             new_state[name] = state[name]
         acts[name] = out
@@ -197,15 +207,11 @@ class ComputationGraph(Network):
         sequence path of recurrent layer vertices (``apply_seq``), mirroring
         the MLN carry threading — the graph analogue of the reference's
         rnnActivateUsingStoredState (ComputationGraph.java:2402)."""
-        cdt = self._dtype
-        if cdt != jnp.float32:
-            params = jax.tree_util.tree_map(lambda a: a.astype(cdt), params)
+        params, inputs = self._to_compute_dtype(params, list(inputs))
         acts: Dict[str, jnp.ndarray] = {}
         mask_of: Dict[str, Optional[jnp.ndarray]] = {}
         for i, name in enumerate(self.conf.network_inputs):
-            x = inputs[i]
-            acts[name] = x.astype(cdt) if (cdt != jnp.float32 and
-                                           jnp.issubdtype(x.dtype, jnp.floating)) else x
+            acts[name] = inputs[i]
             mask_of[name] = None if masks is None else masks[i]
         preouts, new_state, new_carries = run_vertices(
             self.order, self.vertices, self._vpre, self.vertex_input_types,
@@ -228,11 +234,12 @@ class ComputationGraph(Network):
         """Loss over all output layers, summed as in the reference."""
         fwd = self._forward(params, state, inputs, True, rng, fmasks, carries)
         _, preouts, new_state, mask_of = fwd[:4]
-        loss = 0.0
-        for j, out_name in enumerate(self.conf.network_outputs):
-            loss = loss + self._output_score(
-                self.vertices[out_name][0], labels[j], preouts[out_name],
-                None if lmasks is None else lmasks[j], mask_of.get(out_name))
+        scores = [self._output_score(
+            self.vertices[out_name][0], labels[j], preouts[out_name],
+            None if lmasks is None else lmasks[j], mask_of.get(out_name))
+            for j, out_name in enumerate(self.conf.network_outputs)]
+        with jax.named_scope(LOSS_SCORE):
+            loss = sum(scores[1:], scores[0])
         return loss, (new_state if carries is None else (new_state, fwd[4]))
 
     def _loss_fn_tbptt(self, params, state, carries, inputs, labels, rng,

@@ -27,6 +27,7 @@ from deeplearning4j_tpu.nn.conf.layers import (apply_constraints, apply_layer,
 from deeplearning4j_tpu.nn.conf.network import MultiLayerConfiguration
 from deeplearning4j_tpu.nn.engine import (Network, _f32, bind_epoch,
                                           run_epochs)
+from deeplearning4j_tpu.obs.owners import layer_marker
 from deeplearning4j_tpu.obs.trace import get_tracer
 from deeplearning4j_tpu.optimize.updaters import is_sgd_family
 import optax
@@ -94,31 +95,36 @@ class MultiLayerNetwork(Network):
         new_carries = []
         preout = None
         cur_mask = fmask
-        cdt = self._dtype
-        if cdt != jnp.float32:
-            x = x.astype(cdt)
-            params = jax.tree_util.tree_map(lambda a: a.astype(cdt), params)
+        params, x = self._to_compute_dtype(params, x)
         n = len(self.layers)
         for i, layer in enumerate(self.layers):
-            if i in self._pre:
-                x, cur_mask = self._pre[i].apply(x, cur_mask)
+            # what runs for a layer outside ``apply_layer`` lies under the
+            # layer's marker too (obs/owners.py)
+            marker = layer_marker(layer, i)
             k = None
             if rng is not None:
                 rng, k = jax.random.split(rng)
-            p_i = noisy_params(layer, params[i], k, train)
+            with jax.named_scope(marker):
+                if i in self._pre:
+                    x, cur_mask = self._pre[i].apply(x, cur_mask)
+                p_i = noisy_params(layer, params[i], k, train)
             if i == n - 1 and layer.is_output_layer():
-                x_in = dropout_input(x, layer.dropout, train, k)
-                preout = layer.pre_output(p_i, x_in)
-                # loss math in f32 (preout may be a pytree: CenterLoss/YOLO)
-                preout = jax.tree_util.tree_map(_f32, preout)
-                x = layer.output_activations(preout)
+                with jax.named_scope(marker):
+                    x_in = dropout_input(x, layer.dropout, train, k)
+                    preout = layer.pre_output(p_i, x_in)
+                    # loss math in f32 (preout may be a pytree:
+                    # CenterLoss/YOLO)
+                    preout = jax.tree_util.tree_map(_f32, preout)
+                    x = layer.output_activations(preout)
                 new_state.append(state[i])
                 new_carries.append({})
             elif (carries is not None and hasattr(layer, "apply_seq")
                   and getattr(layer, "supports_stateful", True)):
-                x_in = dropout_input(x, layer.dropout, train, k)
-                x, nc = layer.apply_seq(p_i, carries[i], x_in,
-                                        train=train, rng=None, mask=cur_mask)
+                with jax.named_scope(marker):
+                    x_in = dropout_input(x, layer.dropout, train, k)
+                    x, nc = layer.apply_seq(p_i, carries[i], x_in,
+                                            train=train, rng=None,
+                                            mask=cur_mask)
                 new_state.append(state[i])
                 new_carries.append(nc)
             else:

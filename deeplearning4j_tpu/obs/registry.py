@@ -27,7 +27,9 @@ thread.
 
 from __future__ import annotations
 
+import bisect
 import logging
+import math
 import re
 import threading
 import weakref
@@ -41,7 +43,7 @@ __all__ = [
     "watch_training_stats",
     "absorb_inference_stats", "absorb_checkpoint_manager",
     "absorb_model_server", "watch_grad_compression", "watch_moe",
-    "publish_stats_update", "DEFAULT_BUCKETS_MS",
+    "publish_stats_update", "DEFAULT_BUCKETS_MS", "STEP_BUCKETS_MS",
 ]
 
 
@@ -57,6 +59,13 @@ _NAME_RE = re.compile(r"^[a-z][a-z0-9_]*$")
 DEFAULT_BUCKETS_MS = (0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0,
                       100.0, 250.0, 500.0, 1000.0, 2500.0, 5000.0,
                       10000.0, 30000.0, 60000.0)
+
+#: a step's ladder: 1 ms to 10 s in steps of 12%, for the histogram of the
+#: fit loops' turn (``train_iteration_ms``), so that a quantile of a 45 ms
+#: and of a 1.1 s step is read within a bucket of 12% (the default ladder's
+#: 25-50-100 and 1000-2500 are not a step's resolution)
+STEP_BUCKETS_MS = tuple(round(1.12 ** i, 4) for i in range(
+    int(math.log(10000.0) / math.log(1.12)) + 2))
 
 
 class _Instrument:
@@ -149,13 +158,9 @@ class Histogram(_Instrument):
 
     def observe(self, value: float):
         v = float(value)
+        # the first bound that holds v; past the last: the +Inf bucket
+        i = bisect.bisect_left(self.bounds, v)
         with self._lock:
-            i = 0
-            for i, b in enumerate(self.bounds):
-                if v <= b:
-                    break
-            else:
-                i = len(self.bounds)
             self._counts[i] += 1
             self._sum += v
             self._count += 1
@@ -346,8 +351,12 @@ def _sanitize(name: str) -> str:
 # ------------------------------------------------------------ absorb bridges
 def absorb_compile_watch(registry: MetricsRegistry, watch=None):
     """Pull a ``perf.CompileWatch`` (default: the process-wide GLOBAL) into
-    gauges: total compiles/dispatches plus every freeform counter (e.g.
-    ``attention.flash_fallback``)."""
+    gauges: total compiles/dispatches, every freeform counter (e.g.
+    ``attention.flash_fallback``) and, a program, what its compiles cost:
+    ``jit_compile_<phase>_<program>`` (seconds of ``trace_s``, ``lower_s``,
+    ``backend_s``, ``cache_load_s``; ``cache_hits`` / ``cache_misses`` of
+    the persistent cache), from the process's one ``jax.monitoring``
+    listener. ``unwatched`` is what compiled outside any watched program."""
     from deeplearning4j_tpu.perf.compile_watch import GLOBAL
     w = watch if watch is not None else GLOBAL
     registry.gauge("jit_compiles", unit="compiles",
@@ -360,6 +369,13 @@ def absorb_compile_watch(registry: MetricsRegistry, watch=None):
         registry.gauge(f"jit_{_sanitize(key)}", unit="events",
                        help=f"CompileWatch freeform counter '{key}'"
                        ).set(val)
+    for program, phases in w.compile_phases().items():
+        for phase, val in phases.items():
+            registry.gauge(
+                f"jit_compile_{phase}_{_sanitize(program)}",
+                unit="s" if phase.endswith("_s") else "events",
+                help=f"'{phase}' of the compiles of program '{program}' "
+                     "(perf/compile_watch.py PHASES)").set(val)
 
 
 def absorb_training_stats(registry: MetricsRegistry, stats,
@@ -665,6 +681,10 @@ def watch_moe(registry: MetricsRegistry, model):
       expert held here, all routed layers together;
     * ``moe_dropped_tokens_total``: such pairs that no grouped product
       computed. Must read 0;
+    * ``moe_every_window_steps_total``: (layer, step) pairs in which a
+      routed layer's held pairs passed its first window and every window
+      ran (``steps_every_window``), all routed layers together: over routed
+      layers x steps it is the share of the slow tier;
     * ``moe_expert_tokens_<layer>_e<expert>`` gauges: pairs each held
       expert of each layer has got so far (the registry has no labels:
       layer and expert are in the name).
@@ -689,7 +709,9 @@ def watch_moe(registry: MetricsRegistry, model):
                     "expert_tokens": _np.asarray(st["expert_tokens"])
                     .astype(_np.int64).tolist(),
                     "pairs_held": int(_np.asarray(st["pairs_held"])),
-                    "pairs_dropped": int(_np.asarray(st["pairs_dropped"]))}
+                    "pairs_dropped": int(_np.asarray(st["pairs_dropped"])),
+                    "steps_every_window": int(_np.asarray(
+                        st["steps_every_window"]))}
         return out
 
     def _cb(reg: MetricsRegistry):
@@ -718,9 +740,14 @@ def watch_moe(registry: MetricsRegistry, model):
             "moe_dropped_tokens_total", unit="pairs",
             help="pairs on a held expert that no grouped product "
                  "computed: must read 0")
+        every = reg.counter(
+            "moe_every_window_steps_total", unit="steps",
+            help="(routed layer, step) pairs whose held pairs passed the "
+                 "first window, so that every window ran")
         for layer, c in layers.items():
             for what, inst in (("pairs_held", held),
-                               ("pairs_dropped", dropped)):
+                               ("pairs_dropped", dropped),
+                               ("steps_every_window", every)):
                 key = f"{layer}/{what}"
                 now = c[what] % (1 << 32)
                 inst.inc(float((now - seen.get(key, 0)) % (1 << 32)))

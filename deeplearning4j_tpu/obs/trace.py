@@ -61,7 +61,15 @@ from typing import Callable, List, Optional
 
 from jax.profiler import TraceAnnotation
 
+from deeplearning4j_tpu.obs.registry import (DEFAULT_BUCKETS_MS,
+                                             STEP_BUCKETS_MS)
+
 log = logging.getLogger(__name__)
+
+#: spans whose ``<span>_ms`` histogram takes other buckets than the
+#: registry's default: the fit loops' turn is the step time, and a step's
+#: p95 wants a step's resolution
+SPAN_BUCKETS_MS = {"train.iteration": STEP_BUCKETS_MS}
 
 __all__ = ["Tracer", "get_tracer", "configure_tracer", "Stopwatch"]
 
@@ -193,7 +201,9 @@ class Tracer:
                 self.registry.histogram(
                     f"{name}_ms", unit="ms",
                     help=f"duration of span '{record['name']}' "
-                         "(auto-registered by the tracer)"
+                         "(auto-registered by the tracer)",
+                    buckets=SPAN_BUCKETS_MS.get(record["name"],
+                                                DEFAULT_BUCKETS_MS)
                 ).observe(record["dur_ms"])
             except Exception as e:
                 log.debug("span histogram observe failed (%s: %s)",

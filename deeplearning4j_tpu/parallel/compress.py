@@ -58,6 +58,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from deeplearning4j_tpu.obs.owners import GRAD_COMPRESS
+
 __all__ = [
     "GradientCompression", "ThresholdCompression", "TopKCompression",
     "Int8Compression", "OneBitCompression", "enable_grad_compression",
@@ -143,7 +145,12 @@ class GradientCompression:
         """The in-step transform: error-feedback encode/decode over the
         gradient pytree. Returns ``(decoded_grads, new_state)``; traced
         into the train step, zero host syncs (trace_check-asserted in
-        tests/test_compress.py)."""
+        tests/test_compress.py). Its operations lie under the
+        ``grad.compress`` scope in every compressed step (obs/owners.py)."""
+        with jax.named_scope(GRAD_COMPRESS):
+            return self._apply(grads, state)
+
+    def _apply(self, grads, state):
         ctrl = state["ctrl"]
         acc = state["acc"]
         leaves, treedef = jax.tree_util.tree_flatten(grads)

@@ -43,7 +43,6 @@ from deeplearning4j_tpu.perf.compile_cache import (  # noqa: F401
 from deeplearning4j_tpu.perf.compile_watch import (  # noqa: F401
     GLOBAL as GLOBAL_COMPILE_WATCH,
     CompileWatch,
-    backend_compile_events,
 )
 from deeplearning4j_tpu.perf.fusion import (  # noqa: F401
     REMAT_POLICIES,

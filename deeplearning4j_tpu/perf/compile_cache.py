@@ -11,8 +11,9 @@ beyond lease-gated warmup.
 thresholds are dropped to zero so even the small CPU-test programs cache
 (the default config skips sub-second compiles, which on TPU is fine but
 would make the cold-start test meaningless). Cache *hits* are observable
-via :func:`cache_hits`, fed by a ``jax.monitoring`` event listener —
-that is what the cold-start test asserts on.
+via :func:`cache_hits`, read from the process's one ``jax.monitoring``
+listener (``perf/compile_watch.py``) — that is what the cold-start test
+asserts on.
 
 Where the cache lives is decided HERE and nowhere else, so it can be
 placed from outside: ``JAX_COMPILATION_CACHE_DIR``, when set, is the
@@ -29,6 +30,8 @@ import os
 import threading
 from typing import Optional
 
+from deeplearning4j_tpu.perf.compile_watch import cache_hits, install_listener
+
 log = logging.getLogger(__name__)
 
 __all__ = ["enable_compilation_cache", "cache_hits", "cache_dir",
@@ -41,15 +44,6 @@ DEFAULT_CACHE_DIR = os.path.join(
 
 _lock = threading.Lock()
 _dir: Optional[str] = None
-_hits = 0
-_listener_installed = False
-
-
-def _on_event(name: str, **kwargs):
-    global _hits
-    if name == "/jax/compilation_cache/cache_hits":
-        with _lock:
-            _hits += 1
 
 
 def resolve_cache_dir(directory=None) -> str:
@@ -71,7 +65,7 @@ def enable_compilation_cache(directory=None, *,
     Process-global; calling again with the same directory is a no-op,
     with a different one re-points the cache and logs. Returns the
     directory in use."""
-    global _dir, _listener_installed
+    global _dir
     import jax
 
     directory = resolve_cache_dir(directory)
@@ -89,18 +83,9 @@ def enable_compilation_cache(directory=None, *,
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     with _lock:
         _dir = directory
-        if not _listener_installed:
-            jax.monitoring.register_event_listener(_on_event)
-            _listener_installed = True
+    install_listener()
     log.info("persistent compilation cache enabled at %s", directory)
     return directory
-
-
-def cache_hits() -> int:
-    """Number of persistent-cache hits observed this process (compiles
-    answered from disk instead of XLA)."""
-    with _lock:
-        return _hits
 
 
 def cache_dir() -> Optional[str]:

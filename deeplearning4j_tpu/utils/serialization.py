@@ -64,6 +64,13 @@ def _save_npz_bytes(arrays: dict) -> bytes:
     return buf.getvalue()
 
 
+# Counters that a layer's non-trained ``state`` gained after checkpoints had
+# been written without them (by the leaf's own name): such a checkpoint
+# restores with the leaf as the fresh model drew it, a counter at 0. Never a
+# trained leaf.
+_STATE_LEAVES_ADDED_LATER = ("steps_every_window",)
+
+
 def _restore_into(tree, arrays: dict):
     """Rebuild a pytree with the same structure, leaves taken from arrays."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
@@ -71,6 +78,9 @@ def _restore_into(tree, arrays: dict):
     for path, leaf in flat:
         key = _path_key(path)
         if key not in arrays:
+            if key.rpartition("/")[2] in _STATE_LEAVES_ADDED_LATER:
+                leaves.append(leaf)
+                continue
             raise ValueError(f"Missing array '{key}' in checkpoint")
         saved = arrays[key]
         if tuple(saved.shape) != tuple(np.shape(leaf)):

@@ -35,3 +35,37 @@ def devices():
     d = jax.devices()
     assert len(d) == 8, f"expected 8 virtual CPU devices, got {d}"
     return d
+
+
+@pytest.fixture
+def step_op_names():
+    """``step_op_names(net, *batch, extra=())``: the ``op_name`` of every
+    instruction jax emitted into ``net``'s COMPILED train step (a name stack
+    starts with ``jit(`` or, inside a loop's body, with a layer's marker; a
+    reducer's body and a parameter carry other names). ``batch`` is what
+    follows the rng in the step's arguments (arrays or shape structs),
+    ``extra`` what stands between ``opt_state`` and the rng (a compressed
+    step's state). Compiled with the persistent cache OFF: its key leaves
+    metadata out (``jax_compilation_cache_include_metadata_in_key`` is
+    False), so another tree's executable would bring that tree's names, and
+    a benchmark test earlier in the process may have switched the cache on."""
+    import re
+
+    def struct(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+    def names(net, *batch, extra=()):
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
+            text = net._get_jitted("train").lower(
+                struct(net.params), struct(net.state), struct(net.opt_state),
+                *(struct(e) for e in extra), struct(net._rng), *batch, None,
+                None).compile().as_text()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+        return [o for o in re.findall(r'op_name="([^"]*)"', text)
+                if o.startswith("jit(") or re.match(r"[A-Za-z_]\w*:", o)]
+
+    return names

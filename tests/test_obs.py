@@ -719,6 +719,287 @@ class TestDeviceSideNames:
             assert losses() == scoped
 
 
+# ================================================ every operation has an owner
+class TestOwners:
+    @pytest.mark.parametrize("op_name,owner", [
+        ("jit(train_step)/jvp(RoutedExperts:l2_ffn)/cond/branch_0_fun/"
+         "moe.dispatch/gather", "RoutedExperts"),
+        # jvp(...) / transpose(...) wrappers, the backward pass
+        ("jit(train_step)/transpose(jvp(KimiDeltaAttention:l1_attn))/"
+         "jvp(KimiDeltaAttention:l1_attn)/checkpoint/kda.conv/add_any",
+         "KimiDeltaAttention"),
+        # nested layers inside loop.body: the innermost marker
+        ("jit(train_step)/jvp(LoopVertex:loop)/loop.body/while/body/"
+         "LoopVertex:l0_attn/RotaryAttention:attn/rattn.attend/dot_general",
+         "RotaryAttention"),
+        ("LoopVertex:l3_ffn/ElementWiseVertex:add2/add", "ElementWiseVertex"),
+        ("jit(train_step)/jvp(LoopVertex:loop)/loop.body/while",
+         "LoopVertex"),
+        # a layer name with dots, a stack's layer named by its index
+        ("jit(train_step)/transpose(jvp(DenseLayer:block.0.dense))/"
+         "dot_general", "DenseLayer"),
+        ("jit(train_step)/jvp(OutputLayer:1)/dot_general", "OutputLayer"),
+        # the scopes outside any layer
+        ("jit(train_step)/optim.update/add", "optim"),
+        ("jit(train_step)/optim.update/jit(_where)/select_n", "optim"),
+        ("jit(train_step)/grad.compress/sign", "grad.compress"),
+        ("jit(train_step)/jvp(params.cast)/convert_element_type",
+         "params.cast"),
+        ("jit(train_step)/transpose(jvp(params.cast))/convert_element_type",
+         "params.cast"),
+        ("jit(train_step)/jvp(loss.score)/loss.blocked/while", "loss"),
+        ("jit(train_step)/transpose(jvp(loss.penalty))/mul", "loss"),
+        ("jit(train_step)/jvp(loop.exit_head)/while/body/dot_general",
+         "loss"),
+        # a layer's marker wins over a scope around or inside it
+        ("jit(train_step)/jvp(loss.score)/CenterLossOutputLayer:out/sub",
+         "CenterLossOutputLayer"),
+        # nobody's
+        ("jit(train_step)/add", None),
+        ("jit(train_step)/jit(searchsorted)/vmap()/while/body/gather", None),
+        ("params['l4_attn']['Wq']", None), ("", None), (None, None)])
+    def test_owner_of(self, op_name, owner):
+        from deeplearning4j_tpu.obs.owners import owner_of
+        assert owner_of(op_name) == owner
+
+    def test_the_scopes_outside_the_layers_are_one_list(self):
+        """Every constant the program opens a scope with is in
+        ``NON_LAYER_SCOPES``: ``owner_of`` is the only rule."""
+        from deeplearning4j_tpu.obs import owners
+        for scope in (owners.OPTIM, owners.GRAD_COMPRESS, owners.PARAMS_CAST,
+                      owners.LOSS_SCORE, owners.LOSS_PENALTY):
+            assert owners.owner_of(f"jit(train_step)/{scope}/mul") is not None
+        assert owners.layer_marker(DenseLayer(n_out=2), "d.1") \
+            == "DenseLayer:d.1"
+
+    @pytest.mark.parametrize("make", ["stack", "graph"])
+    def test_the_compiled_step_names_an_owner_for_all_it_emitted(
+            self, make, step_op_names):
+        """Both network classes: the step holds instructions under
+        ``optim.update``, and of the instructions jax emitted NONE is
+        without an owner (no primitive is excused): the optimizer, the
+        loss, a graph's merge vertex, an output layer's own product."""
+        import collections
+        import jax.numpy as jnp
+        from deeplearning4j_tpu.obs.owners import owner_of
+        ds = toy_batches(1)[0]
+        x, y = jnp.asarray(ds.features), jnp.asarray(ds.labels)
+        if make == "stack":
+            names = step_op_names(small_net(), x, y)
+        else:
+            names = step_op_names(small_graph(), [x], [y])
+        assert any("/optim.update/" in n for n in names)
+        owners = collections.Counter(owner_of(n) for n in names)
+        unowned = sorted({n.rsplit("/", 1)[-1] for n in names
+                          if owner_of(n) is None})
+        assert unowned == [], unowned
+        assert {"optim", "loss", "DenseLayer", "OutputLayer"} <= set(owners)
+        if make == "graph":
+            assert owners["MergeVertex"] > 0
+
+    def test_a_bfloat16_step_owns_its_cast_and_a_penalty_its_sum(
+            self, step_op_names):
+        import jax.numpy as jnp
+        from deeplearning4j_tpu.obs.owners import owner_of
+        conf = (NeuralNetConfiguration.builder().seed(3)
+                .updater(Sgd(learning_rate=0.05)).weight_init("xavier")
+                .l2(1e-3).dtype("bfloat16").list()
+                .layer(DenseLayer(n_out=8, activation="relu"))
+                .layer(OutputLayer(n_out=3, loss="mcxent"))
+                .set_input_type(InputType.feed_forward(4)).build())
+        ds = toy_batches(1)[0]
+        names = step_op_names(MultiLayerNetwork(conf).init(),
+                              jnp.asarray(ds.features),
+                              jnp.asarray(ds.labels))
+        assert any("params.cast" in n and "transpose(" not in n
+                   for n in names)
+        assert any("loss.penalty" in n for n in names)
+        assert [n for n in names if owner_of(n) is None] == []
+
+    def test_a_compressed_step_owns_its_encode_and_decode(
+            self, step_op_names):
+        import jax.numpy as jnp
+        from deeplearning4j_tpu.obs.owners import owner_of
+        from deeplearning4j_tpu.parallel.compress import (
+            ThresholdCompression, enable_grad_compression,
+            ensure_compress_state)
+        net = small_net()
+        enable_grad_compression(net, ThresholdCompression())
+        ensure_compress_state(net)
+        ds = toy_batches(1)[0]
+        names = step_op_names(net, jnp.asarray(ds.features),
+                              jnp.asarray(ds.labels),
+                              extra=(net.compress_state,))
+        assert {"grad.compress", "optim"} <= {owner_of(n) for n in names}
+        assert [n for n in names if owner_of(n) is None] == []
+
+    def test_the_lowered_text_names_the_scopes_without_a_compile(self):
+        """What a scope test can read with no executable at all:
+        ``lowered.as_text(debug_info=True)`` is never cached."""
+        import jax.numpy as jnp
+        net = small_graph()
+        ds = toy_batches(1)[0]
+        lowered = net._get_jitted("train").lower(
+            net.params, net.state, net.opt_state, net._rng,
+            [jnp.asarray(ds.features)], [jnp.asarray(ds.labels)], None, None)
+        text = lowered.as_text(debug_info=True)
+        for scope in ("optim.update", "loss.score", "MergeVertex:merge",
+                      "OutputLayer:out", "DenseLayer:d1"):
+            assert scope in text, scope
+
+
+# ================================================ what a compile's time went on
+class TestCompilePhases:
+    def test_the_compile_event_says_what_the_time_went_on(self):
+        """The first call of a program: a ``compile`` event with the
+        seconds of tracing, lowering and the backend, and whether the
+        persistent cache served it; the same on the enclosing dispatch.
+        The second call records nothing."""
+        net = small_net()
+        data = toy_batches(2)
+        sink = _traced(lambda: net.fit(data))
+        compiles = [s for s in sink if s["name"] == "compile"]
+        assert len(compiles) == 1
+        attrs = compiles[0]["attrs"]
+        assert {"trace_s", "lower_s", "backend_s", "cache_load_s",
+                "cache_hit", "program", "step"} <= set(attrs)
+        assert attrs["trace_s"] > 0 and attrs["lower_s"] > 0 \
+            and attrs["backend_s"] > 0
+        assert attrs["cache_hit"] in (0, 1)
+        assert (attrs["cache_load_s"] > 0) == bool(attrs["cache_hit"])
+        first, second = [s for s in sink if s["name"] == "train.dispatch"]
+        assert first["attrs"]["compiled"] == 1
+        for key in ("trace_s", "lower_s", "backend_s", "cache_load_s",
+                    "cache_hit"):
+            assert first["attrs"][key] == attrs[key]
+            assert key not in second["attrs"]
+        phases = net.compile_watch.compile_phases("train")
+        assert phases["trace_s"] == attrs["trace_s"]
+        net.fit(data)                           # warm: nothing more
+        assert net.compile_watch.compile_phases("train") == phases
+
+    def test_a_jit_traced_inside_a_jit_is_counted_once(self):
+        """The inner function's trace event ends inside the outer one's:
+        the call's ``trace_s`` is the outermost event's seconds."""
+        from deeplearning4j_tpu.perf import compile_watch as cw
+        call = cw._CompilePhases()
+        t0 = time.perf_counter()
+        while time.perf_counter() - t0 < 0.02:
+            pass
+        call.add_seconds("trace_s", 0.005)      # inner, ended just now
+        call.add_seconds("trace_s", 0.019)      # outer, holds it
+        call.add_seconds("lower_s", 0.5)
+        call.count("cache_hits")
+        assert call.totals() == {"trace_s": 0.019, "lower_s": 0.5,
+                                 "cache_hits": 1}
+        # two in a row (not nested) add up
+        again = cw._CompilePhases()
+        again.add_seconds("backend_s", 0.0)
+        again.add_seconds("backend_s", 0.0)
+        assert len(again._spans["backend_s"]) == 2
+
+    def test_thousands_of_events_in_a_call_cost_no_scan_each(self):
+        """Tracing a step fires a trace event for every ``jnp`` call (each
+        is a jit): 11,000 in ResNet50's step. Filing one looks at the tail
+        of what was heard, never at all of it (a scan each cost 2 s of the
+        cell's set-up), and the event that holds them all replaces them."""
+        from deeplearning4j_tpu.perf import compile_watch as cw
+        call = cw._CompilePhases()
+        t0 = time.perf_counter()
+        for _ in range(200_000):
+            call.add_seconds("trace_s", 0.0)
+        spent = time.perf_counter() - t0
+        assert len(call._spans["trace_s"]) == 200_000
+        assert spent < 5.0, spent           # a scan each: tens of minutes
+        call.add_seconds("trace_s", spent + 1.0)
+        assert call.totals() == {"trace_s": spent + 1.0}
+
+    def test_one_listener_for_the_process_and_cache_hits_reads_it(self):
+        import jax
+        from jax._src import monitoring
+        from deeplearning4j_tpu.perf import (cache_hits, compile_cache,
+                                             compile_watch as cw)
+        cw.install_listener()
+        cw.install_listener()
+        assert monitoring.get_event_duration_listeners().count(
+            cw._on_duration) == 1
+        assert monitoring.get_event_listeners().count(cw._on_event) == 1
+        assert compile_cache.cache_hits is cw.cache_hits is cache_hits
+        assert not hasattr(cw, "backend_compile_events")
+        before = cache_hits()
+        unwatched = cw.GLOBAL.compile_phases(cw.UNWATCHED)
+        jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+        jax.monitoring.record_event_duration_secs(
+            "/jax/core/compile/backend_compile_duration", 1.25)
+        # an event that is no compile's is not taken for one
+        jax.monitoring.record_event(
+            "/jax/compilation_cache/compile_requests_use_cache")
+        assert cache_hits() == before + 1
+        after = cw.GLOBAL.compile_phases(cw.UNWATCHED)
+        assert after["backend_s"] == pytest.approx(
+            unwatched.get("backend_s", 0.0) + 1.25)
+        assert set(after) <= set(cw.PHASES.values()) | {
+            "cache_hits", "cache_misses"}
+
+    def test_the_scrape_has_each_program_s_phases(self):
+        from deeplearning4j_tpu.perf.compile_watch import CompileWatch
+        watch = CompileWatch("t")
+        watch._record_phases("train", {"trace_s": 2.0, "lower_s": 0.5,
+                                       "backend_s": 7.0, "cache_misses": 1})
+        watch._record_phases("train", {"trace_s": 1.0})
+        reg = obs.MetricsRegistry()
+        obs.absorb_compile_watch(reg, watch)
+        got = reg.as_dict()
+        assert got["jit_compile_trace_s_train"]["value"] == 3.0
+        assert got["jit_compile_backend_s_train"]["unit"] == "s"
+        assert got["jit_compile_cache_misses_train"]["value"] == 1.0
+        assert "jit_compile_trace_s_train" in obs.prometheus_text(reg)
+
+
+class TestStepHistogram:
+    @pytest.mark.parametrize("step_ms", [45.0, 310.0, 1109.0])
+    def test_a_step_s_p95_is_read_within_a_bucket(self, step_ms):
+        """``train_iteration_ms`` has a step's ladder (12% a bucket, 1 ms
+        to 10 s): 100 turns of which the slowest ten take 1.3 times the
+        usual read a p95 within 12% of the sample's; the other spans keep
+        the default ladder, which cannot tell 45 ms from 50."""
+        from deeplearning4j_tpu.obs.registry import (DEFAULT_BUCKETS_MS,
+                                                     STEP_BUCKETS_MS)
+        clock = iter(float(i) for i in range(10 ** 6))
+        durations = [step_ms] * 90 + [1.3 * step_ms] * 10
+        reg = obs.MetricsRegistry()
+        tracer = obs.Tracer(enabled=True, registry=reg,
+                            clock=lambda: next(clock))
+        for dur in durations:
+            for name in ("train.iteration", "train.step_host"):
+                span = tracer.span(name)
+                span.__enter__()
+                span.__exit__(None, None, None)
+        # an injected clock ticks whole seconds: observe the sample itself
+        turn = reg.metric("train_iteration_ms")
+        assert turn.bounds == STEP_BUCKETS_MS
+        assert reg.metric("train_step_host_ms").bounds \
+            == tuple(float(b) for b in DEFAULT_BUCKETS_MS)
+        fresh = obs.MetricsRegistry().histogram(
+            "train_iteration_ms", unit="ms", help="a turn",
+            buckets=STEP_BUCKETS_MS)
+        for dur in durations:
+            fresh.observe(dur)
+        want = float(np.quantile(durations, 0.95))
+        assert abs(fresh.quantile(0.95) - want) <= 0.12 * want
+        assert abs(fresh.quantile(0.50) - step_ms) <= 0.12 * step_ms
+        ratios = [b / a for a, b in zip(STEP_BUCKETS_MS, STEP_BUCKETS_MS[1:])]
+        assert max(ratios) < 1.1201 and STEP_BUCKETS_MS[0] == 1.0 \
+            and STEP_BUCKETS_MS[-1] >= 10000.0
+
+    def test_observe_files_a_value_under_the_first_bound_that_holds_it(self):
+        h = obs.MetricsRegistry().histogram("h_ms", unit="ms", help="h",
+                                            buckets=(1.0, 2.0, 4.0))
+        for v in (0.5, 1.0, 1.5, 4.0, 9.0):
+            h.observe(v)
+        assert h.bucket_counts() == [2, 1, 1, 1]
+
+
 # ============================================================ serving + ckpt
 class TestInstrumentedSurfaces:
     def test_parallel_inference_metrics(self):

@@ -868,6 +868,33 @@ def test_both_buffer_tiers_give_the_masked_loop(push):
             1.0, float(jnp.max(jnp.abs(b))))
 
 
+@pytest.mark.parametrize("push,took", [(0.0, 0), (10.0, 1)],
+                         ids=["under_the_window", "over_the_window"])
+def test_the_layer_counts_the_steps_that_took_every_window(push, took):
+    """``steps_every_window`` reads 0 for a step whose held pairs fit the
+    first window (384 slots here) and 1 for one whose pairs pass it (1,200
+    on a router pushed onto the held experts), step after step; a layer
+    whose one window is the worst case has no second tier to count."""
+    layer = _experts(held=2, offset=4, shared=0, total=32)
+    it = InputType.recurrent(12, 300)
+    params, state = layer.init(jax.random.key(0), it)
+    assert state["steps_every_window"].dtype == jnp.int32 \
+        and state["steps_every_window"].shape == ()
+    state = dict(state, bias=jnp.zeros(32).at[4:6].set(push))
+    x = jax.random.normal(jax.random.key(1), (2, 300, 12))
+    _, new = layer.apply(params, state, x)
+    assert (int(new["pairs_held"]) > 384) == bool(took)
+    assert int(new["steps_every_window"]) == took
+    _, again = jax.jit(layer.apply)(params, new, x)
+    assert int(again["steps_every_window"]) == 2 * took
+    # all experts held: one window of tokens x top_k slots, nothing to count
+    whole = _experts(held=8, shared=0)
+    p, st = whole.init(jax.random.key(0), InputType.recurrent(12, 24))
+    _, st = whole.apply(p, st, jax.random.normal(jax.random.key(1),
+                                                 (2, 24, 12)))
+    assert int(st["steps_every_window"]) == 0 and int(st["pairs_held"]) == 96
+
+
 def test_the_dropped_counter_sees_a_window_that_did_not_run(monkeypatch):
     """``pairs_dropped`` is held pairs less the rows that the windows which
     ran gave to the grouped products, not arithmetic that is 0 whatever
@@ -1020,12 +1047,12 @@ def test_the_counters_cost_a_turn_of_fit_no_device_program_and_no_sync():
         m = obs.get_registry().metric(name)
         return 0.0 if m is None else m.value
 
-    def on_device():
-        return int([s for s in net.state if "expert_tokens" in s][0][
-            "pairs_held"])
+    def on_device(what="pairs_held"):
+        return int([s for s in net.state if "expert_tokens" in s][0][what])
 
     obs.get_registry().collect()
-    device0 = on_device()
+    device0, tier0 = on_device(), on_device("steps_every_window")
+    every0 = value("moe_every_window_steps_total")
     held0, by_key0 = value("moe_tokens_held_total"), {
         k: v["dispatches"] for k, v in GLOBAL.as_dict()["by_key"].items()}
     with jax.transfer_guard_device_to_host("disallow"):
@@ -1037,13 +1064,167 @@ def test_the_counters_cost_a_turn_of_fit_no_device_program_and_no_sync():
              if v != by_key0.get(k, 0)}
     assert moved == {"train": 3}
     assert value("moe_tokens_held_total") == held0      # nothing pushed
+    assert value("moe_every_window_steps_total") == every0
     obs.get_registry().collect()
     assert on_device() > device0
     assert value("moe_tokens_held_total") - held0 == on_device() - device0
+    # the tier counter rides the same way: half the experts of 8 held and a
+    # window of four times the even share is the worst case, so one window
+    assert value("moe_every_window_steps_total") - every0 \
+        == on_device("steps_every_window") - tier0 == 0
     assert value("moe_dropped_tokens_total") == 0.0
     gauges = [n for n in obs.get_registry().names()
               if n.startswith("moe_expert_tokens_")]
     assert len(gauges) >= 4
+
+
+def _routed_graph(ffn):
+    """ids (2 x 300 of 30) -> embedding -> ``ffn`` -> blocked token loss."""
+    from deeplearning4j_tpu.nn.conf.graph import GraphBuilder
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+    g = GraphBuilder()
+    g.add_inputs("ids")
+    g.add_layer("embed", EmbeddingSequenceLayer(n_in=30, n_out=12), "ids")
+    g.add_layer("ffn", ffn, "embed")
+    g.add_layer("head", TokenOutputLayer(n_out=30, time_block=16), "ffn")
+    g.set_outputs("head")
+    g.set_input_types(InputType.recurrent(30, 300))
+    return ComputationGraph(dataclasses.replace(
+        g.build(), updater=Adam(1e-3))).init()
+
+
+def test_a_scrape_publishes_the_steps_that_took_every_window():
+    """``moe_every_window_steps_total`` is the layers' ``steps_every_window``
+    summed, moved at scrape time only: three steps of a network whose routed
+    layer is pushed past its first window move it by three."""
+    net = _routed_graph(_experts(held=2, offset=4, shared=0, total=32))
+    net.state = dict(net.state, ffn=dict(
+        net.state["ffn"], bias=jnp.zeros(32).at[4:6].set(10.0)))
+    reg = obs.MetricsRegistry()
+    obs.watch_moe(reg, net)
+    reg.collect()
+    before = reg.metric("moe_every_window_steps_total").value
+    ids = np.random.default_rng(1).integers(0, 30, (2, 301)).astype(np.int32)
+    ds = DataSet(ids[:, :-1], ids[:, 1:])
+    for _ in range(3):
+        net.fit(ds)
+    assert reg.metric("moe_every_window_steps_total").value == before
+    reg.collect()
+    assert reg.metric("moe_every_window_steps_total").value - before == 3.0
+    assert reg.metric("moe_dropped_tokens_total").value == 0.0
+    assert int(net.state["ffn"]["steps_every_window"]) == 3
+
+
+def test_a_checkpoint_from_before_the_tier_counter_restores_with_it_at_zero(
+        tmp_path):
+    """A zip whose coefficients lack ``steps_every_window`` (written before
+    the layer counted the tier) restores: every leaf it holds comes back,
+    the counter reads 0. A TRAINED leaf that is missing is still refused."""
+    import io
+    import zipfile
+    from deeplearning4j_tpu.utils.serialization import (restore,
+                                                        write_model)
+    net = MultiLayerNetwork(_mln()).init()
+    ids = np.random.default_rng(1).integers(0, 30, (2, 33)).astype(np.int32)
+    net.fit(DataSet(ids[:, :-1], ids[:, 1:]))
+    new, old, broken = (str(tmp_path / n) for n in ("new.zip", "old.zip",
+                                                    "broken.zip"))
+    write_model(net, new)
+
+    def rewrite(path, drop):
+        with zipfile.ZipFile(new) as src, zipfile.ZipFile(path, "w") as dst:
+            for item in src.namelist():
+                data = src.read(item)
+                if item == "coefficients.npz":
+                    arrays = dict(np.load(io.BytesIO(data)))
+                    gone = [k for k in arrays if k.endswith(drop)]
+                    assert len(gone) == 1, gone
+                    del arrays[gone[0]]
+                    buf = io.BytesIO()
+                    np.savez(buf, **arrays)
+                    data = buf.getvalue()
+                dst.writestr(item, data)
+
+    rewrite(old, "/steps_every_window")
+    back = restore(old)
+    experts = [s for s in back.state if "expert_tokens" in s][0]
+    assert int(experts["steps_every_window"]) == 0
+    assert int(experts["pairs_held"]) == int(
+        [s for s in net.state if "expert_tokens" in s][0]["pairs_held"]) > 0
+    for a, b in zip(jax.tree_util.tree_leaves(back.params),
+                    jax.tree_util.tree_leaves(net.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    back.fit(DataSet(ids[:, :-1], ids[:, 1:]))          # and trains on
+    rewrite(broken, "/Wr")
+    with pytest.raises(ValueError, match="Missing array"):
+        restore(broken)
+
+
+def _owners(names):
+    """(owners counted, the unowned instructions' primitives)."""
+    import collections
+    from deeplearning4j_tpu.obs.owners import owner_of
+    return collections.Counter(owner_of(n) for n in names), sorted(
+        {n.rsplit("/", 1)[-1] for n in names if owner_of(n) is None})
+
+
+def test_a_routed_graph_s_step_has_an_owner_for_all_it_emitted(
+        step_op_names):
+    """The optimizer's instructions lie under ``optim.update``; what jax
+    emitted without any owner is megablox's group bookkeeping alone (none
+    on the CPU, where ``lax.ragged_dot`` runs)."""
+    net = _routed_graph(RoutedExperts(
+        n_experts=32, experts_held=2, expert_offset=4, top_k=2,
+        expert_size=8, shared_size=8, remat="full"))
+    x = jax.ShapeDtypeStruct((2, 300), jnp.int32)
+    names = step_op_names(net, [x], [x])
+    owners, unowned = _owners(names)
+    assert any("/optim.update/" in n for n in names)
+    assert unowned == [], unowned
+    assert {"optim", "loss", "RoutedExperts", "EmbeddingSequenceLayer"} \
+        <= set(owners)
+    # both tiers of the window are the layer's
+    assert any("RoutedExperts:ffn" in n and "branch_1_fun" in n
+               for n in names)
+
+
+def test_a_looped_graph_s_step_has_an_owner_for_all_it_emitted(
+        step_op_names):
+    """Inside the scan's body too: the residual adds are their vertices',
+    the exits the loss's."""
+    from deeplearning4j_tpu.nn.conf.attention import RotaryAttention
+    from deeplearning4j_tpu.nn.conf.graph import (ElementWiseVertex,
+                                                  GraphBuilder, LoopVertex)
+    from deeplearning4j_tpu.nn.conf.recurrent import (
+        ExitWeightedTokenOutputLayer)
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+    b = GraphBuilder()
+    b.add_inputs("h")
+    b.add_layer("n1", RMSNorm(), "h")
+    b.add_layer("attn", RotaryAttention(n_heads=2, head_dim=8, block=8),
+                "n1")
+    b.add_vertex("add1", ElementWiseVertex("add"), "h", "attn")
+    b.add_layer("ffn", GatedFeedForward(ff_size=32), "add1")
+    b.add_vertex("add2", ElementWiseVertex("add"), "add1", "ffn")
+    b.set_outputs("add2")
+    b.set_input_types(InputType.recurrent(16, 24))
+    g = GraphBuilder()
+    g.add_inputs("ids")
+    g.add_layer("embed", EmbeddingSequenceLayer(n_in=11, n_out=16), "ids")
+    g.add_layer("loop", LoopVertex(body=b.build(), steps=3), "embed")
+    g.add_layer("head", ExitWeightedTokenOutputLayer(
+        n_out=11, time_block=8, entropy_weight=0.05), "loop")
+    g.set_outputs("head")
+    g.set_input_types(InputType.recurrent(11, 24))
+    net = ComputationGraph(dataclasses.replace(
+        g.build(), updater=Adam(1e-2))).init()
+    x = jax.ShapeDtypeStruct((2, 24), jnp.int32)
+    names = step_op_names(net, [x], [x])
+    owners, unowned = _owners(names)
+    assert any("/optim.update/" in n for n in names)
+    assert unowned == [], unowned
+    assert {"optim", "loss", "LoopVertex", "RotaryAttention", "RMSNorm",
+            "GatedFeedForward", "ElementWiseVertex"} <= set(owners)
 
 
 # ------------------------------------------------ the Qwen3-Next family's side
