@@ -190,17 +190,27 @@ def apply_layer(layer, params, state, x, *, train, rng, mask, name,
     """The networks' single entry into ``layer.apply``: lowers the layer
     through ``jax.checkpoint`` when its ``remat=`` knob is set (policy names
     in perf/fusion.py), so the backward pass recomputes instead of saving
-    what the policy excludes. ``extra`` carries optional additional traced
-    inputs (the fused residual-add input in ComputationGraph). ``name`` is
-    the layer's name in its network (a graph's vertex name, an MLN's
-    index): every operation of the layer, forward and backward, carries
-    ``<LayerClass>:<name>`` in its ``op_name``, so a device trace can be
-    read by layer kind whatever the compiler calls its fusions."""
+    what the policy excludes. What the layer's TYPE names as dearer to
+    recompute than to keep (``remat_keeps``) is kept under every policy but
+    ``"nothing_saveable"``: ``"full"`` recomputes everything else, and
+    everything for a type that names nothing (``policy=None``, as ever).
+    Counted at trace time, once a layer application whose policy holds
+    names: ``remat.kept_values``. ``extra`` carries optional additional
+    traced inputs (the fused residual-add input in ComputationGraph).
+    ``name`` is the layer's name in its network (a graph's vertex name, an
+    MLN's index): every operation of the layer, forward and backward,
+    carries ``<LayerClass>:<name>`` in its ``op_name``, so a device trace
+    can be read by layer kind whatever the compiler calls its fusions."""
     extra = extra or {}
     with jax.named_scope(layer_marker(layer, name)):
         if getattr(layer, "remat", None):
-            from deeplearning4j_tpu.perf.fusion import remat_policy
-            policy = remat_policy(layer.remat)
+            from deeplearning4j_tpu.perf.compile_watch import bump_active
+            from deeplearning4j_tpu.perf.fusion import (
+                kept_names, remat_policy)
+            keeps = kept_names(layer)
+            policy = remat_policy(layer.remat, keeps)
+            if keeps:
+                bump_active("remat.kept_values")
 
             def run(p, s, xx, kk, mm, ee):
                 return layer.apply(p, s, xx, train=train, rng=kk, mask=mm,
@@ -244,10 +254,23 @@ class Layer:
     dropout: float = 0.0
     # per-layer rematerialization: lower this layer's apply through
     # jax.checkpoint with the named policy (perf/fusion.py REMAT_POLICIES:
-    # 'full' recomputes everything in the backward; 'dots_saveable' keeps
-    # matmul/conv outputs; ...). None = normal autodiff saving. Validated
-    # by analysis/validation.py; visible in conf.memory_report().
+    # 'full' recomputes everything in the backward but what the layer's
+    # type names (remat_keeps, below); 'dots_saveable' keeps matmul/conv
+    # outputs; 'nothing_saveable' keeps nothing at all; ...). None = normal
+    # autodiff saving. Validated by analysis/validation.py; visible in
+    # conf.memory_report().
     remat: Optional[str] = None
+
+    # what a rematerialised layer of this TYPE keeps all the same, as dearer
+    # to recompute than to hold: the ``checkpoint_name``s its computation
+    # gives them (``apply_layer`` joins them to the policy; ``"full"`` then
+    # keeps these alone, ``"nothing_saveable"`` nothing). Not a field: the
+    # type declares it, and ``remat_kept_bytes`` says what it costs.
+    remat_keeps = ()
+
+    def remat_kept_bytes(self, input_type: InputType) -> int:
+        """Bytes of ``remat_keeps`` for ONE example of ``input_type``."""
+        return 0
 
     # ---- shape inference ----
     def output_type(self, input_type: InputType) -> InputType:

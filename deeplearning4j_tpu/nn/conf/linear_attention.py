@@ -53,6 +53,14 @@ one backward of ``perf/pallas/kda_inputs.py`` that read the products'
 time, K) windows (``kda.kda_scan_heads_major``: no transpose of q, k, v, g
 in or of dq, dk, dv, dg out). Measured in the benchmark's two token cells:
 PERF.md §5-6, PR 31.
+
+Rematerialised (``remat=``), both layers keep what their scan's kernels
+name (``remat_keeps`` = ``kda.KEPT``: o and the chunks' entry states, float32,
+``remat_kept_bytes``), so the backward pass recomputes the projections and
+the input kernel and reads the scan's results: ``kda_scan_fwd`` once a layer
+and step (PERF.md §5-6, PR 36). ``remat="nothing_saveable"`` keeps nothing;
+the ``jax.numpy`` scan names nothing and checkpoints its own groups of
+chunks.
 """
 
 from __future__ import annotations
@@ -318,6 +326,11 @@ class KimiDeltaAttention(BaseLayer):
     weight_init: str = "xavier_fan_in"
 
     supports_stateful = False   # no rnn_time_step carry (yet)
+    remat_keeps = kda_kernels.KEPT
+
+    def remat_kept_bytes(self, it: InputType) -> int:
+        return kda_kernels.kept_bytes(it.timeseries_length or 1,
+                                      self.n_heads, self.head_dim, self.chunk)
 
     def input_kind(self):
         return "rnn"
@@ -451,6 +464,12 @@ class GatedDeltaNet(BaseLayer):
     weight_init: str = "xavier_fan_in"
 
     supports_stateful = False   # no rnn_time_step carry (yet)
+    remat_keeps = kda_kernels.KEPT
+
+    def remat_kept_bytes(self, it: InputType) -> int:
+        return kda_kernels.kept_bytes(it.timeseries_length or 1,
+                                      self.n_value_heads, self.head_dim,
+                                      self.chunk)
 
     def input_kind(self):
         return "rnn"

@@ -39,6 +39,10 @@ class LayerMemoryReport:
     activation_shape: tuple
     # the layer's remat= knob, when set (perf/fusion.py policies)
     remat: Optional[str] = None
+    # what the rematerialised layer keeps all the same, for ONE example
+    # (its type's ``remat_keeps``: the delta-rule scan's output and chunk
+    # states where the kernels run); counted into the activation total
+    remat_kept_bytes_per_example: int = 0
 
     def to_dict(self):
         return dataclasses.asdict(self)
@@ -89,7 +93,9 @@ class MemoryReport:
                 f"{lr.name:<28}{lr.layer_class:<26}{lr.num_params:>12,}"
                 f"{lr.param_bytes / 2**20:>10.2f}"
                 f"{lr.activation_bytes_per_example / 2**10:>11.1f}"
-                + (f"  remat={lr.remat}" if lr.remat else ""))
+                + (f"  remat={lr.remat}" if lr.remat else "")
+                + (f" keeps {lr.remat_kept_bytes_per_example / 2**10:.1f}"
+                   " KB/ex" if lr.remat_kept_bytes_per_example else ""))
         lines.append(
             f"Totals: params {self.total_param_bytes / 2**20:.2f} MB, "
             f"updater state {self.updater_state_bytes / 2**20:.2f} MB, "
@@ -132,6 +138,13 @@ def _type_shape(it) -> tuple:
     return (it.flat_size(),)
 
 
+def _kept_bytes(layer, it) -> int:
+    """Bytes one example adds to what ``layer`` holds from its forward to
+    its backward pass by the names its rematerialisation keeps."""
+    from deeplearning4j_tpu.perf.fusion import kept_names
+    return int(layer.remat_kept_bytes(it)) if kept_names(layer) else 0
+
+
 def _input_type_bytes(it, itemsize: int):
     shape = _type_shape(it)
     return int(np.prod(shape)) * itemsize, shape
@@ -153,6 +166,7 @@ def get_memory_report(net, minibatch: int = 32,
     for i, (layer, it) in enumerate(zip(net.layers, types)):
         out_t = layer.output_type(it)
         act_bytes, act_shape = _input_type_bytes(out_t, itemsize)
+        kept = _kept_bytes(layer, it)
         p_bytes = _tree_bytes(net.params[i])
         n_params = sum(a.size for a in jax.tree_util.tree_leaves(net.params[i]))
         reports.append(LayerMemoryReport(
@@ -162,8 +176,9 @@ def get_memory_report(net, minibatch: int = 32,
             param_bytes=int(p_bytes),
             activation_bytes_per_example=int(act_bytes),
             activation_shape=act_shape,
-            remat=getattr(layer, "remat", None)))
-        total_act += act_bytes * minibatch
+            remat=getattr(layer, "remat", None),
+            remat_kept_bytes_per_example=kept))
+        total_act += (act_bytes + kept) * minibatch
     compiled = None
     if compile_step:
         compiled = _compiled_step_stats(net, minibatch, types[0])
@@ -252,15 +267,17 @@ def conf_memory_report(conf, input_type=None, minibatch: int = 32,
         except ValueError:
             out_t = it
         act_bytes, act_shape = _input_type_bytes(out_t, itemsize)
+        kept = _kept_bytes(layer, it)
         reports.append(LayerMemoryReport(
             name=name, layer_class=type(layer).__name__,
             num_params=n_params, param_bytes=p_bytes,
             activation_bytes_per_example=int(act_bytes),
             activation_shape=act_shape,
-            remat=getattr(layer, "remat", None)))
+            remat=getattr(layer, "remat", None),
+            remat_kept_bytes_per_example=kept))
         if type(layer).__name__ == "FusedConvBNActivation":
             fused_blocks += 1
-        total_act += act_bytes * minibatch
+        total_act += (act_bytes + kept) * minibatch
         total_params += p_bytes
         if n_params:
             opt = jax.eval_shape(upd.to_optax().init, p_abs)

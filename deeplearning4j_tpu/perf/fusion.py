@@ -23,10 +23,16 @@ attacks exactly that traffic, three ways:
   ``ZooModel.init(fold_bn=True)``) contain no BN at all; exact within fp
   tolerance.
 
-- ``remat_policy(name)`` + the per-layer ``remat=`` config knob — lowers a
-  layer's apply through ``jax.checkpoint`` with a selectable policy
-  (gradient checkpointing, Chen et al. 2016), trading recompute FLOPs for
-  saved-activation HBM.
+- ``remat_policy(name, keeps)`` + the per-layer ``remat=`` config knob —
+  lowers a layer's apply through ``jax.checkpoint`` with a selectable
+  policy (gradient checkpointing, Chen et al. 2016), trading recompute
+  FLOPs for saved-activation HBM. ``"full"`` means "recompute everything
+  but what the layer TYPE names as dearer to recompute than to keep"
+  (``Layer.remat_keeps``: ``jax.ad_checkpoint.checkpoint_name``s, e.g. the
+  delta-rule scan's output and chunk states, perf/pallas/kda.py); a layer
+  that names nothing recomputes everything, as before. The saving policies
+  keep what they keep and the names too; ``"nothing_saveable"`` keeps
+  nothing, names included: the way back for a run short of memory.
 
 Observability: ``training_activation_bytes(conf)`` measures the actual
 forward→backward residual set from the jaxpr of ``jax.vjp`` of the REAL
@@ -40,6 +46,7 @@ CompileWatch (``fusion.fused_block``), surfaced by
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -59,8 +66,8 @@ from deeplearning4j_tpu.nn.conf.network import MultiLayerConfiguration
 from deeplearning4j_tpu.nn.conf.normalization import BatchNormalization
 
 __all__ = [
-    "REMAT_POLICIES", "remat_policy", "fuse", "fuse_network", "fold_bn",
-    "training_activation_bytes",
+    "REMAT_POLICIES", "remat_policy", "kept_names", "fuse", "fuse_network",
+    "fold_bn", "training_activation_bytes",
 ]
 
 
@@ -76,17 +83,38 @@ REMAT_POLICIES = {
 }
 
 
-def remat_policy(name: str):
+@functools.lru_cache(maxsize=None)
+def remat_policy(name: str, keeps: Tuple[str, ...] = ()):
     """Resolve a ``remat=`` knob value to a jax.checkpoint policy callable
-    (or None for full recompute). Raises ValueError on unknown names — the
-    same check analysis/validation.py runs ahead of any trace."""
+    (or None for full recompute). ``keeps`` are the ``checkpoint_name``s
+    the layer holds across rematerialisation (``kept_names``): joined to
+    the named policy, so ``"full"`` saves them alone. One policy object a
+    (name, keeps): layers that share a policy share their lowered
+    functions. Raises ValueError on unknown names — the same check
+    analysis/validation.py runs ahead of any trace."""
     try:
         attr = REMAT_POLICIES[str(name)]
     except KeyError:
         raise ValueError(
             f"Unknown remat policy '{name}' "
             f"(known: {sorted(REMAT_POLICIES)})") from None
-    return None if attr is None else getattr(jax.checkpoint_policies, attr)
+    policies = jax.checkpoint_policies
+    base = None if attr is None else getattr(policies, attr)
+    if not keeps:
+        return base
+    named = policies.save_only_these_names(*keeps)
+    return named if base is None else policies.save_from_both_policies(
+        base, named)
+
+
+def kept_names(layer) -> Tuple[str, ...]:
+    """The ``checkpoint_name``s that ``layer``'s rematerialisation keeps:
+    what its type declares (``remat_keeps``) under every ``remat`` but None
+    (nothing is rematerialised) and ``"nothing_saveable"`` (which keeps
+    nothing, by its name)."""
+    if getattr(layer, "remat", None) in (None, "nothing_saveable"):
+        return ()
+    return tuple(getattr(layer, "remat_keeps", ()))
 
 
 # ----------------------------------------------------------------- helpers

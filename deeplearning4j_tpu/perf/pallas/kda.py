@@ -47,6 +47,17 @@ entry state, dO and the dS that arrives: one kernel recomputes the chunk's
 terms in VMEM and emits dq, dk, dv, dg, db, each piece written by hand
 and held to ``jax.vjp`` of the ``jax.numpy`` form in
 tests/test_zz_pallas.py.
+
+Under a layer's rematerialisation (``apply_layer``, ``remat=``) the forward
+kernel's two outputs, o (heads-major, as the kernel wrote it: its transpose
+fuses into whoever reads it, in both passes; named after the transpose it
+became four copies a step on the chip) and the chunks' entry states, carry
+the ``checkpoint_name``s of ``KEPT``: the delta-rule layers declare them
+(``remat_keeps``), so the step runs ``kda_scan_fwd`` once a layer and the
+backward pass reads what the first pass wrote, in float32 as it was made
+(``kept_bytes``: 48 KB a token at 32 heads of 128), where recomputing them
+ran the kernel a second time. q, k, v, g, b are residuals too and are not
+named: they are recomputed (the projections and ``kda_inputs``).
 """
 
 from __future__ import annotations
@@ -56,12 +67,18 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from deeplearning4j_tpu.perf import pallas as _pk
 
-__all__ = ["supported", "kda_scan", "kda_scan_heads_major"]
+__all__ = ["supported", "kda_scan", "kda_scan_heads_major", "KEPT",
+           "kept_bytes"]
 
 CHUNK, SUB = 64, 8
+# the forward kernel's outputs by their ``checkpoint_name``: o (batch,
+# heads, time, V), and the chunks' entry states that the backward kernel
+# starts each chunk from
+KEPT = ("kda_scan.o", "kda_scan.states")
 _HI = lax.Precision.HIGHEST
 _F32 = jnp.float32
 # heads a grid step: amortises the step's fixed cost (0.35 us)
@@ -70,6 +87,10 @@ _VMEM_LIMIT = 64 * 1024 * 1024
 # heads in one straight-line loop body (see _over_heads): 2 reads 6% under 1
 # on the chip forward, 4 no better
 _INTERLEAVE = 2
+
+
+def _whole_lanes(head_dim: int) -> bool:
+    return head_dim % 128 == 0 and 0 < head_dim <= 256
 
 
 def supported(q, k, v, g, b, chunk: int, sub: int) -> bool:
@@ -83,12 +104,23 @@ def supported(q, k, v, g, b, chunk: int, sub: int) -> bool:
         return False
     if g.shape != q.shape or b.shape != q.shape[:3]:
         return False
-    if q.shape[-1] % 128 or q.shape[-1] > 256 or 0 in q.shape:
+    if not _whole_lanes(q.shape[-1]) or 0 in q.shape:
         return False
     if not (q.dtype == k.dtype == v.dtype
             and q.dtype in (jnp.bfloat16, jnp.float32)):
         return False
     return _pk.interpret() or jax.default_backend() == "tpu"
+
+
+def kept_bytes(time: int, heads: int, head_dim: int, chunk: int) -> int:
+    """Bytes of ``KEPT`` for one sequence of ``time`` steps: o (time, heads,
+    V) and the (heads, chunks, K, K) entry states, float32, at the length
+    the kernels run at; 0 for a head or a chunk the kernels do not take
+    (the ``jax.numpy`` form names nothing)."""
+    if chunk != CHUNK or not _whole_lanes(head_dim):
+        return 0
+    padded = time + (-time) % CHUNK
+    return 4 * heads * head_dim * (padded + padded // CHUNK * head_dim)
 
 
 def _trace_time_choices():
@@ -546,8 +578,11 @@ def _forward(q, k, v, g, b, save: bool, exact: bool, interpret: bool,
         "kda_scan_fwd", functools.partial(_fwd_kernel, hb, exact, save),
         interpret, grid, [wide] * 4 + [col], out_specs, out_shape,
         [pltpu.VMEM((hb, kd, kd), _F32)])(*flat, _by_head_group(b, hb))
-    o = jnp.swapaxes(outs[0], 1, 2)
-    return (o, outs[1]) if save else o
+    if not save:
+        return jnp.swapaxes(outs[0], 1, 2)
+    # named as the kernel wrote them (the module's last paragraph)
+    o, states = map(checkpoint_name, outs, KEPT)
+    return jnp.swapaxes(o, 1, 2), states
 
 
 @functools.partial(jax.jit, static_argnames=("exact", "interpret",
