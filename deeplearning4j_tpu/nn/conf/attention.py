@@ -20,7 +20,8 @@ and v widths that differ, no rotation), ``GatedAttention`` (fewer k/v
 heads than query heads, per-head q/k norms, a partial rotary embedding,
 an output gate) and ``RotaryAttention`` (the plain decoder attention: q, k,
 v, o projections, a rotary embedding over the whole head width, equal or
-grouped heads, no norm and no gate).
+grouped heads, no gate; as fields a sliding window, a YaRN-scaled rotation
+and per-head q/k norms).
 
 Param layout: nested ``{"q": {"W", "b"}, "k": ..., "v": ..., "o": ...}``
 (plus ``ff1``/``ff2`` in the encoder block) so the framework's bias-aware
@@ -33,10 +34,12 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from deeplearning4j_tpu.nn.activations import get_activation
@@ -253,9 +256,37 @@ def _diagonal_mask(block: int):
     return jnp.tril(jnp.ones((block, block), bool))
 
 
-def _blocked_forward(q, k, v, block: int):
+def _band_tiles(i: int, block: int, window):
+    """For query tile ``i`` under a window of ``window`` keys (None: the
+    causal triangle): the first key tile whose every position the tile's
+    queries see, what the window's far edge leaves of the diagonal tile
+    (None: all of it), and the key tiles before the first whole one that
+    the far edge crosses; each as ``far``, the pair's distance in
+    positions less the window (``_tile_keep``)."""
+    if window is None:
+        return 0, None, []
+    lo = max(0, (i * block - window + 1) // block)
+    whole = max(lo, i - window // block + 1)
+    return (whole, -window if window < block else None,
+            [(j, (i - j) * block - window) for j in range(lo, min(whole, i))])
+
+
+def _tile_keep(block: int, diagonal: bool, far):
+    """What a (query, key) tile pair keeps: on the diagonal tile the keys
+    at or before the query, and with ``far`` the keys less than a window
+    before it (key - query > ``far`` inside the pair)."""
+    keep = _diagonal_mask(block) if diagonal else None
+    if far is not None:
+        inside = (jnp.arange(block)[None, :] - jnp.arange(block)[:, None]
+                  > far)
+        keep = inside if keep is None else keep & inside
+    return keep
+
+
+def _blocked_forward(q, k, v, block: int, window=None):
     """(out, logsumexp) of causal softmax(q k^T / sqrt(d_q)) v, a block of
-    queries at a time against the key blocks at or before it; one
+    queries at a time against the key blocks at or before it (with a
+    ``window``: those that hold a key the block's queries see); one
     (block, block) score tile a head is alive at a time."""
     bsz, h, t, dq = q.shape
     scale = 1.0 / (dq ** 0.5)
@@ -282,14 +313,20 @@ def _blocked_forward(q, k, v, block: int):
                  jnp.zeros((bsz, h, block), jnp.float32),
                  jnp.zeros((bsz, h, block, v.shape[-1]), jnp.float32))
         # the diagonal tile first: every row has its own key, so the
-        # running maximum is finite from here on
-        s = jnp.where(_diagonal_mask(block), _pair_scores(q_i, tile(k, i),
-                                                          scale), -jnp.inf)
+        # running maximum is finite from here on (also before a tile of
+        # the window's far edge in which a row sees no key)
+        whole, own_far, edge = _band_tiles(i, block, window)
+        s = jnp.where(_tile_keep(block, True, own_far),
+                      _pair_scores(q_i, tile(k, i), scale), -jnp.inf)
         carry = fold(carry, s, tile(v, i))
-        if i:
+        for j, far in edge:
+            carry = fold(carry, jnp.where(
+                _tile_keep(block, False, far),
+                _pair_scores(q_i, tile(k, j), scale), -jnp.inf), tile(v, j))
+        if i > whole:
             carry = lax.fori_loop(
-                0, i, lambda j, c: fold(c, _pair_scores(q_i, tile(k, j),
-                                                        scale), tile(v, j)),
+                whole, i, lambda j, c: fold(c, _pair_scores(
+                    q_i, tile(k, j), scale), tile(v, j)),
                 carry)
         m, l, acc = carry
         outs.append((acc / l[..., None]).astype(v.dtype))
@@ -297,7 +334,7 @@ def _blocked_forward(q, k, v, block: int):
     return jnp.concatenate(outs, 2), jnp.concatenate(lses, 2)
 
 
-def _blocked_backward(q, k, v, out, lse, dout, block: int):
+def _blocked_backward(q, k, v, out, lse, dout, block: int, window=None):
     bsz, h, t, dq = q.shape
     scale = 1.0 / (dq ** 0.5)
     nb = t // block
@@ -319,12 +356,12 @@ def _blocked_backward(q, k, v, out, lse, dout, block: int):
         q_i, do_i = q[:, :, sl], dout[:, :, sl]
         lse_i, delta_i = lse[:, :, sl], delta[:, :, sl]
 
-        def pair(j, carry, masked):
+        def pair(j, carry, diagonal, far=None):
             dq_i, dk, dv = carry
             k_j, v_j = tile(k, j), tile(v, j)
             s = _pair_scores(q_i, k_j, scale)
-            if masked:
-                s = jnp.where(_diagonal_mask(block), s, -jnp.inf)
+            if diagonal or far is not None:
+                s = jnp.where(_tile_keep(block, diagonal, far), s, -jnp.inf)
             p = jnp.exp(s - lse_i[..., None])
             dv = add_tile(dv, j, jnp.einsum(
                 "bhqk,bhqd->bhkd", p.astype(do_i.dtype), do_i,
@@ -340,9 +377,12 @@ def _blocked_backward(q, k, v, out, lse, dout, block: int):
             return dq_i, dk, dv
 
         carry = (jnp.zeros(q_i.shape, jnp.float32), dk, dv)
-        carry = pair(i, carry, True)
-        if i:
-            carry = lax.fori_loop(0, i, lambda j, c: pair(j, c, False),
+        whole, own_far, edge = _band_tiles(i, block, window)
+        carry = pair(i, carry, True, own_far)
+        for j, far in edge:
+            carry = pair(j, carry, False, far)
+        if i > whole:
+            carry = lax.fori_loop(whole, i, lambda j, c: pair(j, c, False),
                                   carry)
         dq_i, dk, dv = carry
         dqs.append(dq_i)
@@ -350,24 +390,24 @@ def _blocked_backward(q, k, v, out, lse, dout, block: int):
             dv.astype(v.dtype))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _blocked_attention(q, k, v, block):
-    return _blocked_forward(q, k, v, block)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _blocked_attention(q, k, v, block, window=None):
+    return _blocked_forward(q, k, v, block, window)[0]
 
 
-def _blocked_attention_fwd(q, k, v, block):
-    out, lse = _blocked_forward(q, k, v, block)
+def _blocked_attention_fwd(q, k, v, block, window):
+    out, lse = _blocked_forward(q, k, v, block, window)
     return out, (q, k, v, out, lse)
 
 
-def _blocked_attention_bwd(block, res, dout):
-    return _blocked_backward(*res, dout, block)
+def _blocked_attention_bwd(block, window, res, dout):
+    return _blocked_backward(*res, dout, block, window)
 
 
 _blocked_attention.defvjp(_blocked_attention_fwd, _blocked_attention_bwd)
 
 
-def blocked_causal_attention(q, k, v, block: int = 512):
+def blocked_causal_attention(q, k, v, block: int = 512, window=None):
     """Causal softmax(q k^T / sqrt(d_q)) v for ``q``, ``k`` (batch, heads,
     time, d_q) and ``v`` (batch, heads, time, d_v), d_q and d_v free to
     differ, q, k and v with the same number of heads (a caller with
@@ -377,7 +417,11 @@ def blocked_causal_attention(q, k, v, block: int = 512):
     backward pass that makes each tile's probabilities again from the saved
     log-sum-exp (the flash-attention recipe). ``time`` is padded up to a
     multiple of ``block``: padded keys lie after every real query, and
-    padded queries are cut off.
+    padded queries are cut off. With a ``window`` w, query t sees key u
+    where ``t - w < u <= t`` (w keys with its own): only the BAND of tile
+    pairs that hold such a key is visited, the pairs across the window's
+    far edge masked there, and a window of at least the length is the
+    triangle (``window=None``, today's function bit for bit).
 
     One algorithm, two executions, chosen at trace time by what the code
     can observe (``perf.pallas.take("blocked_attention", supported(...))``;
@@ -392,15 +436,18 @@ def blocked_causal_attention(q, k, v, block: int = 512):
     from deeplearning4j_tpu.perf.pallas import attention as kernels
 
     t = q.shape[2]
+    if window is not None and window >= t:
+        window = None
     block = min(block, t)
     pad = (-t) % block
     if pad:
         q, k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0)))
                    for a in (q, k, v))
-    if pk.take("blocked_attention", kernels.supported(q, k, v, block)):
-        out = kernels.blocked_attention(q, k, v)
+    if pk.take("blocked_attention",
+               kernels.supported(q, k, v, block, window)):
+        out = kernels.blocked_attention(q, k, v, window)
     else:
-        out = _blocked_attention(q, k, v, block)
+        out = _blocked_attention(q, k, v, block, window)
     return out[:, :, :t] if pad else out
 
 
@@ -503,17 +550,50 @@ class MultiHeadLatentAttention(BaseLayer):
         return out, state
 
 
-def rotate_half_split(x, positions, rotary_dim: int, theta: float):
+def yarn_inv_freq(dim: int, base: float, factor: float,
+                  original_length: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0):
+    """The ``dim / 2`` inverse frequencies of a YaRN-scaled rotation
+    (arXiv:2309.00071, as the public ``rope_type: "yarn"`` initialisation
+    computes them): width pair j turns ``original_length * base^(-2j/dim)
+    / (2 pi)`` times over the original context; pairs that turn more than
+    ``beta_fast`` times keep their frequency, pairs that turn fewer than
+    ``beta_slow`` times have it divided by ``factor``, and a linear ramp
+    over j blends the two in between (its ends rounded outward to whole
+    j). Float32, computed on the host."""
+    def pair_that_turns(times):
+        return (dim * math.log(original_length / (times * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(pair_that_turns(beta_fast)), 0)
+    high = min(math.ceil(pair_that_turns(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    j = np.arange(dim // 2, dtype=np.float64)
+    ramp = np.clip((j - low) / (high - low), 0.0, 1.0)
+    plain = float(base) ** (-2.0 * j / dim)
+    return jnp.asarray((1.0 - ramp) * plain + ramp * plain / factor,
+                       jnp.float32)
+
+
+def rotate_half_split(x, positions, rotary_dim: int, theta: float,
+                      inv_freq=None, factor: float = 1.0):
     """A rotary embedding over the first ``rotary_dim`` widths of ``x``
     (batch, time, heads, width), the rest left as they are: width j of the
     first half is paired with width j + rotary_dim / 2 (the "half-split"
     layout of the Llama family's public code) and the pair turned by
-    ``positions * theta^(-2j / rotary_dim)``. Angles, sines and the turn
+    ``positions * theta^(-2j / rotary_dim)``, or by ``positions *
+    inv_freq[j]`` where a scaled rotation gives its own ``rotary_dim / 2``
+    frequencies (``yarn_inv_freq``); cosines and sines times ``factor``
+    (a scaled rotation's attention factor). Angles, sines and the turn
     itself in float32; the result in ``x``'s type."""
     half = rotary_dim // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rotary_dim)
+    freq = (theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rotary_dim)
+            if inv_freq is None else inv_freq)
     angle = positions.astype(jnp.float32)[:, None] * freq      # (time, half)
     cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     x1 = x[..., :half].astype(jnp.float32)
     x2 = x[..., half:rotary_dim].astype(jnp.float32)
     turned = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
@@ -638,26 +718,39 @@ class GatedAttention(BaseLayer):
 @register_layer
 @dataclasses.dataclass(frozen=True)
 class RotaryAttention(BaseLayer):
-    """Causal multi-head attention with a rotary embedding and nothing
-    else, as the Llama line of decoders (and the looped ``ouro`` models)
-    run it. With h = ``n_heads``, h_kv = ``n_kv_heads`` (h when left at
-    0; h a multiple of it) and d = ``head_dim``:
+    """Causal multi-head attention with a rotary embedding, as the Llama
+    line of decoders (and the looped ``ouro`` models) run it, and what the
+    lines after it add as FIELDS that default to its absence: a sliding
+    window, a scaled rotation, per-head q/k norms. With h = ``n_heads``,
+    h_kv = ``n_kv_heads`` (h when left at 0; h a multiple of it) and d =
+    ``head_dim``:
 
         q = W_q x  (h x d columns),  k = W_k x,  v = W_v x  (h_kv x d each)
+        with ``qk_norm``: q, k = RMSNorm_head(q), RMSNorm_head(k) (over d,
+        plain weights ``q_norm`` / ``k_norm`` started at one, ``eps``)
         all d widths of q and k rotated (``rotate_half_split``; a part of
-        the head: ``GatedAttention``)
+        the head: ``GatedAttention``): by ``rope_theta^(-2j/d)``, or with
+        ``rope_scaling`` (the model config's ``rope_parameters`` entry of
+        this layer's type; ``rope_type`` ``"yarn"`` is built, ``"default"``
+        is none) by ``yarn_inv_freq`` with cosines and sines times its
+        ``attention_factor`` (0.1 ln(factor) + 1 where the entry gives
+        none), at every length
         each k / v head serves h / h_kv consecutive query heads
-        out = W_o softmax(q k^T / sqrt(d)) v,  no bias, no norm, no gate
+        query t sees keys u <= t, and with ``window`` w > 0 of them those
+        with u > t - w (w keys with its own)
+        out = W_o softmax(q k^T / sqrt(d)) v,  no bias, no gate
 
-    The scores go through ``blocked_causal_attention``; k and v are
-    repeated over their group in front of it only where the group is more
-    than one head (``GatedAttention`` has the reasons). Which path a
-    compiled program took is counted at trace time (``bump_active``):
+    The scores go through ``blocked_causal_attention`` (with the window:
+    the band of tile pairs); k and v are repeated over their group in
+    front of it only where the group is more than one head
+    (``GatedAttention`` has the reasons). Which path a compiled program
+    took is counted at trace time (``bump_active``):
     ``attention.rotary_blocked`` with more than one tile,
-    ``attention.rotary_single_tile`` otherwise, and beside them
-    ``kernel.pallas_blocked_attention`` / ``kernel.xla_blocked_attention``.
-    A features mask zeroes the output at masked steps (right-padded batches
-    are exact)."""
+    ``attention.rotary_single_tile`` otherwise,
+    ``attention.rotary_windowed`` once a layer whose window is shorter
+    than the sequence, and beside them ``kernel.pallas_blocked_attention``
+    / ``kernel.xla_blocked_attention``. A features mask zeroes the output
+    at masked steps (right-padded batches are exact)."""
 
     n_in: Optional[int] = None
     n_out: int = 0              # model width; inferred from the input when 0
@@ -667,6 +760,10 @@ class RotaryAttention(BaseLayer):
     rope_theta: float = 10000.0
     block: int = 512
     weight_init: str = "xavier_fan_in"
+    window: int = 0             # 0: every key at or before the query
+    rope_scaling: Optional[dict] = None
+    qk_norm: bool = False
+    eps: float = 1e-6           # of the q/k norms
 
     supports_stateful = False
 
@@ -682,6 +779,26 @@ class RotaryAttention(BaseLayer):
     def _width(self, it: InputType) -> int:
         return self.n_out or self.n_in or it.size
 
+    def _rotation(self):
+        """(inverse frequencies | None for the plain ones, the factor on
+        cosines and sines) of this layer's rotation."""
+        scaling = self.rope_scaling
+        kind = (scaling or {}).get("rope_type", "default")
+        if kind == "default":
+            return None, 1.0
+        if kind != "yarn":
+            raise NotImplementedError(
+                f"rope_type {kind!r}: the plain and the YaRN-scaled "
+                "rotation are built")
+        factor = float(scaling["factor"])
+        return (yarn_inv_freq(
+            self.head_dim, float(scaling.get("rope_theta", self.rope_theta)),
+            factor, int(scaling["original_max_position_embeddings"]),
+            float(scaling.get("beta_fast", 32.0)),
+            float(scaling.get("beta_slow", 1.0))),
+            float(scaling.get("attention_factor")
+                  or 0.1 * math.log(factor) + 1.0))
+
     def output_type(self, it: InputType) -> InputType:
         hkv = self.n_kv_heads or self.n_heads
         if self.n_heads % hkv:
@@ -690,6 +807,9 @@ class RotaryAttention(BaseLayer):
         if self.head_dim % 2:
             raise ValueError(f"head_dim {self.head_dim} has to be even: the "
                              "rotation pairs its halves")
+        if self.window < 0:
+            raise ValueError(f"a window of {self.window} keys")
+        self._rotation()            # an unknown rope_type fails here
         return InputType.recurrent(self._width(it), it.timeseries_length)
 
     def init(self, rng, it: InputType, dtype=jnp.float32):
@@ -702,14 +822,19 @@ class RotaryAttention(BaseLayer):
             return init_weights(key, (n_in, n_out), n_in, n_out,
                                 self.weight_init, self.dist, dtype)
 
-        return {
+        params = {
             "Wq": dense(ks[0], d, h * dh),
             "Wk": dense(ks[1], d, hkv * dh),
             "Wv": dense(ks[2], d, hkv * dh),
             "Wo": dense(ks[3], h * dh, self._width(it)),
-        }, {}
+        }
+        if self.qk_norm:
+            params["q_norm"] = jnp.ones((dh,), dtype)
+            params["k_norm"] = jnp.ones((dh,), dtype)
+        return params, {}
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        from deeplearning4j_tpu.nn.conf.normalization import rms_norm
         from deeplearning4j_tpu.perf.compile_watch import bump_active
 
         x = dropout_input(x, self.dropout, train, rng)
@@ -719,17 +844,26 @@ class RotaryAttention(BaseLayer):
         q = (x @ params["Wq"]).reshape(bsz, t, h, dh)
         k = (x @ params["Wk"]).reshape(bsz, t, hkv, dh)
         v = (x @ params["Wv"]).reshape(bsz, t, hkv, dh)
+        if self.qk_norm:
+            with jax.named_scope("rattn.qk_norm"):
+                q = rms_norm(q, params["q_norm"], self.eps)
+                k = rms_norm(k, params["k_norm"], self.eps)
         with jax.named_scope("rattn.rope"):
             positions = jnp.arange(t)
-            q, k = (rotate_half_split(a, positions, dh, self.rope_theta)
+            inv_freq, factor = self._rotation()
+            q, k = (rotate_half_split(a, positions, dh, self.rope_theta,
+                                      inv_freq, factor)
                     for a in (q, k))
         bump_active("attention.rotary_blocked" if t > self.block
                     else "attention.rotary_single_tile")
+        window = self.window if 0 < self.window < t else None
+        if window:
+            bump_active("attention.rotary_windowed")
         with jax.named_scope("rattn.attend"):
             q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
             if h != hkv:
                 k, v = (jnp.repeat(a, h // hkv, axis=1) for a in (k, v))
-            o = blocked_causal_attention(q, k, v, self.block)
+            o = blocked_causal_attention(q, k, v, self.block, window)
         out = o.transpose(0, 2, 1, 3).reshape(bsz, t, h * dh) @ params["Wo"]
         if mask is not None:             # masked steps emit zeros
             out = out * mask[..., None].astype(out.dtype)
@@ -738,4 +872,4 @@ class RotaryAttention(BaseLayer):
 
 __all__ = ["SelfAttentionLayer", "TransformerEncoderBlock",
            "MultiHeadLatentAttention", "GatedAttention", "RotaryAttention",
-           "blocked_causal_attention", "rotate_half_split"]
+           "blocked_causal_attention", "rotate_half_split", "yarn_inv_freq"]

@@ -23,7 +23,8 @@ GROUPED matrix products over the rows each expert really got
 (``grouped_matmul``: the Pallas ``megablox`` kernel on TPU, whose grid
 follows the load, and ``lax.ragged_dot`` elsewhere). The sorted slots are
 computed a window at a time (four times the even share of the held
-experts, at most the worst case of tokens x top_k slots): one window when
+experts, twice where the share is a quarter or more, at most the worst
+case of tokens x top_k slots: ``_window_slots``): one window when
 the held pairs fit it, all of them under a ``lax.cond`` when they do not,
 so that work, traffic and memory follow the load and a skewed batch is
 slower, never wrong. The selection bias and the load counters live in the layer's
@@ -52,6 +53,12 @@ from deeplearning4j_tpu.nn.conf.layers import (
 from deeplearning4j_tpu.nn.initializers import init_weights
 
 GMM_ROW_TILE = 128
+# a call of ``grouped_matmul`` with this many rows a group or more (the
+# window's rows, which are two to four even shares of an expert) takes
+# ``_gmm_tiling_dense``'s tiles; at 640 and 1,024 rows a group they are 9%
+# and 4% slower than ``_gmm_tiling``'s (PERF.md section 6, PR 37, call 8)
+GMM_DENSE_ROWS = 2048
+GMM_DENSE_ROW_TILE = 512
 
 
 def _swiglu(x, w_gate, w_up, w_down):
@@ -103,16 +110,32 @@ class GatedFeedForward(BaseLayer):
 
 
 # ----------------------------------------------------------- grouped products
+def _fit(x: int, most: int) -> int:
+    for t in (most, 1024, 768, 512, 384, 256, 128):
+        if t <= most and x % t == 0:
+            return t
+    return 128
+
+
 def _gmm_tiling(m: int, k: int, n: int):
     """Tiles for the megablox kernel: the whole contraction in one tile
     where it is an expert's width or the model's (so that an expert's
     matrix passes through VMEM once a row tile), 128 rows."""
-    def fit(x, most):
-        for t in (most, 1024, 768, 512, 384, 256, 128):
-            if t <= most and x % t == 0:
-                return t
-        return 128
-    return GMM_ROW_TILE, fit(k, 1152), fit(n, 512)
+    return GMM_ROW_TILE, _fit(k, 1152), _fit(n, 512)
+
+
+def _gmm_tiling_dense(m: int, k: int, n: int):
+    """Tiles where a group has thousands of rows, so that the MXU and not
+    the weights' bytes bounds the product: 512 rows, and a width whole
+    where it fits (896 = 7 x 128 has no divisor between 128 and itself, and
+    tiles of 128 x 1152 x 128 ran a product of 33,000 rows at a fifth of
+    the MXU's pace: PERF.md section 6, PR 37). The caps are what a v5e's
+    16 MB of VMEM took at 512 rows, timed at Mellum2's widths alone: the
+    backward ``tgmm`` holds a float32 ``tk x tn`` accumulator beside its
+    doubled output, 12.4 MB at 1152 x 896, and ran out at 2304 x 896
+    (ROADMAP Queue 1 item 14c: one rule from a stated budget)."""
+    return (GMM_DENSE_ROW_TILE, k if k <= 1152 else _fit(k, 1152),
+            n if n <= 896 else _fit(n, 768))
 
 
 def grouped_matmul(rows, weights, group_sizes):
@@ -135,9 +158,27 @@ def grouped_matmul(rows, weights, group_sizes):
         # kernel then zeroes what it did not write
         sizes = jnp.concatenate(
             [group_sizes, (rows.shape[0] - jnp.sum(group_sizes))[None]])
+        m, groups = rows.shape[0], weights.shape[0]
+        dense = (m >= GMM_DENSE_ROWS * groups
+                 and m % GMM_DENSE_ROW_TILE == 0)
         return gmm(rows, weights, sizes.astype(jnp.int32), rows.dtype,
-                   _gmm_tiling)
+                   _gmm_tiling_dense if dense else _gmm_tiling)
     return lax.ragged_dot(rows, weights, group_sizes.astype(jnp.int32))
+
+
+def _window_slots(slots: int, held: int, experts: int) -> int:
+    """Sorted slots a window, of the ``slots`` (tokens x top_k) a step has,
+    for a layer that holds ``held`` of ``experts`` experts: four times the
+    even share of the held ones. Where that is every slot (a share of a
+    quarter or more) a window of them all would gather, multiply and
+    scatter the worst case every step, three quarters of it rows of no
+    expert held here: the window is then the larger of TWICE the even share
+    and half the slots, which is one window of them all again from a share
+    of a half on (most pairs are held: nothing to save)."""
+    four = -(-slots * 4 * held // experts)
+    if four < slots:
+        return four
+    return min(slots, max(-(-slots * 2 * held // experts), -(-slots // 2)))
 
 
 @register_layer
@@ -243,8 +284,8 @@ class RoutedExperts(BaseLayer):
         # for a router that drifts towards the experts it is trained
         # through; a skewed batch takes as many as it needs, up to the
         # worst case (tokens x top_k slots)
-        slots = min(-(-n * k * 4 * e // self.n_experts), n * k)
-        window = -(-slots // GMM_ROW_TILE) * GMM_ROW_TILE
+        window = -(-_window_slots(n * k, e, self.n_experts)
+                   // GMM_ROW_TILE) * GMM_ROW_TILE
         windows = -(-n * k // window)
         if windows * window > n * k:
             order = jnp.pad(order, (0, windows * window - n * k))

@@ -45,6 +45,19 @@ exponentials.
   group's last pair. Five products a pair, each made once; the usual two
   backward kernels make the scores and ``dp`` twice (seven) and measured
   17.0 ms against 12.7 at the Kimi Linear's shape (PERF.md §6, PR 29).
+
+With a ``window`` w (query t sees key u where t - w < u <= t) the tables
+list the BAND of tile pairs that hold a kept position and nothing else: a
+query tile's diagonal tile and the ``ceil((w - 1) / tile)`` before it. The
+kernels then read where a query tile's pairs start and where a key tile's
+end from the tables' neighbouring entries, not from ``j == 0`` /
+``i == n - 1``; the forward folds the DIAGONAL tile first (every row has
+its own key there, so the running maximum is finite before a tile in
+which a row sees nothing: ``exp(-inf - -inf)`` never arises); the band's
+far edge (``u > t - w``) is masked in the pairs that cross it, traced
+apart from the pairs that need no mask, as the diagonal is. dq's scratch
+stays the whole time axis. ``window=None`` is the triangle: its tables,
+grid and kernel bodies are what they were.
 """
 
 from __future__ import annotations
@@ -86,7 +99,7 @@ def _dq_bytes_a_head(t: int, dq: int, dtype) -> int:
     return t * -(-dq // _LANES) * _LANES * (4 + 2 * jnp.dtype(dtype).itemsize)
 
 
-def supported(q, k, v, block: int) -> bool:
+def supported(q, k, v, block: int, window=None) -> bool:
     """Shapes the kernels take: q, k (batch, heads, time, d_q) and v
     (batch, heads, time, d_v) with the SAME head count, any number of
     heads (grouped-query heads reach the kernels with k and v repeated
@@ -94,8 +107,11 @@ def supported(q, k, v, block: int) -> bool:
     bfloat16 or float32, ``time``
     (padded by the caller) more than one ``block`` and a multiple of 128,
     head widths multiples of 64 up to 256, one head's dq within the
-    backward kernel's VMEM (32768 steps of 192 in bfloat16); on a TPU
-    backend or in interpret mode."""
+    backward kernel's VMEM (32768 steps of 192 in bfloat16); a ``window``
+    of at least one key (None: the causal triangle); on a TPU backend or
+    in interpret mode."""
+    if window is not None and window < 1:
+        return False
     if q.ndim != 4 or q.shape != k.shape or v.shape[:3] != q.shape[:3]:
         return False
     if 0 in q.shape or 0 in v.shape:
@@ -117,12 +133,32 @@ def _heads_a_step(h: int, most: int) -> int:
     return max(d for d in range(1, most + 1) if h % d == 0)
 
 
-def _pairs(n: int, by_query: bool):
+def _band(window, tile: int):
+    """(key tiles before the diagonal one that hold a kept position, the
+    tile distance from which a pair crosses the window's far edge) for a
+    window of ``window`` keys in tiles of ``tile``; None for the
+    triangle."""
+    if window is None:
+        return None
+    return -((1 - window) // tile), window // tile
+
+
+def _pairs(n: int, by_query: bool, window_tiles=None):
     """The (query tile, key tile) pairs of a causal triangle of ``n`` x
     ``n`` tiles as two int32 tables, grouped by query tile (its key tiles
     from the first to the diagonal) or by key tile (its query tiles from
-    the diagonal to the last)."""
-    if by_query:
+    the diagonal to the last). With ``window_tiles`` the band alone: a
+    query tile's diagonal tile FIRST, then the ``window_tiles`` before it
+    from the oldest on; a key tile's query tiles from the diagonal to the
+    ``window_tiles``-th after it."""
+    if window_tiles is not None:
+        if by_query:
+            pairs = [(i, j) for i in range(n) for j in
+                     [i, *range(max(i - window_tiles, 0), i)]]
+        else:
+            pairs = [(i, j) for j in range(n)
+                     for i in range(j, min(j + window_tiles, n - 1) + 1)]
+    elif by_query:
         pairs = [(i, j) for i in range(n) for j in range(i + 1)]
     else:
         pairs = [(i, j) for j in range(n) for i in range(j, n)]
@@ -138,15 +174,21 @@ def _spread(col, width: int):
     return col if reps == 1 else jnp.tile(col, (1, reps))
 
 
-def _scores(x, y, scale, diagonal: bool, queries_in_rows: bool):
+def _scores(x, y, scale, diagonal: bool, queries_in_rows: bool, far=None):
     """x y^T * scale in float32; on a diagonal tile -inf where the key lies
-    after the query."""
+    after the query; with ``far`` (the pair's tile distance x tile less
+    the window, a scalar) -inf where the key lies ``window`` or more
+    before the query: kept is key - query > ``far`` inside the pair."""
     s = lax.dot_general(x, y, _NT, preferred_element_type=_F32) * scale
-    if not diagonal:
+    if not diagonal and far is None:
         return s
     rows, cols = (lax.broadcasted_iota(jnp.int32, s.shape, d) for d in (0, 1))
-    return jnp.where(cols <= rows if queries_in_rows else rows <= cols, s,
-                     -jnp.inf)
+    query, key = (rows, cols) if queries_in_rows else (cols, rows)
+    keep = key <= query if diagonal else None
+    if far is not None:
+        inside = key - query > far
+        keep = inside if keep is None else keep & inside
+    return jnp.where(keep, s, -jnp.inf)
 
 
 def _on_and_off_the_diagonal(pl, i, j, pair):
@@ -156,26 +198,60 @@ def _on_and_off_the_diagonal(pl, i, j, pair):
     pl.when(i != j)(functools.partial(pair, False))
 
 
+def _by_mask(pl, i, j, band, tile: int, window: int, pair):
+    """``_on_and_off_the_diagonal`` for a band: ``pair(diagonal, far)``
+    traced once a kind of mask that the band holds, each under its own
+    condition, so that only the pairs across the window's far edge pay for
+    its mask (``far`` as ``_scores`` takes it, None elsewhere)."""
+    if band is None:
+        return _on_and_off_the_diagonal(pl, i, j, pair)
+    back, far_from = band
+    apart = i - j
+    edge = apart * tile - window
+    if far_from == 0:            # a window under one tile: every pair
+        pl.when(apart == 0)(lambda: pair(True, edge))
+        if back:
+            pl.when(apart != 0)(lambda: pair(False, edge))
+        return None
+    pl.when(apart == 0)(lambda: pair(True, None))
+    if far_from > 1:
+        pl.when((apart > 0) & (apart < far_from))(lambda: pair(False, None))
+    if back >= far_from:
+        pl.when(apart >= far_from)(lambda: pair(False, edge))
+    return None
+
+
 # ------------------------------------------------------------------ kernels
-def _fwd_kernel(hb, scale, save, qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref,
-                *rest):
+def _last_of_its_group(pl, table_ref, step, own):
+    """Whether the pair at ``step`` is the last of its query tile (key
+    tile): the tables' next entry names another, or there is none."""
+    last = pl.num_programs(2) - 1
+    return (step == last) | (table_ref[jnp.minimum(step + 1, last)] != own)
+
+
+def _fwd_kernel(hb, scale, save, band, window, qi_ref, kj_ref, q_ref, k_ref,
+                v_ref, o_ref, *rest):
     from jax.experimental import pallas as pl
     lse_ref = rest[0] if save else None
     m_ref, l_ref, acc_ref = rest[-3:]
-    i, j = qi_ref[pl.program_id(2)], kj_ref[pl.program_id(2)]
+    step = pl.program_id(2)
+    i, j = qi_ref[step], kj_ref[step]
 
-    @pl.when(j == 0)
+    # the triangle starts a query tile at key tile 0, a band at the
+    # diagonal tile (listed first)
+    @pl.when(j == 0 if band is None else j == i)
     def _():
         m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, _F32)
         l_ref[...] = jnp.zeros(l_ref.shape, _F32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, _F32)
 
-    def pair(diagonal):
-        # the first key tile holds key 0, which every query sees: the
-        # running maximum is finite from the first fold on
+    def pair(diagonal, far=None):
+        # the first key tile holds key 0, which every query sees (a
+        # band's first is the diagonal one, where every query sees its own
+        # key): the running maximum is finite from the first fold on
         for h in range(hb):
             v = v_ref[h]
-            s = _scores(q_ref[h], k_ref[h], scale, diagonal, True)
+            s = _scores(q_ref[h], k_ref[h], scale, diagonal, True, far)
             m_prev = m_ref[h]
             m_next = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
             p = jnp.exp(s - _spread(m_next, s.shape[1]))
@@ -186,9 +262,10 @@ def _fwd_kernel(hb, scale, save, qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref,
                           + lax.dot_general(p.astype(v.dtype), v, _NN,
                                             preferred_element_type=_F32))
 
-    _on_and_off_the_diagonal(pl, i, j, pair)
+    _by_mask(pl, i, j, band, q_ref.shape[1], window, pair)
 
-    @pl.when(j == i)
+    @pl.when(j == i if band is None
+             else _last_of_its_group(pl, qi_ref, step, i))
     def _():
         for h in range(hb):
             l = l_ref[h]
@@ -198,9 +275,9 @@ def _fwd_kernel(hb, scale, save, qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref,
                 lse_ref[h] = (m_ref[h] + jnp.log(l)).T[:1]
 
 
-def _bwd_kernel(hb, scale, n, qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref,
-                lse_ref, delta_ref, dk_ref, dv_ref, dq_ref, dk_acc, dv_acc,
-                dq_acc):
+def _bwd_kernel(hb, scale, n, band, window, qi_ref, kj_ref, q_ref, k_ref,
+                v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dq_ref,
+                dk_acc, dv_acc, dq_acc):
     from jax.experimental import pallas as pl
     step = pl.program_id(2)
     i, j = qi_ref[step], kj_ref[step]
@@ -214,10 +291,10 @@ def _bwd_kernel(hb, scale, n, qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref,
         dk_acc[...] = jnp.zeros(dk_acc.shape, _F32)
         dv_acc[...] = jnp.zeros(dv_acc.shape, _F32)
 
-    def pair(diagonal):
+    def pair(diagonal, far=None):
         for h in range(hb):
             q, k, v, do = q_ref[h], k_ref[h], v_ref[h], do_ref[h]
-            s_t = _scores(k, q, scale, diagonal, False)   # (keys, queries)
+            s_t = _scores(k, q, scale, diagonal, False, far)  # (keys, queries)
             p_t = jnp.exp(s_t - lse_ref[h])
             dv_acc[h] = dv_acc[h] + lax.dot_general(
                 p_t.astype(do.dtype), do, _NN, preferred_element_type=_F32)
@@ -228,14 +305,16 @@ def _bwd_kernel(hb, scale, n, qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref,
             dq_acc[h, i] = dq_acc[h, i] + lax.dot_general(
                 ds_t.T.astype(q.dtype), k, _NN, preferred_element_type=_F32)
 
-    _on_and_off_the_diagonal(pl, i, j, pair)
+    _by_mask(pl, i, j, band, q_ref.shape[1], window, pair)
 
-    @pl.when(i == n - 1)
+    @pl.when(i == n - 1 if band is None
+             else _last_of_its_group(pl, kj_ref, step, j))
     def _():
         dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
-    @pl.when(step == n * (n + 1) // 2 - 1)
+    @pl.when(step == (n * (n + 1) // 2 if band is None
+                      else pl.num_programs(2)) - 1)
     def _():
         dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
 
@@ -277,14 +356,15 @@ def _windows(hb: int, tile: int):
     return at_query, at_key, row
 
 
-@functools.partial(jax.jit, static_argnames=("save", "interpret"))
-def _forward(q, k, v, save: bool, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("save", "interpret", "window"))
+def _forward(q, k, v, save: bool, interpret: bool, window=None):
     from jax.experimental.pallas import tpu as pltpu
     bsz, h, t, dq = q.shape
     dv = v.shape[-1]
     tile, hb = _tile(t), _heads_a_step(h, _HEADS_A_STEP)
     at_query, at_key, row = _windows(hb, tile)
-    qi, kj = _pairs(t // tile, by_query=True)
+    band = _band(window, tile)
+    qi, kj = _pairs(t // tile, True, band and band[0])
     out_shape = [jax.ShapeDtypeStruct((bsz, h, t, dv), v.dtype)]
     out_specs = [at_query(dv)]
     if save:
@@ -292,7 +372,8 @@ def _forward(q, k, v, save: bool, interpret: bool):
         out_specs.append(row)
     outs = _call(
         "mla_attend_fwd",
-        functools.partial(_fwd_kernel, hb, 1.0 / (dq ** 0.5), save),
+        functools.partial(_fwd_kernel, hb, 1.0 / (dq ** 0.5), save, band,
+                          window),
         interpret, (bsz, h // hb, qi.shape[0]),
         [at_query(dq), at_key(dq), at_key(dv)], out_specs, out_shape,
         [pltpu.VMEM((hb, tile, _LANES), _F32),
@@ -301,8 +382,8 @@ def _forward(q, k, v, save: bool, interpret: bool):
     return tuple(outs) if save else outs[0]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _backward(q, k, v, out, lse, dout, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("interpret", "window"))
+def _backward(q, k, v, out, lse, dout, interpret: bool, window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     bsz, h, t, dq = q.shape
@@ -312,13 +393,16 @@ def _backward(q, k, v, out, lse, dout, interpret: bool):
     hb = _heads_a_step(h, min(    # supported(): one head's dq does fit
         _HEADS_A_STEP_BWD, _DQ_VMEM // _dq_bytes_a_head(t, dq, q.dtype)))
     at_query, at_key, row = _windows(hb, tile)
+    band = _band(window, tile)
+    qi, kj = _pairs(n, False, band and band[0])
     delta = jnp.sum(out.astype(_F32) * dout.astype(_F32), -1)
     whole = pl.BlockSpec((None, hb, n, tile, dq),
                          lambda b, g, p, qi, kj: (b, g, 0, 0, 0))
     d_k, d_v, d_q = _call(
         "mla_attend_bwd",
-        functools.partial(_bwd_kernel, hb, 1.0 / (dq ** 0.5), n), interpret,
-        (bsz, h // hb, n * (n + 1) // 2),
+        functools.partial(_bwd_kernel, hb, 1.0 / (dq ** 0.5), n, band,
+                          window), interpret,
+        (bsz, h // hb, qi.shape[0]),
         [at_query(dq), at_key(dq), at_key(dv), at_query(dv), row, row],
         [at_key(dq), at_key(dv), whole],
         [jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -326,25 +410,24 @@ def _backward(q, k, v, out, lse, dout, interpret: bool):
          jax.ShapeDtypeStruct((bsz, h, n, tile, dq), q.dtype)],
         [pltpu.VMEM((hb, tile, dq), _F32), pltpu.VMEM((hb, tile, dv), _F32),
          pltpu.VMEM((hb, n, tile, dq), _F32)])(
-             *_pairs(n, by_query=False), q, k, v, dout, lse,
-             delta[:, :, None, :])
+             qi, kj, q, k, v, dout, lse, delta[:, :, None, :])
     return d_q.reshape(q.shape), d_k, d_v
 
 
-@jax.custom_vjp
-def blocked_attention(q, k, v):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def blocked_attention(q, k, v, window=None):
     """``blocked_causal_attention`` for inputs ``supported`` takes, ``time``
     already padded: (batch, heads, time, d_v) in ``v``'s type."""
-    return _forward(q, k, v, False, _pk.interpret())
+    return _forward(q, k, v, False, _pk.interpret(), window)
 
 
-def _blocked_attention_fwd(q, k, v):
-    out, lse = _forward(q, k, v, True, _pk.interpret())
+def _blocked_attention_fwd(q, k, v, window):
+    out, lse = _forward(q, k, v, True, _pk.interpret(), window)
     return out, (q, k, v, out, lse)
 
 
-def _blocked_attention_bwd(res, dout):
-    return _backward(*res, dout, _pk.interpret())
+def _blocked_attention_bwd(window, res, dout):
+    return _backward(*res, dout, _pk.interpret(), window)
 
 
 blocked_attention.defvjp(_blocked_attention_fwd, _blocked_attention_bwd)

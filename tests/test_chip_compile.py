@@ -239,6 +239,81 @@ def _blocked_attention_rotary(S):
             ("mla_attend_fwd", "mla_attend_bwd"))
 
 
+def _mellum_attention(S, sliding):
+    # one attention layer of the Mellum2 share, through the layer: 32 query
+    # heads over 4 key/value heads of 128 repeated in front of the kernels,
+    # per-head q/k norms, one sequence of 16,384 steps in bfloat16. A
+    # sliding layer runs the BAND of tile pairs under a window of 1024 (93
+    # of 528 at tiles of 512) and turns by the plain frequencies; the full
+    # layer runs the triangle at its first 16k shape (the backward kernel
+    # keeps four heads' dq of 16,384 x 128 float32 in VMEM) and turns by
+    # the YaRN-scaled ones
+    from deeplearning4j_tpu.nn.conf.attention import RotaryAttention
+    from deeplearning4j_tpu.nn.conf.inputs import InputType
+    from deeplearning4j_tpu.perf.pallas import attention
+    yarn = {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+            "original_max_position_embeddings": 8192, "beta_fast": 32,
+            "beta_slow": 1, "attention_factor": 1.2772588722239782}
+    layer = RotaryAttention(n_heads=32, n_kv_heads=4, head_dim=128,
+                            rope_theta=5e5, qk_norm=True,
+                            window=1024 if sliding else 0,
+                            rope_scaling=None if sliding else yarn)
+    q = S((1, 32, 16384, 128), BF16)
+    assert pk.take("blocked_attention", attention.supported(
+        q, q, q, 512, 1024 if sliding else None))
+    shapes = jax.eval_shape(lambda k: layer.init(
+        k, InputType.recurrent(2304, 16384), BF16)[0], jax.random.key(0))
+    params = {k: S(a.shape, BF16) for k, a in shapes.items()}
+
+    def fwd_bwd(params, x):
+        return jax.grad(lambda p, x: jnp.sum(layer.apply(
+            p, {}, x)[0].astype(F32)), argnums=(0, 1))(params, x)
+
+    return (fwd_bwd, (params, S((1, 16384, 2304), BF16)),
+            ("mla_attend_fwd", "mla_attend_bwd"))
+
+
+def _blocked_attention_band(S):
+    return _mellum_attention(S, True)
+
+
+def _blocked_attention_16k(S):
+    return _mellum_attention(S, False)
+
+
+def _blocked_attention_band_float32(S):
+    # the band on float32 inputs with a window that is no multiple of the
+    # tile (300 keys in tiles of 256: the far edge crosses two tiles of a
+    # query tile), a batch of two, a padded length
+    from deeplearning4j_tpu.nn.conf.attention import blocked_causal_attention
+    args = (S((2, 6, 1000, 128), F32),) * 3
+
+    def fwd_bwd(*a):
+        return jax.grad(lambda *a: jnp.sum(blocked_causal_attention(
+            *a, 256, 300)), argnums=(0, 1, 2))(*a)
+
+    return fwd_bwd, args, ("mla_attend_fwd", "mla_attend_bwd")
+
+
+def _grouped_experts_16(S):
+    # the routed layer's grouped products and their backward pass at the
+    # Mellum2 share's widths: 16,384 tokens x top-8 of 64, a quarter held
+    # here: a window of 65,536 sorted slots (half of them all,
+    # ``_window_slots``) of which some 32,768 are rows of the 16 experts of
+    # 2304 x 896 (2,048 rows an expert; 896 = 7 x 128)
+    from deeplearning4j_tpu.nn.conf.experts import grouped_matmul
+
+    def fwd_bwd(rows, w_gate, w_down, sizes):
+        def f(rows, w_gate, w_down):
+            hidden = jax.nn.silu(grouped_matmul(rows, w_gate, sizes))
+            return jnp.sum(grouped_matmul(hidden, w_down, sizes)
+                           .astype(F32))
+        return jax.grad(f, argnums=(0, 1, 2))(rows, w_gate, w_down)
+
+    return fwd_bwd, (S((65536, 2304), BF16), S((16, 2304, 896), BF16),
+                     S((16, 896, 2304), BF16), S((16,), I32))
+
+
 def _inputs_case(S, dtype, gated_delta_net):
     # the delta-rule layers' input path at the two token cells' shapes, one
     # sequence of 8192 steps: KDA's four streams of 32 heads of 128 (q, k,
@@ -311,6 +386,10 @@ AUTO_CASES = [(_pq, "adc_pq"), (_int4_table, "int4_dot"),
               (_kda_scan_scalar_decay, None),
               (_blocked_attention_grouped, None),
               (_blocked_attention_rotary, None),
+              (_blocked_attention_band, None),
+              (_blocked_attention_16k, None),
+              (_blocked_attention_band_float32, None),
+              (_grouped_experts_16, None),
               (_kda_inputs, "kda_inputs"), (_kda_inputs_float32, None),
               (_gdn_inputs, None), (_gdn_inputs_float32, None)]
 EXPLICIT_CASES = [_bn_fwd, _bn_bwd]

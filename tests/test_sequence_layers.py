@@ -868,6 +868,83 @@ def test_both_buffer_tiers_give_the_masked_loop(push):
             1.0, float(jnp.max(jnp.abs(b))))
 
 
+@pytest.mark.parametrize("slots,held,experts,window", [
+    (8192 * 8, 8, 256, 8192),         # a 32nd: four times the even share
+    (8192 * 10, 32, 512, 20480),      # a 16th
+    (16384 * 8, 16, 64, 65536),       # a quarter: twice the share, half the slots
+    (1200, 2, 8, 600), (1200, 3, 8, 900),
+    (1200, 4, 8, 1200), (1200, 8, 8, 1200),   # from a half on: every slot
+], ids=["32nd", "16th", "quarter_16k", "quarter", "three_eighths", "half",
+        "all"])
+def test_the_window_is_four_shares_and_two_from_a_quarter_on(slots, held,
+                                                            experts, window):
+    from deeplearning4j_tpu.nn.conf.experts import _window_slots
+    assert _window_slots(slots, held, experts) == window
+
+
+@pytest.mark.parametrize("m,groups,k,n,tiles", [
+    (8192, 8, 2304, 1024, (128, 1152, 512)),      # Kimi: gate / up
+    (8192, 8, 1024, 2304, (128, 1024, 384)),      # Kimi: down
+    (20480, 32, 2048, 512, (128, 1024, 512)),     # Qwen: gate / up
+    (20480, 32, 512, 2048, (128, 512, 512)),      # Qwen: down
+    (65536, 16, 2304, 896, (512, 1152, 896)),     # 4,096 rows a group: dense
+    (65536, 16, 896, 2304, (512, 896, 768)),
+    (65536, 8, 2304, 1024, (512, 1152, 512)),
+    (65536 + 128, 16, 2304, 896, (128, 1152, 128)),   # no multiple of 512
+], ids=["kimi_up", "kimi_down", "qwen_up", "qwen_down", "dense_up",
+        "dense_down", "dense_1024", "ragged_rows"])
+def test_the_grouped_products_tiles_follow_the_rows_a_group(
+        monkeypatch, m, groups, k, n, tiles):
+    """Which tiles ``grouped_matmul`` hands the megablox kernel: the
+    siblings' windows (1,024 and 640 rows a group) keep theirs, a window of
+    2,048 rows a group or more takes 512 rows and a width whole where it
+    fits. Read from the call itself, the kernel replaced."""
+    from jax.experimental.pallas.ops.tpu import megablox
+    seen = {}
+
+    def gmm(rows, weights, sizes, dtype, tiling):
+        seen["tiles"] = tiling(rows.shape[0], rows.shape[1],
+                               weights.shape[2])
+        return jnp.zeros((rows.shape[0], weights.shape[2]), dtype)
+
+    monkeypatch.setattr(megablox, "gmm", gmm)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.eval_shape(grouped_matmul, jax.ShapeDtypeStruct((m, k), jnp.bfloat16),
+                   jax.ShapeDtypeStruct((groups, k, n), jnp.bfloat16),
+                   jax.ShapeDtypeStruct((groups,), jnp.int32))
+    assert seen["tiles"] == tiles
+
+
+@pytest.mark.parametrize("push", [0.0, 10.0], ids=["first_tier",
+                                                    "worst_case_tier"])
+def test_a_quarter_share_runs_half_the_slots_or_all_of_them(push):
+    """2 of 8 experts held, 1,200 pairs: four times the even share is every
+    slot, so the window is HALF of them (640 in row tiles of 128) and the
+    usual load (300 pairs) fits it; a router pushed onto the held experts
+    (1,200 pairs) takes both windows. The masked loop's result and
+    gradients either way, the tier counted, nothing dropped."""
+    layer = _experts(held=2, offset=2, shared=0, softmax=True)
+    it = InputType.recurrent(12, 300)
+    params, state = layer.init(jax.random.key(0), it)
+    state = dict(state, bias=jnp.zeros(8).at[2:4].set(push))
+    x = jax.random.normal(jax.random.key(1), (2, 300, 12))
+    out, new = layer.apply(params, state, x)
+    held = int(new["pairs_held"])
+    assert (held <= 640) == (push == 0.0) and int(new["pairs_dropped"]) == 0
+    assert int(new["steps_every_window"]) == (1 if push else 0)
+    want = _plain_routed(layer, params, x, state["bias"])
+    assert float(jnp.max(jnp.abs(out - want))) < TOL * max(
+        1.0, float(jnp.max(jnp.abs(want))))
+    got = jax.grad(lambda p, x: jnp.sum(jnp.sin(
+        layer.apply(p, state, x)[0])), (0, 1))(params, x)
+    ref = jax.grad(lambda p, x: jnp.sum(jnp.sin(_plain_routed(
+        layer, p, x, state["bias"]))), (0, 1))(params, x)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(ref)):
+        assert float(jnp.max(jnp.abs(a - b))) < TOL * max(
+            1.0, float(jnp.max(jnp.abs(b))))
+
+
 @pytest.mark.parametrize("push,took", [(0.0, 0), (10.0, 1)],
                          ids=["under_the_window", "over_the_window"])
 def test_the_layer_counts_the_steps_that_took_every_window(push, took):
