@@ -27,7 +27,29 @@ experts, twice where the share is a quarter or more, at most the worst
 case of tokens x top_k slots: ``_window_slots``): one window when
 the held pairs fit it, all of them under a ``lax.cond`` when they do not,
 so that work, traffic and memory follow the load and a skewed batch is
-slower, never wrong. The selection bias and the load counters live in the layer's
+slower, never wrong.
+
+The dispatch around the products: a window's tokens' rows are GATHERED
+(``xf[token]``, ``window`` rows), and the results go back to their tokens
+one of two ways. The scatter form adds the window's rows to a float32 (n, d)
+array (``zeros.at[token].add``; autodiff makes the gather's transpose a
+second scatter-add). The gather form goes through the sort's inverse
+permutation ``rank`` (the sorted slot of every (token, choice) pair): a
+token's result is the float32 sum over its ``top_k`` pairs of the rows found
+through ``rank`` (``_combine``), and so is its row of ``xf``'s cotangent
+(``_take_rows``): gathers of tokens x top_k rows, no (n, d) scatter in
+either direction, the same products with the same weights. A window's rows
+are gathered from a block of columns at a time where they are too many
+bytes for the chip's fast memory (``GATHER_OPERAND_BYTES``): a gathered row
+then costs a fifth. The layer takes the gather form where tokens x top_k <=
+``DISPATCH_GATHER_RATIO`` x ``window``, i.e. where it holds a large share of
+the experts (a quarter: ratio 2; all of them: ratio 1), and keeps the
+scatter form at a 16th or a 32nd (ratios 4 and 8), where the constant's
+readings have it no slower: ``_gathers``, from the shapes alone, counted at
+trace time (``bump_active``) as ``moe.dispatch_gather`` or
+``moe.dispatch_scatter`` once a layer.
+
+The selection bias and the load counters live in the layer's
 non-trained ``state``: ``bias`` (frozen; its load-driven update is not part
 of any published config), ``expert_tokens`` (pairs each held expert got,
 summed over steps), ``pairs_held``, ``pairs_dropped`` (must stay 0) and
@@ -51,6 +73,7 @@ from deeplearning4j_tpu.nn.conf.layers import (
     BaseLayer, dropout_input, register_layer,
 )
 from deeplearning4j_tpu.nn.initializers import init_weights
+from deeplearning4j_tpu.perf.compile_watch import bump_active
 
 GMM_ROW_TILE = 128
 # a call of ``grouped_matmul`` with this many rows a group or more (the
@@ -181,6 +204,150 @@ def _window_slots(slots: int, held: int, experts: int) -> int:
     return min(slots, max(-(-slots * 2 * held // experts), -(-slots // 2)))
 
 
+# A step has ``slots`` = tokens x top_k (token, choice) pairs and a window
+# ``window`` sorted slots. Bringing the window's rows back to their tokens
+# (and the rows' cotangent back to ``xf``) is a scatter-add of ``window`` rows
+# or, through the sort's inverse permutation, a gather of ``slots`` rows: the
+# gather form where ``slots <= DISPATCH_GATHER_RATIO * window``. Timed on a
+# v5e (PERF.md section 6, PR 38), the dispatch alone, forward and backward:
+# 17.1 ms as scatter-adds against 13.8 as gathers at slots / window = 2
+# (Mellum2's share of a quarter; 13.0 against 9.8 at 1, every expert held),
+# 3.15 against 3.40 at 4 (Qwen3-Next's 16th), 2.18 against 2.24 at 8 (Kimi
+# Linear's 32nd); with the gathers' operands in fast memory
+# (``GATHER_OPERAND_BYTES``) a whole layer, forward and gradient, 42.1
+# against 35.7 ms at 2. The constant is the largest ratio read to win.
+DISPATCH_GATHER_RATIO = 2.0
+
+
+def _gathers(slots: int, window: int) -> bool:
+    """Whether a routed layer of ``slots`` pairs a step and ``window`` sorted
+    slots a window takes the gather form of its dispatch."""
+    return slots <= DISPATCH_GATHER_RATIO * window
+
+
+def _inverse(order):
+    """The inverse of the permutation ``order`` (sorted slot -> pair): the
+    sorted slot of every pair, ``order[_inverse(order)[p]] == p``. A sort of
+    integers: a scatter of them is six times slower on a v5e."""
+    return jnp.argsort(order).astype(jnp.int32)
+
+
+def _in_window(rank, start, window, n_held):
+    """For every (token, choice) pair, whether its sorted slot ``rank`` lies
+    in the window ``start .. start + window`` AND holds a pair of an expert
+    held here (the held pairs sort first: slots under ``n_held``), and its
+    row in the window, clipped into it where it does not."""
+    inside = (rank >= start) & (rank < jnp.minimum(start + window, n_held))
+    return inside, jnp.clip(rank - start, 0, window - 1)
+
+
+# A gather runs four to five times faster a row where its whole operand is
+# at most this large: the v5e then serves it from its fast memory (16,384
+# rows of 2304 bfloat16 from 24,576 rows, 108 MiB: 0.24 ms; from 28,672 rows,
+# 126 MiB: 0.65 ms, as from 65,536; PERF.md section 6, PR 38). A larger
+# operand is gathered a block of columns at a time.
+GATHER_OPERAND_BYTES = 108 * 2 ** 20
+
+
+def _column_blocks(rows: int, d: int, itemsize: int):
+    """(start, stop) of the column blocks of a (rows, d) gather operand:
+    the whole width where that fits ``GATHER_OPERAND_BYTES``, else equal
+    blocks of the most lane tiles of 128 columns that do (at least one)."""
+    if rows * d * itemsize <= GATHER_OPERAND_BYTES or d % 128:
+        return [(0, d)]
+    tiles = d // 128
+    fit = max([t for t in range(1, tiles + 1) if tiles % t == 0
+               and rows * t * 128 * itemsize <= GATHER_OPERAND_BYTES] or [1])
+    return [(c, c + fit * 128) for c in range(0, d, fit * 128)]
+
+
+def _rows_at(rows, index):
+    """``rows[index]`` along the first axis for an ``index`` known to lie
+    inside it (a clipped rank, a token of a slot): no bounds to mask."""
+    return rows.at[index].get(mode="promise_in_bounds")
+
+
+def _sum_pairs(rows, at, weight):
+    """``sum_j weight[t, j] * float32(rows[at[t, j]])``: a token's ``top_k``
+    rows of the window, gathered in ``rows``' type and added in float32.
+    ``rows`` (window, d), ``at`` and ``weight`` (n, k). A block of columns
+    is cut out only when the block before it is summed (the barrier), so
+    that one block at a time asks for the fast memory."""
+    parts = []
+    for lo, hi in _column_blocks(*rows.shape, rows.dtype.itemsize):
+        picked = _rows_at(rows[:, lo:hi], at).astype(jnp.float32)
+        total = jnp.sum(picked * weight[..., None], 1)
+        rows, total = lax.optimization_barrier((rows, total))
+        parts.append(total)
+    return jnp.concatenate(parts, -1)
+
+
+@jax.custom_vjp
+def _take_rows(xf, token, rank, start, n_held):
+    """``xf[token]``: the window's tokens' rows. Its cotangent reaches ``xf``
+    as a GATHER through ``rank`` (``_take_rows_bwd``), where autodiff
+    scatter-adds the window's rows."""
+    return _rows_at(xf, token)
+
+
+def _take_rows_fwd(xf, token, rank, start, n_held):
+    return _rows_at(xf, token), (rank, start, n_held)
+
+
+def _take_rows_bwd(kept, d_rows):
+    rank, start, n_held = kept
+    inside, at = _in_window(rank, start, d_rows.shape[0], n_held)
+    dxf = _sum_pairs(d_rows, at, inside.astype(jnp.float32))
+    return dxf.astype(d_rows.dtype), None, None, None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@jax.custom_vjp
+def _combine(y_rows, w, slots, rank, start, n_held):
+    """The window's result rows back at their tokens, float32 (n, d):
+    ``y[t] = sum_j w[t, j] * y_rows[rank[t, j] - start]`` over the pairs of
+    held experts in the window (``_in_window``), a gather where
+    ``zeros.at[token].add`` scatters. ``y_rows`` (window, d), ``w`` and
+    ``rank`` (n, k), ``slots`` (window,) the pairs of the window's slots
+    (the backward rule's)."""
+    inside, at = _in_window(rank, start, y_rows.shape[0], n_held)
+    return _sum_pairs(y_rows, at, jnp.where(inside, w, 0.0))
+
+
+def _combine_fwd(*operands):
+    return _combine.fun(*operands), operands
+
+
+def _combine_bwd(kept, dy):
+    """What autodiff gives the scatter form, slot by slot: a row's cotangent
+    is its token's ``dy`` times the slot's weight, a weight's the row dot of
+    the two, brought to its (token, choice) through ``rank``. ``dy``'s rows
+    are gathered a block of columns at a time, as ``_sum_pairs``' are."""
+    y_rows, w, slots, rank, start, n_held = kept
+    window = y_rows.shape[0]
+    valid = start + jnp.arange(window) < n_held
+    by_slot = jnp.where(valid, _rows_at(w.reshape(-1), slots), 0.0)
+    token = slots // w.shape[1]
+    d_by_slot, parts = jnp.zeros((window,), jnp.float32), []
+    for lo, hi in _column_blocks(*dy.shape, dy.dtype.itemsize):
+        dy_rows = _rows_at(dy[:, lo:hi], token)
+        d_by_slot = d_by_slot + jnp.sum(
+            dy_rows * y_rows[:, lo:hi].astype(jnp.float32), -1)
+        part = (dy_rows * by_slot[:, None]).astype(y_rows.dtype)
+        dy, part = lax.optimization_barrier((dy, part))
+        parts.append(part)
+    inside, at = _in_window(rank, start, window, n_held)
+    d_w = jnp.where(inside, _rows_at(jnp.where(valid, d_by_slot, 0.0), at),
+                    0.0)
+    return (jnp.concatenate(parts, -1), d_w.astype(w.dtype), None, None,
+            None, None)
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 @register_layer
 @dataclasses.dataclass(frozen=True)
 class RoutedExperts(BaseLayer):
@@ -287,21 +454,31 @@ class RoutedExperts(BaseLayer):
         window = -(-_window_slots(n * k, e, self.n_experts)
                    // GMM_ROW_TILE) * GMM_ROW_TILE
         windows = -(-n * k // window)
+        rank = None
+        if _gathers(n * k, window):
+            with jax.named_scope("moe.dispatch"):
+                rank = _inverse(order).reshape(n, k)
+        bump_active("moe.dispatch_scatter" if rank is None
+                    else "moe.dispatch_gather")
         if windows * window > n * k:
             order = jnp.pad(order, (0, windows * window - n * k))
         ends = jnp.cumsum(sizes)
 
         @jax.checkpoint
-        def run(start, xf, experts, order, w, sizes, ends):
+        def run(start, xf, experts, order, w, sizes, ends, rank):
             """The sorted slots ``start .. start + window``: their tokens'
             rows gathered, the three grouped products over the part of
             each expert's rows that lies in the window, and the results
             added back to their tokens with the router's weights. Gather
-            one way, scatter-add the other, over ``window`` rows."""
+            one way over ``window`` rows; the other way a scatter-add of
+            ``window`` rows or, given ``rank`` (``_gathers``), a gather of
+            tokens x top_k rows through it (``_combine``), and the same
+            for the rows' cotangent on its way to ``xf`` (``_take_rows``)."""
             with jax.named_scope("moe.dispatch"):
                 slots = lax.dynamic_slice_in_dim(order, start, window)
                 token = slots // k
-                rows = xf[token]
+                rows = (xf[token] if rank is None else
+                        _take_rows(xf, token, rank, start, ends[-1]))
                 stop = start + window
                 here = (jnp.clip(ends, start, stop)
                         - jnp.clip(ends - sizes, start, stop))
@@ -311,17 +488,21 @@ class RoutedExperts(BaseLayer):
                           * grouped_matmul(rows, experts["Wup"], here))
                 y_rows = grouped_matmul(hidden, experts["Wdown"], here)
             with jax.named_scope("moe.dispatch"):
-                by_slot = jnp.where(start + jnp.arange(window) < ends[-1],
-                                    w.reshape(-1)[slots], 0.0)
-                y = jnp.zeros((n, y_rows.shape[-1]), jnp.float32).at[
-                    token].add(y_rows.astype(jnp.float32)
-                               * by_slot[:, None])
+                if rank is None:
+                    by_slot = jnp.where(
+                        start + jnp.arange(window) < ends[-1],
+                        w.reshape(-1)[slots], 0.0)
+                    y = jnp.zeros((n, y_rows.shape[-1]), jnp.float32).at[
+                        token].add(y_rows.astype(jnp.float32)
+                                   * by_slot[:, None])
+                else:
+                    y = _combine(y_rows, w, slots, rank, start, ends[-1])
             # the rows the grouped products were given: what this window
             # covered of the held pairs
             return y, jnp.sum(here)
 
         experts = {name: params[name] for name in ("Wgate", "Wup", "Wdown")}
-        operands = (xf, experts, order, w, sizes, ends)
+        operands = (xf, experts, order, w, sizes, ends, rank)
 
         def first_window(*ops):
             return run(0, *ops)

@@ -416,6 +416,52 @@ def test_v5e_compiler_accepts(build, v5e, tpu_backend):
         _compiles_with_kernel(*build(S))
 
 
+def test_the_gather_dispatch_reads_its_windows_from_fast_memory(v5e):
+    """The routed layers' gather form at the Mellum2 share's shapes (16,384
+    tokens x top-8, a window of 65,536 sorted slots of 2304 bfloat16),
+    forward and gradient: the window's rows go in three blocks of 768
+    columns (96 MiB each, ``GATHER_OPERAND_BYTES``), and the compiler gives
+    them the fast memory (``S(1)`` in the operand's layout), where a gather
+    of the whole 288 MiB window reads from HBM at a fifth of the pace a row
+    (PERF.md section 6, PR 38)."""
+    import re
+
+    from deeplearning4j_tpu.nn.conf import experts
+
+    n, k, d, window = 16384, 8, 2304, 65536
+    assert experts._gathers(n * k, window)
+    assert experts._column_blocks(window, d, 2) == [(0, 768), (768, 1536),
+                                                    (1536, 2304)]
+
+    def grads(xf, w, order, n_held):
+        rank = experts._inverse(order).reshape(n, k)
+        slots = order[:window]
+
+        def loss(xf, w):
+            rows = experts._take_rows(xf, slots // k, rank, 0, n_held)
+            y = experts._combine(rows * jnp.bfloat16(0.5), w, slots, rank, 0,
+                                 n_held)
+            return jnp.sum(y ** 2)
+        return jax.grad(loss, (0, 1))(xf, w)
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    text = jax.jit(grads).lower(S((n, d), BF16), S((n, k), F32),
+                                S((n * k,), I32), S((), I32)
+                                ).compile().as_text()
+    layouts = {m.group(1): m.group(2) for m in re.finditer(
+        r"^\s*(?:ROOT\s+)?%([\w.\-]+) = (\S+)", text, re.M)}
+    blocks = [layouts[m.group(1)] for m in re.finditer(
+        r"= bf16\[131072,768\]\S* fusion\(%([\w.\-]+),", text)]
+    assert len(blocks) == 6, blocks          # combine and take-rows' backward
+    assert all(b.startswith("bf16[65536,768]") for b in blocks), blocks
+    # the placement is the compiler's own choice, program by program: all
+    # six in the Mellum2 cell's step, five of six in this one
+    assert sum("S(1)" in b for b in blocks) >= 4, blocks
+    assert "scatter" not in text
+
+
 def test_every_auto_family_has_a_case(tpu_backend):
     selected = {f for f, impl in pk.selection_snapshot().items()
                 if impl == "pallas"}

@@ -945,6 +945,175 @@ def test_a_quarter_share_runs_half_the_slots_or_all_of_them(push):
             1.0, float(jnp.max(jnp.abs(b))))
 
 
+@pytest.mark.parametrize(
+    "tokens,total,held,offset,top_k,push,ratio,softmax", [
+        (24, 8, 8, 0, 3, 0.0, 1, False),      # 144 slots in one window of 256
+        (64, 8, 8, 0, 1, 0.0, 1, True),
+        (256, 8, 2, 2, 1, 0.0, 2, True),      # a quarter held: half the slots
+        (256, 8, 2, 2, 1, 10.0, 2, True),
+        (128, 8, 2, 6, 3, 10.0, 2, False),    # 768 slots, windows of 384
+        (256, 32, 2, 4, 3, 0.0, 4, False),    # 1,536 slots, windows of 384
+        (250, 32, 2, 4, 3, 10.0, 4, True),    # 1,500 slots: 4 x 384, padded
+        (512, 32, 1, 31, 1, 0.0, 8, False),   # 1,024 slots, windows of 128
+        (512, 32, 1, 5, 1, 10.0, 8, True),
+    ], ids=["all_held_k3_padded", "all_held_k1", "ratio2_k1",
+            "ratio2_k1_every_window", "ratio2_k3_every_window", "ratio4_k3",
+            "ratio4_k3_padded_every_window", "ratio8_k1",
+            "ratio8_k1_every_window"])
+def test_the_gather_form_of_the_dispatch_is_the_scatter_form(
+        monkeypatch, tokens, total, held, offset, top_k, push, ratio,
+        softmax):
+    """The same layer, seed and operands under both forms of the dispatch
+    (the rule's constant moved out of the way): output, the gradients of
+    the input, the router and the three expert leaves, and the counters.
+    The forms differ only in the order in which a token's ``top_k`` float32
+    terms are added."""
+    from deeplearning4j_tpu.nn.conf import experts as module
+    from deeplearning4j_tpu.perf.compile_watch import GLOBAL
+    layer = _experts(held, offset, shared=0, total=total, top_k=top_k,
+                     softmax=softmax)
+    it = InputType.recurrent(12, tokens)
+    params, state = layer.init(jax.random.key(0), it)
+    state = dict(state,
+                 bias=jnp.zeros(total).at[offset:offset + held].set(push))
+    x = jax.random.normal(jax.random.key(1), (2, tokens, 12))
+    slots = 2 * tokens * top_k
+    window = -(-module._window_slots(slots, held, total) // 128) * 128
+    assert round(slots / window) == ratio
+    assert (-(-slots // window) * window > slots) == (slots % window > 0)
+
+    def both(p, x):
+        out, new = layer.apply(p, state, x)
+        return jnp.sum(jnp.sin(out)), (out, new)
+
+    got = {}
+    for form, constant in (("gather", float("inf")), ("scatter", 0.0)):
+        monkeypatch.setattr(module, "DISPATCH_GATHER_RATIO", constant)
+        before = GLOBAL.counter(f"moe.dispatch_{form}")
+        got[form] = jax.value_and_grad(both, (0, 1), has_aux=True)(params, x)
+        assert GLOBAL.counter(f"moe.dispatch_{form}") > before
+    (_, (out_g, new_g)), grads_g = got["gather"]
+    (_, (out_s, new_s)), grads_s = got["scatter"]
+    assert int(new_g["pairs_dropped"]) == int(new_s["pairs_dropped"]) == 0
+    assert np.array_equal(np.asarray(new_g["expert_tokens"]),
+                          np.asarray(new_s["expert_tokens"]))
+    many = int(new_g["pairs_held"]) > window
+    assert many == bool(push) or window >= slots
+    assert int(new_g["steps_every_window"]) == int(
+        new_s["steps_every_window"]) == int(many and window < slots)
+    want = _plain_routed(layer, params, x, state["bias"])
+    scale = max(1.0, float(jnp.max(jnp.abs(want))))
+    assert float(jnp.max(jnp.abs(out_g - want))) < TOL * scale
+    assert float(jnp.max(jnp.abs(out_g - out_s))) < TOL * scale
+    for name in ("Wr", "Wgate", "Wup", "Wdown"):
+        a, b = grads_g[0][name], grads_s[0][name]
+        assert float(jnp.max(jnp.abs(a - b))) < TOL * max(
+            1.0, float(jnp.max(jnp.abs(b)))), name
+    assert float(jnp.max(jnp.abs(grads_g[1] - grads_s[1]))) < TOL * max(
+        1.0, float(jnp.max(jnp.abs(grads_s[1]))))
+
+
+@pytest.mark.parametrize("rows,d,itemsize,blocks", [
+    (65536, 2304, 2, 3),      # a window's rows, bfloat16: 3 x 768 columns
+    (16384, 2304, 4, 2),      # the tokens' float32 cotangent: 2 x 1152
+    (16384, 2304, 2, 1),      # the tokens' rows: whole
+    (24576, 2304, 2, 1),      # the largest operand read fast on the chip
+    (28672, 2304, 2, 2),      # the smallest read slow
+    (65536, 2048, 2, 4), (10 ** 6, 2304, 2, 18), (10 ** 6, 100, 4, 1),
+], ids=["window_bf16", "tokens_f32", "tokens_bf16", "fast", "slow",
+        "width_2048", "one_tile_at_least", "no_lane_tiles"])
+def test_a_large_gather_operand_goes_in_equal_column_blocks(rows, d,
+                                                            itemsize, blocks):
+    from deeplearning4j_tpu.nn.conf import experts as module
+    got = module._column_blocks(rows, d, itemsize)
+    assert len(got) == blocks and got[0][0] == 0 and got[-1][1] == d
+    assert all(b[0] == a[1] for a, b in zip(got, got[1:]))
+    assert len({hi - lo for lo, hi in got}) == 1
+    if blocks > 1:
+        assert (got[0][1] % 128 == 0 and rows * got[0][1] * itemsize
+                <= module.GATHER_OPERAND_BYTES) or got[0][1] == 128
+
+
+def test_the_column_blocks_gather_what_the_whole_width_does(monkeypatch):
+    """``_sum_pairs`` and the combine's backward rule in three blocks of 128
+    columns against the whole width: the same rows to the bit."""
+    from deeplearning4j_tpu.nn.conf import experts as module
+    rows = jax.random.normal(jax.random.key(0), (40, 384))
+    rank = jax.random.permutation(jax.random.key(1), 48).reshape(16, 3)
+    weight = jax.random.normal(jax.random.key(2), (16, 3))
+    dy = jax.random.normal(jax.random.key(3), (16, 384))
+    slots = module._inverse(rank.reshape(-1))[:40]
+    kept = (rows, weight, slots, rank, jnp.int32(0), jnp.int32(30))
+
+    def both():
+        inside, at = module._in_window(rank, 0, 40, 30)
+        return (module._sum_pairs(rows, at, weight),
+                *module._combine_bwd(kept, dy)[:2])
+
+    whole = both()
+    monkeypatch.setattr(module, "GATHER_OPERAND_BYTES", 16 * 128 * 4)
+    assert len(module._column_blocks(40, 384, 4)) == 3
+    assert len(module._column_blocks(16, 384, 4)) == 3
+    blocked = both()
+    for a, b in zip(whole[:2], blocked):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    # a weight's cotangent is a row dot added block by block
+    assert float(jnp.max(jnp.abs(whole[2] - blocked[2]))) < TOL * float(
+        jnp.max(jnp.abs(whole[2])))
+
+
+def test_the_rank_is_the_inverse_of_the_sort():
+    from deeplearning4j_tpu.nn.conf.experts import _in_window, _inverse
+    key = jax.random.randint(jax.random.key(3), (300,), 0, 5)
+    order = jnp.argsort(key, stable=True)
+    rank = _inverse(order)
+    assert rank.dtype == jnp.int32
+    assert np.array_equal(np.asarray(order[rank]), np.arange(300))
+    assert np.array_equal(np.asarray(rank[order]), np.arange(300))
+    # the pairs a window of 128 from slot 128 holds, 200 of the slots held
+    inside, at = _in_window(rank, 128, 128, 200)
+    assert np.array_equal(np.sort(np.asarray(rank[inside])),
+                          np.arange(128, 200))
+    assert np.array_equal(np.asarray(at[inside]),
+                          np.asarray(rank[inside]) - 128)
+    assert int(jnp.min(at)) >= 0 and int(jnp.max(at)) <= 127
+
+
+@pytest.mark.parametrize("tokens,top_k,total,held,form", [
+    (16384, 8, 64, 16, "gather"),     # mellum2_train_16k_ep4share: ratio 2
+    (8192, 10, 512, 32, "scatter"),   # qwen3_next_train_8k_ep16share: 4
+    (8192, 8, 256, 8, "scatter"),     # kimi_linear_train_8k_ep32share: 8
+    (8192, 8, 16, 16, "gather"),      # every expert held: one chip's user
+    (8192, 8, 16, 8, "gather"),       # a half
+    (1200, 1, 8, 3, "gather"),        # three eighths: 1,200 over 1,024
+    (600, 2, 32, 2, "scatter"),       # 1,200 over 384
+], ids=["mellum_cell", "qwen_cell", "kimi_cell", "all_held", "half",
+        "three_eighths", "sixteenth"])
+def test_the_form_of_the_dispatch_follows_the_shapes_alone(tokens, top_k,
+                                                           total, held, form):
+    """Slots over window against ONE constant, read at trace time from the
+    two counters: nothing but the layer's shapes decides, and the Kimi and
+    Qwen cells' shapes keep the scatter form."""
+    from deeplearning4j_tpu.perf.compile_watch import GLOBAL
+
+    def counts():
+        return {f: GLOBAL.counter(f"moe.dispatch_{f}")
+                for f in ("gather", "scatter")}
+
+    layer = RoutedExperts(n_experts=total, experts_held=held, top_k=top_k,
+                          expert_size=8)
+    it = InputType.recurrent(16, tokens)
+    params, state = jax.eval_shape(
+        lambda: layer.init(jax.random.key(0), it))
+    before = counts()
+    jax.eval_shape(layer.apply, params, state,
+                   jax.ShapeDtypeStruct((1, tokens, 16), jnp.float32))
+    other = "scatter" if form == "gather" else "gather"
+    after = counts()
+    assert after[form] == before[form] + 1
+    assert after[other] == before[other]
+
+
 @pytest.mark.parametrize("push,took", [(0.0, 0), (10.0, 1)],
                          ids=["under_the_window", "over_the_window"])
 def test_the_layer_counts_the_steps_that_took_every_window(push, took):
@@ -1245,16 +1414,27 @@ def _owners(names):
         {n.rsplit("/", 1)[-1] for n in names if owner_of(n) is None})
 
 
+@pytest.mark.parametrize("total,form", [(32, "scatter"), (8, "gather")],
+                         ids=["scatter_form", "gather_form"])
 def test_a_routed_graph_s_step_has_an_owner_for_all_it_emitted(
-        step_op_names):
+        step_op_names, total, form):
     """The optimizer's instructions lie under ``optim.update``; what jax
     emitted without any owner is megablox's group bookkeeping alone (none
-    on the CPU, where ``lax.ragged_dot`` runs)."""
+    on the CPU, where ``lax.ragged_dot`` runs). Under either form of the
+    dispatch (a 16th held, a quarter held): the gather form's backward
+    rules carry ``moe.dispatch`` as the scatter form's transposes do."""
     net = _routed_graph(RoutedExperts(
-        n_experts=32, experts_held=2, expert_offset=4, top_k=2,
+        n_experts=total, experts_held=2, expert_offset=4, top_k=2,
         expert_size=8, shared_size=8, remat="full"))
+    from deeplearning4j_tpu.perf.compile_watch import GLOBAL
+    before = GLOBAL.counter(f"moe.dispatch_{form}")
     x = jax.ShapeDtypeStruct((2, 300), jnp.int32)
     names = step_op_names(net, [x], [x])
+    assert GLOBAL.counter(f"moe.dispatch_{form}") > before
+    backward = [n.rsplit("/", 1)[-1] for n in names
+                if "transpose(" in n and "/moe.dispatch/" in n]
+    assert "gather" in backward
+    assert ("scatter-add" in backward) == (form == "scatter")
     owners, unowned = _owners(names)
     assert any("/optim.update/" in n for n in names)
     assert unowned == [], unowned
