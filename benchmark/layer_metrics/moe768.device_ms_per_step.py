@@ -1,0 +1,16 @@
+"""Device time a step spends in operations that came from a ``RoutedExperts`` layer
+in the cell of the 256-expert sigmoid router over experts of 768 with a
+shared one (the trunk's five routed layers and the prediction module's):
+what ``moe.device_ms_per_step`` reads, by that reader's own code, under a name of
+its own, as ``moe512.device_ms_per_step`` and ``moe64.device_ms_per_step`` do. (The ``moe.*``
+entries of the manifest list the cells they are reported in, and a PR that
+adds a cell may not edit an entry: PERF.md section 7; ROADMAP Queue 2 item
+1a queues the fold.)"""
+
+LAYER = "routed experts"
+UNIT = "ms"
+MOVES = "train_items_per_s"
+
+
+def read(ctx):
+    return ctx["cell"].layer_reader("moe.device_ms_per_step")(ctx)

@@ -87,7 +87,7 @@ def _layer_name(i: Optional[int], layer) -> str:
 _N_OUT_OPTIONAL = ("TransformerEncoderBlock", "KimiDeltaAttention",
                    "GatedDeltaNet", "MultiHeadLatentAttention",
                    "GatedAttention", "RotaryAttention", "GatedFeedForward",
-                   "RoutedExperts")
+                   "RoutedExperts", "MultiTokenCombine")
 
 
 def _check_layer(layer, cur, name: str) -> List[ValidationIssue]:

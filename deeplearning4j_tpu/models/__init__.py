@@ -12,3 +12,4 @@ from deeplearning4j_tpu.models.kimi_linear import KimiLinear  # noqa: F401
 from deeplearning4j_tpu.models.qwen3_next import Qwen3Next  # noqa: F401
 from deeplearning4j_tpu.models.ouro import Ouro  # noqa: F401
 from deeplearning4j_tpu.models.mellum import Mellum2  # noqa: F401
+from deeplearning4j_tpu.models.joyai import JoyAIFlash  # noqa: F401

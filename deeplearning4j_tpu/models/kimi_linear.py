@@ -5,8 +5,10 @@ arXiv:2510.26692; e.g. moonshotai/Kimi-Linear-48B-A3B-Instruct).
 Not in the reference zoo. A decoder of pre-norm blocks,
 ``h = x + Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``, a final RMSNorm
 and an untied head. ``Attn`` is Kimi Delta Attention in the layers that
-``linear_attn_config.kda_layers`` names and latent attention without
-rotation in its ``full_attn_layers``; ``FFN`` is a dense SwiGLU in the
+``linear_attn_config.kda_layers`` names and latent attention in its
+``full_attn_layers`` (without rotation under ``mla_use_nope``, else turned
+in the interleaved pairing at ``rope_theta``; with a low-rank query where
+``q_lora_rank`` is set); ``FFN`` is a dense SwiGLU in the
 first ``first_k_dense_replace`` layers and routed experts plus the shared
 experts in the rest. Input: (batch, time) integer ids; labels: the next
 ids, as integers (``TokenOutputLayer``).
@@ -66,14 +68,18 @@ class KimiLinear(ZooModel):
                 low_rank=self.kda_low_rank, eps=c["rms_norm_eps"],
                 remat=self.remat)
         if index in la["full_attn_layers"]:
-            if not c.get("mla_use_nope", False) or c.get("q_lora_rank"):
+            rotated = not c.get("mla_use_nope", False)
+            if rotated and (c.get("rope_scaling")
+                            or not c.get("rope_interleave", True)):
                 raise NotImplementedError(
-                    "latent attention with rotated keys or a low-rank q "
-                    "projection is not built")
+                    "latent attention turns adjacent widths at the plain "
+                    "frequencies: no scaled rotation, no half-split pairing")
             return MultiHeadLatentAttention(
                 n_heads=c["num_attention_heads"],
                 nope_dim=c["qk_nope_head_dim"], rope_dim=c["qk_rope_head_dim"],
                 v_dim=c["v_head_dim"], kv_rank=c["kv_lora_rank"],
+                q_rank=c.get("q_lora_rank") or 0,
+                rope_theta=float(c["rope_theta"]) if rotated else 0.0,
                 block=self.attention_block, eps=c["rms_norm_eps"],
                 remat=self.remat)
         raise ValueError(f"layer {index} is in neither list of "

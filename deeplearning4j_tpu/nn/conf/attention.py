@@ -16,7 +16,8 @@ beyond one chip, the same math shards over the mesh via
 Beside them the three attention layers of today's decoders, all over
 ``blocked_causal_attention`` (tiles, own backward pass, Pallas kernels on a
 TPU): ``MultiHeadLatentAttention`` (as many k/v heads as query heads, q/k
-and v widths that differ, no rotation), ``GatedAttention`` (fewer k/v
+and v widths that differ; as fields a low-rank query and a decoupled
+rotation in the interleaved pairing), ``GatedAttention`` (fewer k/v
 heads than query heads, per-head q/k norms, a partial rotary embedding,
 an output gate) and ``RotaryAttention`` (the plain decoder attention: q, k,
 v, o projections, a rotary embedding over the whole head width, equal or
@@ -454,13 +455,25 @@ def blocked_causal_attention(q, k, v, block: int = 512, window=None):
 @register_layer
 @dataclasses.dataclass(frozen=True)
 class MultiHeadLatentAttention(BaseLayer):
-    """Multi-head latent attention without positional rotation (the
-    DeepSeek-V2 layout with ``mla_use_nope``): keys and values come from a
-    shared low-rank latent, ``[c; k_r] = W_kva x`` with ``c`` (``kv_rank``)
-    RMS-normalised; per head ``k = [W_kb^K c; k_r]`` (``k_r``, ``rope_dim``
-    wide, shared by the heads and NOT rotated), ``v = W_kb^V c``,
+    """Multi-head latent attention (the DeepSeek-V2 / V3 layout): keys and
+    values come from a shared low-rank latent, ``[c; k_r] = W_kva x`` with
+    ``c`` (``kv_rank``) RMS-normalised; per head ``k = [W_kb^K c; k_r]``
+    (``k_r``, ``rope_dim`` wide, shared by the heads), ``v = W_kb^V c``,
     ``q = W_q x`` (``nope_dim + rope_dim``), causal attention, ``W_o`` over
-    heads x ``v_dim``. q/k heads and v heads differ in width, which
+    heads x ``v_dim``. What the lines after ``mla_use_nope`` add are FIELDS
+    that default to their absence:
+
+        ``q_rank`` r > 0: the query through a latent of its own,
+        ``q = W_qb RMSNorm_q(W_qa x)`` (leaves ``Wqa``, ``q_norm``, ``Wqb``
+        in ``Wq``'s place; scope ``mla.q_lora``);
+        ``rope_theta`` > 0: the decoupled rotation, the last ``rope_dim``
+        widths of every query head and the ONE ``k_r`` (turned once, before
+        it is handed to the heads) turned by position in the interleaved
+        pairing (``rotate_interleaved``; scope ``mla.rope``). At 0 neither
+        is rotated (``mla_use_nope``).
+
+    With both off the layer draws the leaves and lowers to the program it
+    always did. q/k heads and v heads differ in width, which
     ``pallas.ops.tpu.flash_attention`` (``SelfAttentionLayer``'s kernel)
     does not take: scores go through ``blocked_causal_attention`` in tiles
     of ``block``, which on a TPU runs as the Pallas kernels of
@@ -470,7 +483,8 @@ class MultiHeadLatentAttention(BaseLayer):
     otherwise: off a TPU, one tile, an odd width. Which path a compiled
     program took is counted at trace time (``bump_active``):
     ``attention.mla_blocked`` with more than one tile,
-    ``attention.mla_single_tile`` otherwise, and beside them
+    ``attention.mla_single_tile`` otherwise, ``attention.mla_q_lora`` and
+    ``attention.mla_rotary`` once a layer that has them, and beside them
     ``kernel.pallas_blocked_attention`` / ``kernel.xla_blocked_attention``.
     A features mask zeroes the output at masked steps (right-padded batches
     are exact)."""
@@ -485,6 +499,8 @@ class MultiHeadLatentAttention(BaseLayer):
     block: int = 512
     eps: float = 1e-5
     weight_init: str = "xavier_fan_in"
+    q_rank: int = 0             # 0: one full-rank Wq
+    rope_theta: float = 0.0     # 0: q and k_r are not rotated
 
     supports_stateful = False
 
@@ -495,12 +511,16 @@ class MultiHeadLatentAttention(BaseLayer):
         return True
 
     def regularizable(self):
-        return ("Wq", "Wkva", "Wkvb", "Wo")
+        return (("Wqa", "Wqb") if self.q_rank else ("Wq",)) \
+            + ("Wkva", "Wkvb", "Wo")
 
     def _width(self, it: InputType) -> int:
         return self.n_out or self.n_in or it.size
 
     def output_type(self, it: InputType) -> InputType:
+        if self.rope_theta and self.rope_dim % 2:
+            raise ValueError(f"rope_dim {self.rope_dim} has to be even: the "
+                             "rotation pairs adjacent widths")
         return InputType.recurrent(self._width(it), it.timeseries_length)
 
     def init(self, rng, it: InputType, dtype=jnp.float32):
@@ -512,8 +532,16 @@ class MultiHeadLatentAttention(BaseLayer):
             return init_weights(key, (n_in, n_out), n_in, n_out,
                                 self.weight_init, self.dist, dtype)
 
+        d_q = h * (self.nope_dim + self.rope_dim)
+        if self.q_rank:
+            ka, kb = jax.random.split(ks[0])
+            query = {"Wqa": dense(ka, d, self.q_rank),
+                     "q_norm": jnp.ones((self.q_rank,), dtype),
+                     "Wqb": dense(kb, self.q_rank, d_q)}
+        else:
+            query = {"Wq": dense(ks[0], d, d_q)}
         return {
-            "Wq": dense(ks[0], d, h * (self.nope_dim + self.rope_dim)),
+            **query,
             "Wkva": dense(ks[1], d, self.kv_rank + self.rope_dim),
             "kv_norm": jnp.ones((self.kv_rank,), dtype),
             "Wkvb": dense(ks[2], self.kv_rank,
@@ -528,10 +556,25 @@ class MultiHeadLatentAttention(BaseLayer):
         x = dropout_input(x, self.dropout, train, rng)
         bsz, t, _ = x.shape
         h, nope, rope = self.n_heads, self.nope_dim, self.rope_dim
-        q = (x @ params["Wq"]).reshape(bsz, t, h, nope + rope)
+        if self.q_rank:
+            bump_active("attention.mla_q_lora")
+            with jax.named_scope("mla.q_lora"):
+                q = rms_norm(x @ params["Wqa"], params["q_norm"], self.eps) \
+                    @ params["Wqb"]
+        else:
+            q = x @ params["Wq"]
+        q = q.reshape(bsz, t, h, nope + rope)
         kva = x @ params["Wkva"]
         c = rms_norm(kva[..., :self.kv_rank], params["kv_norm"], self.eps)
         k_r = kva[..., self.kv_rank:]
+        if self.rope_theta:
+            bump_active("attention.mla_rotary")
+            with jax.named_scope("mla.rope"):
+                positions = jnp.arange(t)
+                q = jnp.concatenate(
+                    [q[..., :nope], rotate_interleaved(
+                        q[..., nope:], positions, self.rope_theta)], -1)
+                k_r = rotate_interleaved(k_r, positions, self.rope_theta)
         kvb = (c @ params["Wkvb"]).reshape(bsz, t, h, nope + self.v_dim)
         k = jnp.concatenate(
             [kvb[..., :nope],
@@ -598,6 +641,40 @@ def rotate_half_split(x, positions, rotary_dim: int, theta: float,
     x2 = x[..., half:rotary_dim].astype(jnp.float32)
     turned = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
     return jnp.concatenate([turned.astype(x.dtype), x[..., rotary_dim:]], -1)
+
+
+@functools.lru_cache(maxsize=None)
+def _pair_swap(width: int):
+    """The (width, width) matrix S with ``(x @ S)[2j] = -x[2j + 1]`` and
+    ``(x @ S)[2j + 1] = x[2j]``: one +-1 a column, so the float32 product
+    at the highest precision is exact."""
+    s = np.zeros((width, width), np.float32)
+    j = np.arange(0, width, 2)
+    s[j + 1, j], s[j, j + 1] = -1.0, 1.0
+    return s
+
+
+def rotate_interleaved(x, positions, theta: float):
+    """A rotary embedding over ALL widths of ``x`` (batch, time, ..., width)
+    in the interleaved pairing (``rope_interleave`` of the DeepSeek-V3
+    family's configs): adjacent widths (2j, 2j + 1) are one pair, turned by
+    ``positions * theta^(-2j / width)``; ``rotate_half_split`` pairs width
+    j with j + width / 2. ``out = x cos + partner sin`` with
+    ``partner[2j] = -x[2j + 1]``, ``partner[2j + 1] = x[2j]``; the partner is
+    ``x`` times a signed permutation (``_pair_swap``: 2 width^2 operations
+    a row on the MXU, exact; shifts along the width axis read 8.7 ms a
+    layer and step on the chip at 8192 x 32 heads of 64, this 1.8: PERF.md
+    section 6, PR 39). Angles, sines and the turn itself in float32; the
+    result in ``x``'s type."""
+    width = x.shape[-1]
+    freq = theta ** (-jnp.arange(width // 2, dtype=jnp.float32) * 2.0 / width)
+    angle = jnp.repeat(positions.astype(jnp.float32)[:, None] * freq, 2, -1)
+    shape = (angle.shape[0],) + (1,) * (x.ndim - 3) + (width,)
+    cos, sin = jnp.cos(angle).reshape(shape), jnp.sin(angle).reshape(shape)
+    xf = x.astype(jnp.float32)
+    partner = jnp.matmul(xf, _pair_swap(width),
+                         precision=lax.Precision.HIGHEST)
+    return (xf * cos + partner * sin).astype(x.dtype)
 
 
 @register_layer
@@ -872,4 +949,5 @@ class RotaryAttention(BaseLayer):
 
 __all__ = ["SelfAttentionLayer", "TransformerEncoderBlock",
            "MultiHeadLatentAttention", "GatedAttention", "RotaryAttention",
+           "rotate_interleaved",
            "blocked_causal_attention", "rotate_half_split", "yarn_inv_freq"]

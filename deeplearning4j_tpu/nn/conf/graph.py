@@ -6,8 +6,10 @@ Parity surface: reference ``nn/conf/ComputationGraphConfiguration.java``
 ElementWiseVertex, StackVertex, UnstackVertex, SubsetVertex, ReshapeVertex,
 ScaleVertex, ShiftVertex, L2NormalizeVertex, L2Vertex, PreprocessorVertex,
 LastTimeStepVertex, DuplicateToTimeSeriesVertex. Not in the reference:
-``LoopVertex``, a sub-graph run several times over one set of weights (a
-layer to the networks: it owns parameters).
+``TimeShiftVertex`` (position i gets the value of position i + steps),
+``StackStatesVertex`` (several states on a new leading axis, for a layer
+that reads more than one) and ``LoopVertex``, a sub-graph run several times
+over one set of weights (a layer to the networks: it owns parameters).
 
 TPU-native: a vertex is a pure function of its input activations; the whole
 DAG is traced in topological order into ONE XLA program (the reference's
@@ -200,6 +202,44 @@ class ShiftVertex(GraphVertex):
 
     def apply(self, *inputs):
         return inputs[0] + self.shift
+
+
+@register_vertex
+@dataclasses.dataclass(frozen=True)
+class TimeShiftVertex(GraphVertex):
+    """Position i of (batch, time, ...) gets the value of position
+    i + ``steps`` along the time axis; the last ``steps`` positions, which
+    have no such value, get zeros (a reader masks them). A multi-token
+    prediction module reads the NEXT token's embedding this way from the
+    model's one embedding vertex, whose table then gets its gradient from
+    both uses."""
+
+    steps: int = 1
+
+    def apply(self, *inputs):
+        x = inputs[0]
+        if not 0 < self.steps < x.shape[1]:
+            raise ValueError(f"a shift of {self.steps} over {x.shape[1]} "
+                             "steps")
+        tail = jnp.zeros((x.shape[0], self.steps) + x.shape[2:], x.dtype)
+        return jnp.concatenate([x[:, self.steps:], tail], axis=1)
+
+
+@register_vertex
+@dataclasses.dataclass(frozen=True)
+class StackStatesVertex(GraphVertex):
+    """Its inputs, alike in type, on a new leading axis in front of the
+    batch (``InputType.passes``, as a stacked ``LoopVertex`` hands out its
+    passes): what a layer reads that takes more than one state, a graph
+    layer having one input."""
+
+    def output_type(self, *its):
+        if any(it != its[0] for it in its[1:]) or its[0].passes:
+            raise ValueError(f"states of one type, each one state: {its}")
+        return dataclasses.replace(its[0], passes=len(its))
+
+    def apply(self, *inputs):
+        return jnp.stack(inputs, axis=0)
 
 
 @register_vertex

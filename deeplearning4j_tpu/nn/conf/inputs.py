@@ -25,8 +25,9 @@ class InputType:
     width: int = 0
     channels: int = 0
     timeseries_length: Optional[int] = None
-    # a ``LoopVertex``'s stacked output: this many passes on a new leading
-    # axis, in front of the batch; the other fields describe one pass
+    # a ``LoopVertex``'s stacked output (or a ``StackStatesVertex``'s): this
+    # many passes on a new leading axis, in front of the batch; the other
+    # fields describe one pass
     passes: Optional[int] = None
 
     # ---- factories (InputType.feedForward etc. in the reference) ----

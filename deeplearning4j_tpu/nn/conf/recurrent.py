@@ -419,6 +419,100 @@ class ExitWeightedTokenOutputLayer(TokenOutputLayer):
 
 @register_layer
 @dataclasses.dataclass(frozen=True)
+class MultiTokenOutputLayer(TokenOutputLayer):
+    """The output layer of a model with multi-token prediction modules
+    (DeepSeek-V3, arXiv:2412.19437 section 2.2): its input is a
+    ``StackStatesVertex``'s (1 + D, batch, time, n_in), the trunk's final
+    state first and then the D modules', every state is scored by the ONE
+    head ``W``, module k against the labels k steps further on with the
+    positions that have none masked, and the training loss is the trunk's
+    plus ``module_weight`` / D times the modules'
+    (``lossfunctions.blocked_multi_token_mcxent``: ``TokenOutputLayer``'s
+    block loop over the (state, block) pairs, one loop, the head's gradient
+    summed in one carry). ``score`` reports the same sum. INTEGER labels
+    (batch, time), as ``TokenOutputLayer``. ``apply`` / ``output`` give the
+    trunk's softmax: drafting with the modules at inference is not built."""
+
+    module_weight: float = 0.3
+
+    def output_type(self, it: InputType) -> InputType:
+        if not it.passes or it.passes < 2:
+            raise ValueError("expects a StackStatesVertex's trunk and module "
+                             "states, the input brings one state")
+        return InputType.recurrent(self.n_out, it.timeseries_length)
+
+    def _logits(self, preout):
+        return super()._logits({**preout, "x": preout["x"][0]})
+
+    def compute_score(self, labels, preout, mask=None):
+        if not self._blocked():
+            raise ValueError("the multi-token loss is sparse_mcxent over a "
+                             "softmax without class weights")
+        from deeplearning4j_tpu.nn.lossfunctions import (
+            blocked_multi_token_mcxent)
+        return blocked_multi_token_mcxent(
+            preout["x"], preout["W"], preout.get("b"), labels, mask,
+            self.time_block, self.module_weight)
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class MultiTokenCombine(BaseLayer):
+    """The input of a multi-token prediction module (DeepSeek-V3,
+    arXiv:2412.19437 eq. 21): from a ``StackStatesVertex``'s (2, batch,
+    time, d), the state h of the depth before and the embedding e of the
+    token one step further on (a ``TimeShiftVertex`` of the model's
+    embedding), ``[RMSNorm_h(h) ; RMSNorm_e(e)] W`` with ``W`` (2 d, d) and
+    no bias (leaves ``h_norm``, ``e_norm``, ``W``; scope ``mtp.combine``).
+    Counted at trace time, once a module: ``mtp.modules``."""
+
+    n_in: Optional[int] = None
+    n_out: int = 0              # model width; inferred from the input when 0
+    eps: float = 1e-6
+    weight_init: str = "xavier_fan_in"
+
+    def input_kind(self):
+        return "rnn"
+
+    def is_recurrent(self):
+        return True
+
+    def regularizable(self):
+        return ("W",)
+
+    def output_type(self, it: InputType) -> InputType:
+        if it.passes != 2:
+            raise ValueError("expects a StackStatesVertex's two states (the "
+                             "state before, the next token's embedding)")
+        return InputType.recurrent(self.n_out or self.n_in or it.size,
+                                   it.timeseries_length)
+
+    def init(self, rng, it: InputType, dtype=jnp.float32):
+        d = self.n_in or it.size
+        n_out = self.n_out or d
+        return {"h_norm": jnp.ones((d,), dtype),
+                "e_norm": jnp.ones((d,), dtype),
+                "W": init_weights(rng, (2 * d, n_out), 2 * d, n_out,
+                                  self.weight_init, self.dist, dtype)}, {}
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        from deeplearning4j_tpu.nn.conf.normalization import rms_norm
+        from deeplearning4j_tpu.perf.compile_watch import bump_active
+
+        bump_active("mtp.modules")
+        x = dropout_input(x, self.dropout, train, rng)
+        with jax.named_scope("mtp.combine"):
+            out = jnp.concatenate(
+                [rms_norm(x[0], params["h_norm"], self.eps),
+                 rms_norm(x[1], params["e_norm"], self.eps)], -1) \
+                @ params["W"]
+        if mask is not None:             # masked steps emit zeros
+            out = out * mask[..., None].astype(out.dtype)
+        return out, state
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
 class EmbeddingLayer(BaseLayer):
     """Index -> vector lookup (reference nn/conf/layers/EmbeddingLayer.java +
     nn/layers/feedforward/embedding/EmbeddingLayer.java): input is a column of

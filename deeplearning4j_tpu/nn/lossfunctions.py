@@ -105,8 +105,10 @@ def _blocked_ce(x, w, b, ids, coef):
     ``b`` None or (classes,), ``ids`` (blocks, batch, block) int32,
     ``coef`` (blocks, batch, block) float32, one number a token (the
     mask over the count; for a looped model times a pass's exit
-    probability, with the passes' blocks laid end to end). The one block
-    loop under ``blocked_sparse_mcxent`` and ``blocked_exit_weighted_mcxent``.
+    probability, with the passes' blocks laid end to end; for a model with
+    prediction modules a state's weight, the states' blocks laid end to
+    end). The one block loop under ``blocked_sparse_mcxent``,
+    ``blocked_exit_weighted_mcxent`` and ``blocked_multi_token_mcxent``.
 
     Called without differentiation (``score``, evaluation) this body runs:
     one product a block, no gradient. Under ``jax.grad`` the rule below
@@ -191,6 +193,14 @@ def _split_time(a, axis, n, pad):
         a = jnp.pad(a, [(0, pad if i == axis else 0) for i in range(a.ndim)])
     a = a.reshape(a.shape[:axis] + (n, -1) + a.shape[axis + 1:])
     return jnp.moveaxis(a, axis, 0)
+
+
+def _stacked_blocks(a, n, pad):
+    """``a`` (stacked, batch, time, ...) as (stacked x n, batch, block, ...):
+    each stacked entry's ``n`` time blocks, one entry after another (the
+    passes of a looped model, the states of one with prediction modules)."""
+    a = _split_time(a, 2, n, pad)                      # (n, stacked, ...)
+    return jnp.moveaxis(a, 0, 1).reshape((-1,) + a.shape[2:])
 
 
 def _ids_and_mask(ids, mask, bsz, t):
@@ -278,17 +288,53 @@ def blocked_exit_weighted_mcxent(x, w, b, wg, bg, ids, mask=None,
         coef = p * scale
     with jax.named_scope("loop.exit_head"):
         # (pass, block) pairs, one after another
-        def pairs(a):
-            a = _split_time(a, 2, n, pad)              # (n, passes, ...)
-            return jnp.moveaxis(a, 0, 1).reshape((passes * n,) + a.shape[2:])
-
-        total = _blocked_ce(pairs(x), w, b,
+        total = _blocked_ce(_stacked_blocks(x, n, pad), w, b,
                             jnp.tile(_split_time(ids, 1, n, pad),
-                                     (passes, 1, 1)), pairs(coef))
+                                     (passes, 1, 1)),
+                            _stacked_blocks(coef, n, pad))
     if not entropy_weight:
         return total
     with jax.named_scope("loss.exit_weighted"):
         return total + entropy_weight * jnp.sum(jnp.sum(p * logp, 0) * scale)
+
+
+def blocked_multi_token_mcxent(x, w, b, ids, mask=None, block: int = 1024,
+                              module_weight: float = 0.3):
+    """The training loss of a model with D multi-token prediction modules
+    (DeepSeek-V3, arXiv:2412.19437 section 2.2), over
+    ``blocked_sparse_mcxent``'s block loop (``_blocked_ce``): ``x``
+    (1 + D, batch, time, n_in) holds the trunk's state and the D modules',
+    every state is scored by the one head ``w`` (n_in, classes) (``b`` None
+    or (classes,)), state k against the ids k steps further on (``ids``
+    (batch, time) are the trunk's labels, the ids one step on; module k's
+    are ``ids`` shifted by k, its last k positions having none and being
+    masked), and the loss is
+
+        L_main + module_weight / D * sum_k L_k,
+        L_k = (1 / count) sum_i m_i m_{i+k} CE(x_k[i] w + b, ids[i + k])
+
+    with ``count`` the trunk's unmasked steps for every term (the report's
+    1 / T). ONE loop over the (state, time block) pairs, so that under
+    ``jax.grad`` the head's gradient is summed in one carry (three products
+    a pair). No forward-mode rule. Returns a scalar."""
+    states, bsz, t, _ = x.shape
+    modules = states - 1
+    ids, m = _ids_and_mask(ids, mask, bsz, t)
+    block, pad, n = _time_blocks(t, block)
+    with jax.named_scope("loss.multi_token"):
+        def ahead(a, k):            # position i gets a[i + k]; zeros behind
+            return jnp.pad(a[:, k:], ((0, 0), (0, k)))
+
+        scale = 1.0 / jnp.maximum(jnp.sum(m), 1.0)
+        want = jnp.stack([ahead(ids, k) for k in range(states)])
+        coef = jnp.stack([m * scale] + [
+            m * ahead(m, k) * (scale * module_weight / modules)
+            for k in range(1, states)])
+    with jax.named_scope("loss.blocked"):
+        # (state, block) pairs, one after another
+        return _blocked_ce(_stacked_blocks(x, n, pad), w, b,
+                           _stacked_blocks(want, n, pad),
+                           _stacked_blocks(coef, n, pad))
 
 
 def _score_nll(labels, preout, activation, weights):
