@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from deeplearning4j_tpu.nn.activations import get_activation
 from deeplearning4j_tpu.nn.conf.inputs import InputType
@@ -49,6 +50,8 @@ from deeplearning4j_tpu.nn.conf.layers import (
     BaseLayer, dropout_input, register_layer,
 )
 from deeplearning4j_tpu.nn.initializers import init_weights
+from deeplearning4j_tpu.perf import pallas as pk
+from deeplearning4j_tpu.perf.pallas import attention as kernels
 
 
 def _heads(x, h):
@@ -397,7 +400,9 @@ def _blocked_attention(q, k, v, block, window=None):
 
 
 def _blocked_attention_fwd(q, k, v, block, window):
-    out, lse = _blocked_forward(q, k, v, block, window)
+    # the kernels' names for the kernels' two residuals: one contract
+    out, lse = map(checkpoint_name, _blocked_forward(q, k, v, block, window),
+                   kernels.KEPT)
     return out, (q, k, v, out, lse)
 
 
@@ -432,10 +437,11 @@ def blocked_causal_attention(q, k, v, block: int = 512, window=None):
     VMEM, on a TPU for more than one tile of a length that is a multiple
     of 128, q, k, v alike in bfloat16 or float32, head widths multiples of
     64 up to 256; plain ``jax.numpy`` in tiles of ``block`` everywhere
-    else, and as the reference the kernels are held to."""
-    from deeplearning4j_tpu.perf import pallas as pk
-    from deeplearning4j_tpu.perf.pallas import attention as kernels
-
+    else, and as the reference the kernels are held to. Either execution
+    names its output and its log-sum-exp, padded as they are made, with the
+    ``checkpoint_name``s of ``kernels.KEPT``: a rematerialised layer whose
+    type declares them (``remat_keeps``) keeps the two and runs the forward
+    pass once."""
     t = q.shape[2]
     if window is not None and window >= t:
         window = None
@@ -487,7 +493,17 @@ class MultiHeadLatentAttention(BaseLayer):
     ``attention.mla_rotary`` once a layer that has them, and beside them
     ``kernel.pallas_blocked_attention`` / ``kernel.xla_blocked_attention``.
     A features mask zeroes the output at masked steps (right-padded batches
-    are exact)."""
+    are exact).
+
+    Rematerialised (``remat=``) the layer KEEPS the attention's output and
+    log-sum-exp (``remat_keeps`` = ``kernels.KEPT``, the names both
+    executions give them): the backward pass makes the projections, the
+    rotation and q, k, v again and reads the two, so the tile pairs run
+    forward once a layer and step (``mla_attend_fwd`` once, where
+    recomputing ran it twice). It costs ``remat_kept_bytes``: O in the type
+    the layer computes in and a float32 a token and head, 68 MB a layer at
+    8192 tokens and 32 heads of 128 in bfloat16.
+    ``remat="nothing_saveable"`` keeps nothing. (PERF.md §5-6, PR 40.)"""
 
     n_in: Optional[int] = None
     n_out: int = 0              # model width; inferred from the input when 0
@@ -503,6 +519,11 @@ class MultiHeadLatentAttention(BaseLayer):
     rope_theta: float = 0.0     # 0: q and k_r are not rotated
 
     supports_stateful = False
+    remat_keeps = kernels.KEPT
+
+    def remat_kept_bytes(self, it: InputType, dtype=jnp.float32) -> int:
+        return kernels.kept_bytes(it.timeseries_length or 1, self.n_heads,
+                                  self.v_dim, self.block, dtype)
 
     def input_kind(self):
         return "rnn"

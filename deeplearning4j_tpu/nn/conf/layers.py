@@ -268,8 +268,10 @@ class Layer:
     # type declares it, and ``remat_kept_bytes`` says what it costs.
     remat_keeps = ()
 
-    def remat_kept_bytes(self, input_type: InputType) -> int:
-        """Bytes of ``remat_keeps`` for ONE example of ``input_type``."""
+    def remat_kept_bytes(self, input_type: InputType,
+                         dtype=jnp.float32) -> int:
+        """Bytes of ``remat_keeps`` for ONE example of ``input_type`` in a
+        network that computes in ``dtype``."""
         return 0
 
     # ---- shape inference ----

@@ -328,7 +328,7 @@ class KimiDeltaAttention(BaseLayer):
     supports_stateful = False   # no rnn_time_step carry (yet)
     remat_keeps = kda_kernels.KEPT
 
-    def remat_kept_bytes(self, it: InputType) -> int:
+    def remat_kept_bytes(self, it: InputType, dtype=jnp.float32) -> int:
         return kda_kernels.kept_bytes(it.timeseries_length or 1,
                                       self.n_heads, self.head_dim, self.chunk)
 
@@ -466,7 +466,7 @@ class GatedDeltaNet(BaseLayer):
     supports_stateful = False   # no rnn_time_step carry (yet)
     remat_keeps = kda_kernels.KEPT
 
-    def remat_kept_bytes(self, it: InputType) -> int:
+    def remat_kept_bytes(self, it: InputType, dtype=jnp.float32) -> int:
         return kda_kernels.kept_bytes(it.timeseries_length or 1,
                                       self.n_value_heads, self.head_dim,
                                       self.chunk)

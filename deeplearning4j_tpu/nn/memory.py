@@ -41,7 +41,8 @@ class LayerMemoryReport:
     remat: Optional[str] = None
     # what the rematerialised layer keeps all the same, for ONE example
     # (its type's ``remat_keeps``: the delta-rule scan's output and chunk
-    # states where the kernels run); counted into the activation total
+    # states where the kernels run, the latent attention's output and
+    # log-sum-exp); counted into the activation total
     remat_kept_bytes_per_example: int = 0
 
     def to_dict(self):
@@ -138,11 +139,12 @@ def _type_shape(it) -> tuple:
     return (it.flat_size(),)
 
 
-def _kept_bytes(layer, it) -> int:
+def _kept_bytes(layer, it, dtype) -> int:
     """Bytes one example adds to what ``layer`` holds from its forward to
-    its backward pass by the names its rematerialisation keeps."""
+    its backward pass by the names its rematerialisation keeps, in a
+    network that computes in ``dtype``."""
     from deeplearning4j_tpu.perf.fusion import kept_names
-    return int(layer.remat_kept_bytes(it)) if kept_names(layer) else 0
+    return int(layer.remat_kept_bytes(it, dtype)) if kept_names(layer) else 0
 
 
 def _input_type_bytes(it, itemsize: int):
@@ -166,7 +168,7 @@ def get_memory_report(net, minibatch: int = 32,
     for i, (layer, it) in enumerate(zip(net.layers, types)):
         out_t = layer.output_type(it)
         act_bytes, act_shape = _input_type_bytes(out_t, itemsize)
-        kept = _kept_bytes(layer, it)
+        kept = _kept_bytes(layer, it, conf.dtype)
         p_bytes = _tree_bytes(net.params[i])
         n_params = sum(a.size for a in jax.tree_util.tree_leaves(net.params[i]))
         reports.append(LayerMemoryReport(
@@ -267,7 +269,7 @@ def conf_memory_report(conf, input_type=None, minibatch: int = 32,
         except ValueError:
             out_t = it
         act_bytes, act_shape = _input_type_bytes(out_t, itemsize)
-        kept = _kept_bytes(layer, it)
+        kept = _kept_bytes(layer, it, conf.dtype)
         reports.append(LayerMemoryReport(
             name=name, layer_class=type(layer).__name__,
             num_params=n_params, param_bytes=p_bytes,

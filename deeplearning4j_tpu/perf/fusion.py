@@ -28,8 +28,9 @@ attacks exactly that traffic, three ways:
   policy (gradient checkpointing, Chen et al. 2016), trading recompute
   FLOPs for saved-activation HBM. ``"full"`` means "recompute everything
   but what the layer TYPE names as dearer to recompute than to keep"
-  (``Layer.remat_keeps``: ``jax.ad_checkpoint.checkpoint_name``s, e.g. the
-  delta-rule scan's output and chunk states, perf/pallas/kda.py); a layer
+  (``Layer.remat_keeps``: ``jax.ad_checkpoint.checkpoint_name``s: the
+  delta-rule scan's output and chunk states, perf/pallas/kda.py; the latent
+  attention's output and log-sum-exp, perf/pallas/attention.py); a layer
   that names nothing recomputes everything, as before. The saving policies
   keep what they keep and the names too; ``"nothing_saveable"`` keeps
   nothing, names included: the way back for a run short of memory.

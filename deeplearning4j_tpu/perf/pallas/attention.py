@@ -58,6 +58,25 @@ far edge (``u > t - w``) is masked in the pairs that cross it, traced
 apart from the pairs that need no mask, as the diagonal is. dq's scratch
 stays the whole time axis. ``window=None`` is the triangle: its tables,
 grid and kernel bodies are what they were.
+
+Under a layer's rematerialisation (``apply_layer``, ``remat=``) the forward
+kernel's two outputs carry the ``checkpoint_name``s of ``KEPT``, as the
+kernel wrote them: O heads-major (batch, heads, time, d_v) in ``v``'s type
+(its transpose and the cut of the padding fuse into whoever reads them) and
+the log-sum-exp (batch, heads, 1, time) in float32. They are the two
+residuals of the backward rule that only the forward kernel can make again.
+A layer type that declares the names (``remat_keeps``:
+``MultiHeadLatentAttention``) runs ``mla_attend_fwd`` once a layer and step,
+the backward pass reading what the first pass wrote, for ``kept_bytes`` a
+layer (260 bytes a token and head of 128 in bfloat16: 68 MB at 8192 tokens
+and 32 heads); q, k, v are residuals too, are not named and are made again
+(the projections and their transposes). A type that declares nothing
+(``RotaryAttention``, ``GatedAttention``) holds no policy that knows the
+names: the ``name`` equations lower to their operands and its compiled
+program is what it was, two forward kernels a layer (its lowered text too,
+but for the numbers MLIR's symbol table gives private functions: an
+equation's out-of-line lowering takes one even when it is inlined away).
+``remat="nothing_saveable"`` keeps nothing for any type.
 """
 
 from __future__ import annotations
@@ -67,10 +86,17 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from deeplearning4j_tpu.perf import pallas as _pk
 
-__all__ = ["supported", "blocked_attention"]
+__all__ = ["supported", "blocked_attention", "KEPT", "kept_bytes"]
+
+# the forward pass's outputs by their ``checkpoint_name``: O (batch, heads,
+# time, d_v) and the log-sum-exp the backward pass makes the probabilities
+# from; ``blocked_causal_attention``'s ``jax.numpy`` execution gives its own
+# the same names
+KEPT = ("blocked_attention.o", "blocked_attention.lse")
 
 _F32 = jnp.float32
 _LANES = 128
@@ -127,6 +153,15 @@ def supported(q, k, v, block: int, window=None) -> bool:
     if _dq_bytes_a_head(t, q.shape[-1], q.dtype) > _DQ_VMEM:
         return False
     return _pk.interpret() or jax.default_backend() == "tpu"
+
+
+def kept_bytes(time: int, heads: int, v_dim: int, block: int, dtype) -> int:
+    """Bytes of ``KEPT`` for one sequence of ``time`` steps: O (heads, time,
+    d_v) in ``dtype`` and the log-sum-exp (heads, time) in float32, at the
+    length ``blocked_causal_attention`` pads to (whole tiles of ``block``);
+    the same in both executions."""
+    padded = time + (-time) % min(block, time)
+    return heads * padded * (v_dim * jnp.dtype(dtype).itemsize + 4)
 
 
 def _heads_a_step(h: int, most: int) -> int:
@@ -379,7 +414,8 @@ def _forward(q, k, v, save: bool, interpret: bool, window=None):
         [pltpu.VMEM((hb, tile, _LANES), _F32),
          pltpu.VMEM((hb, tile, _LANES), _F32),
          pltpu.VMEM((hb, tile, dv), _F32)])(qi, kj, q, k, v)
-    return tuple(outs) if save else outs[0]
+    # named as the kernel wrote them (the module's last paragraph)
+    return tuple(map(checkpoint_name, outs, KEPT)) if save else outs[0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "window"))
