@@ -265,7 +265,9 @@ class Layer:
     # to recompute than to hold: the ``checkpoint_name``s its computation
     # gives them (``apply_layer`` joins them to the policy; ``"full"`` then
     # keeps these alone, ``"nothing_saveable"`` nothing). Not a field: the
-    # type declares it, and ``remat_kept_bytes`` says what it costs.
+    # type declares it, and ``remat_kept_bytes`` says what it costs (the
+    # delta-rule layers: their scan's results and their wide projections'
+    # outputs; the latent attention: its attention's output and log-sum-exp).
     remat_keeps = ()
 
     def remat_kept_bytes(self, input_type: InputType,

@@ -55,12 +55,24 @@ in or of dq, dk, dv, dg out). Measured in the benchmark's two token cells:
 PERF.md §5-6, PR 31.
 
 Rematerialised (``remat=``), both layers keep what their scan's kernels
-name (``remat_keeps`` = ``kda.KEPT``: o and the chunks' entry states, float32,
-``remat_kept_bytes``), so the backward pass recomputes the projections and
-the input kernel and reads the scan's results: ``kda_scan_fwd`` once a layer
-and step (PERF.md §5-6, PR 36). ``remat="nothing_saveable"`` keeps nothing;
-the ``jax.numpy`` scan names nothing and checkpoints its own groups of
-chunks.
+name (``kda.KEPT``: o and the chunks' entry states, float32) and outputs of
+the wide projections in front of the input path (``PROJECTIONS_KEPT``: KDA's
+``x Wq``, ``x Wk`` and the decay's latent ``x Wf1``, Gated DeltaNet's
+``x Wqkvz``, in the compute type as the products wrote them, named in both
+executions): ``remat_keeps`` is the two together and ``remat_kept_bytes``
+their cost. The projections' outputs are the only residuals of the input
+path (the kernel's backward makes the convolution, SiLU and norm again from
+them, as ``causal_depthwise_conv``'s transpose reads them), so the backward
+pass runs none of the named products a second time and makes q, k, v, g
+again with the input kernel alone. NOT named: KDA's ``x Wv`` (with it the
+TPU's scheduler reorders the whole Kimi step: 0.58 GB more held for no
+shorter a step, PERF.md §6, PR 41; v needs no norm and is the cheapest to
+make again), q, k, v, g, b themselves (1.34 GB over Kimi's four layers for
+the 3.7 ms a step of ``kda_inputs_fwd``) and the narrow products (``Wf2``,
+``Wb`` / ``Wba``, the gate's): those are made again. The scan's results are
+read: ``kda_scan_fwd`` once a layer and step (PERF.md §5-6, PRs 36 and 41).
+``remat="nothing_saveable"`` keeps nothing; the ``jax.numpy`` scan names
+nothing of its own and checkpoints its groups of chunks.
 """
 
 from __future__ import annotations
@@ -72,6 +84,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers import (
@@ -82,6 +95,29 @@ from deeplearning4j_tpu.nn.initializers import init_weights
 from deeplearning4j_tpu.perf import pallas as pk
 from deeplearning4j_tpu.perf.pallas import kda as kda_kernels
 from deeplearning4j_tpu.perf.pallas import kda_inputs
+
+
+# the wide projections' outputs by their ``checkpoint_name`` (one name, several
+# values): what ``kda_inputs`` and ``causal_depthwise_conv`` keep as residuals
+PROJECTIONS_KEPT = ("delta_rule.projections",)
+
+
+def _kept_projection(product):
+    """A projection's output, named as the product wrote it."""
+    return checkpoint_name(product, PROJECTIONS_KEPT[0])
+
+
+def _kept_bytes(it: InputType, heads: int, head_dim: int, chunk: int,
+                columns: int, dtype) -> int:
+    """Bytes a rematerialised delta-rule layer keeps for one sequence of
+    input type ``it``: ``kda.KEPT`` where the scan's kernels take the head
+    and the chunk, and ``PROJECTIONS_KEPT``, ``columns`` numbers a step in
+    the compute type, at the length the kernels pad to where they run."""
+    time = it.timeseries_length or 1
+    scan = kda_kernels.kept_bytes(time, heads, head_dim, chunk)
+    if scan:
+        time += (-time) % kda_kernels.CHUNK
+    return scan + time * columns * jnp.dtype(dtype).itemsize
 
 
 def causal_depthwise_conv(x, w):
@@ -326,11 +362,14 @@ class KimiDeltaAttention(BaseLayer):
     weight_init: str = "xavier_fan_in"
 
     supports_stateful = False   # no rnn_time_step carry (yet)
-    remat_keeps = kda_kernels.KEPT
+    remat_keeps = kda_kernels.KEPT + PROJECTIONS_KEPT
 
     def remat_kept_bytes(self, it: InputType, dtype=jnp.float32) -> int:
-        return kda_kernels.kept_bytes(it.timeseries_length or 1,
-                                      self.n_heads, self.head_dim, self.chunk)
+        # x Wq, x Wk and the decay's latent x Wf1
+        columns = (2 * self.n_heads * self.head_dim
+                   + (self.low_rank or self.head_dim))
+        return _kept_bytes(it, self.n_heads, self.head_dim, self.chunk,
+                           columns, dtype)
 
     def input_kind(self):
         return "rnn"
@@ -391,23 +430,27 @@ class KimiDeltaAttention(BaseLayer):
                 # one kernel from the products to the scan's heads-major
                 # windows (perf/pallas/kda_inputs.py)
                 q, k, v, g = kda_inputs.kda_inputs(
-                    tuple(xp @ params[w] for w in ("Wq", "Wk", "Wv"))
-                    + ((xp @ params["Wf1"]) @ params["Wf2"],),
+                    (_kept_projection(xp @ params["Wq"]),
+                     _kept_projection(xp @ params["Wk"]),
+                     xp @ params["Wv"],
+                     _kept_projection(xp @ params["Wf1"]) @ params["Wf2"]),
                     tuple(params[c].astype(f32)
                           for c in ("conv_q", "conv_k", "conv_v")),
                     (jnp.repeat(-jnp.exp(params["A_log"].astype(f32)),
                                 dk)[None],
                      params["dt_bias"].astype(f32)[None]), spec)
             else:
-                q, k, v = (jax.nn.silu(causal_depthwise_conv(x @ params[w],
-                                                             params[c]))
-                           for w, c in (("Wq", "conv_q"), ("Wk", "conv_k"),
-                                        ("Wv", "conv_v")))
+                q, k = (jax.nn.silu(causal_depthwise_conv(
+                    _kept_projection(x @ params[w]), params[c]))
+                        for w, c in (("Wq", "conv_q"), ("Wk", "conv_k")))
+                v = jax.nn.silu(causal_depthwise_conv(x @ params["Wv"],
+                                                      params["conv_v"]))
                 # normalised in float32, handed on in the compute type
                 q = (_l2norm(heads(q))
                      * (1.0 / math.sqrt(dk))).astype(x.dtype)
                 k = _l2norm(heads(k)).astype(x.dtype)
-                f = ((x @ params["Wf1"]) @ params["Wf2"]).astype(f32)
+                f = (_kept_projection(x @ params["Wf1"])
+                     @ params["Wf2"]).astype(f32)
                 g = -jnp.exp(params["A_log"].astype(f32))[:, None] * heads(
                     jax.nn.softplus(f + params["dt_bias"].astype(f32)))
             b = jax.nn.sigmoid((xp @ params["Wb"]).astype(f32))
@@ -464,12 +507,13 @@ class GatedDeltaNet(BaseLayer):
     weight_init: str = "xavier_fan_in"
 
     supports_stateful = False   # no rnn_time_step carry (yet)
-    remat_keeps = kda_kernels.KEPT
+    remat_keeps = kda_kernels.KEPT + PROJECTIONS_KEPT
 
     def remat_kept_bytes(self, it: InputType, dtype=jnp.float32) -> int:
-        return kda_kernels.kept_bytes(it.timeseries_length or 1,
-                                      self.n_value_heads, self.head_dim,
-                                      self.chunk)
+        # x Wqkvz
+        columns = 2 * (self.n_key_heads + self.n_value_heads) * self.head_dim
+        return _kept_bytes(it, self.n_value_heads, self.head_dim, self.chunk,
+                           columns, dtype)
 
     def input_kind(self):
         return "rnn"
@@ -522,7 +566,8 @@ class GatedDeltaNet(BaseLayer):
         padded = _take_fused_inputs(x, spec, self.conv_size, self.chunk)
         xp = _pad_time(x, padded or t)
         with jax.named_scope("gdn.conv"):
-            qkvz = xp @ params["Wqkvz"]
+            # z, which the output gate reads, is a column range of it
+            qkvz = _kept_projection(xp @ params["Wqkvz"])
             if padded:
                 # the kernels KDA takes: q, k, v are three column ranges of
                 # one product, a q/k head is written to the value heads it

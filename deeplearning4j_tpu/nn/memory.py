@@ -41,8 +41,9 @@ class LayerMemoryReport:
     remat: Optional[str] = None
     # what the rematerialised layer keeps all the same, for ONE example
     # (its type's ``remat_keeps``: the delta-rule scan's output and chunk
-    # states where the kernels run, the latent attention's output and
-    # log-sum-exp); counted into the activation total
+    # states where the kernels run and the delta-rule layers' wide
+    # projections' outputs, the latent attention's output and log-sum-exp);
+    # counted into the activation total
     remat_kept_bytes_per_example: int = 0
 
     def to_dict(self):

@@ -29,11 +29,12 @@ attacks exactly that traffic, three ways:
   FLOPs for saved-activation HBM. ``"full"`` means "recompute everything
   but what the layer TYPE names as dearer to recompute than to keep"
   (``Layer.remat_keeps``: ``jax.ad_checkpoint.checkpoint_name``s: the
-  delta-rule scan's output and chunk states, perf/pallas/kda.py; the latent
-  attention's output and log-sum-exp, perf/pallas/attention.py); a layer
-  that names nothing recomputes everything, as before. The saving policies
-  keep what they keep and the names too; ``"nothing_saveable"`` keeps
-  nothing, names included: the way back for a run short of memory.
+  delta-rule scan's output and chunk states, perf/pallas/kda.py, and the
+  delta-rule layers' wide projections' outputs, nn/conf/linear_attention.py;
+  the latent attention's output and log-sum-exp, perf/pallas/attention.py);
+  a layer that names nothing recomputes everything, as before. The saving
+  policies keep what they keep and the names too; ``"nothing_saveable"``
+  keeps nothing, names included: the way back for a run short of memory.
 
 Observability: ``training_activation_bytes(conf)`` measures the actual
 forward→backward residual set from the jaxpr of ``jax.vjp`` of the REAL

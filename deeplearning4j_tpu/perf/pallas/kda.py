@@ -57,7 +57,11 @@ the ``checkpoint_name``s of ``KEPT``: the delta-rule layers declare them
 backward pass reads what the first pass wrote, in float32 as it was made
 (``kept_bytes``: 48 KB a token at 32 heads of 128), where recomputing them
 ran the kernel a second time. q, k, v, g, b are residuals too and are not
-named: they are recomputed (the projections and ``kda_inputs``).
+named: ``kda_inputs`` makes them again (3.7 ms a step in the Kimi cell for
+1.34 GB, PERF.md §7), from the projections' outputs, the input kernel's
+only residuals, of which the layers DO name and keep all but KDA's ``x Wv``
+(``linear_attention.PROJECTIONS_KEPT``, PR 41), so the backward pass runs
+those wide products once.
 """
 
 from __future__ import annotations

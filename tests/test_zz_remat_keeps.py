@@ -1,8 +1,9 @@
 """What a rematerialised layer keeps (``Layer.remat_keeps``, joined to the
 layer's ``jax.checkpoint`` policy by ``apply_layer``): the delta-rule
 layers keep their scan's output and chunk states, so ``kda_scan_fwd`` runs
-once a layer and step; the latent attention keeps its attention's output
-and log-sum-exp, so ``mla_attend_fwd`` does; every other layer type names
+once a layer and step, and their wide projections' outputs, so those
+products do; the latent attention keeps its attention's output and
+log-sum-exp, so ``mla_attend_fwd`` does; every other layer type names
 nothing and gets the policy it always got. CPU, the kernels forced and
 interpreted. Tail-sorted (``test_zz_``): interpret mode is slow."""
 
@@ -116,7 +117,7 @@ def _renumbered(text: str) -> str:
                   text)
 
 
-def _as_before(layer, state, marker):
+def _as_before(layer, state, marker, policy=None):
     """``apply_layer`` under ``remat="full"`` as it was before a layer type
     could name what it keeps: ``jax.checkpoint(policy=None)`` in the
     layer's scope."""
@@ -125,9 +126,24 @@ def _as_before(layer, state, marker):
             out, _ = jax.checkpoint(
                 lambda p_, s_, x_, k_, m_, e_: layer.apply(
                     p_, s_, x_, train=True, rng=k_, mask=m_, **e_),
-                policy=None)(p, state, xx, None, None, {})
+                policy=policy)(p, state, xx, None, None, {})
         return out
     return apply
+
+
+def _saved_in_the_layer(loss, params, x, capsys):
+    """(shape and type, whence) of what ``jax.grad(loss)`` saves from its
+    forward pass, less its arguments and constants."""
+    capsys.readouterr()
+    jax.ad_checkpoint.print_saved_residuals(loss, params, x)
+    lines = capsys.readouterr().out.splitlines()
+    return [tuple(line.split(" ", 1)) for line in lines
+            if "from the argument" not in line and "constant" not in line]
+
+
+def _execution(execution):
+    return (pk.override(enabled=True, interpret=True)
+            if execution == "kernels" else pk.override(enabled=False))
 
 
 def _kept_counter():
@@ -160,16 +176,19 @@ def test_a_rematerialised_layer_runs_its_kept_kernel_once(
     assert _kept_counter() - before == counted
 
 
-@pytest.mark.parametrize("kind", _DELTA_RULE + _LATENT)
-def test_kept_results_change_no_bit_of_the_gradient(kind):
-    """What is kept is what would be recomputed, as the kernel wrote it
-    (the scan's in float32, the attention's o in ``v``'s type): the
-    gradients under ``"full"`` are those under ``"nothing_saveable"`` bit
-    for bit."""
+@pytest.mark.parametrize("kind,execution", [
+    *[(kind, "kernels") for kind in _DELTA_RULE + _LATENT],
+    *[(kind, "jax_numpy") for kind in _DELTA_RULE]])
+def test_kept_results_change_no_bit_of_the_gradient(kind, execution):
+    """What is kept is what would be recomputed, as it was written (the
+    scan's results in float32, the attention's o in ``v``'s type, the
+    delta-rule layers' projections in the compute type, in both
+    executions): the gradients under ``"full"`` are those under
+    ``"nothing_saveable"`` bit for bit."""
     grads = {}
     for remat in ("full", "nothing_saveable"):
         loss, params, x = _loss_of(kind, remat=remat)
-        with pk.override(enabled=True, interpret=True):
+        with _execution(execution):
             grads[remat] = jax.grad(loss, argnums=(0, 1))(params, x)
     kept, recomputed = (jax.tree.leaves(grads[r])
                         for r in ("full", "nothing_saveable"))
@@ -181,33 +200,143 @@ def test_kept_results_change_no_bit_of_the_gradient(kind):
 
 def test_the_jax_numpy_scan_names_nothing():
     """Off the kernels the scan checkpoints its own groups of chunks and
-    names nothing: the layer's lowered gradient is what ``policy=None``
-    lowers to."""
+    names nothing: the one name in the layer's gradient is the
+    projections', and its lowered text is what a policy that saves that
+    name alone lowers to."""
     changes = dict(head_dim=8, low_rank=4, chunk=16, remat="full")
     loss, params, x = _loss_of("kda", **changes)
-    before = _as_before(dataclasses.replace(_LAYERS["kda"], **changes), {},
-                        "KimiDeltaAttention:mix")
+    before = _as_before(
+        dataclasses.replace(_LAYERS["kda"], **changes), {},
+        "KimiDeltaAttention:mix",
+        jax.checkpoint_policies.save_only_these_names(*la.PROJECTIONS_KEPT))
 
     def plain(p, xx):
         return jnp.sum(jnp.sin(before(p, xx)))
 
     with pk.override(enabled=False):
+        assert _names(jax.make_jaxpr(jax.grad(loss))(params, x).jaxpr) \
+            == set(la.PROJECTIONS_KEPT)
         texts = [_lowered_gradient(f, params, x) for f in (loss, plain)]
     assert "checkpoint_name" not in texts[0]
     assert texts[0] == texts[1]
 
 
+# ------------------------------------------------- the projections' outputs
+# kind -> (the weights whose products are kept, those in front of the scan
+# whose products are made again, the layer's inner scope)
+_PROJECTIONS = {
+    # ``x Wv`` is wide and is NOT named: with it the TPU's scheduler reorders
+    # the Kimi step for no shorter a step (PERF.md §6, PR 41)
+    "kda": (("Wq", "Wk", "Wf1"), ("Wv", "Wf2", "Wb"), "kda.conv"),
+    "gdn": (("Wqkvz",), ("Wba",), "gdn.conv"),
+}
+_EXECUTIONS = ["kernels", "jax_numpy"]
+
+
+def _forward_products(jaxpr, leaves):
+    """How often each of ``jaxpr``'s arguments (named by ``leaves``, in
+    order) is the right-hand side of a product in the forward direction,
+    ``x @ W`` (contracted over W's rows: dX = dY W^T contracts its columns
+    and dW reads no W), through every nested jaxpr that takes its
+    equation's operands one for one."""
+    count = collections.Counter()
+
+    def walk(inner, env):
+        for eqn in inner.eqns:
+            held = [env.get(v) if hasattr(v, "count") else None
+                    for v in eqn.invars]
+            if eqn.primitive.name == "dot_general" and held[1] and tuple(
+                    eqn.params["dimension_numbers"][0][1]) == (0,):
+                count[held[1]] += 1
+            if eqn.primitive.name == "convert_element_type" and held[0]:
+                env = {**env, eqn.outvars[0]: held[0]}
+            for value in eqn.params.values():
+                for sub in (value if isinstance(value, (list, tuple))
+                            else [value]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub, {v: name for v, name in zip(
+                            sub.invars, held) if name}
+                            if len(sub.invars) == len(held) else {})
+
+    walk(jaxpr, dict(zip(jaxpr.invars, leaves)))
+    return count
+
+
+@pytest.mark.parametrize("remat,kept,made_again", [
+    ("full", 1, 2), ("nothing_saveable", 2, 2), (None, 1, 1)])
+@pytest.mark.parametrize("execution", _EXECUTIONS)
+@pytest.mark.parametrize("kind", _DELTA_RULE)
+def test_a_rematerialised_layer_runs_its_wide_projections_once(
+        kind, execution, remat, kept, made_again):
+    """``jax.grad`` through ``apply_layer``: under ``"full"`` the products
+    whose outputs the type names (KDA's ``x Wq``, ``x Wk`` and the decay's
+    latent ``x Wf1``; Gated DeltaNet's ``x Wqkvz``) are in the gradient
+    once in the forward direction, where the parent made them twice, in
+    both executions; the others in front of the scan (KDA's ``x Wv``,
+    ``Wf2``'s, ``Wb``'s / ``Wba``'s) are made again, the input kernel is
+    run again from the kept outputs and the scan's kernel is not;
+    ``"nothing_saveable"`` makes everything twice; without ``remat``
+    nothing is made again."""
+    loss, params, x = _loss_of(kind, remat=remat)
+    named, narrow, _ = _PROJECTIONS[kind]
+    with _execution(execution):
+        jaxpr = jax.make_jaxpr(jax.grad(loss))(params, x).jaxpr
+    found = _forward_products(jaxpr, sorted(params))
+    assert {w: found[w] for w in named + narrow} == {
+        **dict.fromkeys(named, kept), **dict.fromkeys(narrow, made_again)}
+    assert found["Wo"] == 1
+    if execution == "kernels":
+        kernels = _kernels(jaxpr)
+        assert kernels["kda_inputs_fwd"] == (2 if remat else 1)
+        assert kernels["kda_scan_fwd"] == (2 if remat == "nothing_saveable"
+                                           else 1)
+        assert kernels["kda_inputs_bwd"] == kernels["kda_scan_bwd"] == 1
+    assert la.PROJECTIONS_KEPT[0] in _names(jaxpr)
+
+
+@pytest.mark.parametrize("kind,shapes", [
+    ("kda", ["f32[1,128,256]"] * 2 + ["f32[1,128,8]"]),
+    ("gdn", ["f32[1,128,768]"])])
+def test_the_jax_numpy_execution_saves_the_named_projections(kind, shapes,
+                                                             capsys):
+    """One contract, two executions: off the kernels
+    ``causal_depthwise_conv`` reads what the input kernel reads, and under
+    ``"full"`` the layer saves the named products' outputs, as the products
+    wrote them, and nothing else of its own: the scan names nothing
+    there."""
+    with pk.override(enabled=False):
+        loss, params, x = _loss_of(kind, remat="full")
+        saved = [what for what, whence in _saved_in_the_layer(
+            loss, params, x, capsys) if __file__ not in whence]
+    assert sorted(saved) == sorted(shapes)
+
+
+@pytest.mark.parametrize("remat", [None, "nothing_saveable"])
+@pytest.mark.parametrize("kind", _DELTA_RULE)
+def test_a_projection_s_name_no_policy_holds_lowers_to_its_operand(
+        kind, remat, monkeypatch):
+    """A delta-rule layer that is not rematerialised, or keeps nothing,
+    lowers to the parent's program: with ``checkpoint_name`` taken away
+    the lowered gradient is the same text but for the numbers MLIR gives
+    its private functions (``_renumbered``)."""
+    changes = dict(head_dim=8, chunk=16, remat=remat)
+    if kind == "kda":
+        changes["low_rank"] = 4
+    loss, params, x = _loss_of(kind, **changes)
+    with pk.override(enabled=False):
+        assert _names(jax.make_jaxpr(jax.grad(loss))(params, x).jaxpr) \
+            == set(la.PROJECTIONS_KEPT)
+        named = _lowered_gradient(loss, params, x)
+        assert "checkpoint_name" not in named
+        monkeypatch.setattr(la, "checkpoint_name", lambda value, name: value)
+        assert _names(jax.make_jaxpr(jax.grad(loss))(params, x).jaxpr) \
+            == set()
+        assert _renumbered(_lowered_gradient(loss, params, x)) \
+            == _renumbered(named)
+
+
 # ---------------------------------------------------------- latent attention
-def _saved_in_the_layer(loss, params, x, capsys):
-    """(shape and type, whence) of what ``jax.grad(loss)`` saves from its
-    forward pass, less its arguments and constants."""
-    capsys.readouterr()
-    jax.ad_checkpoint.print_saved_residuals(loss, params, x)
-    lines = capsys.readouterr().out.splitlines()
-    return [tuple(line.split(" ", 1)) for line in lines
-            if "from the argument" not in line and "constant" not in line]
-
-
 @pytest.mark.parametrize("execution", ["jax_numpy", "kernels"])
 @pytest.mark.parametrize("kind", _LATENT)
 def test_both_executions_keep_the_same_two_names(kind, execution, capsys):
@@ -222,8 +351,7 @@ def test_both_executions_keep_the_same_two_names(kind, execution, capsys):
     time = _TIME[kind]
     o = f"f32[1,2,{time},64]"
     lse = f"f32[1,2,1,{time}]" if kernels else f"f32[1,2,{time}]"
-    with (pk.override(enabled=True, interpret=True) if kernels
-          else pk.override(enabled=False)):
+    with _execution(execution):
         loss, params, x = _loss_of(kind, remat="full")
         assert _names(jax.make_jaxpr(jax.grad(loss))(params, x).jaxpr) \
             == {o_name, lse_name}
@@ -358,7 +486,8 @@ def test_a_name_no_policy_holds_lowers_to_its_operand(kind, remat,
 @pytest.mark.parametrize("name", sorted(fusion.REMAT_POLICIES))
 @pytest.mark.parametrize("kind", ["kda", "mla"])
 def test_names_join_every_saving_policy_and_not_nothing_saveable(kind, name):
-    kept = {"kda": kda_kernels.KEPT, "mla": attention_kernels.KEPT}[kind]
+    kept = {"kda": kda_kernels.KEPT + la.PROJECTIONS_KEPT,
+            "mla": attention_kernels.KEPT}[kind]
     layer = dataclasses.replace(_LAYERS[kind], remat=name)
     keeps = fusion.kept_names(layer)
     assert keeps == (() if name == "nothing_saveable" else kept)
@@ -373,6 +502,28 @@ def test_names_join_every_saving_policy_and_not_nothing_saveable(kind, name):
     assert fusion.kept_names(dataclasses.replace(layer, remat=None)) == ()
 
 
+def _one_mixer_step(kind, remat, execution, step_op_names):
+    """The ``op_name``s of the compiled train step of a graph of one mixer
+    (``mix1``) and an output layer."""
+    from deeplearning4j_tpu.nn.conf.graph import GraphBuilder
+    from deeplearning4j_tpu.nn.conf.recurrent import RnnOutputLayer
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+    time = _TIME[kind]
+    conf = (GraphBuilder(NeuralNetConfiguration.builder().seed(3)
+                         .updater(Sgd(learning_rate=0.05)))
+            .add_inputs("in")
+            .add_layer("mix1", dataclasses.replace(_LAYERS[kind],
+                                                   remat=remat), "in")
+            .add_layer("out", RnnOutputLayer(n_out=3, loss="mcxent"), "mix1")
+            .set_outputs("out")
+            .set_input_types(InputType.recurrent(12, time)).build())
+    with _execution(execution):
+        net = ComputationGraph(conf).init()
+        return step_op_names(
+            net, [jax.ShapeDtypeStruct((1, time, 12), jnp.float32)],
+            [jax.ShapeDtypeStruct((1, time, 3), jnp.float32)])
+
+
 @pytest.mark.parametrize("kind", _DELTA_RULE + ["rmla"])
 def test_kept_results_lie_under_the_layers_scope(kind, step_op_names):
     """``kda.device_ms_per_step`` / ``gdn.`` / ``mla.`` / ``rmla.`` and the
@@ -381,25 +532,9 @@ def test_kept_results_lie_under_the_layers_scope(kind, step_op_names):
     the compiled step under the layer's marker and ``kda.scan`` /
     ``gdn.scan`` / ``mla.attend``, in the first pass alone; the backward
     kernel reads them under the same scope."""
-    from deeplearning4j_tpu.nn.conf.graph import GraphBuilder
-    from deeplearning4j_tpu.nn.conf.recurrent import RnnOutputLayer
-    from deeplearning4j_tpu.nn.graph import ComputationGraph
     cls, scope, writes, reads = _SCOPES[kind]
-    time = _TIME[kind]
-    conf = (GraphBuilder(NeuralNetConfiguration.builder().seed(3)
-                         .updater(Sgd(learning_rate=0.05)))
-            .add_inputs("in")
-            .add_layer("mix1", dataclasses.replace(_LAYERS[kind],
-                                                   remat="full"), "in")
-            .add_layer("out", RnnOutputLayer(n_out=3, loss="mcxent"), "mix1")
-            .set_outputs("out")
-            .set_input_types(InputType.recurrent(12, time)).build())
     before = _kept_counter()
-    with pk.override(enabled=True, interpret=True):
-        net = ComputationGraph(conf).init()
-        names = step_op_names(
-            net, [jax.ShapeDtypeStruct((1, time, 12), jnp.float32)],
-            [jax.ShapeDtypeStruct((1, time, 3), jnp.float32)])
+    names = _one_mixer_step(kind, "full", "kernels", step_op_names)
     assert _kept_counter() - before == 1
     for kernel, way, other in ((writes, "jvp(", "transpose("),
                                (reads, "transpose(", None)):
@@ -410,6 +545,30 @@ def test_kept_results_lie_under_the_layers_scope(kind, step_op_names):
             # the forward kernel is not in the backward's recomputation
             assert other is None or (other not in n
                                      and "rematted_computation" not in n), n
+
+
+@pytest.mark.parametrize("kind", _DELTA_RULE)
+def test_the_backward_pass_makes_no_named_projection_again(kind,
+                                                           step_op_names):
+    """In the COMPILED step the backward pass's recomputation
+    (``rematted_computation``) under ``kda.conv`` / ``gdn.conv`` still holds
+    products, the narrow ones, and fewer instructions of them than where
+    nothing is kept; the first pass's lie under the same scope, which is
+    where ``kda.device_ms_per_step`` / ``gdn.`` look for them. (Which
+    weight a compiled product reads is not in its name: the jaxpr's count
+    above is the exact one.)"""
+    cls, (_, _, scope) = _SCOPES[kind][0], _PROJECTIONS[kind]
+    made_again = {}
+    for remat in ("full", "nothing_saveable"):
+        names = _one_mixer_step(kind, remat, "jax_numpy", step_op_names)
+        products = [n for n in names if f"{cls}:mix1" in n
+                    and n.endswith(f"{scope}/dot_general")]
+        first = [n for n in products if "transpose(" not in n]
+        assert first and all("rematted_computation" not in n for n in first)
+        made_again[remat] = [n for n in products
+                             if "rematted_computation" in n]
+        assert all("transpose(" in n for n in made_again[remat])
+    assert 0 < len(made_again["full"]) < len(made_again["nothing_saveable"])
 
 
 def test_the_counter_reaches_the_scrape():
@@ -427,29 +586,82 @@ def test_the_counter_reaches_the_scrape():
 
 
 # ----------------------------------------------------------- memory report
+_CELL_LAYERS = {
+    "kimi": la.KimiDeltaAttention(n_heads=32, head_dim=128, low_rank=128,
+                                  remat="full"),
+    "qwen": la.GatedDeltaNet(n_key_heads=16, n_value_heads=32, head_dim=128,
+                             remat="full"),
+}
+
+
 def test_kept_bytes_at_the_kimi_cell_s_shape():
-    """o (8192, 32, 128) and the states (32, 128, 128, 128), float32: 134
-    + 268 MB a layer, 48 KB a token."""
+    """The scan's share, in both cells: o (8192, 32, 128) and the states
+    (32, 128, 128, 128), float32 whatever the network computes in: 134 +
+    268 MB a layer, 48 KB a token."""
     it = InputType.recurrent(2304, 8192)
-    kimi = la.KimiDeltaAttention(n_heads=32, head_dim=128, low_rank=128,
-                                 remat="full")
-    qwen = la.GatedDeltaNet(n_key_heads=16, n_value_heads=32, head_dim=128,
-                            remat="full")
-    assert kda_kernels.kept_bytes(8192, 32, 128, 64) == 402_653_184
-    assert kimi.remat_kept_bytes(it) == qwen.remat_kept_bytes(it) \
+    kimi, qwen = _CELL_LAYERS["kimi"], _CELL_LAYERS["qwen"]
+    assert kda_kernels.kept_bytes(8192, 32, 128, 64) == 402_653_184 \
         == 134_217_728 + 268_435_456
-    assert kimi.remat_kept_bytes(it) // 8192 == 48 * 1024
+    for layer, columns in ((kimi, 2 * 4096 + 128), (qwen, 12288)):
+        assert layer.remat_kept_bytes(it, jnp.bfloat16) \
+            - 8192 * columns * 2 == 402_653_184
+    assert kda_kernels.kept_bytes(8192, 32, 128, 64) // 8192 == 48 * 1024
     # a length that is padded to whole chunks; a head the kernels refuse
     assert kda_kernels.kept_bytes(100, 2, 128, 64) \
         == kda_kernels.kept_bytes(128, 2, 128, 64)
     assert kda_kernels.kept_bytes(128, 2, 64, 64) == 0
-    assert dataclasses.replace(kimi, chunk=32).remat_kept_bytes(it) == 0
+    # a chunk the kernels refuse: the ``jax.numpy`` execution keeps the
+    # projections alone, at the length it is given
+    assert dataclasses.replace(kimi, chunk=32).remat_kept_bytes(it) \
+        == 8192 * (2 * 4096 + 128) * 4
+    short = InputType.recurrent(2304, 100)
+    assert dataclasses.replace(kimi, chunk=32).remat_kept_bytes(short) \
+        == 100 * (2 * 4096 + 128) * 4
+    assert kimi.remat_kept_bytes(short) \
+        == kda_kernels.kept_bytes(128, 32, 128, 64) + 128 * (2 * 4096 + 128) * 4
+
+
+@pytest.mark.parametrize("cell,width,projections,a_token", [
+    # x Wq, x Wk (8192, 4096) and the decay's latent (8192, 128)
+    ("kimi", 2304, 136_314_880, 16_640),
+    # x Wqkvz (8192, 12288): q | k | v | z
+    ("qwen", 2048, 201_326_592, 24_576)])
+def test_projections_kept_bytes_at_the_cells_shapes(cell, width, projections,
+                                                    a_token):
+    """What the named projections add to a delta-rule layer's kept bytes at
+    the cells' shapes (1 x 8192 tokens, bfloat16): 136.3 MB a Kimi layer,
+    201.3 MB a Qwen layer, 16.6 and 24.6 KB a token beside the scan's 48
+    KB; in float32 twice that; and ``conf.memory_report()`` prints the
+    sum."""
+    from deeplearning4j_tpu.nn.conf.recurrent import RnnOutputLayer
+    layer = _CELL_LAYERS[cell]
+    it = InputType.recurrent(width, 8192)
+    scan = 402_653_184
+    assert layer.remat_kept_bytes(it, jnp.bfloat16) \
+        == layer.remat_kept_bytes(it, "bfloat16") == scan + projections
+    assert layer.remat_kept_bytes(it) == scan + 2 * projections
+    assert projections // 8192 == a_token
+    for remat, kept in (("full", scan + projections), ("nothing_saveable", 0)):
+        conf = (NeuralNetConfiguration.builder().seed(1)
+                .updater(Sgd(learning_rate=0.1)).dtype("bfloat16").list()
+                .layer(dataclasses.replace(layer, remat=remat))
+                .layer(RnnOutputLayer(n_out=3, loss="mcxent"))
+                .set_input_type(it).build())
+        text = conf.memory_report(minibatch=1).to_string()
+        assert (f"keeps {kept / 2**10:.1f} KB/ex" in text) == bool(kept)
+        assert ("keeps" in text) == bool(kept)
 
 
 @pytest.mark.parametrize("kind,dtype,want,printed", [
-    # o and the two chunks' states, float32 whatever the network's type
-    ("kda", "float32", 4 * 2 * 128 * (128 + 2 * 128), "keeps 384.0 KB/ex"),
-    ("kda", "bfloat16", 4 * 2 * 128 * (128 + 2 * 128), "keeps 384.0 KB/ex"),
+    # o and the two chunks' states, float32 whatever the network's type,
+    # and the projections' 2 x 256 + 8 columns in the network's type
+    ("kda", "float32", 4 * 2 * 128 * (128 + 2 * 128) + 4 * 128 * 520,
+     "keeps 644.0 KB/ex"),
+    ("kda", "bfloat16", 4 * 2 * 128 * (128 + 2 * 128) + 2 * 128 * 520,
+     "keeps 514.0 KB/ex"),
+    # one product of 2 x (1 + 2) x 128 columns
+    ("gdn", "bfloat16", 4 * 2 * 128 * (128 + 2 * 128) + 2 * 128 * 768,
+     "keeps 576.0 KB/ex"),
     # o in the network's type and a float32 a token and head
     ("rmla", "bfloat16", 2 * 256 * (64 * 2 + 4), "keeps 66.0 KB/ex"),
     ("rmla", "float32", 2 * 256 * (64 * 4 + 4), "keeps 130.0 KB/ex")])
