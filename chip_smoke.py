@@ -138,7 +138,7 @@ def phase_device(ctx: Ctx) -> dict:
 
 # ------------------------------------------------------------------- train
 def _resnet50(ctx: Ctx, side: int):
-    """The north-star model as bench.py builds it: bf16 compute."""
+    """The north-star model as the benchmark configures it: bf16 compute."""
     from deeplearning4j_tpu.models import ResNet50
     from deeplearning4j_tpu.nn.graph import ComputationGraph
     conf = dataclasses.replace(

@@ -225,8 +225,8 @@ def _dotted(node: ast.AST) -> Optional[str]:
 def module_name_for(path: str) -> Tuple[str, bool]:
     """Dotted module name for a file, by walking up ``__init__.py`` chains.
 
-    Loose files (no package) get ``<parentdir>.<stem>`` so tools/ and
-    bench.py functions have unique qnames without colliding.
+    Loose files (no package) get ``<parentdir>.<stem>`` so tools/
+    functions have unique qnames without colliding.
     """
     path = os.path.abspath(path)
     base = os.path.basename(path)

@@ -1871,7 +1871,6 @@ def audit_waivers(paths: Iterable[str]) -> List[StaleWaiver]:
 
 
 def DEFAULT_TARGETS(repo_root: str) -> List[str]:
-    """The tier-1 lint surface: the package, the benches, the tools."""
+    """The tier-1 lint surface: the package and the tools."""
     return [os.path.join(repo_root, "deeplearning4j_tpu"),
-            os.path.join(repo_root, "bench.py"),
             os.path.join(repo_root, "tools")]

@@ -379,7 +379,8 @@ class ParallelInference:
         (``batch_limit`` caps coalesced REQUESTS, not rows). Pass explicit
         ``buckets`` (batch sizes to warm) when traffic mixes request sizes;
         warm up to your worst-case coalesced row count (see
-        bench.py::bench_serving). Returns the warmed dispatch sizes."""
+        tests/test_perf.py::test_warmed_serving_wave_compiles_nothing).
+        Returns the warmed dispatch sizes."""
         ex = np.asarray(example)
         if ex.ndim < 1:
             raise ValueError("warmup example needs a leading batch axis")

@@ -39,8 +39,8 @@ attacks exactly that traffic, three ways:
 Observability: ``training_activation_bytes(conf)`` measures the actual
 forward→backward residual set from the jaxpr of ``jax.vjp`` of the REAL
 loss (no device allocation — abstract tracing only); it feeds the
-training-activation-bytes line of ``conf.memory_report()`` and the
-``bench.py`` fusion ablation. Fused-block trace hits count into
+training-activation-bytes line of ``conf.memory_report()`` and the HBM
+planner. Fused-block trace hits count into
 CompileWatch (``fusion.fused_block``), surfaced by
 ``ParallelInference.stats()``.
 """
@@ -685,8 +685,8 @@ def training_activation_bytes(conf, minibatch: int = 32,
     derived from the jaxpr (``jax.make_jaxpr`` over abstract inputs — zero
     device allocation). Fusion and ``remat=`` knobs change this number the
     same way they change the compiled step's HBM traffic, which makes it
-    the ablation metric for ``bench.py``'s fusion on/off run and the
-    training-activation-bytes line of ``conf.memory_report()``.
+    the number the HBM planner budgets and the training-activation-bytes
+    line of ``conf.memory_report()``.
     ``augmentation`` (datasets/augment.ImageAugmentation) measures the step
     WITH on-device augmentation in the graph — augmentation changes the
     residual set, so the HBM planner passes it through."""

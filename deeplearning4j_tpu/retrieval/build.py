@@ -46,13 +46,12 @@ __all__ = ["vectors_from_word2vec", "vectors_from_graph",
 def synthetic_corpus(n: int, d: int, *, n_clusters: Optional[int] = None,
                      spread: float = 0.5, seed: int = 0,
                      queries: int = 0):
-    """Seeded clustered corpus for smoke tests, benches and demos —
+    """Seeded clustered corpus for smoke tests and demos —
     real embeddings cluster, so uniform noise is the IVF-adversarial
     case, not the deployed one. Returns a float32 ``(n, d)`` matrix, or
     ``(V, Q)`` when ``queries`` > 0 (queries drawn from the same
-    mixture). ONE recipe shared by bench_retrieval, the CLI's
-    ``random:`` source and the tier-1 gates, so they all measure the
-    same distribution."""
+    mixture). ONE recipe shared by the CLI's ``random:`` source and the
+    tier-1 gates, so they measure the same distribution."""
     rng = np.random.default_rng(seed)
     nc = max(16, n // 100) if n_clusters is None else int(n_clusters)
     means = rng.standard_normal((nc, d)).astype(np.float32) * 2.0

@@ -65,8 +65,8 @@ bytes::
 
     {"x_b64": "<base64>", "dtype": "float32", "shape": [4, 784]}
 
-which cuts the payload to ~⅓ of the JSON float encoding (measured in
-``bench_serving_load``). ``dtype`` is ``"float32"`` (the native serving
+which cuts the payload to ~⅓ of the JSON float encoding (held by
+``tests/test_serving.py``). ``dtype`` is ``"float32"`` (the native serving
 dtype), ``"float64"`` (accepted, downcast to f32 on decode), or ``"int8"``
 — the latter on QUANTIZED endpoints only (``quant/``): the payload is
 interpreted on the endpoint's calibrated input grid (``x ≈ xq *

@@ -7,7 +7,7 @@ array::
     {"x_b64": "<base64>", "dtype": "float32", "shape": [4, 784]}
 
 - ``float32`` — the native serving dtype (~3× smaller than JSON float
-  lists, measured in ``bench_serving_load``).
+  lists: ``tests/test_serving.py`` holds it over a mix of request sizes).
 - ``float64`` — accepted, downcast to f32 on decode.
 - ``int8`` — another 4× fewer bytes; only meaningful against a known
   symmetric grid, so decode requires a scale: the endpoint's calibrated

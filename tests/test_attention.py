@@ -80,6 +80,7 @@ def test_transformer_block_shapes_and_gradients():
     out, _ = lay.apply(p, {}, x)
     assert out.shape == (B, T, D)
 
+    @jax.jit
     def loss(pp):
         o, _ = lay.apply(pp, {}, x)
         return jnp.sum(o * o)

@@ -6,14 +6,10 @@ BITWISE-equal to the uninterrupted run (same rng split chain, same
 counters), for both MultiLayerNetwork and ComputationGraph. Around that:
 torn/corrupt checkpoints and manifests must DEGRADE (fall back to the last
 complete checkpoint), never restore garbage; retention must prune while
-pinning the best; the early-stopping saver protocol must work; and the
-bench smoke proves the overhead microbench emits its JSON fields.
+pinning the best; and the early-stopping saver protocol must work.
 """
 
-import json
 import os
-import subprocess
-import sys
 
 import numpy as np
 import jax
@@ -471,29 +467,3 @@ def test_early_stopping_accepts_checkpoint_manager_as_saver(tmp_path):
     out = result.best_model.output(batches[0].features)
     assert out.shape == (32, 3)
     cm.close()
-
-
-# --------------------------------------------------------------- bench smoke
-def test_bench_checkpoint_quick_smoke():
-    """CI tripwire: the checkpoint-overhead microbench runs end-to-end and
-    emits the off/async/sync steps-per-sec comparison. The <10% acceptance
-    number is asserted on the quiet full run, not here — this shared CPU
-    host's run-to-run noise exceeds the bar."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, BENCH_QUICK="1", BENCH_ONLY="checkpoint",
-               JAX_PLATFORMS="cpu")
-    env.pop("XLA_FLAGS", None)  # single-device run, no 8-way host mesh
-    proc = subprocess.run([sys.executable, "bench.py"], cwd=repo, env=env,
-                          capture_output=True, text=True, timeout=420)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    lines = [json.loads(l) for l in proc.stdout.splitlines() if l.strip()]
-    assert not any("error" in l for l in lines), lines
-    by_metric = {l["metric"]: l for l in lines}
-    line = by_metric["checkpoint_async_train_steps_per_sec"]
-    assert line["value"] > 0
-    assert {"steps_per_sec_off", "steps_per_sec_sync", "overhead_async_pct",
-            "overhead_sync_pct", "checkpoints_written",
-            "save_every_n_steps"} <= set(line)
-    assert line["save_every_n_steps"] == 10
-    assert line["checkpoints_written"] >= 1
-    assert line["steps_per_sec_off"] > 0 and line["steps_per_sec_sync"] > 0

@@ -344,11 +344,11 @@ def test_space_to_depth_stem_matches_direct_conv():
                                    rtol=2e-5, atol=2e-5)
         # gradients wrt input and kernel through an arbitrary scalar loss
         co = jnp.asarray(rng.standard_normal(want.shape, np.float32))
-        gx, gk = jax.grad(
+        gx, gk = jax.jit(jax.grad(
             lambda a, b: jnp.sum(ConvolutionLayer._space_to_depth_conv(a, b) * co),
-            argnums=(0, 1))(x, k)
-        rx, rk = jax.grad(
-            lambda a, b: jnp.sum(direct(a, b) * co), argnums=(0, 1))(x, k)
+            argnums=(0, 1)))(x, k)
+        rx, rk = jax.jit(jax.grad(
+            lambda a, b: jnp.sum(direct(a, b) * co), argnums=(0, 1)))(x, k)
         np.testing.assert_allclose(np.asarray(gx), np.asarray(rx),
                                    rtol=2e-4, atol=2e-4)
         np.testing.assert_allclose(np.asarray(gk), np.asarray(rk),

@@ -776,26 +776,3 @@ class TestConsumptionLedger:
         rep = reconcile_ledger(store, batch_size=3)
         assert rep.duplicates == [(0, 2)]
         assert not rep.clean
-
-
-def test_bench_data_plane_quick_smoke():
-    """CI tripwire: the data-plane microbench runs end-to-end and emits
-    the records/s, lease-claim-latency and data-wait-fraction lines
-    (metrics only — thresholds belong to quiet full runs, 9p note)."""
-    import json as _json
-    import subprocess
-    import sys
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, BENCH_QUICK="1", BENCH_ONLY="data_plane",
-               JAX_PLATFORMS="cpu")
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run([sys.executable, "bench.py"], cwd=repo, env=env,
-                          capture_output=True, text=True, timeout=420)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    lines = [_json.loads(l) for l in proc.stdout.splitlines() if l.strip()]
-    assert not any("error" in l for l in lines), lines
-    by_metric = {l["metric"]: l for l in lines}
-    rps = by_metric["data_plane_records_per_sec"]
-    assert rps["value"] > 0 and rps["leased_ledgered"] > 0
-    assert by_metric["data_plane_lease_claim_us"]["value"] > 0
-    assert "async_prefetch_pct" in by_metric["data_plane_data_wait_fraction"]

@@ -65,7 +65,7 @@ def _loss_and_grads(net, x, y):
     else:
         def f(p):
             return net._loss_fn(p, net.state, x, y, None, None, None)[0]
-    return jax.value_and_grad(f)(net.params)
+    return jax.jit(jax.value_and_grad(f))(net.params)
 
 
 def _relerr(a, b):

@@ -48,6 +48,7 @@ def _fd_check_layer_loss(layer, params, x, rng, eps=1e-6, tol=1e-3):
         x64 = jnp.asarray(np.asarray(x, np.float64))
         flat, unravel = ravel_pytree(p64)
 
+        @jax.jit
         def loss(f):
             return layer.pretrain_loss(unravel(f), {}, x64, rng)
 
@@ -214,8 +215,8 @@ def test_yolo_loss_and_gradients():
     with jax.enable_x64():
         p64 = jnp.asarray(np.asarray(preout, np.float64))
         l64 = jnp.asarray(np.asarray(labels, np.float64))
-        g = np.asarray(jax.grad(
-            lambda p: layer.compute_score(l64, p))(p64))
+        score = jax.jit(lambda p: layer.compute_score(l64, p))
+        g = np.asarray(jax.grad(score)(p64))
         flat = np.asarray(p64).ravel()
         rng = np.random.default_rng(1)
         per = 5 + 3
@@ -223,8 +224,8 @@ def test_yolo_loss_and_gradients():
             eps = 1e-6
             fp = flat.copy(); fp[j] += eps
             fm = flat.copy(); fm[j] -= eps
-            num = (float(layer.compute_score(l64, jnp.asarray(fp.reshape(p64.shape))))
-                   - float(layer.compute_score(l64, jnp.asarray(fm.reshape(p64.shape))))) / (2 * eps)
+            num = (float(score(jnp.asarray(fp.reshape(p64.shape))))
+                   - float(score(jnp.asarray(fm.reshape(p64.shape))))) / (2 * eps)
             a = g.ravel()[j]
             denom = max(abs(a), abs(num))
             tol = 1e-3 if (j % per) >= 4 else 5e-2

@@ -70,7 +70,8 @@ def _loss_and_grads(net, x, y, params=None):
     def loss(p):
         return net._loss_fn(p, net.state, [jnp.asarray(x)], [jnp.asarray(y)],
                             None, None, None)[0]
-    return jax.value_and_grad(loss)(net.params if params is None else params)
+    return jax.jit(jax.value_and_grad(loss))(
+        net.params if params is None else params)
 
 
 def _close(a, b, tol=1e-5):
@@ -132,7 +133,7 @@ def test_the_gradient_is_the_sum_over_the_passes_of_untied_copies():
         return head.compute_score(jnp.asarray(y), pre)
 
     copies = [net.params["loop"]] * steps
-    loss_u, (g_copies, g_rest) = jax.value_and_grad(untied, (0, 1))(
+    loss_u, (g_copies, g_rest) = jax.jit(jax.value_and_grad(untied, (0, 1)))(
         copies, net.params)
     assert one.output_type(it) == it
     assert float(abs(loss - loss_u)) < 1e-6
@@ -169,8 +170,8 @@ def test_the_scanned_passes_are_the_passes_written_out(stacked):
 
     assert scanned(params, x).shape == ((3, 2, T, D) if stacked
                                         else (2, T, D))
-    results = [jax.value_and_grad(lambda p, x: jnp.sum(jnp.sin(f(p, x))),
-                                  (0, 1))(params, x)
+    results = [jax.jit(jax.value_and_grad(
+        lambda p, x: jnp.sum(jnp.sin(f(p, x))), (0, 1)))(params, x)
                for f in (scanned, written_out)]
     _close(results[0], results[1], 5e-5)
     head = None if stacked else TokenOutputLayer(n_out=V, time_block=8)
@@ -351,7 +352,7 @@ def test_rotary_attention_is_the_dense_form(kv_heads, t):
     x = jax.random.normal(jax.random.key(1), (2, t, 12))
     from deeplearning4j_tpu.perf.compile_watch import GLOBAL
     before = dict(GLOBAL.counters("attention.rotary"))
-    got, _ = layer.apply(params, {}, x)
+    got, _ = jax.jit(layer.apply)(params, {}, x)
     want = _dense_rotary_attention(layer, params, x)
     assert float(jnp.max(jnp.abs(got - want))) < 2e-5
     rose = {k for k, v in GLOBAL.counters("attention.rotary").items()
@@ -359,7 +360,7 @@ def test_rotary_attention_is_the_dense_form(kv_heads, t):
     assert rose == {"attention.rotary_blocked" if t > 16
                     else "attention.rotary_single_tile"}
     mask = jnp.asarray(np.arange(t)[None, :] < np.array([[t], [t - 3]]))
-    masked, _ = layer.apply(params, {}, x, mask=mask)
+    masked, _ = jax.jit(layer.apply)(params, {}, x, mask=mask)
     assert float(jnp.max(jnp.abs(masked[1, t - 3:]))) == 0.0
     assert float(jnp.max(jnp.abs(masked[1, :t - 3] - got[1, :t - 3]))) < 2e-5
 
@@ -381,7 +382,8 @@ def test_rotary_attention_through_the_pallas_kernels():
         before = GLOBAL.counters("kernel.").get(
             "kernel.pallas_blocked_attention", 0)
         with pk.override(enabled=enabled, interpret=True):
-            results.append(jax.value_and_grad(run, (0, 1))(params, x))
+            results.append(
+                jax.jit(jax.value_and_grad(run, (0, 1)))(params, x))
         rose = GLOBAL.counters("kernel.").get(
             "kernel.pallas_blocked_attention", 0) > before
         assert rose == enabled

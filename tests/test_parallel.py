@@ -156,7 +156,8 @@ def test_ring_attention_matches_reference(devices, causal):
     k = jnp.asarray(rng.standard_normal((b, h, t, d)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((b, h, t, d)), jnp.float32)
     expected = reference_attention(q, k, v, causal=causal)
-    got = ring_self_attention(q, k, v, mesh, causal=causal)
+    got = jax.jit(lambda q, k, v: ring_self_attention(
+        q, k, v, mesh, causal=causal))(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
                                rtol=2e-4, atol=2e-5)
 
@@ -175,7 +176,7 @@ def test_ring_attention_differentiable(devices):
     def loss_ref(q, k, v):
         return jnp.sum(reference_attention(q, k, v, causal=True) ** 2)
 
-    g_ring = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
+    g_ring = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(q, k, v)
     g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b_ in zip(g_ring, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=3e-4, atol=3e-5)
@@ -470,8 +471,10 @@ def test_ulysses_matches_ring_and_validates_heads(devices):
     q = jnp.asarray(rng.standard_normal((b, h, t, d)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((b, h, t, d)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((b, h, t, d)), jnp.float32)
-    ring = ring_self_attention(q, k, v, mesh, causal=True)
-    uly = ulysses_self_attention(q, k, v, mesh, causal=True)
+    ring = jax.jit(lambda q, k, v: ring_self_attention(
+        q, k, v, mesh, causal=True))(q, k, v)
+    uly = jax.jit(lambda q, k, v: ulysses_self_attention(
+        q, k, v, mesh, causal=True))(q, k, v)
     np.testing.assert_allclose(np.asarray(uly), np.asarray(ring),
                                rtol=2e-4, atol=2e-5)
     # differentiable under jit

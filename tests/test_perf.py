@@ -273,6 +273,43 @@ def test_warmup_warms_the_exact_live_dispatch_shape(devices):
     assert net.compile_watch.compiles() == compiles_after
 
 
+def test_warmed_serving_wave_compiles_nothing(devices):
+    """The serving contract under a wave of mixed sizes: ``batch_limit``
+    caps coalesced REQUESTS, not rows, so the ladder is warmed up to every
+    in-flight client's largest request in one dispatch; the traffic then
+    compiles nothing and every dispatch lands on a warmed bucket."""
+    n_clients, reqs_per_client, batch_limit = 4, 4, 16
+    sizes = [1, 3, 7, 20]
+    net = _net(seed=11)
+    policy = BucketPolicy(floor=8)
+    pi = ParallelInference(net, batch_limit=batch_limit, queue_timeout_ms=3,
+                           bucket_policy=policy)
+    max_rows = min(batch_limit, n_clients) * max(sizes)
+    pi.warmup(np.zeros((1, 4), np.float32),
+              buckets=policy.buckets_up_to(max_rows))
+    compiles_after_warmup = net.compile_watch.compiles()
+
+    def client(cid):
+        r = np.random.default_rng(cid)
+        for i in range(reqs_per_client):
+            n = sizes[(cid + i) % len(sizes)]
+            assert pi.output_batched(r.random((n, 4), np.float32)).shape \
+                == (n, 3)
+
+    threads = [threading.Thread(target=client, args=(c,))
+               for c in range(n_clients)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+    st = pi.stats()
+    pi.shutdown()
+    assert st["requests_served"] == n_clients * reqs_per_client
+    assert st["model_compiles"] == compiles_after_warmup, st
+    assert st["unwarmed_dispatches"] == 0
+    assert st["batch_size"]["count"] == st["batches_dispatched"]
+
+
 def test_ones_mask_cache_is_reused_and_readonly():
     from deeplearning4j_tpu.perf.bucketing import _ones_like_mask
     a = _ones_like_mask((), 5, 8)
@@ -527,34 +564,6 @@ def test_training_stats_counters():
     assert "widgets" in st.to_string()
 
 
-# --------------------------------------------------------------- bench smoke
-def test_bench_quick_smoke():
-    """CI tripwire: bench.py runs end-to-end (BENCH_ONLY=lenet,serving —
-    the two benches exercising prefetch and bucketing) and the serving
-    line carries the batch-size summary + compile counters the acceptance
-    criteria require."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, BENCH_QUICK="1", BENCH_ONLY="lenet,serving",
-               JAX_PLATFORMS="cpu")
-    env.pop("XLA_FLAGS", None)  # single-device run, no 8-way host mesh
-    proc = subprocess.run([sys.executable, "bench.py"], cwd=repo, env=env,
-                          capture_output=True, text=True, timeout=420)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    lines = [json.loads(l) for l in proc.stdout.splitlines() if l.strip()]
-    by_metric = {l["metric"]: l for l in lines}
-    assert not any("error" in l for l in lines), lines
-    assert all(l["platform"] == "cpu" and l["device_kind"] for l in lines)
-    assert "lenet_mnist_train_imgs_per_sec_per_chip_plain_fit" in by_metric
-    serving = by_metric["parallel_inference_serving_reqs_per_sec"]
-    assert serving["value"] > 0
-    assert {"p50_ms", "p99_ms", "batches_dispatched", "batch_size",
-            "compiles", "unwarmed_dispatches"} <= set(serving)
-    assert serving["batch_size"]["count"] == serving["batches_dispatched"]
-    # the shape-stability contract: traffic after warmup compiles nothing
-    assert serving["compiles"] == serving["compiles_after_warmup"], serving
-    assert serving["unwarmed_dispatches"] == 0
-
-
 # ------------------------------------------- where a run leaves its traces
 @pytest.mark.parametrize("env, arg, want", [
     ("/from/env", None, "/from/env"),           # placed from outside
@@ -571,55 +580,6 @@ def test_compile_cache_dir_resolution(monkeypatch, env, arg, want):
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     assert compile_cache.resolve_cache_dir(arg) == want.replace(
         "<checkout>", repo)
-
-
-@pytest.fixture
-def bench_main(monkeypatch, capsys):
-    """bench.main() in-process over two stand-in benches (one emits, one
-    raises), with the process-global compile cache left alone. Returns
-    (exit code, parsed stdout lines)."""
-    import importlib.util
-
-    from deeplearning4j_tpu.perf import compile_cache
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "_bench_under_test", os.path.join(repo, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    placed = []
-    monkeypatch.setattr(compile_cache, "enable_compilation_cache",
-                        lambda *a, **k: placed.append(a))
-
-    def boom():
-        raise RuntimeError("bench exploded")
-    monkeypatch.setattr(bench, "bench_lenet",
-                        lambda: bench.emit("ok_metric", 1.0, "u", "lenet"))
-    monkeypatch.setattr(bench, "bench_word2vec", boom)
-
-    def run(only):
-        monkeypatch.setenv("BENCH_ONLY", only)
-        try:
-            bench.main()
-            code = 0
-        except SystemExit as e:
-            code = e.code
-        assert placed, "bench.main() never placed the compile cache"
-        return code, [json.loads(l) for l in
-                      capsys.readouterr().out.splitlines() if l.strip()]
-    return run
-
-
-@pytest.mark.parametrize("only, fails", [("lenet", False),
-                                         ("lenet,word2vec", True)])
-def test_bench_exit_code_and_device_fields(bench_main, only, fails):
-    code, lines = bench_main(only)
-    assert bool(code) == fails, (code, lines)
-    assert any("error" in l for l in lines) == fails
-    dev = jax.devices()[0]
-    for l in lines:  # result and error lines alike say where they ran
-        assert (l["platform"], l["device_kind"]) == (dev.platform,
-                                                     dev.device_kind), l
-        assert "mfu" not in l   # no peak is known for a CPU
 
 
 def test_chip_smoke_refuses_without_a_chip():

@@ -62,8 +62,8 @@ def test_pipeline_gradients_match_sequential(devices):
         return jnp.mean((preds - ys) ** 2)
 
     p_sharded = jax.device_put(params0, stage_shardings(mesh, params0))
-    g_pipe = jax.grad(loss_pipe)(p_sharded)
-    g_seq = jax.grad(loss_seq)(params0)
+    g_pipe = jax.jit(jax.grad(loss_pipe))(p_sharded)
+    g_seq = jax.jit(jax.grad(loss_seq))(params0)
     for k in ("W", "b"):
         np.testing.assert_allclose(np.asarray(g_pipe[k]),
                                    np.asarray(g_seq[k]),

@@ -1105,49 +1105,3 @@ def test_multiprocess_elastic_tests_are_slow_marked_and_bounded():
     # (asserted behaviorally in tests/test_elastic.py's hung-worker test)
     from deeplearning4j_tpu.checkpoint import supervisor as sup_mod
     assert "kill_all()" in inspect.getsource(sup_mod.train_until_process)
-
-
-@pytest.mark.slow
-def test_bench_elastic_quick_smoke():
-    """The elastic microbench runs end-to-end and emits the reshard /
-    sharded-save / membership-transition metric lines (metrics only —
-    thresholds belong to quiet full runs per the 9p note)."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, BENCH_QUICK="1", BENCH_ONLY="elastic",
-               JAX_PLATFORMS="cpu")
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run([sys.executable, "bench.py"], cwd=repo, env=env,
-                          capture_output=True, text=True, timeout=420)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    lines = [json.loads(l) for l in proc.stdout.splitlines() if l.strip()]
-    assert not any("error" in l for l in lines), lines
-    by_metric = {l["metric"]: l for l in lines}
-    for want in ("elastic_sharded_save_ms", "elastic_reshard_restore_ms",
-                 "elastic_membership_transition_ms"):
-        assert by_metric[want]["value"] > 0
-    assert by_metric["elastic_reshard_restore_ms"]["num_shards"] == 4
-
-
-# --------------------------------------------------------------- bench smoke
-def test_bench_resilience_quick_smoke():
-    """CI tripwire: the resilience microbench runs end-to-end and emits the
-    restore-latency and hot-swap-gap metric lines. No thresholds here —
-    the 9p filesystem's fsync jitter makes disk numbers meaningful only on
-    quiet full runs (see the checkpoint bench note)."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, BENCH_QUICK="1", BENCH_ONLY="resilience",
-               JAX_PLATFORMS="cpu")
-    env.pop("XLA_FLAGS", None)  # single-device run, no 8-way host mesh
-    proc = subprocess.run([sys.executable, "bench.py"], cwd=repo, env=env,
-                          capture_output=True, text=True, timeout=420)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    lines = [json.loads(l) for l in proc.stdout.splitlines() if l.strip()]
-    assert not any("error" in l for l in lines), lines
-    by_metric = {l["metric"]: l for l in lines}
-    restore = by_metric["checkpoint_restore_latest_ms"]
-    assert restore["value"] > 0
-    assert {"restore_local_ms", "restore_object_store_ms"} <= set(restore)
-    swap = by_metric["serving_hot_swap_max_gap_ms"]
-    assert swap["value"] > 0
-    assert swap["swaps"] == 1
-    assert swap["gap_p50_plain_ms"] > 0

@@ -139,8 +139,8 @@ def test_chunked_kda_stays_the_recurrence_when_keys_point_the_same_way(
     want = _recurrence(q, k, v, g, b)
     assert np.max(np.abs(np.asarray(got) - want)) < 5e-5 * max(
         1.0, np.max(np.abs(want)))
-    grads = jax.grad(lambda *a: jnp.sum(jnp.sin(chunked_kda(*a))),
-                     argnums=range(5))(q, k, v, g, b)
+    grads = jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(chunked_kda(*a))),
+                             argnums=range(5)))(q, k, v, g, b)
     assert all(np.all(np.isfinite(a)) for a in grads)
 
 
@@ -164,9 +164,10 @@ def test_chunked_kda_gradients_match_a_scan_over_tokens(kda_impl):
     def scalar(f):
         return lambda *a: jnp.sum(jnp.sin(f(*a)))
 
-    got = jax.grad(scalar(lambda *a: chunked_kda(*a, chunk=chunk, sub=8)),
-                   argnums=range(5))(*args)
-    want = jax.grad(scalar(scan_form), argnums=range(5))(*args)
+    got = jax.jit(jax.grad(
+        scalar(lambda *a: chunked_kda(*a, chunk=chunk, sub=8)),
+        argnums=range(5)))(*args)
+    want = jax.jit(jax.grad(scalar(scan_form), argnums=range(5)))(*args)
     for a, b in zip(got, want):
         assert np.all(np.isfinite(a))
         assert float(jnp.max(jnp.abs(a - b))) < TOL * max(
@@ -192,8 +193,9 @@ def test_kda_layer_masks_its_output_and_keeps_its_width():
     params, state = layer.init(jax.random.key(0), it)
     x = jax.random.normal(jax.random.key(1), (2, 20, 12))
     mask = jnp.concatenate([jnp.ones((2, 15)), jnp.zeros((2, 5))], 1)
-    out, _ = layer.apply(params, state, x, mask=mask)
-    free, _ = layer.apply(params, state, x)
+    apply = jax.jit(layer.apply)
+    out, _ = apply(params, state, x, mask=mask)
+    free, _ = apply(params, state, x)
     assert out.shape == (2, 20, 12)
     assert np.allclose(out[:, :15], free[:, :15], atol=1e-6)
     assert not np.any(np.asarray(out[:, 15:]))
@@ -262,7 +264,8 @@ def test_gated_delta_net_is_its_equations_token_by_token(kda_impl):
         def loss(params, x):
             o = fn(params, x)
             return jnp.sum(jnp.sin(o)), o
-        return jax.value_and_grad(loss, (0, 1), has_aux=True)(params, x)
+        return jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True))(
+            params, x)
 
     got = run(lambda p, x: layer.apply(p, state, x)[0])
     want = run(lambda p, x: _gdn_plain(layer, p, x, _scalar_decay_scan))
@@ -276,7 +279,7 @@ def test_gated_delta_net_is_its_equations_token_by_token(kda_impl):
     assert float(jnp.max(jnp.abs(got[0][1] - per_channel))) < 20 * TOL
     assert layer.output_type(InputType.recurrent(12, t)).size == 12
     mask = jnp.ones((bsz, t)).at[0, t - 5:].set(0.0)
-    masked, _ = layer.apply(params, state, x, mask=mask)
+    masked, _ = jax.jit(layer.apply)(params, state, x, mask=mask)
     assert float(jnp.max(jnp.abs(masked[0, t - 5:]))) == 0.0
     with pytest.raises(ValueError, match="no multiple"):
         GatedDeltaNet(n_key_heads=3, n_value_heads=4).output_type(
@@ -341,7 +344,8 @@ def _out_and_grads(fn, q, k, v):
         o = fn(*a).astype(jnp.float32)
         return jnp.sum(jnp.sin(o)), o
 
-    (_, o), grads = jax.value_and_grad(run, (0, 1, 2), has_aux=True)(q, k, v)
+    (_, o), grads = jax.jit(jax.value_and_grad(
+        run, (0, 1, 2), has_aux=True))(q, k, v)
     return (o,) + tuple(g.astype(jnp.float32) for g in grads)
 
 
@@ -407,8 +411,9 @@ def test_mla_layer_counts_the_path_it_took(attn_impl):
     x = jax.random.normal(jax.random.key(1), (1, t, 12))
     before = dict(GLOBAL.as_dict().get("counters", {}))
     kernels = _attention_counters()
-    out, _ = layer.apply(params, state, x)
-    layer.apply(params, state, x[:, :block])
+    apply = jax.jit(layer.apply)
+    out, _ = apply(params, state, x)
+    apply(params, state, x[:, :block])
     after = GLOBAL.as_dict()["counters"]
     assert out.shape == (1, t, 12)
     assert after["attention.mla_blocked"] == before.get(
@@ -449,14 +454,15 @@ def test_mla_layer_is_the_dense_form_and_masks_its_output(attn_impl):
         def loss(params, x):
             o = fn(params, x)
             return jnp.sum(jnp.sin(o)), o
-        return jax.value_and_grad(loss, (0, 1), has_aux=True)(params, x)
+        return jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True))(
+            params, x)
 
     got = run(lambda p, x: layer.apply(p, state, x)[0])
     want = run(dense)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert float(jnp.max(jnp.abs(a - b))) < 20 * TOL
     mask = jnp.ones((2, t)).at[1, t - 5:].set(0.0)
-    masked, _ = layer.apply(params, state, x, mask=mask)
+    masked, _ = jax.jit(layer.apply)(params, state, x, mask=mask)
     assert float(jnp.max(jnp.abs(masked[1, t - 5:]))) == 0.0
     assert float(jnp.max(jnp.abs(masked[0] - got[0][1][0]))) < TOL
 
@@ -533,7 +539,8 @@ def test_gated_attention_is_full_heads_with_k_and_v_repeated(attn_impl):
         def loss(params, x):
             o = fn(params, x)
             return jnp.sum(jnp.sin(o)), o
-        return jax.value_and_grad(loss, (0, 1), has_aux=True)(params, x)
+        return jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True))(
+            params, x)
 
     got = run(lambda p, x: layer.apply(p, state, x)[0])
     want = run(dense)
@@ -541,7 +548,7 @@ def test_gated_attention_is_full_heads_with_k_and_v_repeated(attn_impl):
         assert float(jnp.max(jnp.abs(a - b))) < 20 * TOL
     assert float(jnp.max(jnp.abs(got[1][0]["Wk"]))) > 0
     mask = jnp.ones((2, t)).at[1, t - 5:].set(0.0)
-    masked, _ = layer.apply(params, state, x, mask=mask)
+    masked, _ = jax.jit(layer.apply)(params, state, x, mask=mask)
     assert float(jnp.max(jnp.abs(masked[1, t - 5:]))) == 0.0
     assert float(jnp.max(jnp.abs(masked[0] - got[0][1][0]))) < TOL
 
@@ -555,8 +562,9 @@ def test_gated_attention_counts_the_path_it_took(attn_impl):
     params, state = layer.init(jax.random.key(0), InputType.recurrent(12, t))
     x = jax.random.normal(jax.random.key(1), (1, t, 12))
     before = dict(GLOBAL.as_dict().get("counters", {}))
-    out, _ = layer.apply(params, state, x)
-    layer.apply(params, state, x[:, :block])
+    apply = jax.jit(layer.apply)
+    out, _ = apply(params, state, x)
+    apply(params, state, x[:, :block])
     after = GLOBAL.as_dict()["counters"]
     assert out.shape == (1, t, 12)
     for name in ("attention.gqa_blocked", "attention.gqa_single_tile"):
@@ -789,10 +797,10 @@ def test_routed_experts_are_the_masked_loop(held, offset, softmax):
     out, new = layer.apply(params, state, x)
     want = _plain_routed(layer, params, x, state["bias"])
     assert float(jnp.max(jnp.abs(out - want))) < TOL
-    got = jax.grad(lambda p: jnp.sum(jnp.sin(layer.apply(p, state, x)[0])))(
-        params)
-    ref = jax.grad(lambda p: jnp.sum(jnp.sin(_plain_routed(
-        layer, p, x, state["bias"]))))(params)
+    got = jax.jit(jax.grad(lambda p: jnp.sum(jnp.sin(
+        layer.apply(p, state, x)[0]))))(params)
+    ref = jax.jit(jax.grad(lambda p: jnp.sum(jnp.sin(_plain_routed(
+        layer, p, x, state["bias"])))))(params)
     for key in ref:
         assert float(jnp.max(jnp.abs(got[key] - ref[key]))) < TOL, key
     assert int(new["pairs_dropped"]) == 0
@@ -858,10 +866,10 @@ def test_both_buffer_tiers_give_the_masked_loop(push):
     want = _plain_routed(layer, params, x, state["bias"])
     assert float(jnp.max(jnp.abs(out - want))) < TOL * max(
         1.0, float(jnp.max(jnp.abs(want))))
-    got = jax.grad(lambda p, x: jnp.sum(jnp.sin(
-        layer.apply(p, state, x)[0])), (0, 1))(params, x)
-    ref = jax.grad(lambda p, x: jnp.sum(jnp.sin(_plain_routed(
-        layer, p, x, state["bias"]))), (0, 1))(params, x)
+    got = jax.jit(jax.grad(lambda p, x: jnp.sum(jnp.sin(
+        layer.apply(p, state, x)[0])), (0, 1)))(params, x)
+    ref = jax.jit(jax.grad(lambda p, x: jnp.sum(jnp.sin(_plain_routed(
+        layer, p, x, state["bias"]))), (0, 1)))(params, x)
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(ref)):
         assert float(jnp.max(jnp.abs(a - b))) < TOL * max(
@@ -935,10 +943,10 @@ def test_a_quarter_share_runs_half_the_slots_or_all_of_them(push):
     want = _plain_routed(layer, params, x, state["bias"])
     assert float(jnp.max(jnp.abs(out - want))) < TOL * max(
         1.0, float(jnp.max(jnp.abs(want))))
-    got = jax.grad(lambda p, x: jnp.sum(jnp.sin(
-        layer.apply(p, state, x)[0])), (0, 1))(params, x)
-    ref = jax.grad(lambda p, x: jnp.sum(jnp.sin(_plain_routed(
-        layer, p, x, state["bias"]))), (0, 1))(params, x)
+    got = jax.jit(jax.grad(lambda p, x: jnp.sum(jnp.sin(
+        layer.apply(p, state, x)[0])), (0, 1)))(params, x)
+    ref = jax.jit(jax.grad(lambda p, x: jnp.sum(jnp.sin(_plain_routed(
+        layer, p, x, state["bias"]))), (0, 1)))(params, x)
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(ref)):
         assert float(jnp.max(jnp.abs(a - b))) < TOL * max(
@@ -990,7 +998,8 @@ def test_the_gather_form_of_the_dispatch_is_the_scatter_form(
     for form, constant in (("gather", float("inf")), ("scatter", 0.0)):
         monkeypatch.setattr(module, "DISPATCH_GATHER_RATIO", constant)
         before = GLOBAL.counter(f"moe.dispatch_{form}")
-        got[form] = jax.value_and_grad(both, (0, 1), has_aux=True)(params, x)
+        got[form] = jax.jit(jax.value_and_grad(
+            both, (0, 1), has_aux=True))(params, x)
         assert GLOBAL.counter(f"moe.dispatch_{form}") > before
     (_, (out_g, new_g)), grads_g = got["gather"]
     (_, (out_s, new_s)), grads_s = got["scatter"]

@@ -45,7 +45,8 @@ def _out_and_grads(fn, q, k, v):
         o = fn(*a)
         return jnp.sum(jnp.sin(o)), o
 
-    (_, o), grads = jax.value_and_grad(run, (0, 1, 2), has_aux=True)(q, k, v)
+    (_, o), grads = jax.jit(jax.value_and_grad(
+        run, (0, 1, 2), has_aux=True))(q, k, v)
     return (o,) + grads
 
 

@@ -18,7 +18,6 @@ test_data_plane.py discipline; everything else here is tier-1 and lean.
 
 import json
 import os
-import subprocess
 import sys
 import threading
 
@@ -40,7 +39,6 @@ from deeplearning4j_tpu.datasets.sharded import (ShardedDataset,
                                                  reconcile_ledger)
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
-REPO_ROOT = os.path.dirname(_HERE)
 _ELASTIC_WORKER = os.path.join(_HERE, "elastic_worker.py")
 
 AK, SK = "test-access", "test-secret-key"
@@ -471,29 +469,6 @@ def test_kill_resume_from_lake_bitwise_and_ledger_clean():
         assert 0 < sds.peak_resident_bytes < x.nbytes + y.nbytes
         assert emu.faults_injected >= 3
         cm.close()
-
-
-# =============================================================== bench smoke
-def test_bench_data_lake_quick_smoke():
-    """CI tripwire: bench.py's data_lake bench runs end-to-end and emits
-    the throughput-per-tier and restore-per-tier lines (BENCH_QUICK=1)."""
-    env = dict(os.environ, BENCH_QUICK="1", BENCH_ONLY="data_lake",
-               JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO_ROOT, "bench.py")],
-        capture_output=True, text=True, timeout=600, env=env,
-        cwd=REPO_ROOT)
-    assert out.returncode == 0, out.stderr[-2000:]
-    lines = [json.loads(ln) for ln in out.stdout.splitlines()
-             if ln.startswith("{")]
-    [rps] = [ln for ln in lines
-             if ln.get("metric") == "data_lake_records_per_sec"]
-    assert rps["ram_rps"] > 0 and rps["lake_cold_rps"] > 0
-    assert rps["lake_cached_rps"] > 0 and rps["cache_hit_rate"] > 0
-    [res] = [ln for ln in lines
-             if ln.get("metric") == "data_lake_restore_ms"]
-    assert res["local_fs_ms"] > 0 and res["emulator_ms"] > 0
-    assert res["cached_warm_ms"] > 0
 
 
 # ==================================== multi-process fleet headline (slow)

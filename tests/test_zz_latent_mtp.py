@@ -199,29 +199,37 @@ def test_the_low_rank_query_and_the_rotation_follow_the_equations(rotated):
     assert params["Wqa"].shape == (32, 12) and params["Wqb"].shape == (12, 48)
     assert layer.regularizable() == ("Wqa", "Wqb", "Wkva", "Wkvb", "Wo")
     with jax.default_matmul_precision("highest"):
-        got = layer.apply(params, {}, x)[0]
-        want = _plain_latent(layer, params, x)
+        got = jax.jit(layer.apply)(params, {}, x)[0]
+        want = jax.jit(_plain_latent, static_argnums=0)(layer, params, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
     # and it is not the layer without its rotation
-    plain = dataclasses.replace(layer, rope_theta=0.0).apply(params, {}, x)[0]
+    plain = jax.jit(dataclasses.replace(layer, rope_theta=0.0).apply)(
+        params, {}, x)[0]
     assert float(jnp.max(jnp.abs(plain - got))) > 1e-2
 
 
-@pytest.mark.parametrize("leaf", ["Wqa", "q_norm", "Wqb", "Wkva", "kv_norm",
-                                  "Wkvb", "Wo", "x"])
-def test_every_gradient_of_the_rotated_layer_follows_the_equations(rotated,
-                                                                   leaf):
+@pytest.fixture(scope="module")
+def rotated_gradients(rotated):
+    """Both forms' gradients of one weighted sum, made once for the cases
+    below (each reads one leaf of them)."""
     layer, params, x = rotated
     w = jax.random.normal(jax.random.key(9), (2, 40, 32))
 
     def through(f):
         def loss(p, a):
             return jnp.sum(f(p, a) * w)
-        return jax.grad(loss, argnums=(0, 1))
+        return jax.jit(jax.grad(loss, argnums=(0, 1)))
 
     with jax.default_matmul_precision("highest"):
-        gp, gx = through(lambda p, a: layer.apply(p, {}, a)[0])(params, x)
-        wp, wx = through(lambda p, a: _plain_latent(layer, p, a))(params, x)
+        return (through(lambda p, a: layer.apply(p, {}, a)[0])(params, x),
+                through(lambda p, a: _plain_latent(layer, p, a))(params, x))
+
+
+@pytest.mark.parametrize("leaf", ["Wqa", "q_norm", "Wqb", "Wkva", "kv_norm",
+                                  "Wkvb", "Wo", "x"])
+def test_every_gradient_of_the_rotated_layer_follows_the_equations(
+        rotated_gradients, leaf):
+    (gp, gx), (wp, wx) = rotated_gradients
     got, want = ((gx, wx) if leaf == "x" else (gp[leaf], wp[leaf]))
     assert float(jnp.max(jnp.abs(want))) > 0
     assert float(jnp.max(jnp.abs(got - want))) < 1e-4 * float(
@@ -432,8 +440,8 @@ def test_the_embedding_s_gradient_holds_both_uses():
 
     def grad(w):
         net = nets[w]
-        return jax.grad(lambda p: net._loss_fn(
-            p, net.state, x, y, None, None, None)[0])(nets[0.0].params)
+        return jax.jit(jax.grad(lambda p: net._loss_fn(
+            p, net.state, x, y, None, None, None)[0]))(nets[0.0].params)
 
     g0, g5, g1 = (grad(w)["embed"]["W"] for w in (0.0, 0.5, 1.0))
     module = g1 - g0

@@ -21,16 +21,13 @@ Covers the PR-16 acceptance bars, all on CPU via Pallas interpret mode
   (JSON round-trip, ``apply_tuning``, ``ParallelInference(tuning=...)``)
   into serving;
 - a warmed retrieval ladder under forced-Pallas serves a burst with ZERO
-  new compiles (CompileWatch-asserted);
-- ``bench.py`` pallas ablation smoke (BENCH_QUICK subprocess).
+  new compiles (CompileWatch-asserted).
 """
 
 import json
 import math
 import os
 import re
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -54,7 +51,6 @@ from deeplearning4j_tpu.retrieval import (BruteForceIndex, IVFPQIndex,
                                           PQIndex, synthetic_corpus)
 
 RNG = np.random.default_rng(16)
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _relerr(a, b):
@@ -127,7 +123,7 @@ class TestBnParity:
         out = {}
         for flag in (False, True):
             with pk.override(enabled=flag):
-                out[flag] = jax.value_and_grad(f)(net.params)
+                out[flag] = jax.jit(jax.value_and_grad(f))(net.params)
         loss_ref, grads_ref = out[False]
         loss_pk, grads_pk = out[True]
         assert _relerr(loss_ref, loss_pk) <= 1e-5
@@ -354,28 +350,6 @@ def test_forced_pallas_warmed_ladder_serves_with_zero_compiles():
             "kernel.pallas_adc_pq"] >= 1
 
 
-# ------------------------------------------------------------ bench smoke
-def test_bench_pallas_quick_smoke():
-    """CI tripwire: the pallas on/off ablation bench runs end-to-end and
-    emits paired metrics for every probe (BENCH_QUICK=1)."""
-    env = dict(os.environ, BENCH_QUICK="1", BENCH_ONLY="pallas",
-               JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO_ROOT, "bench.py")],
-        capture_output=True, text=True, timeout=600, env=env)
-    assert out.returncode == 0, out.stderr[-2000:]
-    lines = [json.loads(l) for l in out.stdout.splitlines() if l.strip()]
-    assert not any("error" in l for l in lines), lines
-    metrics = {l["metric"]: l for l in lines if "metric" in l}
-    for stem in ("pallas_bn_block_step_ms", "pallas_resnet50_activation_bytes",
-                 "pallas_retrieval_pq_qps", "pallas_retrieval_ivf_pq_qps",
-                 "pallas_retrieval_int4_qps"):
-        for tag in ("off", "on"):
-            assert f"{stem}_{tag}" in metrics, sorted(metrics)
-    assert metrics["pallas_bn_block_step_ms_on"]["speedup_vs_off"] > 0
-    assert metrics["pallas_bn_block_step_ms_on"]["kernel_mode"] == "interpret"
-
-
 # ----------------------------------------------------- KDA's chunked scan
 # perf/pallas/kda.py behind nn/conf/linear_attention.py::chunked_kda. The
 # recurrence, the keys that point the same way and the gradients against a
@@ -423,10 +397,12 @@ def _jnp_chunk(q, k, v, g, b, state):
 def test_kda_chunk_forward_and_backward_are_chunk_terms_and_step(
         decay, exact_products):
     q, k, v, g, b, state, do, dstate = _kda_chunk(1, decay)
-    want, vjp = jax.vjp(_jnp_chunk, q, k, v, g, b, state)
-    for a, w in zip(kda.chunk_forward(q, k, v, g, b, state, True), want):
+    want, vjp = jax.vjp(jax.jit(_jnp_chunk), q, k, v, g, b, state)
+    for a, w in zip(jax.jit(kda.chunk_forward, static_argnums=6)(
+            q, k, v, g, b, state, True), want):
         _close(a, w)
-    got = kda.chunk_backward(q, k, v, g, b, state, do, dstate, True)
+    got = jax.jit(kda.chunk_backward, static_argnums=8)(
+        q, k, v, g, b, state, do, dstate, True)
     for a, w in zip(got, vjp((do, dstate))):
         assert np.all(np.isfinite(a))
         _close(a, w)
@@ -447,12 +423,14 @@ def test_kda_scores_backward_is_the_vjp_of_decayed_scores(decay,
     def scores(q, kx, k, g_cum):      # kx: k as the rows' factor
         return la._decayed_scores(jnp.stack([q, kx]), k, g_cum, 8)
 
-    both, vjp = jax.vjp(scores, q, k, k, g_cum)
+    both, vjp = jax.vjp(jax.jit(scores), q, k, k, g_cum)
     want_q, want_kx, want_k, want_g = vjp(jnp.stack([dp, dkk]))
-    p, kk_off, kk_cols = kda._chunk_terms(q, k, g, True)[1:]
+    p, kk_off, kk_cols = jax.jit(kda._chunk_terms, static_argnums=3)(
+        q, k, g, True)[1:]
     _close(p, both[0])
     _close(kk_off + kda._placed(kk_cols), jnp.tril(both[1], -1))
-    dq, dkx, dk = kda._scores_backward(q, k, g_cum, dp, dkk, True)
+    dq, dkx, dk = jax.jit(kda._scores_backward, static_argnums=5)(
+        q, k, g_cum, dp, dkk, True)
     _close(dq, want_q)
     _close(dkx, want_kx)
     _close(dk, want_k)
@@ -477,9 +455,10 @@ def test_kda_block_solves_are_solve_unit_lower_and_its_transpose(
     a_off = jnp.where(inside, 0.0, a)
     a_cols = [jnp.sum(jnp.where(col_in_block == i, a, 0.0), 1, keepdims=True)
               for i in range(8)]
-    want, vjp = jax.vjp(lambda rhs: la._solve_unit_lower(a, rhs, 8), rhs)
-    _close(kda._solve_lower(a_off, a_cols, rhs), want, 5e-5)
-    _close(kda._solve_upper(a_off, a_cols, dx), vjp(dx)[0], 5e-5)
+    want, vjp = jax.vjp(jax.jit(lambda rhs: la._solve_unit_lower(a, rhs, 8)),
+                        rhs)
+    _close(jax.jit(kda._solve_lower)(a_off, a_cols, rhs), want, 5e-5)
+    _close(jax.jit(kda._solve_upper)(a_off, a_cols, dx), vjp(dx)[0], 5e-5)
 
 
 def _kda_layer_grads(layer, t, seed=0, dtype=jnp.float32, batch=1):
@@ -493,7 +472,7 @@ def _kda_layer_grads(layer, t, seed=0, dtype=jnp.float32, batch=1):
         out = layer.apply(params, state, x)[0]
         return jnp.sum(jnp.sin(out.astype(jnp.float32)))
 
-    return jax.value_and_grad(loss, argnums=(0, 1))(params, x)
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(params, x)
 
 
 def _family_counters(family):
@@ -561,10 +540,11 @@ def test_kda_kernels_take_two_sequences_of_heads_of_256(exact_products):
         return jnp.sum(jnp.sin(o)), o
 
     with pk.override(enabled=False):
-        want = jax.value_and_grad(run, range(5), has_aux=True)(*args)
+        want = jax.jit(
+            jax.value_and_grad(run, range(5), has_aux=True))(*args)
     with pk.override(enabled=True, interpret=True):
         assert kda.supported(*args, 64, 8)
-        got = jax.value_and_grad(run, range(5), has_aux=True)(*args)
+        got = jax.jit(jax.value_and_grad(run, range(5), has_aux=True))(*args)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         _close(a, b)
 
@@ -626,7 +606,8 @@ def _attention_out_and_grads(q, k, v, block, fn=None):
         o = fn(*a, block)
         return jnp.sum(jnp.sin(o.astype(jnp.float32))), o
 
-    (_, o), grads = jax.value_and_grad(run, (0, 1, 2), has_aux=True)(q, k, v)
+    (_, o), grads = jax.jit(jax.value_and_grad(
+        run, (0, 1, 2), has_aux=True))(q, k, v)
     return (o,) + grads
 
 

@@ -424,7 +424,7 @@ def test_autotune_with_budget_carries_plan():
         assert re_applied == tuned
 
 
-# ------------------------------------------------------------- CLI + bench
+# ------------------------------------------------------------------- CLI
 def test_autotune_cli_writes_record(tmp_path):
     out = str(tmp_path / "lenet.tuning.json")
     proc = subprocess.run(
@@ -440,26 +440,6 @@ def test_autotune_cli_writes_record(tmp_path):
     assert rec.signature == conf_signature(LeNet(num_classes=10).conf())
     line = json.loads(proc.stdout.strip().splitlines()[-1])
     assert line["out"] == out
-
-
-def test_bench_autotune_quick_smoke():
-    """Tier-1 acceptance: bench_autotune runs end-to-end under BENCH_QUICK
-    and reports the tuned-vs-default metrics (metrics-only per the 9p
-    note)."""
-    env = dict(os.environ, BENCH_QUICK="1", BENCH_ONLY="autotune",
-               JAX_PLATFORMS="cpu")
-    proc = subprocess.run([sys.executable, "bench.py"], cwd=REPO, env=env,
-                          capture_output=True, text=True, timeout=420)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    lines = [json.loads(l) for l in proc.stdout.strip().splitlines()
-             if l.startswith("{")]
-    at = [l for l in lines if l["metric"].startswith("autotune_")]
-    assert at, proc.stdout
-    entry = at[0]
-    assert "error" not in entry, entry
-    assert entry["tuned_activation_bytes"] \
-        <= 0.75 * entry["default_activation_bytes"]
-    assert entry["buckets"]
 
 
 # ---------------- PR-13 fusion satellites (helpers from test_fusion)

@@ -8,8 +8,7 @@ scoring path, and the serving integration (429 under overload, 504 on
 expired deadlines, hot-swap index rebuild mid-burst with zero non-200s
 on admitted requests) — plus the satellites: tree-vs-brute property
 tests (random + duplicate-point), the chunked-Lloyd KMeans parity, the
-b64 wire format on /knn and the retrieval endpoints, the build CLI and
-the bench smoke.
+b64 wire format on /knn and the retrieval endpoints and the build CLI.
 
 (Named test_zz_* so the file sorts after every seed test: if the tier-1
 timeout ever cuts the tail, it evicts these before any seed dot.)
@@ -18,7 +17,6 @@ timeout ever cuts the tail, it evicts these before any seed dot.)
 import base64
 import json
 import os
-import subprocess
 import sys
 import threading
 import time
@@ -55,7 +53,7 @@ def _oracle(points, q, k):
 @pytest.fixture(scope="module")
 def corpus():
     # the one shared recipe (retrieval.synthetic_corpus) so the tier-1
-    # gates, the bench and the CLI all measure the same distribution
+    # gates and the CLI measure the same distribution
     return retrieval.synthetic_corpus(4000, 32, n_clusters=50, seed=11,
                                       queries=64)
 
@@ -681,32 +679,3 @@ def test_build_index_cli_in_process(tmp_path):
                     "--nprobe", "1", "--n-cells", "20", "--out", out2,
                     "--gate-min-recall", "1.01"])
     assert rc2 == 1 and not os.path.exists(out2)
-
-
-def test_bench_retrieval_quick_smoke():
-    """CI tripwire: bench.py's retrieval bench runs end-to-end and emits
-    QPS + recall lines for every index kind (BENCH_QUICK=1)."""
-    env = dict(os.environ, BENCH_QUICK="1", BENCH_ONLY="retrieval",
-               JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO_ROOT, "bench.py")],
-        capture_output=True, text=True, timeout=600, env=env)
-    assert out.returncode == 0, out.stderr[-2000:]
-    lines = [json.loads(l) for l in out.stdout.splitlines() if l.strip()]
-    metrics = {l["metric"]: l for l in lines if "metric" in l}
-    assert not any("error" in l for l in lines), lines
-    for kind in ("vptree_host", "brute", "ivf", "ivf_int8", "int4", "pq",
-                 "ivf_pq"):
-        key = f"retrieval_{kind}_2k_qps"
-        assert key in metrics, sorted(metrics)
-        assert metrics[key]["value"] > 0
-    assert metrics["retrieval_ivf_2k_qps"]["recall_at_10"] >= 0.95
-    assert metrics["retrieval_ivf_int8_2k_qps"]["recall_at_10"] >= 0.94
-    # the compression ladder: re-ranked PQ holds recall at a fraction of
-    # the bytes; packed int4 is the smallest whole-vector table
-    assert metrics["retrieval_pq_2k_qps"]["recall_at_10"] >= 0.9
-    assert metrics["retrieval_ivf_pq_2k_qps"]["recall_at_10"] >= 0.9
-    assert metrics["retrieval_pq_2k_qps"]["index_mb"] \
-        < metrics["retrieval_brute_2k_qps"]["index_mb"] / 8
-    assert metrics["retrieval_int4_2k_qps"]["index_mb"] \
-        < metrics["retrieval_brute_2k_qps"]["index_mb"] / 4

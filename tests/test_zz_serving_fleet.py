@@ -541,7 +541,7 @@ def test_scale_down_victim_is_placement_safe():
     assert scaler._victim({"lo": info("lo", ["a"], [], 0)}) is None
 
 
-# ------------------------------------------------------------ CLI + bench
+# ------------------------------------------------------------------- CLI
 def test_fleet_cli_parser_and_model_spec():
     import importlib.util
     spec = importlib.util.spec_from_file_location(
@@ -556,25 +556,6 @@ def test_fleet_cli_parser_and_model_spec():
     with pytest.raises(SystemExit):
         cli.build_parser().parse_args(
             ["up", "--store", "/tmp/s", "--model", "no-equals-sign"])
-
-
-def test_bench_fleet_quick_smoke():
-    """Tier-1 acceptance: bench_fleet runs end-to-end under BENCH_QUICK
-    and reports router overhead + scale-up time-to-ready (metrics-only
-    per the 9p note)."""
-    env = dict(os.environ, BENCH_QUICK="1", BENCH_ONLY="fleet",
-               JAX_PLATFORMS="cpu")
-    proc = subprocess.run([sys.executable, "bench.py"], cwd=REPO, env=env,
-                          capture_output=True, text=True, timeout=420)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    lines = [json.loads(l) for l in proc.stdout.strip().splitlines()
-             if l.startswith("{")]
-    by_metric = {l["metric"]: l for l in lines}
-    over = by_metric["fleet_router_overhead_p50_ms"]
-    assert "error" not in over
-    assert over["routed_p50_ms"] >= over["direct_p50_ms"] > 0
-    up = by_metric["fleet_scale_up_time_to_ready_s"]
-    assert "error" not in up and up["value"] > 0
 
 
 # ------------------------------------------------- multi-process chaos (slow)

@@ -4,8 +4,8 @@
 Usage:
     python tools/run_lint.py [options] [path ...]
 
-With no paths lints the tier-1 surface: ``deeplearning4j_tpu/``,
-``bench.py`` and ``tools/``. Exits 1 on any violation (or, with
+With no paths lints the tier-1 surface: ``deeplearning4j_tpu/`` and
+``tools/``. Exits 1 on any violation (or, with
 ``--audit-waivers``, on any stale waiver) — the same contract
 ``tests/test_lint.py`` enforces in CI.
 
