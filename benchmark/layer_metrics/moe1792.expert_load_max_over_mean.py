@@ -1,0 +1,14 @@
+"""The busiest held expert's pairs over the mean of the held experts'
+in the cell of the 32-expert sigmoid router of 1792-wide experts without a
+shared one: what ``moe.expert_load_max_over_mean`` reads, by that reader's own code, under a name
+of its own, as ``moe64.expert_load_max_over_mean`` does. (The ``moe.*`` entries of the manifest
+list the cells they are reported in, and a PR that adds a cell may not edit
+an entry: PERF.md section 7; ROADMAP Queue 2 item 1a queues the fold.)"""
+
+LAYER = "routed experts"
+UNIT = "x"
+MOVES = "train_items_per_s"
+
+
+def read(ctx):
+    return ctx["cell"].layer_reader("moe.expert_load_max_over_mean")(ctx)

@@ -87,7 +87,7 @@ def _layer_name(i: Optional[int], layer) -> str:
 _N_OUT_OPTIONAL = ("TransformerEncoderBlock", "KimiDeltaAttention",
                    "GatedDeltaNet", "MultiHeadLatentAttention",
                    "GatedAttention", "RotaryAttention", "GatedFeedForward",
-                   "RoutedExperts", "MultiTokenCombine")
+                   "RoutedExperts", "MultiTokenCombine", "GatedShortConv")
 
 
 def _check_layer(layer, cur, name: str) -> List[ValidationIssue]:
@@ -265,6 +265,11 @@ def validate_multilayer(conf, *, eval_shape_check: bool = False,
             cur = pre.output_type(cur)
         types.append(cur)
         issues.extend(_check_layer(layer, cur, name))
+        if getattr(layer, "tied_to", ""):
+            issues.append(ValidationIssue(
+                "tied-head", name,
+                f"tied_to '{layer.tied_to}' names a vertex: a "
+                "ComputationGraph's field, a stack has none"))
         if layer.is_output_layer() and i != len(conf.layers) - 1:
             issues.append(ValidationIssue(
                 "output-layer-position", name,
@@ -509,6 +514,9 @@ def validate_graph(conf, *, eval_shape_check: bool = False,
             if pre is not None:
                 cur = pre.output_type(cur)
             issues.extend(_check_layer(obj, cur, disp))
+            ti = _tied_issue(conf, obj, cur, disp)
+            if ti is not None:
+                issues.append(ti)
             try:
                 known[name] = obj.output_type(cur)
             except ValueError as e:
@@ -558,6 +566,28 @@ def validate_graph(conf, *, eval_shape_check: bool = False,
             and not any(i.severity == "error" for i in issues):
         issues.extend(_eval_shape_check_graph(conf, batch))
     return issues
+
+
+def _tied_issue(conf, layer, cur, name: str) -> Optional[ValidationIssue]:
+    """A layer whose ``tied_to`` names a vertex reads that vertex's matrix
+    ``W`` transposed: the vertex has to be a layer whose ``W`` is this
+    layer's (n_out, n_in)."""
+    tied = getattr(layer, "tied_to", "")
+    if not tied:
+        return None
+    other = conf.vertices.get(tied, (None,))[0]
+    if not (hasattr(other, "n_in") and hasattr(other, "n_out")):
+        return ValidationIssue(
+            "tied-head", name,
+            f"tied_to '{tied}' is no layer with a matrix to read "
+            f"(vertices {sorted(conf.vertices)})")
+    want = (layer.n_out, layer.n_in or cur.size)
+    if (other.n_in, other.n_out) != want:
+        return ValidationIssue(
+            "tied-head", name,
+            f"tied_to '{tied}' holds a matrix of ({other.n_in}, "
+            f"{other.n_out}), this layer reads one of {want} transposed")
+    return None
 
 
 # ------------------------------------------------- jax.eval_shape cross-check

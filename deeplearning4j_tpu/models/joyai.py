@@ -6,8 +6,8 @@ and the auxiliary-loss-free router; section 2.2: multi-token prediction).
 
 Not in the reference zoo. A decoder of pre-norm blocks,
 ``h = x + Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``, a final RMSNorm
-and an untied head. ``Attn`` is latent attention in every layer, with a
-low-rank query (``q_lora_rank``) and a decoupled rotation of the
+and a head (tied to the embedding where ``tie_word_embeddings``). ``Attn``
+is latent attention in every layer, with a low-rank query (``q_lora_rank``) and a decoupled rotation of the
 ``qk_rope_head_dim`` widths in the interleaved pairing
 (``rope_interleave``) at ``rope_theta``; ``FFN`` is a dense SwiGLU in the
 first ``first_k_dense_replace`` layers and, in the rest, ``n_routed_experts``
@@ -64,8 +64,6 @@ class JoyAIFlash(ZooModel):
         vocab = vocab_rows or config["vocab_size"]
         super().__init__(vocab, seed)
         c = config
-        if c.get("tie_word_embeddings"):
-            raise NotImplementedError("a head tied to the embedding")
         if c.get("attention_bias"):
             raise NotImplementedError("biases on the attention projections")
         if c.get("rope_scaling"):
@@ -150,7 +148,8 @@ class JoyAIFlash(ZooModel):
             x = self._block(g, f"l{i}", x, self._routed(i))
         g.add_layer("final_norm", RMSNorm(eps=eps), x)
         head = dict(n_out=self.num_classes, time_block=self.loss_block,
-                    weight_init="xavier_fan_in")
+                    weight_init="xavier_fan_in",
+                    tied_to="embed" if c.get("tie_word_embeddings") else "")
         if c["num_nextn_predict_layers"]:
             # the next token's embedding from the ONE table; the module's
             # block is of the routed kind whatever the depth kept

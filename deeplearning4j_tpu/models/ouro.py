@@ -13,8 +13,8 @@ Not in the reference zoo. A decoder whose whole stack of layers is run
 (sandwich norms: four ``RMSNorm`` a layer with a plain weight; ``Attn`` is
 ``RotaryAttention`` over the whole head width, ``MLP`` a SwiGLU
 ``GatedFeedForward``; the final norm closes every pass and the next pass
-starts from its output). Every pass is read by the untied head and by an
-exit gate, ``lambda_r = sigmoid(x_r w_g + b_g)``, and the training loss is
+starts from its output). Every pass is read by the head (tied to the
+embedding where ``tie_word_embeddings``) and by an exit gate, ``lambda_r = sigmoid(x_r w_g + b_g)``, and the training loss is
 the expectation of the passes' cross-entropies under the gates' exit
 distribution less ``entropy_weight`` times its entropy
 (``ExitWeightedTokenOutputLayer``). Input: (batch, time) integer ids;
@@ -62,8 +62,6 @@ class Ouro(ZooModel):
         if config.get("rope_scaling") or config.get("use_sliding_window"):
             raise NotImplementedError(
                 "scaled rotation and a sliding window are not built")
-        if config.get("tie_word_embeddings"):
-            raise NotImplementedError("a head tied to the embedding")
         self.config = config
         self.layers = layers or config["num_hidden_layers"]
         self.sequence_length = sequence_length
@@ -124,7 +122,8 @@ class Ouro(ZooModel):
         g.add_layer("head", ExitWeightedTokenOutputLayer(
             n_out=self.num_classes, time_block=self.loss_block,
             entropy_weight=self.entropy_weight,
-            weight_init="xavier_fan_in"), "loop")
+            weight_init="xavier_fan_in",
+            tied_to="embed" if c.get("tie_word_embeddings") else ""), "loop")
         g.set_outputs("head")
         g.set_input_types(InputType.recurrent(self.num_classes,
                                               self.sequence_length))

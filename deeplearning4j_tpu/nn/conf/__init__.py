@@ -14,5 +14,6 @@ from deeplearning4j_tpu.nn.conf import pretrain as _pretrain  # noqa: F401,E402
 from deeplearning4j_tpu.nn.conf import variational as _vae  # noqa: F401,E402
 from deeplearning4j_tpu.nn.conf import regularization as _reg  # noqa: F401,E402
 from deeplearning4j_tpu.nn.conf import attention as _attn  # noqa: F401,E402
+from deeplearning4j_tpu.nn.conf import short_conv as _sconv  # noqa: F401,E402
 from deeplearning4j_tpu.nn.conf import linear_attention as _linattn  # noqa: F401,E402
 from deeplearning4j_tpu.nn.conf import experts as _experts  # noqa: F401,E402

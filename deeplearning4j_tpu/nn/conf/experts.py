@@ -357,7 +357,9 @@ class RoutedExperts(BaseLayer):
     ``expert_offset``. ``shared_size`` 0 leaves the shared expert out (the
     other shares of a test that counts it once). ``router_activation`` is
     ``"sigmoid"`` or ``"softmax"``; ``shared_gate`` multiplies the shared
-    expert by ``sigmoid(w_sg . x)`` (the leaf ``Wsg``)."""
+    expert by ``sigmoid(w_sg . x)`` (the leaf ``Wsg``); ``renorm_eps`` is
+    added to the chosen scores' sum before they are divided by it (0: the
+    plain sum)."""
 
     n_in: Optional[int] = None
     n_out: int = 0
@@ -371,6 +373,7 @@ class RoutedExperts(BaseLayer):
     router_activation: str = "sigmoid"
     shared_gate: bool = False
     weight_init: str = "xavier_fan_in"
+    renorm_eps: float = 0.0
 
     def regularizable(self):
         return ("Wr", "Wgate", "Wup", "Wdown", "Sgate", "Sup", "Sdown",
@@ -427,8 +430,11 @@ class RoutedExperts(BaseLayer):
              else jax.nn.sigmoid(logits))
         _, idx = lax.top_k(s + bias, self.top_k)
         chosen = jnp.take_along_axis(s, idx, -1)
-        return (self.scaling * chosen
-                / jnp.sum(chosen, -1, keepdims=True)), idx
+        scaled = self.scaling * chosen
+        total = jnp.sum(chosen, -1, keepdims=True)
+        if self.renorm_eps:
+            total = total + self.renorm_eps
+        return scaled / total, idx
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
         x = dropout_input(x, self.dropout, train, rng)

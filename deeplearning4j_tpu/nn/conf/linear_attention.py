@@ -45,7 +45,7 @@ and KDA's decay, between the projections' products and the scan) has two
 executions in the same way (``take("kda_inputs", ...)``,
 ``kernel.pallas_kda_inputs`` / ``kernel.xla_kda_inputs``, once a call of a
 layer): the ``jax.numpy`` lines of ``KimiDeltaAttention.apply`` and
-``GatedDeltaNet.apply`` (``causal_depthwise_conv``, ``_l2norm``) anywhere,
+``GatedDeltaNet.apply`` (``short_conv.causal_depthwise_conv``, ``_l2norm``) anywhere,
 every CPU run included, and the tests' reference; on a TPU, for heads of
 128 or 256, at most 4 taps and bfloat16 or float32, one kernel forward and
 one backward of ``perf/pallas/kda_inputs.py`` that read the products'
@@ -91,6 +91,7 @@ from deeplearning4j_tpu.nn.conf.layers import (
     BaseLayer, dropout_input, register_layer,
 )
 from deeplearning4j_tpu.nn.conf.normalization import rms_norm
+from deeplearning4j_tpu.nn.conf.short_conv import causal_depthwise_conv
 from deeplearning4j_tpu.nn.initializers import init_weights
 from deeplearning4j_tpu.perf import pallas as pk
 from deeplearning4j_tpu.perf.pallas import kda as kda_kernels
@@ -118,17 +119,6 @@ def _kept_bytes(it: InputType, heads: int, head_dim: int, chunk: int,
     if scan:
         time += (-time) % kda_kernels.CHUNK
     return scan + time * columns * jnp.dtype(dtype).itemsize
-
-
-def causal_depthwise_conv(x, w):
-    """``x`` (batch, time, channels), ``w`` (taps, channels):
-    y_t = sum_j w[j] x_{t - (taps - 1) + j}, zeros before the start."""
-    taps, t = w.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-    out = padded[:, 0:t] * w[0]
-    for j in range(1, taps):
-        out = out + padded[:, j:j + t] * w[j]
-    return out
 
 
 def _l2norm(x, eps: float = 1e-6):

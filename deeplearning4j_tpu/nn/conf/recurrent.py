@@ -320,13 +320,40 @@ class TokenOutputLayer(RnnOutputLayer):
     which the backward pass scales. ``score`` runs one product a block.
     The loss has no forward-mode rule (``jax.jvp`` / ``jacfwd`` /
     ``hessian`` of it raise). ``apply`` / ``output`` still return the whole
-    softmax."""
+    softmax.
+
+    A head TIED to the embedding names, in ``tied_to``, the vertex whose
+    matrix ``W`` (n_out, n_in), an ``EmbeddingSequenceLayer``'s table, it
+    reads transposed. It owns no ``W`` then (``init`` draws none), and the
+    graph hands it that vertex's leaf (``nn/graph.py::run_vertices`` through
+    ``tied_params``): ONE leaf that the embedding gathers from and the
+    blocked loss multiplies by, its gradient the sum of both uses, one
+    optimizer state, one cast to the compute type. Counted at trace time:
+    ``head.tied``. Empty (the default), the layer is what it was. A
+    ``ComputationGraph``'s field: a stack has no vertex to name."""
 
     has_bias: bool = False
     loss: str = "sparse_mcxent"
     time_block: int = 1024
+    tied_to: str = ""          # the vertex whose W this head reads; "": its own
 
     sparse_labels = True       # labels are ids: (batch, time)
+
+    def init(self, rng, it: InputType, dtype=jnp.float32):
+        if not self.tied_to:
+            return super().init(rng, it, dtype)
+        return ({"b": jnp.full((self.n_out,), self.bias_init, dtype)}
+                if self.has_bias else {}), {}
+
+    def tied_params(self, params, other):
+        """This layer's parameters with the matrix of the vertex it is tied
+        to in ``W``'s place: ``other["W"]`` (n_out, n_in) transposed, inside
+        the step (XLA folds it into the loss's products; no transposed copy
+        outlives a step)."""
+        from deeplearning4j_tpu.perf.compile_watch import bump_active
+
+        bump_active("head.tied")
+        return {**params, "W": other["W"].T}
 
     def pre_output(self, params, x):
         # the score needs the features and the weights, not the logits

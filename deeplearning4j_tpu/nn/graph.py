@@ -39,8 +39,10 @@ def run_vertices(order, vertices, vpre, input_types, params, state, acts,
                  mask_of, train: bool, rng, carries=None):
     """Walk wired vertices in ``order``: ``acts`` and ``mask_of`` hold the
     inputs' activations and masks on entry and every vertex's on return.
-    ``params`` / ``state`` hold an entry for every vertex. Returns (output
-    layers' preouts, new state, new carries). The whole graph's forward pass
+    ``params`` / ``state`` hold an entry for every vertex; a layer whose
+    ``tied_to`` names another vertex is handed that vertex's parameters
+    beside its own (``tied_params``). Returns (output layers' preouts, new
+    state, new carries). The whole graph's forward pass
     and a ``LoopVertex``'s body both run here, so a layer is applied in one
     place (``apply_layer``: its ``<LayerClass>:<name>`` scope, its ``remat``
     knob)."""
@@ -63,6 +65,9 @@ def run_vertices(order, vertices, vpre, input_types, params, state, acts,
                     xs = list(xs)
                     xs[0], in_mask = vpre[name].apply(xs[0], in_mask)
                 p_v = noisy_params(obj, params[name], k, train)
+                if getattr(obj, "tied_to", ""):
+                    # a head tied to the embedding reads that vertex's leaf
+                    p_v = obj.tied_params(p_v, params[obj.tied_to])
             if obj.is_output_layer():
                 with jax.named_scope(marker):
                     x_in = dropout_input(xs[0], obj.dropout, train, k)

@@ -314,6 +314,42 @@ def _grouped_experts_16(S):
                      S((16, 896, 2304), BF16), S((16,), I32))
 
 
+def _blocked_attention_64_wide(S):
+    # the one attention layer of the LFM2 share, through the layer: 32 query
+    # heads over 8 key/value heads of 64 (the kernels' first 64-wide shape:
+    # q, k, dq pad to the lanes' 128 in VMEM), k and v repeated over their
+    # group of 4 in front of the kernels, per-head q/k norms, a plain
+    # rotation at 1e6, one sequence of 16,384 steps in bfloat16
+    from deeplearning4j_tpu.nn.conf.attention import RotaryAttention
+    from deeplearning4j_tpu.nn.conf.inputs import InputType
+    from deeplearning4j_tpu.perf.pallas import attention
+    layer = RotaryAttention(n_heads=32, n_kv_heads=8, head_dim=64,
+                            rope_theta=1e6, qk_norm=True, eps=1e-5)
+    q = S((1, 32, 16384, 64), BF16)
+    assert pk.take("blocked_attention", attention.supported(q, q, q, 512,
+                                                            None))
+    shapes = jax.eval_shape(lambda k: layer.init(
+        k, InputType.recurrent(2048, 16384), BF16)[0], jax.random.key(0))
+    params = {k: S(a.shape, BF16) for k, a in shapes.items()}
+
+    def fwd_bwd(params, x):
+        return jax.grad(lambda p, x: jnp.sum(layer.apply(
+            p, {}, x)[0].astype(F32)), argnums=(0, 1))(params, x)
+
+    return (fwd_bwd, (params, S((1, 16384, 2048), BF16)),
+            ("mla_attend_fwd", "mla_attend_bwd"))
+
+
+def _grouped_experts_1792(S):
+    # the routed layer's grouped products and their backward pass at the
+    # LFM2 share's widths: 16,384 tokens x top-4 of 32, a quarter held here:
+    # a window of 32,768 sorted slots of which some 16,384 are rows of the 8
+    # experts of 2048 x 1792 (2,048 rows an expert; 1792 = 14 x 128)
+    fwd_bwd = _grouped_experts_16(S)[0]
+    return fwd_bwd, (S((32768, 2048), BF16), S((8, 2048, 1792), BF16),
+                     S((8, 1792, 2048), BF16), S((8,), I32))
+
+
 def _inputs_case(S, dtype, gated_delta_net):
     # the delta-rule layers' input path at the two token cells' shapes, one
     # sequence of 8192 steps: KDA's four streams of 32 heads of 128 (q, k,
@@ -390,6 +426,8 @@ AUTO_CASES = [(_pq, "adc_pq"), (_int4_table, "int4_dot"),
               (_blocked_attention_16k, None),
               (_blocked_attention_band_float32, None),
               (_grouped_experts_16, None),
+              (_blocked_attention_64_wide, None),
+              (_grouped_experts_1792, None),
               (_kda_inputs, "kda_inputs"), (_kda_inputs_float32, None),
               (_gdn_inputs, None), (_gdn_inputs_float32, None)]
 EXPLICIT_CASES = [_bn_fwd, _bn_bwd]

@@ -548,13 +548,25 @@ def _joyai_config(**over):
     {"n_group": 8}, {"topk_group": 4}, {"scoring_func": "softmax"},
     {"topk_method": "greedy"}, {"norm_topk_prob": False},
     {"rope_interleave": False}, {"rope_scaling": {"type": "yarn"}},
-    {"tie_word_embeddings": True}, {"attention_bias": True},
+    {"attention_bias": True},
     {"num_nextn_predict_layers": 2}], ids=lambda o: next(iter(o)))
 def test_the_joyai_builder_refuses_what_it_does_not_build(over):
     from deeplearning4j_tpu.models import JoyAIFlash
 
     with pytest.raises(NotImplementedError):
         JoyAIFlash(_joyai_config(**over))
+
+
+def test_the_joyai_builder_ties_the_head_where_the_config_says_so():
+    """Since PR 43 a field, not a raise: the ONE table is then the shifted
+    embedding's, the gather's and both states' head (see
+    ``tests/test_zz_short_conv_tied_head.py``)."""
+    from deeplearning4j_tpu.models import JoyAIFlash
+
+    conf = JoyAIFlash(_joyai_config(tie_word_embeddings=True)).conf()
+    assert conf.vertices["head"][0].tied_to == "embed"
+    assert JoyAIFlash(_joyai_config()).conf().vertices["head"][0].tied_to \
+        == ""
 
 
 @pytest.fixture(scope="module")
