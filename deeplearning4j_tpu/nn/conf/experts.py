@@ -134,10 +134,10 @@ class GatedFeedForward(BaseLayer):
 
 # ----------------------------------------------------------- grouped products
 def _fit(x: int, most: int) -> int:
-    for t in (most, 1024, 768, 512, 384, 256, 128):
-        if t <= most and x % t == 0:
-            return t
-    return 128
+    """The largest multiple of 128 that divides ``x`` and is at most
+    ``most``; 128 where none does."""
+    return max((t for t in range(128, min(x, most) + 1, 128) if x % t == 0),
+               default=128)
 
 
 def _gmm_tiling(m: int, k: int, n: int):
@@ -149,16 +149,21 @@ def _gmm_tiling(m: int, k: int, n: int):
 
 def _gmm_tiling_dense(m: int, k: int, n: int):
     """Tiles where a group has thousands of rows, so that the MXU and not
-    the weights' bytes bounds the product: 512 rows, and a width whole
-    where it fits (896 = 7 x 128 has no divisor between 128 and itself, and
-    tiles of 128 x 1152 x 128 ran a product of 33,000 rows at a fifth of
-    the MXU's pace: PERF.md section 6, PR 37). The caps are what a v5e's
-    16 MB of VMEM took at 512 rows, timed at Mellum2's widths alone: the
-    backward ``tgmm`` holds a float32 ``tk x tn`` accumulator beside its
-    doubled output, 12.4 MB at 1152 x 896, and ran out at 2304 x 896
-    (ROADMAP Queue 1 item 14c: one rule from a stated budget)."""
+    the weights' bytes bounds the product: 512 rows, and a width whole up to
+    its cap, else its largest 128-multiple divisor under the cap (1792 =
+    14 x 128 runs in tiles of 896; 896 = 7 x 128 has no divisor between 128
+    and itself, and tiles of 128 x 1152 x 128 ran a product of 33,000 rows
+    at a fifth of the MXU's pace: PERF.md section 6, PRs 37 and 44). The
+    caps, 1152 of the contraction and 896 of the output, are what a v5e's
+    16 MB of VMEM takes at 512 rows of bfloat16. megablox hands ONE rule to
+    all three kernels of a product, and the backward ``tgmm`` holds the
+    most: a float32 ``tk x tn`` accumulator beside its doubled output and
+    the two doubled operand tiles, 8 tk tn + 2048 (tk + tn) bytes = 12.4 MB
+    at the caps; the compiler refuses 2304 x 896 (23.1 MB) and 2048 x 896
+    (20.7 MB) (ROADMAP Queue 1 item 3(e): one rule for both sides from
+    the budget)."""
     return (GMM_DENSE_ROW_TILE, k if k <= 1152 else _fit(k, 1152),
-            n if n <= 896 else _fit(n, 768))
+            n if n <= 896 else _fit(n, 896))
 
 
 def grouped_matmul(rows, weights, group_sizes):

@@ -344,7 +344,12 @@ def _grouped_experts_1792(S):
     # the routed layer's grouped products and their backward pass at the
     # LFM2 share's widths: 16,384 tokens x top-4 of 32, a quarter held here:
     # a window of 32,768 sorted slots of which some 16,384 are rows of the 8
-    # experts of 2048 x 1792 (2,048 rows an expert; 1792 = 14 x 128)
+    # experts of 2048 x 1792 (2,048 rows an expert; 1792 = 14 x 128). The
+    # tiles it holds (``_gmm_tiling_dense``): (512, 1024, 896) for gate / up,
+    # their ``tgmm`` (11.3 MB of the 16 MB of VMEM) and down's dX, (512, 896,
+    # 512) for down, its ``tgmm`` and gate / up's dX. A cap that took the
+    # contraction of 2048 whole is refused HERE: the ``tgmm`` at 2048 x 896
+    # asks for 20.7 MB (PERF.md section 6, PR 44)
     fwd_bwd = _grouped_experts_16(S)[0]
     return fwd_bwd, (S((32768, 2048), BF16), S((8, 2048, 1792), BF16),
                      S((8, 1792, 2048), BF16), S((8,), I32))

@@ -899,8 +899,13 @@ def test_the_window_is_four_shares_and_two_from_a_quarter_on(slots, held,
     (65536, 16, 896, 2304, (512, 896, 768)),
     (65536, 8, 2304, 1024, (512, 1152, 512)),
     (65536 + 128, 16, 2304, 896, (128, 1152, 128)),   # no multiple of 512
+    (32768, 8, 2048, 1792, (512, 1024, 896)),     # LFM2: 1792 = 2 x 896
+    (32768, 8, 1792, 2048, (512, 896, 512)),
+    (8192, 8, 2048, 768, (128, 1024, 384)),       # JoyAI: gate / up
+    (8192, 8, 768, 2048, (128, 768, 512)),        # JoyAI: down
 ], ids=["kimi_up", "kimi_down", "qwen_up", "qwen_down", "dense_up",
-        "dense_down", "dense_1024", "ragged_rows"])
+        "dense_down", "dense_1024", "ragged_rows", "lfm2_up", "lfm2_down",
+        "joyai_up", "joyai_down"])
 def test_the_grouped_products_tiles_follow_the_rows_a_group(
         monkeypatch, m, groups, k, n, tiles):
     """Which tiles ``grouped_matmul`` hands the megablox kernel: the
@@ -921,6 +926,20 @@ def test_the_grouped_products_tiles_follow_the_rows_a_group(
                    jax.ShapeDtypeStruct((groups, k, n), jnp.bfloat16),
                    jax.ShapeDtypeStruct((groups,), jnp.int32))
     assert seen["tiles"] == tiles
+
+
+@pytest.mark.parametrize("most", [512, 896, 1152])
+def test_a_tile_is_the_largest_128_multiple_that_divides_the_width(most):
+    """``_fit`` over every width 128..4608 in steps of 128: a multiple of
+    128 that divides the width and is at most the cap, and no larger such
+    number exists (1792 under 1152 is 896, not the 256 a list of literal
+    tiles gave)."""
+    from deeplearning4j_tpu.nn.conf.experts import _fit
+    for x in range(128, 4608 + 1, 128):
+        t = _fit(x, most)
+        assert t % 128 == 0 and x % t == 0 and t <= most
+        assert not [u for u in range(t + 128, most + 1, 128) if x % u == 0]
+    assert _fit(1792, 1152) == 896 and _fit(100, 1152) == 128
 
 
 @pytest.mark.parametrize("push", [0.0, 10.0], ids=["first_tier",
