@@ -29,6 +29,22 @@ Param layout: nested ``{"q": {"W", "b"}, "k": ..., "v": ..., "o": ...}``
 machinery — l1_bias/l2_bias regularization, bias constraints, weight noise
 ``apply_to_bias`` — discovers the biases through the standard
 ``<prefix>/b`` sibling rule (layers.py ``_bias_keys``).
+
+What a rematerialised layer (``remat=``) holds from its first pass to its
+backward pass: ``blocked_causal_attention``'s backward rule has five
+residuals, and both executions name two of them, the output and the
+log-sum-exp (``kernels.KEPT``). ``MultiHeadLatentAttention`` declares those
+(PR 40) and names and declares the other three itself, q, k and v as it
+hands them over (``OPERANDS_KEPT``, PR 45), so its backward pass runs
+neither the tile pairs forward nor ``W_qb`` / ``W_q``, ``W_kvb``, the
+rotation, the concatenations and the transposes a second time; it still
+makes ``W_qa x``, ``q_norm``, ``W_kva x`` and ``kv_norm`` again, which the
+up-projections' weight gradients and the norms' backward passes read, and
+pays 1,284 bytes a token and head of 192 / 128 in bfloat16.
+``RotaryAttention`` and ``GatedAttention`` declare nothing: no policy of
+theirs knows a name, the ``name`` equations lower to their operands and
+their programs are what they were. ``remat="nothing_saveable"`` keeps
+nothing for any type.
 """
 
 from __future__ import annotations
@@ -52,6 +68,15 @@ from deeplearning4j_tpu.nn.conf.layers import (
 from deeplearning4j_tpu.nn.initializers import init_weights
 from deeplearning4j_tpu.perf import pallas as pk
 from deeplearning4j_tpu.perf.pallas import attention as kernels
+
+
+# q, k and v as ``MultiHeadLatentAttention`` hands them to
+# ``blocked_causal_attention``, by their ``checkpoint_name``: heads-major
+# (batch, heads, time, width) in the compute type, q rotated, the shared
+# rotated key spread over k's heads; the layer names them, not the function
+# it shares with ``RotaryAttention`` and ``GatedAttention``
+OPERANDS_KEPT = ("latent_attention.q", "latent_attention.k",
+                 "latent_attention.v")
 
 
 def _heads(x, h):
@@ -495,15 +520,24 @@ class MultiHeadLatentAttention(BaseLayer):
     A features mask zeroes the output at masked steps (right-padded batches
     are exact).
 
-    Rematerialised (``remat=``) the layer KEEPS the attention's output and
-    log-sum-exp (``remat_keeps`` = ``kernels.KEPT``, the names both
-    executions give them): the backward pass makes the projections, the
-    rotation and q, k, v again and reads the two, so the tile pairs run
-    forward once a layer and step (``mla_attend_fwd`` once, where
-    recomputing ran it twice). It costs ``remat_kept_bytes``: O in the type
-    the layer computes in and a float32 a token and head, 68 MB a layer at
-    8192 tokens and 32 heads of 128 in bfloat16.
-    ``remat="nothing_saveable"`` keeps nothing. (PERF.md §5-6, PR 40.)"""
+    Rematerialised (``remat=``) the layer KEEPS all five residuals of the
+    attention's backward rule (``remat_keeps``): its output and log-sum-exp
+    (``kernels.KEPT``, the names both executions give them; PR 40) and q, k
+    and v as the layer hands them over (``OPERANDS_KEPT``, named here;
+    PR 45). The backward pass reads the five, so the tile pairs run forward
+    once a layer and step (``mla_attend_fwd`` once) and so do ``W_qb`` (or
+    ``W_q``), ``W_kvb``, the rotation, the two concatenations and the three
+    transposes. What it still makes again is what their gradients read:
+    ``W_qa x`` and ``q_norm`` (``W_qb``'s weight gradient and the norm's
+    backward pass), ``W_kva x`` and ``kv_norm`` likewise. It costs
+    ``remat_kept_bytes``: O, q, k and v in the type the layer computes in
+    and a float32 a token and head: 260 + 2 x (2 x (``nope_dim`` +
+    ``rope_dim``) + ``v_dim``) = 1,284 bytes a token and head at 192 / 128
+    in bfloat16, 68 + 268 MB a layer at 8192 tokens and 32 heads (a TPU
+    holds a 192-wide minor axis in 256 lanes: 335 MB of q, k, v there; the
+    JoyAI step's seven layers read 2.27 GB more at its peak for 25 ms of
+    353). ``remat="nothing_saveable"`` keeps nothing and gives it back.
+    (PERF.md §5-6, PRs 40 and 45.)"""
 
     n_in: Optional[int] = None
     n_out: int = 0              # model width; inferred from the input when 0
@@ -519,11 +553,17 @@ class MultiHeadLatentAttention(BaseLayer):
     rope_theta: float = 0.0     # 0: q and k_r are not rotated
 
     supports_stateful = False
-    remat_keeps = kernels.KEPT
+    remat_keeps = kernels.KEPT + OPERANDS_KEPT
 
     def remat_kept_bytes(self, it: InputType, dtype=jnp.float32) -> int:
-        return kernels.kept_bytes(it.timeseries_length or 1, self.n_heads,
-                                  self.v_dim, self.block, dtype)
+        """O and the log-sum-exp at the length the attention pads to, and
+        q, k, v at the length the layer is given (the padding is made
+        again)."""
+        time = it.timeseries_length or 1
+        operands = 2 * (self.nope_dim + self.rope_dim) + self.v_dim
+        return kernels.kept_bytes(time, self.n_heads, self.v_dim, self.block,
+                                  dtype) \
+            + time * self.n_heads * operands * jnp.dtype(dtype).itemsize
 
     def input_kind(self):
         return "rnn"
@@ -604,9 +644,11 @@ class MultiHeadLatentAttention(BaseLayer):
         bump_active("attention.mla_blocked" if t > self.block
                     else "attention.mla_single_tile")
         with jax.named_scope("mla.attend"):
-            o = blocked_causal_attention(
-                q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                v.transpose(0, 2, 1, 3), self.block)
+            # named as the attention reads them: its backward rule's
+            # other three residuals
+            q, k, v = (checkpoint_name(a.transpose(0, 2, 1, 3), name)
+                       for a, name in zip((q, k, v), OPERANDS_KEPT))
+            o = blocked_causal_attention(q, k, v, self.block)
         out = o.transpose(0, 2, 1, 3).reshape(bsz, t, h * self.v_dim) \
             @ params["Wo"]
         if mask is not None:             # masked steps emit zeros

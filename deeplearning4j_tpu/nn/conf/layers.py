@@ -194,8 +194,8 @@ def apply_layer(layer, params, state, x, *, train, rng, mask, name,
     recompute than to keep (``remat_keeps``) is kept under every policy but
     ``"nothing_saveable"``: ``"full"`` recomputes everything else, and
     everything for a type that names nothing (``policy=None``, as ever).
-    Counted at trace time, once a layer application whose policy holds
-    names: ``remat.kept_values``. ``extra`` carries optional additional
+    Counted at trace time, one a name that a layer application's policy
+    holds: ``remat.kept_values``. ``extra`` carries optional additional
     traced inputs (the fused residual-add input in ComputationGraph).
     ``name`` is the layer's name in its network (a graph's vertex name, an
     MLN's index): every operation of the layer, forward and backward,
@@ -210,7 +210,7 @@ def apply_layer(layer, params, state, x, *, train, rng, mask, name,
             keeps = kept_names(layer)
             policy = remat_policy(layer.remat, keeps)
             if keeps:
-                bump_active("remat.kept_values")
+                bump_active("remat.kept_values", len(keeps))
 
             def run(p, s, xx, kk, mm, ee):
                 return layer.apply(p, s, xx, train=train, rng=kk, mask=mm,
@@ -267,7 +267,8 @@ class Layer:
     # keeps these alone, ``"nothing_saveable"`` nothing). Not a field: the
     # type declares it, and ``remat_kept_bytes`` says what it costs (the
     # delta-rule layers: their scan's results and their wide projections'
-    # outputs; the latent attention: its attention's output and log-sum-exp).
+    # outputs; the latent attention: its attention's output and log-sum-exp
+    # and q, k, v as it hands them to the attention).
     remat_keeps = ()
 
     def remat_kept_bytes(self, input_type: InputType,

@@ -42,7 +42,8 @@ class LayerMemoryReport:
     # what the rematerialised layer keeps all the same, for ONE example
     # (its type's ``remat_keeps``: the delta-rule scan's output and chunk
     # states where the kernels run and the delta-rule layers' wide
-    # projections' outputs, the latent attention's output and log-sum-exp);
+    # projections' outputs, the latent attention's output, log-sum-exp and
+    # q, k, v);
     # counted into the activation total
     remat_kept_bytes_per_example: int = 0
 

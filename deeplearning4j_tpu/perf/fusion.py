@@ -31,7 +31,8 @@ attacks exactly that traffic, three ways:
   (``Layer.remat_keeps``: ``jax.ad_checkpoint.checkpoint_name``s: the
   delta-rule scan's output and chunk states, perf/pallas/kda.py, and the
   delta-rule layers' wide projections' outputs, nn/conf/linear_attention.py;
-  the latent attention's output and log-sum-exp, perf/pallas/attention.py);
+  the latent attention's output and log-sum-exp, perf/pallas/attention.py,
+  and its q, k, v as the attention reads them, nn/conf/attention.py);
   a layer that names nothing recomputes everything, as before. The saving
   policies keep what they keep and the names too; ``"nothing_saveable"``
   keeps nothing, names included: the way back for a run short of memory.

@@ -69,8 +69,18 @@ A layer type that declares the names (``remat_keeps``:
 ``MultiHeadLatentAttention``) runs ``mla_attend_fwd`` once a layer and step,
 the backward pass reading what the first pass wrote, for ``kept_bytes`` a
 layer (260 bytes a token and head of 128 in bfloat16: 68 MB at 8192 tokens
-and 32 heads); q, k, v are residuals too, are not named and are made again
-(the projections and their transposes). A type that declares nothing
+and 32 heads). q, k, v are the rule's other three residuals: the kernels'
+wrapper does not name them (``RotaryAttention`` and ``GatedAttention`` share
+it), the latent layer does, where it makes them
+(``nn/conf/attention.py::OPERANDS_KEPT``, PR 45), and declares those names
+too, so its backward pass reads the operands the first pass handed to
+``mla_attend_fwd`` and makes none of ``W_qb`` / ``W_q``, ``W_kvb``, the
+rotation, the concatenations or the transposes again; ``W_qa x``, ``q_norm``,
+``W_kva x`` and ``kv_norm`` it still makes again (the up-projections' weight
+gradients and the norms' backward passes read them). That is 1,024 bytes
+more a token and head of 192 / 128 in bfloat16, 1,284 in all: 268 MB a
+layer at 8192 tokens and 32 heads as values, 335 MB on the chip, which
+holds q's and k's 192 lanes as 256. A type that declares nothing
 (``RotaryAttention``, ``GatedAttention``) holds no policy that knows the
 names: the ``name`` equations lower to their operands and its compiled
 program is what it was, two forward kernels a layer (its lowered text too,

@@ -3,8 +3,9 @@ layer's ``jax.checkpoint`` policy by ``apply_layer``): the delta-rule
 layers keep their scan's output and chunk states, so ``kda_scan_fwd`` runs
 once a layer and step, and their wide projections' outputs, so those
 products do; the latent attention keeps its attention's output and
-log-sum-exp, so ``mla_attend_fwd`` does; every other layer type names
-nothing and gets the policy it always got. CPU, the kernels forced and
+log-sum-exp, so ``mla_attend_fwd`` does, and q, k and v as the attention
+reads them, so its up-projections, its rotation and its transposes do;
+every other layer type names nothing and gets the policy it always got. CPU, the kernels forced and
 interpreted. Tail-sorted (``test_zz_``): interpret mode is slow."""
 
 import collections
@@ -20,7 +21,7 @@ import pytest
 from deeplearning4j_tpu.nn.conf import InputType, NeuralNetConfiguration
 from deeplearning4j_tpu.nn.conf import linear_attention as la
 from deeplearning4j_tpu.nn.conf.attention import (
-    GatedAttention, MultiHeadLatentAttention, RotaryAttention)
+    OPERANDS_KEPT, GatedAttention, MultiHeadLatentAttention, RotaryAttention)
 from deeplearning4j_tpu.nn.conf.experts import GatedFeedForward
 from deeplearning4j_tpu.nn.conf.layers import DenseLayer, apply_layer
 from deeplearning4j_tpu.nn.conf.normalization import RMSNorm
@@ -98,10 +99,14 @@ def _names(jaxpr):
             if eqn.primitive.name == "name"}
 
 
+def _lowered(f, params, x) -> str:
+    """The lowered text of ``f``, whatever ``f`` is called."""
+    text = jax.jit(f).lower(params, x).as_text()
+    return re.sub(r"^module @jit_\S+", "module @jit_f", text)
+
+
 def _lowered_gradient(f, params, x) -> str:
-    """The lowered text of ``jax.grad(f)``, whatever ``f`` is called."""
-    text = jax.jit(jax.grad(f)).lower(params, x).as_text()
-    return re.sub(r"^module @jit_\w+", "module @jit_f", text)
+    return _lowered(jax.grad(f), params, x)
 
 
 def _renumbered(text: str) -> str:
@@ -151,8 +156,8 @@ def _kept_counter():
 
 
 @pytest.mark.parametrize("remat,forwards,counted", [
-    ("full", 1, 1), ("dots_saveable", 1, 1), ("nothing_saveable", 2, 0),
-    (None, 1, 0)])
+    ("full", 1, True), ("dots_saveable", 1, True),
+    ("nothing_saveable", 2, False), (None, 1, False)])
 @pytest.mark.parametrize("kind", _DELTA_RULE + _LATENT)
 def test_a_rematerialised_layer_runs_its_kept_kernel_once(
         kind, remat, forwards, counted):
@@ -162,8 +167,9 @@ def test_a_rematerialised_layer_runs_its_kept_kernel_once(
     attention's q, k, v) and reads the kernel's kept results (ONE
     ``kda_scan_fwd`` / ``mla_attend_fwd``, where the parent ran two);
     ``"nothing_saveable"`` keeps nothing and runs it twice; without
-    ``remat`` nothing is recomputed. ``remat.kept_values`` counts a layer
-    application whose policy holds names."""
+    ``remat`` nothing is recomputed. ``remat.kept_values`` counts the names
+    a layer application's policy holds: three a delta-rule layer (the
+    projections' outputs share one), five a latent layer."""
     loss, params, x = _loss_of(kind, remat=remat)
     _, _, writes, reads = _SCOPES[kind]
     want = {writes: forwards, reads: 1}
@@ -173,25 +179,33 @@ def test_a_rematerialised_layer_runs_its_kept_kernel_once(
     with pk.override(enabled=True, interpret=True):
         found = _kernels(jax.make_jaxpr(jax.grad(loss))(params, x).jaxpr)
     assert found == want
-    assert _kept_counter() - before == counted
+    names = 3 if kind in _DELTA_RULE else 5
+    assert len(_LAYERS[kind].remat_keeps) == names
+    assert _kept_counter() - before == (names if counted else 0)
 
 
-@pytest.mark.parametrize("kind,execution", [
-    *[(kind, "kernels") for kind in _DELTA_RULE + _LATENT],
-    *[(kind, "jax_numpy") for kind in _DELTA_RULE]])
-def test_kept_results_change_no_bit_of_the_gradient(kind, execution):
+@pytest.mark.parametrize("kind,execution,other", [
+    *[(kind, "kernels", "nothing_saveable")
+      for kind in _DELTA_RULE + _LATENT],
+    *[(kind, "jax_numpy", "nothing_saveable") for kind in _DELTA_RULE],
+    # the latent layer's q, k, v are named by the layer, in both
+    # executions; and against the layer that rematerialises nothing
+    *[(kind, "jax_numpy", "nothing_saveable") for kind in _LATENT],
+    *[(kind, execution, None) for kind in _LATENT
+      for execution in ("kernels", "jax_numpy")]])
+def test_kept_results_change_no_bit_of_the_gradient(kind, execution, other):
     """What is kept is what would be recomputed, as it was written (the
-    scan's results in float32, the attention's o in ``v``'s type, the
-    delta-rule layers' projections in the compute type, in both
+    scan's results in float32, the attention's o, q, k, v in the compute
+    type, the delta-rule layers' projections in the compute type, in both
     executions): the gradients under ``"full"`` are those under
-    ``"nothing_saveable"`` bit for bit."""
+    ``"nothing_saveable"`` (and, for the latent layer, under no ``remat``
+    at all) bit for bit."""
     grads = {}
-    for remat in ("full", "nothing_saveable"):
+    for remat in ("full", other):
         loss, params, x = _loss_of(kind, remat=remat)
         with _execution(execution):
             grads[remat] = jax.grad(loss, argnums=(0, 1))(params, x)
-    kept, recomputed = (jax.tree.leaves(grads[r])
-                        for r in ("full", "nothing_saveable"))
+    kept, recomputed = (jax.tree.leaves(grads[r]) for r in ("full", other))
     assert len(kept) == len(recomputed) > 5
     for a, b in zip(kept, recomputed):
         assert float(jnp.max(jnp.abs(a))) > 0
@@ -229,8 +243,24 @@ _PROJECTIONS = {
     # the Kimi step for no shorter a step (PERF.md §6, PR 41)
     "kda": (("Wq", "Wk", "Wf1"), ("Wv", "Wf2", "Wb"), "kda.conv"),
     "gdn": (("Wqkvz",), ("Wba",), "gdn.conv"),
+    # the latent layer names q, k, v as the attention reads them: what goes
+    # from the backward pass is everything between the latents and the
+    # kernel; the down-projections feed the up-projections' weight
+    # gradients and the norms' backward passes and are made again
+    "mla": (("Wq", "Wkvb"), ("Wkva",), "mla.attend"),
+    "rmla": (("Wqb", "Wkvb"), ("Wqa", "Wkva"), "mla.attend"),
 }
 _EXECUTIONS = ["kernels", "jax_numpy"]
+
+
+def _rotations(jaxpr, width):
+    """The rotation's products in the forward direction: ``x @ S`` with S
+    the (width, width) signed permutation, contracted over its rows (the
+    backward pass's ``dy @ S^T`` contracts its columns)."""
+    return sum(1 for eqn in _equations(jaxpr)
+               if eqn.primitive.name == "dot_general"
+               and eqn.invars[1].aval.shape == (width, width)
+               and tuple(eqn.params["dimension_numbers"][0][1]) == (0,))
 
 
 def _forward_products(jaxpr, leaves):
@@ -266,7 +296,7 @@ def _forward_products(jaxpr, leaves):
 @pytest.mark.parametrize("remat,kept,made_again", [
     ("full", 1, 2), ("nothing_saveable", 2, 2), (None, 1, 1)])
 @pytest.mark.parametrize("execution", _EXECUTIONS)
-@pytest.mark.parametrize("kind", _DELTA_RULE)
+@pytest.mark.parametrize("kind", _DELTA_RULE + _LATENT)
 def test_a_rematerialised_layer_runs_its_wide_projections_once(
         kind, execution, remat, kept, made_again):
     """``jax.grad`` through ``apply_layer``: under ``"full"`` the products
@@ -277,7 +307,11 @@ def test_a_rematerialised_layer_runs_its_wide_projections_once(
     ``Wf2``'s, ``Wb``'s / ``Wba``'s) are made again, the input kernel is
     run again from the kept outputs and the scan's kernel is not;
     ``"nothing_saveable"`` makes everything twice; without ``remat``
-    nothing is made again."""
+    nothing is made again. The latent layer, with and without ``q_rank`` /
+    ``rope_theta``: ``W_qb`` (or ``W_q``), ``W_kvb`` and the rotation's two
+    products (every head's rotary widths, the one shared key) once, the
+    down-projections ``W_qa`` / ``W_kva`` again, the attention's forward
+    kernel once."""
     loss, params, x = _loss_of(kind, remat=remat)
     named, narrow, _ = _PROJECTIONS[kind]
     with _execution(execution):
@@ -286,13 +320,20 @@ def test_a_rematerialised_layer_runs_its_wide_projections_once(
     assert {w: found[w] for w in named + narrow} == {
         **dict.fromkeys(named, kept), **dict.fromkeys(narrow, made_again)}
     assert found["Wo"] == 1
-    if execution == "kernels":
-        kernels = _kernels(jaxpr)
-        assert kernels["kda_inputs_fwd"] == (2 if remat else 1)
-        assert kernels["kda_scan_fwd"] == (2 if remat == "nothing_saveable"
-                                           else 1)
-        assert kernels["kda_inputs_bwd"] == kernels["kda_scan_bwd"] == 1
-    assert la.PROJECTIONS_KEPT[0] in _names(jaxpr)
+    kernels = _kernels(jaxpr) if execution == "kernels" else None
+    if kind in _LATENT:
+        rotated = 2 if _LAYERS[kind].rope_theta else 0
+        assert _rotations(jaxpr, _LAYERS[kind].rope_dim) == rotated * kept
+        assert set(OPERANDS_KEPT) <= _names(jaxpr)
+        if kernels:
+            assert kernels == {"mla_attend_fwd": kept, "mla_attend_bwd": 1}
+    else:
+        assert la.PROJECTIONS_KEPT[0] in _names(jaxpr)
+        if kernels:
+            assert kernels["kda_inputs_fwd"] == (2 if remat else 1)
+            assert kernels["kda_scan_fwd"] == (
+                2 if remat == "nothing_saveable" else 1)
+            assert kernels["kda_inputs_bwd"] == kernels["kda_scan_bwd"] == 1
 
 
 @pytest.mark.parametrize("kind,shapes", [
@@ -313,83 +354,141 @@ def test_the_jax_numpy_execution_saves_the_named_projections(kind, shapes,
 
 
 @pytest.mark.parametrize("remat", [None, "nothing_saveable"])
-@pytest.mark.parametrize("kind", _DELTA_RULE)
+@pytest.mark.parametrize("kind", _DELTA_RULE + _LATENT)
 def test_a_projection_s_name_no_policy_holds_lowers_to_its_operand(
         kind, remat, monkeypatch):
-    """A delta-rule layer that is not rematerialised, or keeps nothing,
-    lowers to the parent's program: with ``checkpoint_name`` taken away
-    the lowered gradient is the same text but for the numbers MLIR gives
-    its private functions (``_renumbered``)."""
-    changes = dict(head_dim=8, chunk=16, remat=remat)
+    """A delta-rule or latent layer that is not rematerialised, or keeps
+    nothing, lowers to the parent's program: with ``checkpoint_name`` taken
+    away (the latent layer's three AND the forward rule's two) the lowered
+    gradient is the same text but for the numbers MLIR gives its private
+    functions (``_renumbered``)."""
+    from deeplearning4j_tpu.nn.conf import attention as attention_layers
+    if kind in _LATENT:
+        changes, module = dict(remat=remat), attention_layers
+        names = set(attention_kernels.KEPT + OPERANDS_KEPT)
+    else:
+        changes, module = dict(head_dim=8, chunk=16, remat=remat), la
+        names = set(la.PROJECTIONS_KEPT)
     if kind == "kda":
         changes["low_rank"] = 4
     loss, params, x = _loss_of(kind, **changes)
     with pk.override(enabled=False):
         assert _names(jax.make_jaxpr(jax.grad(loss))(params, x).jaxpr) \
-            == set(la.PROJECTIONS_KEPT)
+            == names
         named = _lowered_gradient(loss, params, x)
         assert "checkpoint_name" not in named
-        monkeypatch.setattr(la, "checkpoint_name", lambda value, name: value)
+        assert "latent_attention." not in named
+        monkeypatch.setattr(module, "checkpoint_name",
+                            lambda value, name: value)
         assert _names(jax.make_jaxpr(jax.grad(loss))(params, x).jaxpr) \
             == set()
         assert _renumbered(_lowered_gradient(loss, params, x)) \
             == _renumbered(named)
 
 
+@pytest.mark.parametrize("remat", [None, "full"])
+@pytest.mark.parametrize("kind", _LATENT)
+def test_a_latent_layer_outside_training_lowers_to_the_parent_s_text(
+        kind, remat, monkeypatch):
+    """``train=False`` and nothing differentiated (serving, ``output``,
+    ``score``): no policy is ever asked, the five ``name`` equations lower
+    to their operands and the program is the parent's, ``remat`` or not."""
+    from deeplearning4j_tpu.nn.conf import attention as attention_layers
+    layer = dataclasses.replace(_LAYERS[kind], remat=remat)
+    time = _TIME[kind]
+    params, state = layer.init(jax.random.key(0),
+                               InputType.recurrent(_WIDTH, time))
+    x = jax.random.normal(jax.random.key(1), (1, time, _WIDTH))
+
+    def forward():      # a new function a trace: jax caches by identity
+        return lambda p, xx: apply_layer(
+            layer, p, state, xx, train=False, rng=None, mask=None,
+            name="mix")[0]
+
+    with pk.override(enabled=False):
+        assert _names(jax.make_jaxpr(forward())(params, x).jaxpr) \
+            == set(OPERANDS_KEPT)       # the forward RULE is never traced
+        named = _lowered(forward(), params, x)
+        assert "latent_attention." not in named
+        monkeypatch.setattr(attention_layers, "checkpoint_name",
+                            lambda value, name: value)
+        assert _names(jax.make_jaxpr(forward())(params, x).jaxpr) == set()
+        assert _renumbered(_lowered(forward(), params, x)) \
+            == _renumbered(named)
+
+
 # ---------------------------------------------------------- latent attention
 @pytest.mark.parametrize("execution", ["jax_numpy", "kernels"])
 @pytest.mark.parametrize("kind", _LATENT)
-def test_both_executions_keep_the_same_two_names(kind, execution, capsys):
+def test_both_executions_keep_the_same_five_names(kind, execution, capsys):
     """One algorithm, two executions, one contract: whichever execution
-    ran, the gradient's jaxpr holds ``KEPT``'s two names and no other, and
-    under ``"full"`` the layer saves the attention's output (batch, heads,
-    time, d_v), the log-sum-exp and nothing else of its own; in the
-    ``jax.numpy`` execution ``jax.ad_checkpoint`` reports both residuals by
-    name (the kernels' are inside a jitted function, whose name it gives)."""
+    ran, the gradient's jaxpr holds ``KEPT``'s two names, the layer's three
+    (``OPERANDS_KEPT``) and no other, and under ``"full"`` the layer saves
+    the backward rule's five residuals: q, k (batch, heads, time, d_q), v
+    and the attention's output (batch, heads, time, d_v), the log-sum-exp,
+    and nothing else of its own; ``jax.ad_checkpoint`` reports q, k, v by
+    name in both executions and, in the ``jax.numpy`` execution, the other
+    two (the kernels' are inside a jitted function, whose name it
+    gives)."""
     o_name, lse_name = attention_kernels.KEPT
     kernels = execution == "kernels"
     time = _TIME[kind]
+    # q / k heads and v heads are both 64 wide here
     o = f"f32[1,2,{time},64]"
     lse = f"f32[1,2,1,{time}]" if kernels else f"f32[1,2,{time}]"
     with _execution(execution):
         loss, params, x = _loss_of(kind, remat="full")
         assert _names(jax.make_jaxpr(jax.grad(loss))(params, x).jaxpr) \
-            == {o_name, lse_name}
+            == {o_name, lse_name, *OPERANDS_KEPT}
         saved = [(what, whence) for what, whence in _saved_in_the_layer(
             loss, params, x, capsys) if __file__ not in whence]
-        assert sorted(what for what, _ in saved) == sorted([o, lse]), saved
+        assert sorted(what for what, _ in saved) == sorted([o] * 4 + [lse]), \
+            saved
         loss, params, x = _loss_of(kind)
-        named = {what: whence for what, whence in _saved_in_the_layer(
-            loss, params, x, capsys) if "named" in whence}
+        named = [(what, whence) for what, whence in _saved_in_the_layer(
+            loss, params, x, capsys) if "named" in whence]
+    for name in OPERANDS_KEPT + (() if kernels else (o_name,)):
+        assert (o, f"named '{name}'") in [
+            (what, whence[:whence.index("'", 7) + 1])
+            for what, whence in named], (name, named)
     if not kernels:
-        assert set(named) == {o, lse}
-        assert f"named '{o_name}'" in named[o]
-        assert f"named '{lse_name}'" in named[lse]
+        assert len(named) == 5
+        assert [what for what, whence in named
+                if f"named '{lse_name}'" in whence] == [lse]
 
 
 def test_latent_kept_bytes_at_the_cells_shape():
     """o (32, 8192, 128) in bfloat16 and the log-sum-exp (32, 8192) in
-    float32: 64 + 1 MB a layer, 260 bytes a token and head; the same in the
-    Kimi and the JoyAI cell (one kernel shape)."""
+    float32: 64 + 1 MB a layer, 260 bytes a token and head; q, k (32, 8192,
+    192) and v (32, 8192, 128) in bfloat16: 96 + 96 + 64 MB, 1,024 bytes a
+    token and head; the same in the Kimi and the JoyAI cell (one kernel
+    shape, whatever made q)."""
     it = InputType.recurrent(2048, 8192)
     shape = dict(n_heads=32, nope_dim=128, rope_dim=64, v_dim=128,
                  kv_rank=512, remat="full")
     kimi = MultiHeadLatentAttention(**shape)
     joyai = MultiHeadLatentAttention(q_rank=1536, rope_theta=32e6, **shape)
-    want = 8192 * 32 * 128 * 2 + 8192 * 32 * 4
-    assert want == 68_157_440
+    results = 8192 * 32 * 128 * 2 + 8192 * 32 * 4
+    operands = 8192 * 32 * (192 + 192 + 128) * 2
+    assert (results, operands) == (68_157_440, 268_435_456)
     assert attention_kernels.kept_bytes(8192, 32, 128, 512,
-                                        jnp.bfloat16) == want
+                                        jnp.bfloat16) == results
     assert kimi.remat_kept_bytes(it, jnp.bfloat16) \
-        == joyai.remat_kept_bytes(it, "bfloat16") == want
-    assert want // (8192 * 32) == 260
-    # float32 where the network computes in it; a length padded to whole
-    # tiles, a sequence under one tile as it is
-    assert kimi.remat_kept_bytes(it) == 8192 * 32 * (128 + 1) * 4
+        == joyai.remat_kept_bytes(it, "bfloat16") == results + operands \
+        == 336_592_896
+    assert (results + operands) // (8192 * 32) == 260 + 1024 == 1284
+    # float32 where the network computes in it; o and the log-sum-exp at a
+    # length padded to whole tiles, q, k, v at the length the layer is
+    # given (the padding is made again); a sequence under one tile as it is
+    assert kimi.remat_kept_bytes(it) == 8192 * 32 * (128 + 1 + 512) * 4
     assert attention_kernels.kept_bytes(1000, 2, 64, 512, jnp.float32) \
         == attention_kernels.kept_bytes(1024, 2, 64, 512, jnp.float32)
     assert attention_kernels.kept_bytes(100, 2, 64, 512, jnp.float32) \
         == 2 * 100 * (64 + 1) * 4
+    assert kimi.remat_kept_bytes(InputType.recurrent(2048, 1000)) \
+        == 32 * (1024 * (128 + 1) + 1000 * 512) * 4
+    assert kimi.remat_kept_bytes(InputType.recurrent(2048, 100)) \
+        == 32 * 100 * (128 + 1 + 512) * 4
 
 
 _NAMELESS = [
@@ -462,32 +561,37 @@ def test_a_nameless_type_under_full_lowers_to_the_parent_s_text(kind):
 
 
 @pytest.mark.parametrize("kind", ["RotaryAttention", "GatedAttention"])
-@pytest.mark.parametrize("remat", ["full", "dots_saveable", None])
+@pytest.mark.parametrize("remat", [*sorted(fusion.REMAT_POLICIES), None])
 def test_a_name_no_policy_holds_lowers_to_its_operand(kind, remat,
                                                       monkeypatch):
     """The other attention types' programs are the parent's: with the
     forward rule's ``checkpoint_name`` taken away the lowered gradient is
     the same text but for the numbers MLIR gives its private functions
-    (``_renumbered``), under the policies that recompute and without
-    ``remat``."""
+    (``_renumbered``), under every ``remat`` that recomputes and without
+    one (under ``"everything_saveable"`` a named value is saved through a
+    ``reduce_precision`` of its own, as on the parent: there the text is
+    held to the forward rule's two names alone); the latent layer's own
+    three names are in no trace of theirs."""
     from deeplearning4j_tpu.nn.conf import attention as attention_layers
     loss, params, x, _, _ = _plain_loss(kind, remat)
     assert _names(jax.make_jaxpr(jax.grad(loss))(params, x).jaxpr) \
         == set(attention_kernels.KEPT)
     named = _lowered_gradient(loss, params, x)
     assert "blocked_attention." not in named
+    assert "latent_attention." not in named
     monkeypatch.setattr(attention_layers, "checkpoint_name",
                         lambda value, name: value)
     assert _names(jax.make_jaxpr(jax.grad(loss))(params, x).jaxpr) == set()
-    assert _renumbered(_lowered_gradient(loss, params, x)) \
-        == _renumbered(named)
+    if remat != "everything_saveable":
+        assert _renumbered(_lowered_gradient(loss, params, x)) \
+            == _renumbered(named)
 
 
 @pytest.mark.parametrize("name", sorted(fusion.REMAT_POLICIES))
-@pytest.mark.parametrize("kind", ["kda", "mla"])
+@pytest.mark.parametrize("kind", ["kda", "mla", "rmla"])
 def test_names_join_every_saving_policy_and_not_nothing_saveable(kind, name):
-    kept = {"kda": kda_kernels.KEPT + la.PROJECTIONS_KEPT,
-            "mla": attention_kernels.KEPT}[kind]
+    kept = (kda_kernels.KEPT + la.PROJECTIONS_KEPT if kind == "kda"
+            else attention_kernels.KEPT + OPERANDS_KEPT)
     layer = dataclasses.replace(_LAYERS[kind], remat=name)
     keeps = fusion.kept_names(layer)
     assert keeps == (() if name == "nothing_saveable" else kept)
@@ -535,7 +639,7 @@ def test_kept_results_lie_under_the_layers_scope(kind, step_op_names):
     cls, scope, writes, reads = _SCOPES[kind]
     before = _kept_counter()
     names = _one_mixer_step(kind, "full", "kernels", step_op_names)
-    assert _kept_counter() - before == 1
+    assert _kept_counter() - before == len(_LAYERS[kind].remat_keeps)
     for kernel, way, other in ((writes, "jvp(", "transpose("),
                                (reads, "transpose(", None)):
         mine = [n for n in names if kernel in n]
@@ -662,9 +766,12 @@ def test_projections_kept_bytes_at_the_cells_shapes(cell, width, projections,
     # one product of 2 x (1 + 2) x 128 columns
     ("gdn", "bfloat16", 4 * 2 * 128 * (128 + 2 * 128) + 2 * 128 * 768,
      "keeps 576.0 KB/ex"),
-    # o in the network's type and a float32 a token and head
-    ("rmla", "bfloat16", 2 * 256 * (64 * 2 + 4), "keeps 66.0 KB/ex"),
-    ("rmla", "float32", 2 * 256 * (64 * 4 + 4), "keeps 130.0 KB/ex")])
+    # o, q, k, v (64 wide each) in the network's type and a float32 a token
+    # and head
+    ("rmla", "bfloat16", 2 * 256 * (4 * 64 * 2 + 4), "keeps 258.0 KB/ex"),
+    ("rmla", "float32", 2 * 256 * (4 * 64 * 4 + 4), "keeps 514.0 KB/ex"),
+    # the same whatever made q: the full-rank query, no rotation
+    ("mla", "bfloat16", 2 * 256 * (4 * 64 * 2 + 4), "keeps 258.0 KB/ex")])
 @pytest.mark.parametrize("remat,keeps", [
     ("full", True), ("dots_saveable", True), ("nothing_saveable", False),
     (None, False)])
@@ -692,10 +799,15 @@ def test_the_memory_report_counts_what_a_layer_keeps(remat, keeps, kind,
 # -------------------------------------------------------------- validation
 @pytest.mark.parametrize("remat,refused", [
     *[(name, False) for name in sorted(fusion.REMAT_POLICIES)],
-    ("keep_the_scan", True), ("save_only_these_names", True)])
+    ("keep_the_scan", True), ("save_only_these_names", True),
+    # what a type keeps is no value of the knob: a ``checkpoint_name`` is
+    # not a policy's name
+    *[(name, True) for name in OPERANDS_KEPT]])
 def test_validation_knows_five_names_and_no_new_one(remat, refused):
     from deeplearning4j_tpu.nn.conf.recurrent import RnnOutputLayer
     assert len(fusion.REMAT_POLICIES) == 5
+    assert not set(OPERANDS_KEPT + attention_kernels.KEPT) \
+        & set(fusion.REMAT_POLICIES)
     conf = (NeuralNetConfiguration.builder().seed(1).list()
             .layer(la.KimiDeltaAttention(n_heads=2, head_dim=8, low_rank=4,
                                          remat=remat))
