@@ -87,7 +87,8 @@ def _layer_name(i: Optional[int], layer) -> str:
 _N_OUT_OPTIONAL = ("TransformerEncoderBlock", "KimiDeltaAttention",
                    "GatedDeltaNet", "MultiHeadLatentAttention",
                    "GatedAttention", "RotaryAttention", "GatedFeedForward",
-                   "RoutedExperts", "MultiTokenCombine", "GatedShortConv")
+                   "RoutedExperts", "MultiTokenCombine", "GatedShortConv",
+                   "Mamba2Mixer")
 
 
 def _check_layer(layer, cur, name: str) -> List[ValidationIssue]:

@@ -16,4 +16,5 @@ from deeplearning4j_tpu.nn.conf import regularization as _reg  # noqa: F401,E402
 from deeplearning4j_tpu.nn.conf import attention as _attn  # noqa: F401,E402
 from deeplearning4j_tpu.nn.conf import short_conv as _sconv  # noqa: F401,E402
 from deeplearning4j_tpu.nn.conf import linear_attention as _linattn  # noqa: F401,E402
+from deeplearning4j_tpu.nn.conf import state_space as _ssm  # noqa: F401,E402
 from deeplearning4j_tpu.nn.conf import experts as _experts  # noqa: F401,E402

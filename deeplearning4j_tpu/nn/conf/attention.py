@@ -879,6 +879,11 @@ class RotaryAttention(BaseLayer):
         query t sees keys u <= t, and with ``window`` w > 0 of them those
         with u > t - w (w keys with its own)
         out = W_o softmax(q k^T / sqrt(d)) v,  no bias, no gate
+        with ``position_embedding`` ``"nope"``: no rotation and no other
+        position term (the causal mask alone orders the keys); with
+        ``softmax_scale`` s > 0: softmax(s q k^T), the scale folded into q
+        (q * (s sqrt(d)), exact in bfloat16 where that is a power of two)
+        in front of the same attention, which scales by 1 / sqrt(d)
 
     The scores go through ``blocked_causal_attention`` (with the window:
     the band of tile pairs); k and v are repeated over their group in
@@ -888,7 +893,8 @@ class RotaryAttention(BaseLayer):
     ``attention.rotary_blocked`` with more than one tile,
     ``attention.rotary_single_tile`` otherwise,
     ``attention.rotary_windowed`` once a layer whose window is shorter
-    than the sequence, and beside them ``kernel.pallas_blocked_attention``
+    than the sequence, ``attention.nope`` once a layer built without the
+    rotation, and beside them ``kernel.pallas_blocked_attention``
     / ``kernel.xla_blocked_attention``. A features mask zeroes the output
     at masked steps (right-padded batches are exact)."""
 
@@ -904,6 +910,8 @@ class RotaryAttention(BaseLayer):
     rope_scaling: Optional[dict] = None
     qk_norm: bool = False
     eps: float = 1e-6           # of the q/k norms
+    position_embedding: str = "rope"    # "nope": no position term at all
+    softmax_scale: float = 0.0          # 0: head_dim ** -0.5
 
     supports_stateful = False
 
@@ -949,6 +957,11 @@ class RotaryAttention(BaseLayer):
                              "rotation pairs its halves")
         if self.window < 0:
             raise ValueError(f"a window of {self.window} keys")
+        if self.position_embedding not in ("rope", "nope"):
+            raise ValueError(f"position_embedding "
+                             f"{self.position_embedding!r}: 'rope' or 'nope'")
+        if self.softmax_scale < 0:
+            raise ValueError(f"a softmax scale of {self.softmax_scale}")
         self._rotation()            # an unknown rope_type fails here
         return InputType.recurrent(self._width(it), it.timeseries_length)
 
@@ -988,12 +1001,18 @@ class RotaryAttention(BaseLayer):
             with jax.named_scope("rattn.qk_norm"):
                 q = rms_norm(q, params["q_norm"], self.eps)
                 k = rms_norm(k, params["k_norm"], self.eps)
-        with jax.named_scope("rattn.rope"):
-            positions = jnp.arange(t)
-            inv_freq, factor = self._rotation()
-            q, k = (rotate_half_split(a, positions, dh, self.rope_theta,
-                                      inv_freq, factor)
-                    for a in (q, k))
+        if self.position_embedding == "nope":
+            bump_active("attention.nope")
+        else:
+            with jax.named_scope("rattn.rope"):
+                positions = jnp.arange(t)
+                inv_freq, factor = self._rotation()
+                q, k = (rotate_half_split(a, positions, dh, self.rope_theta,
+                                          inv_freq, factor)
+                        for a in (q, k))
+        if self.softmax_scale:
+            # the attention below scales by 1 / sqrt(d): q carries the rest
+            q = q * jnp.asarray(self.softmax_scale * math.sqrt(dh), q.dtype)
         bump_active("attention.rotary_blocked" if t > self.block
                     else "attention.rotary_single_tile")
         window = self.window if 0 < self.window < t else None

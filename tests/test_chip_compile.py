@@ -340,6 +340,31 @@ def _blocked_attention_64_wide(S):
             ("mla_attend_fwd", "mla_attend_bwd"))
 
 
+def _blocked_attention_64_wide_nope(S):
+    # the one attention layer of the Granite 4.0-H stage, through the layer:
+    # 32 query heads over 8 key/value heads of 64 with NO rotation and the
+    # model's softmax scale (1/64) folded into q in front of the same
+    # kernels, one sequence of 8,192 steps in bfloat16
+    from deeplearning4j_tpu.nn.conf.attention import RotaryAttention
+    from deeplearning4j_tpu.nn.conf.inputs import InputType
+    from deeplearning4j_tpu.perf.pallas import attention
+    layer = RotaryAttention(n_heads=32, n_kv_heads=8, head_dim=64,
+                            position_embedding="nope", softmax_scale=1 / 64)
+    q = S((1, 32, 8192, 64), BF16)
+    assert pk.take("blocked_attention", attention.supported(q, q, q, 512,
+                                                            None))
+    shapes = jax.eval_shape(lambda k: layer.init(
+        k, InputType.recurrent(2048, 8192), BF16)[0], jax.random.key(0))
+    params = {k: S(a.shape, BF16) for k, a in shapes.items()}
+
+    def fwd_bwd(params, x):
+        return jax.grad(lambda p, x: jnp.sum(layer.apply(
+            p, {}, x)[0].astype(F32)), argnums=(0, 1))(params, x)
+
+    return (fwd_bwd, (params, S((1, 8192, 2048), BF16)),
+            ("mla_attend_fwd", "mla_attend_bwd"))
+
+
 def _grouped_experts_1792(S):
     # the routed layer's grouped products and their backward pass at the
     # LFM2 share's widths: 16,384 tokens x top-4 of 32, a quarter held here:
@@ -432,6 +457,7 @@ AUTO_CASES = [(_pq, "adc_pq"), (_int4_table, "int4_dot"),
               (_blocked_attention_band_float32, None),
               (_grouped_experts_16, None),
               (_blocked_attention_64_wide, None),
+              (_blocked_attention_64_wide_nope, None),
               (_grouped_experts_1792, None),
               (_kda_inputs, "kda_inputs"), (_kda_inputs_float32, None),
               (_gdn_inputs, None), (_gdn_inputs_float32, None)]
@@ -503,6 +529,34 @@ def test_the_gather_dispatch_reads_its_windows_from_fast_memory(v5e):
     # six in the Mellum2 cell's step, five of six in this one
     assert sum("S(1)" in b for b in blocks) >= 4, blocks
     assert "scatter" not in text
+
+
+def test_the_state_space_scan_compiles_for_the_chip(v5e, tpu_backend):
+    """``chunked_ssd`` at the Granite 4.0-H stage's shape (64 heads of 64
+    over one shared 128-wide B and C, 8,192 steps in chunks of 256,
+    bfloat16 operands), forward and every gradient: XLA's own program (no
+    kernel takes the scan), one ``while`` over the chunks whose body is made
+    again in the backward pass, so that what it holds at once is a chunk's
+    factors and the chunks' entry states, not every chunk's (64, 256, 256)
+    decays (0.5 GB in float32)."""
+    from deeplearning4j_tpu.nn.conf.state_space import chunked_ssd
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def fwd_bwd(x, dt, a, bm, cm):
+        return jax.grad(lambda *v: jnp.sum(chunked_ssd(*v, chunk=256)),
+                        argnums=(0, 1, 2, 3, 4))(x, dt, a, bm, cm)
+
+    compiled = jax.jit(fwd_bwd).lower(
+        S((1, 8192, 64, 64), BF16), S((1, 8192, 64), F32), S((64,), F32),
+        S((1, 8192, 1, 128), BF16), S((1, 8192, 1, 128), BF16)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert text.count(" while(") >= 2            # forward and backward
+    # y and the states in float32 (134 + 67 MB), the operands' cotangents,
+    # a chunk's factors; not a chunk-squared array a chunk
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
 
 
 def test_every_auto_family_has_a_case(tpu_backend):
