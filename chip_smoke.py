@@ -39,6 +39,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import math
 import sys
 import traceback
 import urllib.request
@@ -631,6 +632,27 @@ def phase_kernels(ctx: Ctx) -> dict:
         both_arms("kda_scan_scalar_decay", "kda_scan", scalar_decay_scan,
                   (q[:, :, :hk], k[:, :, :hk], v, g, b),
                   shape=[1, steps, hk, hv, 128])
+
+        # the Mamba-2 scan at the Granite cell's shape: 64 heads of 64 over
+        # one shared 128-wide B and C, 8192 steps in chunks of 256, steps
+        # log-uniform in [1e-3, 1e-1] and A = -1..-heads as the public
+        # initialiser draws them
+        from deeplearning4j_tpu.nn.conf.state_space import chunked_ssd
+        from deeplearning4j_tpu.perf.pallas import ssd
+        steps, heads, chunk = (8192, 64, 256) if chip else (256, 2, 128)
+        keys = jax.random.split(jax.random.key(ctx.seed + 4), 4)
+        x, bm, cm = (jax.random.normal(key, (1, steps) + tail).astype(cdt)
+                     for key, tail in zip(keys, ((heads, 64), (1, 128),
+                                                 (1, 128))))
+        sargs = (x, jnp.exp(jax.random.uniform(
+            keys[3], (1, steps, heads), minval=math.log(1e-3),
+            maxval=math.log(1e-1))),
+            -jnp.arange(1, heads + 1, dtype=jnp.float32), bm, cm)
+        check(ssd.supported(*sargs, chunk),
+              "ssd_scan does not take heads of 64 in chunks of 256")
+        both_arms("ssd_scan", "ssd_scan",
+                  lambda *a: chunked_ssd(*a, chunk=chunk), sargs,
+                  shape=[1, steps, heads, 64, 128], chunk=chunk)
 
         # latent attention's call: q/k heads of 192 and v heads of 128,
         # several tiles, a length that is padded

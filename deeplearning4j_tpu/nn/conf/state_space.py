@@ -32,11 +32,16 @@ quotient of two exponentials, so the form is exact at any decay. Only the
 chunks' entry states are carried, in float32, by one ``lax.scan`` whose body
 (a chunk's products) is rematerialised in the backward pass: what is alive
 at once is one chunk's (heads, chunk, chunk) factors. There is no triangular
-solve. Plain ``jax.numpy`` / ``lax``, XLA writing the backward pass, for any
-shape and backend; no Pallas kernel takes it yet (``kernel.xla_ssd_scan`` is
-counted once a call all the same; PERF.md §6-7, PR 46, has the chip's
-reading, a fifth of the Granite cell's step at 6% of the scan's roofline,
-which asks for one).
+solve. That is the plain ``jax.numpy`` / ``lax`` form, XLA writing the
+backward pass, for any shape and backend: the CPU's path, the tests'
+reference, and counted ``kernel.xla_ssd_scan`` once a call. Where
+``perf.pallas.ssd.supported`` takes the call (a TPU, one group, heads of 64
+or 128, N and the chunk multiples of 128, a length the chunk divides) the
+same algorithm runs as a forward and a backward Pallas kernel
+(``ssd_scan_fwd`` / ``ssd_scan_bwd``, ``kernel.pallas_ssd_scan``) that make a
+chunk's factors in VMEM and carry the states in scratch: PERF.md §5-6, PR 47
+(in XLA's form the scan was a fifth of the Granite cell's step at 6% of its
+roofline, PR 46).
 
 Scopes, forward and backward: ``ssm.in_proj``, ``ssm.conv`` (taps, bias,
 SiLU, the softplus of dt), ``ssm.scan`` (the D term with it),
@@ -66,6 +71,7 @@ from deeplearning4j_tpu.nn.conf.short_conv import causal_depthwise_conv
 from deeplearning4j_tpu.nn.initializers import init_weights
 from deeplearning4j_tpu.perf import pallas as pk
 from deeplearning4j_tpu.perf.compile_watch import bump_active
+from deeplearning4j_tpu.perf.pallas import ssd
 
 # the wide projection's output by its ``checkpoint_name``
 PROJECTION_KEPT = ("state_space.projection",)
@@ -126,7 +132,8 @@ def chunked_ssd(x, dt, a_rate, bm, cm, chunk: int = 256):
         raise ValueError(f"{h} heads are no multiple of {groups} groups")
     if chunk < 1:
         raise ValueError(f"a chunk of {chunk} steps")
-    pk.take("ssd_scan", False)           # no kernel yet: counted as XLA's
+    if pk.take("ssd_scan", ssd.supported(x, dt, a_rate, bm, cm, chunk)):
+        return ssd.ssd_scan(x, dt, a_rate, bm, cm, chunk)
     per = h // groups
     length = min(chunk, t)
     pad = (-t) % length
@@ -265,12 +272,20 @@ class Mamba2Mixer(BaseLayer):
             dt = jax.nn.softplus(zxbcdt[..., inner + conv:].astype(f32)
                                  + params["dt_bias"].astype(f32))
         with jax.named_scope("ssm.scan"):
-            xs = xbc[..., :inner].reshape(bsz, t, h, p)
+            xs = xbc[..., :inner]
             y = chunked_ssd(
-                xs, dt, -jnp.exp(params["A_log"].astype(f32)),
+                xs.reshape(bsz, t, h, p), dt,
+                -jnp.exp(params["A_log"].astype(f32)),
                 xbc[..., inner:inner + g * n].reshape(bsz, t, g, n),
                 xbc[..., inner + g * n:].reshape(bsz, t, g, n), self.chunk)
-            y = y + params["D"].astype(f32)[:, None] * xs.astype(f32)
+            # the D term over whole rows of d_in columns, as the scan's
+            # kernels read and write them: a (..., heads, P) operand is
+            # half a lane tile wide, and the compiler then lays y and its
+            # cotangent out time-minor and copies both round the kernels
+            # (a gather, not ``repeat``: a reshaped broadcast draws its
+            # neighbours back into (..., heads, P))
+            skip = params["D"].astype(f32)[jnp.arange(inner) // p]
+            y = y.reshape(bsz, t, inner) + skip * xs.astype(f32)
         with jax.named_scope("ssm.gate_norm"):
             gated = y.reshape(bsz, t, g, inner // g)
             gate = jax.nn.silu(z.astype(f32)).reshape(gated.shape)

@@ -7,7 +7,7 @@ BN-train stats/normalize/residual traffic XLA refuses to fuse across
 costs ~4.7 extra full activation-set HBM crossings (tools/PROFILE_r5.md).
 This package holds the kernels that cross that line by hand — SURVEY
 L0/§7's replacement for libnd4j's C++ kernels exactly where XLA's fusion
-control runs out. Five families, each slotted behind a boundary the repo
+control runs out. Six families, each slotted behind a boundary the repo
 already parity-tests:
 
 - **bn** (:mod:`perf.pallas.bn`): fused BN-train forward/backward behind
@@ -35,6 +35,11 @@ already parity-tests:
   ``blocked_causal_attention`` (nn/conf/attention.py), a forward and a
   single-pass backward kernel behind one custom-VJP — a tile pair's
   scores, probabilities and their cotangents made and used in VMEM.
+- **ssd** (:mod:`perf.pallas.ssd`): the chunked scan of the Mamba-2
+  state-space mixer behind ``chunked_ssd`` (nn/conf/state_space.py),
+  forward and backward behind one custom-VJP — a chunk's (chunk, chunk)
+  decay factors of every head made and used in VMEM, time-major windows as
+  the layer's convolution writes them, the states carried in scratch.
 
 Selection contract (every kernel, no exceptions):
 
@@ -45,9 +50,8 @@ Selection contract (every kernel, no exceptions):
    automatically on a TPU backend (the families of
    :data:`TPU_AUTO_FAMILIES` only) — AND the call site's shape predicate
    (``bn.supported``, ``adc.pq_supported``, ``adc.int4_supported``,
-   ``kda.supported``, ``kda_inputs.supported``, ``attention.supported``)
-   says
-   the kernel fits. Anywhere else the reference runs.
+   ``kda.supported``, ``kda_inputs.supported``, ``attention.supported``,
+   ``ssd.supported``) says the kernel fits. Anywhere else the reference runs.
 2. Off-TPU, a force-enabled kernel runs in Pallas **interpret mode**
    (:func:`interpret` resolves true) — this is how CPU CI bitwise/
    tolerance-parity-tests the kernel bodies (tests/test_zz_pallas.py).
@@ -109,6 +113,8 @@ FAMILIES: Dict[str, str] = {
     "kda_inputs": "the delta-rule layers' input path, from the products to "
                   "the scan's operands, forward and backward "
                   "(nn/conf/linear_attention.py)",
+    "ssd_scan": "chunked_ssd's scan over chunks, forward and backward "
+                "(nn/conf/state_space.py)",
 }
 
 # Families the automatic rule selects on a TPU backend: those the v5e
@@ -122,7 +128,8 @@ FAMILIES: Dict[str, str] = {
 #   does not lower (its flat sibling's jnp.take: "Shape mismatch in
 #   input, indices and output"); needs a DMA rework (ROADMAP Speed 6).
 TPU_AUTO_FAMILIES = frozenset({"adc_pq", "int4_dot", "kda_scan",
-                               "blocked_attention", "kda_inputs"})
+                               "blocked_attention", "kda_inputs",
+                               "ssd_scan"})
 # No shape of these compiles, so not even an explicit enable (a
 # TuningRecord's ``pallas_kernels=True`` is applied process-wide) selects
 # them outside interpret mode.

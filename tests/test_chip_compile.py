@@ -427,6 +427,40 @@ def _gdn_inputs_float32(S):
     return _inputs_case(S, F32, True)
 
 
+def _ssd_scan(S):
+    # the Mamba-2 scan at the Granite 4.0-H stage's shape, one sequence of
+    # 8192 steps, 64 heads of 64 over one 128-wide B and C, chunks of 256:
+    # the forward kernel that saves the chunks' entry states and the
+    # backward kernel, through chunked_ssd's own selection
+    from deeplearning4j_tpu.nn.conf.state_space import chunked_ssd
+    from deeplearning4j_tpu.perf.pallas import ssd
+    args = (S((1, 8192, 64, 64), BF16), S((1, 8192, 64), F32), S((64,), F32),
+            S((1, 8192, 1, 128), BF16), S((1, 8192, 1, 128), BF16))
+    assert pk.take("ssd_scan", ssd.supported(*args, 256))
+
+    def fwd_bwd(*a):
+        return jax.grad(lambda *a: jnp.sum(chunked_ssd(*a, chunk=256)),
+                        argnums=range(5))(*a)
+
+    return fwd_bwd, args, ("ssd_scan_fwd", "ssd_scan_bwd")
+
+
+def _ssd_scan_float32(S):
+    # the same kernels with every product in float32 (what they compile to
+    # under jax.default_matmul_precision("highest")), float32 operands, two
+    # sequences, heads that fill a lane tile, a chunk of one factor block
+    from deeplearning4j_tpu.nn.conf.state_space import chunked_ssd
+    args = (S((2, 1024, 6, 128), F32), S((2, 1024, 6), F32), S((6,), F32),
+            S((2, 1024, 1, 128), F32), S((2, 1024, 1, 128), F32))
+
+    def fwd_bwd(*a):
+        with jax.default_matmul_precision("highest"):
+            return jax.grad(lambda *a: jnp.sum(chunked_ssd(*a, chunk=128)),
+                            argnums=range(5))(*a)
+
+    return fwd_bwd, args, ("ssd_scan_fwd", "ssd_scan_bwd")
+
+
 def _bn_fwd(S):
     # ResNet50 batch 128, the one stage whose rows fit: 7x7
     z = S((128, 7, 7, 2048), BF16)
@@ -460,7 +494,8 @@ AUTO_CASES = [(_pq, "adc_pq"), (_int4_table, "int4_dot"),
               (_blocked_attention_64_wide_nope, None),
               (_grouped_experts_1792, None),
               (_kda_inputs, "kda_inputs"), (_kda_inputs_float32, None),
-              (_gdn_inputs, None), (_gdn_inputs_float32, None)]
+              (_gdn_inputs, None), (_gdn_inputs_float32, None),
+              (_ssd_scan, "ssd_scan"), (_ssd_scan_float32, None)]
 EXPLICIT_CASES = [_bn_fwd, _bn_bwd]
 
 
@@ -534,29 +569,23 @@ def test_the_gather_dispatch_reads_its_windows_from_fast_memory(v5e):
 def test_the_state_space_scan_compiles_for_the_chip(v5e, tpu_backend):
     """``chunked_ssd`` at the Granite 4.0-H stage's shape (64 heads of 64
     over one shared 128-wide B and C, 8,192 steps in chunks of 256,
-    bfloat16 operands), forward and every gradient: XLA's own program (no
-    kernel takes the scan), one ``while`` over the chunks whose body is made
-    again in the backward pass, so that what it holds at once is a chunk's
-    factors and the chunks' entry states, not every chunk's (64, 256, 256)
-    decays (0.5 GB in float32)."""
-    from deeplearning4j_tpu.nn.conf.state_space import chunked_ssd
+    bfloat16 operands), forward and every gradient: the forward and the
+    backward kernel, no ``while`` over the chunks, and nothing of a chunk's
+    (heads, 256, 256) factors in HBM: what the program holds beside its
+    operands is y and its cotangent in float32 (134 MB each), the chunks'
+    entry states (67 MB) and the backward kernel's vectors."""
+    import re
 
-    def S(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
-
-    def fwd_bwd(x, dt, a, bm, cm):
-        return jax.grad(lambda *v: jnp.sum(chunked_ssd(*v, chunk=256)),
-                        argnums=(0, 1, 2, 3, 4))(x, dt, a, bm, cm)
-
-    compiled = jax.jit(fwd_bwd).lower(
-        S((1, 8192, 64, 64), BF16), S((1, 8192, 64), F32), S((64,), F32),
-        S((1, 8192, 1, 128), BF16), S((1, 8192, 1, 128), BF16)).compile()
+    fwd_bwd, args, kernels = _ssd_scan(
+        lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e))
+    compiled = jax.jit(fwd_bwd).lower(*args).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" not in text
-    assert text.count(" while(") >= 2            # forward and backward
-    # y and the states in float32 (134 + 67 MB), the operands' cotangents,
-    # a chunk's factors; not a chunk-squared array a chunk
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+    for name in kernels:
+        assert re.search(rf'custom_call_target="tpu_custom_call".*{name}',
+                         text), name
+    assert " while(" not in text
+    assert not re.search(r"\[(\d+,)+256,256\]", text)   # no factors in HBM
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.45e9
 
 
 def test_every_auto_family_has_a_case(tpu_backend):

@@ -983,3 +983,178 @@ def test_input_kernels_lower_under_the_layers_conv_scope(kind, cls, scope):
             name = l.split('op_name="')[1].split('"')[0]
             assert f"{cls}:mix1" in name and scope in name, name
             assert way in name, name
+
+
+# ------------------------------------------- the state-space scan (PR 47)
+# ``chunked_ssd``'s jax.numpy form against the token-by-token recurrence is
+# tests/test_zz_state_space.py's; here the kernels (interpreted) are held to
+# that form, the selection to what it says, and the kernels to the layer's
+# scope.
+from deeplearning4j_tpu.nn.conf import state_space as ssm  # noqa: E402
+from deeplearning4j_tpu.perf.pallas import ssd  # noqa: E402
+from test_zz_state_space import _operands  # noqa: E402
+
+
+def _ssd_counters():
+    return _family_counters("ssd_scan")
+
+
+def _ssd_operands(t, decay, dtype=jnp.float32, groups=1, h=4, p=64, n=128):
+    """tests/test_zz_state_space.py's seeded operands (steps that all but
+    forget the state, ``near_zero``, all but keep it, ``near_one``, or both
+    in one head, ``mixed``) at widths the kernels take, x, B and C in
+    ``dtype``."""
+    x, dt, a, bm, cm = _operands(t, groups, decay, h=h, p=p, n=n)
+    return x.astype(dtype), dt, a, bm.astype(dtype), cm.astype(dtype)
+
+
+def _ssd_out_and_grads(args, chunk):
+    probe = jax.random.normal(jax.random.key(9), args[0].shape)
+
+    def run(*a):
+        y = ssm.chunked_ssd(*a, chunk=chunk)
+        return jnp.sum(y * probe), y
+
+    return jax.jit(jax.value_and_grad(run, range(5), has_aux=True))(*args)
+
+
+@pytest.mark.parametrize("decay", ["mixed", "near_zero", "near_one"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("p,chunk,t", [(64, 128, 384), (128, 256, 512)],
+                         ids=["pairs_of_64", "heads_of_128"])
+def test_ssd_kernels_are_the_jnp_scan(p, chunk, t, dtype, decay):
+    """Forward and EVERY gradient (x, dt, A, B, C) of ``ssd_scan`` against
+    ``jax.vjp`` of the ``jax.numpy`` form, over several chunks (a carried
+    state, a chunk boundary, dS carried back), two sequences, heads that
+    share a lane tile and heads that fill one, a chunk of one factor block
+    and of two (the skipped block above the diagonal): float32 to float32's
+    rounding, bfloat16 to the products' (the ``jax.numpy`` form rounds its
+    cotangents to bfloat16 where the kernels keep float32)."""
+    args = _ssd_operands(t, decay, dtype, p=p)
+    before = _ssd_counters()
+    with pk.override(enabled=False):
+        want = _ssd_out_and_grads(args, chunk)
+    assert _ssd_counters() == (before[0] + 1, before[1])
+    with pk.override(enabled=True, interpret=True):
+        assert ssd.supported(*args, chunk)
+        got = _ssd_out_and_grads(args, chunk)
+    assert _ssd_counters() == (before[0] + 1, before[1] + 1)
+    assert got[0][1].dtype == jnp.float32
+    # near_zero: A's gradient is a sum of terms e-100 and smaller beside a
+    # few of order one; both forms round it in the fourth digit
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-3 if decay == "near_zero" \
+        else 3e-5
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        _close(a.astype(jnp.float32), b.astype(jnp.float32), tol)
+
+
+@pytest.mark.parametrize("why,change", [
+    ("a ragged length", dict(t=300)),
+    ("heads of 32", dict(p=32)),
+    ("an odd count of 64-wide heads", dict(h=3)),
+    ("two groups", dict(groups=2)),
+    ("a state of 64", dict(n=64)),
+    ("a chunk of 64", dict(chunk=64))])
+def test_ssd_scan_is_untouched_where_the_kernels_do_not_apply(why, change):
+    """Family on at a shape ``supported`` refuses: ``chunked_ssd`` runs the
+    ``jax.numpy`` form, the same program bit for bit, and counts
+    ``kernel.xla_ssd_scan``."""
+    chunk = change.pop("chunk", 128)
+    args = _ssd_operands(**{"t": 256, "decay": "mixed", **change})
+    before = _ssd_counters()
+    with pk.override(enabled=False):
+        off = _ssd_out_and_grads(args, chunk)
+    with pk.override(enabled=True, interpret=True):
+        assert not ssd.supported(*args, chunk), why
+        on = _ssd_out_and_grads(args, chunk)
+    assert _ssd_counters() == (before[0] + 2, before[1])
+    for a, b in zip(jax.tree.leaves(off), jax.tree.leaves(on)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_ssd_supported_reads_types_and_the_backend():
+    x, dt, a, bm, cm = _ssd_operands(256, "mixed")
+    with pk.override(enabled=True, interpret=True):
+        assert ssd.supported(x, dt, a, bm, cm, 128)
+        assert not ssd.supported(x.astype(jnp.bfloat16), dt, a, bm, cm, 128)
+        assert not ssd.supported(x.astype(jnp.float16), dt, a,
+                                 bm.astype(jnp.float16),
+                                 cm.astype(jnp.float16), 128)
+        # the windows of 1,024 heads of 128 at a chunk of 512: over the limit
+        wide = [jax.ShapeDtypeStruct(s, jnp.float32) for s in (
+            (1, 512, 1024, 128), (1, 512, 1024), (1024,), (1, 512, 1, 128),
+            (1, 512, 1, 128))]
+        assert not ssd.supported(*wide, 512)
+    with pk.override(interpret=False):           # a CPU that would compile
+        assert not ssd.supported(x, dt, a, bm, cm, 128)
+    assert "ssd_scan" in pk.FAMILIES and "ssd_scan" in pk.TPU_AUTO_FAMILIES
+
+
+def _mixer_grads(layer, params, x):
+    from deeplearning4j_tpu.nn.conf.layers import apply_layer
+
+    def loss(p, xx):
+        out = apply_layer(layer, p, {}, xx, train=True, rng=None, mask=None,
+                          name="mix")[0]
+        return jnp.sum(jnp.sin(out.astype(jnp.float32)))
+
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(params, x)
+
+
+@pytest.mark.parametrize("remat", [None, "full"], ids=["plain", "remat"])
+def test_a_mixer_gives_the_same_gradients_with_the_kernels(remat):
+    """A ``Mamba2Mixer`` (rematerialised too: the forward kernel twice, the
+    backward once, the entry states the custom-VJP's residuals) with the
+    kernels and without: every leaf's gradient and the input's."""
+    layer = ssm.Mamba2Mixer(n_heads=2, head_dim=64, state_size=128,
+                            chunk=128, remat=remat)
+    params, _ = layer.init(jax.random.key(0), InputType.recurrent(12, 256))
+    x = jax.random.normal(jax.random.key(1), (2, 256, 12))
+    before = _ssd_counters()
+    with jax.default_matmul_precision("highest"):
+        with pk.override(enabled=False):
+            off = _mixer_grads(layer, params, x)
+        assert _ssd_counters()[1] == before[1]
+        with pk.override(enabled=True, interpret=True):
+            on = _mixer_grads(layer, params, x)
+    assert _ssd_counters()[1] > before[1]
+    for a, b in zip(jax.tree.leaves(off), jax.tree.leaves(on)):
+        _close(a, b, 1e-4)
+
+
+def test_ssd_kernels_lower_under_the_layers_scan_scope():
+    """``ssm.scan_device_ms_per_step`` and ``ssm.scan_roofline_pct`` find
+    their operations by ``op_name`` in the step's HLO text: every operation
+    the forward and backward kernels (here their interpreted bodies) lower
+    to carries the layer's scope and ``ssm.scan``, in the backward pass
+    too."""
+    from deeplearning4j_tpu.nn.conf.graph import GraphBuilder
+    from deeplearning4j_tpu.nn.conf.recurrent import RnnOutputLayer
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+    conf = (GraphBuilder(NeuralNetConfiguration.builder().seed(3)
+                         .updater(Sgd(learning_rate=0.05)))
+            .add_inputs("in")
+            .add_layer("l0_ssm", ssm.Mamba2Mixer(
+                n_heads=2, head_dim=64, state_size=128, chunk=128), "in")
+            .add_layer("out", RnnOutputLayer(n_out=3, loss="mcxent"),
+                       "l0_ssm")
+            .set_outputs("out")
+            .set_input_types(InputType.recurrent(12, 256)).build())
+    with pk.override(enabled=True, interpret=True):
+        net = ComputationGraph(conf).init()
+        x = jnp.zeros((1, 256, 12), jnp.float32)
+        y = jnp.zeros((1, 256, 3), jnp.float32)
+        hlo = net._get_jitted("train").lower(
+            net.params, net.state, net.opt_state, net._rng, [x], [y], None,
+            None).compile().as_text()
+    ops = [l for l in hlo.splitlines() if "op_name=" in l]
+    for kernel, way in (("ssd_scan_fwd", "jvp("), ("ssd_scan_bwd",
+                                                   "transpose(")):
+        mine = [l for l in ops if kernel in l]
+        assert len(mine) > 20, (kernel, len(mine))
+        for l in mine:
+            name = l.split('op_name="')[1].split('"')[0]
+            assert "Mamba2Mixer:l0_ssm" in name and "ssm.scan" in name, name
+            assert way in name, name
