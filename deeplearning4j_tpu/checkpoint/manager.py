@@ -215,9 +215,6 @@ class CheckpointManager:
             "checkpoint_restore_ms", unit="ms",
             help="wall time of one checkpoint restore (fetch + verify + "
                  "deserialize)")
-        self._m_bytes_restored = reg.counter(
-            "checkpoint_bytes_restored_total", unit="bytes",
-            help="checkpoint payload bytes read back during restores")
         absorb_checkpoint_manager(reg, self)
 
     def _entry_from_object(self, filename: str) -> Optional[dict]:
@@ -276,7 +273,7 @@ class CheckpointManager:
         from deeplearning4j_tpu.obs.trace import get_tracer
         # the training thread's half of a checkpoint, in the fit loops'
         # span tree (obs/trace.py); a save that triggers adds the
-        # checkpoint.snapshot child
+        # checkpoint.save child
         with get_tracer().span("checkpoint.step_end"):
             if batch_in_epoch is not None:
                 self._batch_in_epoch = int(batch_in_epoch)
@@ -336,51 +333,82 @@ class CheckpointManager:
         # counter on every host.
         self._last_save_t = time.monotonic()
         self._last_save_step = int(model.iteration)
-        if self.sharded:
-            return self._save_sharded(model, metric)
         multi = jax.process_count() > 1
-        if multi and jax.process_index() != 0:
+        if not self.sharded and multi and jax.process_index() != 0:
             # non-writers only barrier: keeps every host's save points in
             # lockstep so process 0's device_get sync can't skew the step
             # cadence across the fleet
             self._barrier("checkpoint save")
             return None
+        from deeplearning4j_tpu.obs.trace import get_tracer
+        self._seq += 1  # every host: shard names must agree fleet-wide
+        ids = {"seq": self._seq, "step": int(model.iteration)}
+        # what this save costs the thread that asked for it, as one span
+        # (obs/trace.py has the tree); the writer's half is
+        # checkpoint_writer.write, under the same seq
+        with get_tracer().span("checkpoint.save", queued=self._queued(),
+                               sharded=int(self.sharded), **ids) as sp:
+            if self.sharded:
+                return self._save_sharded(model, metric, ids, sp)
+            return self._save_whole(model, metric, wait, multi, ids, sp)
+
+    def _queued(self) -> int:
+        """Snapshots waiting for the writer thread (the one it is writing
+        is not among them)."""
+        return 0 if self._q is None else self._q.qsize()
+
+    def _save_whole(self, model, metric, wait: bool, multi: bool, ids: dict,
+                    sp) -> str:
+        from deeplearning4j_tpu.obs.trace import get_tracer
         from deeplearning4j_tpu.utils.serialization import snapshot_training_state
-        snap = self._snapshot(snapshot_training_state, model)
+        snap = self._snapshot(snapshot_training_state, model, ids, sp)
         if not self.save_updater:
             snap["opt_state"] = None
-        self._seq += 1
         extra = {
-            "seq": self._seq,
+            "seq": ids["seq"],
             "batch_in_epoch": self._batch_in_epoch,
             "wall_time": time.time(),
             "metric": None if metric is None else float(metric),
         }
-        filename = f"ckpt-{snap['iteration']:010d}-{self._seq:05d}.zip"
+        filename = f"ckpt-{snap['iteration']:010d}-{ids['seq']:05d}.zip"
         self.saves_requested += 1
         if self.async_write:
             self._ensure_worker()
-            self._q.put((snap, extra, filename))  # bounded: backpressure,
-            # a slow disk can't accumulate unbounded host snapshots
+            # bounded: backpressure, a slow disk can't accumulate unbounded
+            # host snapshots. The span is the writer's lag as the step loop
+            # feels it: nothing while the writer keeps up
+            with get_tracer().span("checkpoint.enqueue",
+                                   queued=self._queued(), **ids):
+                self._q.put((snap, extra, filename, time.perf_counter()))
         else:
             self._write_and_commit(snap, extra, filename)
         if multi:
-            self._barrier("checkpoint save")
+            self._barrier("checkpoint save", **ids)
         if wait:
             self.flush()
         return filename
 
     @staticmethod
-    def _snapshot(take, model) -> dict:
+    def _snapshot(take, model, ids: dict, save_span) -> dict:
         """``take(model)`` (the device-to-host copy a save makes on the
         calling thread) under a ``checkpoint.snapshot`` span that says how
-        many bytes it copied."""
+        many bytes it copied. Its child ``checkpoint.drain`` waits for the
+        steps the host has queued to finish with the very trees ``take``
+        is about to ``device_get`` (which would make the same wait one
+        line later), so the snapshot's SELF time is the copy alone."""
         import jax
         from deeplearning4j_tpu.obs.trace import get_tracer
-        with get_tracer().span("checkpoint.snapshot") as sp:
+        tracer = get_tracer()
+        with tracer.span("checkpoint.snapshot", **ids) as sp:
+            with tracer.span("checkpoint.drain", **ids):
+                jax.block_until_ready(
+                    (model.params, model.state, model.opt_state,
+                     getattr(model, "compress_state", None)))
             snap = take(model)
-            sp.set(bytes=sum(int(getattr(leaf, "nbytes", 0))
-                             for leaf in jax.tree_util.tree_leaves(snap)))
+            nbytes = sum(int(getattr(leaf, "nbytes", 0))
+                         for leaf in jax.tree_util.tree_leaves(snap))
+            sp.set(bytes=nbytes)
+            save_span.set(bytes=nbytes)
         return snap
 
     # ------------------------------------------------------ saver protocol
@@ -419,9 +447,9 @@ class CheckpointManager:
             try:
                 if item is CheckpointManager._SENTINEL:
                     return
-                snap, extra, filename = item
+                snap, extra, filename, queued_at = item
                 try:
-                    self._write_and_commit(snap, extra, filename)
+                    self._write_and_commit(snap, extra, filename, queued_at)
                 except BaseException as e:  # surfaced on the training thread
                     log.exception("checkpoint write failed for %s", filename)
                     self._write_err = e
@@ -429,37 +457,59 @@ class CheckpointManager:
                 self._q.task_done()
 
     # ---------------------------------------------------------- sharded save
-    def _save_sharded(self, model, metric: Optional[float]) -> Optional[str]:
+    def _save_sharded(self, model, metric: Optional[float], ids: dict,
+                      sp) -> Optional[str]:
         """Every host writes its OWN shard; the set becomes one journal
         entry (per-shard sha256) committed by process 0 only after a
         barrier proves every shard durable — the commit of the SET is
         atomic: a crash anywhere before the journal write leaves orphaned
         shards the restore walk never sees. Always synchronous (the save
         ends in a cross-host barrier regardless, and the elastic layer
-        saves at epoch boundaries, not on the step cadence)."""
+        saves at epoch boundaries, not on the step cadence), so the
+        writer's spans open on the calling thread, inside
+        ``checkpoint.save``."""
         import jax
         from deeplearning4j_tpu.checkpoint import sharded as shd
+        from deeplearning4j_tpu.obs.trace import get_tracer
+        tracer = get_tracer()
         pi, pc = jax.process_index(), jax.process_count()
         t0 = time.perf_counter()
-        self._seq += 1  # every host: shard names must agree fleet-wide
-        snap = self._snapshot(shd.shard_snapshot, model)
+        snap = self._snapshot(shd.shard_snapshot, model, ids, sp)
         if not self.save_updater:
             snap["updaterState"] = None
         extra = {
-            "seq": self._seq,
+            "seq": ids["seq"],
             "batch_in_epoch": self._batch_in_epoch,
             "wall_time": time.time(),
             "metric": None if metric is None else float(metric),
         }
-        base = f"ckpt-{snap['iteration']:010d}-{self._seq:05d}"
+        base = f"ckpt-{snap['iteration']:010d}-{ids['seq']:05d}"
         shard_name = shd.shard_object_name(base, pi, pc)
         self.saves_requested += 1
-        shard_bytes = shd.shard_zip_bytes(snap, extra)
-        self._storage.put(shard_name, shard_bytes)
-        self._m_bytes_written.inc(len(shard_bytes))
-        self._barrier("sharded payloads durable")
-        if pi == 0:
-            shards = []
+        with tracer.span("checkpoint_writer.write", waited_ms=0.0,
+                         **ids) as write:
+            with tracer.span("checkpoint_writer.serialize", **ids):
+                shard_bytes = shd.shard_zip_bytes(snap, extra)
+            write.set(bytes=len(shard_bytes))
+            with tracer.span("checkpoint_writer.put", **ids):
+                self._storage.put(shard_name, shard_bytes)
+            self._m_bytes_written.inc(len(shard_bytes))
+            self._barrier("sharded payloads durable", **ids)
+            if pi == 0:
+                self._journal_shard_set(base, pc, snap, extra, ids)
+            self._barrier("sharded journal", **ids)
+        self._m_commit_ms.observe((time.perf_counter() - t0) * 1000.0)
+        return f"{base}.sharded" if pi == 0 else None
+
+    def _journal_shard_set(self, base: str, pc: int, snap: dict,
+                           extra: dict, ids: dict):
+        """Process 0's half of a sharded commit: read every shard back,
+        then journal the set as one entry."""
+        from deeplearning4j_tpu.checkpoint import sharded as shd
+        from deeplearning4j_tpu.obs.trace import get_tracer
+        tracer = get_tracer()
+        shards = []
+        with tracer.span("checkpoint_writer.hash", **ids):
             for host in range(pc):
                 name = shd.shard_object_name(base, host, pc)
                 data = self._storage.get(name)  # read-back doubles as a
@@ -473,66 +523,32 @@ class CheckpointManager:
                     # fetching any payload
                     "blocks": shd.shard_block_summary(data),
                 })
-            entry = {
-                "file": f"{base}.sharded",
-                "sharded": True,
-                "num_hosts": pc,
-                "shards": shards,
-                "seq": extra["seq"],
-                "step": snap["iteration"],
-                "epoch": snap["epoch"],
-                "batch_in_epoch": extra["batch_in_epoch"],
-                "metric": extra["metric"],
-                "wall_time": extra["wall_time"],
-                "sha256": None,
-                "size": sum(s["size"] for s in shards),
-            }
-            try:
-                if self.commit_guard is not None:
-                    self.commit_guard()  # raising aborts the commit
-                with self._lock:
-                    self._entries.append(entry)
-                    self._entries = self._apply_retention(self._entries)
-                    self._mf.write_manifest(self._storage, self._entries)
-            except BaseException:
-                # the un-journaled shard set must not survive: it is a
-                # COMPLETE set, and a later manifest-loss rebuild
-                # (scan_shard_sets) would resurrect the very checkpoint
-                # the fence refused to commit
-                for s in shards:
-                    try:
-                        self._storage.delete(s["file"])
-                    except Exception as de:
-                        log.warning("could not delete aborted shard %s "
-                                    "(%s: %s)", s["file"],
-                                    type(de).__name__, de)
-                raise
-            self.saves_committed += 1
-        self._barrier("sharded journal")
-        self._m_commit_ms.observe((time.perf_counter() - t0) * 1000.0)
-        return f"{base}.sharded" if pi == 0 else None
-
-    def _write_and_commit(self, snap: dict, extra: dict, filename: str):
-        from deeplearning4j_tpu.obs.trace import get_tracer
-        from deeplearning4j_tpu.utils.serialization import checkpoint_zip_bytes
-        t0 = time.perf_counter()
-        data = checkpoint_zip_bytes(snap, extra)
-        sha = hashlib.sha256(data).hexdigest()
-        # fsync_directory deferred to the manifest write below (same dir):
-        # the journal entry can never become durable before the payload
-        # (a local-fs hint; object-store puts are durable on return)
-        self._storage.put(filename, data, fsync_directory=False)
         entry = {
-            "file": filename,
+            "file": f"{base}.sharded",
+            "sharded": True,
+            "num_hosts": pc,
+            "shards": shards,
             "seq": extra["seq"],
             "step": snap["iteration"],
             "epoch": snap["epoch"],
             "batch_in_epoch": extra["batch_in_epoch"],
             "metric": extra["metric"],
             "wall_time": extra["wall_time"],
-            "sha256": sha,
-            "size": len(data),
+            "sha256": None,
+            "size": sum(s["size"] for s in shards),
         }
+        # the un-journaled shard set must not survive an abort: it is a
+        # COMPLETE set, and a later manifest-loss rebuild
+        # (scan_shard_sets) would resurrect the very checkpoint the fence
+        # refused to commit
+        with tracer.span("checkpoint_writer.journal", **ids):
+            self._journal(entry, [s["file"] for s in shards])
+
+    def _journal(self, entry: dict, payloads: List[str]):
+        """Guard, retention and the manifest write of one commit; on any
+        failure the entry's ``payloads`` are deleted before it is raised
+        (an un-journaled payload that survived a guard abort would be
+        resurrected by a later manifest-loss scan)."""
         try:
             if self.commit_guard is not None:
                 self.commit_guard()  # raising aborts the journal commit
@@ -541,22 +557,63 @@ class CheckpointManager:
                 self._entries = self._apply_retention(self._entries)
                 self._mf.write_manifest(self._storage, self._entries)
         except BaseException:
-            # the un-journaled payload must not survive a guard abort: a
-            # later manifest-loss scan would resurrect the very
-            # checkpoint the generation fence refused to commit
-            try:
-                self._storage.delete(filename)
-            except Exception as de:
-                log.warning("could not delete aborted checkpoint %s "
-                            "(%s: %s)", filename, type(de).__name__, de)
+            for name in payloads:
+                try:
+                    self._storage.delete(name)
+                except Exception as de:
+                    log.warning("could not delete aborted checkpoint "
+                                "payload %s (%s: %s)", name,
+                                type(de).__name__, de)
             raise
         self.saves_committed += 1
+
+    def _write_and_commit(self, snap: dict, extra: dict, filename: str,
+                          queued_at: Optional[float] = None):
+        """Serialize, hash, put and journal one snapshot: the writer
+        thread's work on a save (the calling thread's with
+        ``async_write=False``), as ``checkpoint_writer.write`` and its
+        four children. The names do not start with ``checkpoint.``: a
+        reader that puts device idle time down to the training thread's
+        spans by that prefix must not find a seconds-long span of another
+        thread under it."""
+        from deeplearning4j_tpu.obs.trace import get_tracer
+        from deeplearning4j_tpu.utils.serialization import checkpoint_zip_bytes
+        tracer = get_tracer()
+        ids = {"seq": extra["seq"], "step": snap["iteration"]}
+        t0 = time.perf_counter()
+        waited_ms = 0.0 if queued_at is None else (t0 - queued_at) * 1000.0
+        with tracer.span("checkpoint_writer.write",
+                         waited_ms=round(waited_ms, 3), **ids) as write:
+            with tracer.span("checkpoint_writer.serialize", **ids):
+                data = checkpoint_zip_bytes(snap, extra)
+            write.set(bytes=len(data))
+            with tracer.span("checkpoint_writer.hash", **ids):
+                sha = hashlib.sha256(data).hexdigest()
+            # fsync_directory deferred to the manifest write below (same
+            # dir): the journal entry can never become durable before the
+            # payload (a local-fs hint; object-store puts are durable on
+            # return)
+            with tracer.span("checkpoint_writer.put", **ids):
+                self._storage.put(filename, data, fsync_directory=False)
+            entry = {
+                "file": filename,
+                "seq": extra["seq"],
+                "step": snap["iteration"],
+                "epoch": snap["epoch"],
+                "batch_in_epoch": extra["batch_in_epoch"],
+                "metric": extra["metric"],
+                "wall_time": extra["wall_time"],
+                "sha256": sha,
+                "size": len(data),
+            }
+            with tracer.span("checkpoint_writer.journal", **ids):
+                self._journal(entry, [filename])
         commit_ms = (time.perf_counter() - t0) * 1000.0
         self._m_commit_ms.observe(commit_ms)
         self._m_bytes_written.inc(len(data))
-        get_tracer().event("checkpoint.commit", file=filename,
-                           step=snap.get("iteration"), bytes=len(data),
-                           ms=round(commit_ms, 2))
+        tracer.event("checkpoint.commit", file=filename,
+                     step=snap.get("iteration"), bytes=len(data),
+                     ms=round(commit_ms, 2))
 
     def _best_entry(self, entries: List[dict],
                     direction: Optional[str] = None) -> Optional[dict]:
@@ -715,7 +772,6 @@ class CheckpointManager:
         # silently reinterpret num_epochs / skip unrelated batches
         model._resume_state = info if arm_resume else None
         self._m_restore_ms.observe((time.perf_counter() - t0) * 1000.0)
-        self._m_bytes_restored.inc(int(entry.get("size", 0) or 0))
         return model
 
     def restore_latest(self, load_updater: bool = True):
@@ -794,18 +850,22 @@ class CheckpointManager:
         raise CheckpointError(f"no journal entry named {filename!r}")
 
     # ------------------------------------------------------------- multi-host
-    def _barrier(self, what: str):
+    def _barrier(self, what: str, **ids):
         """Bounded collective barrier (watchdog deadline pattern): a dead
         peer at a checkpoint point raises CollectiveTimeoutError with
-        process/device diagnostics instead of hanging the fleet."""
+        process/device diagnostics instead of hanging the fleet. ``ids``:
+        the save's ``seq`` and ``step``, for its span."""
         import jax
         if jax.process_count() <= 1:
             return
+        from deeplearning4j_tpu.obs.trace import get_tracer
         from deeplearning4j_tpu.parallel.watchdog import CollectiveWatchdog
         from jax.experimental import multihost_utils
-        CollectiveWatchdog(timeout_s=self.barrier_timeout_s).call(
-            lambda: multihost_utils.sync_global_devices(f"checkpoint:{what}"),
-            what=f"checkpoint barrier ({what})")
+        with get_tracer().span("checkpoint.barrier", what=what, **ids):
+            CollectiveWatchdog(timeout_s=self.barrier_timeout_s).call(
+                lambda: multihost_utils.sync_global_devices(
+                    f"checkpoint:{what}"),
+                what=f"checkpoint barrier ({what})")
 
 
 def consume_resume_state(model):

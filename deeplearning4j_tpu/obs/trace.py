@@ -22,9 +22,11 @@ span that names no ``step`` of its own takes its parent's, so all spans of
 one training step share one. Self time is a span's duration minus its
 children's. Spans are host-side only and must never enter jit-traced code
 (DLT002: a clock read inside a traced function freezes at trace time), and
-NO span waits for the device: the fit loops' ``train.iteration`` (one turn
-of the loop) is the step time once the device is the bottleneck, because
-the runtime's back-pressure then holds the host inside ``train.dispatch``;
+NO span adds a wait for the device (``checkpoint.drain`` names the one a
+save's ``device_get`` makes anyway): the fit loops' ``train.iteration`` (one
+turn of the loop) is the step time once the device is the bottleneck,
+because the runtime's back-pressure then holds the host inside
+``train.dispatch``;
 device time per step proper comes from the device planes of a profiler
 trace. Operators without a profiler read ``train_iteration_ms`` (p50/p95).
 
@@ -42,7 +44,31 @@ checkpoint/manager.py)::
         train.post                  score handle, counters; sampled=1: the
                                     feature slice a listener reads
         train.listeners             iteration_done of the listeners
-        checkpoint.step_end         child checkpoint.snapshot on a save
+        checkpoint.step_end         every turn with a manager; on a save:
+          checkpoint.save           what the save costs the calling thread
+            checkpoint.snapshot     self time: the device-to-host copy
+              checkpoint.drain      the wait for the queued steps to end
+            checkpoint.enqueue      the hand-over: the writer's lag, if any
+            checkpoint.barrier      multi-process only
+
+    checkpoint_writer.write         the writer thread's work on a snapshot
+      checkpoint_writer.serialize   npz + zip, in memory
+      checkpoint_writer.hash        sha256 of the payload
+      checkpoint_writer.put         the storage backend: write, fsync, rename
+      checkpoint_writer.journal     guard, retention, manifest
+
+``checkpoint.save`` opens whoever calls ``CheckpointManager.save`` (the
+step trigger, an epoch's end, the early-stopping saver, a user); all spans
+of one save, on both threads, carry its ``seq`` and the ``step`` its
+checkpoint holds (the steps done: under a fit loop the turn's own step + 1).
+``checkpoint.drain`` is a ``block_until_ready`` of the trees the snapshot
+is about to ``device_get``, which would make the same wait one line later:
+it adds no synchronisation and gives the wait a name. The writer's spans
+carry a prefix of their own on purpose: a reader that puts device idle time
+down to the training thread's spans by the ``checkpoint.`` prefix must not
+find a seconds-long span of another thread under it. With
+``async_write=False`` (and on the sharded path) they open on the calling
+thread, inside ``checkpoint.save``.
 
 Finished spans and instant events are dispatched to *sinks* (the crash
 flight recorder's ring, a JSONL event log) and — when the tracer carries a

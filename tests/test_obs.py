@@ -505,12 +505,19 @@ class TestFitPhaseBreakdown:
         assert listener.calls == [0, 1, 2, 3]
         listeners = [s for s in sink if s["name"] == "train.listeners"]
         ends = [s for s in sink if s["name"] == "checkpoint.step_end"]
+        saves = [s for s in sink if s["name"] == "checkpoint.save"]
         snaps = [s for s in sink if s["name"] == "checkpoint.snapshot"]
-        assert len(listeners) == len(ends) == 4 and len(snaps) == 2
+        assert len(listeners) == len(ends) == 4
+        assert len(saves) == len(snaps) == 2
         assert {parent_name(s) for s in listeners + ends} == {
             "train.step_host"}
-        assert {parent_name(s) for s in snaps} == {"checkpoint.step_end"}
-        assert [s["attrs"]["step"] for s in snaps] == [1, 3]
+        # a save that triggers hangs under the turn's step_end (the rest of
+        # its tree: tests/test_checkpoint_spans.py)
+        assert {parent_name(s) for s in saves} == {"checkpoint.step_end"}
+        assert {parent_name(s) for s in snaps} == {"checkpoint.save"}
+        # a save's spans carry the step its checkpoint holds (the steps
+        # done: the turn's own step + 1)
+        assert [s["attrs"]["step"] for s in saves + snaps] == [2, 4, 2, 4]
         assert all(s["attrs"]["bytes"] > 0 for s in snaps)
 
     def test_per_window_tbptt_spans_carry_each_windows_step(self):
@@ -1276,19 +1283,22 @@ class TestChaosPostMortem:
         #     membership-transition pause of the respawned generation
         records = []
         for name in backend.list(prefix="events-"):
-            records.extend(obs.read_event_log(backend, name))
+            # span ids are a process's own, and every attempt writes a log
+            # of its own: an id means something beside its log's name only
+            records.extend(dict(r, log=name)
+                           for r in obs.read_event_log(backend, name))
         names = {r["name"] for r in records}
         assert {"train.iteration", "train.data_wait", "train.step_host",
                 "train.stage", "train.dispatch", "train.post",
                 "train.listeners", "checkpoint.step_end"} <= names
         # the worker trains under a watchdog, on its worker thread: the
         # step still hangs under its turn
-        by_id = {r["id"]: r for r in records if "id" in r}
+        by_id = {(r["log"], r["id"]): r for r in records if "id" in r}
         hosts = [r for r in records if r["name"] == "train.step_host"]
         assert hosts and all(
-            by_id[h["parent"]]["name"] == "train.iteration"
-            and by_id[h["parent"]]["thread"] != h["thread"] for h in hosts
-            if h["parent"] in by_id)
+            by_id[h["log"], h["parent"]]["name"] == "train.iteration"
+            and by_id[h["log"], h["parent"]]["thread"] != h["thread"]
+            for h in hosts if (h["log"], h["parent"]) in by_id)
         pauses = [r for r in records
                   if r["name"] == "elastic.transition_pause"]
         assert pauses and pauses[0]["attrs"]["generation"] == 2
