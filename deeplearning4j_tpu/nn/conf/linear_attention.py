@@ -55,7 +55,9 @@ in or of dq, dk, dv, dg out). Measured in the benchmark's two token cells:
 PERF.md §5-6, PR 31.
 
 Rematerialised (``remat=``), both layers keep what their scan's kernels
-name (``kda.KEPT``: o and the chunks' entry states, float32) and outputs of
+name (``kda.KEPT``: o, the chunks' entry states, and the chunks' solved u
+and decayed scores that the backward kernel reads where it made them a
+second time, float32) and outputs of
 the wide projections in front of the input path (``PROJECTIONS_KEPT``: KDA's
 ``x Wq``, ``x Wk`` and the decay's latent ``x Wf1``, Gated DeltaNet's
 ``x Wqkvz``, in the compute type as the products wrote them, named in both
@@ -70,7 +72,7 @@ shorter a step, PERF.md §6, PR 41; v needs no norm and is the cheapest to
 make again), q, k, v, g, b themselves (1.34 GB over Kimi's four layers for
 the 3.7 ms a step of ``kda_inputs_fwd``) and the narrow products (``Wf2``,
 ``Wb`` / ``Wba``, the gate's): those are made again. The scan's results are
-read: ``kda_scan_fwd`` once a layer and step (PERF.md §5-6, PRs 36 and 41).
+read: ``kda_scan_fwd`` once a layer and step (PERF.md §5-6, PRs 36, 41, 49).
 ``remat="nothing_saveable"`` keeps nothing; the ``jax.numpy`` scan names
 nothing of its own and checkpoints its groups of chunks.
 """

@@ -6,7 +6,8 @@ chunk's state-free terms (decayed scores, the triangular solve) are some
 hundreds of small XLA operations whose operands cross HBM between fusions;
 here a chunk of one head is taken up once (q, k, v in the compute type, g
 in float32), everything about it is made on VMEM values, and only o (and,
-for the backward pass, the state at the chunk's entry) is written back.
+for the backward pass, the state at the chunk's entry, the chunk's solved
+u and its decayed scores) is written back.
 
 What is computed is ``chunked_kda``'s algorithm, not a cheaper one: chunks
 of 64 steps; the diagonal blocks of 8 x 8 written out channel by channel
@@ -42,21 +43,33 @@ take (batch, heads, time, K) arrays, so a (64, 128) tile of one head is a
 the chip, strided windows of the arrays as they are the same).
 
 Backward: the same grid with the chunk index reversed and dS carried in
-the scratch. A chunk's gradients need only its own q, k, v, g, b, its
-entry state, dO and the dS that arrives: one kernel recomputes the chunk's
-terms in VMEM and emits dq, dk, dv, dg, db, each piece written by hand
-and held to ``jax.vjp`` of the ``jax.numpy`` form in
-tests/test_zz_pallas.py.
+the scratch. A chunk's gradients need its own q, k, v, g, b, its entry
+state, dO and the dS that arrives, and two things the forward kernel made
+of them and wrote out, which this kernel READS and does not make again
+(PERF.md §6, PR 49: of the 6,550 bundles a pair of heads that the kernel
+was when it recomputed the chunk's terms, the second forward substitution,
+``_solve_lower``, was 1,234 and these scores 365): the solved u, and the
+decayed scores P and ``kk_off`` (k's with itself below the diagonal blocks)
+side by side as one (C, 2 C) array, 128 whole lanes. It makes again the
+running sum of g and k's diagonal blocks as columns (the transposed solve,
+``_solve_upper``, wants them spread over the lanes), and emits dq, dk, dv,
+dg, db, each piece written by hand and held to ``jax.vjp`` of the
+``jax.numpy`` form in tests/test_zz_pallas.py.
 
 Under a layer's rematerialisation (``apply_layer``, ``remat=``) the forward
-kernel's two outputs, o (heads-major, as the kernel wrote it: its transpose
-fuses into whoever reads it, in both passes; named after the transpose it
-became four copies a step on the chip) and the chunks' entry states, carry
-the ``checkpoint_name``s of ``KEPT``: the delta-rule layers declare them
+kernel's four outputs in its ``save`` form, o (heads-major, as the kernel
+wrote it: its transpose fuses into whoever reads it, in both passes; named
+after the transpose it became four copies a step on the chip), the chunks'
+entry states, the chunks' solved u and their scores ``[P | kk_off]``
+(heads-major, float32), carry the ``checkpoint_name``s of ``KEPT``: the
+delta-rule layers declare them
 (``remat_keeps``), so the step runs ``kda_scan_fwd`` once a layer and the
 backward pass reads what the first pass wrote, in float32 as it was made
-(``kept_bytes``: 48 KB a token at 32 heads of 128), where recomputing them
-ran the kernel a second time. q, k, v, g, b are residuals too and are not
+(``kept_bytes``: 80 KB a token at 32 heads of 128: o 16, u 16, the scores
+16, the states 32), where recomputing them ran the kernel a second time.
+All four are residuals of the ``custom_vjp``; one left unnamed would bring
+the second run back. The form without ``save`` (nothing differentiated) writes o alone.
+q, k, v, g, b are residuals too and are not
 named: ``kda_inputs`` makes them again (3.7 ms a step in the Kimi cell for
 1.34 GB, PERF.md §7), from the projections' outputs, the input kernel's
 only residuals, of which the layers DO name and keep all but KDA's ``x Wv``
@@ -80,9 +93,10 @@ __all__ = ["supported", "kda_scan", "kda_scan_heads_major", "KEPT",
 
 CHUNK, SUB = 64, 8
 # the forward kernel's outputs by their ``checkpoint_name``: o (batch,
-# heads, time, V), and the chunks' entry states that the backward kernel
-# starts each chunk from
-KEPT = ("kda_scan.o", "kda_scan.states")
+# heads, time, V), the chunks' entry states that the backward kernel starts
+# each chunk from, the chunks' solved u (batch, heads, time, V) and their
+# decayed scores [P | kk_off] (batch, heads, time, 2 CHUNK)
+KEPT = ("kda_scan.o", "kda_scan.states", "kda_scan.u", "kda_scan.scores")
 _HI = lax.Precision.HIGHEST
 _F32 = jnp.float32
 # heads a grid step: amortises the step's fixed cost (0.35 us)
@@ -117,14 +131,16 @@ def supported(q, k, v, g, b, chunk: int, sub: int) -> bool:
 
 
 def kept_bytes(time: int, heads: int, head_dim: int, chunk: int) -> int:
-    """Bytes of ``KEPT`` for one sequence of ``time`` steps: o (time, heads,
-    V) and the (heads, chunks, K, K) entry states, float32, at the length
-    the kernels run at; 0 for a head or a chunk the kernels do not take
-    (the ``jax.numpy`` form names nothing)."""
+    """Bytes of ``KEPT`` for one sequence of ``time`` steps: o and u (time,
+    heads, V) each, the scores (time, heads, 2 CHUNK) and the (heads,
+    chunks, K, K) entry states, float32, at the length the kernels run at;
+    0 for a head or a chunk the kernels do not take (the ``jax.numpy`` form
+    names nothing)."""
     if chunk != CHUNK or not _whole_lanes(head_dim):
         return 0
     padded = time + (-time) % CHUNK
-    return 4 * heads * head_dim * (padded + padded // CHUNK * head_dim)
+    return 4 * heads * (head_dim * (2 * padded + padded // CHUNK * head_dim)
+                        + 2 * CHUNK * padded)
 
 
 def _trace_time_choices():
@@ -313,24 +329,29 @@ def _strictly_lower(c: int):
     return _iota((c, c), 0) > _iota((c, c), 1)
 
 
+def _strictly_below(cols):
+    """Diagonal blocks' columns with the diagonal zeroed too (i < r)."""
+    _, row_in_block = _block_masks(cols[0].shape[0])
+    return [jnp.where(row_in_block > i, col, 0.0)
+            for i, col in enumerate(cols)]
+
+
 def _chunk_terms(q, k, g, exact: bool):
     """What a chunk needs before its state: the running sum G, the decayed
     scores P (i <= r) of q with k as a matrix, and those of k with itself
     (i < r) as the part below the diagonal blocks (a matrix) and the
     diagonal blocks (columns)."""
-    _, row_in_block = _block_masks(k.shape[0])
     g_cum = _running_sum(g)
     p_cols, kk_cols = _diag_columns([q, k], k, g_cum)
     p_off, kk_off = _below_blocks([q, k], k, g_cum, exact)
-    kk_cols = [jnp.where(row_in_block > i, col, 0.0)
-               for i, col in enumerate(kk_cols)]
-    return g_cum, p_off + _placed(p_cols), kk_off, kk_cols
+    return g_cum, p_off + _placed(p_cols), kk_off, _strictly_below(kk_cols)
 
 
 @functools.partial(jax.jit, static_argnames="exact")
 def chunk_forward(q, k, v, g, b, st, exact: bool):
     """One chunk of one head from its entry state ``st`` (V, K): the
-    chunk's output (C, V) and the exit state."""
+    chunk's output (C, V), the exit state, and what the backward pass
+    reads: the solved u (C, V) and the decayed scores [P | kk_off] (C, 2 C)."""
     g_cum, p, kk_off, kk_cols = _chunk_terms(q, k, g, exact)
     gamma = jnp.exp(g_cum)
     g_end = g_cum[-1:, :]
@@ -339,16 +360,20 @@ def chunk_forward(q, k, v, g, b, st, exact: bool):
     o = _dot(q * gamma, st, exact, _NT) + _dot(p, u, exact)
     k_end = k * jnp.exp(g_end - g_cum)
     st = st * jnp.exp(g_end) + _dot(u, k_end, exact, _TN)
-    return o, st
+    return o, st, u, jnp.concatenate([p, kk_off], 1)
 
 
 @functools.partial(jax.jit, static_argnames="exact")
-def chunk_backward(q, k, v, g, b, st, do, dst, exact: bool):
+def chunk_backward(q, k, v, g, b, st, u, scores, do, dst, exact: bool):
     """The chunk's gradients from dO (C, V) and the gradient ``dst`` of
-    its exit state: (dq, dk, dv, dg, db, d entry state). The chunk's terms
-    are made again from its inputs and its entry state."""
+    its exit state: (dq, dk, dv, dg, db, d entry state). The solved ``u``
+    and ``scores`` = [P | kk_off] are ``chunk_forward``'s (neither the
+    forward substitution nor the products below the diagonal blocks are run
+    again); k's diagonal blocks are made again, as columns."""
     c = k.shape[0]
-    g_cum, p, kk_off, kk_cols = _chunk_terms(q, k, g, exact)
+    g_cum = _running_sum(g)
+    p, kk_off = scores[:, :c], scores[:, c:]
+    kk_cols = _strictly_below(_diag_columns([k], k, g_cum)[0])
     a_off, a_cols = kk_off * b, [col * b for col in kk_cols]
     gamma = jnp.exp(g_cum)
     g_end = g_cum[-1:, :]
@@ -356,7 +381,6 @@ def chunk_backward(q, k, v, g, b, st, do, dst, exact: bool):
     decay = jnp.exp(g_end)
     kg, qg, k_end = k * gamma, q * gamma, k * tail
     resid = v - _dot(kg, st, exact, _NT)
-    u = _solve_lower(a_off, a_cols, b * resid)
 
     # o = qg S + P u;  S' = decay S + k_end^T u   (S = st^T)
     du = _dot(p, do, exact, _TN) + _dot(k_end, dst, exact, _NT)
@@ -462,6 +486,8 @@ def _fwd_kernel(hb, exact, save, q_ref, k_ref, v_ref, g_ref, b_ref,
                 o_ref, *rest):
     from jax.experimental import pallas as pl
     st_ref = rest[-1]
+    # ``save``: the windows of what the backward kernel reads
+    states_ref, u_ref, scores_ref = rest[:-1] if save else (None,) * 3
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -472,19 +498,23 @@ def _fwd_kernel(hb, exact, save, q_ref, k_ref, v_ref, g_ref, b_ref,
     def head(h):
         st = st_ref[h]
         if save:
-            rest[0][0, h, 0] = st
-        o, st = chunk_forward(
+            states_ref[0, h, 0] = st
+        o, st, u, scores = chunk_forward(
             q_ref[0, h].astype(_F32), k_ref[0, h].astype(_F32),
             v_ref[0, h].astype(_F32), g_ref[0, h],
             _head_column(b_all, h), st, exact)
         o_ref[0, h] = o
         st_ref[h] = st
+        if save:
+            u_ref[0, h] = u
+            scores_ref[0, h] = scores
 
     _over_heads(hb, head)
 
 
-def _bwd_kernel(hb, exact, q_ref, k_ref, v_ref, g_ref, b_ref, s_ref,
-                do_ref, dq_ref, dk_ref, dv_ref, dg_ref, db_ref, dst_ref):
+def _bwd_kernel(hb, exact, q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, u_ref,
+                sc_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref, db_ref,
+                dst_ref):
     from jax.experimental import pallas as pl
 
     @pl.when(pl.program_id(2) == 0)
@@ -499,8 +529,8 @@ def _bwd_kernel(hb, exact, q_ref, k_ref, v_ref, g_ref, b_ref, s_ref,
         dq, dk, dv, dg, db, dst = chunk_backward(
             q_ref[0, h].astype(_F32), k_ref[0, h].astype(_F32),
             v_ref[0, h].astype(_F32), g_ref[0, h],
-            _head_column(b_all, h), s_ref[0, h, 0], do_ref[0, h],
-            dst_ref[h], exact)
+            _head_column(b_all, h), s_ref[0, h, 0], u_ref[0, h],
+            sc_ref[0, h], do_ref[0, h], dst_ref[h], exact)
         dq_ref[0, h] = dq.astype(dq_ref.dtype)
         dk_ref[0, h] = dk.astype(dk_ref.dtype)
         dv_ref[0, h] = dv.astype(dv_ref.dtype)
@@ -536,8 +566,9 @@ def _call(name, kernel, interpret, grid, in_specs, out_specs, out_shape,
 
 
 def _specs(shape, hb: int, reverse: bool):
-    """Windows of the (B, H, T, K) arrays, of b by head group and of the
-    (B, H, N, V, K) entry states for grid (batch, head group, chunk)."""
+    """Windows of the (B, H, T, K) arrays, of b by head group, of the
+    (B, H, N, V, K) entry states and of the (B, H, T, 2 CHUNK) scores for
+    grid (batch, head group, chunk)."""
     from jax.experimental import pallas as pl
     bsz, t, h, kd = shape
     n = t // CHUNK
@@ -549,7 +580,9 @@ def _specs(shape, hb: int, reverse: bool):
     col = pl.BlockSpec((1, 1, CHUNK, hb), lambda i, j, c: (i, j, at(c), 0))
     state = pl.BlockSpec((1, hb, 1, kd, kd),
                          lambda i, j, c: (i, j, at(c), 0, 0))
-    return (bsz, h // hb, n), wide, col, state
+    pair = pl.BlockSpec((1, hb, CHUNK, 2 * CHUNK),
+                        lambda i, j, c: (i, j, at(c), 0))
+    return (bsz, h // hb, n), wide, col, state, pair
 
 
 def _windows(q, k, v, g, heads_major: bool):
@@ -571,13 +604,14 @@ def _forward(q, k, v, g, b, save: bool, exact: bool, interpret: bool,
     flat, shape = _windows(q, k, v, g, heads_major)
     bsz, t, h, kd = shape
     hb = _heads_a_step(h)
-    grid, wide, col, state = _specs(shape, hb, reverse=False)
+    grid, wide, col, state, pair = _specs(shape, hb, reverse=False)
     out_shape = [jax.ShapeDtypeStruct((bsz, h, t, kd), _F32)]
     out_specs = [wide]
     if save:
-        out_shape.append(jax.ShapeDtypeStruct(
-            (bsz, h, t // CHUNK, kd, kd), _F32))
-        out_specs.append(state)
+        out_shape += [jax.ShapeDtypeStruct((bsz, h, t // CHUNK, kd, kd), _F32),
+                      out_shape[0],
+                      jax.ShapeDtypeStruct((bsz, h, t, 2 * CHUNK), _F32)]
+        out_specs += [state, wide, pair]
     outs = _call(
         "kda_scan_fwd", functools.partial(_fwd_kernel, hb, exact, save),
         interpret, grid, [wide] * 4 + [col], out_specs, out_shape,
@@ -585,28 +619,28 @@ def _forward(q, k, v, g, b, save: bool, exact: bool, interpret: bool,
     if not save:
         return jnp.swapaxes(outs[0], 1, 2)
     # named as the kernel wrote them (the module's last paragraph)
-    o, states = map(checkpoint_name, outs, KEPT)
-    return jnp.swapaxes(o, 1, 2), states
+    o, *saved = map(checkpoint_name, outs, KEPT)
+    return jnp.swapaxes(o, 1, 2), *saved
 
 
 @functools.partial(jax.jit, static_argnames=("exact", "interpret",
                                              "heads_major"))
-def _backward(q, k, v, g, b, states, do, exact: bool, interpret: bool,
-              heads_major: bool = False):
+def _backward(q, k, v, g, b, states, u, scores, do, exact: bool,
+              interpret: bool, heads_major: bool = False):
     from jax.experimental.pallas import tpu as pltpu
     flat, shape = _windows(q, k, v, g, heads_major)
     bsz, t, h, kd = shape
     hb = _heads_a_step(h)
-    grid, wide, col, state = _specs(shape, hb, reverse=True)
+    grid, wide, col, state, pair = _specs(shape, hb, reverse=True)
     like = jax.ShapeDtypeStruct((bsz, h, t, kd), q.dtype)
     out_shape = [like, like, like,
                  jax.ShapeDtypeStruct((bsz, h, t, kd), _F32),
                  jax.ShapeDtypeStruct((bsz, h // hb, t, hb), _F32)]
     dq, dk, dv, dg, db = _call(
         "kda_scan_bwd", functools.partial(_bwd_kernel, hb, exact),
-        interpret, grid, [wide] * 4 + [col, state, wide], [wide] * 4 + [col],
-        out_shape, [pltpu.VMEM((hb, kd, kd), _F32)])(
-            *flat, _by_head_group(b, hb), states,
+        interpret, grid, [wide] * 4 + [col, state, wide, pair, wide],
+        [wide] * 4 + [col], out_shape, [pltpu.VMEM((hb, kd, kd), _F32)])(
+            *flat, _by_head_group(b, hb), states, u, scores,
             jnp.swapaxes(do.astype(_F32), 1, 2))
     if not heads_major:
         dq, dk, dv, dg = (jnp.swapaxes(a, 1, 2) for a in (dq, dk, dv, dg))
@@ -620,9 +654,9 @@ def _scan(heads_major: bool, doc: str):
                         heads_major=heads_major)
 
     def fwd(q, k, v, g, b):
-        o, states = _forward(q, k, v, g, b, True, *_trace_time_choices(),
+        o, *saved = _forward(q, k, v, g, b, True, *_trace_time_choices(),
                              heads_major=heads_major)
-        return o, (q, k, v, g, b, states)
+        return o, (q, k, v, g, b, *saved)
 
     def bwd(res, do):
         return _backward(*res, do, *_trace_time_choices(),

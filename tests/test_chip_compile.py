@@ -121,8 +121,8 @@ def _grouped_experts(S):
 def _kda_scan(S):
     # Kimi Delta Attention's chunked scan at the Kimi Linear share's shape,
     # one sequence of 8192 steps, 32 heads of 128: the forward kernel that
-    # saves the chunks' entry states and the backward kernel, through
-    # chunked_kda's own selection
+    # saves the chunks' entry states, solved u and scores, and the backward
+    # kernel that reads them, through chunked_kda's own selection
     from deeplearning4j_tpu.nn.conf.linear_attention import chunked_kda
     from deeplearning4j_tpu.perf.pallas import kda
     shape = (1, 8192, 32, 128)
@@ -586,6 +586,59 @@ def test_the_state_space_scan_compiles_for_the_chip(v5e, tpu_backend):
     assert " while(" not in text
     assert not re.search(r"\[(\d+,)+256,256\]", text)   # no factors in HBM
     assert compiled.memory_analysis().temp_size_in_bytes < 0.45e9
+
+
+@pytest.mark.parametrize("kind", ["KimiDeltaAttention", "GatedDeltaNet"])
+def test_a_rematerialised_delta_rule_layer_runs_each_scan_kernel_once(
+        kind, v5e, tpu_backend):
+    """A delta-rule layer's gradient program under ``remat="full"``, lowered
+    and compiled for the described v5e (32 heads of 128 as in the cells,
+    1,024 steps): ONE ``kda_scan_fwd``, in its ``save`` form (o, the chunks'
+    entry states, the chunks' solved u, their scores [P | kk_off]), and ONE
+    ``kda_scan_bwd`` of nine operands (q, k, v, g, b, the states, u, the
+    scores, dO). A residual that was not named would show as a second
+    ``kda_scan_fwd`` (the rematerialised first pass)."""
+    import re
+
+    from deeplearning4j_tpu.nn.conf import InputType
+    from deeplearning4j_tpu.nn.conf import linear_attention as la
+    from deeplearning4j_tpu.nn.conf.layers import apply_layer
+    layer = (la.KimiDeltaAttention(n_heads=32, head_dim=128, low_rank=128,
+                                   remat="full")
+             if kind == "KimiDeltaAttention" else
+             la.GatedDeltaNet(n_key_heads=16, n_value_heads=32, head_dim=128,
+                              remat="full"))
+    assert {"kda_scan.u", "kda_scan.scores"} < set(layer.remat_keeps)
+    it = InputType.recurrent(2048, 1024)
+    params, state = jax.eval_shape(
+        lambda: layer.init(jax.random.key(0), it))
+
+    def S(a, dtype=BF16):
+        return jax.ShapeDtypeStruct(a.shape, dtype, sharding=v5e)
+
+    def loss(p, x):
+        out, _ = apply_layer(layer, p, state, x, train=True, rng=None,
+                             mask=None, name="mix")
+        return jnp.sum(out.astype(F32))
+
+    lowered = jax.jit(jax.grad(loss)).lower(
+        jax.tree.map(S, params), S(jax.ShapeDtypeStruct((1, 1024, 2048),
+                                                        BF16)))
+    text = lowered.as_text()
+    for kernel in ("kda_scan_fwd", "kda_scan_bwd"):
+        assert len(re.findall(rf'kernel_name = "{kernel}"', text)) == 1, kernel
+    compiled = lowered.compile().as_text()
+    wide, states = "f32[1,32,1024,128]", "f32[1,32,16,128,128]"
+    calls = {kernel: line.split(" custom-call(")
+             for line in compiled.splitlines()
+             for kernel in ("kda_scan_fwd", "kda_scan_bwd")
+             if "tpu_custom_call" in line and f"%{kernel}" in line}
+    results, _ = calls["kda_scan_fwd"]
+    assert re.findall(r"\w+\[[\d,]+\]", results) == [wide, states, wide,
+                                                         wide]
+    _, operands = calls["kda_scan_bwd"]
+    operands = operands.split("), custom_call_target")[0]
+    assert operands.count("%") == 9      # q k v g b states u scores dO
 
 
 def test_every_auto_family_has_a_case(tpu_backend):

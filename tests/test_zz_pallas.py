@@ -398,11 +398,12 @@ def test_kda_chunk_forward_and_backward_are_chunk_terms_and_step(
         decay, exact_products):
     q, k, v, g, b, state, do, dstate = _kda_chunk(1, decay)
     want, vjp = jax.vjp(jax.jit(_jnp_chunk), q, k, v, g, b, state)
-    for a, w in zip(jax.jit(kda.chunk_forward, static_argnums=6)(
-            q, k, v, g, b, state, True), want):
+    o, exit_state, u, scores = jax.jit(kda.chunk_forward, static_argnums=6)(
+        q, k, v, g, b, state, True)
+    for a, w in zip((o, exit_state), want):
         _close(a, w)
-    got = jax.jit(kda.chunk_backward, static_argnums=8)(
-        q, k, v, g, b, state, do, dstate, True)
+    got = jax.jit(kda.chunk_backward, static_argnums=10)(
+        q, k, v, g, b, state, u, scores, do, dstate, True)
     for a, w in zip(got, vjp((do, dstate))):
         assert np.all(np.isfinite(a))
         _close(a, w)
@@ -459,6 +460,61 @@ def test_kda_block_solves_are_solve_unit_lower_and_its_transpose(
                         rhs)
     _close(jax.jit(kda._solve_lower)(a_off, a_cols, rhs), want, 5e-5)
     _close(jax.jit(kda._solve_upper)(a_off, a_cols, dx), vjp(dx)[0], 5e-5)
+
+
+def _backward_that_solves_again(q, k, v, g, b, st, u, scores, do, dst, exact):
+    """``chunk_backward`` as it was before PR 49: it takes nothing of the
+    chunk from the forward pass, makes the chunk's terms again and runs the
+    forward substitution a second time."""
+    g_cum, p, kk_off, kk_cols = kda._chunk_terms(q, k, g, exact)
+    resid = v - kda._dot(k * jnp.exp(g_cum), st, exact, kda._NT)
+    u = kda._solve_lower(kk_off * b, [col * b for col in kk_cols], b * resid)
+    return _kept_chunk_backward(q, k, v, g, b, st, u,
+                                jnp.concatenate([p, kk_off], 1), do, dst,
+                                exact)
+
+
+_kept_chunk_backward = kda.chunk_backward
+
+
+@pytest.mark.parametrize("heads,head_dim", [(2, 128), (3, 256)])
+@pytest.mark.parametrize("dtype,exact", [(jnp.bfloat16, False),
+                                         (jnp.float32, True)],
+                         ids=["bfloat16", "exact"])
+def test_kda_backward_kernel_reads_u_and_equals_the_solve_run_again(
+        heads, head_dim, dtype, exact, monkeypatch):
+    """The backward kernel's five outputs with ``u`` and the scores
+    ``[P | kk_off]`` read from the forward kernel's outputs, against the same
+    kernel with the parent's chunk rule (the chunk's terms and the solve made
+    again from the chunk's inputs and entry state): equal, at two sequences
+    of three chunks, heads of 128 and 256, bfloat16 operands with bfloat16
+    products and float32 with exact ones. What is read is what the forward
+    pass made (the patched rule ignores zeros handed in their place), and
+    the form without ``save`` writes o alone."""
+    shape = (2, 192, heads, head_dim)
+    ks = jax.random.split(jax.random.key(49), 6)
+    k = jax.random.normal(ks[1], shape)
+    q, k, v = (a.astype(dtype) for a in (
+        0.3 * jax.random.normal(ks[0], shape),
+        k / jnp.linalg.norm(k, axis=-1, keepdims=True),
+        jax.random.normal(ks[2], shape)))
+    g = -jax.random.uniform(ks[3], shape)
+    b = jax.random.uniform(ks[4], shape[:3])
+    do = jax.random.normal(ks[5], shape)
+    o, states, u, scores = kda._forward(q, k, v, g, b, True, exact, True)
+    assert u.shape == (2, heads, 192, head_dim) and u.dtype == jnp.float32
+    assert scores.shape == (2, heads, 192, 128) and scores.dtype == u.dtype
+    assert np.array_equal(np.asarray(o), np.asarray(
+        kda._forward(q, k, v, g, b, False, exact, True)))
+    got = kda._backward(q, k, v, g, b, states, u, scores, do, exact, True)
+    monkeypatch.setattr(kda, "chunk_backward", _backward_that_solves_again)
+    # a fresh function: jax's trace cache would serve the trace above
+    want = jax.jit(lambda *a: kda._backward.__wrapped__(*a, exact, True))(
+        q, k, v, g, b, states, jnp.zeros_like(u), jnp.zeros_like(scores), do)
+    for a, w in zip(got, want):
+        assert a.dtype == w.dtype and np.all(np.isfinite(
+            np.asarray(a, np.float32)))
+        assert np.array_equal(np.asarray(a), np.asarray(w))
 
 
 def _kda_layer_grads(layer, t, seed=0, dtype=jnp.float32, batch=1):

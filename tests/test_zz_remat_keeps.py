@@ -168,8 +168,9 @@ def test_a_rematerialised_layer_runs_its_kept_kernel_once(
     ``kda_scan_fwd`` / ``mla_attend_fwd``, where the parent ran two);
     ``"nothing_saveable"`` keeps nothing and runs it twice; without
     ``remat`` nothing is recomputed. ``remat.kept_values`` counts the names
-    a layer application's policy holds: three a delta-rule layer (the
-    projections' outputs share one), five a latent layer."""
+    a layer application's policy holds: five a delta-rule layer (the scan's
+    o, states, u and scores; the projections' outputs share one), five a
+    latent layer."""
     loss, params, x = _loss_of(kind, remat=remat)
     _, _, writes, reads = _SCOPES[kind]
     want = {writes: forwards, reads: 1}
@@ -179,7 +180,7 @@ def test_a_rematerialised_layer_runs_its_kept_kernel_once(
     with pk.override(enabled=True, interpret=True):
         found = _kernels(jax.make_jaxpr(jax.grad(loss))(params, x).jaxpr)
     assert found == want
-    names = 3 if kind in _DELTA_RULE else 5
+    names = 5
     assert len(_LAYERS[kind].remat_keeps) == names
     assert _kept_counter() - before == (names if counted else 0)
 
@@ -699,17 +700,22 @@ _CELL_LAYERS = {
 
 
 def test_kept_bytes_at_the_kimi_cell_s_shape():
-    """The scan's share, in both cells: o (8192, 32, 128) and the states
-    (32, 128, 128, 128), float32 whatever the network computes in: 134 +
-    268 MB a layer, 48 KB a token."""
+    """The scan's share, in both cells: o, u and the scores [P | kk_off]
+    (8192, 32, 128) each and the states (32, 128, 128, 128), float32
+    whatever the network computes in: 3 x 134 + 268 MB a layer, 80 KB a
+    token."""
     it = InputType.recurrent(2304, 8192)
     kimi, qwen = _CELL_LAYERS["kimi"], _CELL_LAYERS["qwen"]
-    assert kda_kernels.kept_bytes(8192, 32, 128, 64) == 402_653_184 \
-        == 134_217_728 + 268_435_456
+    assert kda_kernels.kept_bytes(8192, 32, 128, 64) == 671_088_640 \
+        == 3 * 134_217_728 + 268_435_456
     for layer, columns in ((kimi, 2 * 4096 + 128), (qwen, 12288)):
         assert layer.remat_kept_bytes(it, jnp.bfloat16) \
-            - 8192 * columns * 2 == 402_653_184
-    assert kda_kernels.kept_bytes(8192, 32, 128, 64) // 8192 == 48 * 1024
+            - 8192 * columns * 2 == 671_088_640
+    assert kda_kernels.kept_bytes(8192, 32, 128, 64) // 8192 == 80 * 1024
+    # the scores are 2 x 64 wide whatever the head: 256-wide heads keep
+    # o and u at 256 and the states at 256 x 256
+    assert kda_kernels.kept_bytes(64, 1, 256, 64) == 4 * (
+        2 * 64 * 256 + 64 * 128 + 256 * 256)
     # a length that is padded to whole chunks; a head the kernels refuse
     assert kda_kernels.kept_bytes(100, 2, 128, 64) \
         == kda_kernels.kept_bytes(128, 2, 128, 64)
@@ -725,6 +731,36 @@ def test_kept_bytes_at_the_kimi_cell_s_shape():
         == kda_kernels.kept_bytes(128, 32, 128, 64) + 128 * (2 * 4096 + 128) * 4
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kind", _DELTA_RULE)
+def test_kept_bytes_are_the_bytes_of_the_values_named(kind, dtype):
+    """``remat_kept_bytes`` against the layer's own gradient trace under
+    the kernels (without ``remat``, where every named value is made once):
+    the values that carry one of ``remat_keeps``'s names (the scan's o, its
+    chunk states, the chunks' solved u and their scores [P | kk_off] in
+    float32, the projections' outputs in the network's type) add up to it,
+    and every name is there."""
+    layer = _LAYERS[kind]
+    it = InputType.recurrent(_WIDTH, _TIME[kind])
+    params, state = layer.init(jax.random.key(0), it)
+    params = jax.tree.map(lambda a: a.astype(dtype), params)
+    x = jnp.zeros((1, _TIME[kind], _WIDTH), dtype)
+    with pk.override(enabled=True, interpret=True):
+        jaxpr = jax.make_jaxpr(jax.grad(lambda p, xx: jnp.sum(apply_layer(
+            layer, p, state, xx, train=True, rng=None, mask=None,
+            name="mix")[0].astype(jnp.float32))))(params, x).jaxpr
+    named = [(eqn.params["name"], eqn.outvars[0].aval)
+             for eqn in _equations(jaxpr) if eqn.primitive.name == "name"]
+    assert {name for name, _ in named} == set(layer.remat_keeps) \
+        == set(kda_kernels.KEPT + la.PROJECTIONS_KEPT)
+    for name in ("kda_scan.u", "kda_scan.scores"):
+        read, = [aval for n, aval in named if n == name]
+        assert read.shape == (1, 2, _TIME[kind], 128)
+        assert read.dtype == jnp.float32
+    assert sum(aval.size * aval.dtype.itemsize for _, aval in named) \
+        == layer.remat_kept_bytes(it, dtype)
+
+
 @pytest.mark.parametrize("cell,width,projections,a_token", [
     # x Wq, x Wk (8192, 4096) and the decay's latent (8192, 128)
     ("kimi", 2304, 136_314_880, 16_640),
@@ -734,13 +770,13 @@ def test_projections_kept_bytes_at_the_cells_shapes(cell, width, projections,
                                                     a_token):
     """What the named projections add to a delta-rule layer's kept bytes at
     the cells' shapes (1 x 8192 tokens, bfloat16): 136.3 MB a Kimi layer,
-    201.3 MB a Qwen layer, 16.6 and 24.6 KB a token beside the scan's 48
+    201.3 MB a Qwen layer, 16.6 and 24.6 KB a token beside the scan's 80
     KB; in float32 twice that; and ``conf.memory_report()`` prints the
     sum."""
     from deeplearning4j_tpu.nn.conf.recurrent import RnnOutputLayer
     layer = _CELL_LAYERS[cell]
     it = InputType.recurrent(width, 8192)
-    scan = 402_653_184
+    scan = 671_088_640
     assert layer.remat_kept_bytes(it, jnp.bfloat16) \
         == layer.remat_kept_bytes(it, "bfloat16") == scan + projections
     assert layer.remat_kept_bytes(it) == scan + 2 * projections
@@ -757,15 +793,16 @@ def test_projections_kept_bytes_at_the_cells_shapes(cell, width, projections,
 
 
 @pytest.mark.parametrize("kind,dtype,want,printed", [
-    # o and the two chunks' states, float32 whatever the network's type,
-    # and the projections' 2 x 256 + 8 columns in the network's type
-    ("kda", "float32", 4 * 2 * 128 * (128 + 2 * 128) + 4 * 128 * 520,
-     "keeps 644.0 KB/ex"),
-    ("kda", "bfloat16", 4 * 2 * 128 * (128 + 2 * 128) + 2 * 128 * 520,
-     "keeps 514.0 KB/ex"),
+    # o, u, the scores and the two chunks' states, float32 whatever the
+    # network's type, and the projections' 2 x 256 + 8 columns in the
+    # network's type
+    ("kda", "float32", 4 * 2 * 128 * (3 * 128 + 2 * 128) + 4 * 128 * 520,
+     "keeps 900.0 KB/ex"),
+    ("kda", "bfloat16", 4 * 2 * 128 * (3 * 128 + 2 * 128) + 2 * 128 * 520,
+     "keeps 770.0 KB/ex"),
     # one product of 2 x (1 + 2) x 128 columns
-    ("gdn", "bfloat16", 4 * 2 * 128 * (128 + 2 * 128) + 2 * 128 * 768,
-     "keeps 576.0 KB/ex"),
+    ("gdn", "bfloat16", 4 * 2 * 128 * (3 * 128 + 2 * 128) + 2 * 128 * 768,
+     "keeps 832.0 KB/ex"),
     # o, q, k, v (64 wide each) in the network's type and a float32 a token
     # and head
     ("rmla", "bfloat16", 2 * 256 * (4 * 64 * 2 + 4), "keeps 258.0 KB/ex"),
