@@ -88,7 +88,8 @@ _N_OUT_OPTIONAL = ("TransformerEncoderBlock", "KimiDeltaAttention",
                    "GatedDeltaNet", "MultiHeadLatentAttention",
                    "GatedAttention", "RotaryAttention", "GatedFeedForward",
                    "RoutedExperts", "MultiTokenCombine", "GatedShortConv",
-                   "Mamba2Mixer")
+                   "Mamba2Mixer", "Mamba1Mixer", "GatedMemoryUnit",
+                   "DifferentialAttention")
 
 
 def _check_layer(layer, cur, name: str) -> List[ValidationIssue]:
@@ -411,7 +412,9 @@ def validate_graph(conf, *, eval_shape_check: bool = False,
                 f"vertex '{name}' has no inputs"))
             structurally_ok = False
         for i in in_names:
-            if i not in known_names:
+            # ``<vertex>.<value>``: a value a layer hands on beside its
+            # output (whether it hands on THAT value: shape inference below)
+            if conf.producer_of(i) not in known_names:
                 issues.append(ValidationIssue(
                     "unknown-input", f"'{name}'",
                     f"vertex '{name}' references unknown input '{i}' "
@@ -421,6 +424,14 @@ def validate_graph(conf, *, eval_shape_check: bool = False,
         ai = _vertex_arity_issue(obj, in_names, f"'{name}'")
         if ai is not None:
             issues.append(ai)
+        wanted = getattr(obj, "extra_inputs", ())
+        if isinstance(obj, Layer) and len(in_names) < 1 + len(wanted):
+            issues.append(ValidationIssue(
+                "layer-inputs", f"'{name}'",
+                f"{type(obj).__name__} reads {1 + len(wanted)} inputs (its "
+                f"own and {list(wanted)}), the vertex is wired to "
+                f"{len(in_names)}"))
+            structurally_ok = False
 
     for out in conf.network_outputs:
         if out not in conf.vertices:
@@ -445,7 +456,7 @@ def validate_graph(conf, *, eval_shape_check: bool = False,
     children: Dict[str, List[str]] = {n: [] for n in known_names}
     for name, (_, in_names) in conf.vertices.items():
         for i in in_names:
-            children[i].append(name)
+            children[conf.producer_of(i)].append(name)
     order: List[str] = []
     frontier = list(conf.network_inputs)
     while frontier:
@@ -485,7 +496,8 @@ def validate_graph(conf, *, eval_shape_check: bool = False,
         return issues
 
     # dangling vertices: output feeds nothing and is not a network output
-    consumed = {i for _, (_, ins) in conf.vertices.items() for i in ins}
+    consumed = {conf.producer_of(i)
+                for _, (_, ins) in conf.vertices.items() for i in ins}
     for name in conf.vertices:
         if name not in consumed and name not in conf.network_outputs:
             issues.append(ValidationIssue(
@@ -500,8 +512,18 @@ def validate_graph(conf, *, eval_shape_check: bool = False,
     final_types: Dict[str, object] = {}
     for name in order:
         obj, in_names = conf.vertices[name]
-        its = tuple(known[i] for i in in_names)
         disp = f"'{name}'"
+        missing = [i for i in in_names if i not in known]
+        if missing:
+            maker = conf.producer_of(missing[0])
+            issues.append(ValidationIssue(
+                "unknown-value", disp,
+                f"vertex '{name}' reads '{missing[0]}', which '{maker}' "
+                f"({type(conf.vertices[maker][0]).__name__}) does not hand "
+                "on (Layer.shared_values)"))
+            inference_ok = False
+            break
+        its = tuple(known[i] for i in in_names)
         if isinstance(obj, Layer):
             cur = its[0]
             try:
@@ -526,6 +548,9 @@ def validate_graph(conf, *, eval_shape_check: bool = False,
                     f"{e} (input {describe_type(cur)})"))
                 inference_ok = False
                 break
+            for key, kind in obj.shared_values(cur).items():
+                known[f"{name}.{key}"] = kind
+            issues.extend(_extra_input_issues(obj, its, in_names, disp))
         else:
             issues.extend(_merge_agreement_issues(obj, its, in_names, disp))
             if isinstance(obj, LastTimeStepVertex) \
@@ -567,6 +592,31 @@ def validate_graph(conf, *, eval_shape_check: bool = False,
             and not any(i.severity == "error" for i in issues):
         issues.extend(_eval_shape_check_graph(conf, batch))
     return issues
+
+
+def _extra_input_issues(layer, its, in_names, name: str):
+    """What a layer reads beside its own input has the width the layer's
+    type says (``extra_input_sizes``, where the type has one)."""
+    sizes = getattr(layer, "extra_input_sizes", None)
+    if sizes is None:
+        return []
+    out = []
+    # a layer whose fields NAME the value it reads is wired to that value
+    for ref, want in zip(in_names[1:],
+                         getattr(layer, "extra_input_refs", ())):
+        if ref != want:
+            out.append(ValidationIssue(
+                "extra-input", name,
+                f"{type(layer).__name__} names '{want}' in its fields, the "
+                f"vertex is wired to '{ref}'"))
+    for ref, kind, (key, want) in zip(in_names[1:], its[1:],
+                                      sizes(its[0]).items()):
+        if kind.flat_size() != want:
+            out.append(ValidationIssue(
+                "extra-input", name,
+                f"{type(layer).__name__} reads '{ref}' as its {key} at a "
+                f"width of {want}, the value is {describe_type(kind)}"))
+    return out
 
 
 def _tied_issue(conf, layer, cur, name: str) -> Optional[ValidationIssue]:

@@ -15,3 +15,4 @@ from deeplearning4j_tpu.models.mellum import Mellum2  # noqa: F401
 from deeplearning4j_tpu.models.joyai import JoyAIFlash  # noqa: F401
 from deeplearning4j_tpu.models.lfm2 import Lfm2Moe  # noqa: F401
 from deeplearning4j_tpu.models.granite_hybrid import GraniteHybrid  # noqa: F401
+from deeplearning4j_tpu.models.phi4_flash import Phi4Flash  # noqa: F401

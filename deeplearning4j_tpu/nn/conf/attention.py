@@ -1029,7 +1029,212 @@ class RotaryAttention(BaseLayer):
         return out, state
 
 
+def differential_lambda_init(layer_index: int) -> float:
+    """``0.8 - 0.6 exp(-0.3 i)`` at layer index i from 0 (Differential
+    Transformer, arXiv:2410.05258)."""
+    return 0.8 - 0.6 * math.exp(-0.3 * layer_index)
+
+
+def _key_bias_held(bias, start: int, width: int):
+    """``bias`` with its KEY columns (``width`` from ``start``) constant to
+    autodiff. A bias on the keys shifts every score of a row alike, so no
+    softmax moves and its gradient is identically zero; what arithmetic
+    leaves of it is rounding, which Adam would normalise into a walk of
+    learning-rate steps (in bfloat16 every step, in float32 hardly: the one
+    leaf on which the two would part for no reason in the mathematics)."""
+    return jnp.concatenate([
+        bias[:start], lax.stop_gradient(bias[start:start + width]),
+        bias[start + width:]])
+
+
+def differential(first, second, lam):
+    """``A1 - lambda A2``: the second softmax's output taken off the
+    first's."""
+    return first - lam * second
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class DifferentialAttention(BaseLayer):
+    """Differential attention (arXiv:2410.05258) over grouped heads, causal
+    or under a window, with no position term, as SambaY's decoders run it
+    (arXiv:2507.06607). With h = ``n_heads`` query heads and h_kv =
+    ``n_kv_heads`` key/value heads of d = ``head_dim`` (h and h_kv even, h a
+    multiple of h_kv):
+
+        [q | k | v] = x W_qkv + b_qkv       (h d + h_kv d + h_kv d columns)
+        query heads (2j, 2j+1) are q1_j, q2_j (j < h/2); key heads (2g,
+        2g+1) are k1_g, k2_g and V_g = [v_2g | v_2g+1], 2d wide (g <
+        h_kv/2); pair j reads group g = j // (h / h_kv)
+        A1_j = softmax(q1_j k1_g^T / sqrt(d) + mask) V_g,  A2_j likewise
+        lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init, four
+            d-vectors a layer, lambda_init = ``differential_lambda_init``
+            of ``layer_index`` (the PUBLISHED index of the layer)
+        o_j = (1 - lambda_init) RMSNorm_2d(A1_j - lambda A2_j)   (one 2d-wide
+            weight ``subln`` a layer, ``eps``)
+        out = [o_0 .. o_{h/2-1}] W_o + b_o
+
+    the mask causal (``window`` 0) or causal within ``window`` keys (the
+    query's own with them). With ``share_kv`` the layer hands its ``[k |
+    v]`` columns on as the value ``kv`` (``shared_values``); with
+    ``kv_from`` naming such a layer's vertex it is a CROSS-attention: it
+    owns ``W_q``, ``b_q`` alone of the three projections and reads
+    ``<kv_from>.kv`` as its second input (``extra_inputs``), under the
+    causal mask or its window. The KEY columns of ``b_qkv`` are held where they start (``_key_bias_held``:
+    their gradient is identically zero, and the layer hands Adam that zero
+    and not the rounding left of it).
+
+    The two softmaxes of every pair go through ONE
+    ``blocked_causal_attention`` call of h "heads": query head i against key
+    head 2 (i // (2 h / h_kv)) + i % 2 and its group's 2d-wide value, k and
+    V repeated over their group in front of it as ``RotaryAttention`` does,
+    widths (d | d | 2d): (64, 128) reaches the kernels of
+    ``perf/pallas/attention.py`` unchanged. Counted at trace time:
+    ``attention.differential`` once a layer,
+    ``attention.differential_windowed`` once a layer whose window is shorter
+    than the sequence, ``attention.shared_kv`` once a layer that reads
+    another's keys and values, beside ``kernel.pallas_blocked_attention`` /
+    ``kernel.xla_blocked_attention``. Scopes, forward and backward:
+    ``dattn.qkv``, ``dattn.attend`` (the repeat, the layout copies and the
+    attention), ``dattn.combine`` (lambda, the subtraction, the norm),
+    ``dattn.out``. A features mask zeroes the output at masked steps."""
+
+    n_in: Optional[int] = None
+    n_out: int = 0              # model width; inferred from the input when 0
+    n_heads: int = 4
+    n_kv_heads: int = 2
+    head_dim: int = 32
+    layer_index: int = 0        # published index: lambda_init reads it
+    window: int = 0             # 0: every key at or before the query
+    kv_from: str = ""           # a vertex whose keys and values are read
+    share_kv: bool = False      # hand [k | v] on as the value ``kv``
+    block: int = 512
+    eps: float = 1e-5           # of the norm after the subtraction
+    weight_init: str = "xavier_fan_in"
+
+    supports_stateful = False
+
+    @property
+    def extra_inputs(self):
+        return ("kv",) if self.kv_from else ()
+
+    @property
+    def extra_input_refs(self):
+        return (self.kv_from + ".kv",) if self.kv_from else ()
+
+    def extra_input_sizes(self, it: InputType):
+        return {"kv": 2 * self.n_kv_heads * self.head_dim}
+
+    def shared_values(self, it: InputType):
+        if not self.share_kv:
+            return {}
+        return {"kv": InputType.recurrent(
+            2 * self.n_kv_heads * self.head_dim, it.timeseries_length)}
+
+    def input_kind(self):
+        return "rnn"
+
+    def is_recurrent(self):
+        return True
+
+    def regularizable(self):
+        return ("Wq", "Wo") if self.kv_from else ("Wqkv", "Wo")
+
+    def _width(self, it: InputType) -> int:
+        return self.n_out or self.n_in or it.size
+
+    def output_type(self, it: InputType) -> InputType:
+        h, hkv = self.n_heads, self.n_kv_heads
+        if h % 2 or hkv % 2 or h % hkv:
+            raise ValueError(
+                f"{h} query heads over {hkv} key/value heads: both pair up "
+                "and the query heads are a multiple of the key/value heads")
+        if self.window < 0:
+            raise ValueError(f"a window of {self.window} keys")
+        if self.kv_from and self.share_kv:
+            raise ValueError("a layer that reads another's keys and values "
+                             "has none of its own to hand on")
+        return InputType.recurrent(self._width(it), it.timeseries_length)
+
+    def init(self, rng, it: InputType, dtype=jnp.float32):
+        d = self.n_in or it.size
+        h, hkv, dh = self.n_heads, self.n_kv_heads, self.head_dim
+        k_in, k_out, k_lam = jax.random.split(rng, 3)
+        cols = h * dh if self.kv_from else (h + 2 * hkv) * dh
+        name = "q" if self.kv_from else "qkv"
+
+        def dense(key, n_in, n_out):
+            return init_weights(key, (n_in, n_out), n_in, n_out,
+                                self.weight_init, self.dist, dtype)
+
+        params = {"W" + name: dense(k_in, d, cols),
+                  "b" + name: jnp.zeros((cols,), dtype),
+                  "Wo": dense(k_out, h * dh, self._width(it)),
+                  "bo": jnp.zeros((self._width(it),), dtype),
+                  "subln": jnp.ones((2 * dh,), dtype)}
+        # the four lambda vectors N(0, 0.1^2), as the paper draws them
+        for leaf, key in zip(("lambda_q1", "lambda_k1", "lambda_q2",
+                              "lambda_k2"), jax.random.split(k_lam, 4)):
+            params[leaf] = 0.1 * jax.random.normal(key, (dh,), dtype)
+        return params, {}
+
+    def apply(self, params, state, x, *, kv=None, train=False, rng=None,
+              mask=None):
+        from deeplearning4j_tpu.nn.conf.normalization import rms_norm
+        from deeplearning4j_tpu.perf.compile_watch import bump_active
+
+        x = dropout_input(x, self.dropout, train, rng)
+        bsz, t, _ = x.shape
+        h, hkv, dh = self.n_heads, self.n_kv_heads, self.head_dim
+        f32 = jnp.float32
+        bump_active("attention.differential")
+        with jax.named_scope("dattn.qkv"):
+            if self.kv_from:
+                bump_active("attention.shared_kv")
+                q = x @ params["Wq"] + params["bq"]
+            else:
+                qkv = x @ params["Wqkv"] + _key_bias_held(
+                    params["bqkv"], h * dh, hkv * dh)
+                q, kv = qkv[..., :h * dh], qkv[..., h * dh:]
+        window = self.window if 0 < self.window < t else None
+        if window:
+            bump_active("attention.differential_windowed")
+        with jax.named_scope("dattn.attend"):
+            # query head i = 4 g + 2 p + s reads key head 2 g + s (s: which
+            # softmax of the pair) and the group's 2d-wide value
+            per = 2 * h // hkv           # query heads a key/value group
+            k = kv[..., :hkv * dh].reshape(bsz, t, hkv // 2, 1, 2, dh)
+            k = jnp.broadcast_to(k, (bsz, t, hkv // 2, per // 2, 2, dh))
+            v = kv[..., hkv * dh:].reshape(bsz, t, hkv // 2, 1, 2 * dh)
+            v = jnp.broadcast_to(v, (bsz, t, hkv // 2, per, 2 * dh))
+            o = blocked_causal_attention(
+                q.reshape(bsz, t, h, dh).transpose(0, 2, 1, 3),
+                k.reshape(bsz, t, h, dh).transpose(0, 2, 1, 3),
+                v.reshape(bsz, t, h, 2 * dh).transpose(0, 2, 1, 3),
+                self.block, window)
+            o = o.transpose(0, 2, 1, 3).reshape(bsz, t, h // 2, 2, 2 * dh)
+        with jax.named_scope("dattn.combine"):
+            start = differential_lambda_init(self.layer_index)
+            lam = (jnp.exp(jnp.sum(params["lambda_q1"].astype(f32)
+                                   * params["lambda_k1"].astype(f32)))
+                   - jnp.exp(jnp.sum(params["lambda_q2"].astype(f32)
+                                     * params["lambda_k2"].astype(f32)))
+                   + start)
+            diff = differential(o[..., 0, :].astype(f32),
+                                o[..., 1, :].astype(f32), lam)
+            o = ((1.0 - start) * rms_norm(diff, params["subln"], self.eps)
+                 ).astype(x.dtype)
+        with jax.named_scope("dattn.out"):
+            out = o.reshape(bsz, t, h * dh) @ params["Wo"] + params["bo"]
+        if mask is not None:             # masked steps emit zeros
+            out = out * mask[..., None].astype(out.dtype)
+        if self.share_kv:
+            return (out, {"kv": kv}), state
+        return out, state
+
+
 __all__ = ["SelfAttentionLayer", "TransformerEncoderBlock",
            "MultiHeadLatentAttention", "GatedAttention", "RotaryAttention",
+           "DifferentialAttention", "differential_lambda_init",
            "rotate_interleaved",
            "blocked_causal_attention", "rotate_half_split", "yarn_inv_freq"]

@@ -838,6 +838,11 @@ class FusedConvBNActivation(BaseLayer):
     beta: float = 0.0
     residual: bool = False
 
+    @property
+    def extra_inputs(self):
+        # the residual-add operand is the vertex's second input
+        return ("res",) if self.residual else ()
+
     def input_kind(self):
         return "cnn"
 

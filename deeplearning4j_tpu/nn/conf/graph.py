@@ -362,6 +362,16 @@ class ComputationGraphConfiguration:
                 f"fwd={self.tbptt_fwd_length}, back={self.tbptt_back_length}. "
                 "Use equal lengths")
 
+    def producer_of(self, ref: str) -> str:
+        """The vertex (or network input) that makes what ``ref`` names: the
+        name itself, or for a value a layer hands on beside its output
+        (``"<vertex>.<value>"``: ``Layer.shared_values``) that layer's
+        vertex. A name that is neither is returned as it is."""
+        if ref in self.vertices or ref in self.network_inputs:
+            return ref
+        vertex = ref.rpartition(".")[0]
+        return vertex if vertex in self.vertices else ref
+
     # ---- topology (reference ComputationGraph.topologicalSortOrder :1190) ----
     def topological_order(self) -> List[str]:
         indeg = {}
@@ -369,6 +379,7 @@ class ComputationGraphConfiguration:
         for name, (_, inputs) in self.vertices.items():
             indeg[name] = len(inputs)
             for i in inputs:
+                i = self.producer_of(i)
                 if i not in children:
                     raise ValueError(f"Vertex '{name}' references unknown input '{i}'")
                 children[i].append(name)
@@ -401,6 +412,11 @@ class ComputationGraphConfiguration:
         pres = {}
         for name in self.topological_order():
             obj, inputs = self.vertices[name]
+            missing = [i for i in inputs if i not in known]
+            if missing:
+                raise ValueError(
+                    f"Vertex '{name}' reads {missing}: no value of that name "
+                    f"is handed on by '{self.producer_of(missing[0])}'")
             its = tuple(known[i] for i in inputs)
             if isinstance(obj, Layer):
                 pre = infer_preprocessor(its[0], obj)
@@ -409,6 +425,8 @@ class ComputationGraphConfiguration:
                     its = (pre.output_type(its[0]),) + its[1:]
                 types[name] = its
                 known[name] = obj.output_type(its[0])
+                for key, kind in obj.shared_values(its[0]).items():
+                    known[f"{name}.{key}"] = kind
             else:
                 types[name] = its
                 known[name] = obj.output_type(*its)

@@ -277,6 +277,20 @@ class Layer:
         network that computes in ``dtype``."""
         return 0
 
+    # a layer in a ``ComputationGraph`` may read more than one vertex and
+    # hand on more than one value. ``extra_inputs`` names the keyword by
+    # which ``apply`` takes each vertex input after the first (the fused
+    # conv block's residual-add operand ``res``, a gated memory unit's
+    # ``memory``, a cross-attention's ``kv``). ``shared_values`` names what
+    # ``apply`` hands on BESIDE its output, and each value's type: with any,
+    # ``apply`` returns ``((out, {name: value}), state)`` and a later vertex
+    # reads ``<vertex>.<name>`` as one of its inputs (``nn/graph.py``
+    # ``run_vertices``). Not fields: the type declares both from its fields.
+    extra_inputs = ()
+
+    def shared_values(self, input_type: InputType) -> dict:
+        return {}
+
     # ---- shape inference ----
     def output_type(self, input_type: InputType) -> InputType:
         return input_type

@@ -1,5 +1,5 @@
 """Normalization layers: BatchNormalization, LocalResponseNormalization,
-RMSNorm.
+RMSNorm, LayerNorm.
 
 Parity surface: reference ``nn/conf/layers/BatchNormalization.java`` +
 ``nn/layers/normalization/BatchNormalization.java:57`` (helper hook; cuDNN
@@ -159,3 +159,37 @@ class RMSNorm(BaseLayer):
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
         g = 1.0 + params["w"] if self.zero_centered else params["g"]
         return rms_norm(x, g, self.eps), state
+
+
+def layer_norm(x, weight, bias, eps: float):
+    """(x - mean) / sqrt(var + eps) * weight + bias over the last axis; mean
+    and variance are taken in float32 whatever x's type (the variance of the
+    centred values, not a difference of means), the result is x's type."""
+    x32 = x.astype(jnp.float32)
+    centred = x32 - jnp.mean(x32, -1, keepdims=True)
+    scale = lax.rsqrt(jnp.mean(jnp.square(centred), -1, keepdims=True) + eps)
+    return (centred * scale * weight.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(x.dtype)
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class LayerNorm(BaseLayer):
+    """Layer normalisation over the feature axis (Ba et al. 2016) with a
+    learned weight and bias (leaves ``weight`` started at one, ``bias`` at
+    zero): the pre-norm of the decoders that kept the mean subtraction.
+    Shape-preserving; statistics in float32 (``layer_norm``)."""
+
+    eps: float = 1e-5
+
+    def regularizable(self):
+        return ()
+
+    def init(self, rng, it: InputType, dtype=jnp.float32):
+        n = it.channels if it.kind == "cnn" else it.flat_size()
+        return {"weight": jnp.ones((n,), dtype),
+                "bias": jnp.zeros((n,), dtype)}, {}
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        return layer_norm(x, params["weight"], params["bias"],
+                          self.eps), state

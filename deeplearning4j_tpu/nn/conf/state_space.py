@@ -299,4 +299,275 @@ class Mamba2Mixer(BaseLayer):
         return out, state
 
 
-__all__ = ["Mamba2Mixer", "chunked_ssd", "gated_norm", "step_bias_start"]
+# ------------------------------------------------- the first generation
+# steps of a chunk an iteration of its loop runs: alone on the chip 1, 8, 16
+# and 64 read 44.4, 31.6, 33.7 and 184 ms forward + backward (PERF.md §6)
+_UNROLL = 8
+
+
+def _selective_chunk(a_t, skip, state, xs):
+    """One chunk of ``chunked_selective_scan`` met with the state at its
+    entry, its steps IN ORDER. ``state`` (B, N, C) float32: the states on
+    the sublanes, the channels on the lanes; ``xs`` the chunk's x and dt
+    (L, B, C) and B and C (L, B, N), time first; ``a_t`` (N, C) = A
+    transposed; ``skip`` (C,) = D. Returns the state at the chunk's exit
+    and its y (L, B, C) float32. A step is one pass over the (N, C) state
+    in registers: its decay is the exponential of a product that is at
+    most 0."""
+    f32 = jnp.float32
+
+    def step(s, row):
+        x, dt, bm, cm = (a.astype(f32) for a in row)
+        decay = jnp.exp(dt[:, None, :] * a_t)
+        s = decay * s + (dt * x)[:, None, :] * bm[:, :, None]
+        return s, jnp.sum(s * cm[:, :, None], axis=1) + skip * x
+
+    return lax.scan(step, state, xs, unroll=min(_UNROLL, xs[0].shape[0]))
+
+
+def chunked_selective_scan(x, dt, a_rate, bm, cm, chunk: int = 64,
+                           skip=None):
+    """Mamba-1's selective recurrence from a zero state, a decay for every
+    (channel, state) pair:
+
+        S_t[c, n] = exp(dt_t[c] A[c, n]) S_{t-1}[c, n] + dt_t[c] B_t[n] x_t[c]
+        y_t[c] = sum_n C_t[n] S_t[c, n] + D[c] x_t[c]
+
+    ``x``, ``dt`` (batch, time, channels) (dt after its softplus: >= 0),
+    ``a_rate`` (channels, N) (A: < 0), ``bm``, ``cm`` (batch, time, N),
+    ``skip`` (channels,) = D or None for none; any float type (the decays
+    and the state are float32). Returns y (batch, time, channels) float32.
+
+    With a decay a PAIR there is no ``C B^T`` masked by a difference of
+    running sums (``chunked_ssd``'s algebra): the work is 5,120 x 16
+    exponentials and multiply-adds a token on the vector units, with no
+    matrix product. Time is cut into chunks of ``chunk`` steps: one
+    ``lax.scan`` over the chunks carries the (N, channels) float32 state
+    and its body, a chunk's steps in order (``_selective_chunk``, ``_UNROLL``
+    steps an iteration of the inner loop), is rematerialised: no (time,
+    channels, N) array is ever alive (2.7 GB a layer at 8,192 tokens), the
+    backward pass holds time / chunk entry states and, while it runs a
+    chunk, that chunk's states. Decays are ``exp(dt A)`` of a non-positive
+    product, never a quotient of running products, so the form is exact at
+    any decay. ``time`` need not be a multiple of ``chunk`` (steps with dt
+    = 0 and x = 0 are appended: they leave the state as it is). Plain
+    ``lax``, XLA writing the backward pass, on every backend: counted
+    ``kernel.xla_selective_scan`` once a call."""
+    bsz, t, c = x.shape
+    n = bm.shape[-1]
+    if a_rate.shape != (c, n):
+        raise ValueError(f"A is {a_rate.shape}, not {(c, n)}")
+    if chunk < 1:
+        raise ValueError(f"a chunk of {chunk} steps")
+    bump_active("kernel.xla_selective_scan")
+    f32 = jnp.float32
+    length = min(chunk, t)
+    pad = (-t) % length
+    count = (t + pad) // length
+
+    def chunks(a):                       # (B, T, W) -> (count, L, B, W)
+        if pad:
+            a = jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
+        a = jnp.moveaxis(a, 1, 0)
+        return a.reshape((count, length) + a.shape[1:])
+
+    a_t = a_rate.astype(f32).T
+    d = (jnp.zeros((c,), f32) if skip is None else skip.astype(f32))
+    step = jax.checkpoint(lambda s, xs: _selective_chunk(a_t, d, s, xs))
+    s0 = jnp.zeros((bsz, n, c), f32)
+    _, y = lax.scan(step, s0, tuple(map(chunks, (x, dt, bm, cm))))
+    return jnp.moveaxis(y.reshape(count * length, bsz, c), 0, 1)[:, :t]
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class Mamba1Mixer(BaseLayer):
+    """Mamba's first-generation mixer (arXiv:2312.00752) over (batch, time,
+    features), with d_in = ``expand`` x the model width, N = ``state_size``,
+    K = ``conv_size`` taps and R = ``dt_rank`` (0: ceil(width / 16)):
+
+        [xr | z] = u W_in                     (2 d_in columns, no bias)
+        xc = SiLU(conv_K(xr) + b_conv)        (depthwise, causal)
+        [r | B | C] = xc W_x                  (R + N + N columns, no bias)
+        dt = softplus(r W_dt + dt_bias)       (R -> d_in)
+        A = -exp(A_log)                       (d_in, N)
+        y = the selective recurrence over xc, dt, A, B, C with D
+            (``chunked_selective_scan``, chunks of ``chunk`` steps)
+        out = (y * SiLU(z)) W_out             (no bias)
+
+    With ``share_scan`` the layer hands ``y``, BEFORE its gate, on as the
+    value ``scan`` beside its output (``shared_values``: a later
+    ``GatedMemoryUnit`` reads it as ``<vertex>.scan``). Scopes, forward and
+    backward: ``mamba1.in_proj``, ``mamba1.conv``, ``mamba1.dt_bc`` (the two
+    narrow products and the softplus), ``mamba1.scan``, ``mamba1.gate_out``;
+    a layer traced counts ``ssm.mamba1`` once. A features mask zeroes the
+    output at masked steps."""
+
+    n_in: Optional[int] = None
+    n_out: int = 0
+    expand: int = 2
+    state_size: int = 16
+    conv_size: int = 4
+    dt_rank: int = 0
+    chunk: int = 64
+    share_scan: bool = False
+    weight_init: str = "xavier_fan_in"
+
+    supports_stateful = False   # no rnn_time_step carry (yet)
+
+    def input_kind(self):
+        return "rnn"
+
+    def is_recurrent(self):
+        return True
+
+    def regularizable(self):
+        return ("Win", "conv", "Wx", "Wdt", "Wout")
+
+    def _width(self, it: InputType) -> int:
+        return self.n_out or self.n_in or it.size
+
+    def _sizes(self, d: int):
+        """(d_in, R) at a model width of ``d``."""
+        return self.expand * d, self.dt_rank or -(-d // 16)
+
+    def output_type(self, it: InputType) -> InputType:
+        if min(self.expand, self.state_size, self.conv_size, self.chunk) < 1:
+            raise ValueError(
+                f"expand {self.expand}, a state of {self.state_size}, "
+                f"{self.conv_size} taps, a chunk of {self.chunk} steps")
+        return InputType.recurrent(self._width(it), it.timeseries_length)
+
+    def shared_values(self, it: InputType):
+        if not self.share_scan:
+            return {}
+        inner, _ = self._sizes(self.n_in or it.size)
+        return {"scan": InputType.recurrent(inner, it.timeseries_length)}
+
+    def init(self, rng, it: InputType, dtype=jnp.float32):
+        d = self.n_in or it.size
+        inner, rank = self._sizes(d)
+        n, width = self.state_size, self._width(it)
+        k_in, k_conv, k_x, k_dt, k_b, k_out = jax.random.split(rng, 6)
+
+        def dense(key, n_in, n_out):
+            return init_weights(key, (n_in, n_out), n_in, n_out,
+                                self.weight_init, self.dist, dtype)
+
+        return {
+            "Win": dense(k_in, d, 2 * inner),
+            "conv": (jax.random.normal(k_conv, (self.conv_size, inner), dtype)
+                     / math.sqrt(self.conv_size)),
+            "conv_b": jnp.zeros((inner,), dtype),
+            "Wx": dense(k_x, inner, rank + 2 * n),
+            "Wdt": dense(k_dt, rank, inner),
+            # the public initialiser: A = 1..N a channel, D = 1
+            "dt_bias": step_bias_start(k_b, inner, dtype),
+            "A_log": jnp.broadcast_to(
+                jnp.log(jnp.arange(1, n + 1, dtype=dtype)), (inner, n)),
+            "D": jnp.ones((inner,), dtype),
+            "Wout": dense(k_out, inner, width),
+        }, {}
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        x = dropout_input(x, self.dropout, train, rng)
+        bump_active("ssm.mamba1")
+        inner, rank = params["Wdt"].shape[1], params["Wdt"].shape[0]
+        n = self.state_size
+        f32 = jnp.float32
+        with jax.named_scope("mamba1.in_proj"):
+            xz = x @ params["Win"]
+        with jax.named_scope("mamba1.conv"):
+            xc = jax.nn.silu(causal_depthwise_conv(xz[..., :inner],
+                                                   params["conv"])
+                             + params["conv_b"])
+        with jax.named_scope("mamba1.dt_bc"):
+            rbc = xc @ params["Wx"]
+            dt = jax.nn.softplus(
+                (rbc[..., :rank] @ params["Wdt"]).astype(f32)
+                + params["dt_bias"].astype(f32))
+        with jax.named_scope("mamba1.scan"):
+            y = chunked_selective_scan(
+                xc, dt, -jnp.exp(params["A_log"].astype(f32)),
+                rbc[..., rank:rank + n], rbc[..., rank + n:], self.chunk,
+                skip=params["D"]).astype(x.dtype)
+        with jax.named_scope("mamba1.gate_out"):
+            out = gated_memory(y, xz[..., inner:]) @ params["Wout"]
+        if mask is not None:             # masked steps emit zeros
+            out = out * mask[..., None].astype(out.dtype)
+        if self.share_scan:
+            return (out, {"scan": y}), state
+        return out, state
+
+
+def gated_memory(memory, gate):
+    """``memory * SiLU(gate)``, the gate's sigmoid in float32."""
+    return (memory.astype(jnp.float32)
+            * jax.nn.silu(gate.astype(jnp.float32))).astype(gate.dtype)
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class GatedMemoryUnit(BaseLayer):
+    """SambaY's gated memory unit (arXiv:2507.06607): a layer of the
+    cross-decoder that runs no scan of its own and gates another layer's,
+
+        out = (m * SiLU(x W_1)) W_2        (no bias)
+
+    with ``m`` (batch, time, ``memory_size``) a ``Mamba1Mixer``'s scan
+    output before its gate, read as this vertex's SECOND input (the value
+    ``<mixer's vertex>.scan``: ``extra_inputs``). ``W_1`` is width x
+    ``memory_size``, ``W_2`` ``memory_size`` x width. Scope ``gmu.gate``
+    (the gate alone; the two products lie under the layer's marker)."""
+
+    n_in: Optional[int] = None
+    n_out: int = 0
+    memory_size: int = 0
+    weight_init: str = "xavier_fan_in"
+
+    extra_inputs = ("memory",)
+
+    def extra_input_sizes(self, it: InputType):
+        return {"memory": self.memory_size}
+
+    def regularizable(self):
+        return ("W1", "W2")
+
+    def input_kind(self):
+        return "rnn"
+
+    def _width(self, it: InputType) -> int:
+        return self.n_out or self.n_in or it.size
+
+    def output_type(self, it: InputType) -> InputType:
+        if self.memory_size < 1:
+            raise ValueError("memory_size: the width of the memory read")
+        return InputType.recurrent(self._width(it), it.timeseries_length)
+
+    def init(self, rng, it: InputType, dtype=jnp.float32):
+        d = self.n_in or it.size
+        k1, k2 = jax.random.split(rng)
+        m, width = self.memory_size, self._width(it)
+        return {
+            "W1": init_weights(k1, (d, m), d, m, self.weight_init, self.dist,
+                               dtype),
+            "W2": init_weights(k2, (m, width), m, width, self.weight_init,
+                               self.dist, dtype),
+        }, {}
+
+    def apply(self, params, state, x, *, memory, train=False, rng=None,
+              mask=None):
+        x = dropout_input(x, self.dropout, train, rng)
+        bump_active("ssm.gated_memory")
+        gate = x @ params["W1"]
+        with jax.named_scope("gmu.gate"):
+            gated = gated_memory(memory, gate)
+        out = gated @ params["W2"]
+        if mask is not None:             # masked steps emit zeros
+            out = out * mask[..., None].astype(out.dtype)
+        return out, state
+
+
+__all__ = ["Mamba2Mixer", "Mamba1Mixer", "GatedMemoryUnit", "chunked_ssd",
+           "chunked_selective_scan", "gated_memory", "gated_norm",
+           "step_bias_start"]

@@ -45,7 +45,20 @@ def run_vertices(order, vertices, vpre, input_types, params, state, acts,
     state, new carries). The whole graph's forward pass
     and a ``LoopVertex``'s body both run here, so a layer is applied in one
     place (``apply_layer``: its ``<LayerClass>:<name>`` scope, its ``remat``
-    knob)."""
+    knob).
+
+    What a layer may read: its FIRST input as ``x`` (after the vertex's
+    preprocessor), and every further input by the keyword its type names for
+    it (``Layer.extra_inputs``: ``res``, ``memory``, ``kv``); a vertex that
+    is no layer reads all its inputs in order. What a vertex may read is
+    another vertex's output (``"l3_ffn"``) or a VALUE a layer hands on
+    beside its output (``"l16_ssm.scan"``, ``"l17_attn.kv"``:
+    ``Layer.shared_values``; such a layer's ``apply`` returns ``(out,
+    {name: value})`` and the values lie in ``acts`` under
+    ``<vertex>.<name>``). Autodiff sums a value's cotangents over its
+    readers, whichever layer made it; under a layer's ``remat`` the values
+    it hands on are outputs of its checkpoint like its output, so they are
+    kept once for all their readers."""
     new_state = {}
     new_carries = {}
     preouts = {}
@@ -87,17 +100,25 @@ def run_vertices(order, vertices, vpre, input_types, params, state, acts,
                 new_carries[name] = nc
                 new_state[name] = state[name]
             else:
-                # fused conv→BN→act blocks with residual=True take the
-                # residual-add operand as a second vertex input
-                extra = ({"res": xs[1]}
-                         if getattr(obj, "residual", False) and len(xs) > 1
-                         else None)
+                # the vertex's inputs after the first, by the keywords the
+                # layer's type names for them (``extra_inputs``: the fused
+                # conv block's residual-add operand, a memory, another
+                # layer's keys and values)
+                extra = dict(zip(obj.extra_inputs, xs[1:])) or None
                 # apply_layer lowers through jax.checkpoint when the
                 # layer's remat= knob is set (perf/fusion.py policies)
                 out, st = apply_layer(obj, p_v, state[name], xs[0],
                                       train=train, rng=k, mask=in_mask,
                                       name=name, extra=extra)
                 new_state[name] = st
+                shared = obj.shared_values(input_types[name][0])
+                if shared:
+                    # what the layer hands on beside its output: a later
+                    # vertex reads it as ``<name>.<value>``
+                    out, values = out
+                    for key in shared:
+                        acts[f"{name}.{key}"] = values[key]
+                        mask_of[f"{name}.{key}"] = in_mask
             out_kind = obj.output_type(input_types[name][0]).kind
             mask_of[name] = in_mask if out_kind in ("rnn", "cnn1d") else None
         else:

@@ -170,6 +170,10 @@ def get_memory_report(net, minibatch: int = 32,
     for i, (layer, it) in enumerate(zip(net.layers, types)):
         out_t = layer.output_type(it)
         act_bytes, act_shape = _input_type_bytes(out_t, itemsize)
+        # what the layer hands on beside its output (``shared_values``) is
+        # its activation too: counted here, once, whoever reads it
+        for shared_t in layer.shared_values(it).values():
+            act_bytes += _input_type_bytes(shared_t, itemsize)[0]
         kept = _kept_bytes(layer, it, conf.dtype)
         p_bytes = _tree_bytes(net.params[i])
         n_params = sum(a.size for a in jax.tree_util.tree_leaves(net.params[i]))
@@ -271,6 +275,10 @@ def conf_memory_report(conf, input_type=None, minibatch: int = 32,
         except ValueError:
             out_t = it
         act_bytes, act_shape = _input_type_bytes(out_t, itemsize)
+        # what the layer hands on beside its output (``shared_values``) is
+        # its activation too: counted here, once, whoever reads it
+        for shared_t in layer.shared_values(it).values():
+            act_bytes += _input_type_bytes(shared_t, itemsize)[0]
         kept = _kept_bytes(layer, it, conf.dtype)
         reports.append(LayerMemoryReport(
             name=name, layer_class=type(layer).__name__,

@@ -30,6 +30,9 @@ def _graph_ancestors(vertices, names):
     stack = list(names)
     while stack:
         cur = stack.pop()
+        if cur not in vertices:
+            # ``<vertex>.<value>``: a value a layer hands on beside its output
+            cur = cur.rpartition(".")[0]
         if cur in seen or cur not in vertices:
             continue
         seen.add(cur)

@@ -365,6 +365,40 @@ def _blocked_attention_64_wide_nope(S):
             ("mla_attend_fwd", "mla_attend_bwd"))
 
 
+def _differential_attention(S, window):
+    # one differential attention layer of the Phi-4-mini-flash stage,
+    # through the layer: 40 query "heads" of 64 against their k1 / k2 head
+    # and the group's 128-wide value, ONE call of shape (1, 40, 8192, 64 |
+    # 64 | 128): the kernels' first (64, 128) width pair and, with the
+    # window of 512, the band's first window of one tile
+    from deeplearning4j_tpu.nn.conf.attention import DifferentialAttention
+    from deeplearning4j_tpu.nn.conf.inputs import InputType
+    from deeplearning4j_tpu.perf.pallas import attention
+    layer = DifferentialAttention(n_heads=40, n_kv_heads=20, head_dim=64,
+                                  layer_index=15, window=window)
+    q, v = S((1, 40, 8192, 64), BF16), S((1, 40, 8192, 128), BF16)
+    assert pk.take("blocked_attention", attention.supported(
+        q, q, v, 512, window or None))
+    shapes = jax.eval_shape(lambda k: layer.init(
+        k, InputType.recurrent(2560, 8192), BF16)[0], jax.random.key(0))
+    params = {k: S(a.shape, BF16) for k, a in shapes.items()}
+
+    def fwd_bwd(params, x):
+        return jax.grad(lambda p, x: jnp.sum(layer.apply(
+            p, {}, x)[0].astype(F32)), argnums=(0, 1))(params, x)
+
+    return (fwd_bwd, (params, S((1, 8192, 2560), BF16)),
+            ("mla_attend_fwd", "mla_attend_bwd"))
+
+
+def _differential_attention_window_512(S):
+    return _differential_attention(S, 512)
+
+
+def _differential_attention_causal(S):
+    return _differential_attention(S, 0)
+
+
 def _grouped_experts_1792(S):
     # the routed layer's grouped products and their backward pass at the
     # LFM2 share's widths: 16,384 tokens x top-4 of 32, a quarter held here:
@@ -492,6 +526,8 @@ AUTO_CASES = [(_pq, "adc_pq"), (_int4_table, "int4_dot"),
               (_grouped_experts_16, None),
               (_blocked_attention_64_wide, None),
               (_blocked_attention_64_wide_nope, None),
+              (_differential_attention_window_512, None),
+              (_differential_attention_causal, None),
               (_grouped_experts_1792, None),
               (_kda_inputs, "kda_inputs"), (_kda_inputs_float32, None),
               (_gdn_inputs, None), (_gdn_inputs_float32, None),
@@ -639,6 +675,36 @@ def test_a_rematerialised_delta_rule_layer_runs_each_scan_kernel_once(
     _, operands = calls["kda_scan_bwd"]
     operands = operands.split("), custom_call_target")[0]
     assert operands.count("%") == 9      # q k v g b states u scores dO
+
+
+def test_the_selective_scan_compiles_for_the_chip(v5e, tpu_backend):
+    """``chunked_selective_scan`` at the Phi-4-mini-flash stage's shape
+    (5,120 channels x 16 states, 8,192 steps in chunks of 64, bfloat16 x, B
+    and C), forward and all six gradients: XLA's own program (no kernel
+    takes the scan), a ``while`` over the chunks around a ``while`` over a
+    chunk's steps, the chunk made again in the backward pass, so that what
+    it holds at once is the chunks' entry states (128 of 328 KB) and ONE
+    chunk's states, never a (time, 5120, 16) array (2.7 GB)."""
+    from deeplearning4j_tpu.nn.conf.state_space import chunked_selective_scan
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def fwd_bwd(x, dt, a, bm, cm, d):
+        return jax.grad(lambda *v: jnp.sum(chunked_selective_scan(
+            *v[:5], chunk=64, skip=v[5])), argnums=(0, 1, 2, 3, 4, 5))(
+                x, dt, a, bm, cm, d)
+
+    compiled = jax.jit(fwd_bwd).lower(
+        S((1, 8192, 5120), BF16), S((1, 8192, 5120), F32),
+        S((5120, 16), F32), S((1, 8192, 16), BF16), S((1, 8192, 16), BF16),
+        S((5120,), F32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert text.count(" while(") >= 4    # chunks and steps, both directions
+    # y and the cotangents of x and dt in float32 (3 x 168 MB), the entry
+    # states (42 MB), one chunk's saved states and decays (2 x 21 MB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.9e9
 
 
 def test_every_auto_family_has_a_case(tpu_backend):
