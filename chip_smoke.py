@@ -654,6 +654,29 @@ def phase_kernels(ctx: Ctx) -> dict:
                   lambda *a: chunked_ssd(*a, chunk=chunk), sargs,
                   shape=[1, steps, heads, 64, 128], chunk=chunk)
 
+        # the Mamba-1 selective scan at the Phi-4-mini-flash cell's shape:
+        # 5,120 channels x 16 states, 8,192 steps, a D term, steps
+        # log-uniform in [1e-3, 1e-1] and A = -1..-N a channel as the public
+        # initialiser draws them: output and six gradients
+        from deeplearning4j_tpu.nn.conf.state_space import (
+            chunked_selective_scan)
+        from deeplearning4j_tpu.perf.pallas import selective_scan
+        steps, channels = (8192, 5120) if chip else (256, 128)
+        keys = jax.random.split(jax.random.key(ctx.seed + 5), 5)
+        x, bm, cm = (jax.random.normal(key, (1, steps, width)).astype(cdt)
+                     for key, width in zip(keys, (channels, 16, 16)))
+        margs = (x, jnp.exp(jax.random.uniform(
+            keys[3], (1, steps, channels), minval=math.log(1e-3),
+            maxval=math.log(1e-1))),
+            -jnp.broadcast_to(jnp.arange(1, 17, dtype=jnp.float32),
+                              (channels, 16)), bm, cm,
+            jax.random.normal(keys[4], (channels,)))
+        check(selective_scan.supported(*margs),
+              "selective_scan does not take 16 states over whole lane tiles")
+        both_arms("selective_scan", "selective_scan",
+                  lambda *a: chunked_selective_scan(*a[:5], skip=a[5]),
+                  margs, shape=[1, steps, channels, 16])
+
         # latent attention's call: q/k heads of 192 and v heads of 128,
         # several tiles, a length that is padded
         heads, steps = (8, 3 * s.attn_seq - 24) if chip else (2, 360)

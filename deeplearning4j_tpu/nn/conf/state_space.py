@@ -50,7 +50,11 @@ once. Rematerialised, the layer keeps the output of its one wide product
 (``u W_in``) where ``keep_projection`` says so (``remat_keeps``,
 ``remat_kept_bytes``), and nothing else. A features mask zeroes the output
 at masked steps: a right-padded batch is exact, since no step reads a later
-one."""
+one.
+
+The first generation (``Mamba1Mixer`` over ``chunked_selective_scan``, a
+decay for every (channel, state) pair, and SambaY's ``GatedMemoryUnit``)
+follows under its own marker below, with its two executions."""
 
 from __future__ import annotations
 
@@ -71,7 +75,7 @@ from deeplearning4j_tpu.nn.conf.short_conv import causal_depthwise_conv
 from deeplearning4j_tpu.nn.initializers import init_weights
 from deeplearning4j_tpu.perf import pallas as pk
 from deeplearning4j_tpu.perf.compile_watch import bump_active
-from deeplearning4j_tpu.perf.pallas import ssd
+from deeplearning4j_tpu.perf.pallas import selective_scan, ssd
 
 # the wide projection's output by its ``checkpoint_name``
 PROJECTION_KEPT = ("state_space.projection",)
@@ -300,8 +304,23 @@ class Mamba2Mixer(BaseLayer):
 
 
 # ------------------------------------------------- the first generation
-# steps of a chunk an iteration of its loop runs: alone on the chip 1, 8, 16
-# and 64 read 44.4, 31.6, 33.7 and 184 ms forward + backward (PERF.md §6)
+# Mamba-1's recurrence has a decay for every (channel, state) pair, so there
+# is no ``C B^T`` to mask and no matrix product: ``chunked_selective_scan``
+# runs the steps in order over an (N, channels) float32 state. Two
+# executions of the same arithmetic, as ``chunked_ssd`` has: the ``lax`` form
+# below (any shape, any backend: the CPU's path, the tests' reference,
+# ``kernel.xla_selective_scan``) and, where ``perf.pallas.selective_scan
+# .supported`` takes the call (a TPU, channels whole lane tiles, N a
+# multiple of 8, a length that is a multiple of the kernels' block of 256
+# steps, bfloat16 or float32), a forward and a backward Pallas kernel that
+# carry the state in registers (``selective_scan_fwd`` / ``selective_scan_bwd``,
+# ``kernel.pallas_selective_scan``): PERF.md §5-6, PR 51 (as XLA's loops the
+# two scans were 30% of the Phi-4-mini-flash cell's step at 2% of their
+# roofline, PR 50).
+#
+# steps of a chunk an iteration of the ``lax`` form's loop runs: alone on the
+# chip 1, 8, 16 and 64 read 44.4, 31.6, 33.7 and 184 ms forward + backward
+# (PERF.md §6, PR 50)
 _UNROLL = 8
 
 
@@ -350,16 +369,22 @@ def chunked_selective_scan(x, dt, a_rate, bm, cm, chunk: int = 64,
     chunk, that chunk's states. Decays are ``exp(dt A)`` of a non-positive
     product, never a quotient of running products, so the form is exact at
     any decay. ``time`` need not be a multiple of ``chunk`` (steps with dt
-    = 0 and x = 0 are appended: they leave the state as it is). Plain
-    ``lax``, XLA writing the backward pass, on every backend: counted
-    ``kernel.xla_selective_scan`` once a call."""
+    = 0 and x = 0 are appended: they leave the state as it is). That is the
+    plain ``lax`` form, XLA writing the backward pass, counted
+    ``kernel.xla_selective_scan`` once a call. Where
+    ``selective_scan.supported`` takes the call the same steps run as the
+    Pallas kernels of ``perf/pallas/selective_scan.py``, counted
+    ``kernel.pallas_selective_scan``: their block of time is their own
+    constant, and ``chunk`` is the ``lax`` form's alone."""
     bsz, t, c = x.shape
     n = bm.shape[-1]
     if a_rate.shape != (c, n):
         raise ValueError(f"A is {a_rate.shape}, not {(c, n)}")
     if chunk < 1:
         raise ValueError(f"a chunk of {chunk} steps")
-    bump_active("kernel.xla_selective_scan")
+    if pk.take("selective_scan",
+               selective_scan.supported(x, dt, a_rate, bm, cm, skip)):
+        return selective_scan.selective_scan(x, dt, a_rate, bm, cm, skip)
     f32 = jnp.float32
     length = min(chunk, t)
     pad = (-t) % length
@@ -392,7 +417,8 @@ class Mamba1Mixer(BaseLayer):
         dt = softplus(r W_dt + dt_bias)       (R -> d_in)
         A = -exp(A_log)                       (d_in, N)
         y = the selective recurrence over xc, dt, A, B, C with D
-            (``chunked_selective_scan``, chunks of ``chunk`` steps)
+            (``chunked_selective_scan``: Pallas kernels where they take the
+            call, else ``lax`` loops over chunks of ``chunk`` steps)
         out = (y * SiLU(z)) W_out             (no bias)
 
     With ``share_scan`` the layer hands ``y``, BEFORE its gate, on as the

@@ -7,7 +7,7 @@ BN-train stats/normalize/residual traffic XLA refuses to fuse across
 costs ~4.7 extra full activation-set HBM crossings (tools/PROFILE_r5.md).
 This package holds the kernels that cross that line by hand — SURVEY
 L0/§7's replacement for libnd4j's C++ kernels exactly where XLA's fusion
-control runs out. Six families, each slotted behind a boundary the repo
+control runs out. Seven families, each slotted behind a boundary the repo
 already parity-tests:
 
 - **bn** (:mod:`perf.pallas.bn`): fused BN-train forward/backward behind
@@ -40,6 +40,13 @@ already parity-tests:
   forward and backward behind one custom-VJP — a chunk's (chunk, chunk)
   decay factors of every head made and used in VMEM, time-major windows as
   the layer's convolution writes them, the states carried in scratch.
+- **selective_scan** (:mod:`perf.pallas.selective_scan`): the recurrence
+  of the Mamba-1 mixer behind ``chunked_selective_scan``
+  (nn/conf/state_space.py), forward and backward behind one custom-VJP —
+  no matrix product: a tile's (N, 512) float32 state carried in registers
+  through a block's steps, the block's states made again in VMEM for the
+  adjoint steps, (time, channels) windows as the layer's neighbours write
+  and read them.
 
 Selection contract (every kernel, no exceptions):
 
@@ -51,7 +58,8 @@ Selection contract (every kernel, no exceptions):
    :data:`TPU_AUTO_FAMILIES` only) — AND the call site's shape predicate
    (``bn.supported``, ``adc.pq_supported``, ``adc.int4_supported``,
    ``kda.supported``, ``kda_inputs.supported``, ``attention.supported``,
-   ``ssd.supported``) says the kernel fits. Anywhere else the reference runs.
+   ``ssd.supported``, ``selective_scan.supported``) says the kernel fits.
+   Anywhere else the reference runs.
 2. Off-TPU, a force-enabled kernel runs in Pallas **interpret mode**
    (:func:`interpret` resolves true) — this is how CPU CI bitwise/
    tolerance-parity-tests the kernel bodies (tests/test_zz_pallas.py).
@@ -76,8 +84,9 @@ auto-selected kernel for a described v5e at a main-path shape, and
 chip_smoke.py's ``kernels`` phase runs each against its reference on
 the chip. Measured speed: ``kda_scan`` (PERF.md §5-6, PR 27: the cell's
 scan 221 -> 103 ms a step), ``blocked_attention`` (PR 29: the cell's
-latent attention, see PERF.md §6) and ``kda_inputs`` (PR 31: both token
-cells' delta-rule layers, PERF.md §5-6); the retrieval kernels against XLA
+latent attention, see PERF.md §6), ``kda_inputs`` (PR 31: both token
+cells' delta-rule layers, PERF.md §5-6), ``ssd_scan`` (PR 47) and
+``selective_scan`` (PR 51: PERF.md §5-6); the retrieval kernels against XLA
 at 1M rows are still unmeasured (ROADMAP Speed 3/6).
 """
 
@@ -115,6 +124,8 @@ FAMILIES: Dict[str, str] = {
                   "(nn/conf/linear_attention.py)",
     "ssd_scan": "chunked_ssd's scan over chunks, forward and backward "
                 "(nn/conf/state_space.py)",
+    "selective_scan": "chunked_selective_scan's recurrence, forward and "
+                      "backward (nn/conf/state_space.py)",
 }
 
 # Families the automatic rule selects on a TPU backend: those the v5e
@@ -129,7 +140,7 @@ FAMILIES: Dict[str, str] = {
 #   input, indices and output"); needs a DMA rework (ROADMAP Speed 6).
 TPU_AUTO_FAMILIES = frozenset({"adc_pq", "int4_dot", "kda_scan",
                                "blocked_attention", "kda_inputs",
-                               "ssd_scan"})
+                               "ssd_scan", "selective_scan"})
 # No shape of these compiles, so not even an explicit enable (a
 # TuningRecord's ``pallas_kernels=True`` is applied process-wide) selects
 # them outside interpret mode.

@@ -495,6 +495,37 @@ def _ssd_scan_float32(S):
     return fwd_bwd, args, ("ssd_scan_fwd", "ssd_scan_bwd")
 
 
+def _selective_scan_args(S, bsz, steps, channels, dtype):
+    return (S((bsz, steps, channels), dtype), S((bsz, steps, channels), F32),
+            S((channels, 16), F32), S((bsz, steps, 16), dtype),
+            S((bsz, steps, 16), dtype), S((channels,), F32))
+
+
+def _selective_fwd_bwd(x, dt, a, bm, cm, d):
+    from deeplearning4j_tpu.nn.conf.state_space import chunked_selective_scan
+    return jax.grad(lambda *v: jnp.sum(chunked_selective_scan(
+        *v[:5], chunk=64, skip=v[5])), argnums=range(6))(x, dt, a, bm, cm, d)
+
+
+def _selective_scan(S):
+    # the Mamba-1 scan at the Phi-4-mini-flash stage's shape, one sequence
+    # of 8192 steps, 5,120 channels x 16 states, bfloat16 x, B and C and a D
+    # term: the forward kernel that saves the blocks' entry states and the
+    # backward kernel, through chunked_selective_scan's own selection
+    from deeplearning4j_tpu.perf.pallas import selective_scan
+    args = _selective_scan_args(S, 1, 8192, 5120, BF16)
+    assert pk.take("selective_scan", selective_scan.supported(*args))
+    return _selective_fwd_bwd, args, ("selective_scan_fwd",
+                                      "selective_scan_bwd")
+
+
+def _selective_scan_float32(S):
+    # the same kernels on float32 operands, two sequences, three tiles of
+    # 128 channels (the narrowest the kernels take)
+    return (_selective_fwd_bwd, _selective_scan_args(S, 2, 512, 384, F32),
+            ("selective_scan_fwd", "selective_scan_bwd"))
+
+
 def _bn_fwd(S):
     # ResNet50 batch 128, the one stage whose rows fit: 7x7
     z = S((128, 7, 7, 2048), BF16)
@@ -531,7 +562,9 @@ AUTO_CASES = [(_pq, "adc_pq"), (_int4_table, "int4_dot"),
               (_grouped_experts_1792, None),
               (_kda_inputs, "kda_inputs"), (_kda_inputs_float32, None),
               (_gdn_inputs, None), (_gdn_inputs_float32, None),
-              (_ssd_scan, "ssd_scan"), (_ssd_scan_float32, None)]
+              (_ssd_scan, "ssd_scan"), (_ssd_scan_float32, None),
+              (_selective_scan, "selective_scan"),
+              (_selective_scan_float32, None)]
 EXPLICIT_CASES = [_bn_fwd, _bn_bwd]
 
 
@@ -679,32 +712,28 @@ def test_a_rematerialised_delta_rule_layer_runs_each_scan_kernel_once(
 
 def test_the_selective_scan_compiles_for_the_chip(v5e, tpu_backend):
     """``chunked_selective_scan`` at the Phi-4-mini-flash stage's shape
-    (5,120 channels x 16 states, 8,192 steps in chunks of 64, bfloat16 x, B
-    and C), forward and all six gradients: XLA's own program (no kernel
-    takes the scan), a ``while`` over the chunks around a ``while`` over a
-    chunk's steps, the chunk made again in the backward pass, so that what
-    it holds at once is the chunks' entry states (128 of 328 KB) and ONE
-    chunk's states, never a (time, 5120, 16) array (2.7 GB)."""
-    from deeplearning4j_tpu.nn.conf.state_space import chunked_selective_scan
+    (5,120 channels x 16 states, 8,192 steps, bfloat16 x, B and C), forward
+    and all six gradients: the forward and the backward kernel, no ``while``
+    over chunks or steps, and no states in HBM but the 32 blocks' entry
+    states (10.5 MB): never a (.., 64, 16, 5120) chunk of them nor a
+    (time, 16, 5120) array. What the program holds at once beside its
+    operands and results is y's cotangent (168 MB), B and C, then dB and dC,
+    with every value over a lane tile (2 x 67 MB) and the entry states:
+    0.38 GB, held under 0.45 where XLA's loops were held under 0.9."""
+    import re
 
-    def S(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
-
-    def fwd_bwd(x, dt, a, bm, cm, d):
-        return jax.grad(lambda *v: jnp.sum(chunked_selective_scan(
-            *v[:5], chunk=64, skip=v[5])), argnums=(0, 1, 2, 3, 4, 5))(
-                x, dt, a, bm, cm, d)
-
-    compiled = jax.jit(fwd_bwd).lower(
-        S((1, 8192, 5120), BF16), S((1, 8192, 5120), F32),
-        S((5120, 16), F32), S((1, 8192, 16), BF16), S((1, 8192, 16), BF16),
-        S((5120,), F32)).compile()
+    fwd_bwd, args, kernels = _selective_scan(
+        lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e))
+    compiled = jax.jit(fwd_bwd).lower(*args).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" not in text
-    assert text.count(" while(") >= 4    # chunks and steps, both directions
-    # y and the cotangents of x and dt in float32 (3 x 168 MB), the entry
-    # states (42 MB), one chunk's saved states and decays (2 x 21 MB)
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.9e9
+    for name in kernels:
+        assert re.search(rf'custom_call_target="tpu_custom_call".*{name}',
+                         text), name
+    assert " while(" not in text
+    assert not re.search(r"\[(\d+,)*64,16,5120\]", text)
+    assert not re.search(r"\[(\d+,)*8192,16,5120\]", text)
+    assert "f32[1,32,16,5120]" in text          # the blocks' entry states
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.45e9
 
 
 def test_every_auto_family_has_a_case(tpu_backend):

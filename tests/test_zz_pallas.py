@@ -1214,3 +1214,217 @@ def test_ssd_kernels_lower_under_the_layers_scan_scope():
             name = l.split('op_name="')[1].split('"')[0]
             assert "Mamba2Mixer:l0_ssm" in name and "ssm.scan" in name, name
             assert way in name, name
+
+
+# ------------------------------------------ the selective scan (PR 51)
+# ``chunked_selective_scan``'s ``lax`` form against the recurrence run step
+# by step is tests/test_zz_selective_scan.py's; here the kernels
+# (interpreted) are held to that form, the selection to what it says, and
+# the kernels to the layer's scope.
+from deeplearning4j_tpu.perf.pallas import selective_scan  # noqa: E402
+
+
+def _selective_counters():
+    return _family_counters("selective_scan")
+
+
+def _selective_operands(decay, dtype=jnp.float32, t=512, c=128, n=16, b=2,
+                        skip=True):
+    """Seeded operands of a selective scan whose steps decay ``near_zero``
+    (a block's product of decays underflows float32 many times over),
+    ``near_one`` (the state all but kept over both blocks) or ``mixed``
+    (channels of both kinds, long steps among short ones): x, B and C in
+    ``dtype``, dt float32 and >= 0, A (channels, N) < 0, D or None."""
+    k = jax.random.split(jax.random.key(0), 8)
+    x = jax.random.normal(k[0], (b, t, c)).astype(dtype)
+    dt = jnp.exp(jax.random.uniform(k[1], (b, t, c), minval=math.log(1e-3),
+                                    maxval=math.log(1e-1)))
+    rate = {"near_zero": -400.0, "near_one": -1e-3, "mixed": -1.0}[decay]
+    a = rate * jnp.exp(jax.random.uniform(k[2], (c, n), minval=-0.5,
+                                          maxval=0.5))
+    if decay == "mixed":
+        dt = jnp.where(jax.random.bernoulli(k[3], 0.2, dt.shape), 30.0 * dt,
+                       dt)
+        a = jnp.where(jax.random.bernoulli(k[4], 0.3, (c, 1)), 300.0 * a, a)
+    bm = jax.random.normal(k[5], (b, t, n)).astype(dtype)
+    cm = jax.random.normal(k[6], (b, t, n)).astype(dtype)
+    d = jax.random.normal(k[7], (c,)) if skip else None
+    return x, dt, a, bm, cm, d
+
+
+def _selective_out_and_grads(args):
+    """y and the gradient of every operand that is there (x, dt, A, B, C
+    and D where the call has one)."""
+    probe = jax.random.normal(jax.random.key(9), args[0].shape)
+    args = args if args[5] is not None else args[:5]
+
+    def run(*a):
+        y = ssm.chunked_selective_scan(
+            *a[:5], chunk=64, skip=a[5] if len(a) > 5 else None)
+        return jnp.sum(y * probe), y
+
+    return jax.jit(jax.value_and_grad(run, range(len(args)),
+                                      has_aux=True))(*args)
+
+
+@pytest.mark.parametrize("decay", ["mixed", "near_zero", "near_one"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("c,skip", [(128, True), (384, True), (512, False)],
+                         ids=["one_tile", "three_tiles", "a_wide_tile_no_skip"])
+def test_selective_scan_kernels_are_the_lax_scan(c, skip, dtype, decay):
+    """Forward and EVERY gradient (x, dt, A, B, C, D) of ``selective_scan``
+    against ``jax.vjp`` of the ``lax`` form over two time blocks (a carried
+    state, dS carried back, a block's states made again from its entry),
+    two sequences, one channel tile, several (dB, dC and the states'
+    scratch over tiles) and a tile of four lane tiles: the same float32
+    operations but for the order of the sum over the states, so both types
+    agree to float32's rounding (the cast of dx, dB and dC to bfloat16 is
+    the same in both)."""
+    args = _selective_operands(decay, dtype, c=c, skip=skip)
+    before = _selective_counters()
+    with pk.override(enabled=False):
+        want = _selective_out_and_grads(args)
+    assert _selective_counters() == (before[0] + 1, before[1])
+    with pk.override(enabled=True, interpret=True):
+        assert selective_scan.supported(*args)
+        got = _selective_out_and_grads(args)
+    assert _selective_counters() == (before[0] + 1, before[1] + 1)
+    assert got[0][1].dtype == jnp.float32
+    # near_zero: A's gradient is a sum of terms e-100 and smaller beside a
+    # few of order one; bfloat16: one rounding of a cotangent's last bit
+    tol = 1e-2 if dtype == jnp.bfloat16 else 1e-3 if decay == "near_zero" \
+        else 3e-5
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert bool(jnp.all(jnp.isfinite(a.astype(jnp.float32))))
+        _close(a.astype(jnp.float32), b.astype(jnp.float32), tol)
+
+
+@pytest.mark.parametrize("why,change", [
+    ("a ragged length", dict(t=300)),
+    ("channels off a lane tile", dict(c=96)),
+    ("a state of 4", dict(n=4)),
+    ("float16", dict(dtype=jnp.float16)),
+    ("a CPU that would compile", dict(interpret=False))])
+def test_selective_scan_is_untouched_where_the_kernels_do_not_apply(why,
+                                                                    change):
+    """Family on at a call ``supported`` refuses: ``chunked_selective_scan``
+    runs the ``lax`` form, the same program bit for bit, and counts
+    ``kernel.xla_selective_scan``."""
+    interpret = change.pop("interpret", True)
+    args = _selective_operands(**{"decay": "mixed", "t": 256, **change})
+    before = _selective_counters()
+    with pk.override(enabled=False):
+        off = _selective_out_and_grads(args)
+    with pk.override(enabled=True, interpret=interpret):
+        assert not selective_scan.supported(*args), why
+        on = _selective_out_and_grads(args)
+    assert _selective_counters() == (before[0] + 2, before[1])
+    for a, b in zip(jax.tree.leaves(off), jax.tree.leaves(on)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_selective_scan_supported_reads_shapes_types_and_the_limit():
+    x, dt, a, bm, cm, d = _selective_operands("mixed", t=256)
+    with pk.override(enabled=True, interpret=True):
+        assert selective_scan.supported(x, dt, a, bm, cm, d)
+        assert selective_scan.supported(x, dt, a, bm, cm)
+        # x, B and C alike; dt float32; A and D as wide as x
+        assert not selective_scan.supported(x.astype(jnp.bfloat16), dt, a,
+                                            bm, cm, d)
+        assert not selective_scan.supported(x, dt.astype(jnp.bfloat16), a,
+                                            bm, cm, d)
+        assert not selective_scan.supported(x, dt, a[:64], bm, cm, d)
+        assert not selective_scan.supported(x, dt, a, bm, cm, d[:64])
+        # the cell's call, and 64 times its channels: over the VMEM limit
+        S = jax.ShapeDtypeStruct
+        cell = [S((1, 8192, 5120), jnp.bfloat16), S((1, 8192, 5120),
+                                                    jnp.float32),
+                S((5120, 16), jnp.float32), S((1, 8192, 16), jnp.bfloat16),
+                S((1, 8192, 16), jnp.bfloat16), S((5120,), jnp.float32)]
+        assert selective_scan.supported(*cell)
+        wide = [S(tuple(5120 * 64 if k == 5120 else k for k in s.shape),
+                  s.dtype) for s in cell]
+        assert not selective_scan.supported(*wide)
+    assert ("selective_scan" in pk.FAMILIES
+            and "selective_scan" in pk.TPU_AUTO_FAMILIES)
+
+
+@pytest.mark.parametrize("kind", ["plain", "share_scan", "remat"])
+def test_a_mamba1_mixer_gives_the_same_gradients_with_the_kernels(kind):
+    """A ``Mamba1Mixer`` plain, handing its scan on (``share_scan``: the
+    scan's output has two readers) and rematerialised (the forward kernel
+    twice, the backward once, the entry states the custom-VJP's residuals)
+    with the kernels and without: the output, every leaf's gradient and
+    the input's."""
+    from deeplearning4j_tpu.nn.conf.layers import apply_layer
+    layer = ssm.Mamba1Mixer(expand=2, state_size=16,
+                            share_scan=kind == "share_scan",
+                            remat="full" if kind == "remat" else None)
+    params, _ = layer.init(jax.random.key(0), InputType.recurrent(64, 256))
+    x = jax.random.normal(jax.random.key(1), (2, 256, 64))
+
+    def loss(p, xx):
+        out = apply_layer(layer, p, {}, xx, train=True, rng=None, mask=None,
+                          name="mix")[0]
+        total = sum(jnp.sum(jnp.sin(o.astype(jnp.float32)))
+                    for o in jax.tree.leaves(out))
+        return total, out
+
+    def run():
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
+                                          has_aux=True))(params, x)
+
+    before = _selective_counters()
+    with jax.default_matmul_precision("highest"):
+        with pk.override(enabled=False):
+            off = run()
+        assert _selective_counters()[1] == before[1]
+        with pk.override(enabled=True, interpret=True):
+            on = run()
+    assert _selective_counters()[1] > before[1]
+    assert len(jax.tree.leaves(off)) == len(jax.tree.leaves(on)) >= 12
+    for a, b in zip(jax.tree.leaves(off), jax.tree.leaves(on)):
+        _close(a, b, 1e-4)
+
+
+def test_selective_scan_kernels_lower_under_the_layers_scan_scope():
+    """``mamba1.scan_device_ms_per_step`` and ``mamba1.scan_roofline_pct``
+    find their operations by ``op_name`` in the step's HLO text: every
+    operation the forward and backward kernels (here their interpreted
+    bodies) lower to carries the layer's scope and ``mamba1.scan``, in the
+    backward pass too."""
+    from deeplearning4j_tpu.nn.conf.graph import GraphBuilder
+    from deeplearning4j_tpu.nn.conf.recurrent import RnnOutputLayer
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+    conf = (GraphBuilder(NeuralNetConfiguration.builder().seed(3)
+                         .updater(Sgd(learning_rate=0.05)))
+            .add_inputs("in")
+            .add_layer("l0_ssm", ssm.Mamba1Mixer(expand=2, state_size=16),
+                       "in")
+            .add_layer("out", RnnOutputLayer(n_out=3, loss="mcxent"),
+                       "l0_ssm")
+            .set_outputs("out")
+            .set_input_types(InputType.recurrent(64, 256)).build())
+    before = _selective_counters()
+    with pk.override(enabled=True, interpret=True):
+        net = ComputationGraph(conf).init()
+        x = jnp.zeros((1, 256, 64), jnp.float32)
+        y = jnp.zeros((1, 256, 3), jnp.float32)
+        hlo = net._get_jitted("train").lower(
+            net.params, net.state, net.opt_state, net._rng, [x], [y], None,
+            None).compile().as_text()
+    assert _selective_counters() == (before[0], before[1] + 1)
+    # but the bodies of the reducers (the interpreted sums over the states)
+    ops = [l for l in hlo.splitlines() if "op_name=" in l
+           and not re.search(r"= f32\[\] (add|maximum)\(", l)]
+    for kernel, way in (("selective_scan_fwd", "jvp("),
+                        ("selective_scan_bwd", "transpose(")):
+        mine = [l for l in ops if kernel in l]
+        assert len(mine) > 20, (kernel, len(mine))
+        for l in mine:
+            name = l.split('op_name="')[1].split('"')[0]
+            assert "Mamba1Mixer:l0_ssm" in name and "mamba1.scan" in name, \
+                name
+            assert way in name, name
